@@ -3,8 +3,8 @@
 The serving workload the ROADMAP targets is dominated by shared prefixes —
 the same system prompt and conversation history arrive over and over, and
 the reference engine (like our own pre-page scheduler) re-prefills every
-one of them from position 0. Prefill is the expensive phase (130 ms warm /
-8.6 s cold per 64 tokens vs 9.2 ms/token decode, BENCH_r05), so reusing
+one of them from position 0. Prefill is the expensive phase (one weight
+read per prompt chunk plus attention over the whole prefix), so reusing
 prefill compute across requests is the biggest remaining serving win. This
 is the RadixAttention idea (SGLang, Zheng et al. 2024) over PagedAttention
 pages (vLLM, Kwon et al. 2023), adapted to the TPU-friendly static-shape

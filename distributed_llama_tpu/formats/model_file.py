@@ -480,6 +480,21 @@ class ModelFileWriter:
         self._next += 1
         return entry
 
+    def write_raw(self, buf: bytes | np.ndarray, name: str | None = None) -> TensorEntry:
+        """Write the next tensor from bytes ALREADY in its file encoding
+        (e.g. BlockQ40 records) — no float round trip."""
+        entry = self._layout[self._next]
+        if name is not None and name != entry.name:
+            raise ValueError(f"expected tensor {entry.name!r}, got {name!r}")
+        view = memoryview(buf).cast("B")
+        if view.nbytes != entry.nbytes:
+            raise ValueError(
+                f"tensor {entry.name}: {view.nbytes} raw bytes, layout wants {entry.nbytes}"
+            )
+        self.f.write(view)
+        self._next += 1
+        return entry
+
     def expected(self) -> TensorEntry:
         return self._layout[self._next]
 

@@ -70,6 +70,51 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int = 0) -> str:
     return path
 
 
+def llama2_7b_spec(n_layers: int = 32, seq_len: int = 2048) -> ModelSpec:
+    """Llama-2-7B at its published widths (meta-llama/Llama-2-7b
+    ``params.json``: dim 4096, 32 heads MHA, hidden 11008, vocab 32000),
+    Q40 weights. Depth is the one thing a caller may cut."""
+    return ModelSpec(
+        arch_type=ArchType.LLAMA, dim=4096, hidden_dim=11008, n_layers=n_layers,
+        n_heads=32, n_kv_heads=32, vocab_size=32000, seq_len=seq_len,
+        hidden_act=HiddenAct.SILU, rope_theta=10000.0, rope_type=RopeType.LLAMA,
+        weights_float_type=FloatType.Q40,
+    )
+
+
+def write_random_q40_model(path: str, spec: ModelSpec, seed: int = 0) -> str:
+    """A full-size Q40 `.m` in seconds: every Q40 tensor is written as
+    seeded random BlockQ40 records directly (uniform nibbles, per-block f16
+    scales sized so the dequantized weights are ~N(0, 1/d_in) like
+    :func:`random_tensors`) — quantizing billions of host floats buys
+    nothing when the weights are random anyway. F32 tensors (embedding,
+    norms) follow the :func:`random_tensors` rules."""
+    from distributed_llama_tpu.quants import Q40_BLOCK_BYTES, QK
+
+    rng = np.random.default_rng(seed)
+    nibble_std = np.sqrt((16**2 - 1) / 12.0)  # uniform 0..15
+    with open(path, "wb") as f:
+        w = ModelFileWriter(f, spec)
+        for e in w.remaining():
+            if e.float_type == FloatType.Q40:
+                n_blocks = e.n_values // QK
+                blocks = rng.integers(
+                    0, 256, (n_blocks, Q40_BLOCK_BYTES), dtype=np.uint8
+                )
+                base = 1.0 / (np.sqrt(e.shape[-1]) * nibble_std)
+                scales = (base * rng.uniform(0.5, 1.5, n_blocks)).astype(np.float16)
+                blocks[:, :2] = scales.view(np.uint8).reshape(n_blocks, 2)
+                w.write_raw(blocks, e.name)
+            elif e.name.startswith("rms") or ".rms" in e.name:
+                t = 1.0 + 0.1 * rng.standard_normal(e.shape, dtype=np.float32)
+                w.write_tensor(t, e.name)
+            else:
+                t = rng.standard_normal(e.shape, dtype=np.float32)
+                w.write_tensor(t / np.float32(np.sqrt(e.shape[-1])), e.name)
+        w.finish()
+    return path
+
+
 # the tiniest template the ChatTemplate sniffer classifies as CHATML
 # (tokenizer.detect_chat_template matches on the "<|im_start|>" substring)
 SYNTHETIC_CHAT_TEMPLATE = (
@@ -77,13 +122,15 @@ SYNTHETIC_CHAT_TEMPLATE = (
 )
 
 
-def synthetic_tokenizer_data():
+def synthetic_tokenizer_data(vocab_size: int | None = None):
     """A sentencepiece-style synthetic vocab with full byte fallback:
     <unk>/<s>/</s>, 256 byte tokens, a few merge-scored words — every
     string encodes (1 token per byte for novel text), so synthetic prompts
     need no real tokenizer. The chatml template makes it chat-servable:
     the one shared tokenizer behind the loadgen self-host server
-    (loadgen/selfhost.py) and CI-scale serving smokes."""
+    (loadgen/selfhost.py) and CI-scale serving smokes. ``vocab_size`` pads
+    the vocab with never-merged filler pieces up to a model's width (the
+    loader insists the two agree)."""
     from distributed_llama_tpu.formats.tokenizer_file import TokenizerData
 
     vocab: list[bytes] = [b"<unk>", b"<s>", b"</s>"]
@@ -100,6 +147,12 @@ def synthetic_tokenizer_data():
     ):
         vocab.append(tok)
         scores.append(score)
+    if vocab_size is not None:
+        if vocab_size < len(vocab):
+            raise ValueError(f"vocab_size {vocab_size} < the {len(vocab)} base pieces")
+        for i in range(len(vocab), vocab_size):
+            vocab.append(f"<filler_{i}>".encode())
+            scores.append(-1e9)
     return TokenizerData(
         vocab=vocab, scores=scores, bos_id=1, eos_id=2, chat_eos_id=2,
         chat_template=SYNTHETIC_CHAT_TEMPLATE,

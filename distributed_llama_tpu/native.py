@@ -4,68 +4,95 @@ The compute path is JAX/XLA/Pallas; this library covers the *host* hot paths
 around it — Q40 repacking/dequantization at weight-load time and BPE encode —
 the same split the reference makes between its engine and its loaders.
 
-Loading is best-effort: if the library isn't built (``make -C native``), every
-caller falls back to the numpy/Python implementation, so the package works
-from a clean checkout; the native path is an optimization, not a dependency.
+The library is OPTIONAL where no toolchain exists (no ``make`` on PATH:
+every caller takes the numpy/Python implementation, so the package works
+from a clean checkout). Where a toolchain exists the library is built from
+the sources in ``native/`` and a build that fails raises — a broken build
+never silently becomes the slow path. :func:`available` says which side
+serves.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
+import shutil
 import subprocess
 
 import numpy as np
 
 _LIB_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _LIB_PATH = os.path.join(_LIB_DIR, "libdllama_native.so")
+# content hash of the sources the binary was built from, written next to it
+_KEY_PATH = _LIB_PATH + ".key"
 
 _lib = None
 _load_attempted = False
 
 
-def _try_build() -> bool:
-    try:
-        subprocess.run(
-            ["make", "-C", _LIB_DIR],
-            capture_output=True, timeout=120, check=True,
-        )
-        return True
-    except Exception:
-        return False
+def _source_key() -> str:
+    """Hash of everything the binary depends on (sources + Makefile: the
+    flags are part of the ABI too). Content, not mtime: a copied tree keeps
+    neither the times nor any promise that the .so beside the sources was
+    built from them."""
+    h = hashlib.sha256()
+    for f in sorted(os.listdir(_LIB_DIR)):
+        if f.endswith((".cpp", ".h", ".hpp")) or f == "Makefile":
+            h.update(f.encode())
+            with open(os.path.join(_LIB_DIR, f), "rb") as fh:
+                h.update(fh.read())
+    return h.hexdigest()
 
 
 def _stale() -> bool:
-    """The built library is older than a source file (e.g. a checkout built
-    before an ABI change): calling through a new prototype into an old
-    binary corrupts memory, so rebuild first."""
+    """The built library does not match the sources (e.g. a checkout built
+    before an ABI change, or a binary copied in from elsewhere): calling
+    through a new prototype into an old binary corrupts memory, so rebuild
+    first."""
     try:
-        lib_mtime = os.path.getmtime(_LIB_PATH)
-        # the Makefile is part of the ABI too (CXXFLAGS/defines changes)
-        return any(
-            os.path.getmtime(os.path.join(_LIB_DIR, f)) > lib_mtime
-            for f in os.listdir(_LIB_DIR)
-            if f.endswith((".cpp", ".h", ".hpp")) or f == "Makefile"
-        )
+        with open(_KEY_PATH) as fh:
+            return fh.read().strip() != _source_key()
     except OSError:
         return True
 
 
+def _build() -> None:
+    """``make -C native`` from clean; raises with the compiler's output on
+    failure."""
+    for stale in (_LIB_PATH, _KEY_PATH):
+        if os.path.exists(stale):
+            os.remove(stale)
+    proc = subprocess.run(
+        ["make", "-C", _LIB_DIR], capture_output=True, text=True, timeout=300,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"building {_LIB_PATH} failed (rc={proc.returncode}):\n"
+            f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}"
+        )
+    with open(_KEY_PATH, "w") as fh:
+        fh.write(_source_key())
+
+
 def load_library(build: bool = True):
-    """Returns the loaded library or None. Builds it on first use if a
-    toolchain is available (and rebuilds when sources are newer than the
-    binary — the C ABI may have changed)."""
+    """Returns the loaded library, or None where there is no toolchain to
+    build it with (or ``build`` is False and no current binary exists).
+    Rebuilds when the binary does not match the sources' content hash."""
     global _lib, _load_attempted
     if _lib is not None or _load_attempted:
         return _lib
     _load_attempted = True
-    if (not os.path.exists(_LIB_PATH) or _stale()) and build:
-        if not _try_build():
-            return None
-    try:
+    # one builder at a time: test workers and sibling processes share the
+    # checkout, and a half-written binary must never be loaded
+    with open(_LIB_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not os.path.exists(_LIB_PATH) or _stale():
+            if not build or shutil.which("make") is None:
+                return None
+            _build()
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        return None
 
     lib.q40_dequant_f32.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,

@@ -13,6 +13,7 @@ context entirely.
 
 from __future__ import annotations
 
+import functools
 import os as _os
 
 import jax
@@ -309,9 +310,10 @@ def batched_decode_attention(
 # parity vs the scan is bit-exact at the pinned decode/verify test shapes
 # and within-ulp in general (bench.py --kernels records the divergence).
 #
-# Compiled-mode notes: operands sit in ANY (HBM) memory space, chunks are
-# DMA'd into VMEM scratch, page tables/ids read from SMEM — the Mosaic-
-# shaped structure. The page/slab DMAs are DOUBLE-BUFFERED: chunk i+1's
+# Compiled-mode notes: the KV halves sit in ANY (HBM) memory space and
+# are DMA'd chunk by chunk into VMEM scratch; page ids and loop bounds read
+# from SMEM, queries and mask positions from VMEM — the Mosaic-shaped
+# structure. The page/slab DMAs are DOUBLE-BUFFERED: chunk i+1's
 # copies start into the other scratch slot before chunk i's einsums run, so
 # the loads fly under the compute (``DLT_FUSED_DB=0`` keeps the serial
 # start+wait schedule — the A/B baseline in bench.py --kernels; the
@@ -319,23 +321,22 @@ def batched_decode_attention(
 # are bit-identical by construction). The same kernel body serves the
 # speculative-decode verify hit path (T-query windows per row — decode is
 # its T=1 degenerate case; :func:`fused_paged_verify_attention`). The
-# authoritative gate in this tree is interpret-mode bit-parity on the CPU
-# mesh — the container's jax cannot compile Mosaic.
+# gate in this tree is interpret-mode parity on the CPU mesh; the v5e
+# compiler still refuses the body (see :func:`_fused_paged_enabled`).
 # ---------------------------------------------------------------------------
 
 
 def _fused_paged_enabled() -> bool:
-    """Default: ON where the kernel runs interpreted (CPU — the fully
-    parity-gated mode), OFF on accelerators until a chip smoke validates
-    the Mosaic build (a compiled-mode lowering failure would surface at
-    XLA compile of the whole decode program, past any dispatch-level
-    fallback — the same prudence as the ring all-reduce default).
-    ``DLT_FUSED_PAGED`` overrides either way; read per dispatch decision
-    (trace time)."""
+    """OFF unless ``DLT_FUSED_PAGED=1`` — on every platform, so tier-1
+    exercises the segmented scan the chip runs. The v5e compiler refuses
+    the kernel body ("'tpu.matmul' op Not implemented: Up to 1 batch dim
+    supported": the shared per-chunk einsums carry batch dims B and K;
+    tests/test_chip_compile.py holds the xfail that notices the day it
+    lowers), so asking for it on a chip fails at compile of the decode
+    program. Its parity tests select it explicitly (interpret mode).
+    Read per dispatch decision (trace time)."""
     env = _os.environ.get("DLT_FUSED_PAGED")
-    if env is not None:
-        return env != "0"
-    return jax.devices()[0].platform == "cpu"
+    return env is not None and env != "0"
 
 
 def _fused_paged_eligible(qg, keys, values, paged, chunk: int) -> bool:
@@ -375,7 +376,7 @@ def _fused_paged_attention(
     :func:`fused_paged_verify_attention` — decode is the T=1 degenerate
     case of the verify window, so ONE kernel body serves both and a parity
     fix can never reach one entry point and skip the other."""
-    from distributed_llama_tpu.ops.q40 import tpu_compiler_params
+    from distributed_llama_tpu.ops.q40 import _interpret_default
 
     pool_k, pool_v, tables, matched = paged
     if verify:
@@ -394,7 +395,7 @@ def _fused_paged_attention(
     cdt = kvc.compute_dtype(keys)
     prec = kvc.einsum_precision(keys)
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = _interpret_default()
     if double_buffer is None:
         double_buffer = _double_buffer_default()
     nslots = 2 if double_buffer else 1
@@ -413,10 +414,10 @@ def _fused_paged_attention(
         return [pltpu.VMEM((nslots, n_rows, chunk, K, hd), h.dtype)]
 
     def kernel(*refs):
-        pos_ref, matched_ref, tables_ref, qg_ref = refs[:4]
-        body = refs[4 : 4 + 4 * nh]
-        out_ref = refs[4 + 4 * nh]
-        scr = refs[5 + 4 * nh :]
+        pos_s, matched_s, pos_ref, matched_ref, tables_ref, qg_ref = refs[:6]
+        body = refs[6 : 6 + 4 * nh]
+        out_ref = refs[6 + 4 * nh]
+        scr = refs[7 + 4 * nh :]
         slab_k, slab_v = body[:nh], body[nh : 2 * nh]
         pk, pv = body[2 * nh : 3 * nh], body[3 * nh : 4 * nh]
         sk_scr, sv_scr = scr[:nh], scr[nh : 2 * nh]
@@ -427,9 +428,16 @@ def _fused_paged_attention(
         matched_ = matched_ref[:]
         mk_partial = _verify_partial if verify else _decode_partial
         partial = mk_partial(qg_ref[:], pos_, chunk, cdt, prec)
-        live = jnp.clip(jnp.max(pos_) + T, 0, S)
+        # loop bounds are SCALAR work: read the SMEM copies (Mosaic loads
+        # vectors from VMEM only, and a vector reduce cannot feed a loop
+        # bound) — integer math, so the bounds equal the scan's exactly
+        pos_max = functools.reduce(jnp.maximum, [pos_s[b] for b in range(B)])
+        m_lo = functools.reduce(jnp.minimum, [matched_s[b] for b in range(B)])
+        m_hi = functools.reduce(jnp.maximum, [matched_s[b] for b in range(B)])
+        live = jnp.clip(pos_max + T, 0, S)
         n_chunks = jax.lax.div(live + chunk - 1, chunk)
-        a, b_seg = paged_segments(matched_, chunk, n_chunks)
+        a = jnp.minimum(jax.lax.div(m_lo, chunk), n_chunks)
+        b_seg = jnp.clip(jax.lax.div(m_hi + chunk - 1, chunk), a, n_chunks)
 
         def slab_copies(i, slot):
             # one sliced DMA per half: the first B slab rows' chunk window
@@ -544,9 +552,14 @@ def _fused_paged_attention(
         m, l, o = jax.lax.fori_loop(b_seg, n_chunks, with_loads(compute_slab), carry)
         out_ref[:] = o / jnp.maximum(l, 1e-30)[..., None]
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    # the KV halves stay in HBM (ANY) and reach VMEM scratch by DMA; what
+    # the body reads directly sits where Mosaic can load it: scalars (loop
+    # bounds, page ids) in SMEM, vectors (mask positions, queries) in VMEM
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_specs = (
-        [any_spec, any_spec, pl.BlockSpec(memory_space=pltpu.SMEM), any_spec]
+        [smem_spec, smem_spec, vmem_spec, vmem_spec, smem_spec, vmem_spec]
         + [any_spec] * (4 * nh)
     )
     scratch = (
@@ -558,11 +571,11 @@ def _fused_paged_attention(
         kernel,
         out_shape=jax.ShapeDtypeStruct(lead + (hd,), jnp.float32),
         in_specs=in_specs,
-        out_specs=any_spec,
+        out_specs=vmem_spec,
         scratch_shapes=scratch,
         interpret=interpret,
-        **tpu_compiler_params(),
     )(
+        pos.astype(jnp.int32), matched.astype(jnp.int32),
         pos.astype(jnp.int32), matched.astype(jnp.int32),
         tables.astype(jnp.int32), qg,
         *halves(keys), *halves(values), *halves(pool_k), *halves(pool_v),

@@ -23,21 +23,21 @@ diverge across shards).
 
 Three implementations behind one seam (:func:`all_reduce`):
 
-* ``psum``     — ``jax.lax.psum``, the default off-TPU (and the safety
-                 net everywhere: any ring-path build failure falls back).
+* ``psum``     — ``jax.lax.psum``, the default on every platform.
 * ``ring_xla`` — the ring SCHEDULE via ``lax.ppermute`` steps: the same
                  chunk walk without Pallas, runnable on the CPU test mesh
-                 (the container's jax cannot interpret remote DMA — the
-                 version-gate/soft-fallback policy of the tp clamp), and
-                 the parity reference for the kernel's schedule.
+                 (remote DMA has no interpret mode), and the parity
+                 reference for the kernel's schedule.
 * ``ring``     — the Pallas remote-DMA kernel, TPU compiled mode only.
 
 ``DLT_ALLREDUCE`` pins an implementation (``psum`` / ``ring_xla`` /
-``ring``); unset, EVERY platform defaults to psum for now — the ring
-kernel has never been Mosaic-compiled (no chip in this tree's CI) and a
-lowering failure would surface at XLA compile of the whole jitted
-forward, past any fallback; flipping the TPU default is the first chip-
-validation follow-up (ROADMAP item 1). Every selection is counted in
+``ring``); unset, EVERY platform takes psum. An arm asked for by name is
+the arm that runs: a ring kernel that fails to trace or lower fails the
+program, it never degrades to psum. The ring kernels compile for a
+described v5e 2x2 (tests/test_chip_compile.py) but have not run on a
+chip: the two receive slots are reused without a capacity handshake, so
+a neighbour running ahead could overwrite an unread slot — ROADMAP
+Speed 8. Every selection is counted in
 ``dllama_kernel_path_total{kernel="all_reduce"}`` so the implementation
 actually serving is visible in /metrics.
 """
@@ -53,41 +53,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map across the jax versions this tree supports: current jax
-    wants ``jax.shard_map(check_vma=False)``, the container's 0.4.37 only
-    has ``jax.experimental.shard_map.shard_map(check_rep=False)``. The
-    production backends keep their pinned ``check_vma`` call (the known
-    env-failure ceiling); NEW collective tests/benches use this compat so
-    the ring parity gates run everywhere."""
-    try:
-        from jax import shard_map as _sm  # type: ignore
-
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
-def _axis_size(axis_name: str) -> int | None:
-    """Static size of a named mesh axis during a shard_map trace, across
-    the jax versions this tree supports; None when unresolvable (→ psum)."""
-    try:
-        fr = jax.core.axis_frame(axis_name)  # returns the int itself on 0.4.x
-        return int(getattr(fr, "size", fr))
-    except Exception:
-        pass
-    try:
-        from jax._src.core import get_axis_env
-
-        return int(get_axis_env().axis_size(axis_name))
-    except Exception:
-        return None
-
-
 def _note(path: str) -> None:
     from distributed_llama_tpu import telemetry
 
@@ -95,13 +60,9 @@ def _note(path: str) -> None:
 
 
 def default_impl() -> str:
-    """psum unless ``DLT_ALLREDUCE`` pins otherwise — INCLUDING on TPU for
-    now: the ring kernel has never been Mosaic-compiled (no chip in this
-    tree's CI), and the seam's try/except can only catch TRACE-time
-    failures — a Mosaic lowering error surfaces later, at XLA compile of
-    the whole jitted forward, where no fallback can run. Flipping the TPU
-    default to "ring" is the first item of the chip-validation follow-up
-    (ROADMAP item 1); until then the kernel is an explicit opt-in."""
+    """psum unless ``DLT_ALLREDUCE`` pins otherwise, on every platform:
+    the ring kernel is an explicit opt-in until a chip run has judged it
+    (module docstring; ROADMAP Speed 8)."""
     return _os.environ.get("DLT_ALLREDUCE") or "psum"
 
 
@@ -114,18 +75,15 @@ def all_reduce(x: jax.Array, axis_name: str | None, impl: str | None = None) -> 
     if impl is None:
         impl = default_impl()
     if impl in ("ring", "ring_xla"):
-        n = _axis_size(axis_name)
-        if n is None or n <= 1 or x.shape[-1] < n:
+        n = lax.axis_size(axis_name)
+        if n <= 1 or x.shape[-1] < n:
             impl = "psum"  # tiny/odd payloads: the ring buys nothing
     if impl == "ring":
-        try:
-            out = ring_all_reduce(x, axis_name, n)
-            _note("ici_ring")
-            return out
-        except Exception:
-            # version-gated Pallas surface missing (or the kernel failed to
-            # trace): the collective must not take the program down
-            impl = "psum"
+        # asked for by name: a kernel that fails to trace or lower fails
+        # the program — it never degrades to psum behind the caller's back
+        out = ring_all_reduce(x, axis_name, n)
+        _note("ici_ring")
+        return out
     if impl == "ring_xla":
         _note("ring_xla")
         return ring_all_reduce_xla(x, axis_name, n)
@@ -145,11 +103,11 @@ def all_reduce(x: jax.Array, axis_name: str | None, impl: str | None = None) -> 
 # reduce-scatter walk and starts both directions' remote copies BEFORE
 # computing the next step's chunks: the next tile's MXU work runs while
 # the copies are in flight, which is the overlap the ISSUE's superstep
-# buys over psum. The seam (:func:`matmul_all_reduce`) keeps the same
-# safety ladder as :func:`all_reduce`: the fused kernel engages only
-# under ``DLT_ALLREDUCE=ring`` + the int8 q40 path + an eligible shape,
-# and ANY failure falls back to the unfused matmul + all_reduce arms
-# (whose ring_xla/psum parity is pinned on the CPU mesh).
+# buys over psum. The seam (:func:`matmul_all_reduce`) engages the fused
+# kernel only under ``DLT_ALLREDUCE=ring`` + the int8 q40 path + an
+# eligible shape; ineligible shapes take the unfused matmul + all_reduce
+# arms (whose ring_xla/psum parity is pinned on the CPU mesh), and a
+# kernel that fails to build fails the program.
 
 
 def _fused_ring_eligible(x: jax.Array, qm, n: int) -> bool:
@@ -178,6 +136,18 @@ def _fused_ring_eligible(x: jax.Array, qm, n: int) -> bool:
     return vmem < 10 * 2**20  # ~16 MB/core VMEM, leave headroom
 
 
+def _neighbor_barrier(neighbor) -> None:
+    """Both ring neighbours have entered the kernel before the first remote
+    copy lands in their scratch (the rendezvous ``collective_id`` names)."""
+    barrier = pltpu.get_barrier_semaphore()
+    for nb in neighbor:
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id=nb,
+            device_id_type=pltpu.DeviceIdType.LOGICAL,
+        )
+    pltpu.semaphore_wait(barrier, 2)
+
+
 def _make_fused_matmul_ring_kernel(axis_name: str, n: int, nj: int, w: int):
     """The fused int8-matmul + bidirectional-ring kernel factory.
 
@@ -200,39 +170,40 @@ def _make_fused_matmul_ring_kernel(axis_name: str, n: int, nj: int, w: int):
                comm_ref, scratch_ref, send_sem, recv_sem):
         my = lax.axis_index(axis_name)
         neighbor = (jnp.mod(my + 1, n), jnp.mod(my - 1, n))  # cw, ccw
+        _neighbor_barrier(neighbor)
         np2 = qs_ref.shape[0]  # packed rows = n_pad/2
         bn2 = np2 // nj  # packed rows per block_n tile
         nbt = bn2 // QK  # quant blocks per tile per half
-        T = xq_ref.shape[0]
+        T = xq_ref.shape[1]
 
         def compute_chunk(k):
             """out[:, k*w:(k+1)*w] of THIS shard's x @ dequant(qm): the
             int8 block-dot epilogue, on demand."""
             cols = pl.ds(k * w, w)
 
-            def half(xqh, sxh, nib, swh):
-                xb = xqh.reshape(T, nbt, QK)
+            def half(xb, sxh, nib, swh):
                 wb = nib.reshape(nbt, QK, w)
                 P = jax.lax.dot_general(
-                    xb, wb, (((2,), (1,)), ((1,), (0,))),
+                    xb, wb, (((2,), (1,)), ((0,), (0,))),
                     preferred_element_type=jnp.int32,
                 )  # [nbt, T, w]
                 scaled = P.astype(jnp.float32) * swh[:, None, :]
                 return jnp.sum(scaled * jnp.transpose(sxh)[:, :, None], axis=0)
 
             def tile(j, acc):
-                qs = qs_ref[pl.ds(j * bn2, bn2), cols]
+                # operands arrive in ops.q40.q80_kernel_operands layout
+                qs = qs_ref[pl.ds(j * bn2, bn2), cols].astype(jnp.int32)
                 lo = (qs & 0xF).astype(jnp.int8)
                 hi = (qs >> 4).astype(jnp.int8)
                 acc += half(
-                    xq_ref[:, pl.ds(j * bn2, bn2)],
-                    sx_ref[:, pl.ds(j * nbt, nbt)],
+                    xq_ref[pl.ds(j * nbt, nbt)],
+                    sx_ref[j],
                     lo,
                     scales_ref[pl.ds(j * nbt, nbt), cols],
                 )
                 acc += half(
-                    xq_ref[:, pl.ds((nj + j) * bn2, bn2)],
-                    sx_ref[:, pl.ds((nj + j) * nbt, nbt)],
+                    xq_ref[pl.ds((nj + j) * nbt, nbt)],
+                    sx_ref[nj + j],
                     hi,
                     scales_ref[pl.ds((nj + j) * nbt, nbt), cols],
                 )
@@ -256,7 +227,7 @@ def _make_fused_matmul_ring_kernel(axis_name: str, n: int, nj: int, w: int):
                 dst_ref=comm_ref.at[d, slot],
                 send_sem=send_sem.at[d],
                 recv_sem=recv_sem.at[d],
-                device_id=(neighbor[d],),
+                device_id=neighbor[d],
                 device_id_type=pltpu.DeviceIdType.LOGICAL,
             )
             rdma.start()
@@ -278,8 +249,8 @@ def _make_fused_matmul_ring_kernel(axis_name: str, n: int, nj: int, w: int):
         p_cw, p_ccw = lax.fori_loop(
             1, n, rs_step, (compute_chunk(2 * my), compute_chunk(2 * my + 1))
         )
-        pl.store(out_ref, (2 * jnp.mod(my + 1, n),), p_cw)
-        pl.store(out_ref, (2 * jnp.mod(my - 1, n) + 1,), p_ccw)
+        out_ref[2 * jnp.mod(my + 1, n)] = p_cw
+        out_ref[2 * jnp.mod(my - 1, n) + 1] = p_ccw
 
         def ag_step(s, carry):
             c_cw, c_ccw = carry
@@ -289,8 +260,8 @@ def _make_fused_matmul_ring_kernel(axis_name: str, n: int, nj: int, w: int):
             r0.wait()
             r1.wait()
             got_cw, got_ccw = comm_ref[0, slot], comm_ref[1, slot]
-            pl.store(out_ref, (2 * jnp.mod(my - s + 1, n),), got_cw)
-            pl.store(out_ref, (2 * jnp.mod(my + s - 1, n) + 1,), got_ccw)
+            out_ref[2 * jnp.mod(my - s + 1, n)] = got_cw
+            out_ref[2 * jnp.mod(my + s - 1, n) + 1] = got_ccw
             return got_cw, got_ccw
 
         lax.fori_loop(1, n, ag_step, (p_cw, p_ccw))
@@ -304,23 +275,16 @@ def fused_matmul_ring_all_reduce(x: jax.Array, qm, axis_name: str, n: int) -> ja
     the int8 matmul computed chunk-by-chunk INSIDE the bidirectional ring
     reduce-scatter, remote copies overlapping the next chunks' MXU work.
     TPU compiled mode only, exactly like :func:`ring_all_reduce` (remote
-    DMA cannot run interpreted on the container's jax); callers reach it
-    through the :func:`matmul_all_reduce` seam, which guards eligibility
-    and falls back to the unfused arms on any failure."""
+    DMA has no interpret mode); callers reach it through the
+    :func:`matmul_all_reduce` seam, which guards eligibility."""
     from distributed_llama_tpu.quants import QK
     from distributed_llama_tpu.ops.q40 import (
         BLOCK_N,
         _largest_divisor_tile,
+        q80_kernel_operands,
         quantize_q80,
-        tpu_compiler_params,
     )
 
-    params = tpu_compiler_params(has_side_effects=True, collective_id=1)
-    if not params:
-        raise RuntimeError(
-            "pallas compiler params lack has_side_effects/collective_id; "
-            "refusing to build the fused matmul+ring kernel without them"
-        )
     np_, dp = qm.n_padded, qm.d_padded
     T = x.shape[0]
     w = dp // (2 * n)
@@ -331,6 +295,7 @@ def fused_matmul_ring_all_reduce(x: jax.Array, qm, axis_name: str, n: int) -> ja
     xq, sx = quantize_q80(x)
     qsum = jnp.sum(xq.astype(jnp.float32).reshape(T, np_ // QK, QK), axis=-1)
     xsum = sx * qsum
+    xqb, sxw = q80_kernel_operands(xq, sx, bn)
     slot = (2, 2, T, w)
     out = pl.pallas_call(
         _make_fused_matmul_ring_kernel(axis_name, n, nj, w),
@@ -343,8 +308,10 @@ def fused_matmul_ring_all_reduce(x: jax.Array, qm, axis_name: str, n: int) -> ja
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        **params,
-    )(xq, sx, xsum, qm.qs, qm.scales)
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True, collective_id=1
+        ),
+    )(xqb, sxw, xsum, qm.qs, qm.scales)
     flat = jnp.concatenate(list(out), axis=-1)  # [T, dp]
     return flat[:, : qm.d] if dp != qm.d else flat
 
@@ -374,21 +341,17 @@ def matmul_all_reduce(
     if impl == "ring":
         from distributed_llama_tpu.ops.q40 import QuantizedMatrix, default_q40_path
 
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         if (
-            n is not None
-            and n > 1
+            n > 1
             and isinstance(w, QuantizedMatrix)
             and not w.interleaved
             and default_q40_path() == "int8"
             and _fused_ring_eligible(x, w, n)
         ):
-            try:
-                out = fused_matmul_ring_all_reduce(x, w, axis_name, n)
-                _note("fused_ring")
-                return out
-            except Exception:
-                pass  # unfused arms below are the safety net
+            out = fused_matmul_ring_all_reduce(x, w, axis_name, n)
+            _note("fused_ring")
+            return out
     return all_reduce(_matmul(x, w), axis_name, impl)
 
 
@@ -451,10 +414,10 @@ def ring_all_reduce_xla(x: jax.Array, axis_name: str, n: int) -> jax.Array:
 # ring_all_reduce_xla. The remote copies are started as soon as a partial
 # is ready — on TPU the next chunk's local add (and the surrounding
 # program's epilogue) proceeds while the copy is in flight, which is the
-# overlap psum structurally cannot give. The container's jax cannot
-# interpret make_async_remote_copy (version gate), so this path is
-# TPU-compiled-only; the schedule itself is pinned by the ring_xla parity
-# tests and the two share their chunk arithmetic by construction.
+# overlap psum structurally cannot give. make_async_remote_copy has no
+# interpret mode, so this path is TPU-compiled-only; the schedule itself
+# is pinned by the ring_xla parity tests and the two share their chunk
+# arithmetic by construction.
 
 
 def _make_ring_kernel(axis_name: str, n: int):
@@ -473,6 +436,7 @@ def _make_ring_kernel(axis_name: str, n: int):
     def kernel(chunks_ref, out_ref, comm_ref, scratch_ref, send_sem, recv_sem):
         my = lax.axis_index(axis_name)
         neighbor = (jnp.mod(my + 1, n), jnp.mod(my - 1, n))  # cw, ccw
+        _neighbor_barrier(neighbor)
 
         def start_hop(d, slot, value):
             """Stage ``value`` and start its copy to ring ``d``'s
@@ -484,7 +448,7 @@ def _make_ring_kernel(axis_name: str, n: int):
                 dst_ref=comm_ref.at[d, slot],
                 send_sem=send_sem.at[d],
                 recv_sem=recv_sem.at[d],
-                device_id=(neighbor[d],),
+                device_id=neighbor[d],
                 device_id_type=pltpu.DeviceIdType.LOGICAL,
             )
             rdma.start()
@@ -498,7 +462,7 @@ def _make_ring_kernel(axis_name: str, n: int):
             return comm_ref[0, slot], comm_ref[1, slot]
 
         def local_chunk(d, c):
-            return pl.load(chunks_ref, (2 * c + d,))
+            return chunks_ref[2 * c + d]
 
         def rs_step(s, carry):
             p_cw, p_ccw = carry
@@ -511,14 +475,14 @@ def _make_ring_kernel(axis_name: str, n: int):
         p_cw, p_ccw = lax.fori_loop(
             1, n, rs_step, (local_chunk(0, my), local_chunk(1, my))
         )
-        pl.store(out_ref, (2 * jnp.mod(my + 1, n),), p_cw)
-        pl.store(out_ref, (2 * jnp.mod(my - 1, n) + 1,), p_ccw)
+        out_ref[2 * jnp.mod(my + 1, n)] = p_cw
+        out_ref[2 * jnp.mod(my - 1, n) + 1] = p_ccw
 
         def ag_step(s, carry):
             c_cw, c_ccw = carry
             got_cw, got_ccw = both_hops(s % 2, c_cw, c_ccw)
-            pl.store(out_ref, (2 * jnp.mod(my - s + 1, n),), got_cw)
-            pl.store(out_ref, (2 * jnp.mod(my + s - 1, n) + 1,), got_ccw)
+            out_ref[2 * jnp.mod(my - s + 1, n)] = got_cw
+            out_ref[2 * jnp.mod(my + s - 1, n) + 1] = got_ccw
             return got_cw, got_ccw
 
         lax.fori_loop(1, n, ag_step, (p_cw, p_ccw))
@@ -528,22 +492,8 @@ def _make_ring_kernel(axis_name: str, n: int):
 
 def ring_all_reduce(x: jax.Array, axis_name: str, n: int) -> jax.Array:
     """Bidirectional Pallas remote-DMA ring all-reduce over ``axis_name``
-    (TPU compiled mode only; see the module note on why the container
-    cannot run it interpreted — any TRACE-time failure falls back to psum
-    via :func:`all_reduce`, and the decode payloads are small enough that
+    (TPU compiled mode only; the decode payloads are small enough that
     every operand sits in VMEM)."""
-    from distributed_llama_tpu.ops.q40 import tpu_compiler_params
-
-    params = tpu_compiler_params(has_side_effects=True, collective_id=0)
-    if not params:
-        # has_side_effects/collective_id are CORRECTNESS-critical for a
-        # cross-device DMA kernel (DCE/reordering and the rendezvous id),
-        # not droppable hints: a jax whose params class can't express them
-        # must not run the ring at all (the seam converts this to psum)
-        raise RuntimeError(
-            "pallas compiler params lack has_side_effects/collective_id; "
-            "refusing to build the ring kernel without them"
-        )
     orig_shape = x.shape
     d = x.shape[-1]
     # 2n chunks: index 2c+0 rides the clockwise ring, 2c+1 the counter ring
@@ -564,7 +514,11 @@ def ring_all_reduce(x: jax.Array, axis_name: str, n: int) -> jax.Array:
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        **params,
+        # has_side_effects/collective_id are CORRECTNESS-critical for a
+        # cross-device DMA kernel (DCE/reordering and the rendezvous id)
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True, collective_id=0
+        ),
     )(chunks)
     flat_out = jnp.concatenate(list(out), axis=-1)
     flat_out = flat_out[..., :d] if pad else flat_out

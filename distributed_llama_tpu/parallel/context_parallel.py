@@ -198,7 +198,6 @@ class SequenceParallelForward(TransferProbeMixin):
         from distributed_llama_tpu.parallel.tensor_parallel import (
             param_specs_layered,
             q40_param_specs,
-            shard_map,
             validate_tp,
         )
 
@@ -221,7 +220,6 @@ class SequenceParallelForward(TransferProbeMixin):
         )
         self._P = P
         self._NamedSharding = NamedSharding
-        self._shard_map = shard_map
         self.shard_vocab = tp > 1 and cfg.vocab_size % tp == 0
         # per-layer (keys, values) tuples of [S, K, hd]: sequence slots
         # shard over sp, KV heads over tp (one spec is the pytree prefix
@@ -252,7 +250,7 @@ class SequenceParallelForward(TransferProbeMixin):
         # issued; threads that never forwarded read the 1-dispatch default.
         self._dispatch_local = threading.local()
 
-        prefill = shard_map(
+        prefill = jax.shard_map(
             functools.partial(_sp_prefill, cfg, self._tp_axis),
             mesh=self.mesh,
             in_specs=(self._pspecs, P("sp"), self._cache_spec),
@@ -261,7 +259,7 @@ class SequenceParallelForward(TransferProbeMixin):
         )
         self._prefill = jax.jit(prefill, donate_argnums=(2,))
 
-        step = shard_map(
+        step = jax.shard_map(
             functools.partial(_sp_decode_step, cfg, self._tp_axis),
             mesh=self.mesh,
             in_specs=(self._pspecs, P(), self._cache_spec, P()),
@@ -270,7 +268,7 @@ class SequenceParallelForward(TransferProbeMixin):
         )
         self._step = jax.jit(step, donate_argnums=(2,))
 
-        chunk_fwd = shard_map(
+        chunk_fwd = jax.shard_map(
             functools.partial(_sp_chunk_forward, cfg, self._tp_axis),
             mesh=self.mesh,
             in_specs=(self._pspecs, P(), self._cache_spec, P()),
@@ -430,7 +428,7 @@ class SequenceParallelForward(TransferProbeMixin):
                 )
 
             in_specs = (self._pspecs, P(), self._cache_spec, P(), P())
-        mapped = self._shard_map(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs,
             out_specs=(P(), self._cache_spec), check_vma=False,
         )
@@ -475,7 +473,7 @@ class SequenceParallelForward(TransferProbeMixin):
             return m, o, z
 
         P = self._P
-        mapped = self._shard_map(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=(P(), P(), P()), out_specs=(P(), P(), P()),
             check_vma=False,
         )

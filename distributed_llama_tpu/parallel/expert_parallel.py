@@ -196,7 +196,6 @@ class ExpertParallelMoE:
         from jax.experimental import mesh_utils
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from distributed_llama_tpu.parallel.tensor_parallel import shard_map
 
         if cfg.n_experts % ep:
             raise ValueError(f"ep={ep} must divide n_experts={cfg.n_experts}")
@@ -217,7 +216,7 @@ class ExpertParallelMoE:
             "moe_up": P("ep", None, None),
             "moe_down": P("ep", None, None),
         }
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=self.mesh, in_specs=(P(), lp_specs), out_specs=P(),
             check_vma=False,
         )
@@ -302,7 +301,6 @@ class ExpertParallelForward(TransferProbeMixin):
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         from distributed_llama_tpu.parallel.tensor_parallel import (
-            shard_map,
             validate_tp,
         )
 
@@ -327,7 +325,6 @@ class ExpertParallelForward(TransferProbeMixin):
         )
         self._P = P
         self._NamedSharding = NamedSharding
-        self._shard_map = shard_map
         self.shard_vocab = tp > 1 and cfg.vocab_size % tp == 0
         self._tp_axis = "tp" if tp > 1 else None
         self._specs = ep_param_specs(cfg, quantized, self.shard_vocab)
@@ -335,7 +332,7 @@ class ExpertParallelForward(TransferProbeMixin):
         self._cache_spec = [cache_ax] * cfg.n_layers
         self._decode_cache: dict = {}
 
-        step = shard_map(
+        step = jax.shard_map(
             functools.partial(_ep_forward, cfg, self._tp_axis),
             mesh=self.mesh,
             in_specs=(self._specs, P(), self._cache_spec, P()),
@@ -439,7 +436,7 @@ class ExpertParallelForward(TransferProbeMixin):
                 )
 
             in_specs = (self._specs, P(), self._cache_spec, P(), P())
-        mapped = self._shard_map(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs,
             out_specs=(P(), self._cache_spec), check_vma=False,
         )
@@ -474,7 +471,7 @@ class ExpertParallelForward(TransferProbeMixin):
             (x, z), _ = jax.lax.scan(token_step, (x, z), None, length=n_tokens)
             return x, z
 
-        mapped = self._shard_map(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=(P(), P()), out_specs=(P(), P()),
             check_vma=False,
         )

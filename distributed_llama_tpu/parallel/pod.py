@@ -40,13 +40,11 @@ too, so at matched lanes the aggregate is no worse (BENCH_POD_r08.json)
 
 Everything runs under ``JAX_PLATFORMS=cpu`` +
 ``--xla_force_host_platform_device_count`` mesh mocks, the way PR 7's TP
-pool does — including on container JAX (0.4.x) via the
-:func:`compat_shard_map` signature shim.
+pool does.
 """
 
 from __future__ import annotations
 
-import inspect
 import re
 from typing import Any
 
@@ -57,32 +55,10 @@ from jax.sharding import Mesh
 
 from distributed_llama_tpu.models.config import LlamaConfig
 from distributed_llama_tpu.parallel import sharding
-from distributed_llama_tpu.parallel.tensor_parallel import (
-    TensorParallelForward,
-    shard_map,
-)
+from distributed_llama_tpu.parallel.tensor_parallel import TensorParallelForward
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
-
-_SHARD_MAP_PARAMS = None
-
-
-def compat_shard_map(fn, mesh, in_specs, out_specs, check_vma: bool = False, **kw):
-    """``shard_map`` across jax versions: newer jax names the replication
-    check ``check_vma``, 0.4.x names it ``check_rep``. The legacy 1-D
-    backends keep calling ``check_vma`` directly (their env failures are
-    a pinned baseline); the pod routes through this shim so one-process
-    pod serving runs on both."""
-    global _SHARD_MAP_PARAMS
-    if _SHARD_MAP_PARAMS is None:
-        _SHARD_MAP_PARAMS = frozenset(inspect.signature(shard_map).parameters)
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = check_vma
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
 
 def parse_pod(spec: str) -> tuple[int, int]:
     """``--pod DATAxMODEL`` (e.g. ``2x2``) -> (data, model)."""
@@ -120,8 +96,6 @@ class PodForward(TensorParallelForward):
     weight or cache rule, so arrays replicate over it and one instance
     (shared by every slice's engine) serves the whole pod with one
     compiled program per shape."""
-
-    _shard_map = staticmethod(compat_shard_map)
 
     def __init__(
         self,

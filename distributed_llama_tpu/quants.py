@@ -208,14 +208,12 @@ def deserialize_tensor(buf: bytes | np.ndarray, float_type: FloatType, n_values:
     if float_type == FloatType.F16:
         return np.frombuffer(buf, dtype=np.float16, count=n_values).astype(np.float32)
     if float_type == FloatType.Q40:
-        try:
-            from distributed_llama_tpu import native
+        from distributed_llama_tpu import native
 
-            fast = native.q40_dequant_f32(np.frombuffer(buf, np.uint8, tensor_bytes(float_type, n_values)), n_values)
-            if fast is not None:
-                return fast
-        except Exception:
-            pass
+        if native.available():
+            return native.q40_dequant_f32(
+                np.frombuffer(buf, np.uint8, tensor_bytes(float_type, n_values)), n_values
+            )
         return dequantize_q40(*q40_from_bytes(buf, n_values))
     if float_type == FloatType.Q80:
         return dequantize_q80(*q80_from_bytes(buf, n_values))

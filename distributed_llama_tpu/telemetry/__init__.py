@@ -403,8 +403,8 @@ class PrefixCacheInstruments:
 
 def note_compile_cache_hit() -> None:
     """Count one persistent-compilation-cache hit (a compile served from
-    ``--compile-cache-dir`` instead of a fresh XLA build — the 8.6 s
-    cold-prefill attack, BENCH_r05). Called from the jax monitoring
+    the persistent cache directory instead of a fresh XLA build — the
+    cold-prefill attack). Called from the jax monitoring
     listener platform.enable_compilation_cache installs; cache events are
     rare, so the registry lookup per event is fine (no bind-once needed)."""
     if _enabled:

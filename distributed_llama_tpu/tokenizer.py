@@ -50,14 +50,11 @@ class Tokenizer:
             self._index.setdefault(tok, i)
         # the O(n^2) split+merge core runs natively when the host lib is
         # built (same algorithm, see native/bpe_native.cpp)
-        self._native = None
-        try:
-            from distributed_llama_tpu import native
+        from distributed_llama_tpu import native
 
-            if native.available():
-                self._native = native.NativeBpe(self.vocab, self.scores)
-        except Exception:
-            self._native = None
+        self._native = (
+            native.NativeBpe(self.vocab, self.scores) if native.available() else None
+        )
 
     @classmethod
     def from_file(cls, path: str, model_vocab_size: int | None = None) -> "Tokenizer":

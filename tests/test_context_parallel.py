@@ -16,7 +16,6 @@ from distributed_llama_tpu.parallel.context_parallel import (
     ring_attention,
     sp_decode_attention,
 )
-from distributed_llama_tpu.parallel.tensor_parallel import shard_map
 
 
 def full_causal_attention(q, k, v):
@@ -49,7 +48,7 @@ class TestRingAttention:
         want = full_causal_attention(q, k, v)
 
         mesh = make_mesh(n_dev)
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(ring_attention, axis_name="sp"),
             mesh=mesh,
             in_specs=(P("sp"), P("sp"), P("sp")),
@@ -66,7 +65,7 @@ class TestRingAttention:
         k = rng.randn(S, H, hd).astype(np.float32)
         v = rng.randn(S, H, hd).astype(np.float32)
         mesh = make_mesh(1)
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(ring_attention, axis_name="sp"),
             mesh=mesh,
             in_specs=(P("sp"), P("sp"), P("sp")),
@@ -98,7 +97,7 @@ class TestSpDecodeAttention:
         want = want.reshape(H, hd).astype(np.float32)
 
         mesh = make_mesh(n_dev)
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(sp_decode_attention, axis_name="sp"),
             mesh=mesh,
             in_specs=(P(), P("sp"), P("sp"), P()),

@@ -427,7 +427,6 @@ class TestSampledFailoverReplay:
 
 class TestShardedTopK:
     def test_matches_full_vocab_topk(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         from distributed_llama_tpu.models.sampling import sharded_topk_indices
@@ -444,10 +443,10 @@ class TestShardedTopK:
         logits[0, 10] = logits[0, V // tp + 3] = 7.5
         mesh = Mesh(np.array(devs[:tp]), ("tp",))
 
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda x: sharded_topk_indices(x, "tp", K),
             mesh=mesh, in_specs=(P(None, "tp"),), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         got = np.asarray(fn(jnp.asarray(logits)))
         want = np.asarray(jax.lax.top_k(jnp.asarray(logits), K)[1])

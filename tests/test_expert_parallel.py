@@ -108,8 +108,7 @@ class TestExpertParallel:
         from jax.sharding import Mesh
 
         from distributed_llama_tpu.models.moe import moe_ffn
-        from distributed_llama_tpu.parallel.tensor_parallel import shard_map
-
+        
         cfg, xn, router, gate, up, down = _moe_setup(E=8, k=2, T=32, D=64, H=128)
         epm = ExpertParallelMoE(cfg, 4)
 
@@ -124,7 +123,7 @@ class TestExpertParallel:
             "router": P(), "moe_gate": P(None, None, "tp"),
             "moe_up": P(None, None, "tp"), "moe_down": P(None, "tp", None),
         }
-        tp_fn = jax.jit(shard_map(
+        tp_fn = jax.jit(jax.shard_map(
             tp_body, mesh=mesh, in_specs=(P(), lp_spec), out_specs=P(),
             check_vma=False,
         ))

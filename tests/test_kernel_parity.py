@@ -16,13 +16,11 @@ an 8-device virtual mesh):
   double-buffered and serial DMA schedules, plus the spec-hit ==
   plain-decode transitivity on the fused path.
 * ring all-reduce + the matmul_all_reduce seam: the ring schedule
-  (ppermute realization — the container's jax cannot interpret remote
-  DMA; the version gate in ops/collectives.py documents this) vs psum
+  (ppermute realization — remote DMA has no interpret mode) vs psum
   under the CPU mesh mocks. The fused matmul+ring kernel is TPU-compiled
-  only; the seam's CPU contract is a clean fallback.
+  only (tests/test_chip_compile.py compiles it for a described v5e).
 """
 
-import os
 
 import numpy as np
 import pytest
@@ -196,7 +194,7 @@ class TestFusedPagedAttention:
     the EXACT-EMPTY-PARTIAL merge semantics must survive verbatim."""
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "i8"])
-    @pytest.mark.parametrize("B,S,chunk,page", [(4, 64, 16, 4), (2, 96, 24, 8)])
+    @pytest.mark.parametrize("B,S,chunk,page", [(3, 64, 16, 8), (2, 96, 24, 8)])
     def test_bit_parity_vs_segmented_scan(self, dtype, B, S, chunk, page):
         rng = np.random.RandomState(0)
         K, M, hd, P_ = 2, 2, 8, 16
@@ -211,33 +209,29 @@ class TestFusedPagedAttention:
         )
         pos = jnp.asarray(rng.randint(0, S, B).astype(np.int32))
         paged = (pool_k, pool_v, tables, matched)
-        os.environ["DLT_FUSED_PAGED"] = "0"
-        try:
-            ref = att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
-        finally:
-            os.environ.pop("DLT_FUSED_PAGED", None)
-        got = att.fused_paged_decode_attention(qg, keys, values, pos, chunk, paged)
-        assert bool(jnp.all(got == ref)), float(jnp.max(jnp.abs(got - ref)))
+        # the dispatch default is the segmented scan (the path the chip
+        # runs); the fused kernel is selected by its explicit entry point
+        ref = att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
         # tentpole (c): the double-buffered DMA schedule only reorders copy
         # issue/wait around unchanged compute — both arms bit-identical
-        ser = att.fused_paged_decode_attention(
-            qg, keys, values, pos, chunk, paged, double_buffer=False
-        )
-        db = att.fused_paged_decode_attention(
-            qg, keys, values, pos, chunk, paged, double_buffer=True
-        )
-        assert bool(jnp.all(ser == ref))
-        assert bool(jnp.all(db == ref))
+        for db in (True, False):
+            got = att.fused_paged_decode_attention(
+                qg, keys, values, pos, chunk, paged, double_buffer=db
+            )
+            assert bool(jnp.all(got == ref)), (db, float(jnp.max(jnp.abs(got - ref))))
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "i8"])
-    @pytest.mark.parametrize("B,S,chunk,page", [(4, 64, 16, 4), (2, 96, 24, 8)])
+    @pytest.mark.parametrize("B,S,chunk,page", [(3, 64, 16, 8), (2, 96, 24, 8)])
     def test_verify_bit_parity_and_decode_transitivity(self, dtype, B, S, chunk, page):
         """Tentpole (d): the fused verify kernel vs the segmented verify
         scan (bit), both DMA schedules, AND the spec-hit == plain-decode
-        transitivity — query t of a verify window at position pos+t must
-        emit the exact bytes of a plain decode at that position, on the
-        fused path (the contract that makes speculative acceptance
-        decisions identical to the non-speculative stream)."""
+        transitivity — query t of a verify window at position pos+t is
+        the same MATH as a plain decode at that position, so the two agree
+        within a few f32 roundings. Bit equality ACROSS query widths is
+        not XLA's to promise and the claim is withdrawn (ops/attention.py):
+        it emits one dot and one loop body per width T and rounds them
+        differently — 1 ulp measured on the XLA of jax 0.9, and at
+        chunk=24 even op-by-op dispatch differs."""
         rng = np.random.RandomState(4)
         K, M, hd, P_, T = 2, 2, 8, 16, 3
         qg = jnp.asarray(rng.randn(B, T, K, M, hd).astype(np.float32))
@@ -254,31 +248,25 @@ class TestFusedPagedAttention:
             matched, jnp.asarray(rng.randint(0, S - T, B), jnp.int32)
         )
         paged = (pool_k, pool_v, tables, matched)
-        os.environ["DLT_FUSED_PAGED"] = "0"
-        try:
-            ref = att.batched_verify_attention(
-                qg, keys, values, pos, chunk, paged=paged
-            )
-        finally:
-            os.environ.pop("DLT_FUSED_PAGED", None)
+        ref = att.batched_verify_attention(qg, keys, values, pos, chunk, paged=paged)
         for db in (True, False):
             got = att.fused_paged_verify_attention(
                 qg, keys, values, pos, chunk, paged, double_buffer=db
             )
             assert bool(jnp.all(got == ref)), (db, float(jnp.max(jnp.abs(got - ref))))
-        # dispatch routes the paged verify hit path to the fused kernel
-        hit = att.batched_verify_attention(qg, keys, values, pos, chunk, paged=paged)
-        assert bool(jnp.all(hit == ref))
-        # transitivity: verify query t == plain fused decode at pos+t
+        # transitivity: verify query t vs plain decode at pos+t
         t = 1
-        dec = att.fused_paged_decode_attention(
-            qg[:, t], keys, values, pos + t, chunk, paged
+        dec = att.batched_decode_attention(
+            qg[:, t], keys, values, pos + t, chunk, paged=paged
         )
-        assert bool(jnp.all(ref[:, t] == dec))
+        # a handful of roundings per merge, at most S/chunk merges
+        atol = 8 * np.finfo(np.float32).eps * float(jnp.max(jnp.abs(dec)))
+        np.testing.assert_allclose(np.asarray(ref[:, t]), np.asarray(dec), rtol=0, atol=atol)
 
-    def test_verify_dispatch_counts_fused_path(self):
+    def test_verify_dispatch_counts_fused_path(self, monkeypatch):
         from distributed_llama_tpu import telemetry
 
+        monkeypatch.setenv("DLT_FUSED_PAGED", "1")
         telemetry.enable()
         try:
             telemetry.reset()
@@ -306,9 +294,10 @@ class TestFusedPagedAttention:
             telemetry.reset()
             telemetry.disable()
 
-    def test_dispatch_takes_fused_path_and_counts_it(self):
+    def test_dispatch_takes_fused_path_and_counts_it(self, monkeypatch):
         from distributed_llama_tpu import telemetry
 
+        monkeypatch.setenv("DLT_FUSED_PAGED", "1")
         telemetry.enable()
         try:
             telemetry.reset()
@@ -329,11 +318,9 @@ class TestFusedPagedAttention:
                 "dllama_kernel_path_total", labelnames=("kernel", "path")
             )
             assert ctr.labels(kernel="paged_attention", path="pallas_fused").value >= 1
-            os.environ["DLT_FUSED_PAGED"] = "0"
-            try:
-                att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
-            finally:
-                os.environ.pop("DLT_FUSED_PAGED", None)
+            # unset, every platform takes the segmented scan
+            monkeypatch.delenv("DLT_FUSED_PAGED")
+            att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
             assert ctr.labels(kernel="paged_attention", path="xla_segmented").value >= 1
         finally:
             telemetry.reset()
@@ -372,8 +359,9 @@ class TestRingAllReduce:
                 w = 1.0 + jax.lax.axis_index("tp").astype(jnp.float32)
                 return collectives.all_reduce(y * w, "tp", impl=impl)
 
-            return jax.jit(collectives.shard_map_compat(
-                f, mesh=mesh, in_specs=P(None, None), out_specs=P(None, None)
+            return jax.jit(jax.shard_map(
+                f, mesh=mesh, in_specs=P(None, None), out_specs=P(None, None),
+                check_vma=False,
             ))
 
         for d in (4096, 4100, 256):
@@ -398,16 +386,18 @@ class TestRingAllReduce:
             # re-expose per-shard results so divergence would be visible
             return out[None]
 
-        g = jax.jit(collectives.shard_map_compat(
-            f, mesh=mesh, in_specs=P(None, None), out_specs=P("tp", None, None)
+        g = jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=P(None, None), out_specs=P("tp", None, None),
+            check_vma=False,
         ))
         per_shard = np.asarray(g(x))  # [8, 2, 512]
         for i in range(1, 8):
             np.testing.assert_array_equal(per_shard[0], per_shard[i])
         # and the ring equals psum bitwise on replicated inputs
-        h = jax.jit(collectives.shard_map_compat(
+        h = jax.jit(jax.shard_map(
             lambda y: jax.lax.psum(y, "tp"),
             mesh=mesh, in_specs=P(None, None), out_specs=P(None, None),
+            check_vma=False,
         ))
         np.testing.assert_array_equal(per_shard[0], np.asarray(h(x)))
 
@@ -420,9 +410,10 @@ class TestRingAllReduce:
 
         mesh = self._mesh()
         x = jnp.ones((1, 4), jnp.float32)
-        g = jax.jit(collectives.shard_map_compat(
+        g = jax.jit(jax.shard_map(
             lambda y: collectives.all_reduce(y, "tp", impl="ring_xla"),
             mesh=mesh, in_specs=P(None, None), out_specs=P(None, None),
+            check_vma=False,
         ))
         np.testing.assert_array_equal(np.asarray(g(x)), np.full((1, 4), 8.0))
 
@@ -435,8 +426,8 @@ class TestRingAllReduce:
 class TestMatmulAllReduceSeam:
     """Tentpole (b)'s seam: the wo/down matmul+all-reduce entry point
     (``collectives.matmul_all_reduce``). The fused matmul+ring kernel is
-    TPU-compiled only (the container's jax cannot interpret remote DMA),
-    so the CPU-mesh contract is arm parity through the fallback ladder:
+    TPU-compiled only (remote DMA has no interpret mode), so the CPU-mesh
+    contract is arm parity of the unfused arms:
     the psum arm is exactly the per-shard int8 matmul + psum composition,
     and ring-schedule arms agree within summation-order tolerance (the
     same allclose pin as the plain ring all-reduce)."""
@@ -467,8 +458,9 @@ class TestMatmulAllReduceSeam:
             qm0 = jax.tree.map(lambda a: a[0], qm)
             return collectives.matmul_all_reduce(x[0], qm0, "tp", impl=impl)
 
-        return np.asarray(jax.jit(collectives.shard_map_compat(
+        return np.asarray(jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P("tp"), P("tp")), out_specs=P(None, None),
+            check_vma=False,
         ))(xs, stacked))
 
     def test_seam_arms_agree(self):
@@ -480,12 +472,18 @@ class TestMatmulAllReduceSeam:
             axis=0,
         )
         psum = self._run(mesh, stacked, xs, "psum")
-        ring = self._run(mesh, stacked, xs, "ring")  # fused → clean fallback
         ring_xla = self._run(mesh, stacked, xs, "ring_xla")
         scale = np.abs(ref).max()
         np.testing.assert_allclose(psum / scale, ref / scale, atol=1e-5)
-        np.testing.assert_allclose(ring / scale, psum / scale, atol=1e-5)
         np.testing.assert_allclose(ring_xla / scale, psum / scale, atol=1e-5)
+
+    def test_ring_arm_asked_by_name_fails_loudly_off_tpu(self):
+        """The remote-DMA kernel cannot run on the CPU mesh; asked for by
+        name it must raise, never hand back a psum under the ring's label."""
+        mesh = self._mesh()
+        _, stacked, xs = self._setup()
+        with pytest.raises(ValueError, match="interpret mode"):
+            self._run(mesh, stacked, xs, "ring")
 
     def test_seam_no_axis_is_plain_matmul(self):
         packs, _, xs = self._setup()

@@ -56,32 +56,6 @@ def decode_tokens(stream, prompt, temp, topp, seed, n, spec_draft=None):
     return got
 
 
-def _shard_map_ok() -> bool:
-    """The container may ship a JAX whose shard_map lacks ``check_vma`` —
-    the known tier-1 env ceiling. TP paged tests run where the real
-    collective path runs, and skip cleanly where it cannot."""
-    try:
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        from distributed_llama_tpu.parallel.tensor_parallel import shard_map
-
-        mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
-        f = shard_map(
-            lambda x: x, mesh=mesh, in_specs=(P(),), out_specs=P(),
-            check_vma=False,
-        )
-        np.asarray(jax.jit(f)(jnp.zeros(1)))
-        return True
-    except TypeError:
-        return False
-
-
-tp_env = pytest.mark.skipif(
-    not _shard_map_ok(),
-    reason="this JAX lacks shard_map(check_vma=) — known env ceiling",
-)
-
-
 # ---------------------------------------------------------------------------
 # Ops level: the paged read must be BYTE-identical to attending over a slab
 # that holds copies of the pages (the copy design's layout)
@@ -505,9 +479,6 @@ class TestPagedTelemetry:
 # ---------------------------------------------------------------------------
 # Tensor parallel: the sharded pool (per-shard halves, replicated tables)
 # ---------------------------------------------------------------------------
-
-
-@tp_env
 class TestTensorParallelPool:
     def _sched(self, engine, **kw):
         kw.setdefault("prefix_cache", True)

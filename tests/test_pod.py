@@ -2,8 +2,7 @@
 
 * Greedy streams from the pod are bit-identical to N independent
   engines at the same model degree (the N-process ReplicaPool shape) —
-  and, where container JAX allows the legacy ``check_vma`` path, to the
-  real ``--tp`` backend the pool would run.
+  and to the real ``--tp`` backend the pool would run.
 * One params tree: every slice engine shares the SAME placed arrays
   (the N x weight-copy tax is gone), the rebuild path never reloads
   weights, and the resident-bytes accounting divides by the slice count.
@@ -11,14 +10,8 @@
   replay bit-identically on surviving slices through the untouched
   PR 9/10 ladder, and the supervisor rebuilds the slice from the shared
   substrate.
-
-The pod rides :func:`~distributed_llama_tpu.parallel.pod.compat_shard_map`,
-so these tests run on container JAX (0.4.x, no ``check_vma``) too —
-except the direct tp-backend comparison, which skips there with the
-legacy backends' own env limitation.
 """
 
-import inspect
 import types
 
 import jax.numpy as jnp
@@ -29,14 +22,11 @@ from distributed_llama_tpu import retry, telemetry
 from distributed_llama_tpu.engine import InferenceEngine, faults
 from distributed_llama_tpu.parallel import pod as pod_lib
 from distributed_llama_tpu.parallel.pod import PodGroup, parse_pod, tree_weight_bytes
-from distributed_llama_tpu.parallel.tensor_parallel import shard_map
 from distributed_llama_tpu.server.api import ApiState
 
 from tests.model_utils import random_tensors, tiny_spec, write_model_file
 from tests.test_faults import post_raw, serve_state
 from tests.test_fair_sched import SseStream
-
-HAS_CHECK_VMA = "check_vma" in inspect.signature(shard_map).parameters
 
 
 @pytest.fixture(autouse=True)
@@ -159,12 +149,6 @@ class TestPodParity:
         got = list(s.generate_chunks(6, temperature=0.0, chunk=5, limit=s.pos + 16))
         np.testing.assert_array_equal(np.asarray(got[:16]), want)
 
-    @pytest.mark.skipif(
-        not HAS_CHECK_VMA,
-        reason="container JAX lacks shard_map(check_vma=): the legacy tp "
-        "backend cannot build here (the pinned env-failure class); the "
-        "pod itself runs via compat_shard_map either way",
-    )
     def test_pod_matches_tp_replica_pool_backend(self, model_path):
         """Pod slices vs the REAL --tp backend the N-process ReplicaPool
         runs (tp=2 == model=2): bit-identical greedy streams."""

@@ -129,7 +129,12 @@ def test_tp_loads_standard_basis_on_eligible_dims(tmp_path):
     """The block-interleaved basis (and its TP partial variant) is RETIRED:
     a TP engine on the dims the basis used to engage on loads every pack
     in the standard basis — the int8 MXU kernel's scale-product epilogue
-    made the permute moot — and still matches the single-device engine."""
+    made the permute moot — and still matches the single-device engine.
+    The dims keep every per-shard matrix kernel-eligible (input dims >= the
+    512 tile granule after the tp=2 split), so both engines run the SAME
+    arithmetic: a shard that dropped to the XLA fallback would skip the Q80
+    activation rounding its single-device twin applies (0.04 of drift at
+    dim=512, where wo's shard is 256 wide)."""
     import numpy as np
 
     from tests.model_utils import random_tensors, tiny_spec, write_model_file
@@ -137,7 +142,7 @@ def test_tp_loads_standard_basis_on_eligible_dims(tmp_path):
     from distributed_llama_tpu.quants import FloatType
 
     spec = tiny_spec(
-        dim=512, hidden_dim=1024, n_heads=4, n_kv_heads=4, vocab_size=96,
+        dim=1024, hidden_dim=2048, n_heads=4, n_kv_heads=4, vocab_size=96,
         seq_len=24, weights_float_type=FloatType.Q40,
     )
     path = str(tmp_path / "tp_std.m")

@@ -13,7 +13,7 @@
   builders produce (engine.weights.load_params / random_params /
   stack_expert_leaves), so the table and the loaders cannot drift.
 
-These run on container JAX too (no shard_map involved).
+Pure rule-table checks: no shard_map, no devices involved.
 """
 
 import json

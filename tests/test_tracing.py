@@ -465,8 +465,16 @@ class TestFailoverTrace:
 
                 trees = {}
                 for rid in rids:
-                    status, tree = _get_json(url, f"/debug/trace/{rid}")
-                    assert status == 200, rid
+                    # the handler finishes a trace AFTER the last SSE byte
+                    # is out, so the client can get here first: wait for
+                    # the finished tree (e2e_s set), bounded
+                    deadline = time.monotonic() + 5.0
+                    while True:
+                        status, tree = _get_json(url, f"/debug/trace/{rid}")
+                        assert status == 200, rid
+                        if tree["e2e_s"] is not None or time.monotonic() > deadline:
+                            break
+                        time.sleep(0.02)
                     trees[rid] = tree
                 victims = [
                     t for t in trees.values() if len(t["attempts"]) == 2
