@@ -1,0 +1,539 @@
+"""One run of one cell: files from the seed, the server child, warm-up, the
+measured window, drain, the reference, and the result object.
+
+The parent (this module) never imports JAX: a chip belongs to one process at a
+time, and that process is the server child (the reference child computes on
+the host). ``require_platform`` is an argument so that
+the tests can rehearse the whole run on the CPU; ``run.py`` passes ``"tpu"``
+and has no way to pass anything else.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+
+from benchmark.harness import bytes_model, client, prom, readers, stats, traffic
+
+# The reference check (PERF.md, PR 22, "How close"). The served path returns
+# no logits, so what is compared is the served greedy TOKEN, teacher-forced:
+# at every answered position the float32 reference scores the same context
+# and says how far the served token lies below its own best, as a share of
+# max|logit| (the DEFICIT; 0 where the tokens are equal).
+#
+# What a faithful engine shows (full width, the chip's kernels on the CPU and
+# a float32 copy of the reference with the engine's roundings): the Q80
+# rounding of the activations into every Q40 matmul puts its logits 1.6e-3
+# (2 layers) to 3.5e-3 (16 layers) rms of max|logit| from float32, 1.6e-2 at
+# the worst of 32000. A deficit needs the errors of two logits to differ by
+# more than their margin: the largest of 1280 positions was 5.7e-3.
+LOGIT_TOL = 2e-2  # max over the vocabulary of |engine logit - reference|; tools/logit_check.py
+MISS_TOL = 1e-2  # a position whose deficit is over this is a MISS
+# Misses allowed: 3 % of the positions compared, for the tail of the rounding
+# at a near-tie: on the chip 1 of the first 512 positions of the dense cells
+# was a miss (1.36e-2, the reference's second choice), on the CPU 1 of 1024
+# (1.7e-2). 3 % of 8 probes' 256 positions is 7: fewer than the probes, so a
+# fault at ONE position of every probe (a page boundary, the first token)
+# fails. Activations at 3 mantissa bits miss 12 to 23 of 256, a dropped layer
+# 96 or more.
+MAX_MISS_SHARE = 0.03
+# A sparse-expert router is discontinuous: where the last expert kept and the
+# first one dropped are a near-tie, rounding swaps them and the logits jump
+# (30 of 1024 positions of a faithful 4-layer Mixtral, 7 of them misses, up to
+# 6.7e-2). So a position is not compared where the reference's own routing
+# gap, in any layer, is under ROUTER_TIE of max|router logit|: a quarter of the
+# positions at 4 layers, and with them every swap and every miss of the 1024.
+ROUTER_TIE = 2e-2
+# In a dense model, which has no such jump, no position at all may be off by
+# more than this.
+DENSE_HARD_TOL = 3e-2
+PROBES, PROBE_PROMPT, PROBE_TOKENS = 8, 64, 32  # 32: one decode chunk, whatever is asked
+# an answer is compared where its text says its tokens for certain (traffic.answer_ids: 5 of
+# 6 answers at a vocabulary of 32000); routing near-ties go too
+MIN_COMPARED = PROBES * PROBE_TOKENS // 4
+TRACE_SECONDS = 2.0  # 5 s of a 16-layer server's ops crashed the profiler at stop_trace (PR 22)
+
+
+class BenchFailure(RuntimeError):
+    """The run cannot produce a result: no line is printed, exit code 1."""
+
+
+def log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def load_json(path: str):
+    with open(path) as f:
+        return json.load(f)
+
+
+class Cell:
+    """The data of one cell, found by name from ``BENCHMARK.json``."""
+
+    def __init__(self, root: str, workload: str):
+        self.root = root
+        self.bench = load_json(os.path.join(root, "BENCHMARK.json"))
+        entry = next((w for w in self.bench["workloads"] if w["name"] == workload), None)
+        if entry is None:
+            raise BenchFailure(f"no workload {workload!r} in BENCHMARK.json")
+        self.dir = os.path.join(root, self.bench["paths"][0])
+        self.name = workload
+        self.chips = int(entry["chips"])
+        self.launch = load_json(os.path.join(self.dir, "workloads", f"{workload}.json"))
+        cfg_entry = next(c for c in self.bench["configs"] if c["name"] == entry["config"])
+        self.config = load_json(os.path.join(root, cfg_entry["file"]))
+        self.mix = load_json(os.path.join(self.dir, "traffic", f"{entry['traffic']}.json"))
+        for key in ("config", "traffic", "chips"):
+            if self.launch[key] != entry[key]:
+                raise BenchFailure(f"{workload}: its file and BENCHMARK.json differ on {key!r}")
+
+    def metrics_for(self, group: str) -> list[dict]:
+        return [m for m in self.bench[group]
+                if "workloads" not in m or self.name in m["workloads"]]
+
+    def flag(self, name: str, default: int) -> int:
+        flags = self.launch["flags"]
+        return int(flags[flags.index(name) + 1]) if name in flags else default
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _child_env(root: str, cache: str) -> dict:
+    env = dict(os.environ)
+    env["PYTHONUNBUFFERED"] = "1"
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    # the compile cache: one fixed directory inside the checkout, whatever the
+    # machine's own says, so that two checkouts share nothing and the path (part
+    # of the cache key) never moves; no size cap, or the big programs evict each
+    # other and every run compiles
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(cache, "jax")
+    env["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"
+    return env
+
+
+class Server:
+    """The server child and the control channel beside it."""
+
+    def __init__(self, cell: Cell, model: str, tokenizer: str, cache: str, require_platform: str):
+        self.port, self.control_port = _free_port(), _free_port()
+        self.beside: list = []  # other children of the run, killed with it
+        self.log_path = os.path.join(cache, "server.log")
+        cmd = [sys.executable, "-m", "benchmark.harness.server_child", require_platform,
+               str(cell.chips), str(self.control_port),
+               "--model", model, "--tokenizer", tokenizer, "--port", str(self.port),
+               "--trace-out", os.path.join(cache, "dllama-trace.json"), *cell.launch["flags"]]
+        if cell.chips > 1 and "--tp" not in cell.launch["flags"]:
+            raise BenchFailure("a cell on several chips names its --tp in its flags")
+        log(f"[setup] server: {' '.join(cmd[2:])}")
+        self._log = open(self.log_path, "w")
+        self.proc = subprocess.Popen(cmd, cwd=cell.root, env=_child_env(cell.root, cache),
+                                     stdout=self._log, stderr=subprocess.STDOUT)
+
+    def tail(self, n: int = 40) -> str:
+        self._log.flush()
+        with open(self.log_path, errors="replace") as f:
+            return "".join(f.readlines()[-n:])
+
+    def wait_ready(self, limit_s: float) -> None:
+        t0 = time.monotonic()
+        while True:
+            if self.proc.poll() is not None:
+                raise BenchFailure(f"server exited with code {self.proc.returncode} before it "
+                                   f"was ready:\n{self.tail()}")
+            if time.monotonic() - t0 > limit_s:
+                raise BenchFailure(f"server not ready after {limit_s:.0f} s:\n{self.tail()}")
+            try:
+                status, _ = client.http_json(self.port, "GET", "/readyz", timeout=5.0)
+                if status == 200:
+                    return
+            except OSError:
+                pass
+            time.sleep(0.5)
+
+    def control(self, path: str, body: dict | None = None) -> dict:
+        status, raw = client.http_json(self.control_port, "POST", path, body or {}, timeout=120.0)
+        if status != 200:
+            raise BenchFailure(f"control {path}: HTTP {status}")
+        return json.loads(raw)
+
+    def scrape(self) -> list:
+        status, raw = client.http_json(self.port, "GET", "/metrics", timeout=30.0)
+        if status != 200:
+            raise BenchFailure(f"/metrics: HTTP {status}")
+        return prom.parse(raw.decode())
+
+    def stop(self) -> int | None:
+        """SIGTERM, wait for the drain; the exit code, or None if it had to be killed."""
+        if self.proc.poll() is None:
+            self.proc.send_signal(signal.SIGTERM)
+            try:
+                self.proc.wait(120)
+            except subprocess.TimeoutExpired:
+                self.proc.kill()
+                self.proc.wait()
+                self._log.close()
+                return None
+        self._log.close()
+        return self.proc.returncode
+
+
+def _probe(server: Server, req) -> stats.Record:
+    rec = stats.Record(index=req.index, due=time.monotonic(), asked=req.max_tokens)
+    client.send(server.port, rec, req.body, timeout=900.0)
+    if not rec.ok:
+        raise BenchFailure(f"probe {req.index} failed: status {rec.status} error {rec.error} "
+                           f"finish {rec.finish}")
+    return rec
+
+
+def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
+             require_platform: str, t_process: float) -> dict:
+    """Run the cell; return the result object (the last line of stdout)."""
+    cell = Cell(root, workload)
+    try:
+        from benchmark.harness import modelfile  # imports the package's writers
+        import distributed_llama_tpu  # noqa: F401
+    except ImportError as e:
+        raise BenchFailure(f"the system under test is not in this directory: {e}") from None
+    cache = os.path.join(cell.dir, ".cache")
+    model_dir = os.path.join(cache, "model")
+    trace_dir = os.path.join(cache, "trace")
+    for d in (model_dir, trace_dir):
+        shutil.rmtree(d, ignore_errors=True)  # one seed's files at a time: they are gigabytes
+    os.makedirs(trace_dir)
+    server = None
+    try:
+        t = time.monotonic()
+        model, tokenizer = modelfile.write_artifacts(cell.config, seed, model_dir,
+                                                     cell.config["max_position_embeddings"])
+        size_gb = os.path.getsize(model) / 1e9
+        log(f"[setup] {size_gb:.2f} GB model file from seed {seed} in {time.monotonic() - t:.1f} s")
+        t = time.monotonic()
+        server = Server(cell, model, tokenizer, cache, require_platform)
+        server.wait_ready(1000.0)
+        device = server.control("/device")
+        log(f"[setup] server ready in {time.monotonic() - t:.1f} s; device {json.dumps(device)}")
+        return _measure(cell, server, device, model, cache, trace_dir, seed, seconds, trace,
+                        require_platform, t_process)
+    finally:
+        if server is not None:
+            for child in server.beside:
+                child.kill()
+            if server.proc.poll() is None:
+                server.proc.kill()
+                server.proc.wait()
+        shutil.rmtree(model_dir, ignore_errors=True)
+
+
+def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, trace_dir: str,
+             seed: int, seconds: float, trace: bool, require_platform: str,
+             t_process: float) -> dict:
+    # probes: alone, not streamed, prefix cache off; they are also the token-count check
+    probes = traffic.probe_requests(seed, PROBES, PROBE_PROMPT, PROBE_TOKENS)
+    t = time.monotonic()
+    answers = [_probe(server, p) for p in probes]
+    for p, a in zip(probes, answers):
+        if a.prompt_tokens != p.prompt_tokens:
+            raise BenchFailure(f"the generator counts {p.prompt_tokens} prompt tokens, the "
+                               f"server {a.prompt_tokens}: one character is not one token")
+    building = sum(e["seconds"] for e in server.control("/compiles")["events"])
+    log(f"[setup] {PROBES} probes answered in {time.monotonic() - t:.1f} s, {building:.1f} s of it "
+        f"building or loading programs; prompt and completion token counts agree with usage")
+
+    # the reference scores the answers on the host's cores while the warm-up,
+    # which is one Python thread tracing programs, goes on; it is over before
+    # the lead-in, so the window is not disturbed
+    reference = _Reference(cell, model, cache, probes, answers)
+    server.beside.append(reference)  # run_cell stops it if the run fails first
+
+    mix = cell.mix
+    rows = cell.flag("--parallel", 2)
+    t = time.monotonic()
+    loop_rows = rows if mix["loop"] == "open" else min(rows, int(mix["callers"]))
+    pool_tokens = cell.flag("--kv-pages", 0) * cell.flag("--kv-page-size", 64)
+    warm = client.waves(server.port, traffic.warmup_waves(mix, seed, loop_rows, pool_tokens),
+                        timeout=900.0)
+    bad = [r for r in warm if not r.ok]
+    if bad:
+        raise BenchFailure(f"{len(bad)} warm-up requests failed: {bad[0].status} {bad[0].error}")
+    built = server.control("/compiles")
+    slowest = sorted(built["events"], key=lambda e: e["seconds"], reverse=True)[:6]
+    log(f"[setup] warm-up: {len(warm)} requests in {time.monotonic() - t:.1f} s; "
+        f"{built['count']} programs built so far, {built['cache_hits']} from the compile cache, "
+        f"{sum(e['seconds'] for e in built['events']):.1f} s in the compiler; slowest "
+        f"{[(e['fun'], round(e['seconds'], 1)) for e in slowest]}")
+
+    log(f"[setup] waited {reference.wait():.1f} s after the warm-up for the reference")
+    lead_in = float(mix["lead_in_s"])
+    drain = float(mix["drain_limit_s"])
+    t0 = time.monotonic() + 0.2  # the load's clock; the window opens lead_in later
+    w0, w1 = t0 + lead_in, t0 + lead_in + seconds
+    marks: dict = {}
+
+    def at_edges() -> None:
+        """Scrapes at the window's edges, and the profiler in its middle."""
+        time.sleep(max(0.0, w0 - time.monotonic()))
+        marks["before"] = server.scrape()
+        if trace:
+            span = min(TRACE_SECONDS, seconds / 2)
+            time.sleep(max(0.0, w0 + (seconds - span) / 2 - time.monotonic()))
+            marks["trace_start"] = time.monotonic()
+            server.control("/profile", {"action": "start", "dir": trace_dir})
+            time.sleep(span)
+            server.control("/profile", {"action": "stop"})
+            marks["trace_stop"] = time.monotonic()
+        time.sleep(max(0.0, w1 - time.monotonic()))
+        marks["after"] = server.scrape()
+        marks["memory"] = server.control("/memory")
+
+    edge = threading.Thread(target=at_edges, daemon=True)
+    edge.start()
+    life0 = server.scrape()
+    if mix["loop"] == "open":
+        schedule = traffic.open_loop_schedule(mix, seed, seconds)
+        log(f"[load] open loop: {len(schedule)} requests at {mix['rate_rps']} req/s over "
+            f"{lead_in:.0f} s lead-in + {seconds:.0f} s window")
+        records = client.open_loop(server.port, schedule, t0, w1 + drain, timeout=drain + seconds + lead_in)
+        sent_prompt = {r.index: s.prompt_tokens for r, s in zip(records, schedule)}
+    else:
+        log(f"[load] closed loop: {mix['callers']} callers over {lead_in:.0f} s lead-in + "
+            f"{seconds:.0f} s window")
+        time.sleep(max(0.0, t0 - time.monotonic()))
+        records = client.closed_loop(server.port, traffic.closed_loop_requests(mix, seed),
+                                     int(mix["callers"]), w1, w1 + drain,
+                                     timeout=drain + seconds + lead_in)
+        sent_prompt = {r.index: r.prompt_tokens for r in records}
+    edge.join(drain + 60.0)
+    if edge.is_alive() or "after" not in marks:
+        raise BenchFailure("the window's second scrape never came")
+    setup_s = w0 - t_process
+
+    in_window = [r for r in records if w0 <= r.due < w1]
+    all_deltas = [t for r in records for t in r.deltas]
+    wanted = [m["name"] for m in cell.metrics_for("end_to_end")]
+    metrics, details = stats.end_to_end(in_window, w0, seconds, all_deltas, wanted)
+    metrics["setup_s"] = setup_s
+    log(f"[window] {json.dumps(details)}")
+    # every request's own numbers, so that a later reading can try another statistic
+    log("[window] per request, ms (ttft, tpot, stall): " + json.dumps(
+        [[round(1e3 * v, 1) if v is not None else None for v in (r.ttft, r.tpot, r.stall)]
+         for r in in_window if r.ok]))
+    log(f"[window] end to end: {json.dumps({k: round(v, 3) for k, v in metrics.items()})}")
+
+    # after the drain: the same greedy probe, alone again, must answer the same
+    life1 = server.scrape()
+    again = _probe(server, probes[0])
+    same = again.text == answers[0].text
+    log(f"[check] greedy probe repeated after the drain: {'identical' if same else 'DIFFERENT'}")
+    built = server.control("/compiles")
+    # time.monotonic is one clock for every process of a machine (CLOCK_MONOTONIC),
+    # so the child's instants compare with the parent's
+    in_win = [e for e in built["events"] if w0 <= e["at"] < w1]
+    log(f"[check] programs built inside the window: {len(in_win)} "
+        f"{[e['fun'] for e in in_win][:8]}")
+    paths = {json.dumps(lab, sort_keys=True): v for n, lab, v in life1 if n == "dllama_kernel_path_total"}
+    log(f"[check] dllama_kernel_path_total: {json.dumps(paths)}")
+    rc = server.stop()
+    log(f"[check] server exit code after SIGTERM: {rc}")
+
+    # token totals: dllama_tokens_generated_total counts whole decode chunks per
+    # row, the pipelined chunk dispatched ahead of a stream's end included, so
+    # it is an upper bound on what clients received, never an equal
+    got = sum(len(r.deltas) for r in records)
+    made = prom.delta(life0, life1, "dllama_tokens_generated_total") or 0.0
+    totals_ok = 0 < got <= made
+    log(f"[check] tokens: client received {got}, server counted {made:.0f} generated "
+        f"({'consistent' if totals_ok else 'INCONSISTENT'}; {sum(not r.ok for r in records)} "
+        f"of {len(records)} requests not completed)")
+
+    ref_ok, ref_note = reference.verdict()
+    log(f"[check] reference: {ref_note}")
+    correct = bool(device["platform"] == require_platform and device["count"] >= cell.chips
+                   and rc == 0 and same and totals_ok and ref_ok)
+
+    peak = max(marks["memory"]["peak_bytes"], default=0)
+    result = {
+        "correct": correct,
+        "attempted": details["attempted"],
+        "failed": details["failed"],
+        "metrics": {},
+        "device": {"platform": device["platform"], "kind": device["kind"],
+                   "count": device["count"], "memory_peak_bytes": peak},
+    }
+    if not trace:
+        for m in cell.metrics_for("end_to_end"):
+            if m["name"] not in metrics:
+                raise BenchFailure(f"the window gave no {m['name']}")
+            result["metrics"][m["name"]] = {"value": metrics[m["name"]], "unit": m["unit"]}
+        return result
+
+    facts = _trace_facts(cell, cache, trace_dir, device, records, sent_prompt, marks, w0, w1,
+                         len(in_win), peak)
+    # the window's latencies as the client saw them, for cells that record
+    # them without judging them
+    for quantity, ps in details["percentiles_ms"].items():
+        for p, value in ps.items():
+            facts[f"client.{quantity}_{p}_ms"] = value
+    ctx = readers.Context(marks["before"], marks["after"], facts)
+    for m in cell.metrics_for("per_layer"):
+        value, unit = readers.read_metric(os.path.join(cell.dir, "layer_metrics"), m["name"], ctx)
+        if value is None:
+            log(f"[trace] {m['name']}: nothing to read, left out")
+            continue
+        result["metrics"][m["name"]] = {"value": value, "unit": unit}
+    result["device"]["busy_s"] = facts["trace.busy_s"]
+    result["device"]["window_s"] = facts["trace.window_s"]
+    result["breakdown"] = {"device_ops": facts["trace.device_ops"],
+                           "idle_gaps": facts["trace.idle_gaps"]}
+    return result
+
+
+def judge_probes(rows: list[dict]) -> tuple[bool, str]:
+    """The verdict on the probes' positions. ``rows``: per answered position
+    the served token, the reference's best, the served token's deficit and,
+    from a sparse-expert model, the position's routing gap."""
+    answered = len(rows)
+    dense = all(r.get("router_gap") is None for r in rows)
+    rows = [r for r in rows if r.get("router_gap") is None or r["router_gap"] >= ROUTER_TIE]
+    ties = answered - len(rows)
+    if len(rows) < MIN_COMPARED:
+        return False, (f"only {len(rows)} positions could be compared ({ties} more are routing "
+                       f"near-ties), {MIN_COMPARED} are needed")
+    equal = sum(1 for r in rows if r["server"] == r["reference"])
+    misses = [r for r in rows if r["deficit"] > MISS_TOL]
+    worst = max(r["deficit"] for r in rows)
+    allowed = int(MAX_MISS_SHARE * len(rows))
+    ok = len(misses) <= allowed and (not dense or worst <= DENSE_HARD_TOL)
+    note = (f"{len(rows)} positions compared" + (f" ({ties} routing near-ties left out)" if ties else "")
+            + f": {equal} equal the reference's greedy token, "
+            f"{len(misses)} over {MISS_TOL:.0e} of max|logit| below its best ({allowed} allowed); "
+            f"worst {worst:.2e}" + (f" (a dense model: at most {DENSE_HARD_TOL:.0e})" if dense else ""))
+    return ok, note
+
+
+class _Reference:
+    """The plain reference over the probes the server answered, in a child on
+    the host's CPU (the chip is the server's), teacher-forced with the
+    server's own tokens."""
+
+    def __init__(self, cell: Cell, model: str, cache: str, probes: list, answers: list):
+        self.proc, self.skipped = None, 0
+        items = []
+        for p, a in zip(probes, answers):
+            ids = traffic.answer_ids(a.text or "", len(a.deltas))
+            self.skipped += len(a.deltas) - len(ids)
+            if ids:
+                items.append({"prompt": traffic.encode_chat(p.body["messages"]), "answer": ids})
+        if not items:
+            return
+        probes_path = os.path.join(cache, "probes.json")
+        self.out_path = os.path.join(cache, "reference.json")
+        with open(probes_path, "w") as f:
+            json.dump(items, f)
+        self._err = open(os.path.join(cache, "reference.log"), "w+")
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "benchmark.reference.probe_child", model, probes_path, self.out_path],
+            cwd=cell.root, env=dict(_child_env(cell.root, cache), JAX_PLATFORMS="cpu"),
+            stdout=self._err, stderr=subprocess.STDOUT)
+
+    def wait(self) -> float:
+        """Wait for the child; the seconds waited."""
+        t = time.monotonic()
+        if self.proc is not None and self.proc.poll() is None:
+            try:
+                self.proc.wait(600)
+            except subprocess.TimeoutExpired:
+                self.kill()
+        return time.monotonic() - t
+
+    def kill(self) -> None:
+        if self.proc is not None and self.proc.poll() is None:
+            self.proc.kill()
+            self.proc.wait()
+
+    def verdict(self) -> tuple[bool, str]:
+        if self.proc is None:
+            return False, "no probe answer could be read back as tokens"
+        self.wait()
+        if self.proc.returncode != 0:
+            self._err.seek(0)
+            return False, f"reference child exited with code {self.proc.returncode}: {self._err.read()[-2000:]}"
+        out = load_json(self.out_path)
+        rows = [dict(r, probe=i, position=j) for i, probe in enumerate(out["probes"])
+                for j, r in enumerate(probe)]
+        for r in rows:
+            if r["deficit"] > MISS_TOL:
+                tie = r["router_gap"] is not None and r["router_gap"] < ROUTER_TIE
+                log(f"[check] over the miss line{' (a routing near-tie, left out)' if tie else ''}: "
+                    f"{json.dumps(r)}")
+        margins = sorted(r["margin"] for r in rows)
+        ok, note = judge_probes(rows)
+        return ok, (f"{note}; {self.skipped} answered positions not read back as tokens; the "
+                    f"reference's top-1/top-2 margin: median {margins[len(margins) // 2]:.2e}; "
+                    f"{out['seconds']:.1f} s on the host beside the warm-up")
+
+
+def _trace_facts(cell: Cell, cache: str, trace_dir: str, device: dict, records: list,
+                 sent_prompt: dict, marks: dict, w0: float, w1: float,
+                 compiles_in_window: int, peak: int) -> dict:
+    """Named values for the per-layer readers: from the generator, the control
+    thread, the trace reduction, the bytes model and the table of peaks."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=cell.root)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.harness.trace_reduce", trace_dir, str(cell.chips)],
+        cwd=cell.root, env=env, capture_output=True, text=True, timeout=600)
+    if proc.returncode != 0:
+        raise BenchFailure(f"trace reduction failed: {proc.stderr[-2000:]}")
+    red = json.loads(proc.stdout.strip().splitlines()[-1])
+    with open(os.path.join(cache, "trace_inventory.json"), "w") as f:
+        json.dump({"inventory": red["inventory"], "modules": red.get("modules")}, f)
+    if "error" in red:
+        raise BenchFailure(f"trace: {red['error']}; planes {list(red['inventory'])}")
+    peaks = load_json(os.path.join(cell.dir, "peaks.json"))
+    if device["kind"] not in peaks:
+        raise BenchFailure(f"no published peaks for device kind {device['kind']!r}")
+    peak_bw = peaks[device["kind"]]["hbm_bytes_per_s"]
+    log(f"[trace] {red['window_s']:.2f} s traced, device busy {red['busy_s']:.2f} s; modules "
+        f"{json.dumps({k: [v['count'], round(v['seconds'], 3)] for k, v in red['modules'].items()})}")
+    facts = {
+        "gen.prompt_tokens_in_window": float(sum(sent_prompt[r.index] for r in records
+                                                 if w0 <= r.sent < w1)),
+        "control.compiles_in_window": float(compiles_in_window),
+        "control.peak_hbm_gb": peak / 1e9,
+        "trace.idle_share": red["idle_share"],
+        "trace.busy_s": red["busy_s"],
+        "trace.window_s": red["window_s"],
+        "trace.device_ops": red["device_ops"],
+        "trace.idle_gaps": red["idle_gaps"],
+        "trace.modules": red["modules"],
+        "peaks.hbm_bytes_per_s": peak_bw,
+    }
+    # the decode step's share of the memory roofline, over the traced span:
+    # rows and live context as the client saw them in that span
+    a, b = marks["trace_start"], marks["trace_stop"]
+    rows = positions = 0.0
+    for r in records:
+        if len(r.deltas) > 1:
+            share = max(0.0, min(b, r.deltas[-1]) - max(a, r.deltas[0])) / (b - a)
+            seen = sum(1 for t in r.deltas if t < (a + b) / 2)
+            rows += share
+            positions += share * (sent_prompt[r.index] + seen)
+    facts["gen.live_rows"] = rows
+    facts["gen.live_positions"] = positions
+    facts["server.decode_chunk"] = float(device["decode_chunk"])
+    facts["model.decode_step_bytes"] = bytes_model.decode_step_bytes(
+        cell.config, max(1.0, rows), positions)
+    log(f"[trace] decode step floor: {facts['model.decode_step_bytes'] / 1e9:.3f} GB at "
+        f"{rows:.1f} live rows and {positions:.0f} live positions")
+    return facts

@@ -1,0 +1,120 @@
+"""``BENCHMARK.json`` against the contract it is written to, the data files it
+names, and the shape of the result line."""
+
+import json
+import os
+import re
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def bench():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def load(*parts):
+    with open(os.path.join(REPO, *parts)) as f:
+        return json.load(f)
+
+
+def test_top_level_keys_and_sizes(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
+                          "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
+    assert isinstance(bench["run_seconds"], int) and 1 <= bench["run_seconds"] <= 51
+    assert 1 <= len(bench["paths"]) <= 16 and all(os.path.isdir(os.path.join(REPO, p)) for p in bench["paths"])
+    assert bench["command"][1].startswith(bench["paths"][0] + "/")
+    # a full check must fit: 2 + 14 runs a cell, run_seconds + 60 each, 180 s a cell to compile
+    assert (2 + 14 * 24) * (bench["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+
+
+def test_configs(bench):
+    names = [c["name"] for c in bench["configs"]]
+    assert len(set(names)) == len(names) and len({c["file"] for c in bench["configs"]}) == len(names)
+    used = {w["config"] for w in bench["workloads"]}
+    widths = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|head|experts_per_tok")
+    for c in bench["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and c["name"] in used
+        assert c["source"].startswith("https://") and len(c["source"]) <= 200
+        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+        data = load(c["file"])
+        assert data["name"] == c["name"] and data["source"] == c["source"]
+        assert data["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
+        assert set(data.get("reduced_from", {})) == set(c["reduced"])
+        assert not any(widths.search(k) for k in c["reduced"])
+        # the published widths of both models, which no cut may touch
+        assert (data["hidden_size"], data["intermediate_size"], data["num_attention_heads"],
+                data["num_key_value_heads"], data["vocab_size"]) == (4096, 14336, 32, 8, 32000)
+
+
+def test_workloads_and_their_files(bench):
+    names = [w["name"] for w in bench["workloads"]]
+    assert len(set(names)) == len(names) and 1 <= len(names) <= 24
+    assert len({(w["config"], w["traffic"]) for w in bench["workloads"]}) == len(names)
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) <= max(1, len(names) // 4)
+    for w in bench["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert all(NAME.match(w[k]) for k in ("name", "config", "traffic"))
+        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+        cell = load("benchmark", "workloads", f"{w['name']}.json")
+        assert {k: cell[k] for k in ("name", "config", "traffic", "chips")} == \
+            {k: w[k] for k in ("name", "config", "traffic", "chips")}
+        # the launch describes the deployment; the program's tunables keep their defaults
+        assert not {"--decode-chunk", "--prefill-chunk", "--kv-page-size", "--admission-queue"} & set(cell["flags"])
+        mix = load("benchmark", "traffic", f"{w['traffic']}.json")
+        assert mix["loop"] in ("open", "closed") and len(mix["why"]) > 40
+        assert ("rate_rps" in mix) == (mix["loop"] == "open")
+        assert ("callers" in mix) == (mix["loop"] == "closed")
+
+
+def test_metrics(bench):
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.1
+    every = bench["end_to_end"] + bench["per_layer"]
+    assert len({m["name"] for m in every}) == len(every)
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
+    layers = set()
+    for m in bench["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+        assert m["moves"] in e2e and m["source"] in SOURCES
+        assert set(m.get("workloads", cells)) <= cells
+        # one reader file per quantity, found by the name up to its first dot
+        data = load("benchmark", "layer_metrics", f"{m['name'].split('.')[0]}.json")
+        assert set(data) == {"unit", "source", "what", "reader"}
+        assert (data["unit"], data["source"]) == (m["unit"], m["source"])
+        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
+            assert m["unit"] == "%"
+        layers.add(m["layer"])
+    for m in every:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+    perf = open(os.path.join(REPO, "PERF.md")).read()
+    assert all(layer in perf for layer in layers)
+    readers = {f[:-5] for f in os.listdir(os.path.join(REPO, "benchmark", "layer_metrics"))}
+    assert readers == {m["name"].split(".")[0] for m in bench["per_layer"]}  # none unused
+
+
+def test_peaks_table_has_a_source_and_the_v5e(bench):
+    peaks = load("benchmark", "peaks.json")
+    assert "TPU v5e" in peaks["source"]
+    assert peaks["TPU v5 lite"]["hbm_bytes_per_s"] == 819e9
+    assert peaks["TPU v5 lite"]["bf16_flop_per_s"] == 197e12
+
+
+def test_files_under_paths_are_named_from_name_characters(bench):
+    ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
+    for p in bench["paths"]:
+        for base, dirs, files in os.walk(os.path.join(REPO, p)):
+            dirs[:] = [d for d in dirs if d not in (".cache", "__pycache__")]
+            for f in files:
+                assert ok.match(os.path.relpath(os.path.join(base, f), REPO))

@@ -1,0 +1,145 @@
+"""The traffic generator: a pure function of (mix, seed), the same work for
+every seed, token counts that match the program's tokenizer."""
+
+import json
+import os
+
+import pytest
+
+from benchmark.harness import traffic
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(REPO, "benchmark", "traffic")))
+
+
+def mix(name):
+    with open(os.path.join(REPO, "benchmark", "traffic", f"{name}.json")) as f:
+        return json.load(f)
+
+
+def requests_of(m, seed, seconds=20.0, n=40):
+    if m["loop"] == "open":
+        return traffic.open_loop_schedule(m, seed, seconds)
+    gen = traffic.closed_loop_requests(m, seed)
+    return [next(gen) for _ in range(n)]
+
+
+@pytest.fixture(scope="module")
+def tokenizer():
+    from distributed_llama_tpu.formats.synthetic import synthetic_tokenizer_data
+    from distributed_llama_tpu.tokenizer import ChatItem, ChatTemplate, Tokenizer, detect_chat_template
+
+    data = synthetic_tokenizer_data(vocab_size=32000)
+    tok = Tokenizer(data)
+    template = ChatTemplate(detect_chat_template(data.chat_template), data.chat_template, "</s>")
+
+    def encode(messages):
+        text = template.generate([ChatItem(m["role"], m["content"]) for m in messages],
+                                 append_generation_prompt=True)
+        return tok.encode(text, add_bos=True)
+
+    return encode
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_schedule_is_a_pure_function_of_mix_and_seed(name):
+    a, b = requests_of(mix(name), 2**31 + 5), requests_of(mix(name), 2**31 + 5)
+    assert [(r.due_s, r.body) for r in a] == [(r.due_s, r.body) for r in b]
+    c = requests_of(mix(name), 6)
+    assert [r.body for r in a] != [r.body for r in c]
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_token_counts_match_the_programs_tokenizer(name, tokenizer):
+    for r in requests_of(mix(name), 3)[:12]:
+        ids = tokenizer(r.body["messages"])
+        assert ids == traffic.encode_chat(r.body["messages"])
+        assert len(ids) == r.prompt_tokens == traffic.chat_tokens(r.body["messages"])
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_every_request_fits_the_context_and_never_needs_an_exact_length_compile(name):
+    m = mix(name)
+    for r in requests_of(m, 11, seconds=45.0, n=200):
+        assert r.prompt_tokens + r.max_tokens <= m["context_cap"] <= 2048 - 32
+        # engine/batch.py pads a prefill chunk to a power of two unless that
+        # passes seq_len: a prompt of at most 1792 tokens never does at 2048
+        assert r.prompt_tokens <= m["prompt_cap"] <= 1792
+        assert r.body["temperature"] == 0.0 and r.body["stream"] is True
+
+
+def test_every_seed_sends_the_same_work_at_the_same_instants():
+    m = mix("chat_shared")
+    runs = [traffic.open_loop_schedule(m, seed, 45.0) for seed in (1, 2, 3, 2**31 + 9)]
+    first = [r for r in runs[0] if r.due_s >= m["lead_in_s"]]
+    for run in runs[1:]:
+        win = [r for r in run if r.due_s >= m["lead_in_s"]]
+        assert [(r.due_s, r.prompt_tokens, r.max_tokens, r.turn) for r in win] == \
+            [(r.due_s, r.prompt_tokens, r.max_tokens, r.turn) for r in first]
+        # what the seed does change: the text
+        assert [r.body for r in win] != [r.body for r in first]
+
+
+def test_open_loop_rate_and_sharing_are_what_the_mix_says():
+    m = mix("chat_shared")
+    sched = traffic.open_loop_schedule(m, 5, 45.0)
+    rate = len(sched) / (m["lead_in_s"] + 45.0)
+    assert 0.85 * m["rate_rps"] <= rate <= 1.1 * m["rate_rps"]
+    systems = {r.body["messages"][0]["content"] for r in sched}
+    assert 2 <= len(systems) <= m["system_prompts"]["pool"]
+    later = [r for r in sched if r.turn > 0]
+    assert later and all(len(r.body["messages"]) == 2 + 2 * r.turn for r in later)
+    assert [r.due_s for r in sched] == sorted(r.due_s for r in sched)
+
+
+def test_closed_loop_blocks_hold_the_same_multiset_for_every_seed():
+    m = mix("batch_decode")
+    a = requests_of(m, 1, n=m["block"])
+    b = requests_of(m, 2, n=m["block"])
+    assert sorted(r.max_tokens for r in a) == sorted(r.max_tokens for r in b)
+    assert sorted(r.prompt_tokens for r in a) == sorted(r.prompt_tokens for r in b)
+    assert len({r.body["messages"][0]["content"] for r in a + b}) == 2 * m["block"]
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_warmup_touches_every_prefill_bucket_the_mix_can_reach(name):
+    m = mix(name)
+    waves = traffic.warmup_waves(dict(m, warm_pool_overflow=True), 1, 16, 384 * 64)
+    assert len(traffic.warmup_waves(dict(m, warm_pool_overflow=False), 1, 16, 384 * 64)) == 12
+    singles = [w[0].prompt_tokens for w in waves if len(w) == 1]
+
+    def buckets(n):
+        out = set()
+        while n > 0:
+            c = min(256, n)
+            out.add(max(8, 1 << (c - 1).bit_length()))
+            n -= c
+        return out
+
+    warmed = set().union(*(buckets(n) for n in singles))
+    needed = set().union(*(buckets(r.prompt_tokens) for r in requests_of(m, 4, n=64)))
+    assert needed <= warmed
+    assert sum(n for n in singles if n >= 1024) > 384 * 64  # the pool overflows
+    ramp = waves[-1]
+    assert len(ramp) == 16 and ramp[0].due_s == 0.0 and ramp[-1].due_s > ramp[0].due_s
+
+
+@pytest.mark.parametrize("text,count,want", [
+    ("<filler_300><filler_31999><filler_5>", 3, [300, 31999, 5]),
+    ("<filler_300><filler_301>", 3, []),  # a token left no text: where, nobody knows
+    ("<filler_300><filler_301>", 1, []),
+    ("<filler_300>x<filler_5>", 3, []),  # a letter is a piece or a byte
+    ("<filler_9><<filler_5>", 3, []),
+    ("hello", 1, []),
+    ("", 0, []),
+])
+def test_answer_ids_reads_back_only_what_is_certain(text, count, want):
+    assert traffic.answer_ids(text, count) == want
+
+
+def test_the_first_filler_follows_the_synthetic_vocabularys_pieces():
+    from distributed_llama_tpu.formats.synthetic import synthetic_tokenizer_data
+
+    vocab = synthetic_tokenizer_data(vocab_size=400).vocab
+    assert vocab[traffic.FIRST_FILLER_ID] == f"<filler_{traffic.FIRST_FILLER_ID}>".encode()
+    assert not vocab[traffic.FIRST_FILLER_ID - 1].startswith(b"<filler_")
