@@ -15,6 +15,7 @@ import os
 import shutil
 import signal
 import socket
+import statistics
 import subprocess
 import sys
 import threading
@@ -59,6 +60,9 @@ PROBES, PROBE_PROMPT, PROBE_TOKENS = 8, 64, 32  # 32: one decode chunk, whatever
 # 6 answers at a vocabulary of 32000); routing near-ties go too
 MIN_COMPARED = PROBES * PROBE_TOKENS // 4
 TRACE_SECONDS = 2.0  # 5 s of a 16-layer server's ops crashed the profiler at stop_trace (PR 22)
+# --trace 2: the lead-in of the traced phase, at most (the mix's own if shorter): long enough
+# for the rows to refill and, in a closed loop, for the callers to fall out of step again
+TRACE_LEAD_IN_S = 6.0
 
 
 class BenchFailure(RuntimeError):
@@ -197,9 +201,14 @@ def _probe(server: Server, req) -> stats.Record:
     return rec
 
 
-def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
+def run_cell(root: str, workload: str, seed: int, seconds: float, trace: int,
              require_platform: str, t_process: float) -> dict:
-    """Run the cell; return the result object (the last line of stdout)."""
+    """Run the cell; return the result object (the last line of stdout).
+    ``trace``: 0 measures; 1 measures with the profiler in the middle of the
+    window and gives the per-layer metrics; 2 measures as 0 does and then
+    traces a short second phase of the same traffic in the same process,
+    and gives both kinds of metric."""
+    trace = int(trace)
     cell = Cell(root, workload)
     try:
         from benchmark.harness import modelfile  # imports the package's writers
@@ -237,7 +246,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
 
 
 def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, trace_dir: str,
-             seed: int, seconds: float, trace: bool, require_platform: str,
+             seed: int, seconds: float, trace: int, require_platform: str,
              t_process: float) -> dict:
     # probes: alone, not streamed, prefix cache off; they are also the token-count check
     probes = traffic.probe_requests(seed, PROBES, PROBE_PROMPT, PROBE_TOKENS)
@@ -285,7 +294,7 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
         """Scrapes at the window's edges, and the profiler in its middle."""
         time.sleep(max(0.0, w0 - time.monotonic()))
         marks["before"] = server.scrape()
-        if trace:
+        if trace == 1:
             span = min(TRACE_SECONDS, seconds / 2)
             time.sleep(max(0.0, w0 + (seconds - span) / 2 - time.monotonic()))
             marks["trace_start"] = time.monotonic()
@@ -310,8 +319,8 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
         log(f"[load] closed loop: {mix['callers']} callers over {lead_in:.0f} s lead-in + "
             f"{seconds:.0f} s window")
         time.sleep(max(0.0, t0 - time.monotonic()))
-        records = client.closed_loop(server.port, traffic.closed_loop_requests(mix, seed),
-                                     int(mix["callers"]), w1, w1 + drain,
+        requests = traffic.closed_loop_requests(mix, seed)
+        records = client.closed_loop(server.port, requests, int(mix["callers"]), w1, w1 + drain,
                                      timeout=drain + seconds + lead_in)
         sent_prompt = {r.index: r.prompt_tokens for r in records}
     edge.join(drain + 60.0)
@@ -344,6 +353,12 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
         f"{[e['fun'] for e in in_win][:8]}")
     paths = {json.dumps(lab, sort_keys=True): v for n, lab, v in life1 if n == "dllama_kernel_path_total"}
     log(f"[check] dllama_kernel_path_total: {json.dumps(paths)}")
+    traced = None
+    if trace == 2:
+        # everything above is the --trace 0 run, to the instant; the server is still up
+        traced = _traced_phase(server, mix, seed, seconds,
+                               requests if mix["loop"] == "closed" else None, trace_dir)
+        marks["memory"] = server.control("/memory")  # the peak of the whole run
     rc = server.stop()
     log(f"[check] server exit code after SIGTERM: {rc}")
 
@@ -371,15 +386,21 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
         "device": {"platform": device["platform"], "kind": device["kind"],
                    "count": device["count"], "memory_peak_bytes": peak},
     }
-    if not trace:
+    if trace != 1:
         for m in cell.metrics_for("end_to_end"):
             if m["name"] not in metrics:
                 raise BenchFailure(f"the window gave no {m['name']}")
             result["metrics"][m["name"]] = {"value": metrics[m["name"]], "unit": m["unit"]}
-        return result
+        if trace == 0:
+            return result
 
+    # --trace 2: counters over the MEASURED window's scrapes (the whole untraced window, not
+    # one with the profiler in its middle); the device trace, and the rows and positions live
+    # under it, from the traced phase
     facts = _trace_facts(cell, cache, trace_dir, device, records, sent_prompt, marks, w0, w1,
-                         len(in_win), peak)
+                         len(in_win), peak, traced)
+    if traced is not None:
+        shutil.rmtree(trace_dir, ignore_errors=True)  # reduced: tens of megabytes
     # the window's latencies as the client saw them, for cells that record
     # them without judging them
     for quantity, ps in details["percentiles_ms"].items():
@@ -484,18 +505,120 @@ class _Reference:
                     f"{out['seconds']:.1f} s on the host beside the warm-up")
 
 
-def _trace_facts(cell: Cell, cache: str, trace_dir: str, device: dict, records: list,
-                 sent_prompt: dict, marks: dict, w0: float, w1: float,
-                 compiles_in_window: int, peak: int) -> dict:
-    """Named values for the per-layer readers: from the generator, the control
-    thread, the trace reduction, the bytes model and the table of peaks."""
+def _traced_phase(server: Server, mix: dict, seed: int, seconds: float, requests,
+                  trace_dir: str) -> dict:
+    """``--trace 2``, after the measured run: the same mix again for a short
+    lead-in plus ``TRACE_SECONDS`` traced through the program's own capture
+    control (``POST /debug/profile``), then a drain. Returns the phase's
+    records, the prompt tokens it sent and the traced span's edges, in the
+    form ``_trace_facts`` reads.
+
+    Closed loop: the same callers go on drawing ``requests``, the generator
+    the window drew from, so every prompt is new. Open loop: the part of the
+    one seeded schedule, made for a run this much longer, that is due after
+    the window's end, moved to this phase's clock: the same process at the
+    same rate, the same system prompts (already cached, as in a server that
+    has been up a while), new user text. It is not a replay of prompts the
+    window sent; what it cannot have is the earlier turns of its own
+    sessions in the cache."""
+    def capture(body: dict) -> dict:
+        status, raw = client.http_json(server.port, "POST", "/debug/profile", body, timeout=120.0)
+        if status != 200:
+            raise BenchFailure(f"capture {body['action']}: HTTP {status} {raw[:300]!r}")
+        return json.loads(raw)
+
+    # the first start of the profiler in a process costs seconds: paid here, into no number
+    scratch = trace_dir + ".first"
+    capture({"action": "start", "dir": scratch, "max_seconds": 30})
+    first = capture({"action": "stop"})
+    shutil.rmtree(scratch, ignore_errors=True)
+    log(f"[trace] profiler started and stopped once, trace thrown away "
+        f"({first['stop_trace_seconds']:.2f} s to stop)")
+
+    lead_in = min(float(mix["lead_in_s"]), TRACE_LEAD_IN_S)
+    drain = float(mix["drain_limit_s"])
+    t0 = time.monotonic() + 0.2
+    t_trace = t0 + lead_in
+    t_stop = t_trace + TRACE_SECONDS + 1.0  # load goes on until the capture has stopped
+    out: dict = {}
+
+    def load() -> None:
+        if requests is None:
+            horizon = float(mix["lead_in_s"]) + seconds  # where the window's schedule ended
+            longer = traffic.open_loop_schedule(mix, seed, seconds + (t_stop - t0))
+            schedule = [r for r in longer if r.due_s >= horizon]
+            for i, r in enumerate(schedule):
+                r.due_s -= horizon
+                r.index = i
+            out["records"] = client.open_loop(server.port, schedule, t0, t_stop + drain,
+                                              timeout=drain + (t_stop - t0))
+            out["sent_prompt"] = {r.index: s.prompt_tokens
+                                  for r, s in zip(out["records"], schedule)}
+        else:
+            time.sleep(max(0.0, t0 - time.monotonic()))
+            out["records"] = client.closed_loop(server.port, requests, int(mix["callers"]),
+                                                t_stop, t_stop + drain,
+                                                timeout=drain + (t_stop - t0))
+            out["sent_prompt"] = {r.index: r.prompt_tokens for r in out["records"]}
+
+    loader = threading.Thread(target=load, daemon=True)
+    loader.start()
+    time.sleep(max(0.0, t_trace - time.monotonic()))
+    out["trace_start"] = time.monotonic()
+    capture({"action": "start", "dir": trace_dir, "max_seconds": TRACE_SECONDS + 5.0})
+    time.sleep(TRACE_SECONDS)
+    out["trace_stop"] = time.monotonic()  # the profiler collects until it is asked to stop ...
+    stopped = capture({"action": "stop"})
+    t_stopped = time.monotonic()  # ... and takes seconds more to hand the trace over
+    loader.join(drain + (t_stop - t0) + 60.0)
+    if loader.is_alive() or "records" not in out:
+        raise BenchFailure("the traced phase's load never ended")
+    done = sum(r.ok for r in out["records"])
+    log(f"[trace] second phase: {lead_in:.0f} s lead-in + {TRACE_SECONDS:.0f} s traced by the "
+        f"program's capture control ({stopped['seconds']:.2f} s captured, {stopped['spans']} host "
+        f"spans, {stopped['stop_trace_seconds']:.2f} s to stop); {len(out['records'])} requests "
+        f"sent, {done} completed")
+    # what the capture costs while it runs (recorded, judged nowhere): the phase's own traffic
+    # before the capture against the traffic that met it, its seconds of stopping included
+    a, b = out["trace_start"], min(t_stop, t_stopped)  # b: load was offered until then
+
+    def rate(lo: float, hi: float) -> float:
+        return sum(lo <= t < hi for r in out["records"] for t in r.deltas) / (hi - lo)
+
+    def tpot(records: list) -> str:
+        ms = [1e3 * (r.deltas[-1] - r.deltas[0]) / (len(r.deltas) - 1) for r in records
+              if len(r.deltas) > 1]
+        return f"{statistics.median(ms):.3f} ms over {len(ms)} requests" if ms else "no request"
+
+    streamed = [r for r in out["records"] if r.deltas]
+    log(f"[trace] before the capture: {rate(t0 + lead_in / 2, a):.1f} tokens/s received in the "
+        f"lead-in's second half, tpot p50 {tpot([r for r in streamed if r.deltas[-1] < a])} that "
+        f"ended before it; under it: {rate(a, b):.1f} tokens/s in its first {b - a:.1f} s, tpot p50 "
+        f"{tpot([r for r in streamed if r.deltas[-1] >= a and r.deltas[0] < t_stopped])} that met "
+        f"it or the {t_stopped - out['trace_stop']:.1f} s of its stopping")
+    return out
+
+
+def _reduce_trace(cell: Cell, trace_dir: str) -> dict:
+    """The trace reduction, in a child that may import JAX (this process never does)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=cell.root)
     proc = subprocess.run(
         [sys.executable, "-m", "benchmark.harness.trace_reduce", trace_dir, str(cell.chips)],
         cwd=cell.root, env=env, capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
         raise BenchFailure(f"trace reduction failed: {proc.stderr[-2000:]}")
-    red = json.loads(proc.stdout.strip().splitlines()[-1])
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def _trace_facts(cell: Cell, cache: str, trace_dir: str, device: dict, records: list,
+                 sent_prompt: dict, marks: dict, w0: float, w1: float,
+                 compiles_in_window: int, peak: int, traced: dict | None = None) -> dict:
+    """Named values for the per-layer readers: from the generator, the control
+    thread, the trace reduction, the bytes model and the table of peaks.
+    ``traced`` (``--trace 2``): the second phase, whose records and span the
+    facts about the traced span come from; the window's facts stay the
+    window's."""
+    red = _reduce_trace(cell, trace_dir)
     with open(os.path.join(cache, "trace_inventory.json"), "w") as f:
         json.dump({"inventory": red["inventory"], "modules": red.get("modules")}, f)
     if "error" in red:
@@ -521,14 +644,15 @@ def _trace_facts(cell: Cell, cache: str, trace_dir: str, device: dict, records: 
     }
     # the decode step's share of the memory roofline, over the traced span:
     # rows and live context as the client saw them in that span
-    a, b = marks["trace_start"], marks["trace_stop"]
+    under = traced or {"records": records, "sent_prompt": sent_prompt, **marks}
+    a, b = under["trace_start"], under["trace_stop"]
     rows = positions = 0.0
-    for r in records:
+    for r in under["records"]:
         if len(r.deltas) > 1:
             share = max(0.0, min(b, r.deltas[-1]) - max(a, r.deltas[0])) / (b - a)
             seen = sum(1 for t in r.deltas if t < (a + b) / 2)
             rows += share
-            positions += share * (sent_prompt[r.index] + seen)
+            positions += share * (under["sent_prompt"][r.index] + seen)
     facts["gen.live_rows"] = rows
     facts["gen.live_positions"] = positions
     facts["server.decode_chunk"] = float(device["decode_chunk"])

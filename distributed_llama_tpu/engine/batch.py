@@ -202,6 +202,10 @@ class BatchStream:
         engine._streams.append(self)
         engine._tel.active_streams.set(len(engine._streams))
         self._queue: collections.deque[int] = collections.deque()
+        # tokens queued since the join: at _leave, delivered - len(queue) is
+        # what the stream consumed (the row-step ledger, counted per leave,
+        # never per pop)
+        self._delivered = 0
         self._joined = False
         self._epoch = 0  # bumped per join/leave: stale fetches can't deliver
         self._first = None  # device scalar (or host int) feeding the next chunk
@@ -497,6 +501,12 @@ class BatchStream:
             self._spec_on = bool(spec_draft and spec_draft > 0)
             first_token = prev  # host int: the next verify window's feed[0]
         sched._join(self, first_token, temperature, topp, seed, topk)
+        # the consumer's side of the pump on the profiler's timeline, one
+        # span per refill of this row's queue (a span that began before a
+        # capture did is in no trace): a device gap under no scheduler span
+        # but under this one is the caller handing its tokens on
+        # (detokenize, SSE write) before it asks for more
+        handing_on = None
         try:
             if fused_first and not spec_mode:
                 # dispatch chunk 1 before the fused fetch so the scalar
@@ -511,11 +521,19 @@ class BatchStream:
                 fed = consumed - 1 if fused_first else consumed
                 if start_pos + fed >= stop:
                     break
+                if handing_on is not None and not self._queue:
+                    handing_on.__exit__(None, None, None)  # dry: off to the pump
+                    handing_on = None
                 tok = sched.next_token(self)
+                if handing_on is None:
+                    handing_on = engine._tel.span("decode_stream", batch_row=self.row)
+                    handing_on.__enter__()
                 consumed += 1
                 keep = on_token(prev, tok)
                 prev = tok
         finally:
+            if handing_on is not None:
+                handing_on.__exit__(None, None, None)
             sched._leave(self)
             fed = max(consumed - 1, 0) if fused_first else consumed
             self.rollback(min(start_pos + fed, self.pos))
@@ -733,6 +751,11 @@ class BatchScheduler:
         # (finite, wrong, invisible to the vocab/finite validation — the
         # class only the canary's golden comparison can see)
         self._sdc_logits_pending = 0
+        # a chunk whose dispatch→delivery takes longer than this leaves a
+        # `slow_chunk` flight event naming the phase that took it (a chunk
+        # is 0.3–0.6 s in every benchmark cell; PERF.md PR 22's 13.7 s
+        # freeze of all 16 streams then says host or device)
+        self.slow_chunk_s = 2.0
         self._lost = False
         self.lost_cause: str | None = None
         self.lost_victims = 0
@@ -756,8 +779,9 @@ class BatchScheduler:
         )
         self._streams: list[BatchStream] = []
         self._cond = lockcheck.make_condition("BatchScheduler._cond")
-        # one dispatched-but-unfetched chunk at a time: (tokens_dev, epoch
-        # snapshot, bucket, active count, stopwatch)
+        # one dispatched-but-unfetched chunk at a time: (mode, tokens_dev,
+        # epoch snapshot, bucket, active count, stopwatch, spec draft lens,
+        # build-start and dispatched instants)
         self._pending = None
         self._fetching = False
         # fetch generation: bumped when a thread takes the pending chunk; the
@@ -834,6 +858,7 @@ class BatchScheduler:
         if self._pending is not None:
             # the speculative chunk dies with the replica: nobody will
             # fetch it, so its depth hold releases here
+            self._count_lost_chunk(self._pending)
             self._pending = None
             with self.engine._depth_lock:
                 self.engine._pipeline_depth -= 1
@@ -879,6 +904,7 @@ class BatchScheduler:
                     # leaving it would make the LAST _leave's idle-drain
                     # fetch it SYNCHRONOUSLY on a request thread — blocking
                     # that client's error response behind the hang
+                    self._count_lost_chunk(self._pending)
                     self._pending = None
                     released += 1
                 with self.engine._depth_lock:
@@ -1029,57 +1055,66 @@ class BatchScheduler:
             padded = np.zeros(bucket, dtype=np.int32)
             padded[:c] = tokens[off : off + c]
             tr = stream.trace
-            t0 = time.perf_counter() if tr is not None else 0.0
+            t0 = time.monotonic() if tr is not None else 0.0
             with self._cond:
-                try:
-                    # whole-replica crash site (ISSUE 9): prefill chunk
-                    # dispatches are round-trips too — a crash mid-prompt
-                    # must fail over exactly like one mid-decode
-                    self._faults.fire("replica.crash", row=self.replica_id)
-                except Exception as e:
-                    self._mark_lost_locked(f"injected crash at prefill: {e}")
-                if self._lost:
-                    err = stream._fetch_error or faults.ReplicaLost(
-                        f"replica {self.replica_id} lost: {self.lost_cause}"
-                    )
-                    stream._fetch_error = None
-                    raise err
-                if self._pool is not None:
-                    # pool-enabled scheduler: every prefill runs the paged
-                    # program — an unaliased row dispatches with matched 0
-                    # (pure slab reads, byte-identical to the plain one),
-                    # so one compiled program serves hits and misses
-                    table, matched = self._alias_row_arrays_locked(stream)
-                    if engine._tp_engine is None:
-                        logits, self._slab = _slab_prefill_single_paged(
-                            engine.cfg, engine.params, jnp.asarray(padded),
-                            self._slab, self._pool, jnp.int32(stream.row),
-                            jnp.int32(stream.pos), jnp.int32(c), table, matched,
+                # decode chunks already on the device's queue: this prompt
+                # piece runs behind them (the decode chunk is the
+                # scheduler's clock)
+                ahead = (self._pending is not None) + self._fetching
+                engine._tel.prefill_chunks_ahead.observe(ahead)
+                with engine._tel.span(
+                    "prefill_chunk_dispatch", tokens=c, row=stream.row,
+                    chunks_ahead=ahead,
+                ):
+                    try:
+                        # whole-replica crash site (ISSUE 9): prefill chunk
+                        # dispatches are round-trips too — a crash mid-prompt
+                        # must fail over exactly like one mid-decode
+                        self._faults.fire("replica.crash", row=self.replica_id)
+                    except Exception as e:
+                        self._mark_lost_locked(f"injected crash at prefill: {e}")
+                    if self._lost:
+                        err = stream._fetch_error or faults.ReplicaLost(
+                            f"replica {self.replica_id} lost: {self.lost_cause}"
+                        )
+                        stream._fetch_error = None
+                        raise err
+                    if self._pool is not None:
+                        # pool-enabled scheduler: every prefill runs the paged
+                        # program — an unaliased row dispatches with matched 0
+                        # (pure slab reads, byte-identical to the plain one),
+                        # so one compiled program serves hits and misses
+                        table, matched = self._alias_row_arrays_locked(stream)
+                        if engine._tp_engine is None:
+                            logits, self._slab = _slab_prefill_single_paged(
+                                engine.cfg, engine.params, jnp.asarray(padded),
+                                self._slab, self._pool, jnp.int32(stream.row),
+                                jnp.int32(stream.pos), jnp.int32(c), table, matched,
+                            )
+                        else:
+                            logits, self._slab = engine._tp_engine.slab_forward_paged(
+                                engine.params, jnp.asarray(padded), self._slab,
+                                self._pool, stream.row, stream.pos, c, table,
+                                matched,
+                            )
+                    elif engine._tp_engine is None:
+                        logits, self._slab = _slab_prefill_single(
+                            engine.cfg, engine.params, jnp.asarray(padded), self._slab,
+                            jnp.int32(stream.row), jnp.int32(stream.pos), jnp.int32(c),
                         )
                     else:
-                        logits, self._slab = engine._tp_engine.slab_forward_paged(
+                        logits, self._slab = engine._tp_engine.slab_forward(
                             engine.params, jnp.asarray(padded), self._slab,
-                            self._pool, stream.row, stream.pos, c, table,
-                            matched,
+                            stream.row, stream.pos, c,
                         )
-                elif engine._tp_engine is None:
-                    logits, self._slab = _slab_prefill_single(
-                        engine.cfg, engine.params, jnp.asarray(padded), self._slab,
-                        jnp.int32(stream.row), jnp.int32(stream.pos), jnp.int32(c),
-                    )
-                else:
-                    logits, self._slab = engine._tp_engine.slab_forward(
-                        engine.params, jnp.asarray(padded), self._slab,
-                        stream.row, stream.pos, c,
-                    )
-                stream.pos += c
+                    stream.pos += c
             off += c
             if tr is not None:
                 # one child span per dispatched prompt chunk: the trace
                 # shows exactly how a long prompt interleaved with other
                 # rows' decode between these boundaries (ISSUE 16)
                 tr.add_span(
-                    "prefill_chunk", t0, time.perf_counter() - t0,
+                    "prefill_chunk", t0, time.monotonic() - t0,
                     tokens=c, off=off - c, of=n, row=stream.row,
                 )
         return logits, c - 1
@@ -1170,7 +1205,7 @@ class BatchScheduler:
         reloadable chain."""
         prefix = self._prefix
         tr = stream.trace
-        t0 = time.perf_counter() if tr is not None else 0.0
+        t0 = time.monotonic() if tr is not None else 0.0
         reloaded = 0
         with self._cond:
             # unwind any stale alias left by a caller that skipped reset
@@ -1185,7 +1220,7 @@ class BatchScheduler:
                 # how much prompt the match skipped, and how many spilled
                 # pages had to re-upload to get there (ISSUE 16)
                 tr.add_span(
-                    "prefix_match", t0, time.perf_counter() - t0,
+                    "prefix_match", t0, time.monotonic() - t0,
                     matched_tokens=len(chain) * prefix.page,
                     pages=len(chain), reloaded_pages=reloaded,
                 )
@@ -1429,6 +1464,7 @@ class BatchScheduler:
             stream._topk = int(topk)
             stream._seed32 = prng.fold_seed(seed)
             stream._queue.clear()
+            stream._delivered = 0
             stream._epoch += 1
             stream._joined = True
             stream._chunk_fps = []
@@ -1536,6 +1572,14 @@ class BatchScheduler:
         with self._cond:
             if not stream._joined and not stream._queue:
                 return
+            tel = self.engine._tel
+            if tel.enabled and stream._delivered:
+                # the ledger's last two fates, once per leave: what was
+                # queued and never popped is unread, the rest was consumed
+                unread = len(stream._queue)
+                tel.row_steps_unread.inc(unread)
+                tel.row_steps_consumed.inc(stream._delivered - unread)
+            stream._delivered = 0
             stream._joined = False
             stream._queue.clear()
             stream._epoch += 1
@@ -1623,7 +1667,8 @@ class BatchScheduler:
                     gen = self._begin_fetch_locked()
                 else:
                     # another thread is mid-fetch: wait for its notify
-                    self._cond.wait(timeout=0.1)
+                    with self.engine._tel.span("sched_wait", row=stream.row):
+                        self._cond.wait(timeout=0.1)
                     continue
             self._fetch(pend, gen)
 
@@ -1697,6 +1742,16 @@ class BatchScheduler:
             return None
         return result
 
+    def _count_lost_chunk(self, pend) -> None:
+        """Close the ledger on a dispatched chunk nobody will deliver (the
+        watchdog or a replica loss dropped it, or its fetch generation was
+        retired): its joined rows' steps end as ``quarantined`` — every one
+        of those rows was handed a typed failure."""
+        tel = self.engine._tel
+        if tel.enabled:
+            steps = self.chunk if pend[0] == "chunk" else 1
+            tel.row_steps_quarantined.inc(pend[4] * steps)
+
     def _fire_sdc_locked(self) -> None:
         """The ``engine.sdc`` chaos site (ISSUE 10), fired per batched
         dispatch with ``row=`` selecting the REPLICA id. A ``kind=corrupt``
@@ -1746,12 +1801,17 @@ class BatchScheduler:
             max(max(s.row for s in joined) + 1, self._bucket_floor), self.b_max
         )
         rows = self._streams[:bucket]
-        live, pos, active, temps, topps, topks, seeds, tables, matched = (
-            self._row_dispatch_arrays_locked(rows)
-        )
-        first = jnp.stack(
-            [jnp.asarray(s._first if ok else 0, jnp.int32) for s, ok in zip(rows, live)]
-        )
+        t_build = time.monotonic()
+        with engine._tel.span("sched_build", bucket=bucket, active=len(joined)):
+            live, pos, active, temps, topps, topks, seeds, tables, matched = (
+                self._row_dispatch_arrays_locked(rows)
+            )
+            first = jnp.stack(
+                [
+                    jnp.asarray(s._first if ok else 0, jnp.int32)
+                    for s, ok in zip(rows, live)
+                ]
+            )
         sw = Stopwatch()
 
         def dispatch():
@@ -1803,18 +1863,28 @@ class BatchScheduler:
         # per-row fingerprint/finite rows (engine/integrity.py) — with the
         # stateless counter PRNG those int32 rows are the ONLY bytes the
         # chunk ever sends host-ward (no advanced keys return)
-        for s in joined:
-            # the next chunk seeds from this chunk's last token, which stays
-            # device-resident (no fetch on the critical path); its coins
-            # re-key from (seed, position) — nothing else carries over
-            s._first = out[self.chunk - 1, s.row]
-            s.pos += self.chunk
-        if engine._tel.enabled:
-            engine._tel.batch_occupancy.set(len(joined) / bucket)
+        with engine._tel.span("sched_post_dispatch", active=len(joined)):
+            for s in joined:
+                # the next chunk seeds from this chunk's last token, which
+                # stays device-resident (no fetch on the critical path); its
+                # coins re-key from (seed, position) — nothing else carries
+                # over
+                s._first = out[self.chunk - 1, s.row]
+                s.pos += self.chunk
+        self._note_dispatched(bucket, len(joined), self.chunk)
         self._pending = (
             "chunk", out, [(s, s._epoch) for s in joined], bucket,
-            len(joined), sw, None,
+            len(joined), sw, None, t_build, time.monotonic(),
         )
+
+    def _note_dispatched(self, bucket: int, n_active: int, steps: int) -> None:
+        """Per dispatched chunk: its joined and bucket rows, and the
+        ledger's ``masked`` fate — the bucket rows that ran inactive."""
+        tel = self.engine._tel
+        if tel.enabled:
+            tel.chunk_rows_active.observe(n_active)
+            tel.chunk_rows_bucket.observe(bucket)
+            tel.row_steps_masked.inc((bucket - n_active) * steps)
 
     def _dispatch_spec_locked(self) -> None:
         """Build and dispatch one batched speculative VERIFY step (cond
@@ -1850,9 +1920,11 @@ class BatchScheduler:
         S = engine.cfg.seq_len
         feed = np.zeros((bucket, T), np.int32)
         lens = np.zeros(bucket, np.int32)
-        live, pos, active, temps, topps, topks, seeds, tables, matched = (
-            self._row_dispatch_arrays_locked(rows)
-        )
+        t_build = time.monotonic()
+        with engine._tel.span("sched_build", bucket=bucket, active=len(joined)):
+            live, pos, active, temps, topps, topks, seeds, tables, matched = (
+                self._row_dispatch_arrays_locked(rows)
+            )
         for s, ok in zip(rows, live):
             if not ok:
                 continue
@@ -1904,13 +1976,12 @@ class BatchScheduler:
             return
         # pos/_first wait for the fetch (the advance is variable and
         # data-dependent); sampler coins re-key from (seed, position)
-        tel = engine._tel
-        if tel.enabled:
-            tel.batch_occupancy.set(len(joined) / bucket)
-            tel.spec_draft_tokens.inc(int(lens.sum()))
+        engine._tel.spec_draft_tokens.inc(int(lens.sum()))
+        # a verify step is one weight read: a masked row is 1 row-step
+        self._note_dispatched(bucket, len(joined), 1)
         self._pending = (
             "spec", out, [(s, s._epoch) for s in joined], bucket, len(joined),
-            sw, lens.copy(),
+            sw, lens.copy(), t_build, time.monotonic(),
         )
 
     def _fetch(self, pend, gen: int) -> None:
@@ -1925,11 +1996,15 @@ class BatchScheduler:
         generation check keeps a watchdog-killed fetch from delivering at
         all."""
         engine = self.engine
-        mode, tokens_dev, snapshot, bucket, n_active, sw, spec_lens = pend
+        (mode, tokens_dev, snapshot, bucket, n_active, sw, spec_lens, t_build,
+         t_dispatched) = pend
         toks = None
         error: Exception | None = None
+        t_fetch = time.monotonic()  # since t_dispatched: queued behind a fetch
+        waited = 0.0  # seconds the last attempt blocked on the device
 
         def attempt_once():
+            nonlocal waited
             self._faults.fire("batch.fetch")
             # replica chaos (ISSUE 9): `slow` (kind=delay) stretches this
             # round-trip past the pool's suspect threshold, `hang`
@@ -1942,7 +2017,10 @@ class BatchScheduler:
             except Exception:
                 pass  # optional acceleration; np.asarray is the contract
             with engine._tel.span("batch_decode_fetch", bucket=bucket):
-                return np.asarray(tokens_dev)  # [chunk, bucket]
+                t = time.monotonic()
+                out = np.asarray(tokens_dev)  # [chunk, bucket]
+                waited = time.monotonic() - t
+                return out
 
         try:
             # Exception only (retry_call's contract): a KeyboardInterrupt/
@@ -1984,6 +2062,7 @@ class BatchScheduler:
             # rows already hold StallTimeout errors, the depth hold was
             # released on our behalf, and a newer fetch may be in flight —
             # deliver nothing
+            self._count_lost_chunk(pend)
             with self._cond:
                 self._cond.notify_all()
             return
@@ -1993,10 +2072,48 @@ class BatchScheduler:
             # machine turns the replica SUSPECT past its threshold and
             # back HEALTHY on a fast round-trip (server/replicas.py)
             hook("roundtrip", sw.elapsed_s())
-        if mode == "spec":
-            self._deliver_spec(toks, snapshot, sw, spec_lens, error)
-            self._drain_if_idle()
-            return
+        t_fetched = time.monotonic()
+        tel = engine._tel
+        with tel.span("sched_deliver", bucket=bucket, active=n_active):
+            if mode == "spec":
+                self._deliver_spec(toks, snapshot, sw, spec_lens, error)
+            else:
+                self._deliver_chunk(toks, snapshot, bucket, n_active, sw, error)
+        t_end = time.monotonic()
+        if tel.enabled:
+            tel.chunk_fetch_wait.observe(waited)
+            tel.chunk_host.observe((t_dispatched - t_build) + (t_end - t_fetched))
+        if t_end - t_build > self.slow_chunk_s:
+            # rare by construction (a chunk is a fraction of a second): say
+            # where this one's time went — on the host (building and
+            # dispatching it, waiting for a thread to fetch it, delivering
+            # it) or in the fetch, blocked on the device
+            phases = {
+                "dispatch": t_dispatched - t_build, "queued": t_fetch - t_dispatched,
+                "fetch": t_fetched - t_fetch, "deliver": t_end - t_fetched,
+            }
+            phase = max(phases, key=phases.get)
+            flight.record(
+                self.replica_id, "slow_chunk", phase=phase,
+                where="device" if phase == "fetch" else "host",
+                seconds=round(t_end - t_build, 6), mode=mode, bucket=bucket,
+                active=n_active,
+                **{f"{k}_s": round(v, 6) for k, v in phases.items()},
+            )
+        # a chunk kicked WHILE this fetch was in flight may already be
+        # orphaned (its kicker stopped at the fused first token and its
+        # _leave-time drain skipped because _fetching was still true):
+        # re-check the idle-drain condition now that the fetch is done —
+        # the one-pending-slot invariant bounds the recursion.
+        self._drain_if_idle()
+
+    def _deliver_chunk(self, toks, snapshot, bucket, n_active, sw, error) -> None:
+        """Deliver one fetched plain decode chunk: validate each snapshot
+        row's column, queue it to the row's stream if the stream is still
+        the one that was joined at dispatch, and close the row-step ledger
+        on the rest. Runs with fetch ownership already claimed
+        (``_fetch``)."""
+        engine = self.engine
         per_token_ms = sw.elapsed_ms() / self.chunk
         # the I/T split may trigger a transfer re-measurement (a device
         # round trip under TP) — run it BEFORE taking the scheduler
@@ -2042,7 +2159,7 @@ class BatchScheduler:
                 col = toks[:, s.row]
                 if not ((col >= 0) & (col < vocab)).all():
                     bad_rows.add(s.row)
-        delivered = 0
+        delivered = orphaned = quarantined = 0
         with self._cond:
             # phase 2: deliver and release fetch ownership in ONE block, so
             # the pending chunk N+1 can only be taken (and its tokens
@@ -2050,6 +2167,9 @@ class BatchScheduler:
             self._fetching = False
             for s, epoch in snapshot:
                 if not (s._joined and s._epoch == epoch):
+                    # the row left (or its slot has a new occupant) while
+                    # this chunk, dispatched ahead, was on the device
+                    orphaned += 1
                     continue
                 if toks is None or s.row in bad_rows or s.row in nonfinite_rows:
                     # the row's tokens are lost/corrupt and its position
@@ -2081,8 +2201,10 @@ class BatchScheduler:
                         self.replica_id, "rows_quarantined", rows=[s.row],
                         where="fetch", error=type(err).__name__,
                     )
+                    quarantined += 1
                     continue
                 s._queue.extend(int(t) for t in toks[:, s.row])
+                s._delivered += self.chunk
                 s._chunk_fps.append(int(fps[s.row]))
                 s.stats.extend([entry] * self.chunk)
                 delivered += 1
@@ -2095,21 +2217,14 @@ class BatchScheduler:
                         row=s.row, chunk=self.chunk, bucket=bucket,
                         co_batched=n_active,
                     )
-                if tel.enabled:
-                    tel.kv_occupancy.set(
-                        min(s.pos / engine.cfg.seq_len, 1.0)
-                    )
             self._cond.notify_all()
-        if tel.enabled and delivered:
-            tel.tokens_generated.inc(self.chunk * delivered)
-            tel.device_sampled_tokens.inc(self.chunk * delivered)
-            tel.decode_latency.observe(per_token_ms / 1000.0)
-        # a chunk kicked WHILE this fetch was in flight may already be
-        # orphaned (its kicker stopped at the fused first token and its
-        # _leave-time drain skipped because _fetching was still true):
-        # re-check the idle-drain condition now that the fetch is done —
-        # the one-pending-slot invariant bounds the recursion.
-        self._drain_if_idle()
+        if tel.enabled:
+            tel.row_steps_orphaned.inc(self.chunk * orphaned)
+            tel.row_steps_quarantined.inc(self.chunk * quarantined)
+            if delivered:
+                tel.tokens_generated.inc(self.chunk * delivered)
+                tel.device_sampled_tokens.inc(self.chunk * delivered)
+                tel.decode_latency.observe(per_token_ms / 1000.0)
 
     def _deliver_spec(self, toks, snapshot, sw, lens, error) -> None:
         """Deliver one fetched batched VERIFY step: row ``b``'s column is
@@ -2154,10 +2269,14 @@ class BatchScheduler:
                 entries[s.row] = engine._split_stats(step_ms, n_tokens=n_emit)
         delivered_rows = 0
         delivered_tokens = 0
+        # the ledger in verify steps: a row that emitted counts its variable
+        # advance (on delivery, or as orphaned), any other row 1 step
+        orphaned = quarantined = 0
         with self._cond:
             self._fetching = False
             for s, epoch in snapshot:
                 if not (s._joined and s._epoch == epoch):
+                    orphaned += len(emits.get(s.row, ())) or 1
                     continue
                 if toks is None or s.row not in emits:
                     err = faults.RowQuarantined(
@@ -2176,6 +2295,7 @@ class BatchScheduler:
                         self.replica_id, "rows_quarantined", rows=[s.row],
                         where="spec_verify", error=type(err).__name__,
                     )
+                    quarantined += 1
                     continue
                 col = emits[s.row]
                 n_emit = len(col)
@@ -2183,6 +2303,7 @@ class BatchScheduler:
                 s._first = col[-1]  # host int: the next window's feed[0]
                 s._history.extend(col)
                 s._queue.extend(col)
+                s._delivered += n_emit
                 s.stats.append(entries[s.row])
                 delivered_rows += 1
                 delivered_tokens += n_emit
@@ -2198,12 +2319,14 @@ class BatchScheduler:
                         ),
                     )
                 if tel.enabled:
-                    tel.kv_occupancy.set(min(s.pos / engine.cfg.seq_len, 1.0))
                     tel.spec_accepted_tokens.inc(n_emit - 1)
                     if int(lens[s.row]) > 0:
                         tel.spec_acceptance.observe((n_emit - 1) / int(lens[s.row]))
                     tel.spec_step_advance.observe(n_emit)
             self._cond.notify_all()
+        if tel.enabled:
+            tel.row_steps_orphaned.inc(orphaned)
+            tel.row_steps_quarantined.inc(quarantined)
         if tel.enabled and delivered_tokens:
             tel.tokens_generated.inc(delivered_tokens)
             tel.device_sampled_tokens.inc(delivered_tokens)

@@ -68,8 +68,11 @@ def _activation(x: jax.Array, act: HiddenAct) -> jax.Array:
     return jax.nn.silu(x)
 
 
-def _matmul(x: jax.Array, w) -> jax.Array:
-    """x [T, n] @ w [n, d] with f32 accumulation on the MXU.
+def _matmul(x: jax.Array, w, role: str | None = None) -> jax.Array:
+    """x [T, n] @ w [n, d] with f32 accumulation on the MXU. ``role``
+    (``wqkv``, ``wo``, ``gate_up``, ``down``, ``experts``, ``logits``) names
+    the matrix in the device trace: a static string, in the Q40 kernel's
+    name and as a named scope around the plain dot.
 
     ``w`` is a plain array (bf16/f32) or a Q40 :class:`QuantizedMatrix`,
     which routes to the fused Pallas kernel (weights stay 4-bit in HBM).
@@ -78,17 +81,20 @@ def _matmul(x: jax.Array, w) -> jax.Array:
     from distributed_llama_tpu.ops.q40 import QuantizedMatrix, q40_matmul
 
     if isinstance(w, QuantizedMatrix):
-        return q40_matmul(x, w)
-    return jax.lax.dot_general(
-        x,
-        w,
-        (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
+        return q40_matmul(x, w, role=role)
+    with jax.named_scope(role or "matmul"):
+        return jax.lax.dot_general(
+            x,
+            w,
+            (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
 
 
-def _norm_matmul(x: jax.Array, weight: jax.Array, w) -> jax.Array:
+def _norm_matmul(
+    x: jax.Array, weight: jax.Array, w, role: str | None = None
+) -> jax.Array:
     """rmsnorm(x, weight) @ w — ONE fused program on the q40 int8 path
     (the decode superstep's part (a): the Q80 activation quantize rides
     the rmsnorm epilogue instead of paying its own program dispatch,
@@ -98,8 +104,8 @@ def _norm_matmul(x: jax.Array, weight: jax.Array, w) -> jax.Array:
     from distributed_llama_tpu.ops.q40 import QuantizedMatrix, rmsnorm_q40_matmul
 
     if isinstance(w, QuantizedMatrix):
-        return rmsnorm_q40_matmul(x, weight, w)
-    return _matmul(rmsnorm(x, weight).astype(w.dtype), w)
+        return rmsnorm_q40_matmul(x, weight, w, role=role)
+    return _matmul(rmsnorm(x, weight).astype(w.dtype), w, role)
 
 
 def project_qkv(
@@ -119,7 +125,7 @@ def project_qkv(
         # large bandwidth-efficient kernel call instead of three small
         # ones) — and the norm + Q80 quantize fused into that same program
         # on the int8 path (_norm_matmul)
-        fused = _norm_matmul(x, lp["rms_att"], lp["qkv"])  # [T, (Hl+2*Kl)*hd] f32
+        fused = _norm_matmul(x, lp["rms_att"], lp["qkv"], "wqkv")  # [T, (Hl+2*Kl)*hd] f32
         d_q = lp["wo"].shape[-2]  # Hl*hd (wo's input dim)
         d_kv = (fused.shape[-1] - d_q) // 2
         q = fused[:, :d_q]
@@ -129,9 +135,9 @@ def project_qkv(
         # three consumers of one normed activation: the norm cannot ride a
         # single matmul's epilogue here, so it stays standalone
         xc = rmsnorm(x, lp["rms_att"]).astype(lp["q"].dtype)
-        q = _matmul(xc, lp["q"])  # [T, Hl*hd] f32
-        k = _matmul(xc, lp["k"])  # [T, Kl*hd]
-        v = _matmul(xc, lp["v"])  # [T, Kl*hd]
+        q = _matmul(xc, lp["q"], "wqkv")  # [T, Hl*hd] f32
+        k = _matmul(xc, lp["k"], "wqkv")  # [T, Kl*hd]
+        v = _matmul(xc, lp["v"], "wqkv")  # [T, Kl*hd]
     Hl = q.shape[-1] // hd
     Kl = k.shape[-1] // hd
     q = apply_rope(q.reshape(T, Hl, hd), rope_rows, cfg)
@@ -156,7 +162,7 @@ def block_tail(
     a bucket-padded batch (rows >= n_real are engine pad zeros) — the
     capacity-bucketed MoE prefill masks pads out of its expert buckets."""
     if axis_name is None:
-        out = _matmul(att.astype(lp["wo"].dtype), lp["wo"])  # [T, dim]
+        out = _matmul(att.astype(lp["wo"].dtype), lp["wo"], "wo")  # [T, dim]
     else:
         # the TP all-reduce: replaces gather + merge-add on root
         # (reference: src/llama2-tasks.cpp:115-131) with one ICI collective,
@@ -168,7 +174,7 @@ def block_tail(
         from distributed_llama_tpu.ops import collectives
 
         out = collectives.matmul_all_reduce(
-            att.astype(lp["wo"].dtype), lp["wo"], axis_name
+            att.astype(lp["wo"].dtype), lp["wo"], axis_name, role="wo"
         )
     if cfg.arch.name == "GROK1":
         # grok rmsnorms the attention output with rmsFfn before the residual
@@ -190,7 +196,7 @@ def final_logits(cfg: LlamaConfig, params: Params, x: jax.Array) -> jax.Array:
     reference: src/llama2-tasks.cpp:222-239, src/grok1-tasks.cpp:270-273.
     Norm + quantize + matmul fuse into one program on the q40 int8 path
     (_norm_matmul)."""
-    logits = _norm_matmul(x, params["rms_final"], params["wcls"])
+    logits = _norm_matmul(x, params["rms_final"], params["wcls"], "logits")
     if cfg.arch.name == "GROK1":
         logits = logits * 0.5773502691896257
     return logits
@@ -311,19 +317,21 @@ def ffn(cfg: LlamaConfig, x: jax.Array, lp: Params, axis_name: str | None) -> ja
     if "gate_up" in lp:
         # gate|up packed as one matmul (see the qkv note in attention),
         # with the norm + Q80 quantize fused in on the int8 path
-        fused = _norm_matmul(x, lp["rms_ffn"], lp["gate_up"])
+        fused = _norm_matmul(x, lp["rms_ffn"], lp["gate_up"], "gate_up")
         hidden = fused.shape[-1] // 2
         h = _activation(fused[:, :hidden], cfg.hidden_act) * fused[:, hidden:]
     else:
         xn = rmsnorm(x, lp["rms_ffn"]).astype(lp["gate"].dtype)
-        h = _activation(_matmul(xn, lp["gate"]), cfg.hidden_act) * _matmul(xn, lp["up"])
+        h = _activation(_matmul(xn, lp["gate"], "gate_up"), cfg.hidden_act) * _matmul(
+            xn, lp["up"], "gate_up"
+        )
     if axis_name is None:
-        return _matmul(h.astype(lp["down"].dtype), lp["down"])
+        return _matmul(h.astype(lp["down"].dtype), lp["down"], "down")
     from distributed_llama_tpu.ops import collectives
 
     # down + TP all-reduce through the fused seam (see block_tail)
     return collectives.matmul_all_reduce(
-        h.astype(lp["down"].dtype), lp["down"], axis_name
+        h.astype(lp["down"].dtype), lp["down"], axis_name, role="down"
     )
 
 

@@ -78,13 +78,15 @@ def _expert_ffn(cfg: LlamaConfig, xn: jax.Array, ew) -> jax.Array:
     from distributed_llama_tpu.models.llama import _activation, _matmul
 
     if "gate_up" in ew:
-        fused = _matmul(xn.astype(ew["gate_up"].dtype), ew["gate_up"])
+        fused = _matmul(xn.astype(ew["gate_up"].dtype), ew["gate_up"], "experts")
         hidden = fused.shape[-1] // 2
         h = _activation(fused[:, :hidden], cfg.hidden_act) * fused[:, hidden:]
     else:
         xc = xn.astype(ew["gate"].dtype)
-        h = _activation(_matmul(xc, ew["gate"]), cfg.hidden_act) * _matmul(xc, ew["up"])
-    return _matmul(h.astype(ew["down"].dtype), ew["down"])
+        h = _activation(_matmul(xc, ew["gate"], "experts"), cfg.hidden_act) * _matmul(
+            xc, ew["up"], "experts"
+        )
+    return _matmul(h.astype(ew["down"].dtype), ew["down"], "experts")
 
 
 def _moe_topk(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:

@@ -574,6 +574,7 @@ def _fused_paged_attention(
         out_specs=vmem_spec,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="paged_attention_fused",
     )(
         pos.astype(jnp.int32), matched.astype(jnp.int32),
         pos.astype(jnp.int32), matched.astype(jnp.int32),

@@ -269,7 +269,9 @@ def _make_fused_matmul_ring_kernel(axis_name: str, n: int, nj: int, w: int):
     return kernel
 
 
-def fused_matmul_ring_all_reduce(x: jax.Array, qm, axis_name: str, n: int) -> jax.Array:
+def fused_matmul_ring_all_reduce(
+    x: jax.Array, qm, axis_name: str, n: int, role: str | None = None
+) -> jax.Array:
     """psum_over_shards(x @ dequant(qm)) as ONE Pallas program: Q80
     quantize (outside — elementwise, XLA fuses it into the caller), then
     the int8 matmul computed chunk-by-chunk INSIDE the bidirectional ring
@@ -281,6 +283,7 @@ def fused_matmul_ring_all_reduce(x: jax.Array, qm, axis_name: str, n: int) -> ja
     from distributed_llama_tpu.ops.q40 import (
         BLOCK_N,
         _largest_divisor_tile,
+        kernel_name,
         q80_kernel_operands,
         quantize_q80,
     )
@@ -311,13 +314,15 @@ def fused_matmul_ring_all_reduce(x: jax.Array, qm, axis_name: str, n: int) -> ja
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=1
         ),
+        name=kernel_name("q40_int8_ring", role),
     )(xqb, sxw, xsum, qm.qs, qm.scales)
     flat = jnp.concatenate(list(out), axis=-1)  # [T, dp]
     return flat[:, : qm.d] if dp != qm.d else flat
 
 
 def matmul_all_reduce(
-    x: jax.Array, w, axis_name: str | None, impl: str | None = None
+    x: jax.Array, w, axis_name: str | None, impl: str | None = None,
+    role: str | None = None,
 ) -> jax.Array:
     """THE matmul+all-reduce seam: ``sum_over_shards(x @ w)``, replicated
     identically on every shard — what ``models.llama.block_tail``/``ffn``
@@ -335,7 +340,7 @@ def matmul_all_reduce(
     from distributed_llama_tpu.models.llama import _matmul
 
     if axis_name is None:
-        return _matmul(x, w)
+        return _matmul(x, w, role)
     if impl is None:
         impl = default_impl()
     if impl == "ring":
@@ -349,10 +354,10 @@ def matmul_all_reduce(
             and default_q40_path() == "int8"
             and _fused_ring_eligible(x, w, n)
         ):
-            out = fused_matmul_ring_all_reduce(x, w, axis_name, n)
+            out = fused_matmul_ring_all_reduce(x, w, axis_name, n, role)
             _note("fused_ring")
             return out
-    return all_reduce(_matmul(x, w), axis_name, impl)
+    return all_reduce(_matmul(x, w, role), axis_name, impl)
 
 
 # ---------------------------------------------------------------------------
@@ -519,6 +524,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str, n: int) -> jax.Array:
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=0
         ),
+        name="ring_all_reduce",
     )(chunks)
     flat_out = jnp.concatenate(list(out), axis=-1)
     flat_out = flat_out[..., :d] if pad else flat_out

@@ -510,6 +510,17 @@ def _make_q40_kernel(compute_dtype, interpret: bool = False):
     return kernel
 
 
+def kernel_name(kind: str, role: str | None) -> str:
+    """The name a kernel launch carries into the compiled program and the
+    device trace: its kind, and the ROLE of the matrix it multiplies
+    (``wqkv``, ``wo``, ``gate_up``, ``down``, ``experts``, ``logits``) where
+    the call site says it — ``wo`` and ``down`` share an output shape, and
+    without the role a trace cannot tell them apart. A static string: it
+    changes a program's text, never its arithmetic or how often it
+    compiles."""
+    return f"{kind}_{role}" if role else kind
+
+
 def _resolve_tiles(qm: QuantizedMatrix, T: int, block_n: int, block_d: int):
     """The kernel-eligibility decision, shared by every path: (bn, bd)
     tiles dividing the padded dims, or None → the XLA fallback. block_n
@@ -567,6 +578,7 @@ def q40_matmul(
     block_d: int = BLOCK_D,
     interpret: bool | None = None,
     path: str | None = None,
+    role: str | None = None,
 ) -> jax.Array:
     """y[T, d] = x[T, n] @ dequant(qm), f32 accumulation — the ONE Q40
     matmul entry point (``models.llama._matmul`` routes every quantized
@@ -582,7 +594,8 @@ def q40_matmul(
 
     Every dispatch decision is counted in ``dllama_kernel_path_total``
     (mxu_int8 / mxu_int8_fusedq / vpu_f32 / xla_fallback) so a silent
-    fallback to the slow path is visible in /metrics."""
+    fallback to the slow path is visible in /metrics. ``role`` names the
+    matrix in the kernel's trace name (:func:`kernel_name`)."""
     if qm.interleaved:
         raise ValueError(
             "interleaved pack: the block-interleaved basis is retired — "
@@ -601,18 +614,19 @@ def q40_matmul(
     int8_tiles = _fit_int8_tiles(qm, x.shape[0], bn, bd) if path == "int8" else None
     if int8_tiles is not None:
         _note_path("q40_matmul", "mxu_int8")
-        return _q40_matmul_int8(x, qm, *int8_tiles, interpret)
+        return _q40_matmul_int8(x, qm, *int8_tiles, interpret, role)
     _note_path("q40_matmul", "vpu_f32")
-    return _q40_matmul_f32(x, qm, bn, bd, interpret)
+    return _q40_matmul_f32(x, qm, bn, bd, interpret, role)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret", "role"))
 def _q40_matmul_f32(
     x: jax.Array,
     qm: QuantizedMatrix,
     block_n: int,
     block_d: int,
     interpret: bool,
+    role: str | None = None,
 ) -> jax.Array:
     """The f32-dequant kernel path: tiles are pre-resolved (the dispatch in
     :func:`q40_matmul` owns eligibility); internally the kernel runs on the
@@ -648,6 +662,7 @@ def _q40_matmul_f32(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name=kernel_name("q40_f32", role),
     )(xb, xb, qm.qs, qm.scales, qm.scales)
     # the kernel dequantized BIASED nibbles (0..15); subtract the +8 bias as
     # a rank-reduced correction on the MXU instead of 2 VPU passes over every
@@ -793,6 +808,7 @@ def _int8_core(
     block_n: int,
     block_d: int,
     interpret: bool,
+    role: str | None = None,
 ) -> jax.Array:
     """The int8 kernel launch + bias epilogue on ALREADY-QUANTIZED Q80
     activations (xq int8 [T, n_pad], sx f32 [T, n_pad/32]) — shared by the
@@ -828,6 +844,7 @@ def _int8_core(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name=kernel_name("q40_int8", role),
     )(xqb, xqb, sxw, sxw, qm.qs, qm.scales, qm.scales)
     # bias correction on the DEQUANTIZED Q80 block sums: sum_{i in b} of
     # sx[t,b]*xq[t,i] — f32-exact given the int sums are exact
@@ -842,13 +859,14 @@ def _int8_core(
     return out[:, :d] if dp != d else out
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret", "role"))
 def _q40_matmul_int8(
     x: jax.Array,
     qm: QuantizedMatrix,
     block_n: int,
     block_d: int,
     interpret: bool,
+    role: str | None = None,
 ) -> jax.Array:
     """The int8 MXU path of :func:`q40_matmul`: Q80-quantize x, run the
     per-block int8 kernel, subtract the +8 bias as the rank-reduced MXU
@@ -858,7 +876,7 @@ def _q40_matmul_int8(
     if x.shape[-1] != np_:
         x = jnp.pad(x, ((0, 0), (0, np_ - x.shape[-1])))
     xq, sx = quantize_q80(x)
-    return _int8_core(xq, sx, qm, block_n, block_d, interpret)
+    return _int8_core(xq, sx, qm, block_n, block_d, interpret, role)
 
 
 # ---------------------------------------------------------------------------
@@ -895,7 +913,7 @@ def _fused_q80_enabled() -> bool:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_n", "block_d", "interpret", "eps")
+    jax.jit, static_argnames=("block_n", "block_d", "interpret", "eps", "role")
 )
 def _rmsnorm_q40_matmul_int8(
     x: jax.Array,
@@ -905,6 +923,7 @@ def _rmsnorm_q40_matmul_int8(
     block_d: int,
     interpret: bool,
     eps: float,
+    role: str | None = None,
 ) -> jax.Array:
     # the EXACT unfused op sequence — rmsnorm_ref ops, the bf16 activation
     # cast models.llama._matmul would apply, end-padding, quantize — in one
@@ -918,7 +937,7 @@ def _rmsnorm_q40_matmul_int8(
     if xb.shape[-1] != np_:
         xb = jnp.pad(xb, ((0, 0), (0, np_ - xb.shape[-1])))
     xq, sx = quantize_q80(xb)
-    return _int8_core(xq, sx, qm, block_n, block_d, interpret)
+    return _int8_core(xq, sx, qm, block_n, block_d, interpret, role)
 
 
 def rmsnorm_q40_matmul(
@@ -930,6 +949,7 @@ def rmsnorm_q40_matmul(
     block_d: int = BLOCK_D,
     interpret: bool | None = None,
     path: str | None = None,
+    role: str | None = None,
 ) -> jax.Array:
     """y = rmsnorm(x, weight) @ dequant(qm) as ONE fused program when the
     int8 kernel path is eligible (noted ``mxu_int8_fusedq``); otherwise the
@@ -952,12 +972,12 @@ def rmsnorm_q40_matmul(
         # (the fused path absorbs it; docs/OBSERVABILITY.md)
         _note_path("rmsnorm", "xla_standalone")
         xb = rmsnorm_ref(x, weight, eps).astype(jnp.bfloat16)
-        return q40_matmul(xb, qm, block_n, block_d, interpret, path)
+        return q40_matmul(xb, qm, block_n, block_d, interpret, path, role)
     if interpret is None:
         interpret = _interpret_default()
     bn, bd = tiles
     _note_path("q40_matmul", "mxu_int8_fusedq")
-    return _rmsnorm_q40_matmul_int8(x, weight, qm, bn, bd, interpret, eps)
+    return _rmsnorm_q40_matmul_int8(x, weight, qm, bn, bd, interpret, eps, role)
 
 
 def _shrink_block_d(T: int, block_d: int) -> int:

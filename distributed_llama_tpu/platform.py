@@ -22,11 +22,17 @@ DEFAULT_COMPILE_CACHE = os.path.join(
 _cache_hit_listener_installed = False
 
 
-def _install_cache_hit_listener() -> None:
-    """Count persistent-cache hits into telemetry: jax announces each
-    cache-served compile via a monitoring event; the listener forwards it
-    to ``dllama_compile_cache_hits_total`` (no-op while telemetry is off)."""
+def _install_compile_listeners() -> None:
+    """Count program builds into telemetry: jax announces each
+    cache-served compile, and the duration of every backend compile (a real
+    build or a load from the persistent cache), via monitoring events; the
+    listeners forward them to ``dllama_compile_cache_hits_total`` and
+    ``dllama_compiles_total`` / ``dllama_compile_seconds_total`` (no-ops
+    while telemetry is off)."""
     global _cache_hit_listener_installed
+    from distributed_llama_tpu import telemetry
+
+    telemetry.bind_compile_counters()  # at 0 from the first scrape on
     if _cache_hit_listener_installed:
         return
     from jax._src import monitoring
@@ -37,7 +43,15 @@ def _install_cache_hit_listener() -> None:
 
             telemetry.note_compile_cache_hit()
 
+    def _on_duration(event: str, duration: float, **kwargs) -> None:
+        # fires for a real build and for a load from the persistent cache
+        if event == "/jax/core/compile/backend_compile_duration":
+            from distributed_llama_tpu import telemetry
+
+            telemetry.note_compile(float(duration))
+
     monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
     _cache_hit_listener_installed = True
 
 
@@ -53,10 +67,12 @@ def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
     outside. Otherwise: the explicit argument (the flag), then
     ``DLLAMA_COMPILE_CACHE`` (empty string disables), else
     :data:`DEFAULT_COMPILE_CACHE`. Returns the directory in use, or None
-    when disabled. Cache-served compiles are counted in
-    ``dllama_compile_cache_hits_total`` when telemetry is enabled."""
+    when disabled. With telemetry enabled, program builds are counted in
+    ``dllama_compiles_total`` / ``dllama_compile_seconds_total`` and
+    cache-served ones in ``dllama_compile_cache_hits_total``."""
     import jax
 
+    _install_compile_listeners()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not placed:
         if cache_dir is None:
@@ -71,5 +87,4 @@ def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
     # RECOMPILES of big ones; cache everything that took >1s to build
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _install_cache_hit_listener()
     return placed or cache_dir

@@ -74,6 +74,7 @@ from distributed_llama_tpu.server.replicas import (
     ReplicaPool,
 )
 from distributed_llama_tpu.telemetry import Stopwatch, flight, trace
+from distributed_llama_tpu.telemetry.capture import Capture, CaptureBusy, NoCapture
 from distributed_llama_tpu.telemetry.trace import RequestTraceStore
 from distributed_llama_tpu.tokenizer import (
     ChatItem,
@@ -323,6 +324,12 @@ class ApiState:
                 sample_rate=1.0 if sample is None else float(sample),
                 slow_ttft_s=1.0 if slow is None else float(slow),
             )
+        # the capture control behind POST /debug/profile (ISSUE 23,
+        # telemetry/capture.py): a short profiler trace of this process
+        # with the spans on its timeline. None with telemetry off
+        self.capture: Capture | None = (
+            Capture(telemetry.TRACER) if telemetry.is_enabled() else None
+        )
         # flight recorder (ISSUE 16, telemetry/flight.py): always on —
         # lifecycle events are rare; arm the fault-fire observer and the
         # optional on-death JSON artifact directory
@@ -883,7 +890,7 @@ class ApiState:
             nonlocal sent, skip
             if skip > 0:
                 skip -= 1  # an already-delivered delta, identical by the
-                return     # bit-parity contract — swallow the replay
+                return False  # bit-parity contract — swallow the replay
             send_chunk(data)
             sent += 1
 
@@ -1092,10 +1099,30 @@ class ApiState:
 
         buffer = []
         emitted = 0
+        held = 0  # tokens fed whose text has not been handed over yet
+        answered = 0  # tokens in a non-streamed answer, counted at its return
         finish_reason = "length"  # overwritten on EOS/stop exit
 
+        def hand_over(text: str) -> None:
+            """``text`` goes to the client: now as an SSE delta, or with the
+            answer. One piece may carry several tokens (those a possible
+            stop-string prefix held back); a matched stop string's tokens
+            are never handed over."""
+            nonlocal held, answered
+            buffer.append(text)
+            if stream:
+                with trace.span(ctx, "sse_send", chars=len(text)):
+                    sent = send_chunk(
+                        self._chunk_json(text, stop=False, request_id=request_id)
+                    )
+                if sent is not False:  # False: a replayed delta, swallowed
+                    self.tel.tokens_streamed.inc(held)
+            else:
+                answered += held
+            held = 0
+
         def feed(prev: int, token: int) -> EosDetectorResult:
-            nonlocal emitted
+            nonlocal emitted, held
             if deadline is not None and time.monotonic() >= deadline:
                 # per-token deadline enforcement (both decode paths; the
                 # batch scheduler additionally retires the row between
@@ -1104,6 +1131,7 @@ class ApiState:
                     f"deadline expired after {emitted} tokens"
                 )
             emitted += 1
+            held += 1
             if ctx is not None:
                 # the TTFT/TPOT stamp: first mark is time-to-first-token,
                 # the spread of the rest is time-per-output-token
@@ -1113,16 +1141,12 @@ class ApiState:
             if res in (EosDetectorResult.NOT_EOS, EosDetectorResult.EOS):
                 delta = detector.get_delta()
                 if delta:
-                    text = delta.decode("utf-8", errors="replace")
-                    buffer.append(text)
-                    if stream:
-                        with trace.span(ctx, "sse_send", chars=len(text)):
-                            send_chunk(self._chunk_json(text, stop=False, request_id=request_id))
+                    hand_over(delta.decode("utf-8", errors="replace"))
                 detector.clear()
             return res
 
         res = EosDetectorResult.NOT_EOS
-        decode_t0 = time.perf_counter()
+        decode_t0 = time.monotonic()
         try:
             if device_decode:  # implies max_new > 0 (see device_decode above)
                 if max_new == 1:
@@ -1182,7 +1206,7 @@ class ApiState:
                 # the whole token loop as one span (the scheduler fans per-row
                 # batch_decode_chunk_row children into the same tree)
                 ctx.add_span(
-                    "decode_stream", decode_t0, time.perf_counter() - decode_t0,
+                    "decode_stream", decode_t0, time.monotonic() - decode_t0,
                     emitted=emitted, finish=finish_reason,
                 )
                 ctx.add_stage("decode", stage_sw.elapsed_s())
@@ -1191,10 +1215,7 @@ class ApiState:
             # string prefix (MAYBE_EOS) so the response tail is not lost
             tail = detector.flush_delta()
             if tail:
-                text = tail.decode("utf-8", errors="replace")
-                buffer.append(text)
-                if stream:
-                    send_chunk(self._chunk_json(text, stop=False, request_id=request_id))
+                hand_over(tail.decode("utf-8", errors="replace"))
 
         content = "".join(buffer)
         if engine.pos >= seq_len:
@@ -1209,6 +1230,7 @@ class ApiState:
             )
             send_chunk("[DONE]")
             return None
+        self.tel.tokens_streamed.inc(answered)
         result = {
             "id": f"chatcmpl-{request_id}",
             "object": "chat.completion",
@@ -1345,6 +1367,20 @@ class ApiState:
         }
 
 
+def _hbm_peak_bytes() -> int:
+    """The allocator's peak on the fullest local device; 0 where the
+    backend reports none (the CPU)."""
+    import jax
+
+    return max(
+        (
+            int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.local_devices()
+        ),
+        default=0,
+    )
+
+
 def make_handler(state: ApiState):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
@@ -1390,6 +1426,8 @@ def make_handler(state: ApiState):
                 # (engine + server + collective instruments). Valid, possibly
                 # sparse, output even when telemetry is disabled — scrapers
                 # should not get a 404 from a healthy server.
+                if state.tel.enabled:
+                    state.tel.hbm_peak.set(_hbm_peak_bytes())
                 payload = telemetry.prometheus_text().encode()
                 self.send_response(200)
                 self.send_header(
@@ -1539,9 +1577,63 @@ def make_handler(state: ApiState):
             self._send_json(200, result, request_id=rid)
             return "200"
 
+        def _debug_profile(self, rid: str) -> str:
+            """``POST /debug/profile``: the capture control
+            (telemetry/capture.py). ``{"action": "start", "dir": ...,
+            "max_seconds": N}`` / ``{"action": "stop"}``; 404 without
+            ``--telemetry``, 409 while another capture runs (or on a stop
+            with none running), 400 on a malformed body."""
+            try:
+                length = int(self.headers.get("Content-Length", 0))
+                body = json.loads(self.rfile.read(max(length, 0)) or b"{}")
+                action = body["action"]
+                if action not in ("start", "stop"):
+                    raise ValueError(f"unknown action {action!r}")
+                directory = body["dir"] if action == "start" else None
+                max_seconds = body.get("max_seconds")
+            except (TypeError, ValueError, KeyError) as e:
+                self._send_json(
+                    400,
+                    self._error_body(
+                        f"malformed capture request: {e!r}",
+                        "invalid_request_error", rid,
+                    ),
+                    request_id=rid,
+                )
+                return "400"
+            if state.capture is None:
+                self._send_json(
+                    404,
+                    self._error_body(
+                        "the capture control needs --telemetry",
+                        "not_found", rid,
+                    ),
+                    request_id=rid,
+                )
+                return "404"
+            try:
+                if action == "start":
+                    out = state.capture.start(str(directory), max_seconds)
+                else:
+                    out = state.capture.stop()
+            except (CaptureBusy, NoCapture) as e:
+                self._send_json(
+                    409, self._error_body(str(e), "capture_conflict", rid),
+                    request_id=rid,
+                )
+                return "409"
+            except ValueError as e:
+                self._send_json(
+                    400, self._error_body(str(e), "invalid_request_error", rid),
+                    request_id=rid,
+                )
+                return "400"
+            self._send_json(200, out, request_id=rid)
+            return "200"
+
         def do_POST(self):
             # request-duration measurement uses a MONOTONIC clock (Stopwatch
-            # wraps perf_counter: a wall-clock step mid-request — NTP, DST —
+            # wraps time.monotonic: a wall-clock step mid-request — NTP, DST —
             # must not corrupt the duration histogram), and every response
             # carries a correlation id so client-reported failures can be
             # matched to server logs
@@ -1556,8 +1648,9 @@ def make_handler(state: ApiState):
                 tel.inflight.dec()
                 tel.request_duration.observe(sw.elapsed_s())
                 route = (
-                    "/v1/chat/completions"
-                    if self.path == "/v1/chat/completions" else "other"
+                    self.path
+                    if self.path in ("/v1/chat/completions", "/debug/profile")
+                    else "other"
                 )
                 tel.requests.labels(route=route, status=status).inc()
 
@@ -1565,6 +1658,8 @@ def make_handler(state: ApiState):
             """Handle one POST; returns the response status for metrics."""
             if self.path == "/admin/rollout":
                 return self._admin_rollout(rid)
+            if self.path == "/debug/profile":
+                return self._debug_profile(rid)
             if self.path != "/v1/chat/completions":
                 self.send_error(404)
                 return "404"
@@ -1782,6 +1877,11 @@ def serve(args) -> None:
     # ApiState bind their instrument bundles (bind-once contract)
     if getattr(args, "telemetry", False):
         telemetry.enable()
+    if telemetry.is_enabled():
+        # no route exports the span ring, so the server records into it
+        # only during a capture (POST /debug/profile); outside one a span
+        # is a profiler annotation and nothing else
+        telemetry.TRACER.recording = False
     # the persistent compile cache must be configured before make_engine's
     # first jit (platform.enable_compilation_cache: a cold 7B prefill
     # compile becomes a cache deserialization)

@@ -186,21 +186,24 @@ def export_chrome_trace(path: str) -> str:
 
 
 class Stopwatch:
-    """``sw = Stopwatch(); ...; ms = sw.elapsed_ms()`` — monotonic, restartable."""
+    """``sw = Stopwatch(); ...; ms = sw.elapsed_ms()`` — restartable, on
+    ``time.monotonic`` (the one clock of spans, request traces, flight
+    events and the benchmark's client), so ``_t0`` is an instant a
+    request trace's ``add_span`` takes as it is."""
 
     __slots__ = ("_t0",)
 
     def __init__(self):
-        self._t0 = time.perf_counter()
+        self._t0 = time.monotonic()
 
     def restart(self) -> None:
-        self._t0 = time.perf_counter()
+        self._t0 = time.monotonic()
 
     def elapsed_s(self) -> float:
-        return time.perf_counter() - self._t0
+        return time.monotonic() - self._t0
 
     def elapsed_ms(self) -> float:
-        return (time.perf_counter() - self._t0) * 1000.0
+        return (time.monotonic() - self._t0) * 1000.0
 
 
 # ----------------------------------------------------------------------
@@ -217,7 +220,11 @@ class EngineInstruments:
         self.span = span_factory()
         self.tokens_generated = counter(
             "dllama_tokens_generated_total",
-            "Decoded (generated) tokens across all engine streams",
+            "Decoded tokens delivered to engine streams: under the batched "
+            "scheduler WHOLE chunks per row, the chunk dispatched ahead of "
+            "a stream's end included — an upper bound on what clients "
+            "receive (dllama_tokens_streamed_total counts those; "
+            "dllama_decode_row_steps_total says what became of the rest)",
         )
         self.prompt_tokens = counter(
             "dllama_prompt_tokens_total",
@@ -252,11 +259,55 @@ class EngineInstruments:
             "dllama_engine_streams",
             "Engine streams constructed (each owns one KV cache of HBM)",
         )
-        self.batch_occupancy = gauge(
-            "dllama_batch_occupancy",
-            "Active rows / dispatched bucket rows of the most recent batched "
-            "decode chunk (0..1; 1.0 = every slab row in the bucket is a "
-            "live request sharing the step's weight reads)",
+        # the batched scheduler's ledger (ISSUE 23): per CHUNK, never per
+        # token. Every row-step a decode chunk computed (bucket rows x
+        # steps) ends under exactly one fate, so the fates sum to the
+        # device's decode work once the streams have left
+        row_steps = counter(
+            "dllama_decode_row_steps_total",
+            "Row-steps computed by batched decode chunks (bucket rows x "
+            "steps; a spec-verify step counts a row's emitted tokens, 1 "
+            "for a row that emitted none), by what became of them: masked "
+            "(bucket row not joined), orphaned (the row left or changed "
+            "occupant before delivery: the chunk dispatched ahead), "
+            "quarantined (row retired by a fault), unread (delivered to a "
+            "stream's queue, still there when the stream left), consumed "
+            "(popped by the stream)",
+            labelnames=("fate",),
+        )
+        self.row_steps_masked = row_steps.labels(fate="masked")
+        self.row_steps_orphaned = row_steps.labels(fate="orphaned")
+        self.row_steps_quarantined = row_steps.labels(fate="quarantined")
+        self.row_steps_unread = row_steps.labels(fate="unread")
+        self.row_steps_consumed = row_steps.labels(fate="consumed")
+        chunk_rows = histogram(
+            "dllama_decode_chunk_rows",
+            "Rows of each dispatched batched decode chunk: kind=active the "
+            "joined rows, kind=bucket the power-of-two bucket dispatched "
+            "(sum(active)/sum(bucket) is the mean row fill)",
+            labelnames=("kind",),
+            buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0),
+        )
+        self.chunk_rows_active = chunk_rows.labels(kind="active")
+        self.chunk_rows_bucket = chunk_rows.labels(kind="bucket")
+        self.chunk_host = histogram(
+            "dllama_chunk_host_seconds",
+            "Host time of one batched decode chunk NOT spent waiting on "
+            "the device: build + dispatch + post-dispatch + delivery (the "
+            "host gap between chunks)",
+        )
+        self.chunk_fetch_wait = histogram(
+            "dllama_chunk_fetch_wait_seconds",
+            "Time one batched decode chunk's fetch blocked on the device "
+            "(the np.asarray of its token bundle)",
+        )
+        self.prefill_chunks_ahead = histogram(
+            "dllama_prefill_chunks_ahead",
+            "Decode chunks in flight on the device (one pending, one being "
+            "fetched) when a prefill chunk was enqueued behind them: how "
+            "long the decode chunk, the scheduler's clock, makes a prompt "
+            "wait",
+            buckets=(0.0, 1.0, 2.0),
         )
         # fault-tolerance surface (ISSUE 3): quarantines, retries, stalls
         self.rows_quarantined = counter(
@@ -414,6 +465,34 @@ def note_compile_cache_hit() -> None:
         ).inc()
 
 
+def bind_compile_counters() -> None:
+    """Register ``dllama_compiles_total`` / ``dllama_compile_seconds_total``
+    at 0 so a scrape before the first build already shows them (a window's
+    delta of a series that does not exist cannot be read)."""
+    if _enabled:
+        note_compile(0.0, count=0)
+
+
+def note_compile(seconds: float, count: int = 1) -> None:
+    """Count one program built or loaded from the persistent cache (the
+    ``backend_compile_duration`` monitoring event fires for both), and the
+    seconds it took. Called from the listener
+    platform.enable_compilation_cache installs; rare, so the registry
+    lookup per event is fine."""
+    if _enabled:
+        REGISTRY.counter(
+            "dllama_compiles_total",
+            "Programs built by the backend compiler or loaded from the "
+            "persistent compile cache; moving in steady state means a "
+            "mid-traffic recompile (a new shape reached the engine)",
+        ).inc(count)
+        REGISTRY.counter(
+            "dllama_compile_seconds_total",
+            "Seconds spent building or loading programs (sum of the "
+            "backend-compile durations counted by dllama_compiles_total)",
+        ).inc(seconds)
+
+
 def note_kernel_path(kernel: str, path: str) -> None:
     """Count one hot-path kernel DISPATCH DECISION by (kernel, path) —
     ``dllama_kernel_path_total`` (docs/OBSERVABILITY.md). Decisions happen
@@ -495,6 +574,18 @@ class ServerInstruments:
         self.inflight = gauge(
             "dllama_http_requests_in_flight",
             "Completion requests currently being served",
+        )
+        self.tokens_streamed = counter(
+            "dllama_tokens_streamed_total",
+            "Tokens whose text was handed to a client: in an SSE content "
+            "delta, or in a non-streamed answer (tokens swallowed by a "
+            "matched stop string are not)",
+        )
+        self.hbm_peak = gauge(
+            "dllama_hbm_peak_bytes",
+            "Allocator peak of the fullest local device "
+            "(memory_stats()['peak_bytes_in_use']), refreshed at each "
+            "/metrics scrape; 0 where the backend reports none",
         )
         self.queue_wait = histogram(
             "dllama_slot_queue_wait_seconds",

@@ -51,7 +51,6 @@ class FlightRecorder:
         self.max_dumps = max(1, int(max_dumps))
         self.dump_dir = dump_dir
         self._lock = lockcheck.make_lock("FlightRecorder._lock")
-        self._epoch = time.perf_counter()
         self._rings: dict[int, collections.deque] = {}
         self._dumps: collections.deque = collections.deque(maxlen=self.max_dumps)
         self._seq = 0
@@ -64,7 +63,9 @@ class FlightRecorder:
         format)."""
         ev = {
             "seq": 0,  # patched under the lock: a global order across rings
-            "t_s": round(time.perf_counter() - self._epoch, 6),
+            # absolute time.monotonic() seconds: the clock of the span ring,
+            # the request traces and a capture's host_spans.json
+            "t_s": round(time.monotonic(), 6),
             "replica": int(replica),
             "kind": kind,
         }
@@ -90,7 +91,7 @@ class FlightRecorder:
             n = self.dumps_total
         d = {
             "dump": n,
-            "t_s": round(time.perf_counter() - self._epoch, 6),
+            "t_s": round(time.monotonic(), 6),
             "replica": int(replica),
             "reason": reason,
             "events": events,

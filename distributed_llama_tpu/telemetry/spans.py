@@ -1,7 +1,7 @@
 """The span-name registry (ISSUE 16).
 
 Every string literal passed as a span name — ``tel.span("...")`` /
-``trace_span("...")`` on the ring tracer, or ``trace.span(ctx, "...")`` /
+``trace_span("...")`` on the span path (profiler annotation + ring), or ``trace.span(ctx, "...")`` /
 ``ctx.add_span("...")`` on a request trace — must be registered here and
 documented in docs/OBSERVABILITY.md's span-name table. The static
 analyzer's TRC-001 rule (analysis/rules/registries.py) cross-checks every
@@ -31,6 +31,13 @@ SPAN_NAMES = (
     "spec_verify_chunk",
     "prefix_spill_reload",
     "prefix_publish",
+    # the scheduler's own phases (ISSUE 23): what the host was doing
+    # between device programs; tools read them off a capture's xplane
+    "sched_build",
+    "sched_post_dispatch",
+    "sched_deliver",
+    "sched_wait",
+    "prefill_chunk_dispatch",
     # request-trace spans (ISSUE 16, telemetry/trace.py): the per-request
     # tree assembled by RequestTraceStore and served at /debug/trace/<id>
     "queue_wait",

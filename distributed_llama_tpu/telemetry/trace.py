@@ -55,12 +55,12 @@ class _TraceSpan:
         self._args = args
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         self._ctx.add_span(
-            self._name, self._t0, time.perf_counter() - self._t0, **self._args
+            self._name, self._t0, time.monotonic() - self._t0, **self._args
         )
         return False
 
@@ -104,7 +104,7 @@ class TraceContext:
         self.request_id = request_id
         self.tenant = tenant
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
+        self._t0 = time.monotonic()
         self.attempt = 0
         # one dict per attempt; [-1] is the live one. ``replayed`` marks
         # attempts re-run after a replica loss / preemption requeue.
@@ -126,7 +126,7 @@ class TraceContext:
                 {
                     "replayed": bool(replayed),
                     "replica": replica,
-                    "start_us": (time.perf_counter() - self._t0) * 1e6,
+                    "start_us": time.monotonic() * 1e6,
                 }
             )
             self.attempt = len(self.attempts) - 1
@@ -137,22 +137,22 @@ class TraceContext:
         with self._lock:
             if not self.attempts:
                 self.attempts.append(
-                    {"replayed": False, "replica": None, "start_us": 0.0}
+                    {"replayed": False, "replica": None, "start_us": self._t0 * 1e6}
                 )
             self.attempts[-1]["replica"] = int(replica)
 
     def add_span(self, name: str, t0: float, dur_s: float, **args) -> None:
-        """Record a completed span (``t0`` an absolute ``perf_counter``
-        instant; sub-perf_counter-resolution spans keep dur 0)."""
+        """Record a completed span (``t0`` an absolute ``time.monotonic``
+        instant, kept absolute; sub-clock-resolution spans keep dur 0)."""
         with self._lock:
             if not self.attempts:
                 self.attempts.append(
-                    {"replayed": False, "replica": None, "start_us": 0.0}
+                    {"replayed": False, "replica": None, "start_us": self._t0 * 1e6}
                 )
             self.events.append(
                 {
                     "name": name,
-                    "ts_us": (t0 - self._t0) * 1e6,
+                    "ts_us": t0 * 1e6,
                     "dur_us": dur_s * 1e6,
                     "attempt": self.attempt,
                     "args": args,
@@ -178,7 +178,7 @@ class TraceContext:
     def mark_token(self) -> None:
         """Per-emitted-token stamp (the serving layer's feed loop): the
         first stamp is TTFT, the spread of the rest is TPOT."""
-        now = time.perf_counter() - self._t0
+        now = time.monotonic() - self._t0
         with self._lock:
             if self.first_token_s is None:
                 self.first_token_s = now
@@ -186,7 +186,7 @@ class TraceContext:
             self.emitted += 1
 
     def finish(self) -> None:
-        self.e2e_s = time.perf_counter() - self._t0
+        self.e2e_s = time.monotonic() - self._t0
 
     # -- derived --------------------------------------------------------
 
@@ -208,10 +208,17 @@ class TraceContext:
 
     def tree(self) -> dict:
         """The assembled span tree: request root → attempt siblings →
-        recorded spans (docs/OBSERVABILITY.md "Request tracing")."""
+        recorded spans (docs/OBSERVABILITY.md "Request tracing"). Instants
+        are recorded absolute (``time.monotonic``); here, at export, they
+        become µs since the request's arrival, which the tree gives as
+        ``t0_monotonic_s`` so that it lies beside a capture's
+        ``host_spans.json``, the flight recorder and a client's clock."""
+        origin_us = self._t0 * 1e6
         with self._lock:
-            events = list(self.events)
-            attempts = [dict(a) for a in self.attempts]
+            events = [dict(e, ts_us=e["ts_us"] - origin_us) for e in self.events]
+            attempts = [
+                dict(a, start_us=a["start_us"] - origin_us) for a in self.attempts
+            ]
         nodes = []
         for i, meta in enumerate(attempts):
             spans = [e for e in events if e["attempt"] == i]
@@ -233,6 +240,7 @@ class TraceContext:
         return {
             "request_id": self.request_id,
             "tenant": self.tenant,
+            "t0_monotonic_s": self._t0,
             "sampled": self.sampled,
             "e2e_s": self.e2e_s,
             "ttft_s": self.ttft_s,

@@ -44,6 +44,76 @@ def test_a_cell_runs_and_agrees_with_the_reference(root, cell, chips):
     assert not os.path.exists(os.path.join(root, "benchmark", ".cache", "model"))  # gigabytes at full size
 
 
+def _cpu_trace_as_device(cell, trace_dir):
+    """Stands in for ``cell._reduce_trace`` on the CPU, where a capture has no
+    ``/device:TPU`` plane: the XLA CPU client's thread lines of the capture
+    the PROGRAM took are read as one device's ops, by the real reduction."""
+    import re
+
+    from benchmark.harness import trace_reduce
+
+    planes = trace_reduce.load(trace_dir, re.compile(r"^/host:CPU$"))
+    host = planes["/host:CPU"]
+    ops = [e for line, events in host.items() if line.startswith("tf_XLA") for e in events]
+    assert any(e[0].startswith("dllama/") for events in host.values() for e in events), \
+        "the program's spans are on the capture's host lines"
+    return {"inventory": planes["_inventory"],
+            **trace_reduce.reduce({"/device:TPU:0": {trace_reduce.OPS_LINE: ops}}, cell.chips)}
+
+
+NEW_COUNTERS = {"decode_consumed_share", "decode_orphaned_share", "decode_active_rows_mean",
+                "decode_row_fill_share", "chunk_host_ms_mean", "chunk_fetch_wait_ms_mean",
+                "program_builds_in_window"}
+
+
+@pytest.mark.parametrize("cell,suffix", [("tiny.open", "open"), ("tiny-moe.closed", "closed")])
+def test_trace_2_measures_as_trace_0_does_and_then_traces_in_the_same_process(
+        root, cell, suffix, monkeypatch):
+    from benchmark.harness import cell as cell_mod
+
+    phases = []
+    real_phase, real_e2e = cell_mod._traced_phase, cell_mod.stats.end_to_end
+
+    def e2e(*a, **kw):
+        phases.append("window")  # the window's numbers are taken ...
+        return real_e2e(*a, **kw)
+
+    def phase(server, mix, seed, seconds, requests, trace_dir):
+        phases.append("traced")  # ... before anything of the trace starts
+        assert (requests is None) == (mix["loop"] == "open")
+        before = server.scrape()
+        out = real_phase(server, mix, seed, seconds, requests, trace_dir)
+        # the traced phase sends NEW requests: none of the window's is replayed
+        assert out["records"] and all(r.ok for r in out["records"])
+        assert out["trace_stop"] - out["trace_start"] >= cell_mod.TRACE_SECONDS
+        assert os.path.exists(os.path.join(trace_dir, "host_spans.json"))
+        assert not os.path.exists(trace_dir + ".first")
+        grew = cell_mod.prom.delta(before, server.scrape(), "dllama_tokens_streamed_total")
+        assert grew == sum(len(r.deltas) for r in out["records"])
+        return out
+
+    monkeypatch.setattr(cell_mod.stats, "end_to_end", e2e)
+    monkeypatch.setattr(cell_mod, "_traced_phase", phase)
+    monkeypatch.setattr(cell_mod, "_reduce_trace", _cpu_trace_as_device)
+    monkeypatch.setattr(cell_mod, "TRACE_SECONDS", 0.5)
+    result = run_cell(root, cell, 2**31 + 13, 3.0, 2, "cpu", time.monotonic())
+    assert phases == ["window", "traced"]
+    assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
+    # both kinds of metric, side by side: the end-to-end ones of a --trace 0 line ...
+    assert NAMES[cell] <= set(result["metrics"])
+    # ... and the per-layer ones, the counters read over the MEASURED window
+    per_layer = set(result["metrics"]) - NAMES[cell]
+    assert NEW_COUNTERS | {f"server_ttft_ms_mean.{suffix}", f"prefill_chunks_ahead_mean.{suffix}",
+                           "device_idle_share", "decode_ms_per_tok"} <= per_layer
+    m = {k: v["value"] for k, v in result["metrics"].items()}
+    assert 0 < m["decode_consumed_share"] <= 100 and 0 <= m["decode_orphaned_share"] < 100
+    assert 0 < m["decode_row_fill_share"] <= 100 and m["program_builds_in_window"] == 0
+    assert set(result["device"]) >= {"memory_peak_bytes", "busy_s", "window_s"}
+    assert 0 < result["device"]["busy_s"] <= result["device"]["window_s"]
+    assert not os.path.exists(os.path.join(root, "benchmark", ".cache", "trace"))  # reduced, deleted
+    json.dumps(result)
+
+
 def test_a_traced_run_with_no_device_operation_is_refused(root):
     with pytest.raises(BenchFailure, match="no device operation"):
         run_cell(root, "tiny.open", 5, 2.0, True, "cpu", time.monotonic())

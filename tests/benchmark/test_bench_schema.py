@@ -26,7 +26,8 @@ def load(*parts):
 
 def test_top_level_keys_and_sizes(bench):
     assert set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
-                          "end_to_end", "per_layer"}
+                          "end_to_end", "per_layer", "trace_in_run"}
+    assert bench["trace_in_run"] is True  # the runs that measure also trace: run.py --trace 2
     assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
     assert isinstance(bench["run_seconds"], int) and 1 <= bench["run_seconds"] <= 51
     assert 1 <= len(bench["paths"]) <= 16 and all(os.path.isdir(os.path.join(REPO, p)) for p in bench["paths"])
