@@ -1629,7 +1629,7 @@ def run_kernels() -> dict:
         prev = os.environ.get("DLT_FUSED_PAGED")
         os.environ["DLT_FUSED_PAGED"] = "0"
         try:
-            return att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
+            return att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
         finally:
             if prev is None:
                 os.environ.pop("DLT_FUSED_PAGED", None)
@@ -1682,7 +1682,7 @@ def run_kernels() -> dict:
         os.environ["DLT_FUSED_PAGED"] = "0"
         try:
             return att.batched_verify_attention(
-                qgv, keys, values, posv, chunk, paged=paged)
+                qgv, (keys, values), posv, chunk, paged=paged)
         finally:
             if prev is None:
                 os.environ.pop("DLT_FUSED_PAGED", None)
@@ -1790,7 +1790,7 @@ def run_kernels() -> dict:
     # the sum IS the per-layer program count.
     def superstep():
         h = rmsnorm_q40_matmul(x, wgt, qm, path="int8")       # attn norm+qkv
-        a_ = att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
+        a_ = att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
         o = q40_matmul(x, qm, path="int8")                    # wo
         g = rmsnorm_q40_matmul(x, wgt, qm, path="int8")       # ffn norm+gate_up
         dn = q40_matmul(x, qm, path="int8")                   # down

@@ -209,7 +209,7 @@ def _kernels_alone(seed: int, shapes=SHAPES_7B, interpret: bool = False) -> None
     # bf16 storage, bf16 multiplies with f32 accumulation: 2^-8 per product
     att_tol = 2e-2
     got = jax.jit(lambda *a: att.batched_decode_attention(
-        a[0], a[1], a[2], a[3], chunk, paged=(a[4], a[5], a[6], a[7])
+        a[0], (a[1], a[2]), a[3], chunk, paged=(a[4], a[5], a[6], a[7])
     ))(qg, keys, values, pos, pool_k, pool_v, tables, matched)
     err = float(np.abs(np.asarray(got) - want).max() / np.abs(want).max())
     log(f"[c] paged decode attention (xla_segmented) B={B} S={S} max err {err:.2e} of max|want|")

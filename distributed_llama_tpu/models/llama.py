@@ -450,7 +450,7 @@ def attention_batched(
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     B = x.shape[0]
-    S = cache_l[0].shape[1]
+    S, cdt, prec = kvc.slab_facts(cache_l)
     hd = cfg.head_size
     q, k, v = project_qkv(cfg, lp, x, rope_rows)  # [B, Hl, hd], [B, Kl, hd] x2
     Hl, Kl = q.shape[1], k.shape[1]
@@ -460,15 +460,13 @@ def attention_batched(
         # fused slab leaf [2, B, S, Kl, hd]: one coalesced scatter writes
         # every row's key AND value (see the fused note in attention())
         new_cache = kvc.fused_update_row_batched(cache_l, k, v, write_slot)
-        keys, values = new_cache[0], new_cache[1]
     else:
-        keys = kvc.update_row_batched(cache_l[0], k, write_slot)
-        values = kvc.update_row_batched(cache_l[1], v, write_slot)
-        new_cache = (keys, values)
+        new_cache = (
+            kvc.update_row_batched(cache_l[0], k, write_slot),
+            kvc.update_row_batched(cache_l[1], v, write_slot),
+        )
 
     kv_mul = Hl // Kl
-    cdt = kvc.compute_dtype(keys)
-    prec = kvc.einsum_precision(keys)
     qg = q.reshape(B, Kl, kv_mul, hd).astype(cdt)
     # inactive rows read from position 0 so they cannot inflate the shared
     # dynamic chunk bound (their output is garbage either way)
@@ -479,12 +477,15 @@ def attention_batched(
     ):
         from distributed_llama_tpu.ops.attention import batched_decode_attention
 
+        # the cache goes in AS STORED: the chunk loops slice their chunks
+        # out of the leaf; ``keys``/``values`` of a whole slab never form
         att = batched_decode_attention(
-            qg.astype(jnp.float32), keys, values, read_pos, ATT_CHUNK,
-            paged=paged,
+            qg.astype(jnp.float32), new_cache, read_pos, ATT_CHUNK, paged=paged
         ).astype(jnp.float32)
         return att.reshape(B, Hl * hd), new_cache
-    # a dispatch bucket below B_max reads only its own slab rows
+    # small/odd caches read all of S anyway: the halves may form here.
+    # A dispatch bucket below B_max reads only its own slab rows
+    keys, values = new_cache[0], new_cache[1]
     keys_b = keys if keys.shape[0] == B else kvc.slice_rows_batched(keys, 0, S, rows=B)
     values_b = (
         values if values.shape[0] == B else kvc.slice_rows_batched(values, 0, S, rows=B)
@@ -499,7 +500,7 @@ def attention_batched(
             from distributed_llama_tpu.ops.attention import batched_decode_attention
 
             att = batched_decode_attention(
-                qg.astype(jnp.float32), keys_b, values_b, read_pos, ATT_CHUNK
+                qg.astype(jnp.float32), (keys_b, values_b), read_pos, ATT_CHUNK
             ).astype(jnp.float32)
             return att.reshape(B, Hl * hd), new_cache
     scores = kvc.scores_einsum_batched(qg, keys_b, prec) / jnp.sqrt(jnp.float32(hd))
@@ -575,7 +576,7 @@ def attention_verify_batched(
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     B, T = x.shape[0], x.shape[1]
-    S = cache_l[0].shape[1]
+    S, cdt, prec = kvc.slab_facts(cache_l)
     hd = cfg.head_size
     # projections/rope are position-free per row: run them on the flattened
     # [B*T] token axis (one matmul per matrix — the whole point of scoring
@@ -592,16 +593,14 @@ def attention_verify_batched(
     slots = jnp.where(active[:, None] & (slots < S), slots, S)  # S = dropped
     if kvc.is_fused_leaf(cache_l):
         new_cache = kvc.fused_update_verify_batched(cache_l, k, v, slots)
-        keys, values = new_cache[0], new_cache[1]
     else:
         b_idx = jnp.arange(B)[:, None]
-        keys = kvc.scatter_verify_rows(cache_l[0], b_idx, slots, k)
-        values = kvc.scatter_verify_rows(cache_l[1], b_idx, slots, v)
-        new_cache = (keys, values)
+        new_cache = (
+            kvc.scatter_verify_rows(cache_l[0], b_idx, slots, k),
+            kvc.scatter_verify_rows(cache_l[1], b_idx, slots, v),
+        )
 
     kv_mul = Hl // Kl
-    cdt = kvc.compute_dtype(keys)
-    prec = kvc.einsum_precision(keys)
     qg = q.reshape(B, T, Kl, kv_mul, hd).astype(cdt)
     read_pos = jnp.where(active, pos, 0)
     use_blocked = S % ATT_CHUNK == 0 and S > ATT_CHUNK
@@ -611,10 +610,10 @@ def attention_verify_batched(
         from distributed_llama_tpu.ops.attention import batched_verify_attention
 
         att = batched_verify_attention(
-            qg.astype(jnp.float32), keys, values, read_pos, ATT_CHUNK,
-            paged=paged,
+            qg.astype(jnp.float32), new_cache, read_pos, ATT_CHUNK, paged=paged
         ).astype(jnp.float32)
         return att.reshape(B, T, Hl * hd), new_cache
+    keys, values = new_cache[0], new_cache[1]
     keys_b = keys if keys.shape[0] == B else kvc.slice_rows_batched(keys, 0, S, rows=B)
     values_b = (
         values if values.shape[0] == B else kvc.slice_rows_batched(values, 0, S, rows=B)
@@ -627,7 +626,7 @@ def attention_verify_batched(
             from distributed_llama_tpu.ops.attention import batched_verify_attention
 
             att = batched_verify_attention(
-                qg.astype(jnp.float32), keys_b, values_b, read_pos, ATT_CHUNK
+                qg.astype(jnp.float32), (keys_b, values_b), read_pos, ATT_CHUNK
             ).astype(jnp.float32)
             return att.reshape(B, T, Hl * hd), new_cache
     scores = kvc.scores_einsum_verify(qg, keys_b, prec) / jnp.sqrt(jnp.float32(hd))

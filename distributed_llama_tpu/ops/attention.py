@@ -92,7 +92,7 @@ def paged_segments(matched, chunk: int, n_chunks):
     return a, b
 
 
-def _segmented_batched_scan(partial, keys, values, paged, chunk: int, n_chunks, init, rows: int):
+def _segmented_batched_scan(partial, cache, paged, chunk: int, n_chunks, init, rows: int):
     """The batched paged chunk scan shared by decode and verify attention:
     run ``partial(kc, vc, start, carry)`` over every chunk, reading each
     chunk from the slab (``paged`` None), or through the pool-only / mixed /
@@ -100,23 +100,38 @@ def _segmented_batched_scan(partial, keys, values, paged, chunk: int, n_chunks, 
     byte selects in the mixed span. One definition so a fix to the segment
     logic can never reach one caller and skip the other.
 
-    Parity scope: the segments keep one fori_loop each — decode must not
-    pay a pool gather on slab-only chunks — which means a backend whose
-    per-loop codegen differs could perturb the merge by ulps (the
-    mechanism that forced :func:`blocked_attention`'s paged prefill to a
-    single mixed loop). Bit-parity vs the copy path is test-enforced on
-    the CPU mesh; the hit-vs-cold parity tests are the tripwire on any
-    new backend."""
+    ``cache`` is the layer's slab as it is stored (a fused leaf or a
+    ``(keys, values)`` tuple) and between the step's cache write and these
+    reads nothing of the slab's size may form. Two rules keep it so, both
+    read off the v5e compiler's output (tests/test_chip_compile.py holds
+    them; PERF.md §5 has the history):
 
-    def slab_chunk(i):
-        return (
-            kvc.slice_rows_batched(keys, i * chunk, chunk, rows=rows),
-            kvc.slice_rows_batched(values, i * chunk, chunk, rows=rows),
-        )
+    - each chunk is sliced out of the cache INSIDE the loop
+      (:func:`kv_cache.slab_chunk`). The loops' bounds are dynamic, so they
+      compile to ``while`` loops, and a ``while`` operand is a buffer of its
+      own: handing the loops ``leaf[0]``/``leaf[1]`` made XLA copy both
+      halves of the whole slab in every layer of every decode step;
+    - ONE loop per call reads the slab. A leaf that feeds two ``while``
+      loops is re-laid out for them (heads outside positions, one copy of
+      the whole leaf a layer a step), so the mixed and the slab-only
+      segment share a loop and a ``cond`` on the chunk index picks the
+      segment's arithmetic; the pool-only loop never touches the slab.
+
+    Parity scope: every segment keeps its own instance of ``partial`` (the
+    pool-only loop and the two ``cond`` branches) — decode must not pay a
+    pool gather on slab-only chunks — which means a backend whose
+    per-instance codegen differs could perturb the merge by ulps (the
+    mechanism that forced :func:`blocked_attention`'s paged prefill to a
+    single mixed body). Chunk indices, chunk bytes and merge order are
+    those of the unpaged scan. Bit-parity vs the copy path is
+    test-enforced on the CPU mesh; the hit-vs-cold parity tests are the
+    tripwire on any new backend."""
+
+    def slab_only(kc, vc, i, carry):
+        return partial(kc, vc, i * chunk, carry)
 
     def body_slab(i, carry):
-        kc, vc = slab_chunk(i)
-        return partial(kc, vc, i * chunk, carry)
+        return slab_only(*kvc.slab_chunk(cache, i * chunk, chunk, rows), i, carry)
 
     if paged is None:
         return jax.lax.fori_loop(0, n_chunks, body_slab, init)
@@ -130,8 +145,7 @@ def _segmented_batched_scan(partial, keys, values, paged, chunk: int, n_chunks, 
         vc = kvc.pool_chunk(pool_v, tables, i, ppc)
         return partial(kc, vc, i * chunk, carry)
 
-    def body_mixed(i, carry):
-        kc_s, vc_s = slab_chunk(i)
+    def mixed(kc_s, vc_s, i, carry):
         kc_p = kvc.pool_chunk(pool_k, tables, i, ppc)
         vc_p = kvc.pool_chunk(pool_v, tables, i, ppc)
         sel = (i * chunk + jnp.arange(chunk))[None, :] < matched[:, None]
@@ -140,9 +154,12 @@ def _segmented_batched_scan(partial, keys, values, paged, chunk: int, n_chunks, 
             i * chunk, carry,
         )
 
+    def body_mixed_then_slab(i, carry):
+        kc_s, vc_s = kvc.slab_chunk(cache, i * chunk, chunk, rows)
+        return jax.lax.cond(i < b, mixed, slab_only, kc_s, vc_s, i, carry)
+
     carry = jax.lax.fori_loop(0, a, body_pool, init)
-    carry = jax.lax.fori_loop(a, b, body_mixed, carry)
-    return jax.lax.fori_loop(b, n_chunks, body_slab, carry)
+    return jax.lax.fori_loop(a, n_chunks, body_mixed_then_slab, carry)
 
 
 def blocked_partials(
@@ -237,8 +254,7 @@ def _verify_partial(qg, pos, chunk: int, cdt, prec):
 
 def batched_decode_attention(
     qg: jax.Array,  # [B, K, M, hd] f32 grouped queries (one token per row)
-    keys,  # slab cache half [B, S, K, hd] (array or QuantizedKV)
-    values,
+    cache,  # the layer's slab: fused leaf [2, B_max, S, K, hd] or (keys, values)
     pos: jax.Array,  # [B] per-row absolute positions (inactive rows: 0)
     chunk: int,
     paged=None,  # (pool_k, pool_v, tables [B, n_table], matched [B])
@@ -252,7 +268,9 @@ def batched_decode_attention(
     [B, K, M, hd] f32. Requires S % chunk == 0 (callers fall back to the
     full-S einsum otherwise, exactly like the single-stream path). The
     slab may hold MORE rows than B (a dispatch bucket below B_max): only
-    the first B rows are read.
+    the first B rows are read. ``cache`` is passed as the layer stores it
+    (array or QuantizedKV leaf, or the tp backend's ``(keys, values)``
+    halves) and only chunk-sized pieces of it are ever sliced out.
 
     With ``paged`` set (zero-copy prefix aliasing), row ``b``'s positions
     below ``matched[b]`` are read from the shared page pool THROUGH its page
@@ -263,20 +281,18 @@ def batched_decode_attention(
     to the copy path's. Requires chunk % page == 0 (callers fall back to
     the virtual-row einsum otherwise)."""
     B, K, M, hd = qg.shape
-    S = keys.shape[1]
-    if paged is not None and _fused_paged_eligible(qg, keys, values, paged, chunk):
+    S, cdt, prec = kvc.slab_facts(cache)
+    if paged is not None and _fused_paged_eligible(qg, cache, paged, chunk):
         from distributed_llama_tpu import telemetry
 
         telemetry.note_kernel_path("paged_attention", "pallas_fused")
-        return fused_paged_decode_attention(qg, keys, values, pos, chunk, paged)
+        return fused_paged_decode_attention(qg, *cache, pos, chunk, paged)
     if paged is not None:
         from distributed_llama_tpu import telemetry
 
         # the hit path fell back to the chain of segmented-scan programs —
         # visible in /metrics so a silent slow path can be alerted on
         telemetry.note_kernel_path("paged_attention", "xla_segmented")
-    cdt = kvc.compute_dtype(keys)
-    prec = kvc.einsum_precision(keys)
     live = jnp.clip(jnp.max(pos) + 1, 0, S)
     n_chunks = jax.lax.div(live + chunk - 1, chunk)
     partial = _decode_partial(qg, pos, chunk, cdt, prec)
@@ -284,7 +300,7 @@ def batched_decode_attention(
     l0 = jnp.zeros((B, K, M), jnp.float32)
     o0 = jnp.zeros((B, K, M, hd), jnp.float32)
     m, l, o = _segmented_batched_scan(
-        partial, keys, values, paged, chunk, n_chunks, (m0, l0, o0), rows=B
+        partial, cache, paged, chunk, n_chunks, (m0, l0, o0), rows=B
     )
     return o / jnp.maximum(l, 1e-30)[..., None]
 
@@ -339,7 +355,7 @@ def _fused_paged_enabled() -> bool:
     return env is not None and env != "0"
 
 
-def _fused_paged_eligible(qg, keys, values, paged, chunk: int) -> bool:
+def _fused_paged_eligible(qg, cache, paged, chunk: int) -> bool:
     """Shape/dtype gate for the fused kernel: slab and pool halves must
     agree on quantization class, chunks must be whole pages, and the slab
     must block evenly (callers already guarantee the last two on the
@@ -347,15 +363,15 @@ def _fused_paged_eligible(qg, keys, values, paged, chunk: int) -> bool:
     if not _fused_paged_enabled():
         return False
     pool_k, pool_v, tables, matched = paged
-    quant = isinstance(keys, kvc.QuantizedKV)
+    halves = (cache,) if kvc.is_fused_leaf(cache) else tuple(cache)
+    quant = isinstance(halves[0], kvc.QuantizedKV)
     if any(
         isinstance(h, kvc.QuantizedKV) is not quant
-        for h in (values, pool_k, pool_v)
+        for h in halves + (pool_k, pool_v)
     ):
         return False
     page = kvc.pool_page_size(pool_k)
-    S = keys.shape[1]
-    return chunk % page == 0 and S % chunk == 0
+    return chunk % page == 0 and kvc.slab_facts(cache)[0] % chunk == 0
 
 
 def _double_buffer_default() -> bool:
@@ -625,8 +641,7 @@ def fused_paged_verify_attention(
 
 def batched_verify_attention(
     qg: jax.Array,  # [B, T, K, M, hd] f32 grouped queries (T = draft k + 1)
-    keys,  # slab cache half [B, S, K, hd] (array or QuantizedKV)
-    values,
+    cache,  # the layer's slab: fused leaf [2, B_max, S, K, hd] or (keys, values)
     pos: jax.Array,  # [B] per-row positions of query t=0 (inactive rows: 0)
     chunk: int,
     paged=None,  # (pool_k, pool_v, tables [B, n_table], matched [B])
@@ -648,18 +663,16 @@ def batched_verify_attention(
     dispatches to the fused Pallas kernel under the same eligibility gate
     as decode (:func:`_fused_paged_eligible`, ``DLT_FUSED_PAGED``)."""
     B, T, K, M, hd = qg.shape
-    S = keys.shape[1]
-    if paged is not None and _fused_paged_eligible(qg, keys, values, paged, chunk):
+    S, cdt, prec = kvc.slab_facts(cache)
+    if paged is not None and _fused_paged_eligible(qg, cache, paged, chunk):
         from distributed_llama_tpu import telemetry
 
         telemetry.note_kernel_path("paged_attention", "pallas_fused_verify")
-        return fused_paged_verify_attention(qg, keys, values, pos, chunk, paged)
+        return fused_paged_verify_attention(qg, *cache, pos, chunk, paged)
     if paged is not None:
         from distributed_llama_tpu import telemetry
 
         telemetry.note_kernel_path("paged_attention", "xla_segmented")
-    cdt = kvc.compute_dtype(keys)
-    prec = kvc.einsum_precision(keys)
     live = jnp.clip(jnp.max(pos) + T, 0, S)
     n_chunks = jax.lax.div(live + chunk - 1, chunk)
     partial = _verify_partial(qg, pos, chunk, cdt, prec)
@@ -667,7 +680,7 @@ def batched_verify_attention(
     l0 = jnp.zeros((B, T, K, M), jnp.float32)
     o0 = jnp.zeros((B, T, K, M, hd), jnp.float32)
     m, l, o = _segmented_batched_scan(
-        partial, keys, values, paged, chunk, n_chunks, (m0, l0, o0), rows=B
+        partial, cache, paged, chunk, n_chunks, (m0, l0, o0), rows=B
     )
     return o / jnp.maximum(l, 1e-30)[..., None]
 

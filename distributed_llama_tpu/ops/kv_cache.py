@@ -402,14 +402,20 @@ def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int):
 # layer's K/V write is ONE dynamic_update_slice / scatter instead of the
 # historical (keys, values) pair. The leading axis is fully covered by
 # every write (index 0, extent 2), so XLA aliases the donated leaf in
-# place exactly like the tuple halves did; reads are static leading-index
-# slices (``fused[0]``/``fused[1]``) — contiguous views, no copy. PERF.md
-# names the per-layer update pair on the decode critical path; halving the
-# op count is the point. i8 fuses the same way (QuantizedKV with
-# [2, ...] data+scales: 2 updates per layer instead of 4). The tensor/
-# sequence/expert-parallel backends keep tuple halves (their cache
-# PartitionSpecs shard the unfused rank), so every update helper here
-# keeps its tuple form too.
+# place exactly like the tuple halves did. Reads: ``fused[0]``/``fused[1]``
+# are contiguous, but they are views WITHOUT a copy only while one fusion
+# consumes them (the full-S einsum of a small cache). A half that is the
+# operand of a loop with a dynamic bound — the chunk loops of the blocked
+# attention compile to ``while`` — is a buffer of its own: XLA materialised
+# both halves of the 16-row slab (2 x 67 MB) in every layer of every decode
+# step, two thirds of a step on the chip (PERF.md §5). So a blocked read
+# slices its chunk out of the LEAF inside the loop and splits the chunk
+# (:func:`slab_chunk`), and only one loop a layer reads the slab
+# (``ops.attention._segmented_batched_scan``). i8 fuses the same way
+# (QuantizedKV with [2, ...] data+scales: 2 updates per layer instead of
+# 4). The tensor/sequence/expert-parallel backends keep tuple halves
+# (their cache PartitionSpecs shard the unfused rank), so every update
+# helper here keeps its tuple form too.
 # ---------------------------------------------------------------------------
 
 
@@ -502,6 +508,43 @@ def fused_put_row(slab_leaf, row_leaf, row):
             ),
         )
     return jax.lax.dynamic_update_slice(slab_leaf, row_leaf[:, None], (0, row, 0, 0, 0))
+
+
+def slab_facts(cache_l):
+    """``(S, einsum operand dtype, einsum precision)`` of a layer's slab in
+    either stored form (fused leaf or ``(keys, values)`` tuple) — read off
+    shapes and dtypes, so nothing of the slab is sliced to learn them."""
+    half = cache_l if is_fused_leaf(cache_l) else cache_l[0]
+    return half.shape[-3], compute_dtype(half), einsum_precision(half)
+
+
+def slab_chunk(cache_l, start, n: int, rows: int):
+    """One chunk of the batched blocked attention, read out of a layer's
+    slab AS IT IS STORED: slots [start, start+n) of the first ``rows`` slab
+    rows -> ``(kc, vc)`` of [rows, n, K, hd]. ``cache_l`` is a fused leaf
+    [2, B_max, S, K, hd] (array or :class:`QuantizedKV`) or a ``(keys,
+    values)`` tuple (the tp backend's sharded slab); which one is seen from
+    the input (:func:`is_fused_leaf`). A fused leaf is sliced ONCE over its
+    leading 2-axis and the CHUNK is split by static index, so no half of
+    the whole slab is ever formed (see the fused-layout note above: a half
+    that feeds a loop is a copy). ``start`` may be traced."""
+    if not is_fused_leaf(cache_l):
+        keys, values = cache_l
+        return (
+            slice_rows_batched(keys, start, n, rows=rows),
+            slice_rows_batched(values, start, n, rows=rows),
+        )
+
+    def both(a):
+        return jax.lax.dynamic_slice(
+            a, (0, 0, start, 0, 0), (2, rows, n) + a.shape[3:]
+        )
+
+    if isinstance(cache_l, QuantizedKV):
+        d, s = both(cache_l.data), both(cache_l.scales)
+        return QuantizedKV(d[0], s[0]), QuantizedKV(d[1], s[1])
+    c = both(cache_l)
+    return c[0], c[1]
 
 
 def scores_einsum_verify(qg: jax.Array, keys, prec) -> jax.Array:

@@ -259,7 +259,7 @@ class TestBatchedBlockedAttention:
         values = jnp.asarray(rng.randn(B, S, K, hd).astype(np.float32))
         pos = jnp.asarray([0, 517, 1023], jnp.int32)
 
-        got = batched_decode_attention(qg, keys, values, pos, chunk)
+        got = batched_decode_attention(qg, (keys, values), pos, chunk)
 
         scores = jnp.einsum("bkmh,bskh->bkms", qg, keys) / np.sqrt(hd)
         mask = (jnp.arange(S)[None, :] <= pos[:, None])[:, None, None, :]
@@ -279,8 +279,8 @@ class TestBatchedBlockedAttention:
         keys = jnp.asarray(rng.randn(B_slab, S, K, hd).astype(np.float32))
         values = jnp.asarray(rng.randn(B_slab, S, K, hd).astype(np.float32))
         pos = jnp.asarray([100, 400], jnp.int32)
-        got = batched_decode_attention(qg, keys, values, pos, chunk)
-        want = batched_decode_attention(qg, keys[:B], values[:B], pos, chunk)
+        got = batched_decode_attention(qg, (keys, values), pos, chunk)
+        want = batched_decode_attention(qg, (keys[:B], values[:B]), pos, chunk)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 

@@ -14,6 +14,7 @@ All compiles happen in the test's own process.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from distributed_llama_tpu.formats.model_file import ArchType
+from distributed_llama_tpu.models import llama, sampling
+from distributed_llama_tpu.models.config import LlamaConfig
 from distributed_llama_tpu.ops import attention as att
 from distributed_llama_tpu.ops import collectives, q40
 
@@ -127,7 +131,7 @@ def test_paged_decode_attention_scan_compiles(one_chip, K, M):
 
     def f(qg, keys, values, pos, pool_k, pool_v, tables, matched):
         return att.batched_decode_attention(
-            qg, keys, values, pos, 512, paged=(pool_k, pool_v, tables, matched)
+            qg, (keys, values), pos, 512, paged=(pool_k, pool_v, tables, matched)
         )
 
     jax.jit(f).lower(**_paged_decode_args(one_chip, K, M)).compile()
@@ -149,6 +153,145 @@ def test_fused_paged_attention_kernel_compiles(one_chip, K, M):
         )
 
     jax.jit(f).lower(**_paged_decode_args(one_chip, K, M)).compile()
+
+
+# ---------------------------------------------------------------------------
+# The served batched programs at the benchmark cells' shapes (Mistral-7B
+# widths, 2 of its layers, the 16-row x 2048 bf16 slab, the 384-page pool):
+# between a step's cache write and its attention reads nothing of the slab's
+# size may form. At PR 23 a slice of both halves and two copies of them, per
+# layer per step, were two thirds of a decode step on the chip (PERF.md §5).
+# ---------------------------------------------------------------------------
+
+SERVED_LAYERS, SERVED_ROWS, SERVED_PAGES, SERVED_PAGE = 2, 16, 384, 64
+# ops whose result is their operand's buffer (or no buffer at all)
+_NO_BUFFER_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "conditional"}
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
+_COMPUTATION = re.compile(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+
+
+def _served_program_shapes(one_chip):
+    """(cfg, params, slab, pool, s) of the Mistral cells as shapes placed on
+    the described chip: what ``server.api`` builds for ``--dtype q40
+    --parallel 16 --max-seq-len 2048 --kv-pages 384``, two layers deep."""
+    cfg = LlamaConfig(
+        arch=ArchType.LLAMA, dim=4096, hidden_dim=14336, n_layers=SERVED_LAYERS,
+        n_heads=32, n_kv_heads=8, vocab_size=32000, seq_len=2048, head_size=128,
+        kv_dim=1024, rope_theta=1e6,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+
+    layer = dict(
+        qkv=_qm_shape(4096, 6144, one_chip), wo=_qm_shape(4096, 4096, one_chip),
+        gate_up=_qm_shape(4096, 28672, one_chip), down=_qm_shape(14336, 4096, one_chip),
+        rms_att=s((4096,), jnp.float32), rms_ffn=s((4096,), jnp.float32),
+    )
+    params = dict(
+        embedding=s((32000, 4096), jnp.float32), layers=[layer] * SERVED_LAYERS,
+        rms_final=s((4096,), jnp.float32), rope_table=s((2048, 64, 2), jnp.float32),
+        wcls=_qm_shape(4096, 32000, one_chip),
+    )
+    slab = placed(jax.eval_shape(
+        lambda: llama.init_batch_cache(cfg, SERVED_ROWS, dtype=jnp.bfloat16)))
+    pool = placed(jax.eval_shape(
+        lambda: llama.init_page_pool(cfg, SERVED_PAGES, SERVED_PAGE, dtype=jnp.bfloat16)))
+    return cfg, params, slab, pool, s
+
+
+def _slab_sized_results(hlo: str, half_elements: int):
+    """Read a compiled program's text: ``(in_place_writes, others)``, the
+    instructions outside fusions whose result holds a bf16 array of at least
+    half a slab leaf. A write is a dynamic-update-slice or scatter into the
+    leaf (alone or as a fusion's body): it updates its operand's buffer.
+    ``others`` are buffers of their own, each one pass over the slab."""
+    computations, name = {}, None
+    for line in hlo.splitlines():
+        m = None if line.startswith(" ") else _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+
+    def big(result_type: str) -> bool:
+        return any(
+            np.prod([int(d) for d in dims.split(",")]) >= half_elements
+            for dims in re.findall(r"bf16\[([\d,]+)\]", result_type)
+        )
+
+    def writes_in_place(op: str, line: str) -> bool:
+        if op in ("dynamic-update-slice", "scatter"):
+            return True
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        return op == "fusion" and called is not None and any(
+            (m := _INSTRUCTION.match(inner)) and big(m.group(2))
+            and m.group(3) in ("dynamic-update-slice", "scatter")
+            for inner in computations.get(called.group(1), ())
+        )
+
+    fused = {
+        m.group(1) for lines in computations.values() for line in lines
+        if " fusion(" in line and (m := re.search(r"calls=%?([\w.\-]+)", line))
+    }
+    writes, others = [], []
+    for comp, lines in computations.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            m = _INSTRUCTION.match(line)
+            if not m or m.group(3) in _NO_BUFFER_OPS or not big(m.group(2)):
+                continue
+            found = f"{comp}: %{m.group(1)} = {m.group(2)[:120]} {m.group(3)}"
+            (writes if writes_in_place(m.group(3), line) else others).append(found)
+    return writes, others
+
+
+def _assert_no_slab_sized_temporaries(compiled, slab):
+    leaf = jax.tree.leaves(slab)[0]
+    writes, others = _slab_sized_results(compiled.as_text(), leaf.size // 2)
+    # one in-place write a layer, or the reader above no longer sees the program
+    assert len(writes) == SERVED_LAYERS, writes
+    assert not others, "slab-sized buffers besides the cache write:\n" + "\n".join(others)
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "unpaged"])
+@pytest.mark.parametrize("rows", [1, SERVED_ROWS])
+def test_served_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch, rows, paged):
+    """``sampling.decode_chunk_batched[_paged]`` as the cells dispatch it
+    (bucket 1: ``single_stream``; bucket 16: ``chat_shared``, ``batch_decode``):
+    in the whole program, the scan's body included, only the per-layer cache
+    write has a result of half a slab leaf or more."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)  # steer the CPU branch
+    cfg, params, slab, pool, s = _served_program_shapes(one_chip)
+    head = (cfg, params, s((rows,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_))
+    sampler = (32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+               s((rows,), jnp.uint32))
+    if paged:
+        lowered = sampling.decode_chunk_batched_paged.lower(
+            *head, pool, *sampler, s((rows, 2048 // SERVED_PAGE), jnp.int32), s((rows,), jnp.int32))
+    else:
+        lowered = sampling.decode_chunk_batched.lower(*head, *sampler)
+    _assert_no_slab_sized_temporaries(lowered.compile(), slab)
+
+
+def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
+    """``sampling.spec_verify_chunk_batched_paged`` (``--spec-draft 4``, 16
+    rows): one forward per dispatch, so the whole program is the step."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    cfg, params, slab, pool, s = _served_program_shapes(one_chip)
+    rows, window = SERVED_ROWS, 5
+    compiled = sampling.spec_verify_chunk_batched_paged.lower(
+        cfg, params, s((rows, window), jnp.int32), slab, s((rows,), jnp.int32),
+        s((rows,), jnp.bool_), pool, s((rows,), jnp.int32), s((rows,), jnp.float32),
+        s((rows,), jnp.float32), s((rows,), jnp.int32), s((rows,), jnp.uint32),
+        s((rows, 2048 // SERVED_PAGE), jnp.int32), s((rows,), jnp.int32),
+    ).compile()
+    _assert_no_slab_sized_temporaries(compiled, slab)
 
 
 @pytest.mark.parametrize(

@@ -211,7 +211,7 @@ class TestFusedPagedAttention:
         paged = (pool_k, pool_v, tables, matched)
         # the dispatch default is the segmented scan (the path the chip
         # runs); the fused kernel is selected by its explicit entry point
-        ref = att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
+        ref = att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
         # tentpole (c): the double-buffered DMA schedule only reorders copy
         # issue/wait around unchanged compute — both arms bit-identical
         for db in (True, False):
@@ -248,7 +248,7 @@ class TestFusedPagedAttention:
             matched, jnp.asarray(rng.randint(0, S - T, B), jnp.int32)
         )
         paged = (pool_k, pool_v, tables, matched)
-        ref = att.batched_verify_attention(qg, keys, values, pos, chunk, paged=paged)
+        ref = att.batched_verify_attention(qg, (keys, values), pos, chunk, paged=paged)
         for db in (True, False):
             got = att.fused_paged_verify_attention(
                 qg, keys, values, pos, chunk, paged, double_buffer=db
@@ -257,7 +257,7 @@ class TestFusedPagedAttention:
         # transitivity: verify query t vs plain decode at pos+t
         t = 1
         dec = att.batched_decode_attention(
-            qg[:, t], keys, values, pos + t, chunk, paged=paged
+            qg[:, t], (keys, values), pos + t, chunk, paged=paged
         )
         # a handful of roundings per merge, at most S/chunk merges
         atol = 8 * np.finfo(np.float32).eps * float(jnp.max(jnp.abs(dec)))
@@ -282,7 +282,7 @@ class TestFusedPagedAttention:
                 jnp.asarray([8, 0], jnp.int32),
             )
             pos = jnp.asarray([20, 5], jnp.int32)
-            att.batched_verify_attention(qg, keys, values, pos, chunk, paged=paged)
+            att.batched_verify_attention(qg, (keys, values), pos, chunk, paged=paged)
             ctr = telemetry.REGISTRY.counter(
                 "dllama_kernel_path_total", labelnames=("kernel", "path")
             )
@@ -313,14 +313,14 @@ class TestFusedPagedAttention:
                 jnp.asarray([8, 0], jnp.int32),
             )
             pos = jnp.asarray([20, 5], jnp.int32)
-            att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
+            att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
             ctr = telemetry.REGISTRY.counter(
                 "dllama_kernel_path_total", labelnames=("kernel", "path")
             )
             assert ctr.labels(kernel="paged_attention", path="pallas_fused").value >= 1
             # unset, every platform takes the segmented scan
             monkeypatch.delenv("DLT_FUSED_PAGED")
-            att.batched_decode_attention(qg, keys, values, pos, chunk, paged=paged)
+            att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
             assert ctr.labels(kernel="paged_attention", path="xla_segmented").value >= 1
         finally:
             telemetry.reset()
@@ -335,7 +335,7 @@ class TestFusedPagedAttention:
         keys = _mk_half(rng, (B, S, K, hd), jnp.float32)
         values = _mk_half(rng, (B, S, K, hd), jnp.float32)
         pos = jnp.asarray([20, 5], jnp.int32)
-        out = att.batched_decode_attention(qg, keys, values, pos, chunk)
+        out = att.batched_decode_attention(qg, (keys, values), pos, chunk)
         assert out.shape == (B, K, M, hd)
 
 

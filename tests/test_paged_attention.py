@@ -117,9 +117,9 @@ class TestOpsBitParity:
             rng.randn(self.B, self.K, self.M, self.HD).astype(np.float32)
         )
         pos = jnp.asarray([40, 35], jnp.int32)
-        want = batched_decode_attention(qg, full, full, pos, self.CHUNK)
+        want = batched_decode_attention(qg, (full, full), pos, self.CHUNK)
         got = batched_decode_attention(
-            qg, aliased, aliased, pos, self.CHUNK,
+            qg, (aliased, aliased), pos, self.CHUNK,
             paged=(pool, pool, tables, m),
         )
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -133,9 +133,9 @@ class TestOpsBitParity:
             rng.randn(self.B, T, self.K, self.M, self.HD).astype(np.float32)
         )
         pos = jnp.asarray([30, 20], jnp.int32)
-        want = batched_verify_attention(qg, full, full, pos, self.CHUNK)
+        want = batched_verify_attention(qg, (full, full), pos, self.CHUNK)
         got = batched_verify_attention(
-            qg, aliased, aliased, pos, self.CHUNK,
+            qg, (aliased, aliased), pos, self.CHUNK,
             paged=(pool, pool, tables, m),
         )
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -153,6 +153,114 @@ class TestOpsBitParity:
             paged=(pool, pool, tables[0], m[0]),
         )
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    # -- the slab AS STORED: a fused [2, B_max, S, K, hd] leaf must read
+    # exactly like the (keys, values) tuple of its halves (PR 24: the chunk
+    # loops slice the leaf themselves; no half of a whole slab is formed)
+
+    B_MAX = 4  # the fused slab holds more rows than a dispatch bucket reads
+
+    def _fused_leaf(self, dtype, seed=3):
+        """A fused slab leaf with distinct keys and values in every slot."""
+        rng = np.random.RandomState(seed)
+        rows = jnp.asarray(
+            rng.randn(2, self.B_MAX, self.S, self.K, self.HD).astype(np.float32)
+        )
+        if kvc.is_quantized_cache_dtype(dtype):
+            q, s = jax.vmap(jax.vmap(kvc.quantize_rows))(rows)
+            return kvc.QuantizedKV(q, s)
+        return rows.astype(dtype)
+
+    @staticmethod
+    def _assert_same_bytes(got, want):
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(
+                np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32))
+            )
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, "i8"])
+    @pytest.mark.parametrize("rows", [1, 2, 4])
+    @pytest.mark.parametrize("chunk_index", [0, 1, 3], ids=["first", "middle", "last"])
+    def test_slab_chunk_reads_leaf_like_its_halves(self, dtype, rows, chunk_index):
+        leaf = self._fused_leaf(dtype)
+        start = jnp.int32(chunk_index * self.CHUNK)
+        read = jax.jit(kvc.slab_chunk, static_argnums=(2, 3))
+        got = read(leaf, start, self.CHUNK, rows)
+        want = read((leaf[0], leaf[1]), start, self.CHUNK, rows)
+        self._assert_same_bytes(got, want)
+        # and they are the slots asked for, keys apart from values
+        lo = chunk_index * self.CHUNK
+        for i in (0, 1):
+            self._assert_same_bytes(got[i], leaf[i][:rows, lo : lo + self.CHUNK])
+        assert got[0].shape == (rows, self.CHUNK, self.K, self.HD)
+
+    def _paged_for(self, dtype, matched):
+        """A pool + tables + matched for ``len(matched)`` rows; the pool holds
+        its own random pages (leaf and halves read the SAME pool, so parity
+        needs no byte agreement between pool and slab)."""
+        rng = np.random.RandomState(11)
+        pool = rng.randn(self.P, PAGE, self.K, self.HD).astype(np.float32)
+        if kvc.is_quantized_cache_dtype(dtype):
+            q, s = jax.vmap(kvc.quantize_rows)(jnp.asarray(pool))
+            pool = kvc.QuantizedKV(q, s)
+        else:
+            pool = jnp.asarray(pool).astype(dtype)
+        tables = rng.randint(0, self.P, (len(matched), self.S // PAGE)).astype(np.int32)
+        return pool, pool, jnp.asarray(tables), jnp.asarray(matched, jnp.int32)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, "i8"])
+    @pytest.mark.parametrize("matched", [None, [0, 0], [16, 8], [32, 32], [20, 0]])
+    def test_batched_decode_on_leaf_equals_on_halves(self, dtype, matched):
+        leaf = self._fused_leaf(dtype)
+        rng = np.random.RandomState(12)
+        qg = jnp.asarray(rng.randn(self.B, self.K, self.M, self.HD).astype(np.float32))
+        pos = jnp.asarray([60, 35], jnp.int32)
+        paged = None if matched is None else self._paged_for(dtype, matched)
+        run = jax.jit(lambda c: batched_decode_attention(qg, c, pos, self.CHUNK, paged=paged))
+        np.testing.assert_array_equal(
+            np.asarray(run(leaf)), np.asarray(run((leaf[0], leaf[1])))
+        )
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, "i8"])
+    @pytest.mark.parametrize("matched", [None, [16, 8], [32, 0]])
+    def test_batched_verify_on_leaf_equals_on_halves(self, dtype, matched):
+        leaf = self._fused_leaf(dtype)
+        rng = np.random.RandomState(13)
+        T = 3
+        qg = jnp.asarray(
+            rng.randn(self.B, T, self.K, self.M, self.HD).astype(np.float32)
+        )
+        pos = jnp.asarray([40, 33], jnp.int32)
+        paged = None if matched is None else self._paged_for(dtype, matched)
+        run = jax.jit(lambda c: batched_verify_attention(qg, c, pos, self.CHUNK, paged=paged))
+        np.testing.assert_array_equal(
+            np.asarray(run(leaf)), np.asarray(run((leaf[0], leaf[1])))
+        )
+
+    def test_paged_scan_reads_the_slab_in_one_loop(self):
+        """A leaf that feeds TWO loops is re-laid out for them on the chip
+        (PERF.md §5): with ``paged`` the mixed and the slab-only segment
+        share one ``while``; the pool-only loop does not take the slab."""
+        leaf = self._fused_leaf(jnp.bfloat16)
+        qg = jnp.zeros((self.B, self.K, self.M, self.HD), jnp.float32)
+        pos = jnp.asarray([60, 35], jnp.int32)
+        paged = self._paged_for(jnp.bfloat16, [16, 8])
+        jaxpr = jax.make_jaxpr(
+            lambda c: batched_decode_attention(qg, c, pos, self.CHUNK, paged=paged)
+        )(leaf)
+        slab_shape = leaf.shape
+        loops = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "while"]
+        assert len(loops) == 2
+        taking_slab = [
+            e for e in loops if any(getattr(v.aval, "shape", None) == slab_shape for v in e.invars)
+        ]
+        assert len(taking_slab) == 1
+        # and no equation outside the loops forms a half of the slab
+        assert not [
+            e for e in jaxpr.jaxpr.eqns
+            if any(getattr(v.aval, "shape", None) == slab_shape[1:] for v in e.outvars)
+        ]
 
     @pytest.mark.parametrize("dtype", [jnp.float32, "i8"])
     def test_virtual_rows_match_copied_slab(self, dtype):
