@@ -1,14 +1,13 @@
-"""The plain reference: the forward pass of a dense GQA transformer (Mistral)
-and of its sparse-expert sibling (Mixtral) in straightforward float32
-``jax.numpy``, with no kernel, no cache and no batching tricks, over weights
-dequantized from the file's raw Q40 bytes one layer at a time.
+"""The Llama lineage's plain reference: the forward pass of a dense GQA
+transformer (Mistral) and of its sparse-expert sibling (Mixtral) in
+straightforward float32 ``jax.numpy``, with no kernel, no cache and no
+batching tricks, over weights dequantized from the file's raw Q40 bytes one
+layer at a time; and what the ``.m`` file of either looks like.
 
 Departures from the published models, all forced by the file format under
 test: weights are Q40 blocks (dequantized exactly: value = scale * (nibble - 8)),
 the router is stored Q40 like every matrix, and the rope pairing is the
 header's (interleaved pairs for the llama layout, half-split for mixtral).
-Matmuls run at ``highest`` precision: on a TPU a float32 product is otherwise
-computed in bfloat16 passes.
 """
 
 from __future__ import annotations
@@ -19,32 +18,52 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.reference.qfile import ROPE_INTERLEAVED, QFile
+from benchmark.reference.ops import HI, matmul, rmsnorm
+from benchmark.reference.qfile import F32, Q40, named
 
-EPS = 1e-5
-HI = jax.lax.Precision.HIGHEST
-
-
-def dequant(raw: jax.Array) -> jax.Array:
-    """uint8 [d_out, n_blocks, 18] -> float32 [d_out, n_blocks * 32]. A block
-    is an f16 scale and 16 bytes; byte j holds value j in its low nibble and
-    value j + 16 in its high nibble, both offset by 8."""
-    lo16 = raw[..., 0].astype(jnp.uint16) | (raw[..., 1].astype(jnp.uint16) << 8)
-    scale = jax.lax.bitcast_convert_type(lo16, jnp.float16).astype(jnp.float32)
-    qs = raw[..., 2:]
-    lo = (qs & 0xF).astype(jnp.int32) - 8
-    hi = (qs >> 4).astype(jnp.int32) - 8
-    vals = jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32) * scale[..., None]
-    return vals.reshape(raw.shape[0], -1)
+ARCH_LLAMA, ARCH_MIXTRAL = 0xABCD00, 0xABCD02
+ROPE_INTERLEAVED, ROPE_HALF_SPLIT = 0, 1
 
 
-def rmsnorm(x, w):
-    return w * x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + EPS)
+def header(raw: dict[int, int]) -> dict:
+    """The header's numbered values under their names, with what follows
+    from them; refuses what this reference cannot read."""
+    h = {"n_experts": 0, "n_active_experts": 0, "rope_type": -1, **named(raw)}
+    if h["weights_float_type"] != Q40 or h["hidden_act"] != 1:
+        raise ValueError("the reference reads Q40 weights with SiLU only")
+    if h["arch"] not in (ARCH_LLAMA, ARCH_MIXTRAL):
+        raise ValueError(f"unknown architecture {h['arch']:#x}")
+    if h["rope_type"] < 0:
+        h["rope_type"] = ROPE_INTERLEAVED if h["arch"] == ARCH_LLAMA else ROPE_HALF_SPLIT
+    h["head_dim"] = h["dim"] // h["n_heads"]
+    h["kv_dim"] = h["head_dim"] * h["n_kv_heads"]
+    return h
 
 
-def matmul(x, raw):
-    """y = x @ W.T for a Q40 matrix W [d_out, d_in]."""
-    return jnp.einsum("...i,oi->...o", x, dequant(raw), precision=HI)
+def layout(h: dict):
+    """(name, shape, kind) of every tensor, in file order."""
+    dim, hid, kv, vocab = h["dim"], h["hidden_dim"], h["kv_dim"], h["vocab_size"]
+    yield "embedding", (vocab, dim), F32
+    for l in range(h["n_layers"]):
+        p = f"layers.{l}."
+        yield p + "q", (dim, dim), Q40
+        yield p + "k", (kv, dim), Q40
+        yield p + "v", (kv, dim), Q40
+        yield p + "wo", (dim, dim), Q40
+        if h["n_experts"]:
+            yield p + "moe_router", (h["n_experts"], dim), Q40
+            for e in range(h["n_experts"]):
+                yield f"{p}experts.{e}.up", (hid, dim), Q40
+                yield f"{p}experts.{e}.gate", (hid, dim), Q40
+                yield f"{p}experts.{e}.down", (dim, hid), Q40
+        else:
+            yield p + "gate", (hid, dim), Q40
+            yield p + "down", (dim, hid), Q40
+            yield p + "up", (hid, dim), Q40
+        yield p + "rms_att", (dim,), F32
+        yield p + "rms_ffn", (dim,), F32
+    yield "rms_final", (dim,), F32
+    yield "wcls", (vocab, dim), Q40
 
 
 def rope(x, theta: float, interleaved: bool):
@@ -102,7 +121,7 @@ def head(x, rms, wcls):
     return matmul(rmsnorm(x, rms), wcls)
 
 
-def forward(qf: QFile, tokens: np.ndarray, positions: np.ndarray,
+def forward(qf, tokens: np.ndarray, positions: np.ndarray,
             router_gaps: list | None = None) -> np.ndarray:
     """Logits [B, len(positions), vocab] after a full causal pass over
     ``tokens`` [B, T]; layers are streamed from the file one at a time. A

@@ -10,6 +10,7 @@ and has no way to pass anything else.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import shutil
@@ -21,44 +22,11 @@ import sys
 import threading
 import time
 
-from benchmark.harness import bytes_model, client, prom, readers, stats, traffic
+from benchmark import families
+from benchmark.harness import client, prom, readers, stats, traffic
 
-# The reference check (PERF.md, PR 22, "How close"). The served path returns
-# no logits, so what is compared is the served greedy TOKEN, teacher-forced:
-# at every answered position the float32 reference scores the same context
-# and says how far the served token lies below its own best, as a share of
-# max|logit| (the DEFICIT; 0 where the tokens are equal).
-#
-# What a faithful engine shows (full width, the chip's kernels on the CPU and
-# a float32 copy of the reference with the engine's roundings): the Q80
-# rounding of the activations into every Q40 matmul puts its logits 1.6e-3
-# (2 layers) to 3.5e-3 (16 layers) rms of max|logit| from float32, 1.6e-2 at
-# the worst of 32000. A deficit needs the errors of two logits to differ by
-# more than their margin: the largest of 1280 positions was 5.7e-3.
-LOGIT_TOL = 2e-2  # max over the vocabulary of |engine logit - reference|; tools/logit_check.py
-MISS_TOL = 1e-2  # a position whose deficit is over this is a MISS
-# Misses allowed: 3 % of the positions compared, for the tail of the rounding
-# at a near-tie: on the chip 1 of the first 512 positions of the dense cells
-# was a miss (1.36e-2, the reference's second choice), on the CPU 1 of 1024
-# (1.7e-2). 3 % of 8 probes' 256 positions is 7: fewer than the probes, so a
-# fault at ONE position of every probe (a page boundary, the first token)
-# fails. Activations at 3 mantissa bits miss 12 to 23 of 256, a dropped layer
-# 96 or more.
-MAX_MISS_SHARE = 0.03
-# A sparse-expert router is discontinuous: where the last expert kept and the
-# first one dropped are a near-tie, rounding swaps them and the logits jump
-# (30 of 1024 positions of a faithful 4-layer Mixtral, 7 of them misses, up to
-# 6.7e-2). So a position is not compared where the reference's own routing
-# gap, in any layer, is under ROUTER_TIE of max|router logit|: a quarter of the
-# positions at 4 layers, and with them every swap and every miss of the 1024.
-ROUTER_TIE = 2e-2
-# In a dense model, which has no such jump, no position at all may be off by
-# more than this.
-DENSE_HARD_TOL = 3e-2
-PROBES, PROBE_PROMPT, PROBE_TOKENS = 8, 64, 32  # 32: one decode chunk, whatever is asked
-# an answer is compared where its text says its tokens for certain (traffic.answer_ids: 5 of
-# 6 answers at a vocabulary of 32000); routing near-ties go too
-MIN_COMPARED = PROBES * PROBE_TOKENS // 4
+# The reference check's numbers (probe count and lengths, the rule's tolerances) are data:
+# ``benchmark/check.json`` holds each with its reason, ``load_check`` reads them.
 TRACE_SECONDS = 2.0  # 5 s of a 16-layer server's ops crashed the profiler at stop_trace (PR 22)
 # --trace 2: the lead-in of the traced phase, at most (the mix's own if shorter): long enough
 # for the rows to refill and, in a closed loop, for the callers to fall out of step again
@@ -71,11 +39,33 @@ class BenchFailure(RuntimeError):
 
 def log(msg: str) -> None:
     print(msg, flush=True)
+    if msg.startswith("[check]"):
+        # each number compared beside its limit: the last lines of standard error too, which is
+        # what a record keeps of a run that was not correct
+        print(msg, file=sys.stderr, flush=True)
 
 
 def load_json(path: str):
     with open(path) as f:
         return json.load(f)
+
+
+def load_check(bench_dir: str = families.BENCH_DIR, config: dict | None = None) -> dict:
+    """The reference check's numbers: the defaults of ``<bench_dir>/check.json``,
+    each replaced by the value a configuration's ``check`` block gives it. The
+    block carries its reason under ``why``; a name the defaults do not have is
+    an error. ``min_compared``, the positions a verdict needs, follows."""
+    check = {k: v["value"] for k, v in load_json(os.path.join(bench_dir, "check.json")).items()}
+    override = dict((config or {}).get("check") or {})
+    if override:
+        why = override.pop("why", "")
+        unknown = sorted(set(override) - set(check))
+        if unknown or not (isinstance(why, str) and why.strip()):
+            raise BenchFailure(f"configuration {config.get('name')!r}: its check block needs a "
+                               f"\"why\" and may set {sorted(check)} only, not {unknown}")
+        check.update(override)
+    check["min_compared"] = int(check["probes"] * check["probe_tokens"] * check["min_compared_share"])
+    return check
 
 
 class Cell:
@@ -92,7 +82,13 @@ class Cell:
         self.chips = int(entry["chips"])
         self.launch = load_json(os.path.join(self.dir, "workloads", f"{workload}.json"))
         cfg_entry = next(c for c in self.bench["configs"] if c["name"] == entry["config"])
-        self.config = load_json(os.path.join(root, cfg_entry["file"]))
+        self.config_path = os.path.join(root, cfg_entry["file"])
+        self.config = load_json(self.config_path)
+        try:
+            self.counts = families.counts(self.config, self.dir)  # and every key is known to it
+        except families.FamilyError as e:
+            raise BenchFailure(str(e)) from None
+        self.check = load_check(self.dir, self.config)
         self.mix = load_json(os.path.join(self.dir, "traffic", f"{entry['traffic']}.json"))
         for key in ("config", "traffic", "chips"):
             if self.launch[key] != entry[key]:
@@ -224,8 +220,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: int,
     server = None
     try:
         t = time.monotonic()
-        model, tokenizer = modelfile.write_artifacts(cell.config, seed, model_dir,
-                                                     cell.config["max_position_embeddings"])
+        model, tokenizer = modelfile.write_artifacts(
+            cell.config, seed, model_dir, cell.config["max_position_embeddings"], cell.dir)
         size_gb = os.path.getsize(model) / 1e9
         log(f"[setup] {size_gb:.2f} GB model file from seed {seed} in {time.monotonic() - t:.1f} s")
         t = time.monotonic()
@@ -249,7 +245,8 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
              seed: int, seconds: float, trace: int, require_platform: str,
              t_process: float) -> dict:
     # probes: alone, not streamed, prefix cache off; they are also the token-count check
-    probes = traffic.probe_requests(seed, PROBES, PROBE_PROMPT, PROBE_TOKENS)
+    check = cell.check
+    probes = traffic.probe_requests(seed, check["probes"], check["probe_prompt"], check["probe_tokens"])
     t = time.monotonic()
     answers = [_probe(server, p) for p in probes]
     for p, a in zip(probes, answers):
@@ -257,7 +254,7 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
             raise BenchFailure(f"the generator counts {p.prompt_tokens} prompt tokens, the "
                                f"server {a.prompt_tokens}: one character is not one token")
     building = sum(e["seconds"] for e in server.control("/compiles")["events"])
-    log(f"[setup] {PROBES} probes answered in {time.monotonic() - t:.1f} s, {building:.1f} s of it "
+    log(f"[setup] {len(probes)} probes answered in {time.monotonic() - t:.1f} s, {building:.1f} s of it "
         f"building or loading programs; prompt and completion token counts agree with usage")
 
     # the reference scores the answers on the host's cores while the warm-up,
@@ -420,26 +417,28 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
     return result
 
 
-def judge_probes(rows: list[dict]) -> tuple[bool, str]:
+def judge_probes(rows: list[dict], check: dict) -> tuple[bool, str]:
     """The verdict on the probes' positions. ``rows``: per answered position
     the served token, the reference's best, the served token's deficit and,
-    from a sparse-expert model, the position's routing gap."""
+    from a sparse-expert model, the position's routing gap. ``check``: the
+    rule's numbers (``load_check``; ``check.json`` says what each is for)."""
     answered = len(rows)
     dense = all(r.get("router_gap") is None for r in rows)
-    rows = [r for r in rows if r.get("router_gap") is None or r["router_gap"] >= ROUTER_TIE]
+    rows = [r for r in rows if r.get("router_gap") is None or r["router_gap"] >= check["router_tie"]]
     ties = answered - len(rows)
-    if len(rows) < MIN_COMPARED:
+    if len(rows) < check["min_compared"]:
         return False, (f"only {len(rows)} positions could be compared ({ties} more are routing "
-                       f"near-ties), {MIN_COMPARED} are needed")
+                       f"near-ties), {check['min_compared']} are needed")
     equal = sum(1 for r in rows if r["server"] == r["reference"])
-    misses = [r for r in rows if r["deficit"] > MISS_TOL]
+    misses = [r for r in rows if r["deficit"] > check["miss_tol"]]
     worst = max(r["deficit"] for r in rows)
-    allowed = int(MAX_MISS_SHARE * len(rows))
-    ok = len(misses) <= allowed and (not dense or worst <= DENSE_HARD_TOL)
+    allowed = int(check["max_miss_share"] * len(rows))
+    ok = len(misses) <= allowed and (not dense or worst <= check["dense_hard_tol"])
     note = (f"{len(rows)} positions compared" + (f" ({ties} routing near-ties left out)" if ties else "")
             + f": {equal} equal the reference's greedy token, "
-            f"{len(misses)} over {MISS_TOL:.0e} of max|logit| below its best ({allowed} allowed); "
-            f"worst {worst:.2e}" + (f" (a dense model: at most {DENSE_HARD_TOL:.0e})" if dense else ""))
+            f"{len(misses)} over {check['miss_tol']:.0e} of max|logit| below its best ({allowed} allowed); "
+            f"worst {worst:.2e}"
+            + (f" (a dense model: at most {check['dense_hard_tol']:.0e})" if dense else ""))
     return ok, note
 
 
@@ -449,7 +448,7 @@ class _Reference:
     server's own tokens."""
 
     def __init__(self, cell: Cell, model: str, cache: str, probes: list, answers: list):
-        self.proc, self.skipped = None, 0
+        self.proc, self.skipped, self.check = None, 0, cell.check
         items = []
         for p, a in zip(probes, answers):
             ids = traffic.answer_ids(a.text or "", len(a.deltas))
@@ -464,7 +463,8 @@ class _Reference:
             json.dump(items, f)
         self._err = open(os.path.join(cache, "reference.log"), "w+")
         self.proc = subprocess.Popen(
-            [sys.executable, "-m", "benchmark.reference.probe_child", model, probes_path, self.out_path],
+            [sys.executable, "-m", "benchmark.reference.probe_child", cell.dir, cell.config_path,
+             model, probes_path, self.out_path],
             cwd=cell.root, env=dict(_child_env(cell.root, cache), JAX_PLATFORMS="cpu"),
             stdout=self._err, stderr=subprocess.STDOUT)
 
@@ -494,12 +494,12 @@ class _Reference:
         rows = [dict(r, probe=i, position=j) for i, probe in enumerate(out["probes"])
                 for j, r in enumerate(probe)]
         for r in rows:
-            if r["deficit"] > MISS_TOL:
-                tie = r["router_gap"] is not None and r["router_gap"] < ROUTER_TIE
+            if r["deficit"] > self.check["miss_tol"]:
+                tie = r["router_gap"] is not None and r["router_gap"] < self.check["router_tie"]
                 log(f"[check] over the miss line{' (a routing near-tie, left out)' if tie else ''}: "
                     f"{json.dumps(r)}")
         margins = sorted(r["margin"] for r in rows)
-        ok, note = judge_probes(rows)
+        ok, note = judge_probes(rows, self.check)
         return ok, (f"{note}; {self.skipped} answered positions not read back as tokens; the "
                     f"reference's top-1/top-2 margin: median {margins[len(margins) // 2]:.2e}; "
                     f"{out['seconds']:.1f} s on the host beside the warm-up")
@@ -626,9 +626,12 @@ def _trace_facts(cell: Cell, cache: str, trace_dir: str, device: dict, records: 
     peaks = load_json(os.path.join(cell.dir, "peaks.json"))
     if device["kind"] not in peaks:
         raise BenchFailure(f"no published peaks for device kind {device['kind']!r}")
-    peak_bw = peaks[device["kind"]]["hbm_bytes_per_s"]
+    peaks = peaks[device["kind"]]
     log(f"[trace] {red['window_s']:.2f} s traced, device busy {red['busy_s']:.2f} s; modules "
         f"{json.dumps({k: [v['count'], round(v['seconds'], 3)] for k, v in red['modules'].items()})}")
+    longest = sorted(red["ops"].items(), key=lambda kv: kv[1]["seconds"], reverse=True)[:12]
+    log(f"[trace] longest ops (launches, seconds): "
+        f"{json.dumps({k: [v['count'], round(v['seconds'], 4)] for k, v in longest})}")
     facts = {
         "gen.prompt_tokens_in_window": float(sum(sent_prompt[r.index] for r in records
                                                  if w0 <= r.sent < w1)),
@@ -640,7 +643,9 @@ def _trace_facts(cell: Cell, cache: str, trace_dir: str, device: dict, records: 
         "trace.device_ops": red["device_ops"],
         "trace.idle_gaps": red["idle_gaps"],
         "trace.modules": red["modules"],
-        "peaks.hbm_bytes_per_s": peak_bw,
+        "trace.ops": red["ops"],
+        "peaks": peaks,
+        "model.kernel_launch": functools.partial(cell.counts.kernel_launch, cell.config),
     }
     # the decode step's share of the memory roofline, over the traced span:
     # rows and live context as the client saw them in that span
@@ -656,7 +661,7 @@ def _trace_facts(cell: Cell, cache: str, trace_dir: str, device: dict, records: 
     facts["gen.live_rows"] = rows
     facts["gen.live_positions"] = positions
     facts["server.decode_chunk"] = float(device["decode_chunk"])
-    facts["model.decode_step_bytes"] = bytes_model.decode_step_bytes(
+    facts["model.decode_step_bytes"] = cell.counts.decode_step_bytes(
         cell.config, max(1.0, rows), positions)
     log(f"[trace] decode step floor: {facts['model.decode_step_bytes'] / 1e9:.3f} GB at "
         f"{rows:.1f} live rows and {positions:.0f} live positions")

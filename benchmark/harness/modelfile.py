@@ -5,6 +5,12 @@ that a later change to the package's synthetic writer cannot move them.
 Imports numpy and the package's format modules only: no JAX, so the parent
 may call it.
 
+The drawing rules below are one piece of code for every family of
+architectures. What a family supplies (``families/<family>/modelfile.py``):
+the configuration's ``ModelSpec``, each tensor's ROLE under these rules, and
+the draw of any tensor that has none (a filter, a decay, a bias). A tensor
+with neither is an error, not a default.
+
 How the weights are drawn, and why (PERF.md, PR 22, "How close"):
 
 * every Q40 value is ``scale * v`` with ``v`` SYMMETRIC about zero: the 16
@@ -35,34 +41,17 @@ import os
 
 import numpy as np
 
+from benchmark import families
 from benchmark.harness.traffic import FIRST_FILLER_ID
 
-_ARCH = {"llama": "LLAMA", "mixtral": "MIXTRAL"}
 RESIDUAL_GAIN = 0.5
+# role -> gain of a Q40 matrix: "residual" are the matrices that write into the residual stream
+_Q40_GAIN = {"matrix": 1.0, "head": 1.0, "residual": RESIDUAL_GAIN}
 # byte -> byte with each nibble's code 0 (value -8) rewritten to code 8 (value 0)
 _SYMMETRIC = np.array([(b | (0x08 if b & 0x0F == 0 else 0) | (0x80 if b & 0xF0 == 0 else 0))
                        for b in range(256)], np.uint8)
 # values -7..7 once each and 0 twice, of 16 codes
 _VALUE_STD = float(np.sqrt(2 * sum(v * v for v in range(1, 8)) / 16.0))
-
-
-def model_spec(config: dict, seq_len: int):
-    from distributed_llama_tpu.formats.model_file import ArchType, HiddenAct, ModelSpec, RopeType
-    from distributed_llama_tpu.quants import FloatType
-
-    if config.get("hidden_act", "silu") != "silu":
-        raise ValueError("only silu configurations are known to this builder")
-    arch = ArchType[_ARCH[config["arch"]]]
-    return ModelSpec(
-        arch_type=arch, dim=config["hidden_size"], hidden_dim=config["intermediate_size"],
-        n_layers=config["num_hidden_layers"], n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"], vocab_size=config["vocab_size"],
-        seq_len=seq_len, n_experts=config.get("num_local_experts", 0),
-        n_active_experts=config.get("num_experts_per_tok", 0), hidden_act=HiddenAct.SILU,
-        rope_theta=float(config["rope_theta"]),
-        rope_type=RopeType.LLAMA if arch == ArchType.LLAMA else RopeType.FALCON,
-        weights_float_type=FloatType.Q40,
-    )
 
 
 def q40_blocks(rng: np.random.Generator, n_blocks: int, d_in: int, gain: float) -> np.ndarray:
@@ -78,30 +67,41 @@ def q40_blocks(rng: np.random.Generator, n_blocks: int, d_in: int, gain: float) 
     return blocks
 
 
-def write_model(path: str, spec, seed: int) -> str:
-    """The seeded Q40 ``.m`` for ``spec``: the same seed gives the same bytes."""
+def write_model(path: str, config: dict, seq_len: int, seed: int,
+                bench_dir: str = families.BENCH_DIR) -> str:
+    """The seeded Q40 ``.m`` of ``config``: the same seed gives the same bytes.
+    One generator draws the tensors in file order, each by its role."""
     from distributed_llama_tpu.formats.model_file import ModelFileWriter
     from distributed_llama_tpu.quants import FloatType
 
+    family = families.load(config, "modelfile", bench_dir)
     rng = np.random.default_rng(seed)
     with open(path, "wb") as f:
-        w = ModelFileWriter(f, spec)
+        w = ModelFileWriter(f, family.model_spec(config, seq_len))
         for e in list(w.remaining()):
-            if e.float_type == FloatType.Q40:
-                gain = RESIDUAL_GAIN if e.name.endswith((".wo", ".down")) else 1.0
-                blocks = q40_blocks(rng, e.n_values // 32, e.shape[-1], gain)
-                if e.name == "wcls":
+            role = family.role(e.name)
+            if role in _Q40_GAIN and e.float_type == FloatType.Q40:
+                blocks = q40_blocks(rng, e.n_values // 32, e.shape[-1], _Q40_GAIN[role])
+                if role == "head":
                     blocks[:FIRST_FILLER_ID * (e.shape[-1] // 32), :2] = 0  # scale 0: the row is 0
                 w.write_raw(blocks, e.name)
-            elif "rms" in e.name:
+            elif role == "norm" and e.float_type == FloatType.F32:
                 w.write_tensor(1.0 + 0.1 * rng.standard_normal(e.shape, dtype=np.float32), e.name)
-            else:  # the embedding
+            elif role == "embedding" and e.float_type == FloatType.F32:
                 w.write_tensor(rng.standard_normal(e.shape, dtype=np.float32), e.name)
+            elif role is None and hasattr(family, "draw"):
+                w.write_tensor(family.draw(e, rng), e.name)
+            else:
+                raise families.FamilyError(
+                    f"tensor {e.name!r} ({e.float_type.name}) of configuration {config.get('name')!r}: "
+                    f"role {role!r} is no drawing rule for it, and family "
+                    f"{families.family_of(config)!r} draws none of its own")
         w.finish()
     return path
 
 
-def write_artifacts(config: dict, seed: int, directory: str, seq_len: int) -> tuple[str, str]:
+def write_artifacts(config: dict, seed: int, directory: str, seq_len: int,
+                    bench_dir: str = families.BENCH_DIR) -> tuple[str, str]:
     """Write ``<config>.m`` and ``<config>.t`` for ``seed`` into ``directory``
     (one seed's files at a time: they are gigabytes). Returns their paths."""
     from distributed_llama_tpu.formats.synthetic import synthetic_tokenizer_data
@@ -112,5 +112,5 @@ def write_artifacts(config: dict, seed: int, directory: str, seq_len: int) -> tu
     tokenizer = os.path.join(directory, f"{config['name']}.t")
     with open(tokenizer, "wb") as f:
         write_tokenizer_file(f, synthetic_tokenizer_data(vocab_size=config["tokenizer_vocab"]))
-    write_model(model, model_spec(config, seq_len), seed)
+    write_model(model, config, seq_len, seed, bench_dir)
     return model, tokenizer

@@ -19,7 +19,8 @@ from benchmark.harness import prom
 class Context:
     """What a traced run collected: ``before``/``after`` are parsed /metrics
     scrapes at the window's edges, ``facts`` the named values from the
-    generator, the control thread, the trace reduction and the bytes model."""
+    generator, the control thread, the trace reduction, the table of peaks
+    and the counts of the configuration's family."""
 
     def __init__(self, before: list, after: list, facts: dict):
         self.before, self.after, self.facts = before, after, facts
@@ -41,14 +42,36 @@ def _read(spec: dict, ctx: Context) -> float | None:
                 if re.search(spec["modules"], k)]
         busy = sum(m["seconds"] for m in mods)
         steps = sum(m["count"] for m in mods) * ctx.facts.get(spec["steps_per_call"], 0.0)
-        value = (100.0 * ctx.facts[spec["bytes"]] * steps / busy / ctx.facts["peaks.hbm_bytes_per_s"]
+        value = (100.0 * ctx.facts[spec["bytes"]] * steps / busy / ctx.facts["peaks"]["hbm_bytes_per_s"]
                  if busy and steps else None)
+    elif kind == "kernel_roofline":
+        value = _kernel_roofline(spec, ctx.facts)
     elif kind == "ratio":
         num, den = _read(spec["num"], ctx), _read(spec["den"], ctx)
         value = num / den if num is not None and den else None
     else:
         raise ValueError(f"unknown reader kind {kind!r}")
     return None if value is None else value * spec.get("scale", 1.0)
+
+
+def _kernel_roofline(spec: dict, facts: dict) -> float | None:
+    """The share of their roofline that the device ops named like ``ops`` (a
+    regex with a group ``role``) ran at: for each, the least time one launch
+    could take (the larger of its bytes over the chip's bandwidth and its
+    operations over the peak rate the reader names; both counted by the
+    configuration's family from the role and the result shape in the op's
+    name) times its launches, over the device seconds the trace gives it."""
+    launch, peaks = facts["model.kernel_launch"], facts["peaks"]
+    least = seconds = 0.0
+    for name, op in (facts.get("trace.ops") or {}).items():
+        found = re.search(spec["ops"], name)
+        if found is None:
+            continue
+        shape = [int(n) for n in re.search(r"\[([\d,]*)\]", name).group(1).split(",")]
+        nbytes, operations = launch(found.group("role"), shape)
+        least += op["count"] * max(nbytes / peaks["hbm_bytes_per_s"], operations / peaks[spec["rate"]])
+        seconds += op["seconds"]
+    return 100.0 * least / seconds if seconds else None
 
 
 def read_metric(directory: str, name: str, ctx: Context) -> tuple[float | None, str]:

@@ -10,6 +10,7 @@ the chip) it prints the reduction as one JSON object.
 
 from __future__ import annotations
 
+import collections
 import glob
 import json
 import os
@@ -107,7 +108,7 @@ def reduce_plane(lines: dict) -> dict:
     modules = sorted(lines.get(MODULES_LINE) or [], key=lambda e: e[1])
     every = [e for evs in lines.values() for e in evs]
     if not every:
-        return {"window_ns": 0, "busy_ns": 0, "ops": {}, "modules": {}, "gaps": {}}
+        return {"window_ns": 0, "busy_ns": 0, "ops": {}, "launches": {}, "modules": {}, "gaps": {}}
     t0 = min(e[1] for e in every)
     t1 = max(e[1] + e[2] for e in every)
     # an op runs on the device; where a trace has no ops line, a module does
@@ -140,6 +141,7 @@ def reduce_plane(lines: dict) -> dict:
         "window_ns": t1 - t0,
         "busy_ns": sum(b - a for a, b in busy),
         "ops": _by_kind(_self_times(ops)),
+        "launches": dict(collections.Counter(_op_name(e[0]) for e in ops)),
         "modules": mods,
         "gaps": gaps,
     }
@@ -165,6 +167,10 @@ def reduce(planes: dict, chips: int) -> dict:
         "modules": {k: {"count": v["count"], "seconds": v["ns"] / 1e9}
                     for k, v in first["modules"].items()},
         "device_ops": [[k, v / 1e9] for k, v in top_ops],
+        # every kind of op, for the readers that look for a kernel by name: its events on the
+        # line (a kernel's launches) and its self time
+        "ops": {k: {"count": first["launches"][k], "seconds": v / 1e9}
+                for k, v in first["ops"].items()},
         "idle_gaps": [[k, v / 1e9] for k, v in top_gaps],
         "planes_used": used,
     }

@@ -269,8 +269,9 @@ def warmup_waves(mix: dict, seed: int, rows: int, pool_tokens: int) -> list[list
     """Waves of unmeasured requests that touch every shape the mix can make
     the server compile; a wave's requests are sent ``due_s`` after the wave
     starts and the next wave waits for all of them. First, alone, one prompt
-    for each power-of-two count of pages a prefill publishes and one whose
-    tail covers each prefill bucket (8..256 rows). Then, where the mix says
+    for each power-of-two count of pages a prefill publishes (1..16, and on
+    doubling while a prompt of that many pages fits the mix's ``prompt_cap``)
+    and one whose tail covers each prefill bucket (8..256 rows). Then, where the mix says
     ``warm_pool_overflow`` (its traffic fills the page pool within a run),
     enough unique long prompts to overflow the pool of ``pool_tokens``
     positions: the evictor's spill program runs, and the pool is left full,
@@ -286,6 +287,10 @@ def warmup_waves(mix: dict, seed: int, rows: int, pool_tokens: int) -> list[list
 
     cap = int(mix.get("prompt_cap", int(mix["context_cap"]) - 64))
     lengths = [64 * pages + 20 for pages in (1, 2, 4, 8, 16)]
+    pages = 32
+    while 64 * pages + 20 <= cap:  # a mix of longer prompts publishes more pages at once
+        lengths.append(64 * pages + 20)
+        pages *= 2
     # the prefill program is keyed by its chunk's padded rows alone: one tail per bucket
     lengths += [256 + b - 3 for b in (8, 16, 32, 64, 128, 256)]
     if mix.get("warm_pool_overflow"):

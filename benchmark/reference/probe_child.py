@@ -4,7 +4,11 @@ server's own tokens, so every answered position is compared against the
 reference's logits for the same context. It leaves two cores to the server
 and the load generator.
 
-    JAX_PLATFORMS=cpu python -m benchmark.reference.probe_child <model.m> <probes.json> <out.json>
+    JAX_PLATFORMS=cpu python -m benchmark.reference.probe_child <bench_dir> <config.json> \
+        <model.m> <probes.json> <out.json>
+
+The forward pass and the file's layout are those of the family that the
+configuration's file names, found under ``<bench_dir>/families/``.
 
 ``probes.json``: [{"prompt": [ids], "answer": [ids]}]. ``out.json``: the seconds it took, and per probe
 and answered position the reference's best token, how far the server's token
@@ -51,7 +55,7 @@ def score(logits, answers: list[list[int]], router_gaps: list | None = None) -> 
 
 def main(argv: list[str]) -> int:
     t0 = time.monotonic()
-    model, probes_path, out_path = argv
+    bench_dir, config_path, model, probes_path, out_path = argv
     cores = sorted(os.sched_getaffinity(0))
     if len(cores) > 4:
         os.sched_setaffinity(0, cores[2:])
@@ -62,12 +66,14 @@ def main(argv: list[str]) -> int:
         print("reference: run with JAX_PLATFORMS=cpu, the chip is the server's", file=sys.stderr)
         return 3
 
-    from benchmark.reference.model import forward
+    from benchmark import families
     from benchmark.reference.qfile import QFile
 
+    with open(config_path) as f:
+        family = families.load(json.load(f), "reference", bench_dir)
     with open(probes_path) as f:
         probes = json.load(f)
-    qf = QFile(model)
+    qf = QFile(model, family)
     n_prompt = len(probes[0]["prompt"])
     if any(len(p["prompt"]) != n_prompt for p in probes):
         raise ValueError("probes must share one prompt length")
@@ -79,7 +85,7 @@ def main(argv: list[str]) -> int:
     # position n_prompt - 1 + j predicts answer token j
     positions = np.arange(n_prompt - 1, n_prompt - 1 + n_ans)
     gaps: list = []
-    logits = forward(qf, tokens, positions, gaps)
+    logits = family.forward(qf, tokens, positions, gaps)
     out = score(logits, [p["answer"] for p in probes], gaps)
     with open(out_path, "w") as f:
         json.dump({"seconds": time.monotonic() - t0, "probes": out}, f)

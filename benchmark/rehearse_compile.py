@@ -36,6 +36,7 @@ def rehearse(name: str) -> bool:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    from benchmark import families
     from benchmark.harness import modelfile
     from distributed_llama_tpu.engine import InferenceEngine
     from distributed_llama_tpu.engine import batch
@@ -47,6 +48,7 @@ def rehearse(name: str) -> bool:
     # the tree's shapes from a ONE-layer file loaded on the CPU; every layer has them
     one = dict(config, num_hidden_layers=1, name=f"{name}.1l")
     directory = os.path.join(ROOT, "benchmark", ".cache", "rehearse")
+    families.counts(config)  # a configuration its family does not know stops here, by name
     model, _ = modelfile.write_artifacts(one, 0, directory, config["max_position_embeddings"])
     engine = InferenceEngine(model, dtype="q40", max_seq_len=SEQ)
     os.remove(model)

@@ -39,8 +39,8 @@ def main(argv: list[str]) -> int:
     cache = os.path.join(cell.dir, ".cache")
     model_dir = os.path.join(cache, "model")
     shutil.rmtree(model_dir, ignore_errors=True)
-    model, tokenizer = modelfile.write_artifacts(cell.config, seed, model_dir,
-                                                 cell.config["max_position_embeddings"])
+    model, tokenizer = modelfile.write_artifacts(
+        cell.config, seed, model_dir, cell.config["max_position_embeddings"], cell.dir)
     server = Server(cell, model, tokenizer, cache, "tpu")
     try:
         server.wait_ready(1000.0)

@@ -1,11 +1,12 @@
-"""``bytes_model`` against counts made by hand for both configurations."""
+"""The counts of both accepted configurations' family (``decode_step_bytes``,
+once ``harness/bytes_model.py``) against counts made by hand."""
 
 import json
 import os
 
 import pytest
 
-from benchmark.harness import bytes_model
+from benchmark import families
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -13,6 +14,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 def config(name):
     with open(os.path.join(REPO, "benchmark", "configs", f"{name}.json")) as f:
         return json.load(f)
+
+
+bytes_model = families.counts(config("mistral-7b-q40-16l"))
 
 
 def full_depth(name):

@@ -11,17 +11,20 @@ import numpy as np
 import pytest
 
 import tiny_root
+from benchmark import families
 from benchmark.harness import cell, modelfile
 
-CONFIG = {**tiny_root.TINY, "name": "mid-dense", "arch": "llama", "num_hidden_layers": 4}
+CONFIG = {**tiny_root.TINY, "name": "mid-dense", "family": "llama", "arch": "llama",
+          "num_hidden_layers": 4}
 MOE = {**CONFIG, "name": "mid-moe", "arch": "mixtral", "num_local_experts": 8, "num_experts_per_tok": 2}
-PROBES, PROMPT, ANSWER = cell.PROBES, 48, cell.PROBE_TOKENS
+CHECK = cell.load_check()  # the defaults: what both accepted configurations are judged by
+PROBES, PROMPT, ANSWER = CHECK["probes"], 48, CHECK["probe_tokens"]
 
 
 @pytest.fixture(scope="module")
 def model(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("mid") / "mid.m")
-    return modelfile.write_model(path, modelfile.model_spec(CONFIG, 512), 2**31 + 5)
+    return modelfile.write_model(path, CONFIG, 512, 2**31 + 5)
 
 
 def probe_tokens():
@@ -51,8 +54,7 @@ def activations_rounded_by(act):
     """The reference with ``act`` applied to the input of every Q40 matmul."""
     import jax
 
-    from benchmark.reference import model as ref
-
+    ref = families.load(CONFIG, "reference")
     plain = ref.matmul
     ref.matmul = lambda x, raw: plain(act(x), raw)
     jax.clear_caches()
@@ -64,15 +66,15 @@ def activations_rounded_by(act):
 
 
 def logits_of(model, tokens, positions, act=None, drop_layers=0, swap_rope=False, router_gaps=None):
-    from benchmark.reference import qfile
-    from benchmark.reference.model import forward
+    from benchmark.reference.qfile import QFile
 
-    qf = qfile.QFile(model)
+    ref = families.load(CONFIG, "reference")
+    qf = QFile(model, ref)
     qf.h["n_layers"] -= drop_layers
     if swap_rope:
-        qf.h["rope_type"] = qfile.ROPE_HALF_SPLIT
+        qf.h["rope_type"] = ref.ROPE_HALF_SPLIT
     with activations_rounded_by(act) if act else contextlib.nullcontext():
-        return forward(qf, tokens, positions, router_gaps)
+        return ref.forward(qf, tokens, positions, router_gaps)
 
 
 def verdict(reference_logits, served_logits, router_gaps=None):
@@ -81,7 +83,7 @@ def verdict(reference_logits, served_logits, router_gaps=None):
     from benchmark.reference.probe_child import score
 
     return cell.judge_probes([r for probe in score(reference_logits, served_logits.argmax(-1).tolist(),
-                                                   router_gaps) for r in probe])
+                                                   router_gaps) for r in probe], CHECK)
 
 
 @pytest.fixture(scope="module")
@@ -128,11 +130,11 @@ def rows(n, misses=(), router_gap=None):
     ("a decided router may be swapped after an earlier swap, once", rows(256, [0.2], router_gap=0.3), True),
     ("a routing near-tie is not compared", rows(200, router_gap=0.3) + rows(56, [0.2] * 56, router_gap=0.019), True),
     ("under the miss line is no miss", rows(256, [0.009] * 40), True),
-    ("too little read back", rows(cell.MIN_COMPARED - 1), False),
-    ("too many near-ties", rows(cell.MIN_COMPARED - 1, router_gap=0.3) + rows(200, router_gap=0.001), False),
+    ("too little read back", rows(CHECK["min_compared"] - 1), False),
+    ("too many near-ties", rows(CHECK["min_compared"] - 1, router_gap=0.3) + rows(200, router_gap=0.001), False),
 ])
 def test_the_rule(case, rows_, want):
-    ok, note = cell.judge_probes(rows_)
+    ok, note = cell.judge_probes(rows_, CHECK)
     assert ok is want, f"{case}: {note}"
 
 
@@ -160,8 +162,8 @@ def test_no_token_but_a_filler_can_be_the_greedy_answer(reference_logits):
 
 
 def test_the_same_seed_gives_the_same_file_and_another_seed_another(tmp_path):
-    spec = modelfile.model_spec({**CONFIG, "num_hidden_layers": 1}, 512)
-    a, b, c = (open(modelfile.write_model(str(tmp_path / n), spec, seed), "rb").read()
+    one = {**CONFIG, "num_hidden_layers": 1}
+    a, b, c = (open(modelfile.write_model(str(tmp_path / n), one, 512, seed), "rb").read()
                for n, seed in (("a.m", 2**31 + 9), ("b.m", 2**31 + 9), ("c.m", 2**31 + 10)))
     assert a == b and a != c
 
@@ -175,13 +177,13 @@ def test_answers_depend_on_their_context_with_these_weights_and_not_with_uniform
     from distributed_llama_tpu.formats.synthetic import write_random_q40_model
 
     wide = {**CONFIG, "hidden_size": 1024, "intermediate_size": 1024, "head_dim": 128}
-    spec = modelfile.model_spec(wide, 512)
+    spec = families.load(wide, "modelfile").model_spec(wide, 512)
     tokens, positions = probe_tokens()
     tokens, positions = tokens[:4, :40], np.arange(32, 40)
     changed = tokens.copy()
     changed[:, 1:8] = (changed[:, 1:8] + 17) % 250 + 3
     moved = {}
-    for name, path in (("seeded", modelfile.write_model(str(tmp_path / "s.m"), spec, 7)),
+    for name, path in (("seeded", modelfile.write_model(str(tmp_path / "s.m"), wide, 512, 7)),
                        ("uniform", write_random_q40_model(str(tmp_path / "u.m"), spec, seed=7))):
         a, b = logits_of(path, tokens, positions), logits_of(path, changed, positions)
         moved[name] = float(np.median(np.abs(a - b).max(-1) / np.abs(a).max(-1)))
@@ -189,7 +191,7 @@ def test_answers_depend_on_their_context_with_these_weights_and_not_with_uniform
 
 
 def test_a_sparse_expert_model_with_the_engines_roundings_passes(tmp_path):
-    path = modelfile.write_model(str(tmp_path / "moe.m"), modelfile.model_spec(MOE, 512), 2**31 + 5)
+    path = modelfile.write_model(str(tmp_path / "moe.m"), MOE, 512, 2**31 + 5)
     tokens, positions = probe_tokens()
     gaps: list = []
     reference = logits_of(path, tokens, positions, router_gaps=gaps)

@@ -3,6 +3,7 @@ devices with ``--tp 4``, the MoE path; and the ways a run must refuse."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -112,6 +113,39 @@ def test_trace_2_measures_as_trace_0_does_and_then_traces_in_the_same_process(
     assert 0 < result["device"]["busy_s"] <= result["device"]["window_s"]
     assert not os.path.exists(os.path.join(root, "benchmark", ".cache", "trace"))  # reduced, deleted
     json.dumps(result)
+
+
+BROKEN_UNDERNEATH = '''
+"""Laid into a miniature checkout by test_bench_run.py: Python imports it at the start of every
+child of the run, and in the server child it alters each token where it is produced (the
+logits are rolled by one id, so the greedy token is the neighbour of the right one)."""
+import sys
+
+if "benchmark.harness.server_child" in getattr(sys, "orig_argv", []):
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.models import llama
+
+    _final_logits = llama.final_logits
+    llama.final_logits = lambda cfg, params, x: jnp.roll(_final_logits(cfg, params, x), 1, axis=-1)
+'''
+
+
+def test_a_run_whose_timed_path_is_broken_underneath_comes_out_not_correct(tmp_path, capsys):
+    """The whole of a run but the look for a chip, over a server that answers
+    every request in full and on time with the wrong tokens: the window's
+    numbers are all there, and ``correct`` is false by the reference rule."""
+    broken = tiny_root.build(str(tmp_path / "checkout"))
+    with open(os.path.join(broken, "sitecustomize.py"), "w") as f:
+        f.write(BROKEN_UNDERNEATH)
+    result = run_cell(broken, "tiny-moe.closed", 2**31 + 19, 3.0, 0, "cpu", time.monotonic())
+    assert result["correct"] is False and result["failed"] == 0 and result["attempted"] > 0
+    assert set(result["metrics"]) == NAMES["tiny-moe.closed"]
+    # each number compared beside its limit, as the last lines of standard error too
+    last = capsys.readouterr().err.rstrip().splitlines()[-1]
+    assert last.startswith("[check] reference:") and "allowed" in last
+    found = re.search(r"(\d+) over 1e-02 of max\|logit\| below its best \((\d+) allowed\)", last)
+    assert found and int(found.group(1)) > int(found.group(2))
 
 
 def test_a_traced_run_with_no_device_operation_is_refused(root):

@@ -7,7 +7,11 @@ import re
 
 import pytest
 
+from benchmark import families
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+PUBLISHED = os.path.join(REPO, "tests", "benchmark", "data", "published")
+WIDTHS = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|head|experts_per_tok")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -22,6 +26,35 @@ def bench():
 def load(*parts):
     with open(os.path.join(REPO, *parts)) as f:
         return json.load(f)
+
+
+def against_published(data: dict, directory: str = PUBLISHED) -> list[str]:
+    """What is wrong with a configuration's file when it is held to its
+    source's published values, ``<directory>/<name>.json``: [] if nothing.
+    Every published key is in the file, equal unless ``reduced`` lists it
+    (then ``reduced_from`` gives the published value); no width is reduced;
+    and every number of the file is a published one, an ``assumed`` one, or
+    the harness's own."""
+    path = os.path.join(directory, f"{data['name']}.json")
+    if not os.path.isfile(path):
+        return [f"no published values on file for {data['name']!r}: {path}"]
+    with open(path) as f:
+        on_file = json.load(f)
+    wrong = [] if on_file["source"] == data["source"] else ["the published file is of another source"]
+    published, reduced = on_file["config"], data.get("reduced", [])
+    wrong += [f"{k}: a width may not be reduced" for k in reduced if WIDTHS.search(k)]
+    for key, value in published.items():
+        if key in reduced:
+            if data.get("reduced_from", {}).get(key) != value:
+                wrong.append(f"{key}: reduced_from does not give the published {value!r}")
+        elif key not in data or data[key] != value or type(data[key]) is not type(value):
+            wrong.append(f"{key}: {data.get(key)!r} in the file, {value!r} published, not in reduced")
+    wrong += [f"{k}: reduced, but not a published key" for k in reduced if k not in published]
+    for key, value in data.items():
+        if (isinstance(value, (int, float)) and not isinstance(value, bool) and key not in published
+                and key not in data.get("assumed", {}) and key not in families.HARNESS_KEYS):
+            wrong.append(f"{key}: a number that is neither published nor listed under assumed")
+    return wrong
 
 
 def test_top_level_keys_and_sizes(bench):
@@ -40,7 +73,6 @@ def test_configs(bench):
     names = [c["name"] for c in bench["configs"]]
     assert len(set(names)) == len(names) and len({c["file"] for c in bench["configs"]}) == len(names)
     used = {w["config"] for w in bench["workloads"]}
-    widths = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|head|experts_per_tok")
     for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["name"] in used
@@ -50,10 +82,37 @@ def test_configs(bench):
         assert data["name"] == c["name"] and data["source"] == c["source"]
         assert data["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
         assert set(data.get("reduced_from", {})) == set(c["reduced"])
-        assert not any(widths.search(k) for k in c["reduced"])
-        # the published widths of both models, which no cut may touch
-        assert (data["hidden_size"], data["intermediate_size"], data["num_attention_heads"],
-                data["num_key_value_heads"], data["vocab_size"]) == (4096, 14336, 32, 8, 32000)
+        # the source's published values, which no cut but those of ``reduced`` may touch
+        assert against_published(data) == []
+        # its family is there, whole, and knows every key of the file
+        assert families.counts(data) and all(families.load(data, part) for part in families.PARTS)
+
+
+OTHER_WIDTHS = {"name": "other", "source": "https://example.org/other/config.json", "family": "llama",
+                "d_model": 6144, "n_layer": 8, "n_head": 48, "head_dim": 128, "tokenizer_vocab": 1000,
+                "reduced": ["n_layer"], "reduced_from": {"n_layer": 64},
+                "assumed": {"head_dim": "d_model / n_head"}}
+OTHER_PUBLISHED = {"source": OTHER_WIDTHS["source"], "config": {"d_model": 6144, "n_layer": 64, "n_head": 48}}
+
+
+@pytest.mark.parametrize("case,change,published,complaint", [
+    ("other widths, published values on file", {}, OTHER_PUBLISHED, None),
+    ("other widths, nothing on file", {}, None, "no published values on file"),
+    ("a width that differs from the published one", {"d_model": 4096}, OTHER_PUBLISHED, "d_model: 4096 in the file"),
+    ("a published key left out", {"n_head": None}, OTHER_PUBLISHED, "n_head: None in the file"),
+    ("a reduced key whose published value is not stated", {"reduced_from": {"n_layer": 32}}, OTHER_PUBLISHED,
+     "n_layer: reduced_from does not give"),
+    ("a width in reduced", {"reduced": ["n_layer", "head_dim"]}, OTHER_PUBLISHED, "head_dim: a width may not"),
+    ("a number from nowhere", {"n_shared_experts": 1}, OTHER_PUBLISHED, "n_shared_experts: a number that is neither"),
+    ("the file of another source", {"source": "https://example.org/sibling"}, OTHER_PUBLISHED, "another source"),
+])
+def test_a_configuration_is_held_to_its_published_values_whatever_its_widths(
+        tmp_path, case, change, published, complaint):
+    data = {k: v for k, v in {**OTHER_WIDTHS, **change}.items() if v is not None}
+    if published is not None:
+        (tmp_path / "other.json").write_text(json.dumps(published))
+    wrong = against_published(data, str(tmp_path))
+    assert (wrong == []) if complaint is None else any(complaint in w for w in wrong), f"{case}: {wrong}"
 
 
 def test_workloads_and_their_files(bench):

@@ -152,7 +152,7 @@ def test_reader_kinds(spec, want):
              "trace.modules": {"jit_decode_chunk_batched_paged": {"count": 10, "seconds": 4.0},
                                "jit__slab_prefill_single_paged": {"count": 3, "seconds": 0.5}},
              "model.decode_step_bytes": 4e9, "server.decode_chunk": 32.0,
-             "peaks.hbm_bytes_per_s": 819e9}
+             "peaks": {"hbm_bytes_per_s": 819e9}}
     got = readers._read(spec, readers.Context(prom.parse(TEXT), prom.parse(LATER), facts))
     assert got is None if want is None else got == pytest.approx(want)
 

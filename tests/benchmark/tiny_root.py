@@ -1,6 +1,7 @@
 """A miniature checkout for rehearsing ``run_cell`` on the CPU: the real
-harness, reference and layer-metric readers (symlinked), with tiny
-configurations, short traffic and a ``BENCHMARK.json`` of their own."""
+harness, reference, families, check defaults and layer-metric readers
+(symlinked), with tiny configurations, short traffic and a ``BENCHMARK.json``
+of their own."""
 
 from __future__ import annotations
 
@@ -14,9 +15,9 @@ TINY = {"hidden_act": "silu", "hidden_size": 256, "intermediate_size": 512, "num
         "max_position_embeddings": 512, "rms_norm_eps": 1e-05, "rope_theta": 1000000.0,
         "tokenizer_vocab": 16384}
 CONFIGS = {
-    "tiny-dense": {**TINY, "name": "tiny-dense", "arch": "llama"},
-    "tiny-moe": {**TINY, "name": "tiny-moe", "arch": "mixtral", "num_local_experts": 8,
-                 "num_experts_per_tok": 2},
+    "tiny-dense": {**TINY, "name": "tiny-dense", "family": "llama", "arch": "llama"},
+    "tiny-moe": {**TINY, "name": "tiny-moe", "family": "llama", "arch": "mixtral",
+                 "num_local_experts": 8, "num_experts_per_tok": 2},
 }
 TRAFFIC = {
     "open": {"loop": "open", "rate_rps": 3.0, "lead_in_s": 1, "template_seed": 1,
@@ -41,8 +42,14 @@ def build(root: str, device_kind: str = "cpu") -> str:
     os.makedirs(bench)
     os.symlink(os.path.join(REPO, "distributed_llama_tpu"), os.path.join(root, "distributed_llama_tpu"))
     os.symlink(os.path.join(REPO, "native"), os.path.join(root, "native"))
-    for name in ("harness", "reference", "layer_metrics", "__init__.py"):
+    for name in ("harness", "reference", "layer_metrics", "check.json", "__init__.py"):
         os.symlink(os.path.join(REPO, "benchmark", name), os.path.join(bench, name))
+    # a directory of its own, so that a test can lay a further family beside the real ones
+    os.makedirs(os.path.join(bench, "families"))
+    for name in os.listdir(os.path.join(REPO, "benchmark", "families")):
+        if name != "__pycache__":
+            os.symlink(os.path.join(REPO, "benchmark", "families", name),
+                       os.path.join(bench, "families", name))
     with open(os.path.join(bench, "peaks.json"), "w") as f:
         json.dump({"source": "none: a CPU rehearsal", device_kind: {"hbm_bytes_per_s": 1e11}}, f)
     cells = {
