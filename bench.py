@@ -419,7 +419,7 @@ def run_batch(cfg, name: str, B: int, prefill_len: int = 64, chunk: int = 32,
     slab = llama.init_batch_cache(cfg, B, dtype=jnp.bfloat16)
     firsts = []
     for i in range(B):
-        logits, slab = _slab_prefill_single(
+        logits, slab, _ = _slab_prefill_single(
             cfg, params, prompts[i], slab, jnp.int32(i), jnp.int32(0),
             jnp.int32(prefill_len),
         )
@@ -599,7 +599,7 @@ def run_sampled(cfg, name: str, B: int = 4, prefill_len: int = 32,
     slab = llama.init_batch_cache(cfg, B, dtype=jnp.bfloat16)
     firsts = []
     for i in range(B):
-        logits, slab = _slab_prefill_single(
+        logits, slab, _ = _slab_prefill_single(
             cfg, params, prompts[i], slab, jnp.int32(i), jnp.int32(0),
             jnp.int32(prefill_len),
         )

@@ -91,6 +91,18 @@ from distributed_llama_tpu.ops import kv_cache as kvc
 from distributed_llama_tpu.telemetry import Stopwatch, flight
 
 
+# pool pages per recurrent-state snapshot slot: `--kv-pages` // 16 slots. A
+# prompt's pages share ONE snapshot (taken at its last page boundary), so the
+# pool needs a slot for every prompt it can hold, not for every page: at the
+# default page of 64 tokens 16 pages are 1024 tokens, half of the context a
+# row serves by default, so a pool of P pages has a slot for each of the P/16
+# prompts of that length it holds. Shorter prompts run out of slots before
+# pages (a row then publishes without one and nothing resumes there); a slot
+# is a row's whole state, some tens of pages' worth, so one a page would
+# multiply the pool's memory.
+SNAPSHOT_PAGES = 16
+
+
 def decode_bucket(n: int, b_max: int) -> int:
     """Power-of-two row bucket covering rows 0..n-1 (capped at b_max): one
     compiled batched program per bucket, holes masked inactive."""
@@ -111,7 +123,7 @@ def _slice_page(pool, pid):
     fetches (the download runs under the scheduler cond; its wall time is
     lock hold time for every lane)."""
     out = []
-    for pk, pv in pool:
+    for pk, pv in filter(None, pool):
         out.extend(kvc.slice_pool_page(pk, pid))
         out.extend(kvc.slice_pool_page(pv, pid))
     return out
@@ -124,9 +136,12 @@ def _upload_page(pool, pid, page_kvs):
     reverse (ISSUE 11). ``page_kvs`` is per layer a pair of flat
     array lists (``[data]``, or ``[data, scales]`` for i8 — the
     download's verbatim layout). The donated pool aliases in place."""
+    kvs = iter(page_kvs)  # one entry per layer that HAS keys and values
     return [
-        (kvc.upload_pool_page(pk, pid, hk), kvc.upload_pool_page(pv, pid, hv))
-        for (pk, pv), (hk, hv) in zip(pool, page_kvs)
+        None if half is None else tuple(
+            kvc.upload_pool_page(p, pid, h) for p, h in zip(half, next(kvs))
+        )
+        for half in pool
     ]
 
 
@@ -137,12 +152,43 @@ def _publish_pages(page: int, slab, pool, page_ids, src_page, row):
     pool aliases in place; the slab is read-only here (``leaf[0]``/
     ``leaf[1]`` are contiguous views of the fused leaf)."""
     return [
-        (
-            kvc.publish_row_pages(pk, leaf[0], row, src_page, page_ids, page),
-            kvc.publish_row_pages(pv, leaf[1], row, src_page, page_ids, page),
+        None if half is None else (
+            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page),
+            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page),
         )
-        for leaf, (pk, pv) in zip(slab, pool)
+        for leaf, half in zip(slab, pool)
     ]
+
+
+@functools.partial(jax.jit, donate_argnums=(1,))
+def _snapshot_take(slab, store, row, slot):
+    """Copy slab row ``row``'s recurrent state (every linear layer's state
+    and convolution tail) into snapshot slot ``slot``. ``store`` mirrors
+    the slab's layer list: a state leaf of ``[slots, ...]`` arrays for a
+    linear layer, None for a softmax one. The donated store aliases in
+    place; the slab is read-only here."""
+    return [
+        None if snap is None else kvc.fused_put_row(snap, kvc.fused_take_row(leaf, row), slot)
+        for leaf, snap in zip(slab, store)
+    ]
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _snapshot_restore(slab, store, row, slot):
+    """:func:`_snapshot_take` in reverse: slot ``slot`` into slab row
+    ``row`` (a prefix hit resumes there). Only the slab is donated."""
+    return [
+        leaf if snap is None else kvc.fused_put_row(leaf, kvc.fused_take_row(snap, slot), row)
+        for leaf, snap in zip(slab, store)
+    ]
+
+
+def _held_of_real(counts, n_real):
+    """The expert choices of a prefill chunk's REAL tokens that fell on a held
+    expert (an int32 scalar), or None for an arch that holds every expert."""
+    if not counts:
+        return None
+    return jnp.sum(jnp.where(jnp.arange(counts[0].shape[0]) < n_real, counts[0], 0))
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(3,))
@@ -153,14 +199,15 @@ def _slab_prefill_single(cfg: LlamaConfig, params, tokens, slab, row, pos, n_rea
     coalesced K/V updates — all reused), and written back; the donated slab
     aliases every other row in place. Returns (logits [T, vocab], new slab)."""
     row_cache = [kvc.fused_take_row(leaf, row) for leaf in slab]
+    counts = [] if cfg.n_routed_experts else None
     logits, new_rows = llama.forward_tokens(
-        cfg, params, tokens, row_cache, pos, n_real=n_real
+        cfg, params, tokens, row_cache, pos, n_real=n_real, held_counts=counts
     )
     new_slab = [
         kvc.fused_put_row(leaf, new_leaf, row)
         for leaf, new_leaf in zip(slab, new_rows)
     ]
-    return logits, new_slab
+    return logits, new_slab, _held_of_real(counts, n_real)
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(3,))
@@ -173,15 +220,16 @@ def _slab_prefill_single_paged(
     continuation prefill on an aliased row). The pool is read-only — only
     the slab is donated."""
     row_cache = [kvc.fused_take_row(leaf, row) for leaf in slab]
+    counts = [] if cfg.n_routed_experts else None
     logits, new_rows = llama.forward_tokens(
         cfg, params, tokens, row_cache, pos, n_real=n_real,
-        paged=(pool, table, matched),
+        paged=(pool, table, matched), held_counts=counts,
     )
     new_slab = [
         kvc.fused_put_row(leaf, new_leaf, row)
         for leaf, new_leaf in zip(slab, new_rows)
     ]
-    return logits, new_slab
+    return logits, new_slab, _held_of_real(counts, n_real)
 
 
 class BatchStream:
@@ -245,6 +293,14 @@ class BatchStream:
         self._alias_chain: list = []
         self._alias_ids: list[int] = []
         self.matched_len = 0
+        # an arch with linear-attention layers: the slot holding this row's
+        # state snapshot between taking it (at the last page boundary of the
+        # admission prefill) and publishing it with the chain; and whether
+        # the device state may have run past ``pos`` (a decode chunk computes
+        # whole chunks), after which only a rewind to 0 is possible
+        self._snap_slot: int | None = None
+        self._snap_at: int | None = None
+        self._state_ahead = False
         # speculative decode (scheduler spec mode): this row's host-side
         # prompt-lookup corpus (prompt + emitted tokens, extended at chunk
         # delivery) and its lazily-built drafter. ``_spec_on`` False rides
@@ -336,6 +392,20 @@ class BatchStream:
         pool."""
         if not 0 <= pos <= self.pos:
             raise ValueError(f"cannot rollback to {pos} from {self.pos}")
+        if 0 < pos < self.pos:
+            # a recurrent state is where the device left it, not at ``pos``:
+            # a rewind to 0 starts the row over (the next prefill zeroes the
+            # state), anything else would go on with a stale state
+            llama.refuse_recurrent(
+                self.cfg, f"rollback to position {pos} of a row at {self.pos}"
+            )
+        self._rewind(pos)
+
+    def _rewind(self, pos: int) -> None:
+        """Move the position pointer back. After a decode the device state
+        of a recurrent arch has run past it (``_state_ahead``: a decode chunk
+        computes whole chunks); the next prefill or decode of this row
+        refuses unless it starts over at 0."""
         self.pos = pos
         if self.matched_len > pos:
             self.scheduler._truncate_alias(self, pos)
@@ -536,7 +606,7 @@ class BatchStream:
                 handing_on.__exit__(None, None, None)
             sched._leave(self)
             fed = max(consumed - 1, 0) if fused_first else consumed
-            self.rollback(min(start_pos + fed, self.pos))
+            self._rewind(min(start_pos + fed, self.pos))
         return consumed
 
     # ------------------------------------------------------------------
@@ -593,6 +663,18 @@ class BatchScheduler:
             )
         if n_rows < 1:
             raise ValueError(f"need at least one batch row, got {n_rows}")
+        if engine.cfg.is_recurrent:
+            # paths that move or rewind a row by position refuse by name
+            if spec_draft and int(spec_draft) > 0:
+                llama.refuse_recurrent(
+                    engine.cfg, f"speculative decode (--spec-draft {spec_draft})"
+                )
+            if tp_engine is not None:
+                llama.refuse_recurrent(engine.cfg, "a sharded (tp/pod) backend")
+            if spill_arena is not None or host_spill_bytes > 0:
+                llama.refuse_recurrent(
+                    engine.cfg, "the host spill tier (a state snapshot has no spill form)"
+                )
         self.engine = engine
         self.b_max = n_rows
         self.chunk = int(chunk)
@@ -674,6 +756,12 @@ class BatchScheduler:
                         "(single-chip backend only)"
                     )
                     arena = None
+                # recurrent-state snapshots share the pool's budget: one slot
+                # for every SNAPSHOT_PAGES pool pages (a snapshot is taken
+                # once an admission, at its last page boundary)
+                snap_slots = (
+                    max(2, kv_pages // SNAPSHOT_PAGES) if engine.cfg.is_recurrent else 0
+                )
                 self._prefix = PrefixCache(
                     kv_pages, page_size,
                     page_bytes=llama.page_pool_bytes(
@@ -683,6 +771,7 @@ class BatchScheduler:
                     page_fetch=self._download_page if arena is not None else None,
                     owner_id=replica_id,
                     shared_index=shared_index,
+                    snap_slots=snap_slots,
                 )
                 if tp_engine is None:
                     self._pool = llama.init_page_pool(
@@ -769,6 +858,26 @@ class BatchScheduler:
             )
         else:
             self._slab = tp_engine.init_batch_cache(n_rows, dtype=engine.cache_dtype)
+        # row buckets whose plain decode program has been dispatched (built)
+        self._decode_built: set[int] = set()
+        # (device scalar, tokens) of prefill chunks whose held-expert sums are
+        # not read yet
+        self._moe_pending: list = []
+        self._snaps = None
+        if engine.cfg.is_recurrent:
+            engine._tel.recurrent_state_bytes.set(
+                llama.recurrent_state_bytes(engine.cfg, n_rows)
+            )
+            if self._prefix is not None:
+                self._snaps = [
+                    None if engine.cfg.is_softmax_layer(l)
+                    else llama.init_state_leaf(engine.cfg, (self._prefix.snap_slots,))
+                    for l in range(engine.cfg.n_layers)
+                ]
+                # both programs are built now, not at the first hit inside
+                # a measured window (slot 0 and row 0 hold zeros)
+                self._snaps = _snapshot_take(self._slab, self._snaps, jnp.int32(0), jnp.int32(0))
+                self._slab = _snapshot_restore(self._slab, self._snaps, jnp.int32(0), jnp.int32(0))
         # backends whose slab shards its BATCH axis across the mesh (the
         # pod's 'data' axis) dispatch the whole slab every chunk: a sub-
         # bucket's rows would straddle the wrong shards. The floor is set
@@ -989,6 +1098,12 @@ class BatchScheduler:
             raise ValueError(
                 f"context overflow: pos {stream.pos} + {n} > {engine.cfg.seq_len}"
             )
+        if stream.pos == 0:
+            stream._state_ahead = False  # the prefill program zeroes the state
+        elif stream._state_ahead:
+            llama.refuse_recurrent(
+                engine.cfg, f"a continuation prefill at position {stream.pos} after a decode"
+            )
         admission = (
             self._prefix is not None
             and stream.pos == 0
@@ -996,16 +1111,22 @@ class BatchScheduler:
         )
         chain: list = []
         suffix = tokens
+        stream._snap_at = None
         if admission:
             chain = self._match_alias(stream, tokens)
             if chain:
                 suffix = tokens[len(chain) * self._prefix.page :]
+            if self._snaps is not None:
+                # the state where the last full page ends is what a later
+                # prompt resumes from: a prefill chunk must end there
+                stream._snap_at = n // self._prefix.page * self._prefix.page
         try:
             logits, last = self._dispatch_prefill_chunks(stream, suffix)
         except BaseException:
             # a failed suffix prefill fails the request: unwind the alias
             # bind (release the chain pins, reset the position) so the
             # row is clean for its next occupant and the pages evictable
+            self._drop_row_snapshot(stream)
             if chain:
                 self._release_row_pins(stream)
                 stream.pos = 0
@@ -1019,11 +1140,15 @@ class BatchScheduler:
         ``prefill_chunk`` tokens: the scheduler lock is released between
         chunk dispatches so other rows' decode chunks interleave with a
         long prefill (Sarathi-style) instead of queueing behind the whole
-        prompt. Returns (device logits of the final dispatch, index of the
-        last real token's logits row)."""
+        prompt. ``stream._snap_at``: an absolute position at which a chunk
+        must end; the row's recurrent state is snapshotted there (into
+        ``stream._snap_slot``) before the next chunk moves it on. Returns
+        (device logits of the final dispatch, index of the last real
+        token's logits row)."""
         engine = self.engine
         n = tokens.shape[0]
         step = self.prefill_chunk if self.prefill_chunk > 0 else n
+        cut = stream._snap_at
         logits = None
         off = 0
         c = n
@@ -1049,6 +1174,8 @@ class BatchScheduler:
                     f"{off}/{n} prompt tokens dispatched)"
                 )
             c = min(step, n - off)
+            if cut is not None and stream.pos < cut < stream.pos + c:
+                c = cut - stream.pos
             bucket = _prefill_bucket(c)
             if stream.pos + bucket > engine.cfg.seq_len:
                 bucket = c  # exact-length compile near the context limit
@@ -1086,11 +1213,12 @@ class BatchScheduler:
                         # so one compiled program serves hits and misses
                         table, matched = self._alias_row_arrays_locked(stream)
                         if engine._tp_engine is None:
-                            logits, self._slab = _slab_prefill_single_paged(
+                            logits, self._slab, held = _slab_prefill_single_paged(
                                 engine.cfg, engine.params, jnp.asarray(padded),
                                 self._slab, self._pool, jnp.int32(stream.row),
                                 jnp.int32(stream.pos), jnp.int32(c), table, matched,
                             )
+                            self._note_prefill_held_locked(held, c)
                         else:
                             logits, self._slab = engine._tp_engine.slab_forward_paged(
                                 engine.params, jnp.asarray(padded), self._slab,
@@ -1098,16 +1226,19 @@ class BatchScheduler:
                                 matched,
                             )
                     elif engine._tp_engine is None:
-                        logits, self._slab = _slab_prefill_single(
+                        logits, self._slab, held = _slab_prefill_single(
                             engine.cfg, engine.params, jnp.asarray(padded), self._slab,
                             jnp.int32(stream.row), jnp.int32(stream.pos), jnp.int32(c),
                         )
+                        self._note_prefill_held_locked(held, c)
                     else:
                         logits, self._slab = engine._tp_engine.slab_forward(
                             engine.params, jnp.asarray(padded), self._slab,
                             stream.row, stream.pos, c,
                         )
                     stream.pos += c
+                    if stream.pos == cut:
+                        self._take_snapshot_locked(stream)
             off += c
             if tr is not None:
                 # one child span per dispatched prompt chunk: the trace
@@ -1129,6 +1260,69 @@ class BatchScheduler:
     # republish only manifests as a LATER device program).
     # ------------------------------------------------------------------
 
+    def _note_prefill_held_locked(self, held, n_tokens: int) -> None:
+        """Keep a prefill chunk's device sum of held-expert choices for the
+        next decode chunk's delivery to count (cond held). Telemetry off,
+        nothing is kept and nothing is read."""
+        if held is not None and self.engine._tel.enabled:
+            self._moe_pending.append((held, n_tokens))
+
+    def _count_moe(self, held: int, tokens: int, forwards: int) -> None:
+        """``tokens`` tokens made ``held`` of their expert choices, over all
+        layers, on experts held here, in ``forwards`` forward steps."""
+        cfg, tel = self.engine.cfg, self.engine._tel
+        tel.moe_assigned_held.inc(held)
+        tel.moe_assigned_absent.inc(tokens * cfg.n_layers * cfg.n_active_experts - held)
+        tel.moe_rows_per_expert.observe(held / (forwards * cfg.n_layers * cfg.n_experts))
+
+    def _count_prefill_held(self) -> None:
+        """Count the sums of the prefill chunks dispatched so far (telemetry
+        on: nothing is pending otherwise). The read is a device-to-host
+        fetch on the delivery path and WAITS for those chunks, the ones
+        queued on the device behind the pending decode chunk too: while
+        prompt work is queued, the next decode dispatch comes later. That is
+        a cost of counting, not a rule of the scheduler, and it has a
+        measured side (PERF.md §6 and §7, PR 26: against a read of the ready
+        sums only, `out_tok_s` 502-541 over 9 runs against 435-503 over 11,
+        `stall` 10 % longer). A rule "no decode dispatch while prefill
+        chunks are queued", for every architecture and whether or not
+        anything is counted, is ROADMAP Speed's to propose and measure."""
+        with self._cond:
+            pending, self._moe_pending = self._moe_pending, []
+        for held, n_tokens in pending:
+            try:
+                held = int(held)
+            except Exception:  # the chunk failed on the device: its request says so
+                continue
+            self._count_moe(held, n_tokens, 1)
+
+    def _take_snapshot_locked(self, stream: BatchStream) -> None:
+        """Copy ``stream``'s recurrent state, as the prefill chunk just
+        dispatched leaves it, into a snapshot slot the row holds until its
+        publish (cond held; device ordering puts the copy after that chunk
+        and before the next). No slot to be had: the row publishes its
+        pages without one, and no later prompt resumes there."""
+        self._drop_snapshot_locked(stream)
+        slot = self._prefix.snapshot_slot()
+        if slot is None:
+            return
+        with self.engine._tel.span("state_snapshot", batch_row=stream.row, pos=stream.pos):
+            self._snaps = _snapshot_take(
+                self._slab, self._snaps, jnp.int32(stream.row), jnp.int32(slot)
+            )
+        stream._snap_slot = slot
+        self._prefix.tel.snapshots_taken.inc()
+
+    def _drop_snapshot_locked(self, stream: BatchStream) -> None:
+        if stream._snap_slot is not None:
+            self._prefix.snap_free.append(stream._snap_slot)
+            stream._snap_slot = None
+
+    def _drop_row_snapshot(self, stream: BatchStream) -> None:
+        if stream._snap_slot is not None:
+            with self._cond:
+                self._drop_snapshot_locked(stream)
+
     def _download_page(self, pid: int) -> list[np.ndarray]:
         """Host byte arrays of pool page ``pid`` across every layer and
         half, in the flat spill-entry layout (the PrefixCache eviction
@@ -1145,7 +1339,7 @@ class BatchScheduler:
         back to a cold prefill, never upload misshapen bytes)."""
         halves: list[list] = []
         i = 0
-        for pk, pv in self._pool:
+        for pk, pv in filter(None, self._pool):
             for half in (pk, pv):
                 n = kvc.pool_page_arrays_per_half(half)
                 halves.append(list(arrays[i : i + n]))
@@ -1155,7 +1349,7 @@ class BatchScheduler:
                 f"spill entry layout mismatch: {len(arrays)} arrays, "
                 f"expected {i}"
             )
-        return [(halves[2 * l], halves[2 * l + 1]) for l in range(len(self._pool))]
+        return [(halves[2 * l], halves[2 * l + 1]) for l in range(len(halves) // 2)]
 
     def _reload_spilled_locked(self, tokens: np.ndarray) -> int:
         """Pull spilled pages of this prompt's prefix back into the pool
@@ -1214,7 +1408,7 @@ class BatchScheduler:
                 # a dead replica must not re-announce chains to the shared
                 # index after the pool dropped its ownership
                 reloaded = self._reload_spilled_locked(tokens)
-            chain = prefix.match(tokens)
+            chain = prefix.match(tokens, resumable=self._snaps is not None)
             if tr is not None:
                 # admission-time cache outcome in the request's own tree:
                 # how much prompt the match skipped, and how many spilled
@@ -1230,6 +1424,18 @@ class BatchScheduler:
             stream._alias_ids = [nd.page_id for nd in chain]
             stream.matched_len = len(chain) * prefix.page
             stream.pos = stream.matched_len
+            if self._snaps is not None:
+                # the chain ends at a block with a snapshot: the row's
+                # recurrent state resumes from it, as its attention resumes
+                # from the pages
+                with self.engine._tel.span(
+                    "state_restore", batch_row=stream.row, pos=stream.pos
+                ):
+                    self._slab = _snapshot_restore(
+                        self._slab, self._snaps, jnp.int32(stream.row),
+                        jnp.int32(chain[-1].snap),
+                    )
+                prefix.tel.snapshots_restored.inc()
         return chain
 
     def _publish_row(self, stream: BatchStream, tokens: np.ndarray, chain: list) -> None:
@@ -1248,8 +1454,14 @@ class BatchScheduler:
                 # index AFTER the pool dropped this replica's ownership
                 # (dangling routing); the request's own ReplicaLost
                 # surfaces at its next chunk boundary
+                self._drop_snapshot_locked(stream)
                 return
             new_ids, new_blocks = prefix.publish(tokens, tokens.shape[0], chain)
+            if stream._snap_slot is not None:
+                # the snapshot goes with the chain: to the block that ends
+                # where it was taken (or back, if that block is not there)
+                slot, stream._snap_slot = stream._snap_slot, None
+                prefix.snapshot_attach(tokens, tokens.shape[0] // page * page, slot)
             if new_ids:
                 bucket = _page_bucket(len(new_ids))
                 ids = np.full(bucket, prefix.capacity, np.int32)  # pad drops
@@ -1293,12 +1505,14 @@ class BatchScheduler:
         Idempotent — quarantine and the subsequent reset both call it."""
         if stream._alias_chain and self._prefix is not None:
             self._prefix.release(stream._alias_chain)
+        if self._prefix is not None:
+            self._drop_snapshot_locked(stream)
         stream._alias_chain = []
         stream._alias_ids = []
         stream.matched_len = 0
 
     def _release_row_pins(self, stream: BatchStream) -> None:
-        if not stream._alias_chain:
+        if not stream._alias_chain and stream._snap_slot is None:
             # nothing pinned (the common miss/reset case): no lock needed —
             # only this row's owner thread binds/clears its alias state
             stream._alias_ids = []
@@ -1444,7 +1658,8 @@ class BatchScheduler:
                 self._prefix.check(
                     row_pages=[
                         list(s._alias_ids) for s in self._streams if s._alias_ids
-                    ]
+                    ],
+                    held_snapshots=sum(s._snap_slot is not None for s in self._streams),
                 )
 
     # ------------------------------------------------------------------
@@ -1457,6 +1672,10 @@ class BatchScheduler:
     ) -> None:
         from distributed_llama_tpu import prng
 
+        if stream._state_ahead:
+            llama.refuse_recurrent(
+                self.engine.cfg, f"a second decode of row {stream.row} without a prefill from 0"
+            )
         with self._cond:
             stream._first = first_token
             stream._temperature = float(temperature)
@@ -1467,6 +1686,9 @@ class BatchScheduler:
             stream._delivered = 0
             stream._epoch += 1
             stream._joined = True
+            # a decode chunk computes whole chunks: from here on the row's
+            # recurrent state may be past ``pos``
+            stream._state_ahead = self.engine.cfg.is_recurrent
             stream._chunk_fps = []
             if not isinstance(
                 stream._fetch_error, (faults.RowPreempted, faults.ReplicaLost)
@@ -1797,8 +2019,10 @@ class BatchScheduler:
         if not joined:
             self._cond.notify_all()
             return
-        bucket = decode_bucket(
-            max(max(s.row for s in joined) + 1, self._bucket_floor), self.b_max
+        bucket = self._built_bucket(
+            decode_bucket(
+                max(max(s.row for s in joined) + 1, self._bucket_floor), self.b_max
+            )
         )
         rows = self._streams[:bucket]
         t_build = time.monotonic()
@@ -1871,11 +2095,26 @@ class BatchScheduler:
                 # over
                 s._first = out[self.chunk - 1, s.row]
                 s.pos += self.chunk
+        self._decode_built.add(bucket)
         self._note_dispatched(bucket, len(joined), self.chunk)
         self._pending = (
             "chunk", out, [(s, s._epoch) for s in joined], bucket,
             len(joined), sw, None, t_build, time.monotonic(),
         )
+
+    def _built_bucket(self, bucket: int) -> int:
+        """The row bucket to dispatch when ``bucket`` rows would do: itself
+        if its program has run before or no larger one has, else the
+        smallest larger bucket whose program has. Building a program stalls
+        EVERY lane for the length of a compile (seconds from the compile
+        cache, tens of seconds without), while a larger bucket only computes
+        masked rows for one chunk; so under load a bucket first met on the
+        way DOWN (rows 16-31 idle together for a moment) rides the program
+        the way up has already built, and which buckets a warm-up happened
+        to visit no longer decides whether a request stalls."""
+        if bucket in self._decode_built:
+            return bucket
+        return min((b for b in self._decode_built if b > bucket), default=bucket)
 
     def _note_dispatched(self, bucket: int, n_active: int, steps: int) -> None:
         """Per dispatched chunk: its joined and bucket rows, and the
@@ -2126,7 +2365,13 @@ class BatchScheduler:
         if toks is not None:
             # unpack the [chunk + 2, B] bundle: tokens + per-row logit
             # fingerprint + finiteness flag (ONE fetch moved all three)
+            held = integrity.chunk_extra_row(toks, self.chunk)
             toks, fps, finite = integrity.split_chunk_outputs(toks, self.chunk)
+            if held is not None:
+                # the expert share's routing sums came with the tokens
+                if tel.enabled:
+                    self._count_moe(int(held.sum()), n_active * self.chunk, self.chunk)
+                    self._count_prefill_held()
             with self._cond:
                 if self._sdc_logits_pending > 0:
                     # engine.sdc message=logits: shift every token column

@@ -175,6 +175,8 @@ class EngineStream:
         every slot is overwritten before the position pointer crosses it."""
         if not 0 <= pos <= self.pos:
             raise ValueError(f"cannot rollback to {pos} from {self.pos}")
+        if 0 < pos < self.pos:
+            llama.refuse_recurrent(self.cfg, f"rollback to position {pos} of a stream at {self.pos}")
         self.pos = pos
 
     def _forward_device(self, tokens: np.ndarray):

@@ -160,7 +160,7 @@ class SharedPrefixIndex:
 class PageNode:
     """One radix-tree node: a ``page``-token block bound to one pool page."""
 
-    __slots__ = ("key", "page_id", "parent", "children", "refs", "last_use")
+    __slots__ = ("key", "page_id", "parent", "children", "refs", "last_use", "snap")
 
     def __init__(self, key, page_id: int, parent: "PageNode | None"):
         self.key = key  # tuple of the block's token ids (edge label)
@@ -169,6 +169,9 @@ class PageNode:
         self.children: dict[tuple, PageNode] = {}
         self.refs = 0
         self.last_use = 0
+        # slot of the recurrent-state snapshot taken where this block ENDS
+        # (archs with linear-attention layers); None = a row cannot resume here
+        self.snap: int | None = None
 
 
 class PrefixCache:
@@ -177,6 +180,7 @@ class PrefixCache:
     def __init__(
         self, n_pages: int, page: int, page_bytes: int = 0,
         spill=None, page_fetch=None, owner_id: int = 0, shared_index=None,
+        snap_slots: int = 0,
     ):
         if n_pages < 1:
             raise ValueError(f"need at least one pool page, got {n_pages}")
@@ -201,6 +205,11 @@ class PrefixCache:
         # copy-traffic-saved counter; 0 = unknown (host-only unit tests)
         self.page_bytes = int(page_bytes)
         self.free: list[int] = list(range(n_pages))
+        # recurrent-state snapshot slots (the scheduler owns the device
+        # store; this is its index): a slot is free, held by a row between
+        # its snapshot and its publish, or attached to ONE tree node
+        self.snap_slots = int(snap_slots)
+        self.snap_free: list[int] = list(range(self.snap_slots))
         self.root = PageNode(None, -1, None)
         self._clock = 0
         # running count of refs>0 nodes, maintained at the 0<->1 ref
@@ -282,7 +291,7 @@ class PrefixCache:
     def _set_pinned_gauge(self) -> None:
         self.tel.pinned_pages.set(self.pinned_pages())
 
-    def check(self, row_pages=None) -> None:
+    def check(self, row_pages=None, held_snapshots: int = 0) -> None:
         """Structural invariants (tests + the eviction stress): every tree
         page is allocated exactly once and disjoint from the free list.
 
@@ -309,6 +318,15 @@ class PrefixCache:
         assert self._pinned == walked_pinned, (
             f"pinned counter drift: running {self._pinned} "
             f"!= walked {walked_pinned}"
+        )
+        # a snapshot slot is free, held by a row (``held_snapshots`` of
+        # them), or attached to exactly one node
+        snaps = [n.snap for n in seen.values() if n.snap is not None]
+        assert len(set(snaps)) == len(snaps), f"snapshot slot attached twice: {sorted(snaps)}"
+        assert not (set(snaps) & set(self.snap_free)), "attached snapshot slot on the free list"
+        assert len(snaps) + len(self.snap_free) + held_snapshots == self.snap_slots, (
+            f"snapshot slot leak: {len(snaps)} attached + {len(self.snap_free)} free + "
+            f"{held_snapshots} held != {self.snap_slots}"
         )
         for ids in row_pages or ():
             for pid in ids:
@@ -349,16 +367,22 @@ class PrefixCache:
         self._clock += 1
         return self._clock
 
-    def match(self, tokens) -> list[PageNode]:
+    def match(self, tokens, resumable: bool = False) -> list[PageNode]:
         """Longest chain of full-block matches STRICTLY shorter than the
         prompt (at least the last token always prefills — its logits seed
         the first sampled token). Acquires one ref per matched node; the
         pins last for the LIFETIME of the aliasing row (its attention
         reads the pages through its table every step), so the caller
         :meth:`release`\\ s the chain at row reset/quarantine — not after
-        admission."""
+        admission. ``resumable``: the row also needs its recurrent state
+        where the chain ends, so the chain stops at the deepest matched
+        block that has a snapshot and the matched pages past it are
+        dropped (none has one: a miss)."""
         page = self.page
         chain = self.walk(tokens)
+        if resumable:
+            deepest = max((i for i, nd in enumerate(chain) if nd.snap is not None), default=-1)
+            chain = chain[: deepest + 1]
         t = self._tick()
         for nd in chain:
             self._ref(nd)
@@ -432,6 +456,47 @@ class PrefixCache:
         self._set_pinned_gauge()
         return new_ids, new_blocks
 
+    # ------------------------------------------------------------------
+    # Recurrent-state snapshots (archs with linear-attention layers)
+    # ------------------------------------------------------------------
+
+    def snapshot_slot(self) -> int | None:
+        """A slot for a snapshot about to be taken: a free one, else the
+        least recently used attached one (its node stays, a row can no
+        longer resume there). None when the store has no slot to give."""
+        if self.snap_free:
+            return self.snap_free.pop()
+        victim = min(
+            (nd for nd in self._walk() if nd.snap is not None),
+            key=lambda nd: nd.last_use, default=None,
+        )
+        if victim is None:
+            return None
+        slot, victim.snap = victim.snap, None
+        self.tel.snapshots_evicted.inc()
+        return slot
+
+    def snapshot_attach(self, tokens, n_tokens: int, slot: int) -> bool:
+        """Attach the snapshot in ``slot``, taken after ``tokens[:n_tokens]``
+        (a whole number of blocks), to the node that ends there. False, and
+        the slot goes back, when that block is not in the tree (a partial
+        publish) or already has a snapshot."""
+        # walk() stops one block short of its input's end (a match is strictly
+        # shorter than the prompt): one token more reaches the last block
+        chain = self.walk(list(tokens[:n_tokens]) + [0])
+        if len(chain) * self.page != n_tokens or not chain or chain[-1].snap is not None:
+            self.snap_free.append(slot)
+            return False
+        chain[-1].snap = slot
+        self.tel.snapshots_published.inc()
+        return True
+
+    def _drop_snapshot(self, node: PageNode) -> None:
+        if node.snap is not None:
+            self.snap_free.append(node.snap)
+            node.snap = None
+            self.tel.snapshots_evicted.inc()
+
     def unpublish(self, tokens, new_ids: list[int], new_blocks: list[int]) -> None:
         """Unwind a :meth:`publish` whose device copy failed to dispatch:
         detach the inserted sub-chain and return its pages to the free
@@ -457,6 +522,7 @@ class PrefixCache:
             nd = stack.pop()
             if nd.refs > 0:
                 self._pinned -= 1
+            self._drop_snapshot(nd)
             if self.shared_index is not None:
                 # the publish already announced these chains; an unwound
                 # publish must retract them or placement routes to pages
@@ -509,6 +575,7 @@ class PrefixCache:
         if self.shared_index is not None:
             self.shared_index.withdraw(self.owner_id, key)
         del victim.parent.children[victim.key]
+        self._drop_snapshot(victim)  # snapshot and page go together
         self.free.append(victim.page_id)
         self.tel.evictions.inc()
         self._set_pages_gauges()

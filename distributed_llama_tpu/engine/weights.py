@@ -306,6 +306,65 @@ def load_params(
             ).astype(np_dtype),
         )
 
+    def fused(names: list[str]):
+        """:func:`weight_fused` for every dtype: the plain-array form is the
+        same matrices side by side."""
+        if quantized:
+            return weight_fused(names)
+        return cast(np.concatenate([_t(reader.tensor(n), np.float32) for n in names], axis=1))
+
+    def f32(name: str) -> np.ndarray:
+        return reader.tensor(name).astype(np.float32)
+
+    def hybrid_layer(l: int) -> dict:
+        """One ``ArchType.SOLAR_OPEN2`` layer: every input projection of a
+        mixer as ONE matrix (``qkvg``: q|k|v|gate of a softmax layer;
+        ``lin_in``: q|k|v|f_down|g_down|beta of a linear one), the held
+        experts as a list of fused gate|up + down leaves, the shared expert
+        as a dense SwiGLU. The router stays float32 at its published width:
+        a top 8 of 320 is decided by margins that bf16 weights would move."""
+        from distributed_llama_tpu.ops.q40 import stack_bank
+
+        p = f"layers.{l}."
+        if cfg.is_softmax_layer(l):
+            lp = {"qkvg": fused([p + "q", p + "k", p + "v", p + "gate"])}
+        else:
+            lp = {
+                "lin_in": fused([p + n for n in ("q", "k", "v", "f_down", "g_down", "beta")]),
+                "conv": f32(p + "conv"),
+                "f_up": weight(p + "f_up"),
+                "dt_bias": f32(p + "dt_bias"),
+                "a_log": f32(p + "a_log"),
+                "g_up": weight(p + "g_up"),
+                "o_norm": f32(p + "o_norm"),
+            }
+        lp["wo"] = weight(p + "wo")
+        lp["router"] = _t(reader.tensor(p + "moe_router"), np.float32)
+        lp["router_bias"] = f32(p + "router_bias")
+        held = [f"{p}experts.{e}." for e in range(cfg.n_experts)]
+        bank = stack_bank if quantized else np.stack
+        lp["experts_gate_up"] = bank([fused([ep + "gate", ep + "up"]) for ep in held])
+        lp["experts_down"] = bank([weight(ep + "down") for ep in held])
+        if cfg.n_shared_experts:
+            lp["shared_gate_up"] = fused([p + "shared.gate", p + "shared.up"])
+            lp["shared_down"] = weight(p + "shared.down")
+        lp["rms_att"] = f32(p + "rms_att")
+        lp["rms_ffn"] = f32(p + "rms_ffn")
+        return lp
+
+    if cfg.arch == ArchType.SOLAR_OPEN2:
+        from distributed_llama_tpu.models.llama import refuse_recurrent
+
+        if tp > 1:
+            refuse_recurrent(cfg, f"tensor parallelism (--tp {tp})")
+        return {
+            "embedding": reader.tensor("embedding").astype(np.float32),
+            "layers": [hybrid_layer(l) for l in range(cfg.n_layers)],
+            "rms_final": reader.tensor("rms_final").astype(np.float32),
+            "wcls": weight("wcls"),
+            "rope_table": build_rope_table(cfg),
+        }
+
     layers: dict[str, list] = {}
 
     def add(key: str, value) -> None:

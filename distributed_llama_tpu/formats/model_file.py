@@ -24,6 +24,20 @@ Tensor order (reference: src/transformer.cpp:479-540 Transformer::loadRoot):
   rms_final (F32) [dim]
   wcls [vocab, dim]
 
+``ArchType.SOLAR_OPEN2`` (no reference counterpart; :func:`_hybrid_layer`)
+mixes two kinds of layer and holds a SHARE of the routed experts; its files
+carry the header keys from ``HEAD_SIZE`` up, which no other arch writes:
+
+  softmax layer (l % attn_period == 0): q, k, v, gate [H*hd, dim], wo
+  linear layer: q, k, v [Hl*dl, dim], conv (F32) [3*Hl*dl, taps],
+    f_down [rank, dim], f_up [Hl*dl, rank], dt_bias (F32) [Hl*dl],
+    a_log (F32) [Hl], beta [Hl, dim], g_down [rank, dim], g_up [Hl*dl, rank],
+    o_norm (F32) [dl], wo [dim, Hl*dl]
+  every layer: moe_router [n_routed, dim], router_bias (F32) [n_routed],
+    per HELD expert: up, gate [moe_hidden, dim], down [dim, moe_hidden],
+    shared.up, shared.gate, shared.down (width n_shared * moe_hidden),
+    rms_att, rms_ffn
+
 All matrices are row-major [d_out, d_in] — a matmul computes y = W @ x.
 Q/K projections are stored pre-permuted for interleaved-pair rope
 (reference: converter/convert-hf.py:12-15).
@@ -50,6 +64,9 @@ class ArchType(enum.IntEnum):
     LLAMA = 0xABCD00
     GROK1 = 0xABCD01
     MIXTRAL = 0xABCD02
+    # softmax and gated delta-rule layers in one model, a share of the
+    # routed experts and a shared one; not a reference arch
+    SOLAR_OPEN2 = 0xABCD03
 
 
 class HiddenAct(enum.IntEnum):
@@ -90,6 +107,43 @@ class HeaderKey(enum.IntEnum):
     ROPE_SCALING_HIGH_FREQ_FACTORY = 16
     ROPE_SCALING_ORIG_MAX_SEQ_LEN = 17
     ROPE_TYPE = 18
+    # from here on: written by ArchType.SOLAR_OPEN2 files only
+    HEAD_SIZE = 19  # a head size that is not dim / n_heads
+    MOE_HIDDEN_DIM = 20  # width of one expert
+    N_SHARED_EXPERTS = 21
+    N_ROUTED_EXPERTS = 22  # the router's published width; n_experts are HELD here
+    FIRST_EXPERT = 23  # index of the first held expert
+    ATTN_PERIOD = 24  # layer l is a softmax layer where l % period == 0, else linear
+    LIN_HEADS = 25
+    LIN_HEAD_DIM = 26
+    LIN_CONV = 27  # taps of the causal depthwise convolution
+    LIN_RANK = 28  # rank of the decay's and the output gate's low-rank pairs
+    FLAGS = 29  # ArchFlags bits
+
+
+class ArchFlags(enum.IntFlag):
+    """Bits of ``HeaderKey.FLAGS``."""
+
+    USE_ROPE = 1  # softmax layers rotate q and k
+    GQA_GATE = 2  # softmax layers gate their output per channel
+    NEG_EIGVAL = 4  # beta = 2 * sigmoid(.), so a state transition may reflect
+    NORM_TOPK = 8  # the chosen experts' weights are renormalised to sum to one
+    SIGMOID_ROUTER = 16  # router score = sigmoid, chosen with a selection bias
+
+
+_EXTRA_KEYS = {
+    HeaderKey.HEAD_SIZE: "head_dim",
+    HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim",
+    HeaderKey.N_SHARED_EXPERTS: "n_shared_experts",
+    HeaderKey.N_ROUTED_EXPERTS: "n_routed_experts",
+    HeaderKey.FIRST_EXPERT: "first_expert",
+    HeaderKey.ATTN_PERIOD: "attn_period",
+    HeaderKey.LIN_HEADS: "lin_heads",
+    HeaderKey.LIN_HEAD_DIM: "lin_head_dim",
+    HeaderKey.LIN_CONV: "lin_conv",
+    HeaderKey.LIN_RANK: "lin_rank",
+    HeaderKey.FLAGS: "flags",
+}
 
 
 @dataclasses.dataclass
@@ -119,15 +173,27 @@ class ModelSpec:
     header_size: int = 0
     file_size: int = 0
     orig_seq_len: int = 0
+    # the _EXTRA_KEYS: 0 = the header does not carry it
+    head_dim: int = 0
+    moe_hidden_dim: int = 0
+    n_shared_experts: int = 0
+    n_routed_experts: int = 0
+    first_expert: int = 0
+    attn_period: int = 0
+    lin_heads: int = 0
+    lin_head_dim: int = 0
+    lin_conv: int = 0
+    lin_rank: int = 0
+    flags: int = 0
 
     @property
     def head_size(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_dim or self.dim // self.n_heads
 
     @property
     def kv_dim(self) -> int:
         # reference: src/transformer.cpp:103-104
-        return (self.dim * self.n_kv_heads) // self.n_heads
+        return self.head_size * self.n_kv_heads
 
     def resolved_rope_type(self) -> RopeType:
         """Default rope by arch when the header has none
@@ -175,6 +241,8 @@ def _header_pairs(spec: ModelSpec) -> list[tuple[int, int]]:
             (HeaderKey.ROPE_SCALING_HIGH_FREQ_FACTORY, int(spec.rope_scaling_high_freq_factor)),
             (HeaderKey.ROPE_SCALING_ORIG_MAX_SEQ_LEN, spec.rope_scaling_orig_max_seq_len),
         ]
+    if spec.arch_type == ArchType.SOLAR_OPEN2:
+        pairs += [(key, getattr(spec, name)) for key, name in _EXTRA_KEYS.items()]
     return pairs
 
 
@@ -250,6 +318,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
                 HeaderKey.ROPE_SCALING_HIGH_FREQ_FACTORY: "rope_scaling_high_freq_factor",
                 HeaderKey.ROPE_SCALING_ORIG_MAX_SEQ_LEN: "rope_scaling_orig_max_seq_len",
                 HeaderKey.ROPE_TYPE: "rope_type",
+                **_EXTRA_KEYS,
             }
             for i in range(0, n_ints, 2):
                 key, value = raw[i], raw[i + 1]
@@ -303,6 +372,9 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
     add("embedding", (vocab, dim), FloatType.F32)
     for l in range(spec.n_layers):
         p = f"layers.{l}."
+        if spec.arch_type == ArchType.SOLAR_OPEN2:
+            _hybrid_layer(spec, l, add)
+            continue
         add(p + "q", (dim, dim), wt)
         add(p + "k", (kv_dim, dim), wt)
         add(p + "v", (kv_dim, dim), wt)
@@ -326,6 +398,55 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
     add("rms_final", (dim,), FloatType.F32)
     add("wcls", (vocab, dim), wt)
     return entries
+
+
+def is_softmax_layer(spec, l: int) -> bool:
+    """Whether layer ``l`` mixes by softmax attention (``spec``: a ModelSpec
+    or a LlamaConfig). Every layer of an arch without a period does."""
+    return not spec.attn_period or l % spec.attn_period == 0
+
+
+def _hybrid_layer(spec: ModelSpec, l: int, add) -> None:
+    """One ``ArchType.SOLAR_OPEN2`` layer's tensors (the module docstring's
+    list), through the layout's ``add(name, shape, float_type)``."""
+    wt, f32, dim = spec.weights_float_type, FloatType.F32, spec.dim
+    p = f"layers.{l}."
+    if is_softmax_layer(spec, l):
+        q_dim = spec.n_heads * spec.head_size
+        add(p + "q", (q_dim, dim), wt)
+        add(p + "k", (spec.kv_dim, dim), wt)
+        add(p + "v", (spec.kv_dim, dim), wt)
+        add(p + "gate", (q_dim, dim), wt)
+        add(p + "wo", (dim, q_dim), wt)
+    else:
+        lin = spec.lin_heads * spec.lin_head_dim
+        for name in ("q", "k", "v"):
+            add(p + name, (lin, dim), wt)
+        add(p + "conv", (3 * lin, spec.lin_conv), f32)
+        add(p + "f_down", (spec.lin_rank, dim), wt)
+        add(p + "f_up", (lin, spec.lin_rank), wt)
+        add(p + "dt_bias", (lin,), f32)
+        add(p + "a_log", (spec.lin_heads,), f32)
+        add(p + "beta", (spec.lin_heads, dim), wt)
+        add(p + "g_down", (spec.lin_rank, dim), wt)
+        add(p + "g_up", (lin, spec.lin_rank), wt)
+        add(p + "o_norm", (spec.lin_head_dim,), f32)
+        add(p + "wo", (dim, lin), wt)
+    add(p + "moe_router", (spec.n_routed_experts, dim), wt)
+    add(p + "router_bias", (spec.n_routed_experts,), f32)
+    width = spec.moe_hidden_dim
+    for e in range(spec.n_experts):
+        ep = f"{p}experts.{e}."
+        add(ep + "up", (width, dim), wt)
+        add(ep + "gate", (width, dim), wt)
+        add(ep + "down", (dim, width), wt)
+    if spec.n_shared_experts:
+        shared = spec.n_shared_experts * width
+        add(p + "shared.up", (shared, dim), wt)
+        add(p + "shared.gate", (shared, dim), wt)
+        add(p + "shared.down", (dim, shared), wt)
+    add(p + "rms_att", (dim,), f32)
+    add(p + "rms_ffn", (dim,), f32)
 
 
 class ModelFileReader:

@@ -8,7 +8,14 @@ from __future__ import annotations
 
 import dataclasses
 
-from distributed_llama_tpu.formats.model_file import ArchType, HiddenAct, ModelSpec, RopeType
+from distributed_llama_tpu.formats.model_file import (
+    ArchFlags,
+    ArchType,
+    HiddenAct,
+    ModelSpec,
+    RopeType,
+    is_softmax_layer,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +52,21 @@ class LlamaConfig:
     # (the parity-with-the-reference default); opt into e.g. 2.0 via the
     # CLI/server --moe-capacity flag for the measured prefill speedup.
     moe_capacity_factor: float = 0.0
+    # the layer table and the expert share (ArchType.SOLAR_OPEN2; 0 elsewhere):
+    # layer l is a softmax layer where l % attn_period == 0, else a gated
+    # delta-rule layer of lin_heads x lin_head_dim; the router is
+    # n_routed_experts wide and this process HOLDS experts first_expert ..
+    # first_expert + n_experts - 1, each moe_hidden_dim wide
+    attn_period: int = 0
+    lin_heads: int = 0
+    lin_head_dim: int = 0
+    lin_conv: int = 0
+    lin_rank: int = 0
+    moe_hidden_dim: int = 0
+    n_shared_experts: int = 0
+    n_routed_experts: int = 0
+    first_expert: int = 0
+    flags: int = 0
 
     @property
     def kv_mul(self) -> int:
@@ -53,6 +75,40 @@ class LlamaConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_recurrent(self) -> bool:
+        """Whether some layer keeps a state that is not addressed by position."""
+        return self.attn_period > 1
+
+    def is_softmax_layer(self, l: int) -> bool:
+        return is_softmax_layer(self, l)
+
+    @property
+    def softmax_layers(self) -> tuple[int, ...]:
+        return tuple(l for l in range(self.n_layers) if self.is_softmax_layer(l))
+
+    @property
+    def use_rope(self) -> bool:
+        return self.arch != ArchType.SOLAR_OPEN2 or self.has(ArchFlags.USE_ROPE)
+
+    @property
+    def router_sigmoid(self) -> bool:
+        """Router score: sigmoid with a selection bias (else softmax)."""
+        return self.has(ArchFlags.SIGMOID_ROUTER)
+
+    @property
+    def norm_topk(self) -> bool:
+        """Whether the chosen experts' weights are renormalised to sum to one
+        (always, for the archs that have no flag to say otherwise)."""
+        return self.arch != ArchType.SOLAR_OPEN2 or self.has(ArchFlags.NORM_TOPK)
+
+    def has(self, flag: ArchFlags) -> bool:
+        return bool(self.flags & flag)
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts or self.n_experts
 
 
 def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
@@ -76,5 +132,15 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         rope_scaling_low_freq_factor=spec.rope_scaling_low_freq_factor,
         rope_scaling_high_freq_factor=spec.rope_scaling_high_freq_factor,
         rope_scaling_orig_max_seq_len=spec.rope_scaling_orig_max_seq_len,
+        attn_period=spec.attn_period,
+        lin_heads=spec.lin_heads,
+        lin_head_dim=spec.lin_head_dim,
+        lin_conv=spec.lin_conv,
+        lin_rank=spec.lin_rank,
+        moe_hidden_dim=spec.moe_hidden_dim,
+        n_shared_experts=spec.n_shared_experts,
+        n_routed_experts=spec.n_routed_experts,
+        first_expert=spec.first_expert,
+        flags=spec.flags,
         **overrides,
     )

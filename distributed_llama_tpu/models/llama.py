@@ -118,9 +118,31 @@ def project_qkv(
     (q [T, Hl, hd], k [T, Kl, hd], v [T, Kl, hd]). Shared by the dense,
     tensor-parallel and sequence-parallel attention paths (the reference's
     llamaRmsAtt/llamaQkv/llamaRope chain, src/llama2-tasks.cpp:10-52)."""
+    return project_qkvg(cfg, lp, x, rope_rows)[:3]
+
+
+def project_qkvg(
+    cfg: LlamaConfig,
+    lp: Params,
+    x: jax.Array,
+    rope_rows: jax.Array,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None]:
+    """:func:`project_qkv` and, where the layer gates its attention output
+    per channel (``qkvg``: q|k|v|gate as one matrix), the gate's
+    pre-activation [T, Hl*hd], else None. An arch without rope gets q and k
+    as projected."""
     T = x.shape[0]
     hd = cfg.head_size
-    if "qkv" in lp:
+    gate = None
+    if "qkvg" in lp:
+        fused = _norm_matmul(x, lp["rms_att"], lp["qkvg"], "wqkv")
+        d_q = lp["wo"].shape[-2]
+        d_kv = cfg.n_kv_heads * hd
+        q = fused[:, :d_q]
+        k = fused[:, d_q : d_q + d_kv]
+        v = fused[:, d_q + d_kv : d_q + 2 * d_kv]
+        gate = fused[:, d_q + 2 * d_kv : 2 * d_q + 2 * d_kv]
+    elif "qkv" in lp:
         # q|k|v packed as one matmul on the output dim (the q40 path: one
         # large bandwidth-efficient kernel call instead of three small
         # ones) — and the norm + Q80 quantize fused into that same program
@@ -140,9 +162,16 @@ def project_qkv(
         v = _matmul(xc, lp["v"], "wqkv")  # [T, Kl*hd]
     Hl = q.shape[-1] // hd
     Kl = k.shape[-1] // hd
-    q = apply_rope(q.reshape(T, Hl, hd), rope_rows, cfg)
-    k = apply_rope(k.reshape(T, Kl, hd), rope_rows, cfg)
-    return q, k, v.reshape(T, Kl, hd)
+    q, k = q.reshape(T, Hl, hd), k.reshape(T, Kl, hd)
+    if cfg.use_rope:
+        q, k = apply_rope(q, rope_rows, cfg), apply_rope(k, rope_rows, cfg)
+    return q, k, v.reshape(T, Kl, hd), gate
+
+
+def _gated(att: jax.Array, gate: jax.Array | None) -> jax.Array:
+    """The attention mix [..., Hl*hd] times sigmoid of its gate, where the
+    layer has one."""
+    return att if gate is None else att * jax.nn.sigmoid(gate.reshape(att.shape))
 
 
 def block_tail(
@@ -242,7 +271,7 @@ def attention(
     T = x.shape[0]
     S = cache_l[0].shape[0]  # works for tuple (keys, values) and stacked [2, S, ...] forms
     hd = cfg.head_size
-    q, k, v = project_qkv(cfg, lp, x, rope_rows)
+    q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows)
     Hl, Kl = q.shape[1], k.shape[1]
 
     if kvc.is_fused_leaf(cache_l):
@@ -300,7 +329,7 @@ def attention(
         att = blocked_attention(
             qg.astype(jnp.float32), keys, values, pos, ATT_CHUNK, paged=paged
         ).astype(jnp.float32).reshape(T, Hl * hd)
-        return att, new_cache
+        return _gated(att, gate), new_cache
     scores = kvc.scores_einsum(qg, keys, prec) / jnp.sqrt(jnp.float32(hd))
     # causal mask: query t (absolute pos+t) sees cache slots 0..pos+t
     t_idx = pos + jnp.arange(T)[:, None]
@@ -309,7 +338,110 @@ def attention(
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     att = kvc.mix_einsum(weights, values, cdt, prec).reshape(T, Hl * hd)
-    return att, new_cache
+    return _gated(att, gate), new_cache
+
+
+class RecurrentStateError(RuntimeError):
+    """A path that moves or rewinds a row BY POSITION met an arch some of
+    whose layers keep a state that is not addressed by position
+    (``cfg.is_recurrent``): speculative verify, tensor parallelism, spill
+    and a rollback into the middle of a row refuse by this name rather
+    than go on with a stale state."""
+
+
+def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
+    if cfg.is_recurrent:
+        raise RecurrentStateError(
+            f"{what} is not supported for arch {cfg.arch.name}: its linear-attention "
+            "layers keep a recurrent state that cannot be rewound or moved by position"
+        )
+
+
+def _l2norm(x: jax.Array) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _linear_inputs(cfg: LlamaConfig, lp: Params, x: jax.Array):
+    """Norm + the linear layer's input projections for T tokens:
+    (q|k|v pre-convolution [T, 3*L], decay pre-activation [T, L], beta
+    [T, Hl], output gate [T, L]), ``L = lin_heads * lin_head_dim``. One
+    matrix (``lin_in``: q|k|v|f_down|g_down|beta) reads the normed input."""
+    from distributed_llama_tpu.formats.model_file import ArchFlags
+
+    L, r, Hl = cfg.lin_heads * cfg.lin_head_dim, cfg.lin_rank, cfg.lin_heads
+    fused = _norm_matmul(x, lp["rms_att"], lp["lin_in"], "lin_in")
+    qkv = fused[:, : 3 * L]
+    f_low = fused[:, 3 * L : 3 * L + r]
+    g_low = fused[:, 3 * L + r : 3 * L + 2 * r]
+    beta = jax.nn.sigmoid(fused[:, 3 * L + 2 * r : 3 * L + 2 * r + Hl])
+    if cfg.has(ArchFlags.NEG_EIGVAL):
+        beta = 2.0 * beta
+    decay = _matmul(f_low.astype(lp["f_up"].dtype), lp["f_up"], "lin_f")[:, :L] + lp["dt_bias"]
+    gate = _matmul(g_low.astype(lp["g_up"].dtype), lp["g_up"], "lin_g")[:, :L]
+    return qkv, decay, beta, gate
+
+
+def _linear_heads(cfg: LlamaConfig, lp: Params, qkv: jax.Array, decay: jax.Array):
+    """Convolved q|k|v [T, 3*L] and the decay's pre-activation [T, L] ->
+    q, k, a [T, Hl, dl] (q, k normalised per head, q scaled by 1/sqrt(dl);
+    ``a`` the log of the per-channel decay, <= 0) and v [T, Hl, dl]."""
+    T = qkv.shape[0]
+    Hl, dl = cfg.lin_heads, cfg.lin_head_dim
+    q, k, v = (t.reshape(T, Hl, dl) for t in jnp.split(jax.nn.silu(qkv), 3, axis=-1))
+    q = _l2norm(q) * (1.0 / jnp.sqrt(jnp.float32(dl)))
+    k = _l2norm(k)
+    a = -jnp.exp(lp["a_log"])[None, :, None] * jax.nn.softplus(decay.reshape(T, Hl, dl))
+    return q, k, v, a
+
+
+def _linear_output(cfg: LlamaConfig, lp: Params, o: jax.Array, gate: jax.Array) -> jax.Array:
+    """Per-head RMS norm of the recurrence's output [T, Hl, dl], times the
+    sigmoid of the low-rank output gate -> [T, L] (``wo`` follows in
+    :func:`block_tail`)."""
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + 1e-5) * lp["o_norm"]
+    return o.reshape(gate.shape) * jax.nn.sigmoid(gate)
+
+
+def linear_attention(
+    cfg: LlamaConfig, x: jax.Array, lp: Params, cache_l: dict, pos: jax.Array,
+    n_real: jax.Array | None = None,
+) -> tuple[jax.Array, dict]:
+    """The gated delta-rule mixer for T new tokens of ONE row whose first
+    token sits at ``pos``. ``cache_l``: ``{"S": [Hl, dl, dl] f32, "conv":
+    [taps-1, 3*L] f32}``, the row's state after the tokens before ``pos``;
+    a row that starts over (``pos == 0``) starts from zeros whatever the
+    leaf holds, so a row is reset with its position. Tokens at and past
+    ``n_real`` (bucket padding) leave the state untouched. Returns (mix
+    [T, L], the state after the last real token)."""
+    from distributed_llama_tpu.ops import kda
+
+    fresh = pos == 0
+    S0 = jnp.where(fresh, 0.0, cache_l["S"])
+    tail = jnp.where(fresh, 0.0, cache_l["conv"])
+    qkv, decay, beta, gate = _linear_inputs(cfg, lp, x)
+    qkv, tail = kda.causal_conv(qkv, tail, lp["conv"], n_real)
+    q, k, v, a = _linear_heads(cfg, lp, qkv, decay)
+    o, S = kda.kda_chunk(S0, q, k, v, a, beta, n_real)
+    return _linear_output(cfg, lp, o, gate), {"S": S, "conv": tail}
+
+
+def linear_attention_batched(
+    cfg: LlamaConfig, x: jax.Array, lp: Params, cache_l: dict, active: jax.Array,
+) -> tuple[jax.Array, dict]:
+    """One decode step of B independent rows through the gated delta-rule
+    mixer: ``cache_l`` holds ``[b_max, ...]`` leaves of which the first B
+    rows step; rows where ``active`` is False keep state and tail."""
+    from distributed_llama_tpu.ops import kda
+
+    B = x.shape[0]
+    tail_all = cache_l["conv"]
+    qkv, decay, beta, gate = _linear_inputs(cfg, lp, x)
+    qkv, tail = kda.causal_conv_step(qkv, tail_all[:B], lp["conv"], active)
+    q, k, v, a = _linear_heads(cfg, lp, qkv, decay)
+    o, S = kda.kda_step(cache_l["S"], q, k, v, a, beta, active)
+    if tail_all.shape[0] != B:
+        tail = jax.lax.dynamic_update_slice_in_dim(tail_all, tail, 0, axis=0)
+    return _linear_output(cfg, lp, o, gate), {"S": S, "conv": tail}
 
 
 def ffn(cfg: LlamaConfig, x: jax.Array, lp: Params, axis_name: str | None) -> jax.Array:
@@ -347,9 +479,12 @@ def block_forward(
     n_real: jax.Array | None = None,
     paged=None,
 ) -> tuple[jax.Array, jax.Array]:
-    att, new_cache = attention(
-        cfg, x, lp, cache_l, pos, rope_rows, axis_name, paged=paged
-    )
+    if "lin_in" in lp:
+        att, new_cache = linear_attention(cfg, x, lp, cache_l, pos, n_real)
+    else:
+        att, new_cache = attention(
+            cfg, x, lp, cache_l, pos, rope_rows, axis_name, paged=paged
+        )
     return (
         block_tail(cfg, x, att, lp, axis_name, ep_axis=ep_axis, n_real=n_real),
         new_cache,
@@ -366,6 +501,7 @@ def forward_tokens(
     ep_axis: str | None = None,
     n_real: jax.Array | None = None,
     paged=None,
+    held_counts: list | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Run T tokens through the model starting at absolute position ``pos``.
 
@@ -379,8 +515,20 @@ def forward_tokens(
     ``paged``: ``(pool, table, matched)`` — this row's cache positions
     below ``matched`` live in the shared prefix-page pool (per-layer
     ``(keys, values)`` halves, read through ``table``); requires the
-    layered cache layout.
+    layered cache layout. ``held_counts``: a list that receives one int32
+    [T] array, per token how many of its expert choices, summed over the
+    layers, fell on an expert held here (archs that hold a share).
     """
+    from distributed_llama_tpu.models import moe
+
+    with moe.collect_held(held_counts is not None) as per_layer:
+        out = _forward_tokens(cfg, params, tokens, cache, pos, axis_name, ep_axis, n_real, paged)
+    if per_layer:
+        held_counts.append(sum(per_layer))
+    return out
+
+
+def _forward_tokens(cfg, params, tokens, cache, pos, axis_name, ep_axis, n_real, paged):
     T = tokens.shape[0]
     x = embed(cfg, params, tokens)
     rope_rows = jax.lax.dynamic_slice(
@@ -399,7 +547,7 @@ def forward_tokens(
         new_layers = []
         for l, lp in enumerate(params["layers"]):
             paged_l = None
-            if paged is not None:
+            if paged is not None and paged[0][l] is not None:
                 pool, table, matched = paged
                 paged_l = (pool[l][0], pool[l][1], table, matched)
             x, nc = block_forward(
@@ -452,7 +600,7 @@ def attention_batched(
     B = x.shape[0]
     S, cdt, prec = kvc.slab_facts(cache_l)
     hd = cfg.head_size
-    q, k, v = project_qkv(cfg, lp, x, rope_rows)  # [B, Hl, hd], [B, Kl, hd] x2
+    q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows)  # [B, Hl, hd], [B, Kl, hd] x2
     Hl, Kl = q.shape[1], k.shape[1]
 
     write_slot = jnp.where(active & (pos < S), pos, S)  # S = dropped
@@ -482,7 +630,7 @@ def attention_batched(
         att = batched_decode_attention(
             qg.astype(jnp.float32), new_cache, read_pos, ATT_CHUNK, paged=paged
         ).astype(jnp.float32)
-        return att.reshape(B, Hl * hd), new_cache
+        return _gated(att.reshape(B, Hl * hd), gate), new_cache
     # small/odd caches read all of S anyway: the halves may form here.
     # A dispatch bucket below B_max reads only its own slab rows
     keys, values = new_cache[0], new_cache[1]
@@ -502,13 +650,13 @@ def attention_batched(
             att = batched_decode_attention(
                 qg.astype(jnp.float32), (keys_b, values_b), read_pos, ATT_CHUNK
             ).astype(jnp.float32)
-            return att.reshape(B, Hl * hd), new_cache
+            return _gated(att.reshape(B, Hl * hd), gate), new_cache
     scores = kvc.scores_einsum_batched(qg, keys_b, prec) / jnp.sqrt(jnp.float32(hd))
     mask = jnp.arange(S)[None, :] <= read_pos[:, None]  # [B, S]
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     att = kvc.mix_einsum_batched(weights, values_b, cdt, prec).reshape(B, Hl * hd)
-    return att, new_cache
+    return _gated(att, gate), new_cache
 
 
 def forward_step_batched(
@@ -520,6 +668,7 @@ def forward_step_batched(
     active: jax.Array,  # bool [B]
     axis_name: str | None = None,
     paged=None,  # (pool, tables, matched) — zero-copy prefix aliasing
+    held_counts: list | None = None,  # receives int32 [B], as in forward_tokens
 ) -> tuple[jax.Array, jax.Array]:
     """One batched decode step: B tokens (one per sequence) at per-row
     positions through the whole model, reading each weight matrix ONCE.
@@ -535,6 +684,16 @@ def forward_step_batched(
     single-stream decode up to expert-sum reordering (the dense mix adds
     experts in bank order, the switch in top-k order); the BIT-parity
     contract of the batched path is exact for dense models only."""
+    from distributed_llama_tpu.models import moe
+
+    with moe.collect_held(held_counts is not None) as per_layer:
+        out = _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, paged)
+    if per_layer:
+        held_counts.append(sum(per_layer))
+    return out
+
+
+def _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, paged):
     if not isinstance(cache, (list, tuple)):
         raise ValueError("batched decode requires the layered (per-layer list) cache")
     x = embed(cfg, params, tokens)  # [B, dim]
@@ -545,12 +704,15 @@ def forward_step_batched(
     new_layers = []
     for l, lp in enumerate(layers):
         paged_l = None
-        if paged is not None:
+        if paged is not None and paged[0][l] is not None:
             pool, tables, matched = paged
             paged_l = (pool[l][0], pool[l][1], tables, matched)
-        att, nc = attention_batched(
-            cfg, x, lp, cache[l], pos, rope_rows, active, paged=paged_l
-        )
+        if "lin_in" in lp:
+            att, nc = linear_attention_batched(cfg, x, lp, cache[l], active)
+        else:
+            att, nc = attention_batched(
+                cfg, x, lp, cache[l], pos, rope_rows, active, paged=paged_l
+            )
         x = block_tail(cfg, x, att, lp, axis_name)
         new_layers.append(nc)
     return final_logits(cfg, params, x), type(cache)(new_layers)
@@ -661,6 +823,7 @@ def forward_verify_batched(
     updated slab cache)."""
     if not isinstance(cache, (list, tuple)):
         raise ValueError("batched verify requires the layered (per-layer list) cache")
+    refuse_recurrent(cfg, "speculative verify (a rejected draft rewinds the row)")
     B, T = tokens.shape
     x = embed(cfg, params, tokens.reshape(-1)).reshape(B, T, -1)
     offsets = pos[:, None] + jnp.arange(T)[None, :]
@@ -701,7 +864,28 @@ def init_batch_cache(
 
     kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
     shape = (b_max, cfg.seq_len, kl, cfg.head_size)
-    return [kvc.init_fused(shape, dtype) for _ in range(cfg.n_layers)]
+    return [
+        kvc.init_fused(shape, dtype) if cfg.is_softmax_layer(l) else init_state_leaf(cfg, (b_max,))
+        for l in range(cfg.n_layers)
+    ]
+
+
+def init_state_leaf(cfg: LlamaConfig, lead: tuple[int, ...] = ()) -> dict:
+    """A linear layer's cache: the recurrent state ``S`` [*lead, Hl, dl, dl]
+    and the convolution's last inputs ``conv`` [*lead, taps-1, 3*L], both
+    f32 whatever the K/V dtype. It does not grow with the row's length."""
+    Hl, dl = cfg.lin_heads, cfg.lin_head_dim
+    return {
+        "S": jnp.zeros(lead + (Hl, dl, dl), jnp.float32),
+        "conv": jnp.zeros(lead + (cfg.lin_conv - 1, 3 * Hl * dl), jnp.float32),
+    }
+
+
+def recurrent_state_bytes(cfg: LlamaConfig, rows: int) -> int:
+    """Bytes of recurrent state and convolution tails ``rows`` rows hold."""
+    Hl, dl = cfg.lin_heads, cfg.lin_head_dim
+    per_layer = 4 * (Hl * dl * dl + (cfg.lin_conv - 1) * 3 * Hl * dl)
+    return rows * per_layer * (cfg.n_layers - len(cfg.softmax_layers))
 
 
 def init_page_pool(
@@ -721,12 +905,14 @@ def init_page_pool(
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
+    # a linear layer has no keys and values: its entry is None
     return [
         (
             kvc.init_page_pool_half(n_pages, page, kl, cfg.head_size, dtype),
             kvc.init_page_pool_half(n_pages, page, kl, cfg.head_size, dtype),
         )
-        for _ in range(cfg.n_layers)
+        if cfg.is_softmax_layer(l) else None
+        for l in range(cfg.n_layers)
     ]
 
 
@@ -741,7 +927,7 @@ def page_pool_bytes(cfg: LlamaConfig, page: int, dtype) -> int:
         per_half = page * kl * hd + page * kl * 4  # int8 data + f32 scales
     else:
         per_half = page * kl * hd * jnp.dtype(dtype).itemsize
-    return 2 * cfg.n_layers * per_half
+    return 2 * len(cfg.softmax_layers) * per_half
 
 
 def init_cache(
@@ -769,5 +955,10 @@ def init_cache(
     if kvc.is_quantized_cache_dtype(dtype) and not layered:
         raise ValueError("the i8 KV cache requires the layered cache layout")
     if layered:
-        return [kvc.init_fused(shape, dtype) for _ in range(cfg.n_layers)]
+        return [
+            kvc.init_fused(shape, dtype) if cfg.is_softmax_layer(l) else init_state_leaf(cfg)
+            for l in range(cfg.n_layers)
+        ]
+    if cfg.is_recurrent:
+        raise ValueError("layers of two kinds need the layered cache layout")
     return jnp.zeros((cfg.n_layers, 2) + shape, dtype=dtype)

@@ -26,6 +26,8 @@ TPU-first design notes:
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -34,32 +36,44 @@ from distributed_llama_tpu.models.config import LlamaConfig
 
 
 def router_probs(cfg: LlamaConfig, xn: jax.Array, router: jax.Array) -> jax.Array:
-    """[T, E] softmax router probabilities (reference: src/grok1-tasks.cpp:62-97)."""
+    """[T, E] router scores: softmax over the experts (reference:
+    src/grok1-tasks.cpp:62-97) or, where the config says so, a sigmoid of
+    each expert's logit."""
     logits = jnp.einsum(
         "td,de->te",
         xn.astype(jnp.float32),
         router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    return jax.nn.softmax(logits, axis=-1)
+    return jax.nn.sigmoid(logits) if cfg.router_sigmoid else jax.nn.softmax(logits, axis=-1)
 
 
 def router_topk(
-    cfg: LlamaConfig, xn: jax.Array, router: jax.Array
+    cfg: LlamaConfig, xn: jax.Array, router: jax.Array, bias: jax.Array | None = None
 ) -> tuple[jax.Array, jax.Array]:
-    """Top-k routing: ([T, k] renormalized weights, [T, k] expert ids) —
-    the single home of the select-then-renormalize convention
-    (reference: src/grok1-tasks.cpp:62-114)."""
+    """Top-k routing: ([T, k] weights, [T, k] expert ids) — the single home
+    of the select-then-renormalize convention (reference:
+    src/grok1-tasks.cpp:62-114). The score function and whether the chosen
+    weights are renormalised are the config's; ``bias`` [E] is added to the
+    scores for CHOOSING only, the weights are the scores themselves."""
     probs = router_probs(cfg, xn, router)
-    top_vals, top_idx = jax.lax.top_k(probs, cfg.n_active_experts)
-    return top_vals / jnp.sum(top_vals, axis=-1, keepdims=True), top_idx
+    if bias is None:
+        top_vals, top_idx = jax.lax.top_k(probs, cfg.n_active_experts)
+    else:
+        _, top_idx = jax.lax.top_k(probs + bias, cfg.n_active_experts)
+        top_vals = jnp.take_along_axis(probs, top_idx, axis=-1)
+    if cfg.norm_topk:
+        top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    return top_vals, top_idx
 
 
-def router_weights(cfg: LlamaConfig, xn: jax.Array, router: jax.Array) -> jax.Array:
-    """[T, E] mixing weights: top-k selected, renormalized to sum to 1,
-    zero elsewhere."""
-    top_vals, top_idx = router_topk(cfg, xn, router)
-    one_hot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32)  # [T, k, E]
+def router_weights(
+    cfg: LlamaConfig, xn: jax.Array, router: jax.Array, bias: jax.Array | None = None
+) -> jax.Array:
+    """[T, E] mixing weights over the router's whole width: top-k selected
+    (renormalized to sum to 1 where the config says), zero elsewhere."""
+    top_vals, top_idx = router_topk(cfg, xn, router, bias)
+    one_hot = jax.nn.one_hot(top_idx, cfg.router_width, dtype=jnp.float32)  # [T, k, E]
     return jnp.einsum("tk,tke->te", top_vals, one_hot)
 
 
@@ -256,6 +270,123 @@ def _moe_dense_bucketed(
     return bucket_combine(outs, top_idx, rank, top_vals, C)
 
 
+# Trace-time collector of the expert share's routing sums: while one is open
+# (:func:`collect_held`), every :func:`_moe_share` appends, per row, how many
+# of its top-k choices fell on an expert held here (int32 [T]). The forward
+# that opened it sums the layers and returns the sum with its results, so the
+# counters ride the fetch a program's tokens make anyway.
+_held_counts: list | None = None
+
+
+@contextlib.contextmanager
+def collect_held(enabled: bool = True):
+    global _held_counts
+    before, _held_counts = _held_counts, [] if enabled else None
+    try:
+        yield _held_counts
+    finally:
+        _held_counts = before
+
+
+# rows a held expert's bucket holds, by the rows of the step: a token chooses a
+# given held expert with probability k / routed (1/40 at 8 of 320), so a
+# 256-token prefill chunk gives an expert 6.4 rows (s.d. 2.5) and a 32-row
+# decode step 0.8. A step in which some expert overflows its bucket takes the
+# every-row path instead (exact either way).
+def held_bucket_rows(rows: int) -> int:
+    return 8 if rows <= 64 else 32
+
+
+def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) -> jax.Array:
+    """SwiGLU of every held expert ``on`` marks over its rows: ``x`` [T, D]
+    (every expert multiplies the same rows) or [E, C, D] -> [E, T or C, D].
+    Q40 banks go through ONE grouped launch for gate|up and one for down
+    (``ops.q40.q40_grouped_matmul``: an expert no row chose is neither read
+    nor computed), plain arrays through batched einsums. ``tokens``, the
+    rows of the step that routed, goes into the launches' names: how many
+    experts a launch reads follows from it, not from a bucket's rows."""
+    from distributed_llama_tpu.models.llama import _activation
+    from distributed_llama_tpu.ops.q40 import QuantizedMatrix, q40_grouped_matmul
+
+    gate_up, down = lp["experts_gate_up"], lp["experts_down"]
+    width, dim = down.shape[-2], x.shape[-1]
+    if isinstance(gate_up, QuantizedMatrix):
+        role = f"held_experts_t{tokens}"
+        fused = q40_grouped_matmul(x, gate_up, on, role=role)
+        h = _activation(fused[..., :width], cfg.hidden_act) * fused[..., width : 2 * width]
+        return q40_grouped_matmul(h, down, on, role=role)[..., :dim]
+    hi = jax.lax.Precision.HIGHEST
+    rows = "td" if x.ndim == 2 else "etd"
+    fused = jnp.einsum(f"{rows},edf->etf", x.astype(gate_up.dtype), gate_up, precision=hi,
+                       preferred_element_type=jnp.float32)
+    h = _activation(fused[..., :width], cfg.hidden_act) * fused[..., width:]
+    return jnp.einsum("etf,efd->etd", h.astype(down.dtype), down, precision=hi,
+                      preferred_element_type=jnp.float32)
+
+
+def _held_experts(
+    cfg: LlamaConfig, xn: jax.Array, lp, top_vals: jax.Array, top_idx: jax.Array
+) -> jax.Array:
+    """Sum over the chosen experts HELD here of ``w * SwiGLU_e(xn)``, from
+    the routing's ([T, k] weights, [T, k] ids over the router's width). Each
+    held expert's rows are gathered into a bucket of ``held_bucket_rows``
+    rows and computed there, the results scattered back by the weights
+    (``bucket_rank`` / ``bucket_scatter`` / ``bucket_combine``, the algebra
+    of the capacity-bucketed prefill and the expert-parallel dispatch): an
+    expert computes its own rows, not every row of the step. Where some
+    expert has more rows than its bucket, the step is computed with every
+    held expert over every row instead: exact either way."""
+    T, E = xn.shape[0], cfg.n_experts
+    local = top_idx - cfg.first_expert
+    is_held = (local >= 0) & (local < E)
+    local = jnp.where(is_held, local, E)  # E: the sink the scatter drops
+    weights = jnp.where(is_held, top_vals, 0.0)
+    counts = jnp.sum(jax.nn.one_hot(local, E + 1, dtype=jnp.int32), axis=(0, 1))[:E]
+    on = counts > 0
+    C = held_bucket_rows(T)
+
+    def every_row():
+        held = jnp.einsum("tk,tke->te", weights, jax.nn.one_hot(local, E + 1)[..., :E])
+        return jnp.einsum("te,etd->td", held, _held_ffn(cfg, xn, lp, on, T),
+                          precision=jax.lax.Precision.HIGHEST)
+
+    if C >= T:
+        return every_row()
+
+    def bucketed():
+        flat_e, rank, t_ids = bucket_rank(local, E + 1)
+        buckets = bucket_scatter(xn, flat_e, rank, t_ids, E, C)
+        return bucket_combine(_held_ffn(cfg, buckets, lp, on, T), jnp.minimum(local, E - 1), rank,
+                              weights, C)
+
+    return jax.lax.cond(jnp.max(counts) > C, every_row, bucketed)
+
+
+def _moe_share(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:
+    """An expert layer that HOLDS a share of the experts: routing, the top k
+    and the renormalisation run over the router's whole width; the sum runs
+    over the chosen experts held here (``first_expert`` onwards), and what
+    the absent ones would add is left out. The shared expert, a dense SwiGLU
+    every token takes, is added whole. ``xn`` [T, dim] -> [T, dim] f32."""
+    from distributed_llama_tpu.models.llama import _activation, _matmul
+
+    top_vals, top_idx = router_topk(cfg, xn, lp["router"], lp.get("router_bias"))
+    out = jnp.zeros(xn.shape, jnp.float32)
+    if cfg.n_experts:
+        if _held_counts is not None:
+            local = top_idx - cfg.first_expert
+            _held_counts.append(
+                jnp.sum((local >= 0) & (local < cfg.n_experts), axis=1, dtype=jnp.int32)
+            )
+        out = _held_experts(cfg, xn, lp, top_vals, top_idx)
+    if "shared_gate_up" in lp:
+        fused = _matmul(xn.astype(lp["shared_gate_up"].dtype), lp["shared_gate_up"], "gate_up")
+        hidden = lp["shared_down"].shape[-2]
+        h = _activation(fused[:, :hidden], cfg.hidden_act) * fused[:, hidden : 2 * hidden]
+        out = out + _matmul(h.astype(lp["shared_down"].dtype), lp["shared_down"], "down")
+    return out
+
+
 def moe_ffn(
     cfg: LlamaConfig, xn: jax.Array, lp, axis_name: str | None,
     ep_axis: str | None = None, n_real: jax.Array | None = None,
@@ -271,6 +402,8 @@ def moe_ffn(
         from distributed_llama_tpu.parallel.expert_parallel import ep_moe_ffn
 
         out = ep_moe_ffn(cfg, xn, lp, ep_axis)
+    elif cfg.n_routed_experts:
+        out = _moe_share(cfg, xn, lp)
     elif xn.shape[0] == 1:
         out = _moe_topk(cfg, xn, lp)
     else:

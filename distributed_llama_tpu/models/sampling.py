@@ -477,7 +477,9 @@ def batched_decode_scan(
     compute garbage (masked out of cache writes and position advances) so
     requests can join/leave between chunks without a recompile. Returns
     (tokens [n_steps, B], cache, fingerprints uint32 [B], finite bool
-    [B]) — NOTHING else needs to cross the host per chunk: the sampler is
+    [B]) and, for an arch that holds a share of its experts, int32 [B] the
+    row's expert choices that fell on a held expert — NOTHING else needs to
+    cross the host per chunk: the sampler is
     stateless, so no advanced keys return and no full-vocab logits are
     ever fetched. ``paged``: each row's matched prompt prefix is read from
     the shared page pool through its page table instead of the slab (the
@@ -495,12 +497,19 @@ def batched_decode_scan(
     ``fingerprint=False`` skips the fold (same outputs, initial-state
     hashes) — the overhead-bound test compiles both and compares."""
 
+    # an arch that holds a share of its experts: per row, the choices that
+    # fell on a held expert, summed over the chunk's steps and layers
+    share = cfg.n_routed_experts > 0
+
     def step(carry, _):
-        tokens, cache_c, p, h, okf = carry
+        tokens, cache_c, p, h, okf, held = carry
+        counts = [] if share else None
         logits, cache_c = llama.forward_step_batched(
             cfg, params, tokens, cache_c, p, active, axis_name=axis_name,
-            paged=paged,
+            paged=paged, held_counts=counts,
         )
+        if share:
+            held = held + jnp.where(active, counts[0], 0)
         cand = None
         if axis_name is not None and logits.shape[-1] != cfg.vocab_size:
             # the tp top-k composition: candidates reduce over the sharded
@@ -516,18 +525,20 @@ def batched_decode_scan(
         if fingerprint:
             h, okf = integrity.fingerprint_fold(h, okf, logits, nxt)
         p2 = jnp.where(active, p + 1, p)
-        return (nxt.astype(jnp.int32), cache_c, p2, h, okf), nxt
+        return (nxt.astype(jnp.int32), cache_c, p2, h, okf, held), nxt
 
     h0, ok0 = integrity.fingerprint_init(first_tokens.shape[0])
-    (_, cache, _, h, okf), tokens = jax.lax.scan(
+    (_, cache, _, h, okf, held), tokens = jax.lax.scan(
         step,
         (
             first_tokens.astype(jnp.int32), cache, pos.astype(jnp.int32),
-            h0, ok0,
+            h0, ok0, jnp.zeros(first_tokens.shape, jnp.int32),
         ),
         None,
         length=n_steps,
     )
+    if share:
+        return tokens, cache, h, okf, held
     return tokens, cache, h, okf
 
 
@@ -557,11 +568,11 @@ def decode_chunk_batched(
     (engine/integrity.py ``split_chunk_outputs``) — one fetch still moves
     everything the scheduler needs, and those int32 rows are the ONLY
     bytes that cross the host per chunk."""
-    tokens, cache, h, okf = batched_decode_scan(
+    tokens, cache, *rest = batched_decode_scan(
         cfg, params, first_tokens, cache, pos, active, seeds, n_steps,
         temperature, topp, topk,
     )
-    return integrity.pack_chunk_outputs(tokens, h, okf), cache
+    return integrity.pack_chunk_outputs(tokens, *rest), cache
 
 
 @functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=(3,))
@@ -587,11 +598,11 @@ def decode_chunk_batched_paged(
     Only the slab is donated; the pool is shared across every row and
     dispatch, so it must never alias. Same packed [n_steps + 2, B] return
     bundle as :func:`decode_chunk_batched`."""
-    tokens, cache, h, okf = batched_decode_scan(
+    tokens, cache, *rest = batched_decode_scan(
         cfg, params, first_tokens, cache, pos, active, seeds, n_steps,
         temperature, topp, topk, paged=(pool, tables, matched),
     )
-    return integrity.pack_chunk_outputs(tokens, h, okf), cache
+    return integrity.pack_chunk_outputs(tokens, *rest), cache
 
 
 # ---------------------------------------------------------------------------

@@ -484,7 +484,10 @@ def fused_update_verify_batched(leaf, k_rows: jax.Array, v_rows: jax.Array, slot
 
 def fused_take_row(leaf, row):
     """Extract slab row ``row`` of a fused [2, B, S, K, hd] leaf as a fused
-    single-stream [2, S, K, hd] leaf (the slab prefill's row view)."""
+    single-stream [2, S, K, hd] leaf (the slab prefill's row view). A linear
+    layer's state leaf (a dict of ``[B, ...]`` arrays) gives its row's."""
+    if isinstance(leaf, dict):
+        return {k: jax.lax.dynamic_index_in_dim(v, row, 0, keepdims=False) for k, v in leaf.items()}
     if isinstance(leaf, QuantizedKV):
         _, B, S, K, hd = leaf.data.shape
         return QuantizedKV(
@@ -497,7 +500,13 @@ def fused_take_row(leaf, row):
 
 def fused_put_row(slab_leaf, row_leaf, row):
     """Write a fused single-stream row back into fused slab row ``row`` —
-    one dynamic_update_slice covers both halves."""
+    one dynamic_update_slice covers both halves (a state leaf: one per
+    array)."""
+    if isinstance(slab_leaf, dict):
+        return {
+            k: jax.lax.dynamic_update_index_in_dim(v, row_leaf[k], row, 0)
+            for k, v in slab_leaf.items()
+        }
     if isinstance(slab_leaf, QuantizedKV):
         return QuantizedKV(
             jax.lax.dynamic_update_slice(

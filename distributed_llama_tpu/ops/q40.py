@@ -880,6 +880,173 @@ def _q40_matmul_int8(
 
 
 # ---------------------------------------------------------------------------
+# Grouped int8 matmul over a STACKED bank of experts (ISSUE 26)
+# ---------------------------------------------------------------------------
+#
+# An expert layer that holds 20 small experts of which a 32-row decode step
+# touches about 11 would, as a launch per expert, put 40 kernel sites and 20
+# conditionals into every layer of every program. Here the experts' packs
+# are stacked on a leading axis ([E, n/2, d] nibbles, [E, n/32, d] scales)
+# and ONE launch walks (expert, d-tile, n-tile); a scalar-prefetched table
+# says which experts some row chose. An expert none chose is neither read
+# nor computed: its grid steps point at the block the walk already holds
+# (a block index that does not change starts no DMA) and write zeros.
+
+
+def stack_bank(mats: list[QuantizedMatrix]) -> QuantizedMatrix:
+    """Packs of equal shape stacked into one bank (host-side, at load)."""
+    first = mats[0]
+    return QuantizedMatrix(
+        np.stack([np.asarray(m.qs) for m in mats]),
+        np.stack([np.asarray(m.scales) for m in mats]),
+        first.n_logical, first.d_logical,
+    )
+
+
+def _make_q40_grouped_kernel():
+    """:func:`_make_q40_int8_kernel` with the expert on the first grid axis
+    and the nibbles' +8 bias taken off the exact int32 block sums in place
+    (``P - 8 * sum of the block's Q80 values``), so no per-expert correction
+    matmul over the whole bank's scales follows the launch."""
+
+    def kernel(sel_ref, on_ref, xlo_ref, xhi_ref, sxlo_ref, sxhi_ref, qslo_ref, qshi_ref,
+               qs_ref, slo_ref, shi_ref, out_ref, acc_ref):
+        del sel_ref  # read by the index maps
+        e, j = pl.program_id(0), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        @pl.when(on_ref[e] != 0)
+        def _():
+            qs = qs_ref[:].astype(jnp.int32)
+            lo = (qs & 0xF).astype(jnp.int8)
+            hi = (qs >> 4).astype(jnp.int8)
+            bn2, bd = qs.shape
+            nbt = bn2 // QK
+
+            def half(xq_ref, sx_ref, qsum_ref, w_nibbles, sw_ref):
+                wb = w_nibbles.reshape(nbt, QK, bd)
+                P = jax.lax.dot_general(
+                    xq_ref[:], wb, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.int32,
+                )  # [nbt, T, bd]
+                unbiased = P.astype(jnp.float32) - 8.0 * jnp.transpose(qsum_ref[:])[:, :, None]
+                scaled = unbiased * sw_ref[:][:, None, :]
+                return jnp.sum(scaled * jnp.transpose(sx_ref[:])[:, :, None], axis=0)
+
+            acc_ref[:] += half(xlo_ref, sxlo_ref, qslo_ref, lo, slo_ref)
+            acc_ref[:] += half(xhi_ref, sxhi_ref, qshi_ref, hi, shi_ref)
+
+        @pl.when(j == pl.num_programs(2) - 1)
+        def _():
+            out_ref[:] = acc_ref[:]
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "role"))
+def q40_grouped_matmul(
+    x: jax.Array,
+    bank: QuantizedMatrix,
+    on: jax.Array,
+    interpret: bool | None = None,
+    role: str | None = None,
+) -> jax.Array:
+    """``y[e] = x[e] @ dequant(bank[e])`` for the experts ``on`` [E] marks,
+    zeros for the others, f32 [E, T, d_padded]. ``x`` is [T, n] (every
+    expert multiplies the same rows) or [E, T, n]. Activations are Q80 as in
+    :func:`q40_matmul`'s int8 path. The launch is named
+    ``q40_int8_grouped_<role>``."""
+    E = bank.qs.shape[0]
+    T = x.shape[-2]
+    np_, dp = bank.n_padded, bank.d_padded
+    tiles = _resolve_tiles(bank, T, BLOCK_N, BLOCK_D)
+    tiles = _fit_int8_tiles(bank, T, *tiles) if tiles is not None else None
+    if tiles is None:
+        # packs too small or odd to tile (the tests' toy widths): every
+        # expert through the XLA fallback, the unchosen ones zeroed
+        _note_path("q40_grouped_matmul", "xla_fallback")
+        xs = x if x.ndim == 3 else jnp.broadcast_to(x, (E,) + x.shape)
+        outs = jax.vmap(
+            lambda xe, qs, sc: _q40_matmul_fallback(
+                xe, QuantizedMatrix(qs, sc, bank.n_logical, dp)
+            )
+        )(xs, bank.qs, bank.scales)
+        return jnp.where(on.astype(bool)[:, None, None], outs, 0.0)
+    block_n, block_d = tiles
+    if interpret is None:
+        interpret = _interpret_default()
+    _note_path("q40_grouped_matmul", "mxu_int8")
+    shared = x.ndim == 2
+    if x.shape[-1] != np_:
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, np_ - x.shape[-1]),))
+    flat = x.reshape(-1, np_)
+    xq, sx = quantize_q80(flat)
+    qsum = jnp.sum(xq.astype(jnp.float32).reshape(-1, np_ // QK, QK), axis=-1)
+    # the block sums travel in the scales' window-major layout
+    operands = jax.vmap(lambda a, b: q80_kernel_operands(a, b, block_n))
+    xq3 = xq.reshape(-1, T, np_)
+    xqb, sxw = operands(xq3, sx.reshape(-1, T, np_ // QK))
+    _, qsw = operands(xq3, qsum.reshape(-1, T, np_ // QK))
+    if shared:
+        xqb, sxw, qsw = xqb[0], sxw[0], qsw[0]
+    nj, ni = np_ // block_n, dp // block_d
+    nbt = block_n // 2 // QK
+    on = on.astype(jnp.int32)
+    idx = jnp.arange(E, dtype=jnp.int32)
+    last_on = jax.lax.cummax(jnp.where(on != 0, idx, -1))
+    sel = jnp.where(last_on >= 0, last_on, jnp.argmax(on).astype(jnp.int32))
+
+    def w_map(half):
+        def index(e, i, j, sel_ref, on_ref):
+            live = on_ref[e] != 0
+            jj = jnp.where(live, j, nj - 1)
+            return sel_ref[e], half * nj + jj if half is not None else jj, jnp.where(live, i, ni - 1)
+        return index
+
+    def x_map(half):
+        if shared:
+            return lambda e, i, j, sel_ref, on_ref: (half * nj + j, 0, 0)
+        return lambda e, i, j, sel_ref, on_ref: (e, half * nj + j, 0, 0)
+
+    x_block = (nbt, T, QK) if shared else (None, nbt, T, QK)
+    s_block = (None, T, nbt) if shared else (None, None, T, nbt)
+    out = pl.pallas_call(
+        _make_q40_grouped_kernel(),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(E, ni, nj),
+            in_specs=[
+                pl.BlockSpec(x_block, x_map(0)),
+                pl.BlockSpec(x_block, x_map(1)),
+                pl.BlockSpec(s_block, x_map(0)),
+                pl.BlockSpec(s_block, x_map(1)),
+                pl.BlockSpec(s_block, x_map(0)),
+                pl.BlockSpec(s_block, x_map(1)),
+                pl.BlockSpec((None, block_n // 2, block_d), w_map(None)),
+                pl.BlockSpec((None, nbt, block_d), w_map(0)),
+                pl.BlockSpec((None, nbt, block_d), w_map(1)),
+            ],
+            out_specs=pl.BlockSpec((None, T, block_d), lambda e, i, j, sel_ref, on_ref: (e, 0, i)),
+            scratch_shapes=[pltpu.VMEM((T, block_d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((E, T, dp), jnp.float32),
+        interpret=interpret,
+        # the unbiased block sums are one more [bn/64, T, bd] f32 temporary
+        # than the ungrouped kernel holds: 16.3 MiB at 256 rows against the
+        # compiler's default scoped limit of 16 (the chip's VMEM is 128)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=32 << 20,
+        ),
+        name=kernel_name("q40_int8_grouped", role),
+    )(sel, on, xqb, xqb, sxw, sxw, qsw, qsw, bank.qs, bank.scales, bank.scales)
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Fused rmsnorm → Q80 quantize → int8 matmul (decode superstep, part a)
 # ---------------------------------------------------------------------------
 #

@@ -261,7 +261,12 @@ class ApiState:
 
                 self._shared_index = SharedPrefixIndex(page_sz)
             spill_mb = getattr(args, "host_spill_mb", None)
-            spill_mb = 64.0 if spill_mb is None else float(spill_mb)
+            if spill_mb is None:
+                # no default spill tier under an arch with recurrent state: a
+                # page's state snapshot has no spill form (asked for by flag,
+                # the scheduler refuses by name)
+                spill_mb = 0.0 if engine.cfg.is_recurrent else 64.0
+            spill_mb = float(spill_mb)
             if spill_mb > 0:
                 from distributed_llama_tpu.engine.spill import HostArena
 
@@ -1019,6 +1024,12 @@ class ApiState:
             raise DeadlineExceeded("deadline expired before prefill")
 
         start_pos, delta_messages = slot.cache.resolve_delta_prompt(params["messages"])
+        if start_pos and engine.cfg.is_recurrent:
+            # a recurrent state cannot be rewound to where the cached
+            # messages end: the conversation prefills again from 0, through
+            # the prefix cache, which resumes it from its deepest snapshot
+            slot.cache.clear()
+            start_pos, delta_messages = 0, params["messages"]
         engine.rollback(min(start_pos, engine.pos))
         if engine.pos != start_pos:  # cache said resume further than engine state
             engine.reset()
@@ -2107,8 +2118,10 @@ def main(argv=None) -> None:
     # disk below that; with --replicas > 1 a shared radix index routes
     # each request to the replica owning its longest published chain
     parser.add_argument(
-        "--host-spill-mb", type=float, default=64.0,
-        help="host-RAM budget (MiB) for the prefix-page spill arena: "
+        "--host-spill-mb", type=float, default=None,
+        help="host-RAM budget (MiB) for the prefix-page spill arena "
+        "(default 64; none under an arch with recurrent state, which "
+        "refuses the tier if asked for): "
         "evicted KV pages spill here (bytes verbatim, CRC-guarded) and "
         "re-upload on a later match instead of re-prefilling — "
         "cacheable-prefix capacity at fixed --kv-pages multiplies. "

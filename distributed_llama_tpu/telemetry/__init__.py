@@ -301,6 +301,32 @@ class EngineInstruments:
             "Time one batched decode chunk's fetch blocked on the device "
             "(the np.asarray of its token bundle)",
         )
+        # an expert layer that holds a share of the experts (ISSUE 26): the
+        # programs return, with their tokens, how many of their (token,
+        # choice) assignments fell on a held expert
+        moe_assignments = counter(
+            "dllama_moe_assignments_total",
+            "(token, chosen expert) assignments of the expert layers, summed "
+            "over layers: held=yes those that chose an expert this process "
+            "holds (computed here), held=no those left to absent experts; "
+            "yes / (yes + no) is the held share, held / routed when routing "
+            "is even",
+            labelnames=("held",),
+        )
+        self.moe_assigned_held = moe_assignments.labels(held="yes")
+        self.moe_assigned_absent = moe_assignments.labels(held="no")
+        self.moe_rows_per_expert = histogram(
+            "dllama_moe_rows_per_expert",
+            "Rows one held expert received in one layer of one forward step "
+            "(a decode step, or a prefill chunk), observed once a program as "
+            "the mean over its steps, layers and held experts",
+            buckets=(0.25, 0.5, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0),
+        )
+        self.recurrent_state_bytes = gauge(
+            "dllama_recurrent_state_bytes",
+            "Bytes of recurrent state and convolution tails the slab's rows "
+            "hold (linear-attention layers; does not grow with a row's length)",
+        )
         self.prefill_chunks_ahead = histogram(
             "dllama_prefill_chunks_ahead",
             "Decode chunks in flight on the device (one pending, one being "
@@ -420,6 +446,21 @@ class PrefixCacheInstruments:
             "(page-granular)",
             buckets=self.MATCHED_TOKEN_BUCKETS,
         )
+        # recurrent-state snapshots published with a prefix (ISSUE 26): a
+        # hit resumes only from a page boundary that has one
+        snapshots = counter(
+            "dllama_state_snapshots_total",
+            "Recurrent-state snapshots of linear-attention rows by event: "
+            "taken (copied out of a row at a page boundary of its admission "
+            "prefill), published (attached to the radix node that ends "
+            "there), restored (copied into a row on a prefix hit), evicted "
+            "(slot reclaimed: with its page, or LRU when the slots ran out)",
+            labelnames=("event",),
+        )
+        self.snapshots_taken = snapshots.labels(event="taken")
+        self.snapshots_published = snapshots.labels(event="published")
+        self.snapshots_restored = snapshots.labels(event="restored")
+        self.snapshots_evicted = snapshots.labels(event="evicted")
         # host-RAM / disk spill tier (ISSUE 11, engine/spill.py): the
         # capacity ladder below the HBM pool
         self.spill_pages = counter(

@@ -31,6 +31,8 @@ SPAN_NAMES = (
     "spec_verify_chunk",
     "prefix_spill_reload",
     "prefix_publish",
+    "state_snapshot",
+    "state_restore",
     # the scheduler's own phases (ISSUE 23): what the host was doing
     # between device programs; tools read them off a capture's xplane
     "sched_build",

@@ -284,6 +284,32 @@ class TestBatchedBlockedAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 
+class TestBuiltBuckets:
+    def test_a_bucket_never_built_rides_a_larger_one_that_was(self, tmp_path):
+        """Under load a program build stalls every lane: a row bucket first
+        met after a larger one has run dispatches the larger program (the
+        extra rows masked), and the tokens are the same."""
+        prompt, n = PROMPTS[0], 6
+        temp, topp, seed = SAMPLING[0]
+        want = single_stream_tokens(build_engine(tmp_path, "a.m"), prompt, temp, topp, seed, n)
+
+        sched = BatchScheduler(build_engine(tmp_path, "b.m"), n_rows=4, chunk=4)
+        streams = [sched.new_stream() for _ in range(4)]
+        assert sched._built_bucket(2) == 2  # nothing built: the bucket itself
+        sched._decode_built = {4}
+        buckets = []
+        real = sched._note_dispatched
+        sched._note_dispatched = lambda bucket, *a: (buckets.append(bucket), real(bucket, *a))[1]
+        assert batch_stream_tokens(streams[1], prompt, temp, topp, seed, n) == want
+        assert set(buckets) == {4} and sched._decode_built == {4}
+        # a bucket that has run is used as it is
+        sched._decode_built = {2, 4}
+        buckets.clear()
+        streams[1].reset()
+        assert batch_stream_tokens(streams[1], prompt, temp, topp, seed, n) == want
+        assert set(buckets) == {2}
+
+
 class TestBatchApi:
     """The API server's StreamSlots submit into the shared scheduler:
     completions through the batched path match the classic per-stream

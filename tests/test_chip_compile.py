@@ -110,6 +110,46 @@ def test_q40_f32_kernel_compiles(one_chip, n, d, T):
     _compile_q40(q40._q40_matmul_f32, n, d, T, one_chip)
 
 
+# Solar-Open2's shapes: 20 held experts of width 1280 over a hidden size of 4096 (gate|up
+# 4096 -> 2560, down 1280 -> 4096), 64 linear-attention heads of 128
+@pytest.mark.parametrize("rows", [32, 256])
+@pytest.mark.parametrize("n,d,shared", [(4096, 2560, True), (1280, 4096, False)])
+def test_q40_grouped_kernel_compiles_over_a_bank_of_held_experts(one_chip, monkeypatch, n, d, shared, rows):
+    """One launch over the stacked bank, at a decode bucket and at a prefill
+    chunk (whose block sums need more than the compiler's default 16 MiB of
+    scoped VMEM: the launch asks for 32)."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    one = _qm_shape(n, d, one_chip)
+    E = 20
+    bank = q40.QuantizedMatrix(
+        jax.ShapeDtypeStruct((E,) + one.qs.shape, jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((E,) + one.scales.shape, jnp.float32, sharding=one_chip), n, d)
+    x = jax.ShapeDtypeStruct(((rows, n) if shared else (E, rows, n)), jnp.float32, sharding=one_chip)
+    on = jax.ShapeDtypeStruct((E,), jnp.bool_, sharding=one_chip)
+    compiled = q40.q40_grouped_matmul.lower(x, bank, on, role=f"held_experts_t{rows}").compile()
+    assert f"q40_int8_grouped_held_experts_t{rows}" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel,tokens", [("kda_step", 32), ("kda_chunk", 256), ("kda_chunk", 8)])
+def test_the_gated_delta_rule_kernels_compile(one_chip, monkeypatch, kernel, tokens):
+    """The decode step over 32 rows of a 32-row slab (the state aliased in
+    place) and one row's prefill chunk, at 64 heads of 128."""
+    from distributed_llama_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_interpret_default", lambda: False)
+    H, d = 64, 128
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    vec, beta = s(tokens, H, d), s(tokens, H)
+    if kernel == "kda_step":
+        active = jax.ShapeDtypeStruct((tokens,), jnp.bool_, sharding=one_chip)
+        lowered = jax.jit(kda.kda_step, donate_argnums=(0,)).lower(
+            s(tokens, H, d, d), vec, vec, vec, vec, beta, active)
+    else:
+        lowered = jax.jit(kda.kda_chunk).lower(s(H, d, d), vec, vec, vec, vec, beta)
+    text = lowered.compile().as_text()
+    assert f"%{kernel}" in text and "tpu_custom_call" in text
+
+
 def _paged_decode_args(one_chip, K: int, M: int):
     B, S, page, hd = 4, 2048, 64, 128
 

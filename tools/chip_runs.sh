@@ -9,7 +9,9 @@
 # --trace <mode>), `gaps` (a --trace 2 run through benchmark/tools/gaps_by_span.py, which also
 # prints the capture's tables), or 0c / 2c: the same under a HOME, XDG_CACHE_HOME and TMPDIR
 # of their own that start empty, as the driver's check runs them. A run is not started once
-# <budget_s> seconds of the call are gone.
+# <budget_s> seconds of the call are gone, nor, with NEED="metric,metric" in the environment,
+# after a run whose last line is not `correct` or lacks one of those metrics (the chip's
+# minutes are better kept for the repaired tree).
 set -u
 tag=$1; budget=$2; shift 2
 top=$PWD
@@ -21,6 +23,7 @@ for spec in "$@"; do
   n=$((n + 1))
   IFS=: read -r dir mode cell seed <<<"$spec"
   if [ $SECONDS -gt "$budget" ]; then echo "run $n $spec: not started, $SECONDS s gone"; continue; fi
+  if [ -n "${fault:-}" ]; then echo "run $n $spec: not started, $fault"; continue; fi
   cd "$top/$dir" || { echo "run $n $spec: no directory $dir"; continue; }
   t=$SECONDS
   env=()
@@ -39,5 +42,17 @@ for spec in "$@"; do
   cp benchmark/.cache/server.log "$out/server$n.log" 2>/dev/null
   grep -v '^\[window\] per request' "$out/run$n.out" | cut -c1-2500
   tail -c 1500 "$out/run$n.err"
+  if [ -n "${NEED:-}" ]; then
+    fault=$(tail -n 1 "$out/run$n.out" | NEED=$NEED python3 -c '
+import json, os, sys
+try:
+    r = json.loads(sys.stdin.read())
+except ValueError:
+    sys.exit(print("the last line is no result"))
+lacks = [m for m in os.environ["NEED"].split(",") if m not in r.get("metrics", {})]
+if r.get("correct") is not True or r.get("failed") or lacks:
+    print("correct %s, failed %s, lacks %s" % (r.get("correct"), r.get("failed"), lacks))')
+    [ -n "$fault" ] && fault="run $n: $fault"
+  fi
 done
 echo "call took $SECONDS s"
