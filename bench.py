@@ -431,7 +431,9 @@ def run_batch(cfg, name: str, B: int, prefill_len: int = 64, chunk: int = 32,
     bseeds = jnp.arange(B, dtype=jnp.uint32)
     btopks = jnp.zeros(B, jnp.int32)
     pos0 = jnp.full(B, base, jnp.int32)
-    toks, slab = decode_chunk_batched(  # warm/compile
+    # the donated first-token vector comes back advanced to each row's last
+    # token: the next chunk's feed, never sliced out of the bundle
+    toks, slab, nxt = decode_chunk_batched(  # warm/compile
         cfg, params, first, slab, pos0, active, chunk, temps, topps, btopks,
         bseeds,
     )
@@ -439,17 +441,13 @@ def run_batch(cfg, name: str, B: int, prefill_len: int = 64, chunk: int = 32,
     batch_runs = []
     for rep in range(3):
         pos = pos0
-        # the packed bundle's last TOKEN row (rows chunk/chunk+1 carry the
-        # integrity fingerprint + finiteness flags, engine/integrity.py)
-        nxt = toks[chunk - 1]
         with telemetry.trace_span("bench_batch_decode", rep=rep, b=B):
             sw = Stopwatch()
             for _ in range(n_rounds):
-                toks_r, slab = decode_chunk_batched(
+                toks_r, slab, nxt = decode_chunk_batched(
                     cfg, params, nxt, slab, pos, active, chunk, temps, topps,
                     btopks, bseeds,
                 )
-                nxt = toks_r[chunk - 1]
                 pos = pos + chunk
             np.asarray(toks_r)
             batch_runs.append(B * n_rounds * chunk / sw.elapsed_s())
@@ -611,7 +609,7 @@ def run_sampled(cfg, name: str, B: int = 4, prefill_len: int = 32,
     topks = jnp.full(B, 64, jnp.int32)
     bseeds = jnp.arange(B, dtype=jnp.uint32)
     pos0 = jnp.full(B, base, jnp.int32)
-    toks, slab = decode_chunk_batched(  # warm/compile
+    toks, slab, nxt = decode_chunk_batched(  # warm/compile
         cfg, params, first, slab, pos0, active, chunk, temps, topps, topks,
         bseeds,
     )
@@ -619,15 +617,13 @@ def run_sampled(cfg, name: str, B: int = 4, prefill_len: int = 32,
     batch_runs = []
     for rep in range(3):
         pos = pos0
-        nxt = toks[chunk - 1]
         with telemetry.trace_span("bench_sampled_batched", rep=rep, b=B):
             sw = Stopwatch()
             for _ in range(n_rounds):
-                toks_r, slab = decode_chunk_batched(
+                toks_r, slab, nxt = decode_chunk_batched(
                     cfg, params, nxt, slab, pos, active, chunk, temps, topps,
                     topks, bseeds,
                 )
-                nxt = toks_r[chunk - 1]
                 pos = pos + chunk
             np.asarray(toks_r)
             batch_runs.append(B * n_rounds * chunk / sw.elapsed_s())
@@ -1411,16 +1407,16 @@ def run_pod(data: int = 2, model: int = 2, parallel: int = 4,
         the pool's do). Aggregate tok/s of the pass."""
         for st in states:
             st["pos"] = jnp.full(st["lanes"], prefill_len, jnp.int32)
-            st["nxt"] = st["first"]
+            # a copy: the chunk program donates its first-token vector
+            st["nxt"] = jnp.array(st["first"])
         sw = Stopwatch()
         for _ in range(n_rounds):
             for st in states:  # async: chunks interleave on device
-                packed, st["slab"] = st["be"].batched_decode_chunk(
+                packed, st["slab"], st["nxt"] = st["be"].batched_decode_chunk(
                     st["g"].params, st["nxt"], st["slab"], st["pos"],
                     st["active"], chunk, st["temps"], st["topps"],
                     st["topks"], st["seeds"],
                 )
-                st["nxt"] = packed[chunk - 1]
                 st["pos"] = st["pos"] + chunk
                 st["last"] = packed
         for st in states:

@@ -61,6 +61,14 @@ Architecture
   (MoE models: the batched step uses dense expert mixing — parity holds up
   to expert-sum reordering, and expert HBM reads amortize only once
   B ≥ E/k; see ``llama.forward_step_batched``.)
+* What a chunk needs of each row reaches the device as WHOLE vectors, and
+  the host issues the same handful of device operations a chunk at 1 row
+  and at 32: the token each row's next chunk feeds first lives on the
+  device in ONE carry vector (int32 ``[B_max]``) that the chunk program
+  takes donated and returns advanced, a join writes its row's entry
+  (:func:`_carry_put`), and the row parameters are filled into host numpy
+  buffers that cross with the dispatch. No per-row device operation is
+  issued under the scheduler's lock (tests/test_batch_decode.py counts).
 
 Thread model: request threads call into their own :class:`BatchStream`;
 whichever thread needs tokens first becomes the dispatcher for everyone
@@ -68,6 +76,10 @@ whichever thread needs tokens first becomes the dispatcher for everyone
 the blocking fetch outside it). Joins/leaves take the same lock, so the
 active set is coherent per dispatch; an epoch counter per stream keeps a
 late fetch from delivering a previous request's tokens to a new occupant.
+A decode dispatch never waits for the device with the lock held, and the
+device's queue keeps one order: at most one decode chunk on it, and the
+prompt pieces that were queued when a chunk was delivered run before the
+next chunk is enqueued (``_dispatch_locked``).
 """
 
 from __future__ import annotations
@@ -127,6 +139,16 @@ def _slice_page(pool, pid):
         out.extend(kvc.slice_pool_page(pk, pid))
         out.extend(kvc.slice_pool_page(pv, pid))
     return out
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _carry_put(carry, row, token):
+    """Write ``token`` (a joining row's first decode token: the fused
+    device scalar of ``prefill_device``, or a host int) into entry ``row``
+    of the scheduler's carry vector. The donated carry aliases in place;
+    on the device queue the write lands behind a chunk still in flight, so
+    it also overwrites what a previous occupant's orphaned chunk left."""
+    return carry.at[row].set(token)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -235,8 +257,9 @@ def _slab_prefill_single_paged(
 class BatchStream:
     """One slab row of a :class:`BatchScheduler`, wearing the EngineStream
     serving surface. All mutable request state (position, queue, sampler
-    settings, the device-resident next-token scalar) lives here; the
-    scheduler snapshots it per batched dispatch under its lock."""
+    settings) lives here, but for the token its next chunk feeds first,
+    which stays on the device in the scheduler's carry vector; the
+    scheduler snapshots the rest per batched dispatch under its lock."""
 
     def __init__(self, scheduler: "BatchScheduler", row: int):
         self.scheduler = scheduler
@@ -256,7 +279,6 @@ class BatchStream:
         self._delivered = 0
         self._joined = False
         self._epoch = 0  # bumped per join/leave: stale fetches can't deliver
-        self._first = None  # device scalar (or host int) feeding the next chunk
         self._seed32 = 0  # folded uint32 request seed (stateless counter PRNG)
         self._temperature = 0.0
         self._topp = 0.9
@@ -858,6 +880,23 @@ class BatchScheduler:
             )
         else:
             self._slab = tp_engine.init_batch_cache(n_rows, dtype=engine.cache_dtype)
+        # the token each row's next decode chunk feeds first, on the device:
+        # written at a join, advanced by every chunk program, never fetched.
+        # The write's program is built now, not at the first join
+        self._carry = _carry_put(
+            jnp.zeros((n_rows,), jnp.int32), np.int32(0), np.int32(0)
+        )
+        if self._prefix is not None and self._prefix.spill is not None:
+            # the spill download's program too: the first eviction may come
+            # in the middle of serving, under the scheduler's lock
+            self._download_page(0)
+        # the logits of the newest prompt piece dispatched, and, from each
+        # delivery on, of the newest one that was on the device's queue then:
+        # the next decode chunk is not enqueued in front of it
+        # (_dispatch_locked). Two references: at most two pieces' logits stay
+        # allocated past their request's own use
+        self._last_piece = None
+        self._pieces_first = None
         # row buckets whose plain decode program has been dispatched (built)
         self._decode_built: set[int] = set()
         # (device scalar, tokens) of prefill chunks whose held-expert sums are
@@ -1237,6 +1276,7 @@ class BatchScheduler:
                             stream.row, stream.pos, c,
                         )
                     stream.pos += c
+                    self._last_piece = logits
                     if stream.pos == cut:
                         self._take_snapshot_locked(stream)
             off += c
@@ -1589,10 +1629,10 @@ class BatchScheduler:
                 self.engine._tel.rows_quarantined.inc()
         return [s for s in joined if s._fetch_error is None]
 
-    def _alias_arrays_locked(self, rows, live_flags):
+    def _alias_arrays_locked(self, rows, live):
         """Per-dispatch page tables [len(rows), n_table] + matched lengths
-        (cond held; ``live_flags`` is :meth:`_row_dispatch_arrays_locked`'s
-        liveness list — the ONE definition — not re-derived here): LIVE
+        (cond held; ``live`` is :meth:`_row_dispatch_arrays_locked`'s
+        liveness mask — the ONE definition — not re-derived here): LIVE
         rows without an alias (a miss, or retired mid-build) get matched 0
         — the paged program reads their slab rows only, byte-identical to
         the unpaged dispatch. Bucket-padding rows (not joined: outputs
@@ -1603,44 +1643,45 @@ class BatchScheduler:
         page 0 garbage, which nothing observes."""
         tables = np.zeros((len(rows), self._n_table), np.int32)
         matched = np.zeros(len(rows), np.int32)
-        live = np.array(live_flags, bool)
         for b, s in enumerate(rows):
             if live[b] and s._alias_ids:
                 tables[b, : len(s._alias_ids)] = s._alias_ids
                 matched[b] = s.matched_len
         if live.any():
             matched[~live] = matched[live].max()
-        return jnp.asarray(tables), jnp.asarray(matched)
+        return tables, matched
 
     def _row_dispatch_arrays_locked(self, rows):
         """Per-row arrays shared by the plain-decode and spec-verify chunk
-        builders (cond held): the liveness predicate plus positions /
-        active mask / sampling params / PRNG keys, inert defaults in
-        non-live slots (bucket padding, or rows retired mid-build), and
-        the zero-copy alias arrays when the pool is on (None otherwise).
-        One definition so a lifecycle change to what counts as a live row
-        can never reach one dispatch path and skip the other."""
-        live = [s._joined and s._fetch_error is None for s in rows]
-        pos = jnp.asarray(
-            [s.pos if ok else 0 for s, ok in zip(rows, live)], jnp.int32
+        builders (cond held): the liveness mask (which is the program's
+        ``active``) plus positions / sampling params / folded seeds, inert
+        defaults in non-live slots (bucket padding, or rows retired
+        mid-build), and the zero-copy alias arrays when the pool is on
+        (None otherwise). All of them host numpy buffers: they cross to
+        the device with the dispatch, as whole vectors, and building them
+        issues no device operation whatever the bucket. One definition so
+        a lifecycle change to what counts as a live row can never reach
+        one dispatch path and skip the other."""
+        n = len(rows)
+        live = np.fromiter(
+            (s._joined and s._fetch_error is None for s in rows), bool, n
         )
-        active = jnp.asarray(live, bool)
-        temps = jnp.asarray(
-            [s._temperature if ok else 1.0 for s, ok in zip(rows, live)], jnp.float32
-        )
-        topps = jnp.asarray(
-            [s._topp if ok else 0.9 for s, ok in zip(rows, live)], jnp.float32
-        )
-        topks = jnp.asarray(
-            [s._topk if ok else 0 for s, ok in zip(rows, live)], jnp.int32
-        )
-        seeds = jnp.asarray(
-            [s._seed32 if ok else 0 for s, ok in zip(rows, live)], jnp.uint32
-        )
+        pos = np.zeros(n, np.int32)
+        temps = np.ones(n, np.float32)
+        topps = np.full(n, 0.9, np.float32)
+        topks = np.zeros(n, np.int32)
+        seeds = np.zeros(n, np.uint32)
+        for b in np.flatnonzero(live):
+            s = rows[b]
+            pos[b] = s.pos
+            temps[b] = s._temperature
+            topps[b] = s._topp
+            topks[b] = s._topk
+            seeds[b] = s._seed32
         tables = matched = None
         if self._pool is not None:
             tables, matched = self._alias_arrays_locked(rows, live)
-        return live, pos, active, temps, topps, topks, seeds, tables, matched
+        return live, pos, temps, topps, topks, seeds, tables, matched
 
     def _alias_row_arrays_locked(self, stream: BatchStream):
         """Single-row form of :meth:`_alias_arrays_locked` (the chunked
@@ -1677,7 +1718,15 @@ class BatchScheduler:
                 self.engine.cfg, f"a second decode of row {stream.row} without a prefill from 0"
             )
         with self._cond:
-            stream._first = first_token
+            if self.spec_draft == 0:
+                # behind whatever chunk is in flight on the device queue: the
+                # row's next chunk feeds this token first. (A verify step
+                # feeds from the row's history: _dispatch_spec_locked)
+                if not isinstance(first_token, jax.Array):
+                    first_token = np.int32(first_token)
+                self._carry = _carry_put(
+                    self._carry, np.int32(stream.row), first_token
+                )
             stream._temperature = float(temperature)
             stream._topp = float(topp)
             stream._topk = int(topk)
@@ -1873,13 +1922,11 @@ class BatchScheduler:
                     return stream._queue.popleft()
                 if not stream._joined:
                     raise RuntimeError("next_token on a stream that left the batch")
+                piece = None
                 if self._pending is None:
-                    # dispatch even while another thread is mid-fetch: the
-                    # next chunk's compute then overlaps the fetch round
-                    # trip (the batched analogue of generate_chunks'
-                    # speculative pipelining; at most ONE chunk runs ahead
-                    # — the single pending slot bounds it)
-                    self._dispatch_locked()
+                    # a no-op while another thread is mid-fetch: the next
+                    # chunk goes out once that one is delivered
+                    piece = self._dispatch_locked()
                     if stream._fetch_error is not None:
                         continue  # the dispatch retired this row: re-loop
                         # raises the typed error without a wait cycle
@@ -1887,11 +1934,20 @@ class BatchScheduler:
                     pend = self._pending
                     self._pending = None
                     gen = self._begin_fetch_locked()
-                else:
+                elif piece is None:
                     # another thread is mid-fetch: wait for its notify
                     with self.engine._tel.span("sched_wait", row=stream.row):
                         self._cond.wait(timeout=0.1)
                     continue
+            if pend is None:
+                # queued prompt pieces go first: wait for them with the lock
+                # free (prompts that arrive meanwhile are dispatched at once)
+                with self.engine._tel.span("sched_wait", row=stream.row):
+                    try:
+                        piece.block_until_ready()
+                    except Exception:
+                        pass  # a failed piece fails its own request
+                continue
             self._fetch(pend, gen)
 
     def _run_dispatch_locked(self, joined, dispatch_fn, fail_msg: str):
@@ -1999,15 +2055,41 @@ class BatchScheduler:
             f"corrupted {desc}"
         )
 
-    def _dispatch_locked(self) -> None:
+    def _dispatch_locked(self):
         """Build and dispatch one batched chunk from the joined streams
         (cond lock held; the dispatch itself is asynchronous). Rows inside
         the bucket that are not joined ride along masked-inactive: their
         cache writes DROP and their outputs are discarded. In spec mode the
-        chunk is a batched VERIFY step instead (``_dispatch_spec_locked``)."""
+        chunk is a batched VERIFY step instead (``_dispatch_spec_locked``).
+        Returns None, or the device array the dispatch is waiting for when
+        it declined on account of queued prompt pieces."""
         engine = self.engine
         if self._lost:
             return  # every stream already carries its ReplicaLost
+        if self._fetching:
+            # one decode chunk on the device queue at a time: the next is
+            # dispatched when this one's tokens are in the queues. A chunk
+            # enqueued behind the one being fetched would stand in front of
+            # every prompt piece that arrives meanwhile (a whole chunk more
+            # before a first token), would decode a second chunk for rows
+            # whose requests end with this one, and — a verify step — would
+            # draft from tokens nobody has seen yet
+            return None
+        piece = self._pieces_first
+        if piece is not None:
+            try:
+                ready = piece.is_ready()
+            except Exception:
+                ready = True  # a failed piece fails its own request
+            if not ready:
+                # prompt pieces that were on the device's queue when the last
+                # chunk was delivered run before this chunk wherever it is
+                # enqueued: enqueued now, it would only stand in front of the
+                # prompts that arrive while they run. The caller waits for
+                # the piece outside the lock (next_token) and asks again;
+                # pieces dispatched after that delivery are not waited for
+                return piece
+            self._pieces_first = None
         if self.spec_draft > 0:
             self._dispatch_spec_locked()
             return
@@ -2027,14 +2109,8 @@ class BatchScheduler:
         rows = self._streams[:bucket]
         t_build = time.monotonic()
         with engine._tel.span("sched_build", bucket=bucket, active=len(joined)):
-            live, pos, active, temps, topps, topks, seeds, tables, matched = (
+            active, pos, temps, topps, topks, seeds, tables, matched = (
                 self._row_dispatch_arrays_locked(rows)
-            )
-            first = jnp.stack(
-                [
-                    jnp.asarray(s._first if ok else 0, jnp.int32)
-                    for s, ok in zip(rows, live)
-                ]
             )
         sw = Stopwatch()
 
@@ -2045,33 +2121,39 @@ class BatchScheduler:
             ):
                 from distributed_llama_tpu.models import sampling
 
+                # slab and carry are donated and come back advanced; the
+                # row vectors are host buffers that cross with the call
                 if engine._tp_engine is None:
                     if self._pool is not None:
-                        out, self._slab = (
+                        out, self._slab, self._carry = (
                             sampling.decode_chunk_batched_paged(
-                                engine.cfg, engine.params, first, self._slab,
-                                pos, active, self._pool, self.chunk, temps,
-                                topps, topks, seeds, tables, matched,
+                                engine.cfg, engine.params, self._carry,
+                                self._slab, pos, active, self._pool,
+                                self.chunk, temps, topps, topks, seeds,
+                                tables, matched,
                             )
                         )
                     else:
-                        out, self._slab = sampling.decode_chunk_batched(
-                            engine.cfg, engine.params, first, self._slab, pos,
-                            active, self.chunk, temps, topps, topks, seeds,
+                        out, self._slab, self._carry = (
+                            sampling.decode_chunk_batched(
+                                engine.cfg, engine.params, self._carry,
+                                self._slab, pos, active, self.chunk, temps,
+                                topps, topks, seeds,
+                            )
                         )
                 elif self._pool is not None:
-                    out, self._slab = (
+                    out, self._slab, self._carry = (
                         engine._tp_engine.batched_decode_chunk_paged(
-                            engine.params, first, self._slab, self._pool, pos,
-                            active, self.chunk, temps, topps, topks, seeds,
-                            tables, matched,
+                            engine.params, self._carry, self._slab,
+                            self._pool, pos, active, self.chunk, temps, topps,
+                            topks, seeds, tables, matched,
                         )
                     )
                 else:
-                    out, self._slab = (
+                    out, self._slab, self._carry = (
                         engine._tp_engine.batched_decode_chunk(
-                            engine.params, first, self._slab, pos, active,
-                            self.chunk, temps, topps, topks, seeds,
+                            engine.params, self._carry, self._slab, pos,
+                            active, self.chunk, temps, topps, topks, seeds,
                         )
                     )
             return out
@@ -2090,10 +2172,9 @@ class BatchScheduler:
         with engine._tel.span("sched_post_dispatch", active=len(joined)):
             for s in joined:
                 # the next chunk seeds from this chunk's last token, which
-                # stays device-resident (no fetch on the critical path); its
-                # coins re-key from (seed, position) — nothing else carries
-                # over
-                s._first = out[self.chunk - 1, s.row]
+                # the program left in the carry (no fetch, no per-row slice
+                # on the critical path); its coins re-key from (seed,
+                # position) — nothing else carries over
                 s.pos += self.chunk
         self._decode_built.add(bucket)
         self._note_dispatched(bucket, len(joined), self.chunk)
@@ -2134,15 +2215,14 @@ class BatchScheduler:
         window in a single weight read and accepts/rejects on device. Rows
         advance a VARIABLE number of positions — applied at fetch time,
         because the advance (and the next window's drafts) depend on the
-        fetched results; spec steps therefore never pipeline a second
-        dispatch behind an in-flight fetch."""
+        fetched results (``_dispatch_locked`` never calls this behind an
+        in-flight fetch). That is also why a verify step
+        keeps a feed of its own and does not ride the device carry of the
+        plain chunks: the window is built on the host from host ints, and
+        its first token is the last entry of the row's ``_history`` (the
+        join's first token, then every delivered step's last emitted
+        one)."""
         engine = self.engine
-        if self._lost:
-            return  # every stream already carries its ReplicaLost
-        if self._fetching:
-            # the next window's drafts depend on THIS step's emitted
-            # tokens: wait for the fetch instead of dispatching blind
-            return
         joined = [s for s in self._streams if s._joined]
         if not joined:
             return
@@ -2161,13 +2241,13 @@ class BatchScheduler:
         lens = np.zeros(bucket, np.int32)
         t_build = time.monotonic()
         with engine._tel.span("sched_build", bucket=bucket, active=len(joined)):
-            live, pos, active, temps, topps, topks, seeds, tables, matched = (
+            active, pos, temps, topps, topks, seeds, tables, matched = (
                 self._row_dispatch_arrays_locked(rows)
             )
-        for s, ok in zip(rows, live):
+        for s, ok in zip(rows, active):
             if not ok:
                 continue
-            feed[s.row, :] = int(s._first)  # pad tokens: overwritten KV
+            feed[s.row, :] = s._history[-1]  # pad tokens: overwritten KV
             # never draft past seq_len: the window writes pos..pos+T-1 and
             # out-of-bounds slots drop, but accepted positions must stay
             # inside the cache
@@ -2213,8 +2293,8 @@ class BatchScheduler:
         )
         if out is None:
             return
-        # pos/_first wait for the fetch (the advance is variable and
-        # data-dependent); sampler coins re-key from (seed, position)
+        # pos and the next feed wait for the fetch (the advance is variable
+        # and data-dependent); sampler coins re-key from (seed, position)
         engine._tel.spec_draft_tokens.inc(int(lens.sum()))
         # a verify step is one weight read: a masked row is 1 row-step
         self._note_dispatched(bucket, len(joined), 1)
@@ -2322,6 +2402,7 @@ class BatchScheduler:
         if tel.enabled:
             tel.chunk_fetch_wait.observe(waited)
             tel.chunk_host.observe((t_dispatched - t_build) + (t_end - t_fetched))
+            tel.chunk_build.observe(t_dispatched - t_build)
         if t_end - t_build > self.slow_chunk_s:
             # rare by construction (a chunk is a fraction of a second): say
             # where this one's time went — on the host (building and
@@ -2410,6 +2491,7 @@ class BatchScheduler:
             # the pending chunk N+1 can only be taken (and its tokens
             # queued) strictly after chunk N's tokens are in the queues
             self._fetching = False
+            self._pieces_first = self._last_piece
             for s, epoch in snapshot:
                 if not (s._joined and s._epoch == epoch):
                     # the row left (or its slot has a new occupant) while
@@ -2519,6 +2601,7 @@ class BatchScheduler:
         orphaned = quarantined = 0
         with self._cond:
             self._fetching = False
+            self._pieces_first = self._last_piece
             for s, epoch in snapshot:
                 if not (s._joined and s._epoch == epoch):
                     orphaned += len(emits.get(s.row, ())) or 1
@@ -2545,8 +2628,7 @@ class BatchScheduler:
                 col = emits[s.row]
                 n_emit = len(col)
                 s.pos += n_emit  # the variable advance (deferred from dispatch)
-                s._first = col[-1]  # host int: the next window's feed[0]
-                s._history.extend(col)
+                s._history.extend(col)  # its last entry: the next window's feed[0]
                 s._queue.extend(col)
                 s._delivered += n_emit
                 s.stats.append(entries[s.row])

@@ -542,11 +542,34 @@ def batched_decode_scan(
     return tokens, cache, h, okf
 
 
-@functools.partial(jax.jit, static_argnums=(0, 6), donate_argnums=(3,))
+def batched_chunk_from_carry(
+    cfg: LlamaConfig, params, carry, cache, pos, active, seeds, n_steps: int,
+    temperature, topp, topk, axis_name: str | None = None, paged=None,
+):
+    """The body every batched chunk program shares (single chip and tensor
+    parallel, paged or not): :func:`batched_decode_scan` fed from the
+    scheduler's ``carry`` — int32, one entry a slab row: the token that
+    row's next chunk feeds first; the bucket is the rows ``active`` covers —
+    and the carry advanced: an active row's entry becomes the chunk's last
+    token, every other entry stays (an inactive row's is never read before
+    the row's next join overwrites it). Returns ``(out, cache, carry)``,
+    ``out`` the packed bundle of ``integrity.pack_chunk_outputs``."""
+    b = active.shape[0]
+    tokens, cache, *rest = batched_decode_scan(
+        cfg, params, carry[:b], cache, pos, active, seeds, n_steps,
+        temperature, topp, topk, axis_name=axis_name, paged=paged,
+    )
+    carry = carry.at[:b].set(
+        jnp.where(active, tokens[-1].astype(jnp.int32), carry[:b])
+    )
+    return integrity.pack_chunk_outputs(tokens, *rest), cache, carry
+
+
+@functools.partial(jax.jit, static_argnums=(0, 6), donate_argnums=(2, 3))
 def decode_chunk_batched(
     cfg: LlamaConfig,
     params,
-    first_tokens: jax.Array,
+    carry: jax.Array,
     cache,
     pos: jax.Array,
     active: jax.Array,
@@ -563,23 +586,28 @@ def decode_chunk_batched(
     donated and aliases in place; no sampler state returns — the next
     chunk re-keys its coins from (seed, position).
 
-    Returns ``(out, cache)`` where ``out`` is the packed [n_steps + 2, B]
-    int32 bundle of tokens + per-row logit fingerprint + finiteness flag
-    (engine/integrity.py ``split_chunk_outputs``) — one fetch still moves
-    everything the scheduler needs, and those int32 rows are the ONLY
-    bytes that cross the host per chunk."""
-    tokens, cache, *rest = batched_decode_scan(
-        cfg, params, first_tokens, cache, pos, active, seeds, n_steps,
-        temperature, topp, topk,
+    ``carry`` is the scheduler's vector of next-chunk first tokens (int32,
+    one entry a slab row, at least B long; donated): the bucket's rows of
+    it feed the first step, and it returns advanced
+    (:func:`batched_chunk_from_carry`), so the host never touches a row's
+    token between two chunks.
+
+    Returns ``(out, cache, carry)`` where ``out`` is the packed
+    [n_steps + 2, B] int32 bundle of tokens + per-row logit fingerprint +
+    finiteness flag (engine/integrity.py ``split_chunk_outputs``) — one
+    fetch still moves everything the scheduler needs, and those int32 rows
+    are the ONLY bytes that cross the host per chunk."""
+    return batched_chunk_from_carry(
+        cfg, params, carry, cache, pos, active, seeds, n_steps, temperature,
+        topp, topk,
     )
-    return integrity.pack_chunk_outputs(tokens, *rest), cache
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=(3,))
+@functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=(2, 3))
 def decode_chunk_batched_paged(
     cfg: LlamaConfig,
     params,
-    first_tokens: jax.Array,
+    carry: jax.Array,
     cache,
     pos: jax.Array,
     active: jax.Array,
@@ -595,14 +623,13 @@ def decode_chunk_batched_paged(
     """:func:`decode_chunk_batched` with zero-copy prefix aliasing: rows
     whose prompt hit the radix cache read their matched prefix straight out
     of the shared page pool every step — no gathered slab duplicate exists.
-    Only the slab is donated; the pool is shared across every row and
-    dispatch, so it must never alias. Same packed [n_steps + 2, B] return
-    bundle as :func:`decode_chunk_batched`."""
-    tokens, cache, *rest = batched_decode_scan(
-        cfg, params, first_tokens, cache, pos, active, seeds, n_steps,
-        temperature, topp, topk, paged=(pool, tables, matched),
+    Slab and carry are donated; the pool is shared across every row and
+    dispatch, so it must never alias. Same ``(out, cache, carry)`` return
+    as :func:`decode_chunk_batched`."""
+    return batched_chunk_from_carry(
+        cfg, params, carry, cache, pos, active, seeds, n_steps, temperature,
+        topp, topk, paged=(pool, tables, matched),
     )
-    return integrity.pack_chunk_outputs(tokens, *rest), cache
 
 
 # ---------------------------------------------------------------------------

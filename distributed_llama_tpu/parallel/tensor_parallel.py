@@ -616,19 +616,16 @@ class TensorParallelForward(TransferProbeMixin):
         axis = self.axis
         batch_cache_spec = [self._slab_spec] * cfg.n_layers
 
-        def fn(params, first_tokens, cache, pos, active, temperature, topp,
+        def fn(params, carry, cache, pos, active, temperature, topp,
                topk, seeds):
-            from distributed_llama_tpu.engine import integrity
-
-            tokens, cache, h, okf = sampling.batched_decode_scan(
-                cfg, params, first_tokens, cache, pos, active, seeds, n_steps,
-                temperature, topp, topk, axis_name=axis,
-            )
             # the fingerprint folds the all-gathered full-vocab logits, so
             # every shard packs the same replicated bundle (integrity.py);
             # the sampler's candidate top-k composes over the sharded vocab
             # BEFORE that gather (sampling.sharded_topk_indices)
-            return integrity.pack_chunk_outputs(tokens, h, okf), cache
+            return sampling.batched_chunk_from_carry(
+                cfg, params, carry, cache, pos, active, seeds, n_steps,
+                temperature, topp, topk, axis_name=axis,
+            )
 
         V = self._vec_spec
         mapped = jax.shard_map(
@@ -636,25 +633,28 @@ class TensorParallelForward(TransferProbeMixin):
             mesh=self.mesh,
             in_specs=(self._specs, V, batch_cache_spec, V, V, V, V,
                       V, V),
-            out_specs=(self._tok_out_spec, batch_cache_spec),
+            out_specs=(self._tok_out_spec, batch_cache_spec, V),
             check_vma=False,
         )
-        jitted = jax.jit(mapped, donate_argnums=(2,))
+        jitted = jax.jit(mapped, donate_argnums=(1, 2))
         self._chunk_cache[key] = jitted
         return jitted
 
     def batched_decode_chunk(
-        self, params, first_tokens, cache, pos, active, n_steps, temperature,
+        self, params, carry, cache, pos, active, n_steps, temperature,
         topp, topk, seeds,
     ):
         """One chunk of the batched multi-stream decode under TP: B
         sequences step together with per-row positions/seeds/sampler
         settings, collectives riding the mesh each step. One compiled
-        program per (bucket, chunk) shape; no sampler state returns."""
+        program per (bucket, chunk) shape; no sampler state returns.
+        ``carry`` (the scheduler's first-token vector, donated) feeds the
+        first step and returns advanced, as in
+        ``sampling.decode_chunk_batched``."""
         jitted = self._batched_chunk_jitted(int(n_steps))
         return self._enqueue(
             jitted,
-            params, jnp.asarray(first_tokens), cache, jnp.asarray(pos),
+            params, jnp.asarray(carry), cache, jnp.asarray(pos),
             jnp.asarray(active), jnp.asarray(temperature), jnp.asarray(topp),
             jnp.asarray(topk), jnp.asarray(seeds),
         )
@@ -793,16 +793,13 @@ class TensorParallelForward(TransferProbeMixin):
         axis = self.axis
         batch_cache_spec = [self._slab_spec] * cfg.n_layers
 
-        def fn(params, first_tokens, cache, pool, pos, active, temperature,
+        def fn(params, carry, cache, pool, pos, active, temperature,
                topp, topk, seeds, tables, matched):
-            from distributed_llama_tpu.engine import integrity
-
-            tokens, cache, h, okf = sampling.batched_decode_scan(
-                cfg, params, first_tokens, cache, pos, active, seeds, n_steps,
+            return sampling.batched_chunk_from_carry(
+                cfg, params, carry, cache, pos, active, seeds, n_steps,
                 temperature, topp, topk, axis_name=axis,
                 paged=(pool, tables, matched),
             )
-            return integrity.pack_chunk_outputs(tokens, h, okf), cache
 
         V = self._vec_spec
         mapped = jax.shard_map(
@@ -810,15 +807,15 @@ class TensorParallelForward(TransferProbeMixin):
             mesh=self.mesh,
             in_specs=(self._specs, V, batch_cache_spec, self._pool_spec(),
                       V, V, V, V, V, V, self._table_spec, V),
-            out_specs=(self._tok_out_spec, batch_cache_spec),
+            out_specs=(self._tok_out_spec, batch_cache_spec, V),
             check_vma=False,
         )
-        jitted = jax.jit(mapped, donate_argnums=(2,))
+        jitted = jax.jit(mapped, donate_argnums=(1, 2))
         self._chunk_cache[key] = jitted
         return jitted
 
     def batched_decode_chunk_paged(
-        self, params, first_tokens, cache, pool, pos, active, n_steps,
+        self, params, carry, cache, pool, pos, active, n_steps,
         temperature, topp, topk, seeds, tables, matched,
     ):
         """One batched decode chunk with zero-copy prefix aliasing under
@@ -829,7 +826,7 @@ class TensorParallelForward(TransferProbeMixin):
         jitted = self._batched_chunk_paged_jitted(int(n_steps))
         return self._enqueue(
             jitted,
-            params, jnp.asarray(first_tokens), cache, pool, jnp.asarray(pos),
+            params, jnp.asarray(carry), cache, pool, jnp.asarray(pos),
             jnp.asarray(active), jnp.asarray(temperature), jnp.asarray(topp),
             jnp.asarray(topk), jnp.asarray(seeds), jnp.asarray(tables),
             jnp.asarray(matched),

@@ -301,6 +301,13 @@ class EngineInstruments:
             "Time one batched decode chunk's fetch blocked on the device "
             "(the np.asarray of its token bundle)",
         )
+        self.chunk_build = histogram(
+            "dllama_chunk_build_seconds",
+            "Time the scheduler's lock was held to build and dispatch one "
+            "batched decode chunk (the first part of "
+            "dllama_chunk_host_seconds): consumers and prompt pieces wait "
+            "it out, so it should not grow with the chunk's rows",
+        )
         # an expert layer that holds a share of the experts (ISSUE 26): the
         # programs return, with their tokens, how many of their (token,
         # choice) assignments fell on a held expert

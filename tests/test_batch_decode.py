@@ -284,6 +284,322 @@ class TestBatchedBlockedAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 
+# ---------------------------------------------------------------------------
+# The device carry (ISSUE 27): the token a row's next chunk feeds first lives
+# on the device in ONE vector the chunk program advances; a join writes its
+# row's entry. Driven single-threaded through the scheduler's own steps
+# (_join / kick / next_token / _leave: what stream_decode calls), so a test
+# decides what is in flight when a row joins or leaves.
+# ---------------------------------------------------------------------------
+
+LONG_PROMPTS = [[1, 5, 9, 3, 7], [2, 4, 6, 8, 10, 12], [3, 7, 11], [9, 8, 7, 6]]
+
+
+def carry_sched(tmp_path, paged: bool, n_rows: int, name="bat.m"):
+    kw = dict(prefix_cache=True, kv_pages=24 * n_rows, page_size=4) if paged else {}
+    return BatchScheduler(build_engine(tmp_path, name), n_rows=n_rows, chunk=4, **kw)
+
+
+def reference(tmp_path, requests, n):
+    """Each request's solo stream (prompt, temp, topp, seed) → n tokens, the
+    fused first token included."""
+    engine = build_engine(tmp_path, "ref.m")
+    return [single_stream_tokens(engine, p, t, tp, sd, n) for p, t, tp, sd in requests]
+
+
+def join(sched, stream, request):
+    """Admission as the server does it: prefill + fused first token on the
+    device, then the join. Returns the request's first token (fetched)."""
+    prompt, temp, topp, seed = request
+    first = stream.prefill_device(prompt, temp, topp, seed)
+    sched._join(stream, first, temp, topp, seed, 0)
+    return [stream.fetch_first_token(first)]
+
+
+def take(sched, stream, n):
+    return [sched.next_token(stream) for _ in range(n)]
+
+
+def requests_for(k):
+    return [
+        (LONG_PROMPTS[i % len(LONG_PROMPTS)], *SAMPLING[i % len(SAMPLING)][:2], 100 + i)
+        for i in range(k)
+    ]
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["unpaged", "paged"])
+class TestCarryParity:
+    """Bit-parity of every row's stream with its solo chunked decode across
+    the cases the device carry makes new."""
+
+    def test_join_while_a_chunk_is_in_flight(self, tmp_path, paged):
+        """B's fused first token reaches the next chunk through the carry:
+        its write is queued behind the chunk that runs without B."""
+        reqs = requests_for(2)
+        want = reference(tmp_path, reqs, 9)
+        sched = carry_sched(tmp_path, paged, n_rows=2)
+        a, b = sched.new_stream(), sched.new_stream()
+        out_a = join(sched, a, reqs[0])
+        sched.kick()
+        assert sched._pending is not None  # A's chunk, dispatched and unfetched
+        out_b = join(sched, b, reqs[1])
+        assert sched._pending is not None and len(sched._pending[2]) == 1
+        out_a += take(sched, a, 8)
+        out_b += take(sched, b, 8)
+        assert [out_a, out_b] == want
+
+    def test_slot_retaken_before_the_orphaned_chunk_is_delivered(self, tmp_path, paged):
+        """A leaves with a chunk of its row still in flight; B takes the
+        slot at once. The orphaned chunk advanced the slot's carry entry
+        with A's token; B's join, queued behind it, overwrites that."""
+        reqs = requests_for(3)
+        want = reference(tmp_path, reqs, 9)
+        sched = carry_sched(tmp_path, paged, n_rows=2)
+        a, c = sched.new_stream(), sched.new_stream()
+        out_a, out_c = join(sched, a, reqs[0]), join(sched, c, reqs[1])
+        out_a += take(sched, a, 4)  # chunk 1 fetched and read by A
+        sched.kick()
+        assert len(sched._pending[2]) == 2  # chunk 2: both rows, unfetched
+        sched._leave(a)
+        a.reset()
+        out_b = join(sched, a, reqs[2])  # same slot, chunk 2 still pending
+        assert sched._pending is not None
+        out_c += take(sched, c, 8)  # fetches chunk 2: row 0 of it is orphaned
+        out_b += take(sched, a, 8)
+        assert out_a == want[0][:5]
+        assert [out_c, out_b] == want[1:]
+
+    def test_rollback_between_chunks(self, tmp_path, paged):
+        """An early stop rewinds the row past tokens a chunk had already
+        decoded; the row's next request (reset, same slot) and its
+        neighbour, which kept decoding, both read their own streams."""
+        reqs = requests_for(3)
+        want = reference(tmp_path, reqs, 13)
+        sched = carry_sched(tmp_path, paged, n_rows=2)
+        a, c = sched.new_stream(), sched.new_stream()
+        out_c = join(sched, c, reqs[1])
+        got = []
+        first = a.prefill_device(*reqs[0])
+        a.stream_decode(  # stops after 6 of the 8 tokens its chunks decoded
+            first, lambda prev, tok: (got.append(tok), len(got) < 6)[1],
+            reqs[0][1], reqs[0][2], seed=reqs[0][3], first_prev=reqs[0][0][-1],
+        )
+        assert got == want[0][:6] and a.pos == len(reqs[0][0]) + 5
+        a.rollback(0)
+        out_a = join(sched, a, reqs[2])
+        out_c += take(sched, c, 12)
+        out_a += take(sched, a, 12)
+        assert [out_c, out_a] == want[1:]
+
+    def test_preempted_row_requeues_on_its_slot(self, tmp_path, paged):
+        """A preemption between chunks retires the victim with a chunk of
+        its row in flight; requeued on the same slot with the same seed it
+        streams what an uncontended run streams, and so does the survivor."""
+        from distributed_llama_tpu.engine import faults
+
+        reqs = requests_for(2)
+        want = reference(tmp_path, reqs, 9)
+        sched = carry_sched(tmp_path, paged, n_rows=2)
+        a, c = sched.new_stream(), sched.new_stream()
+        a.priority, c.priority = 0, 5
+        out_a, out_c = join(sched, a, reqs[0]), join(sched, c, reqs[1])
+        out_c += take(sched, c, 4)
+        sched.kick()
+        assert sched.preempt_below(5)
+        with pytest.raises(faults.RowPreempted):
+            take(sched, a, 8)
+        sched._leave(a)
+        a.reset()
+        a.priority = 0
+        out_a = join(sched, a, reqs[0])
+        out_c += take(sched, c, 4)
+        out_a += take(sched, a, 8)
+        assert [out_a, out_c] == want
+
+    def test_bucket_1_to_4_to_16_and_back(self, tmp_path, paged):
+        """Row 0 decodes through every bucket its neighbours' joins and
+        leaves make (1, 4, 16, 1): the carry is one vector for all of
+        them, and each program advances only its bucket's rows."""
+        reqs = requests_for(5)
+        want = reference(tmp_path, reqs, 17)
+        sched = carry_sched(tmp_path, paged, n_rows=16)
+        streams = [sched.new_stream() for _ in range(16)]
+        buckets = []
+        real = sched._note_dispatched
+        sched._note_dispatched = lambda bucket, *a: (buckets.append(bucket), real(bucket, *a))[1]
+        outs = {0: join(sched, streams[0], reqs[0])}
+        outs[0] += take(sched, streams[0], 4)  # bucket 1
+        for row, req in ((1, reqs[1]), (3, reqs[2])):
+            outs[row] = join(sched, streams[row], req)
+        outs[0] += take(sched, streams[0], 4)  # bucket 4
+        outs[15] = join(sched, streams[15], reqs[3])
+        outs[0] += take(sched, streams[0], 4)  # bucket 16
+        for row in (1, 3, 15):
+            outs[row] += take(sched, streams[row], 4)
+            sched._leave(streams[row])
+        outs[0] += take(sched, streams[0], 4)  # alone again
+        # row 1 comes back with another request while row 0 runs on
+        streams[1].reset()
+        outs[1] = outs[1], join(sched, streams[1], reqs[4]) + take(sched, streams[1], 4)
+        assert outs[0] == want[0]
+        assert outs[1] == (want[1][:5], want[4][:5])
+        assert outs[3] == want[2][:5] and outs[15] == want[3][:5]
+        assert buckets[0] == 1 and 4 in buckets and 16 in buckets
+        assert buckets[buckets.index(16):].count(1) >= 1  # and back
+
+
+def test_no_chunk_is_enqueued_behind_the_one_being_fetched(tmp_path):
+    """One decode chunk on the device queue at a time: while a fetch is in
+    flight a dispatch is a no-op, and goes out once that chunk is
+    delivered. (A second chunk in the queue stands in front of every
+    prompt piece that arrives meanwhile.)"""
+    sched = carry_sched(tmp_path, paged=False, n_rows=2)
+    a = sched.new_stream()
+    join(sched, a, requests_for(1)[0])
+    with sched._cond:
+        sched._begin_fetch_locked()  # some thread is blocked in a fetch
+    sched.kick()
+    assert sched._pending is None
+    with sched._cond:
+        sched._fetching = False  # delivered
+    sched.kick()
+    assert sched._pending is not None and take(sched, a, 4)
+
+
+def test_prompt_pieces_queued_at_a_delivery_run_before_the_next_chunk(tmp_path):
+    """A decode chunk is not enqueued in front of the prompt pieces that were
+    on the device's queue when the last chunk was delivered: the dispatch
+    declines, the consumer that asked waits for the piece with the lock free
+    and asks again. Pieces dispatched after that delivery are not waited for."""
+
+    class Piece:
+        ready, waited = False, 0
+
+        def is_ready(self):
+            return self.ready
+
+        def block_until_ready(self):
+            self.waited += 1
+            self.ready = True
+
+    sched = carry_sched(tmp_path, paged=False, n_rows=2)
+    a, b = sched.new_stream(), sched.new_stream()
+    reqs = requests_for(2)
+    want = reference(tmp_path, reqs, 9)
+    out_a = join(sched, a, reqs[0])
+    assert sched._last_piece is not None  # the prompt's own piece
+    out_a += take(sched, a, 4)  # a delivery: what was queued by then goes first
+    assert sched._pieces_first is sched._last_piece
+    piece = sched._pieces_first = Piece()
+    sched.kick()
+    assert sched._pending is None and not piece.waited  # declined, nobody blocked
+    out_b = join(sched, b, reqs[1])  # a prompt that arrives meanwhile is dispatched at once
+    out_a += take(sched, a, 4)  # waits for the piece, then dispatches for both rows
+    assert piece.waited == 1 and sched._pieces_first is not piece
+    out_b += take(sched, b, 4)
+    assert [out_a, out_b] == [want[0], want[1][:5]]
+
+
+def test_carry_survives_concurrent_joins_and_leaves(tmp_path):
+    """More request threads than cores, a short switch interval, requests of
+    unequal length joining and leaving as they please: every join's write
+    to the carry and every chunk's advance of it happen under the
+    scheduler's lock, so each stream still reads its solo tokens."""
+    import sys
+
+    n_rows, rounds = 12, 2
+    reqs = requests_for(n_rows * rounds)
+    lengths = [5 + (3 * i) % 9 for i in range(len(reqs))]
+    want = reference(tmp_path, reqs, max(lengths))
+    sched = carry_sched(tmp_path, paged=False, n_rows=n_rows)
+    streams = [sched.new_stream() for _ in range(n_rows)]
+    got = [None] * len(reqs)
+    errors = []
+
+    def run(row):
+        try:
+            for k in range(rounds):
+                i = row * rounds + k
+                streams[row].reset()
+                got[i] = batch_stream_tokens(streams[row], *reqs[i], lengths[i])
+        except Exception as e:  # pragma: no cover - failure detail
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(row,)) for row in range(n_rows)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=240)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors
+    assert got == [w[:n] for w, n in zip(want, lengths)]
+
+
+def device_work(fn):
+    """(device programs launched, host-to-device transfers) while ``fn``
+    ran, read off the profiler's host trace: every launch is one
+    ``...Executable::Execute`` event and every transfer one ``DevicePut...``
+    event, from the jit fast path too, which no Python hook sees."""
+    import collections
+    import glob
+    import tempfile
+
+    with tempfile.TemporaryDirectory() as d:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        (trace,) = glob.glob(d + "/plugins/profile/*/*.xplane.pb")
+        names = collections.Counter(
+            ev.name
+            for plane in jax.profiler.ProfileData.from_file(trace).planes
+            for line in plane.lines
+            for ev in line.events
+        )
+    programs = sum(n for name, n in names.items() if name.endswith("Executable::Execute"))
+    transfers = sum(n for name, n in names.items() if name.startswith("DevicePut"))
+    return programs, transfers
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["unpaged", "paged"])
+@pytest.mark.parametrize("joined", [1, 4, 16])
+def test_a_dispatch_issues_the_same_device_work_at_any_row_count(tmp_path, joined, paged):
+    """Under the scheduler's lock a decode dispatch launches ONE program
+    (the chunk) and moves one host buffer per row vector, whatever the
+    bucket: nothing per row. (The parent launched 5 + 3 a row.)"""
+    # the hook sees what it should: one launch, one transfer, for a jitted
+    # call with one numpy argument
+    add = jax.jit(lambda x, y: x + y)
+    x = jnp.arange(4)
+    add(x, np.arange(4))
+    assert device_work(lambda: add(x, np.arange(4))) == (1, 1)
+
+    sched = carry_sched(tmp_path, paged, n_rows=16)
+    streams = [sched.new_stream() for _ in range(16)]
+    for stream, req in zip(streams[:joined], requests_for(joined)):
+        join(sched, stream, req)
+    for stream in streams[:joined]:
+        take(sched, stream, 4)  # chunk 1: the bucket's program is built and run
+    assert sched._pending is None
+
+    def dispatch():
+        with sched._cond:
+            sched._dispatch_locked()
+
+    programs, transfers = device_work(dispatch)
+    assert sched._pending is not None and len(sched._pending[2]) == joined
+    row_vectors = 8 if paged else 6  # pos, active, temps, topps, topks, seeds (+ tables, matched)
+    assert (programs, transfers) == (1, row_vectors)
+
+
 class TestBuiltBuckets:
     def test_a_bucket_never_built_rides_a_larger_one_that_was(self, tmp_path):
         """Under load a program build stalls every lane: a row bucket first

@@ -299,16 +299,29 @@ def _assert_no_slab_sized_temporaries(compiled, slab):
     assert not others, "slab-sized buffers besides the cache write:\n" + "\n".join(others)
 
 
+def _aliased_outputs(hlo: str) -> dict:
+    """``{output index: parameter number}`` of a compiled module's
+    ``input_output_alias`` header: the results that live in a donated
+    argument's buffer."""
+    header = hlo[hlo.index("input_output_alias={"):].split("\n", 1)[0]
+    return {
+        int(out): int(param)
+        for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    }
+
+
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "unpaged"])
 @pytest.mark.parametrize("rows", [1, SERVED_ROWS])
 def test_served_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch, rows, paged):
     """``sampling.decode_chunk_batched[_paged]`` as the cells dispatch it
     (bucket 1: ``single_stream``; bucket 16: ``chat_shared``, ``batch_decode``):
     in the whole program, the scan's body included, only the per-layer cache
-    write has a result of half a slab leaf or more."""
+    write has a result of half a slab leaf or more. The scheduler's carry of
+    first tokens (one entry a slab row, whatever the bucket) adds none, and
+    comes back in the buffer it was donated in."""
     monkeypatch.setattr(q40, "_interpret_default", lambda: False)  # steer the CPU branch
     cfg, params, slab, pool, s = _served_program_shapes(one_chip)
-    head = (cfg, params, s((rows,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_))
+    head = (cfg, params, s((SERVED_ROWS,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_))
     sampler = (32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
                s((rows,), jnp.uint32))
     if paged:
@@ -316,7 +329,12 @@ def test_served_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch, r
             *head, pool, *sampler, s((rows, 2048 // SERVED_PAGE), jnp.int32), s((rows,), jnp.int32))
     else:
         lowered = sampling.decode_chunk_batched.lower(*head, *sampler)
-    _assert_no_slab_sized_temporaries(lowered.compile(), slab)
+    compiled = lowered.compile()
+    _assert_no_slab_sized_temporaries(compiled, slab)
+    # results: the bundle, the slab's leaves, the carry; arguments: the
+    # weights' leaves, then the carry
+    carry_out, carry_in = 1 + len(jax.tree.leaves(slab)), len(jax.tree.leaves(params))
+    assert _aliased_outputs(compiled.as_text())[carry_out] == carry_in
 
 
 def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):

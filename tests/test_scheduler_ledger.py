@@ -106,6 +106,25 @@ def test_row_step_fates_sum_to_the_work_the_chunks_computed(tmp_path, enabled, n
     assert host.count == wait.count == bucket.count and host.sum > 0 and wait.sum >= 0
 
 
+@pytest.mark.parametrize("spec_draft", [0, 3], ids=["chunk", "verify"])
+def test_chunk_build_seconds_is_observed_once_a_dispatched_chunk(tmp_path, enabled, spec_draft):
+    """``dllama_chunk_build_seconds``: the time the scheduler's lock was held
+    for a dispatch, one observation a chunk (plain or verify), and a part of
+    that chunk's host time."""
+    engine = build_engine(tmp_path)
+    sched = BatchScheduler(engine, n_rows=4, chunk=4, spec_draft=spec_draft)
+    streams = [sched.new_stream() for _ in range(4)][:3]
+    popped, errs = decode_all(sched, streams, LENGTHS, spec_draft=spec_draft)
+    assert errs == [None] * 3 and sched._pending is None
+    build = telemetry.REGISTRY.get("dllama_chunk_build_seconds")
+    host = telemetry.REGISTRY.get("dllama_chunk_host_seconds")
+    assert build.count == rows("bucket").count == host.count > 0
+    assert 0 < build.sum <= host.sum
+    # the ledger with the carry in place: still bucket rows x steps, all accounted for
+    if not spec_draft:
+        assert sum(fates().values()) == rows("bucket").sum * sched.chunk
+
+
 def test_row_step_fates_with_a_quarantined_row(tmp_path, enabled):
     faults.install(faults.parse("batch.row:kind=nan,row=1,after=1,count=1"))
     engine = build_engine(tmp_path)
