@@ -61,7 +61,7 @@ def percentile(values: list[float], q: float) -> float:
     return v[lo] + (v[hi] - v[lo]) * (k - lo)
 
 
-_TAIL = re.compile(r"^(ttft|tpot|stall)_p(\d{1,2})_ms$")
+_TAIL = re.compile(r"^(ttft|tpot|stall)_(?:p(\d{1,2})|(mean))_ms$")
 
 
 def end_to_end(records: list[Record], t0: float, seconds: float, all_deltas: list[float],
@@ -70,9 +70,10 @@ def end_to_end(records: list[Record], t0: float, seconds: float, all_deltas: lis
     are the requests DUE in the window; ``all_deltas`` the arrival instants of
     every delta of every request the run sent, lead-in included, because a
     token received in the window counts wherever its request was due.
-    ``names`` are the metrics wanted: ``out_tok_s``, or a percentile over the
-    window's completed requests named ``<ttft|tpot|stall>_p<NN>_ms``; a name
-    this function does not know is left to the caller. What follows a name's
+    ``names`` are the metrics wanted: ``out_tok_s``, or over the window's
+    completed requests a percentile, ``<ttft|tpot|stall>_p<NN>_ms``, or the
+    arithmetic mean, ``<ttft|tpot|stall>_mean_ms``; a name this function does
+    not know is left to the caller. What follows a name's
     first dot only tells entries of one quantity apart (``tpot_p50_ms.batch``
     is ``tpot_p50_ms`` under the bound of the cells that list it)."""
     done = [r for r in records if r.ok]
@@ -87,7 +88,8 @@ def end_to_end(records: list[Record], t0: float, seconds: float, all_deltas: lis
         quantity = name.split(".")[0]
         m = _TAIL.match(quantity)
         if m and samples[m.group(1)]:
-            metrics[name] = percentile(samples[m.group(1)], int(m.group(2)))
+            v = samples[m.group(1)]
+            metrics[name] = sum(v) / len(v) if m.group(3) else percentile(v, int(m.group(2)))
         elif quantity == "out_tok_s":
             metrics[name] = in_window / seconds
     lags = [(r.sent - r.due) * 1e3 for r in records if not math.isnan(r.sent)]

@@ -8,7 +8,10 @@ as run-to-run spread that is not the system's.
 
 Prompt text is drawn from characters the synthetic tokenizer never merges, so
 one character is one token and ``chat_tokens`` is exact; set-up checks it
-against the server's ``usage.prompt_tokens``.
+against the server's ``usage.prompt_tokens``. A mix may name the characters
+its own text is drawn from (``alphabet``): how many distinct tokens a prompt
+holds decides how alike its rows route in a sparse-expert model, which is
+work (PERF.md, PR 29).
 """
 
 from __future__ import annotations
@@ -151,8 +154,22 @@ class Request:
     turn: int = 0
 
 
-def _text(rng: random.Random, n: int) -> str:
-    return "".join(rng.choices(ALPHABET, k=n))
+def _text(rng: random.Random, n: int, chars: str = ALPHABET) -> str:
+    return "".join(rng.choices(chars, k=n))
+
+
+def mix_alphabet(mix: dict) -> str:
+    """The characters the mix's prompt text is drawn from: its ``alphabet``,
+    else ``ALPHABET``. Each is a printable ASCII character, named once, and
+    none is a letter of a multi-character piece, so no two can merge and one
+    character stays one token."""
+    chars = mix.get("alphabet", ALPHABET)
+    merging = set("".join(p for p in _PIECES if len(p) > 1))
+    bad = [c for c in chars if not ("!" <= c <= "~") or c in merging]
+    if not chars or bad or len(set(chars)) != len(chars):
+        raise ValueError(f"mix alphabet {chars!r}: empty, a character twice, or one that is not "
+                         f"printable ASCII or could merge: {bad!r}")
+    return chars
 
 
 def _body(messages: list[dict], max_tokens: int) -> dict:
@@ -174,6 +191,7 @@ def open_loop_schedule(mix: dict, seed: int, seconds: float) -> list[Request]:
     before 0 are not sent (a turn's body carries its whole history, so
     nothing depends on them)."""
     rng = random.Random(seed)
+    chars = mix_alphabet(mix)
     slot_rng = random.Random(mix.get("template_seed", 1))
     ses = mix.get("sessions", {})
     turns_cap = int(ses.get("turns_max", 1))
@@ -195,7 +213,8 @@ def open_loop_schedule(mix: dict, seed: int, seconds: float) -> list[Request]:
 
     users = stratified(mix["user_tokens"], n_turns_total, slot_rng, integer=True)
     sysp = mix.get("system_prompts")
-    sys_texts = [_text(rng, int(sysp["tokens"])) for _ in range(int(sysp["pool"]))] if sysp else []
+    sys_texts = ([_text(rng, int(sysp["tokens"]), chars) for _ in range(int(sysp["pool"]))]
+                 if sysp else [])
     sys_ids = ([i for i, c in enumerate(_zipf_counts(len(sys_texts), float(sysp["zipf_s"]), n_sessions))
                 for _ in range(c)] if sysp else [None] * n_sessions)
     slot_rng.shuffle(sys_ids)
@@ -220,12 +239,12 @@ def open_loop_schedule(mix: dict, seed: int, seconds: float) -> list[Request]:
             n_user = min(users[at + turn], room)
             if n_user < min_user or due >= horizon:
                 break  # the session has filled its context, or the run ends
-            messages = messages + [{"role": "user", "content": _text(rng, n_user)}]
+            messages = messages + [{"role": "user", "content": _text(rng, n_user, chars)}]
             n_prompt = chat_tokens(messages)
             if due >= 0.0:
                 out.append(Request(0, due, _body(messages, n_out), n_prompt, n_out, s, turn))
             # the history a later turn carries: generator text of the asked length
-            messages = messages + [{"role": "assistant", "content": _text(rng, n_out)}]
+            messages = messages + [{"role": "assistant", "content": _text(rng, n_out, chars)}]
         at += k
     out.sort(key=lambda r: r.due_s)
     for i, r in enumerate(out):
@@ -237,6 +256,7 @@ def closed_loop_requests(mix: dict, seed: int):
     """An endless stream of single-turn requests for a closed loop: blocks of
     ``block`` stratified shapes, each block shuffled by the seed."""
     rng = random.Random(seed)
+    chars = mix_alphabet(mix)
     block = int(mix.get("block", 64))
     cap = int(mix["context_cap"])
     index = 0
@@ -244,7 +264,7 @@ def closed_loop_requests(mix: dict, seed: int):
         users = stratified(mix["user_tokens"], block, rng, integer=True)
         outs = stratified(mix["output_tokens"], block, rng, integer=True)
         for n_user, n_out in zip(users, outs):
-            messages = [{"role": "user", "content": _text(rng, n_user)}]
+            messages = [{"role": "user", "content": _text(rng, n_user, chars)}]
             n_prompt = chat_tokens(messages)
             if n_prompt + n_out > cap:
                 raise ValueError(f"closed-loop shape {n_prompt}+{n_out} exceeds context_cap {cap}")

@@ -17,7 +17,8 @@ REPO = tiny_root.REPO
 OPEN = {"out_tok_s", "setup_s"}  # the open-loop cell's latencies are recorded, not judged
 NAMES = {  # each miniature cell reports what the real cell it stands for reports (tiny_root.build)
     "tiny.open": OPEN,
-    "tiny-moe.closed": OPEN | {"ttft_p50_ms", "tpot_p50_ms.batch", "stall_p50_ms.batch"},
+    # the 16-row closed loop: pace as a median; ttft and the freeze recorded (.rows16)
+    "tiny-moe.closed": OPEN | {"tpot_p50_ms.batch"},
     "tiny-tp4.closed": OPEN | {"ttft_p50_ms", "tpot_p50_ms", "stall_p50_ms"},
 }
 
@@ -67,9 +68,12 @@ NEW_COUNTERS = {"decode_consumed_share", "decode_orphaned_share", "decode_active
                 "program_builds_in_window"}
 
 
-@pytest.mark.parametrize("cell,suffix", [("tiny.open", "open"), ("tiny-moe.closed", "closed")])
-def test_trace_2_measures_as_trace_0_does_and_then_traces_in_the_same_process(
-        root, cell, suffix, monkeypatch):
+RECORDED = {"tiny.open": {"ttft_p50_ms.open", "tpot_p50_ms.open", "stall_p50_ms.open"},
+            "tiny-moe.closed": {"ttft_p50_ms.rows16", "stall_p50_ms.rows16"}}
+
+
+@pytest.mark.parametrize("cell", ["tiny.open", "tiny-moe.closed"])
+def test_trace_2_measures_as_trace_0_does_and_then_traces_in_the_same_process(root, cell, monkeypatch):
     from benchmark.harness import cell as cell_mod
 
     phases = []
@@ -104,8 +108,10 @@ def test_trace_2_measures_as_trace_0_does_and_then_traces_in_the_same_process(
     assert NAMES[cell] <= set(result["metrics"])
     # ... and the per-layer ones, the counters read over the MEASURED window
     per_layer = set(result["metrics"]) - NAMES[cell]
-    assert NEW_COUNTERS | {f"server_ttft_ms_mean.{suffix}", f"prefill_chunks_ahead_mean.{suffix}",
-                           "device_idle_share", "decode_ms_per_tok"} <= per_layer
+    # (neither cell judges ttft, so the readers that move it elsewhere move out_tok_s here: .open)
+    assert NEW_COUNTERS | RECORDED[cell] | {"server_ttft_ms_mean.open", "prefill_chunks_ahead_mean.open",
+                                            "device_idle_share", "decode_ms_per_tok"} <= per_layer
+    assert not {m for m in per_layer if m.endswith(".closed")}
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert 0 < m["decode_consumed_share"] <= 100 and 0 <= m["decode_orphaned_share"] < 100
     assert 0 < m["decode_row_fill_share"] <= 100 and m["program_builds_in_window"] == 0

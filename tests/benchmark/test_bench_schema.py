@@ -164,6 +164,22 @@ def test_metrics(bench):
     assert readers == {m["name"].split(".")[0] for m in bench["per_layer"]}  # none unused
 
 
+def test_a_per_layer_entry_moves_a_metric_that_every_cell_of_its_list_reports(bench):
+    """``moves`` names an end-to-end entry; a cell in which the per-layer entry is reported (the
+    cells of its list, every cell where it has none) has to report that entry too, or the
+    driver finds a line whose per-layer metric moves nothing the cell is judged on."""
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    cells = [w["name"] for w in bench["workloads"]]
+    stray = [(m["name"], cell, m["moves"]) for m in bench["per_layer"] for cell in m.get("workloads", cells)
+             if cell not in e2e[m["moves"]].get("workloads", cells)]
+    assert stray == []
+    # and one form a quantity and cell: no cell is judged on a quantity as a median and as a mean
+    for cell in cells:
+        judged = [m["name"].split("_")[0] for m in bench["end_to_end"]
+                  if cell in m.get("workloads", cells) and re.match(r"(ttft|tpot|stall)_", m["name"])]
+        assert len(judged) == len(set(judged)), (cell, judged)
+
+
 def test_peaks_table_has_a_source_and_the_v5e(bench):
     peaks = load("benchmark", "peaks.json")
     assert "TPU v5e" in peaks["source"]

@@ -100,6 +100,36 @@ def test_a_suffix_after_the_first_dot_names_the_same_quantity():
     assert metrics["stall_p50_ms.batch"] == pytest.approx(1000.0) and metrics["out_tok_s.x"] == pytest.approx(0.6)
 
 
+def _three_requests():
+    # ttft 200, 400, 900 ms; tpot 150, 300 ms and none (one delta); stall 200, 500 ms and none
+    return [rec(1.0, [1.2, 1.3, 1.5], index=0), rec(2.0, [2.4, 2.5, 3.0], index=1),
+            rec(3.0, [3.9], index=2)]
+
+
+@pytest.mark.parametrize("case,records,names,want", [
+    ("a mean is the sum over the completed requests' samples over their count", _three_requests(),
+     ["ttft_mean_ms", "tpot_mean_ms", "stall_mean_ms"],
+     {"ttft_mean_ms": (200 + 400 + 900) / 3, "tpot_mean_ms": (150 + 300) / 2, "stall_mean_ms": (200 + 500) / 2}),
+    ("it is not the median, which the same samples still give", _three_requests(),
+     ["ttft_mean_ms.batch", "ttft_p50_ms"], {"ttft_mean_ms.batch": 500.0, "ttft_p50_ms": 400.0}),
+    ("a failed request gives no sample", _three_requests() + [rec(4.0, [9.0, 9.5], finish="stop", index=3),
+                                                              rec(4.0, [], status=429, index=4)],
+     ["ttft_mean_ms", "stall_mean_ms"], {"ttft_mean_ms": 500.0, "stall_mean_ms": 350.0}),
+    ("a name with a suffix reads the same quantity", _three_requests(),
+     ["stall_mean_ms", "stall_mean_ms.batch", "stall_mean_ms.rows16"],
+     {"stall_mean_ms": 350.0, "stall_mean_ms.batch": 350.0, "stall_mean_ms.rows16": 350.0}),
+    ("an unknown form is left to the caller", _three_requests(),
+     ["ttft_mean_ms", "ttft_max_ms", "ttft_mean_s", "queue_mean_ms", "ttft_mean", "ttft_pmean_ms"],
+     {"ttft_mean_ms": 500.0}),
+    ("no completed request, no mean", [rec(1.0, [], status=503)], ["ttft_mean_ms", "tpot_mean_ms"], {}),
+])
+def test_the_mean_form(case, records, names, want):
+    metrics, _ = stats.end_to_end(records, 0.0, 10.0, [], names)
+    assert set(metrics) == set(want), case
+    for name, value in want.items():
+        assert metrics[name] == pytest.approx(value), f"{case}: {name}"
+
+
 def test_a_window_with_no_completed_request_reports_no_latency():
     metrics, details = stats.end_to_end([rec(1.0, [], status=503)], 0.0, 5.0, [],
                                         ["ttft_p95_ms", "out_tok_s"])

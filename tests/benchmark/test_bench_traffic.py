@@ -102,6 +102,25 @@ def test_closed_loop_blocks_hold_the_same_multiset_for_every_seed():
 
 
 @pytest.mark.parametrize("name", MIXES)
+def test_a_mix_draws_its_text_from_its_own_alphabet_or_the_generators(name):
+    m = mix(name)
+    chars = traffic.mix_alphabet(m)
+    assert chars == m.get("alphabet", traffic.ALPHABET)
+    text = "".join(msg["content"] for r in requests_of(m, 5) for msg in r.body["messages"])
+    assert set(text) == set(chars)  # some thousands of characters: every one is drawn
+
+
+@pytest.mark.parametrize("chars", ["", "abca", "ab c", "abh", "ab\u00e9", "ab\n"],
+                         ids=["empty", "twice", "space", "merges", "not-ascii", "control"])
+def test_an_alphabet_that_could_break_one_character_one_token_is_refused(chars):
+    m = dict(mix("batch_decode"), alphabet=chars)
+    with pytest.raises(ValueError, match="alphabet"):
+        next(traffic.closed_loop_requests(m, 1))
+    with pytest.raises(ValueError, match="alphabet"):
+        traffic.open_loop_schedule(dict(mix("chat_shared"), alphabet=chars), 1, 5.0)
+
+
+@pytest.mark.parametrize("name", MIXES)
 def test_warmup_touches_every_prefill_bucket_the_mix_can_reach(name):
     m = mix(name)
     waves = traffic.warmup_waves(dict(m, warm_pool_overflow=True), 1, 16, 384 * 64)
