@@ -144,7 +144,7 @@ SHAPES_7B = ((4096, 12288), (4096, 4096), (4096, 22016), (11008, 4096), (4096, 3
 
 
 def _kernels_alone(seed: int, shapes=SHAPES_7B, interpret: bool = False) -> None:
-    """(c) — each kernel of the default path, compiled, vs plain XLA.
+    """(c) — each kernel of the served path, compiled, vs plain XLA.
     (``shapes``/``interpret`` exist for the CPU rehearsal of this code.)"""
     import jax
     import jax.numpy as jnp
@@ -154,9 +154,7 @@ def _kernels_alone(seed: int, shapes=SHAPES_7B, interpret: bool = False) -> None
     from distributed_llama_tpu.ops import q40
 
     key = jax.random.PRNGKey(seed)
-    default = q40.default_q40_path()
-    log(f"[c] q40 default path: {default}; tolerance {KERNEL_TOL} of max|want|")
-    kernels = {"int8": q40._q40_matmul_int8, "f32": q40._q40_matmul_f32}
+    log(f"[c] q40 int8 kernel vs the XLA fallback; tolerance {KERNEL_TOL} of max|want|")
     for n, d in shapes:
         np_, dp = q40._n_padded(n), q40._d_padded(d)
         key, k1, k2 = jax.random.split(key, 3)
@@ -171,16 +169,16 @@ def _kernels_alone(seed: int, shapes=SHAPES_7B, interpret: bool = False) -> None
             x = jax.random.normal(kx, (T, n), jnp.float32).astype(jnp.bfloat16)
             want = np.asarray(q40._q40_matmul_fallback_jit(x, qm))
             scale = float(np.abs(want).max())
-            bn, bd = q40._resolve_tiles(qm, T, q40.BLOCK_N, q40.BLOCK_D)
-            for name, fn in kernels.items():
-                compiled = fn.lower(x, qm, block_n=bn, block_d=bd, interpret=interpret).compile()
-                if not interpret and "tpu_custom_call" not in compiled.as_text():
-                    raise SmokeFailure(f"q40 {name} {n}x{d} T={T}: no tpu_custom_call in the compiled text")
-                err = float(np.abs(np.asarray(compiled(x, qm)) - want).max()) / scale
-                log(f"[c] q40_matmul {name}{'*' if name == default else ' '} {n}->{d} T={T} "
-                    f"tiles ({bn},{bd}) max err {err:.2e} of max|want|")
-                if not (np.isfinite(err) and err <= KERNEL_TOL):
-                    raise SmokeFailure(f"q40 {name} {n}x{d} T={T}: err {err} > {KERNEL_TOL}")
+            bn, bd = q40._int8_tiles(qm, T, q40.BLOCK_N, q40.BLOCK_D)
+            compiled = q40._q40_matmul_int8.lower(
+                x, qm, block_n=bn, block_d=bd, interpret=interpret).compile()
+            if not interpret and "tpu_custom_call" not in compiled.as_text():
+                raise SmokeFailure(f"q40 int8 {n}x{d} T={T}: no tpu_custom_call in the compiled text")
+            err = float(np.abs(np.asarray(compiled(x, qm)) - want).max()) / scale
+            log(f"[c] q40_matmul int8 {n}->{d} T={T} "
+                f"tiles ({bn},{bd}) max err {err:.2e} of max|want|")
+            if not (np.isfinite(err) and err <= KERNEL_TOL):
+                raise SmokeFailure(f"q40 int8 {n}x{d} T={T}: err {err} > {KERNEL_TOL}")
 
     # the chip's attention: the blocked XLA scan (prefill) and the segmented
     # paged scan (batched decode) vs one full-S softmax einsum in f32
@@ -374,7 +372,7 @@ def tp_phase(seed: int) -> None:
     import numpy as np
 
     from distributed_llama_tpu.engine import InferenceEngine
-    from distributed_llama_tpu.ops import collectives, q40
+    from distributed_llama_tpu.ops import collectives
 
     device = _device(4)
     _setup_child()
@@ -391,7 +389,7 @@ def tp_phase(seed: int) -> None:
     e4 = InferenceEngine(MODEL_32, dtype="q40", tp=4)
     jax.block_until_ready(e4.params)
     log(f"[tp] smoke timing: tp=4 load + placement {time.perf_counter() - t0:.1f} s; "
-        f"all-reduce arm: {collectives.default_impl()}; q40 path: {q40.default_q40_path()}")
+        f"all-reduce arm: {collectives.default_impl()}")
 
     def placement(label, arr):
         devs = sorted(d.id for d in arr.sharding.device_set)

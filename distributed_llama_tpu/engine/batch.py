@@ -1608,7 +1608,7 @@ class BatchScheduler:
         """The ``engine.fused_step`` fault site (ISSUE 17 chaos contract):
         fired per joined row while a batched chunk — plain decode OR spec
         verify — is about to launch the fused per-layer superstep programs
-        (rmsnorm→Q80→matmul epilogue, fused paged attention, the
+        (rmsnorm→Q80→matmul epilogue, paged attention, the
         matmul+all-reduce seam). A row-targeted raise mid-superstep
         quarantines ONLY the victim, releases any page pins it holds, and
         drops it from the dispatch; the survivors' streams must be

@@ -155,7 +155,7 @@ class EngineStream:
         # streams and its last measurement stays valid — one stream's reset
         # must be a NO-OP on the shared cadence (zeroing the engine-wide
         # watermark forced an early re-measurement for every stream under
-        # concurrent serving, ADVICE r5). Clearing this stream's stats
+        # concurrent serving). Clearing this stream's stats
         # shrinks the engine-wide token sum, so the watermark shifts down
         # by the same amount to keep (total - watermark) unchanged.
         cleared = sum(s.n_tokens for s in self.stats)

@@ -38,7 +38,7 @@ Injection sites (the strings passed to :meth:`FaultPlan.fire`):
 ``engine.fused_step``  raise mid-superstep (ISSUE 17): fired per joined
                     row as a batched chunk — plain decode or spec verify —
                     is about to launch the fused per-layer programs
-                    (rmsnorm→Q80→matmul epilogue, fused paged attention,
+                    (rmsnorm→Q80→matmul epilogue, paged attention,
                     the matmul+all-reduce seam). A ``row=`` rule
                     quarantines ONLY the victim and releases its page
                     pins; co-batched survivors stream bit-identically

@@ -20,10 +20,9 @@ is a few microseconds of numpy against a <= seq_len token history —
 noise next to a decode step — and the verify forward
 (``models.llama.forward_verify_batched`` / ``forward_tokens``) plus the
 on-device accept/reject (``models.sampling``) keep everything heavy on
-device. On the prefix-cache hit path the verify window's attention runs
-the fused paged Pallas kernel (``ops.attention.fused_paged_verify_attention``
-— decode's superstep kernel with T-query windows), so a speculative step
-keeps the one-program-per-layer dispatch shape of plain decode.
+device. On the prefix-cache hit path the verify window's attention is
+decode's segmented paged scan with T-query windows
+(``ops.attention.batched_verify_attention``).
 """
 
 from __future__ import annotations

@@ -1,8 +1,9 @@
 """Synthetic `.m` models: random seeded weights in the real file format.
 
 The one shared implementation behind the test suite's tiny golden models
-(tests/model_utils.py re-exports these) and the chaos bench
-(``bench.py --chaos``) — the analogue of the reference's synthetic-spec
+(tests/model_utils.py re-exports these) and the load generator's
+self-hosted server (``loadgen/selfhost.py``) — the analogue of the
+reference's synthetic-spec
 golden tests (src/llama2-tasks-test.cpp:531-565), with the xorshift weight
 fill replaced by seeded numpy. Keeping it next to ModelFileWriter means the
 init rules (rms weights near 1, everything else ~N(0, 1/sqrt(d_in))) and

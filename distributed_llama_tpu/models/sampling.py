@@ -61,8 +61,8 @@ TOPP_FAST_K = 128
 # vocab floor for the partition-based bare-top-p fallback: the bit-space
 # binary searches add ~400 ops to the decode program (a few seconds of XLA
 # compile per decode shape) and only beat the full sort where the sort is
-# actually expensive — production-width vocabularies (53× at V=32k,
-# BENCH_KERNELS_r07.json). Below the floor the routing — and therefore the
+# actually expensive — production-width vocabularies (not measured on the
+# chip). Below the floor the routing — and therefore the
 # compiled program — is byte-identical to the pre-partition one: tiny test
 # models must not pay compile time for a path that would LOSE to their
 # cheap sort (a fresh multi-second compile mid-serving is exactly what the
@@ -831,10 +831,9 @@ def spec_verify_chunk_batched_paged(
     verify windows attend over pool pages for the matched prefix and the
     slab row for the private suffix, bit-identical to the copied-prefix
     verify (the spec × prefix-cache parity contract). The paged verify
-    attention rides the fused Pallas kernel
-    (``ops.attention.fused_paged_verify_attention`` — one program per
-    layer instead of the segmented-scan chain) under the same
-    ``DLT_FUSED_PAGED`` gate and bit-parity pins as the decode hit path."""
+    attention is the segmented scan of the decode hit path
+    (``ops.attention.batched_verify_attention``), under the same
+    bit-parity pins."""
     logits, cache = llama.forward_verify_batched(
         cfg, params, feed, cache, pos, active, paged=(pool, tables, matched)
     )

@@ -13,13 +13,8 @@ context entirely.
 
 from __future__ import annotations
 
-import functools
-import os as _os
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from distributed_llama_tpu.ops import kv_cache as kvc
 
@@ -197,10 +192,8 @@ def blocked_partials(
 
 
 def _decode_partial(qg, pos, chunk: int, cdt, prec):
-    """The per-chunk online-softmax arithmetic of the batched decode scan —
-    ONE definition consumed by both the XLA segmented scan and the fused
-    Pallas kernel body, so the two paths emit the identical op sequence on
-    identical chunk bytes (the mechanism behind their bit-parity)."""
+    """The per-chunk online-softmax arithmetic of the batched decode scan,
+    handed to :func:`_segmented_batched_scan` once per segment."""
     hd = qg.shape[-1]
 
     def partial(kc, vc, start, carry):
@@ -226,10 +219,8 @@ def _decode_partial(qg, pos, chunk: int, cdt, prec):
 
 def _verify_partial(qg, pos, chunk: int, cdt, prec):
     """The per-chunk online-softmax arithmetic of the batched verify scan
-    (speculative decode: T-query windows at pos[b]..pos[b]+T-1) — ONE
-    definition consumed by both the XLA segmented scan and the fused Pallas
-    kernel body, exactly like :func:`_decode_partial`: identical op
-    sequence on identical chunk bytes is the bit-parity mechanism."""
+    (speculative decode: T-query windows at pos[b]..pos[b]+T-1), the
+    T-query form of :func:`_decode_partial`."""
     B, T, K, M, hd = qg.shape
     q_pos = pos[:, None] + jnp.arange(T)[None, :]  # [B, T]
 
@@ -282,16 +273,9 @@ def batched_decode_attention(
     the virtual-row einsum otherwise)."""
     B, K, M, hd = qg.shape
     S, cdt, prec = kvc.slab_facts(cache)
-    if paged is not None and _fused_paged_eligible(qg, cache, paged, chunk):
-        from distributed_llama_tpu import telemetry
-
-        telemetry.note_kernel_path("paged_attention", "pallas_fused")
-        return fused_paged_decode_attention(qg, *cache, pos, chunk, paged)
     if paged is not None:
         from distributed_llama_tpu import telemetry
 
-        # the hit path fell back to the chain of segmented-scan programs —
-        # visible in /metrics so a silent slow path can be alerted on
         telemetry.note_kernel_path("paged_attention", "xla_segmented")
     live = jnp.clip(jnp.max(pos) + 1, 0, S)
     n_chunks = jax.lax.div(live + chunk - 1, chunk)
@@ -303,340 +287,6 @@ def batched_decode_attention(
         partial, cache, paged, chunk, n_chunks, (m0, l0, o0), rows=B
     )
     return o / jnp.maximum(l, 1e-30)[..., None]
-
-
-# ---------------------------------------------------------------------------
-# Fused paged decode-attention (ROADMAP item 1): ONE Pallas program replaces
-# the chain of separate XLA programs the segmented scan compiles on the
-# prefix-hit path (per-segment fori_loops, per-chunk pool gathers, select,
-# einsums, merges — each a separate HLO loop body with its own HBM round
-# trips for the m/l/o carries). The kernel walks the SAME chunk indices in
-# the SAME three segments (pool-only / mixed / slab-only — zero pool
-# traffic on slab-only chunks, exactly like the scan), assembles each
-# chunk's KV bytes with explicit async DMA into VMEM scratch (slab slice,
-# or per-page copies routed through the row's page table), and runs the
-# SHARED per-chunk arithmetic (:func:`_decode_partial`) with the online-
-# softmax carries resident on-chip — so the merge math is the identical op
-# sequence on identical bytes and the output is BIT-IDENTICAL to the
-# eager composition of those per-chunk partials (the EXACT-EMPTY-PARTIAL
-# semantics ride along for free; test-enforced across bf16/f32/i8 and
-# bucket shapes in tests/test_kernel_parity.py). The XLA scan is the same
-# math but its fori_loop codegen may reassociate the merge by ulps at
-# verify widths T>1 (the mechanism _segmented_batched_scan documents) —
-# parity vs the scan is bit-exact at the pinned decode/verify test shapes
-# and within-ulp in general (bench.py --kernels records the divergence).
-#
-# Compiled-mode notes: the KV halves sit in ANY (HBM) memory space and
-# are DMA'd chunk by chunk into VMEM scratch; page ids and loop bounds read
-# from SMEM, queries and mask positions from VMEM — the Mosaic-shaped
-# structure. The page/slab DMAs are DOUBLE-BUFFERED: chunk i+1's
-# copies start into the other scratch slot before chunk i's einsums run, so
-# the loads fly under the compute (``DLT_FUSED_DB=0`` keeps the serial
-# start+wait schedule — the A/B baseline in bench.py --kernels; the
-# schedule only reorders copy issue around unchanged compute, so both arms
-# are bit-identical by construction). The same kernel body serves the
-# speculative-decode verify hit path (T-query windows per row — decode is
-# its T=1 degenerate case; :func:`fused_paged_verify_attention`). The
-# gate in this tree is interpret-mode parity on the CPU mesh; the v5e
-# compiler still refuses the body (see :func:`_fused_paged_enabled`).
-# ---------------------------------------------------------------------------
-
-
-def _fused_paged_enabled() -> bool:
-    """OFF unless ``DLT_FUSED_PAGED=1`` — on every platform, so tier-1
-    exercises the segmented scan the chip runs. The v5e compiler refuses
-    the kernel body ("'tpu.matmul' op Not implemented: Up to 1 batch dim
-    supported": the shared per-chunk einsums carry batch dims B and K;
-    tests/test_chip_compile.py holds the xfail that notices the day it
-    lowers), so asking for it on a chip fails at compile of the decode
-    program. Its parity tests select it explicitly (interpret mode).
-    Read per dispatch decision (trace time)."""
-    env = _os.environ.get("DLT_FUSED_PAGED")
-    return env is not None and env != "0"
-
-
-def _fused_paged_eligible(qg, cache, paged, chunk: int) -> bool:
-    """Shape/dtype gate for the fused kernel: slab and pool halves must
-    agree on quantization class, chunks must be whole pages, and the slab
-    must block evenly (callers already guarantee the last two on the
-    production path — the checks make the fallback safe, not rare)."""
-    if not _fused_paged_enabled():
-        return False
-    pool_k, pool_v, tables, matched = paged
-    halves = (cache,) if kvc.is_fused_leaf(cache) else tuple(cache)
-    quant = isinstance(halves[0], kvc.QuantizedKV)
-    if any(
-        isinstance(h, kvc.QuantizedKV) is not quant
-        for h in halves + (pool_k, pool_v)
-    ):
-        return False
-    page = kvc.pool_page_size(pool_k)
-    return chunk % page == 0 and kvc.slab_facts(cache)[0] % chunk == 0
-
-
-def _double_buffer_default() -> bool:
-    """``DLT_FUSED_DB`` gates the double-buffered DMA schedule (default ON:
-    the schedule only reorders copy issue/wait around unchanged compute, so
-    both arms produce identical bytes by construction — pinned by the A/B
-    arm in bench.py --kernels and tests/test_kernel_parity.py).
-    ``DLT_FUSED_DB=0`` keeps the serial start+wait schedule. Read per
-    dispatch decision (trace time)."""
-    env = _os.environ.get("DLT_FUSED_DB")
-    return env != "0" if env is not None else True
-
-
-def _fused_paged_attention(
-    qg, keys, values, pos, chunk: int, paged, interpret, double_buffer, verify: bool
-):
-    """Shared builder behind :func:`fused_paged_decode_attention` and
-    :func:`fused_paged_verify_attention` — decode is the T=1 degenerate
-    case of the verify window, so ONE kernel body serves both and a parity
-    fix can never reach one entry point and skip the other."""
-    from distributed_llama_tpu.ops.q40 import _interpret_default
-
-    pool_k, pool_v, tables, matched = paged
-    if verify:
-        B, T, K, M, hd = qg.shape
-        lead = (B, T, K, M)
-    else:
-        B, K, M, hd = qg.shape
-        T = 1  # decode: one query per row, live bound max(pos) + 1
-        lead = (B, K, M)
-    S = keys.shape[1]
-    quant = isinstance(keys, kvc.QuantizedKV)
-    page = kvc.pool_page_size(pool_k)
-    ppc = chunk // page
-    n_table = tables.shape[1]
-    nh = 2 if quant else 1
-    cdt = kvc.compute_dtype(keys)
-    prec = kvc.einsum_precision(keys)
-    if interpret is None:
-        interpret = _interpret_default()
-    if double_buffer is None:
-        double_buffer = _double_buffer_default()
-    nslots = 2 if double_buffer else 1
-
-    def halves(h):
-        return (h.data, h.scales) if quant else (h,)
-
-    def scratch_for(h, n_rows: int):
-        """VMEM chunk-scratch shapes mirroring one source's halves — one
-        buffer per DMA slot (two double-buffered, one serial)."""
-        if quant:
-            return [
-                pltpu.VMEM((nslots, n_rows, chunk, K, hd), h.data.dtype),
-                pltpu.VMEM((nslots, n_rows, chunk, K, 1), h.scales.dtype),
-            ]
-        return [pltpu.VMEM((nslots, n_rows, chunk, K, hd), h.dtype)]
-
-    def kernel(*refs):
-        pos_s, matched_s, pos_ref, matched_ref, tables_ref, qg_ref = refs[:6]
-        body = refs[6 : 6 + 4 * nh]
-        out_ref = refs[6 + 4 * nh]
-        scr = refs[7 + 4 * nh :]
-        slab_k, slab_v = body[:nh], body[nh : 2 * nh]
-        pk, pv = body[2 * nh : 3 * nh], body[3 * nh : 4 * nh]
-        sk_scr, sv_scr = scr[:nh], scr[nh : 2 * nh]
-        pk_scr, pv_scr = scr[2 * nh : 3 * nh], scr[3 * nh : 4 * nh]
-        sem = scr[4 * nh]
-
-        pos_ = pos_ref[:]
-        matched_ = matched_ref[:]
-        mk_partial = _verify_partial if verify else _decode_partial
-        partial = mk_partial(qg_ref[:], pos_, chunk, cdt, prec)
-        # loop bounds are SCALAR work: read the SMEM copies (Mosaic loads
-        # vectors from VMEM only, and a vector reduce cannot feed a loop
-        # bound) — integer math, so the bounds equal the scan's exactly
-        pos_max = functools.reduce(jnp.maximum, [pos_s[b] for b in range(B)])
-        m_lo = functools.reduce(jnp.minimum, [matched_s[b] for b in range(B)])
-        m_hi = functools.reduce(jnp.maximum, [matched_s[b] for b in range(B)])
-        live = jnp.clip(pos_max + T, 0, S)
-        n_chunks = jax.lax.div(live + chunk - 1, chunk)
-        a = jnp.minimum(jax.lax.div(m_lo, chunk), n_chunks)
-        b_seg = jnp.clip(jax.lax.div(m_hi + chunk - 1, chunk), a, n_chunks)
-
-        def slab_copies(i, slot):
-            # one sliced DMA per half: the first B slab rows' chunk window
-            # (a dispatch bucket below B_max reads only its own rows,
-            # mirroring kvc.slice_rows_batched(rows=B))
-            return [
-                pltpu.make_async_copy(
-                    r.at[pl.ds(0, B), pl.ds(i * chunk, chunk)],
-                    s.at[slot],
-                    sem.at[slot],
-                )
-                for r, s in zip(slab_k + slab_v, sk_scr + sv_scr)
-            ]
-
-        def pool_copies(i, slot):
-            # page-table-routed copies: page p of chunk i for row b comes
-            # from pool page tables[b, i*ppc + p]. The table window start
-            # clamps exactly like the scan's lax.dynamic_slice on tables.
-            base = jnp.clip(i * ppc, 0, n_table - ppc)
-            cs = []
-            for b in range(B):
-                for p in range(ppc):
-                    pid = tables_ref[b, base + p]
-                    cs.extend(
-                        pltpu.make_async_copy(
-                            r.at[pid],
-                            s.at[slot, b, pl.ds(p * page, page)],
-                            sem.at[slot],
-                        )
-                        for r, s in zip(pk + pv, pk_scr + pv_scr)
-                    )
-            return cs
-
-        def start_loads(i, slot):
-            # chunk i's sources by segment: slab from chunk a up, pool
-            # below chunk b_seg — slab-only chunks issue ZERO pool
-            # traffic, exactly like the scan's segment split
-            @pl.when(i >= a)
-            def _():
-                for c in slab_copies(i, slot):
-                    c.start()
-
-            @pl.when(i < b_seg)
-            def _():
-                for c in pool_copies(i, slot):
-                    c.start()
-
-        def wait_loads(i, slot):
-            # recreate the started descriptors (same refs, same sem slot);
-            # every copy of the chunk is drained before any scratch read.
-            # slots alternate, so chunk i+1's in-flight copies signal the
-            # OTHER slot's semaphore and can never satisfy these waits.
-            @pl.when(i >= a)
-            def _():
-                for c in slab_copies(i, slot):
-                    c.wait()
-
-            @pl.when(i < b_seg)
-            def _():
-                for c in pool_copies(i, slot):
-                    c.wait()
-
-        def read(scrs, slot):
-            if quant:
-                return kvc.QuantizedKV(scrs[0][slot], scrs[1][slot])
-            return scrs[0][slot]
-
-        def with_loads(compute):
-            """Wrap a segment body with the DMA schedule. Double-buffered:
-            start chunk i+1's copies into the other slot FIRST, so they fly
-            under chunk i's einsums; segment membership is resolved per
-            chunk index, so the prefetch crosses segment (and fori_loop)
-            boundaries without special cases. Serial: start+wait the
-            chunk's own copies, nothing in flight during compute."""
-
-            def body_fn(i, carry):
-                slot = jax.lax.rem(i, nslots)
-                if double_buffer:
-                    @pl.when(i + 1 < n_chunks)
-                    def _():
-                        start_loads(i + 1, jax.lax.rem(i + 1, nslots))
-                else:
-                    start_loads(i, slot)
-                wait_loads(i, slot)
-                return compute(i, slot, carry)
-
-            return body_fn
-
-        def compute_pool(i, slot, carry):
-            return partial(read(pk_scr, slot), read(pv_scr, slot), i * chunk, carry)
-
-        def compute_mixed(i, slot, carry):
-            sel = (i * chunk + jnp.arange(chunk))[None, :] < matched_[:, None]
-            kc = kvc.select_kv(sel, read(pk_scr, slot), read(sk_scr, slot))
-            vc = kvc.select_kv(sel, read(pv_scr, slot), read(sv_scr, slot))
-            return partial(kc, vc, i * chunk, carry)
-
-        def compute_slab(i, slot, carry):
-            return partial(read(sk_scr, slot), read(sv_scr, slot), i * chunk, carry)
-
-        if double_buffer:
-            # warm-up: chunk 0's copies have no prior compute to hide under
-            @pl.when(n_chunks > 0)
-            def _():
-                start_loads(0, 0)
-
-        m0 = jnp.full(lead, -jnp.inf, jnp.float32)
-        l0 = jnp.zeros(lead, jnp.float32)
-        o0 = jnp.zeros(lead + (hd,), jnp.float32)
-        carry = jax.lax.fori_loop(0, a, with_loads(compute_pool), (m0, l0, o0))
-        carry = jax.lax.fori_loop(a, b_seg, with_loads(compute_mixed), carry)
-        m, l, o = jax.lax.fori_loop(b_seg, n_chunks, with_loads(compute_slab), carry)
-        out_ref[:] = o / jnp.maximum(l, 1e-30)[..., None]
-
-    # the KV halves stay in HBM (ANY) and reach VMEM scratch by DMA; what
-    # the body reads directly sits where Mosaic can load it: scalars (loop
-    # bounds, page ids) in SMEM, vectors (mask positions, queries) in VMEM
-    any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
-    in_specs = (
-        [smem_spec, smem_spec, vmem_spec, vmem_spec, smem_spec, vmem_spec]
-        + [any_spec] * (4 * nh)
-    )
-    scratch = (
-        scratch_for(keys, B) + scratch_for(values, B)
-        + scratch_for(pool_k, B) + scratch_for(pool_v, B)
-        + [pltpu.SemaphoreType.DMA((nslots,))]
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(lead + (hd,), jnp.float32),
-        in_specs=in_specs,
-        out_specs=vmem_spec,
-        scratch_shapes=scratch,
-        interpret=interpret,
-        name="paged_attention_fused",
-    )(
-        pos.astype(jnp.int32), matched.astype(jnp.int32),
-        pos.astype(jnp.int32), matched.astype(jnp.int32),
-        tables.astype(jnp.int32), qg,
-        *halves(keys), *halves(values), *halves(pool_k), *halves(pool_v),
-    )
-
-
-def fused_paged_decode_attention(
-    qg: jax.Array,  # [B, K, M, hd] f32 grouped queries (one token per row)
-    keys,  # slab cache half [B, S, K, hd] (array or QuantizedKV)
-    values,
-    pos: jax.Array,  # [B] per-row absolute positions
-    chunk: int,
-    paged,  # (pool_k, pool_v, tables [B, n_table], matched [B])
-    interpret: bool | None = None,
-    double_buffer: bool | None = None,
-) -> jax.Array:
-    """The fused Pallas form of the paged :func:`batched_decode_attention`
-    hit path — same segment split, same chunk order, same merge arithmetic,
-    bit-identical output. ``double_buffer`` (default: env ``DLT_FUSED_DB``,
-    on) overlaps chunk i+1's page/slab DMAs with chunk i's einsums.
-    Returns [B, K, M, hd] f32."""
-    return _fused_paged_attention(
-        qg, keys, values, pos, chunk, paged, interpret, double_buffer, verify=False
-    )
-
-
-def fused_paged_verify_attention(
-    qg: jax.Array,  # [B, T, K, M, hd] f32 grouped queries (T = draft k + 1)
-    keys,  # slab cache half [B, S, K, hd] (array or QuantizedKV)
-    values,
-    pos: jax.Array,  # [B] per-row positions of query t=0
-    chunk: int,
-    paged,  # (pool_k, pool_v, tables [B, n_table], matched [B])
-    interpret: bool | None = None,
-    double_buffer: bool | None = None,
-) -> jax.Array:
-    """The fused Pallas form of the paged :func:`batched_verify_attention`
-    hit path (speculative decode) — the same kernel as the decode form with
-    the T-query verify arithmetic (:func:`_verify_partial`) in the chunk
-    body, so each query's output stays bit-identical to the single-token
-    decode step at the same position. Returns [B, T, K, M, hd] f32."""
-    return _fused_paged_attention(
-        qg, keys, values, pos, chunk, paged, interpret, double_buffer, verify=True
-    )
 
 
 def batched_verify_attention(
@@ -659,16 +309,9 @@ def batched_verify_attention(
     ``paged``: the zero-copy prefix read, segmented exactly like
     :func:`batched_decode_attention` — the verify window always sits at
     pos >= matched, so every paged position is causally visible to every
-    query offset and the per-chunk math is unchanged. The paged hit path
-    dispatches to the fused Pallas kernel under the same eligibility gate
-    as decode (:func:`_fused_paged_eligible`, ``DLT_FUSED_PAGED``)."""
+    query offset and the per-chunk math is unchanged."""
     B, T, K, M, hd = qg.shape
     S, cdt, prec = kvc.slab_facts(cache)
-    if paged is not None and _fused_paged_eligible(qg, cache, paged, chunk):
-        from distributed_llama_tpu import telemetry
-
-        telemetry.note_kernel_path("paged_attention", "pallas_fused_verify")
-        return fused_paged_verify_attention(qg, *cache, pos, chunk, paged)
     if paged is not None:
         from distributed_llama_tpu import telemetry
 

@@ -328,8 +328,8 @@ def matmul_all_reduce(
     identically on every shard — what ``models.llama.block_tail``/``ffn``
     route the wo/down projections through. ``axis_name=None`` is the
     single-chip plain matmul. Dispatch ladder: the fused int8+ring Pallas
-    kernel when ``DLT_ALLREDUCE=ring`` + the int8 q40 path + an eligible
-    shape (noted ``fused_ring``); otherwise the unfused matmul followed by
+    kernel when ``DLT_ALLREDUCE=ring`` + an eligible shape (noted
+    ``fused_ring``); otherwise the unfused matmul followed by
     :func:`all_reduce` under the chosen impl (psum / ring_xla / ring).
     Arm parity (tests/test_kernel_parity.py): the psum arm is exactly the
     unfused composition; ring-schedule arms agree within summation-order
@@ -344,14 +344,13 @@ def matmul_all_reduce(
     if impl is None:
         impl = default_impl()
     if impl == "ring":
-        from distributed_llama_tpu.ops.q40 import QuantizedMatrix, default_q40_path
+        from distributed_llama_tpu.ops.q40 import QuantizedMatrix
 
         n = lax.axis_size(axis_name)
         if (
             n > 1
             and isinstance(w, QuantizedMatrix)
             and not w.interleaved
-            and default_q40_path() == "int8"
             and _fused_ring_eligible(x, w, n)
         ):
             out = fused_matmul_ring_all_reduce(x, w, axis_name, n, role)

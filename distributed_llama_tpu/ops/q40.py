@@ -42,13 +42,11 @@ from distributed_llama_tpu.quants import QK
 # Tile sizes tuned on v5e (profiled in-model on real decode programs):
 # (1024, 1024) runs the kernel at ~375 GB/s of packed bytes in a 7B decode;
 # small divisor tiles (256x256) are ~10x slower — per-grid-step overhead
-# dominates. Env overrides exist for tuning on other chip generations.
-import os as _os
-
-BLOCK_N = int(_os.environ.get("DLT_BN", 1024))  # input tile (multiple of 512:
-# the x window needs bn/2 % 128 == 0 and the scales tile bn/64 % 8 == 0)
-BLOCK_D = int(_os.environ.get("DLT_BD", 2048))  # output tile (multiple of 128;
-# 2048 profiled ~4% faster than 1024 on v5e decode; T>8 shrinks it for VMEM)
+# dominates.
+BLOCK_N = 1024  # input tile (multiple of 512: the x window needs
+# bn/2 % 128 == 0 and the scales tile bn/64 % 8 == 0)
+BLOCK_D = 2048  # output tile (multiple of 128; 2048 profiled ~4% faster
+# than 1024 on v5e decode; T>8 shrinks it for VMEM)
 
 
 def _interpret_default() -> bool:
@@ -64,19 +62,6 @@ def _note_path(kernel: str, path: str) -> None:
     from distributed_llama_tpu import telemetry
 
     telemetry.note_kernel_path(kernel, path)
-
-
-def _validate_env_tiles() -> None:
-    """Validates the DLT_BN/DLT_BD env overrides at first kernel use, not
-    import time: a bad tuning value must fail pointing at the knob, not make
-    the whole package (including --help) unimportable. Only the env-derived
-    module defaults are checked (explicit block_n/block_d arguments have
-    looser rules — _largest_divisor_tile snaps them to legal tiles)."""
-    if BLOCK_N % 512 or BLOCK_N <= 0:
-        raise ValueError(f"DLT_BN={BLOCK_N} must be a positive multiple of 512 "
-                         "(otherwise every matmul silently takes the slow XLA fallback)")
-    if BLOCK_D % 128 or BLOCK_D <= 0:
-        raise ValueError(f"DLT_BD={BLOCK_D} must be a positive multiple of 128")
 
 
 @jax.tree_util.register_pytree_node_class
@@ -273,15 +258,19 @@ def concat_shard_packs(mats: list[QuantizedMatrix], axis: str) -> QuantizedMatri
     return QuantizedMatrix(qs, scales, n_logical=m0.n, d_logical=m0.d)
 
 
-def dequantize_tpu(qm: QuantizedMatrix) -> np.ndarray:
-    """Reference unpacking of the TPU layout → f32 [n, d] (standard basis).
-    Trims any tile padding back to the logical dims."""
+def _reject_interleaved(qm: QuantizedMatrix) -> None:
     if qm.interleaved:
         raise ValueError(
             "interleaved pack: the block-interleaved basis is retired — "
             "de-interleave at load (q40.deinterleave_input_rows / "
             "weights.remove_basis_interleave)"
         )
+
+
+def dequantize_tpu(qm: QuantizedMatrix) -> np.ndarray:
+    """Reference unpacking of the TPU layout → f32 [n, d] (standard basis).
+    Trims any tile padding back to the logical dims."""
+    _reject_interleaved(qm)
     qs = np.asarray(qm.qs)
     scales = np.asarray(qm.scales)
     # half-split: low nibbles are logical rows [0, half), high [half, n_pad)
@@ -464,52 +453,6 @@ def interleave_vector(v, n_logical: int):
     return jnp.take(v, jnp.asarray(perm), axis=-1)
 
 
-def _make_q40_kernel(compute_dtype, interpret: bool = False):
-    """Kernel factory: one (d-tile, n-tile) grid step dequantizes the weight
-    tile in VMEM and accumulates into the f32 accumulator.
-
-    Half-split pairing: the packed tile's low nibbles are logical rows
-    [j*bn/2, (j+1)*bn/2) and the high nibbles rows half + the same window,
-    so the two dots contract against two CONTIGUOUS windows of x delivered
-    as separate BlockSpec views — no strided splits, no relayouts anywhere.
-
-    ``compute_dtype`` is bf16 on TPU (Q40's quantization noise dwarfs bf16
-    round-off, and bf16 halves VMEM footprint and VPU work) and f32 in
-    interpret mode (XLA:CPU cannot execute bf16 x bf16 dots)."""
-
-    def kernel(xlo_ref, xhi_ref, qs_ref, slo_ref, shi_ref, out_ref, acc_ref):
-        j = pl.program_id(1)
-
-        @pl.when(j == 0)
-        def _():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        qs = qs_ref[:].astype(jnp.int32)  # [bn/2, bd]; mosaic has no u8->f32 cast
-        # nibbles stay BIASED (0..15): the -8 would cost two more full-size
-        # VPU passes here; the caller subtracts 8*sum(x_block)@scales computed
-        # on the MXU instead (see q40_matmul)
-        lo = (qs & 0xF).astype(compute_dtype)
-        # qs holds u8 values, so >>4 is already in 0..15 — no mask needed
-        # (dropping the redundant & 0xF is worth ~25% on the VPU-bound unpack)
-        hi = (qs >> 4).astype(compute_dtype)
-        # CONSECUTIVE logical rows: each scale row broadcasts over its
-        # 32-row block. jnp.repeat expands the SMALL scales tile to
-        # [bn2, bd] and multiplies in 2-D — reshaping the big nibble
-        # tile to [blocks, 32, bd] and back instead costs Mosaic
-        # relayouts on the large array (measured 61 -> 68 tok/s
-        # end-to-end on a 7B decode).
-        wlo = lo * jnp.repeat(slo_ref[:].astype(compute_dtype), QK, axis=0)
-        whi = hi * jnp.repeat(shi_ref[:].astype(compute_dtype), QK, axis=0)
-        acc_ref[:] += jnp.dot(xlo_ref[:], wlo, preferred_element_type=jnp.float32)
-        acc_ref[:] += jnp.dot(xhi_ref[:], whi, preferred_element_type=jnp.float32)
-
-        @pl.when(j == pl.num_programs(1) - 1)
-        def _():
-            out_ref[:] = acc_ref[:]
-
-    return kernel
-
-
 def kernel_name(kind: str, role: str | None) -> str:
     """The name a kernel launch carries into the compiled program and the
     device trace: its kind, and the ROLE of the matrix it multiplies
@@ -519,21 +462,6 @@ def kernel_name(kind: str, role: str | None) -> str:
     changes a program's text, never its arithmetic or how often it
     compiles."""
     return f"{kind}_{role}" if role else kind
-
-
-def _resolve_tiles(qm: QuantizedMatrix, T: int, block_n: int, block_d: int):
-    """The kernel-eligibility decision, shared by every path: (bn, bd)
-    tiles dividing the padded dims, or None → the XLA fallback. block_n
-    granule 512: the x window (T, bn/2) needs bn/2 % 128 == 0 and the
-    scales tile (bn/64, bd) needs bn/64 % 8 == 0 (mosaic sublane/lane
-    tiling rules) — smaller matrices take the XLA fallback."""
-    _validate_env_tiles()
-    block_d = _shrink_block_d(T, block_d)
-    block_n = _largest_divisor_tile(qm.n_padded, block_n, 512)
-    block_d = _largest_divisor_tile(qm.d_padded, block_d, 128)
-    if block_n is None or block_d is None:
-        return None
-    return block_n, block_d
 
 
 # VMEM budget for the int8 kernel's per-block sums: it holds [bn/64, T, bd]
@@ -547,7 +475,7 @@ def _fit_int8_tiles(qm: QuantizedMatrix, T: int, bn: int, bd: int):
     """Shrink (bn, bd) until the int8 kernel's [bn/64, T, bd] block sums fit
     the VMEM budget: output tile first (more grid steps, same contraction
     order, so results do not depend on T), then the input tile. None when no
-    legal tile fits (very long prefill rows) — the f32 kernel serves."""
+    legal tile fits (T > 2048) — the XLA fallback serves."""
     while (bn // 64) * T * bd * 4 > _INT8_BLOCK_SUM_BYTES:
         if bd > 128:
             bd = _largest_divisor_tile(qm.d_padded, bd // 2, 128)
@@ -558,17 +486,18 @@ def _fit_int8_tiles(qm: QuantizedMatrix, T: int, bn: int, bd: int):
     return bn, bd
 
 
-def default_q40_path() -> str:
-    """The q40 kernel path when the caller doesn't pin one: the int8 MXU
-    Q40×Q80 kernel — ONE default for the CPU tests (interpret mode) and
-    the chip (compiled), so tier-1 exercises the path the chip runs
-    (chip_smoke.py checks the compiled kernel against the XLA fallback at
-    the 7B shapes). ``DLT_Q40_INT8=0`` pins the f32-dequant kernel,
-    ``=1`` the int8 one. Read per dispatch decision (trace time)."""
-    env = _os.environ.get("DLT_Q40_INT8")
-    if env is not None:
-        return "int8" if env != "0" else "f32"
-    return "int8"
+def _int8_tiles(qm: QuantizedMatrix, T: int, block_n: int, block_d: int):
+    """The ONE dispatch decision of the Q40 matmul, from shape alone: the
+    (bn, bd) tiles the int8 kernel runs with, dividing the padded dims, or
+    None → the XLA fallback (a matrix too small or odd to tile, or a T
+    whose block sums no legal tile fits into VMEM). block_n granule 512:
+    the x window (T, bn/2) needs bn/2 % 128 == 0 and the scales tile
+    (bn/64, bd) needs bn/64 % 8 == 0 (mosaic sublane/lane tiling rules)."""
+    block_n = _largest_divisor_tile(qm.n_padded, block_n, 512)
+    block_d = _largest_divisor_tile(qm.d_padded, _shrink_block_d(T, block_d), 128)
+    if block_n is None or block_d is None:
+        return None
+    return _fit_int8_tiles(qm, T, block_n, block_d)
 
 
 def q40_matmul(
@@ -577,124 +506,44 @@ def q40_matmul(
     block_n: int = BLOCK_N,
     block_d: int = BLOCK_D,
     interpret: bool | None = None,
-    path: str | None = None,
     role: str | None = None,
 ) -> jax.Array:
     """y[T, d] = x[T, n] @ dequant(qm), f32 accumulation — the ONE Q40
     matmul entry point (``models.llama._matmul`` routes every quantized
-    weight through here). Dispatches between three implementations behind
-    one signature:
+    weight through here). Shape alone picks the implementation
+    (:func:`_int8_tiles`):
 
-    * ``"int8"`` (default): the int8 MXU kernel — activations quantized to
-      Q80 (per-32-block int8 + f32 scale), per-block exact int32
-      accumulation on the MXU, scale-product epilogue (ROADMAP item 1).
-    * ``"f32"``: the round-5 VPU-dequant kernel (nibbles cast+scaled in
-      VMEM, bf16 MXU dots) — the fallback path for the int8 A/B.
-    * XLA fallback for matrices too small/odd to tile (either ``path``).
+    * the int8 MXU kernel — activations quantized to Q80 (per-32-block
+      int8 + f32 scale), per-block exact int32 accumulation on the MXU,
+      scale-product epilogue;
+    * the XLA fallback for matrices too small/odd to tile and for T past
+      the kernel's VMEM fit.
 
     Every dispatch decision is counted in ``dllama_kernel_path_total``
-    (mxu_int8 / mxu_int8_fusedq / vpu_f32 / xla_fallback) so a silent
-    fallback to the slow path is visible in /metrics. ``role`` names the
-    matrix in the kernel's trace name (:func:`kernel_name`)."""
-    if qm.interleaved:
-        raise ValueError(
-            "interleaved pack: the block-interleaved basis is retired — "
-            "de-interleave at load (q40.deinterleave_input_rows / "
-            "weights.remove_basis_interleave)"
-        )
-    tiles = _resolve_tiles(qm, x.shape[0], block_n, block_d)
+    (mxu_int8 / mxu_int8_fusedq / xla_fallback) so a silent fallback to the
+    slow path is visible in /metrics. ``role`` names the matrix in the
+    kernel's trace name (:func:`kernel_name`)."""
+    _reject_interleaved(qm)
+    tiles = _int8_tiles(qm, x.shape[0], block_n, block_d)
     if tiles is None:
         _note_path("q40_matmul", "xla_fallback")
         return _q40_matmul_fallback_jit(x, qm)
     if interpret is None:
         interpret = _interpret_default()
-    if path is None:
-        path = default_q40_path()
-    bn, bd = tiles
-    int8_tiles = _fit_int8_tiles(qm, x.shape[0], bn, bd) if path == "int8" else None
-    if int8_tiles is not None:
-        _note_path("q40_matmul", "mxu_int8")
-        return _q40_matmul_int8(x, qm, *int8_tiles, interpret, role)
-    _note_path("q40_matmul", "vpu_f32")
-    return _q40_matmul_f32(x, qm, bn, bd, interpret, role)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret", "role"))
-def _q40_matmul_f32(
-    x: jax.Array,
-    qm: QuantizedMatrix,
-    block_n: int,
-    block_d: int,
-    interpret: bool,
-    role: str | None = None,
-) -> jax.Array:
-    """The f32-dequant kernel path: tiles are pre-resolved (the dispatch in
-    :func:`q40_matmul` owns eligibility); internally the kernel runs on the
-    padded arrays (zero-scale padding → exact-zero contributions) and trims
-    the output."""
-    n, d = qm.n, qm.d
-    np_, dp = qm.n_padded, qm.d_padded
-    T = x.shape[0]
-
-    if x.shape[-1] != np_:
-        x = jnp.pad(x, ((0, 0), (0, np_ - x.shape[-1])))
-    compute_dtype = jnp.float32 if interpret else jnp.bfloat16
-    xb = x.astype(compute_dtype)
-    nj = np_ // block_n
-    grid = (dp // block_d, nj)
-    # x is NOT split on the host: the lo/hi halves arrive as two BlockSpec
-    # views over the same array — window j for the low nibbles, window
-    # nj + j (the upper half) for the high nibbles. Contiguous, gather-free.
-    out = pl.pallas_call(
-        _make_q40_kernel(compute_dtype, interpret=interpret),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((T, block_n // 2), lambda i, j: (0, j)),
-            pl.BlockSpec((T, block_n // 2), lambda i, j, nj=nj: (0, nj + j)),
-            pl.BlockSpec((block_n // 2, block_d), lambda i, j: (j, i)),
-            pl.BlockSpec((block_n // 2 // QK, block_d), lambda i, j: (j, i)),
-            pl.BlockSpec((block_n // 2 // QK, block_d), lambda i, j, nj=nj: (nj + j, i)),
-        ],
-        out_specs=pl.BlockSpec((T, block_d), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((T, dp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((T, block_d), jnp.float32)],
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        name=kernel_name("q40_f32", role),
-    )(xb, xb, qm.qs, qm.scales, qm.scales)
-    # the kernel dequantized BIASED nibbles (0..15); subtract the +8 bias as
-    # a rank-reduced correction on the MXU instead of 2 VPU passes over every
-    # weight element: sum(x per 32-block) @ scales = sum_i x_i * s_b(i),d.
-    # The sum MUST accumulate in f32: the correction is ~5x the output
-    # magnitude, so bf16 accumulation error here would dominate the result
-    # (measured 6x accuracy loss) — f32 makes it the exact sum of the same
-    # bf16 x values the kernel consumed.
-    xsum = jnp.sum(xb.astype(jnp.float32).reshape(T, np_ // QK, QK), axis=-1)
-    corr = jax.lax.dot_general(
-        xsum, qm.scales,
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        # true-f32 multiplies: the correction cancels against a 5x-larger
-        # kernel sum, so TPU's default bf16 demotion would leak error; the
-        # dot is rank-n/32 — 3-pass f32 costs nothing measurable
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    out = out - 8.0 * corr
-    return out[:, :d] if dp != d else out
+    _note_path("q40_matmul", "mxu_int8")
+    return _q40_matmul_int8(x, qm, *tiles, interpret, role)
 
 
 # ---------------------------------------------------------------------------
 # int8 MXU path: Q40 weights × Q80 activations (ROADMAP item 1)
 # ---------------------------------------------------------------------------
 #
-# The f32 kernel above is VPU-bound in the nibble unpack: every weight
-# element pays a cast + mask/shift + scale multiply on the 8×128 VPU before
-# the MXU sees it (PERF.md measured ~55% of HBM roofline; the numerically-
-# wrong pltpu.repeat experiment bounded the remaining VPU-broadcast win at
-# ~+9%). The int8 path moves the arithmetic onto the MXU's native int8
-# systolic array instead (reference: matmulQ40vQ80, src/funcs.cpp:287-396 —
-# the reference's production combination for exactly this reason):
+# Dequantizing the weight tile to floats in VMEM is VPU-bound in the nibble
+# unpack: every weight element pays a cast + mask/shift + scale multiply on
+# the 8×128 VPU before the MXU sees it. The int8 kernel moves the arithmetic
+# onto the MXU's native int8 systolic array instead (reference:
+# matmulQ40vQ80, src/funcs.cpp:287-396 — the reference's production
+# combination for exactly this reason):
 #
 #   * activations quantize to Q80 — per-32-block int8 + f32 scale, the
 #     reference's buffer format — ONE cheap pass over the [T, n] x (tiny
@@ -710,9 +559,11 @@ def _q40_matmul_f32(
 #     weight element, and exact: int32 block sums are exact, so the only
 #     new noise is the Q80 activation rounding itself, ~0.4% per element
 #     against Q40's own ~3%);
-#   * the +8 nibble bias stays a rank-reduced MXU correction exactly like
-#     the f32 path, computed from the DEQUANTIZED Q80 block sums (the same
-#     values the kernel consumed, so the cancellation is exact in f32).
+#   * the nibbles' +8 bias comes off AFTER the launch as a rank-reduced MXU
+#     correction, 8 * sum(x per 32-block) @ scales, instead of two more VPU
+#     passes over every weight element; it is computed from the DEQUANTIZED
+#     Q80 block sums (the same values the kernel consumed, so the
+#     cancellation is exact in f32).
 
 
 def quantize_q80(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -752,11 +603,11 @@ def _make_q40_int8_kernel():
         def _():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        # widen first: Mosaic has no 8-bit shift on v5e (same cast as the
-        # f32 kernel's unpack)
+        # widen first: Mosaic has no 8-bit shift or u8->f32 cast on v5e
         qs = qs_ref[:].astype(jnp.int32)
         # nibbles stay BIASED (0..15, exact in int8); the -8 is the caller's
-        # rank-reduced MXU correction, same as the f32 kernel
+        # rank-reduced MXU correction (_int8_core). qs holds u8 values, so
+        # >>4 is already in 0..15 — no mask needed
         lo = (qs & 0xF).astype(jnp.int8)
         hi = (qs >> 4).astype(jnp.int8)
         bn2, bd = qs.shape
@@ -771,8 +622,8 @@ def _make_q40_int8_kernel():
                 preferred_element_type=jnp.int32,
             )  # [nbt, T, bd]
             # scale-product epilogue: sum_b sx[t,b] * sw[b,d] * P[b,t,d] —
-            # [T, nbt, bd]-sized VPU work vs the f32 kernel's per-weight-
-            # element scale multiply
+            # [T, nbt, bd]-sized VPU work against a scale multiply per
+            # weight element
             scaled = P.astype(jnp.float32) * sw_ref[:][:, None, :]
             return jnp.sum(scaled * jnp.transpose(sx_ref[:])[:, :, None], axis=0)
 
@@ -827,8 +678,10 @@ def _int8_core(
         _make_q40_int8_kernel(),
         grid=grid,
         in_specs=[
-            # Q80 activations: lo/hi halves as two contiguous BlockSpec
-            # views, exactly like the f32 kernel's x windows
+            # Q80 activations are NOT split on the host: the lo/hi halves
+            # arrive as two BlockSpec views over the same array — window j
+            # for the low nibbles, window nj + j (the upper half) for the
+            # high nibbles. Contiguous, gather-free.
             pl.BlockSpec((nbt, T, QK), lambda i, j: (j, 0, 0)),
             pl.BlockSpec((nbt, T, QK), lambda i, j, nj=nj: (nj + j, 0, 0)),
             pl.BlockSpec((None, T, nbt), lambda i, j: (j, 0, 0)),
@@ -847,7 +700,10 @@ def _int8_core(
         name=kernel_name("q40_int8", role),
     )(xqb, xqb, sxw, sxw, qm.qs, qm.scales, qm.scales)
     # bias correction on the DEQUANTIZED Q80 block sums: sum_{i in b} of
-    # sx[t,b]*xq[t,i] — f32-exact given the int sums are exact
+    # sx[t,b]*xq[t,i] — f32-exact given the int sums are exact. True-f32
+    # multiplies (HIGHEST): the correction is ~5x the output's magnitude and
+    # cancels against the kernel's sum, so TPU's default bf16 demotion would
+    # leak error; the dot is rank-n/32, three passes cost nothing measurable
     qsum = jnp.sum(xq.astype(jnp.float32).reshape(T, np_ // QK, QK), axis=-1)
     xsum = sx * qsum
     corr = jax.lax.dot_general(
@@ -962,8 +818,7 @@ def q40_grouped_matmul(
     E = bank.qs.shape[0]
     T = x.shape[-2]
     np_, dp = bank.n_padded, bank.d_padded
-    tiles = _resolve_tiles(bank, T, BLOCK_N, BLOCK_D)
-    tiles = _fit_int8_tiles(bank, T, *tiles) if tiles is not None else None
+    tiles = _int8_tiles(bank, T, BLOCK_N, BLOCK_D)
     if tiles is None:
         # packs too small or odd to tile (the tests' toy widths): every
         # expert through the XLA fallback, the unchosen ones zeroed
@@ -1070,15 +925,6 @@ def rmsnorm_ref(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array
     return (weight.astype(jnp.float32) * (xf * jax.lax.rsqrt(ms + eps))).astype(x.dtype)
 
 
-def _fused_q80_enabled() -> bool:
-    """DLT_FUSED_Q80=0 pins the standalone quantize (A/B arm); default on —
-    the fusion reuses the parity-gated int8 kernel unchanged, so the only
-    behavior change is the number of program boundaries. The fusion only
-    engages when :func:`default_q40_path` resolves to int8."""
-    env = _os.environ.get("DLT_FUSED_Q80")
-    return env != "0" if env is not None else True
-
-
 @functools.partial(
     jax.jit, static_argnames=("block_n", "block_d", "interpret", "eps", "role")
 )
@@ -1115,36 +961,25 @@ def rmsnorm_q40_matmul(
     block_n: int = BLOCK_N,
     block_d: int = BLOCK_D,
     interpret: bool | None = None,
-    path: str | None = None,
     role: str | None = None,
 ) -> jax.Array:
-    """y = rmsnorm(x, weight) @ dequant(qm) as ONE fused program when the
-    int8 kernel path is eligible (noted ``mxu_int8_fusedq``); otherwise the
-    unfused reference sequence through :func:`q40_matmul` (which notes its
-    own path). Bit-identical to the unfused sequence either way."""
-    if qm.interleaved:
-        raise ValueError(
-            "interleaved pack: the block-interleaved basis is retired — "
-            "de-interleave at load (q40.deinterleave_input_rows / "
-            "weights.remove_basis_interleave)"
-        )
-    tiles = _resolve_tiles(qm, x.shape[0], block_n, block_d)
-    if path is None:
-        path = default_q40_path()
-    if tiles is not None and path == "int8":
-        tiles = _fit_int8_tiles(qm, x.shape[0], *tiles)
-    if tiles is None or path != "int8" or not _fused_q80_enabled():
+    """y = rmsnorm(x, weight) @ dequant(qm) as ONE fused program wherever
+    the int8 kernel serves (noted ``mxu_int8_fusedq``); otherwise the
+    unfused reference sequence into the XLA fallback. Bit-identical to the
+    unfused sequence either way."""
+    _reject_interleaved(qm)
+    tiles = _int8_tiles(qm, x.shape[0], block_n, block_d)
+    if tiles is None:
         # the standalone rmsnorm is its own program ahead of the matmul's —
         # counted so dllama_kernel_path_total sums to programs-per-step
         # (the fused path absorbs it; docs/OBSERVABILITY.md)
         _note_path("rmsnorm", "xla_standalone")
         xb = rmsnorm_ref(x, weight, eps).astype(jnp.bfloat16)
-        return q40_matmul(xb, qm, block_n, block_d, interpret, path, role)
+        return q40_matmul(xb, qm, block_n, block_d, interpret, role)
     if interpret is None:
         interpret = _interpret_default()
-    bn, bd = tiles
     _note_path("q40_matmul", "mxu_int8_fusedq")
-    return _rmsnorm_q40_matmul_int8(x, weight, qm, bn, bd, interpret, eps, role)
+    return _rmsnorm_q40_matmul_int8(x, weight, qm, *tiles, interpret, eps, role)
 
 
 def _shrink_block_d(T: int, block_d: int) -> int:
@@ -1160,10 +995,7 @@ def _shrink_block_d(T: int, block_d: int) -> int:
       T=128: bd512 21.3 | bd2048 17.5           -> full
       T=256: bd256 34.2 | bd2048 30.5           -> full
       T=512: bd2048 fails to compile (VMEM), bd1024 75.8 | bd256 84.8 -> 1024
-
-    DLT_NO_SHRINK=1 disables the cap (tile-tuning experiments only)."""
-    if _os.environ.get("DLT_NO_SHRINK"):
-        return block_d
+    """
     if T <= 8:
         return block_d  # decode regime: 2048 profiled ~4% over 1024 (round 3)
     if T <= 16:

@@ -245,9 +245,9 @@ class SequenceParallelForward(TransferProbeMixin):
         # — the engine scales its measured per-dispatch transfer estimate by
         # it. Thread-local: concurrent serving streams call forward() from
         # their own request threads, and a shared counter would let stream
-        # A's chunked mid-prefill count leak into stream B's I/T stats split
-        # (ADVICE r5). Each thread reads back exactly what its own forward
-        # issued; threads that never forwarded read the 1-dispatch default.
+        # A's chunked mid-prefill count leak into stream B's I/T stats split.
+        # Each thread reads back exactly what its own forward issued;
+        # threads that never forwarded read the 1-dispatch default.
         self._dispatch_local = threading.local()
 
         prefill = jax.shard_map(

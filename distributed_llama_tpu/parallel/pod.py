@@ -35,8 +35,8 @@ N-independent-engines pool at the same model degree (the per-shard
 programs and collective groups are the same). The honest cost under CPU
 mesh mocks: a slice's dispatch occupies all data rows (replicated
 compute); the N-process pool stacked all replicas on the same devices
-too, so at matched lanes the aggregate is no worse (BENCH_POD_r08.json)
-— on real hardware the follow-up is data-sharded slabs per dispatch.
+too (the aggregate against that pool: not measured on the chip) — on
+real hardware the follow-up is data-sharded slabs per dispatch.
 
 Everything runs under ``JAX_PLATFORMS=cpu`` +
 ``--xla_force_host_platform_device_count`` mesh mocks, the way PR 7's TP

@@ -1,24 +1,19 @@
-"""Shared summary statistics: percentiles and medians for latency samples.
+"""Shared summary statistics: percentiles for latency samples.
 
-The ONE implementation behind every latency summary in the tree: bench.py's
-median-of-N decode/TTFT numbers and the load generator's per-tenant
-TTFT/TPOT/E2E p50/p90/p99 report (``distributed_llama_tpu/loadgen``) both
-call these, so "p99" means the same estimator everywhere a number is
-published. Pure stdlib, no numpy — loadgen's report path must stay
-importable in a client-only process.
+The ONE implementation behind the load generator's per-tenant
+TTFT/TPOT/E2E p50/p90/p99 report (``distributed_llama_tpu/loadgen``), so
+"p99" means the same estimator everywhere a number is published. Pure
+stdlib, no numpy — loadgen's report path must stay importable in a
+client-only process.
 
 Estimator: linear interpolation between closest ranks (the numpy default,
-``q/100 * (n-1)`` fractional index). For odd-length inputs the median is
-exactly the middle order statistic — bit-identical to the ``sorted(xs)[1]``
-median-of-3 idiom this module replaced in bench.py.
+``q/100 * (n-1)`` fractional index).
 """
 
 from __future__ import annotations
 
 import math
-from typing import Callable, Iterable, Sequence, TypeVar
-
-T = TypeVar("T")
+from typing import Iterable
 
 # the percentiles every summary() reports — the serving-latency contract
 # (docs/SERVING.md): median, common-case tail, SLO tail
@@ -42,22 +37,6 @@ def percentile(values: Iterable[float], q: float) -> float:
         return xs[lo]
     frac = idx - lo
     return xs[lo] * (1.0 - frac) + xs[hi] * frac
-
-
-def median(values: Iterable[float]) -> float:
-    """Median by :func:`percentile`; for odd N this is exactly the middle
-    order statistic (``sorted(xs)[n // 2]``)."""
-    return percentile(values, 50.0)
-
-
-def median_by(items: Sequence[T], key: Callable[[T], float]) -> T:
-    """The ITEM whose key is the lower-median order statistic — for
-    median-of-N over structured results (bench round dicts) where the
-    caller needs the whole record, not an interpolated scalar."""
-    if not items:
-        raise ValueError("median_by() of an empty sequence")
-    ranked = sorted(items, key=key)
-    return ranked[(len(ranked) - 1) // 2]
 
 
 def summarize(values: Iterable[float], unit: str = "") -> dict:

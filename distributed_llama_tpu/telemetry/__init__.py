@@ -2,14 +2,14 @@
 
 One process-global :class:`~distributed_llama_tpu.telemetry.registry.MetricsRegistry`
 and one :class:`~distributed_llama_tpu.telemetry.tracer.SpanTracer` back every
-instrument in the engine, the parallel backends, the API server, and bench.py.
+instrument in the engine, the parallel backends and the API server.
 The reference engine's only observability is ad-hoc stat prints
 (reference: src/apps/dllama/dllama.cpp:49-93); this module is the shared sink.
 
 Toggling
 --------
 Telemetry is OFF by default. Enable with the ``--telemetry`` CLI flag
-(dllama-tpu / dllama-tpu-api / bench.py) or ``DLLAMA_TELEMETRY=1`` in the
+(dllama-tpu / dllama-tpu-api) or ``DLLAMA_TELEMETRY=1`` in the
 environment (read once at import). ``enable()`` / ``disable()`` switch the
 process at runtime, but instruments are BOUND at component construction:
 code binds once (engine ``__init__``, server startup) via :func:`counter` /
@@ -547,15 +547,15 @@ def note_kernel_path(kernel: str, path: str) -> None:
     at trace time (once per compiled program build, or once per eager
     call), not per token, so the rate is tiny and the registry lookup per
     event is fine (the note_compile_cache_hit pattern, no bind-once
-    needed). The operational read: any ``fallback``/``xla``-labelled
-    series moving on a TPU deployment means a hot-path program silently
-    took the slow path — the Pallas-kernel A/B gate as a live metric."""
+    needed). The operational read: ``xla_fallback`` moving on a TPU
+    deployment for a matrix of the model's own widths means a hot-path
+    program silently took the slow path."""
     if _enabled:
         REGISTRY.counter(
             "dllama_kernel_path_total",
             "Kernel dispatch decisions by kernel (q40_matmul / "
             "paged_attention / all_reduce) and selected path (mxu_int8 / "
-            "vpu_f32 / pallas_fused / xla_segmented / ici_ring / ring_xla / "
+            "mxu_int8_fusedq / xla_segmented / ici_ring / ring_xla / "
             "psum / xla_fallback); counted at trace time per program build",
             labelnames=("kernel", "path"),
         ).labels(kernel=kernel, path=path).inc()

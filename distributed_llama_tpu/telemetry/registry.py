@@ -5,7 +5,7 @@ are the per-token G/I/T stat prints (reference: src/apps/dllama/dllama.cpp:
 49-93). This registry is the shared sink those ad-hoc prints never had:
 every instrument is a named, typed, optionally-labelled value that can be
 read live (Prometheus text exposition, server /metrics) or snapshotted
-(bench.py, `python -m distributed_llama_tpu.telemetry.dump`).
+(`python -m distributed_llama_tpu.telemetry.dump`).
 
 Design constraints (ISSUE 1):
 
@@ -313,8 +313,8 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
     def snapshot(self) -> dict:
-        """One-shot JSON-able view of every metric (the dump helper's and
-        bench.py's read path)."""
+        """One-shot JSON-able view of every metric (the dump helper's read
+        path)."""
         out: dict[str, dict] = {}
         for name in self.names():
             m = self._metrics[name]

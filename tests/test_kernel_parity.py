@@ -4,23 +4,22 @@ ISSUE 17 decode-megakernel fusions).
 All runnable on the CPU test substrate (conftest pins JAX_PLATFORMS=cpu +
 an 8-device virtual mesh):
 
-* int8 MXU Q40×Q80 matmul: tolerance vs the f32 kernel and the
-  dequantize-then-matmul reference (the int8 path adds ONLY the Q80
-  activation rounding, ~0.5% — far under Q40's own ~3% noise), plus
-  path-dispatch/telemetry checks.
+* int8 MXU Q40×Q80 matmul: tolerance vs the dequantize-then-matmul
+  reference (the int8 path adds ONLY the Q80 activation rounding, ~0.5% —
+  far under Q40's own ~3% noise), plus path-dispatch/telemetry checks.
 * fused rmsnorm→Q80 epilogue (``rmsnorm_q40_matmul``): BIT-parity vs the
   standalone rmsnorm + int8 matmul it replaces — the fused program inlines
   the identical op sequence, so any drift is a bug, not tolerance.
-* fused paged decode-attention AND its verify form: BIT-parity vs the
-  segmented-scan chain they replace, across bf16/f32/i8 and bucket shapes,
-  double-buffered and serial DMA schedules, plus the spec-hit ==
-  plain-decode transitivity on the fused path.
+* the paged segmented scan: the spec-hit == plain-decode transitivity
+  across bf16/f32/i8 and bucket shapes, and what the dispatch counts.
 * ring all-reduce + the matmul_all_reduce seam: the ring schedule
   (ppermute realization — remote DMA has no interpret mode) vs psum
   under the CPU mesh mocks. The fused matmul+ring kernel is TPU-compiled
   only (tests/test_chip_compile.py compiles it for a described v5e).
 """
 
+
+import re
 
 import numpy as np
 import pytest
@@ -31,7 +30,11 @@ import jax.numpy as jnp
 from distributed_llama_tpu.ops import attention as att
 from distributed_llama_tpu.ops import kv_cache as kvc
 from distributed_llama_tpu.ops.q40 import (
+    QuantizedMatrix,
+    _d_padded,
+    _n_padded,
     dequantize_tpu,
+    q40_grouped_matmul,
     q40_matmul,
     quantize_q40_tpu,
     quantize_q80,
@@ -47,18 +50,14 @@ class TestInt8Matmul:
         return quantize_q40_tpu(w), rng
 
     @pytest.mark.parametrize("T", [1, 8])
-    def test_int8_matches_dequant_and_f32_kernel(self, T):
+    def test_int8_matches_dequant(self, T):
         qm, rng = self._qm()
         x = jnp.asarray(rng.randn(T, qm.n).astype(np.float32))
         want = np.asarray(x @ jnp.asarray(dequantize_tpu(qm)))
-        f32 = np.asarray(q40_matmul(x, qm, interpret=True, path="f32"))
-        i8 = np.asarray(q40_matmul(x, qm, interpret=True, path="int8"))
+        i8 = np.asarray(q40_matmul(x, qm, interpret=True))
         scale = np.abs(want).max()
-        # f32 kernel: bf16-free in interpret mode — near-exact
-        np.testing.assert_allclose(f32 / scale, want / scale, atol=1e-5)
         # int8 adds only the Q80 activation rounding (~0.5% per element)
         np.testing.assert_allclose(i8 / scale, want / scale, atol=2e-2)
-        np.testing.assert_allclose(i8 / scale, f32 / scale, atol=2e-2)
 
     def test_q80_block_quantization_contract(self):
         """Standard-only Q80: per-32-block int8 values + f32 scales with
@@ -75,18 +74,16 @@ class TestInt8Matmul:
         np.testing.assert_allclose(deq.reshape(3, -1), x, atol=np.abs(x).max() / 120)
 
     def test_dispatch_fallback_small_shapes(self):
-        """Matrices too small to tile take the XLA fallback on EVERY path
-        (the dispatch owns eligibility, not the path argument)."""
+        """Matrices too small to tile take the XLA fallback."""
         rng = np.random.RandomState(3)
         w = rng.randn(64, 96).astype(np.float32)
         qm = quantize_q40_tpu(w)
         x = jnp.asarray(rng.randn(2, 64).astype(np.float32))
         want = x @ jnp.asarray(dequantize_tpu(qm))
-        for path in ("int8", "f32", None):
-            got = q40_matmul(x, qm, path=path)
-            np.testing.assert_allclose(
-                np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
-            )
+        got = q40_matmul(x, qm)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
+        )
 
     def test_kernel_path_counter(self):
         """Every dispatch decision lands in dllama_kernel_path_total — the
@@ -98,14 +95,13 @@ class TestInt8Matmul:
             telemetry.reset()
             qm, rng = self._qm(n=1024, d=256, seed=9)
             x = jnp.asarray(rng.randn(1, qm.n).astype(np.float32))
-            q40_matmul(x, qm, interpret=True, path="int8")
-            q40_matmul(x, qm, interpret=True, path="f32")
+            q40_matmul(x, qm, interpret=True)
             small = quantize_q40_tpu(rng.randn(64, 96).astype(np.float32))
             q40_matmul(jnp.asarray(rng.randn(1, 64).astype(np.float32)), small)
             ctr = telemetry.REGISTRY.counter(
                 "dllama_kernel_path_total", labelnames=("kernel", "path")
             )
-            for path in ("mxu_int8", "vpu_f32", "xla_fallback"):
+            for path in ("mxu_int8", "xla_fallback"):
                 assert ctr.labels(kernel="q40_matmul", path=path).value >= 1, path
         finally:
             telemetry.reset()
@@ -131,29 +127,16 @@ class TestFusedRmsnormQuantize:
     @pytest.mark.parametrize("T,n,d", [(1, 1024, 256), (8, 512, 128)])
     def test_bit_parity_vs_standalone(self, xdt, T, n, d):
         x, wgt, qm = self._case(T, n, d, xdt)
-        fused = rmsnorm_q40_matmul(x, wgt, qm, interpret=True, path="int8")
+        fused = rmsnorm_q40_matmul(x, wgt, qm, interpret=True)
         unfused = q40_matmul(
-            rmsnorm_ref(x, wgt).astype(jnp.bfloat16), qm,
-            interpret=True, path="int8",
+            rmsnorm_ref(x, wgt).astype(jnp.bfloat16), qm, interpret=True
         )
         np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
 
-    def test_flag_off_takes_standalone_arm(self, monkeypatch):
-        """DLT_FUSED_Q80=0 must route through the exact standalone chain —
-        the committed A/B baseline (bench.py --kernels)."""
-        x, wgt, qm = self._case(1, 1024, 256, jnp.float32)
-        want = q40_matmul(
-            rmsnorm_ref(x, wgt).astype(jnp.bfloat16), qm,
-            interpret=True, path="int8",
-        )
-        monkeypatch.setenv("DLT_FUSED_Q80", "0")
-        off = rmsnorm_q40_matmul(x, wgt, qm, interpret=True, path="int8")
-        np.testing.assert_array_equal(np.asarray(off), np.asarray(want))
-
-    def test_untiled_and_f32_paths_fall_back(self):
-        """Shapes the int8 kernel can't tile (or an explicit f32 path)
-        take the standalone chain — dispatch owns eligibility, exactly
-        like q40_matmul's fallback contract."""
+    def test_untiled_shapes_fall_back(self):
+        """Shapes the int8 kernel can't tile take the standalone chain —
+        dispatch owns eligibility, exactly like q40_matmul's fallback
+        contract."""
         rng = np.random.RandomState(5)
         qm = quantize_q40_tpu(rng.randn(64, 96).astype(np.float32))
         x = jnp.asarray(rng.randn(2, 64).astype(np.float32))
@@ -169,7 +152,7 @@ class TestFusedRmsnormQuantize:
         try:
             telemetry.reset()
             x, wgt, qm = self._case(1, 1024, 256, jnp.float32)
-            rmsnorm_q40_matmul(x, wgt, qm, interpret=True, path="int8")
+            rmsnorm_q40_matmul(x, wgt, qm, interpret=True)
             ctr = telemetry.REGISTRY.counter(
                 "dllama_kernel_path_total", labelnames=("kernel", "path")
             )
@@ -177,6 +160,123 @@ class TestFusedRmsnormQuantize:
         finally:
             telemetry.reset()
             telemetry.disable()
+
+
+def _noted(fn, *args) -> dict[str, int]:
+    """What tracing ``fn(*args)`` once notes in ``dllama_kernel_path_total``,
+    as ``{"kernel/path": count}``. ``jax.eval_shape``: the arguments are
+    shapes and no kernel runs."""
+    from distributed_llama_tpu import telemetry
+
+    telemetry.enable()
+    try:
+        telemetry.reset()
+        jax.eval_shape(fn, *args)
+        series = telemetry.REGISTRY.snapshot().get("dllama_kernel_path_total", {"series": []})["series"]
+        return {f"{s['labels']['kernel']}/{s['labels']['path']}": int(s["value"]) for s in series}
+    finally:
+        telemetry.reset()
+        telemetry.disable()
+
+
+def _qm_shape(n: int, d: int, experts: int = 0) -> QuantizedMatrix:
+    lead = (experts,) if experts else ()
+    np_, dp = _n_padded(n), _d_padded(d)
+    return QuantizedMatrix(
+        jax.ShapeDtypeStruct(lead + (np_ // 2, dp), jnp.uint8),
+        jax.ShapeDtypeStruct(lead + (np_ // 32, dp), jnp.float32), n, d,
+    )
+
+
+def _x_shape(T: int, n: int) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((T, n), jnp.bfloat16)
+
+
+# (n, d) of every Q40 matrix the benchmark's configurations multiply by, at
+# their published widths (benchmark/configs/*.json). Mistral-7B and
+# Mixtral-8x7B share attention and FFN widths (an expert IS the FFN).
+SERVED_MATRICES = {
+    "mistral7b_mixtral8x7b": dict(
+        wqkv=(4096, 6144), wo=(4096, 4096), gate_up=(4096, 28672),
+        down=(14336, 4096), logits=(4096, 32000),
+    ),
+    "solar_open2": dict(
+        qkvg=(4096, 18432), wo=(8192, 4096), lin_in=(4096, 24896),
+        shared_gate_up=(4096, 2560), shared_down=(1280, 4096), logits=(4096, 24576),
+    ),
+}
+# Solar-Open2's held experts: one bank of 20 a layer, through the grouped launch
+SOLAR_BANKS = dict(held_gate_up=(4096, 2560), held_down=(1280, 4096))
+
+
+class TestDispatchTable:
+    """The kernel layer's dispatch is a function of shape: every matrix of
+    the served models takes the int8 kernel at every row count a cell
+    dispatches (decode buckets and the 256-row prefill chunk), a row count
+    past the kernel's VMEM fit takes the XLA fallback, and no environment
+    variable can say otherwise."""
+
+    @pytest.mark.parametrize("T", [1, 16, 32, 64, 256, 2049])
+    @pytest.mark.parametrize("family", sorted(SERVED_MATRICES))
+    def test_served_widths_by_row_count(self, family, T):
+        kernel = T <= 2048
+        for role, (n, d) in SERVED_MATRICES[family].items():
+            qm, x = _qm_shape(n, d), _x_shape(T, n)
+            assert _noted(lambda x, qm: q40_matmul(x, qm, role=role), x, qm) == {
+                "q40_matmul/" + ("mxu_int8" if kernel else "xla_fallback"): 1}, (role, T)
+            w = jax.ShapeDtypeStruct((n,), jnp.float32)
+            want = ({"q40_matmul/mxu_int8_fusedq": 1} if kernel else
+                    {"rmsnorm/xla_standalone": 1, "q40_matmul/xla_fallback": 1})
+            assert _noted(
+                lambda x, w, qm: rmsnorm_q40_matmul(x, w, qm, role=role), x, w, qm
+            ) == want, (role, T)
+        if family == "solar_open2":
+            on = jax.ShapeDtypeStruct((20,), jnp.bool_)
+            for role, (n, d) in SOLAR_BANKS.items():
+                assert _noted(
+                    lambda x, bank, on: q40_grouped_matmul(x, bank, on, role=role),
+                    jax.ShapeDtypeStruct((T, n), jnp.float32), _qm_shape(n, d, experts=20), on,
+                ) == {"q40_grouped_matmul/" + ("mxu_int8" if kernel else "xla_fallback"): 1}, (role, T)
+
+    def test_one_decode_layer_notes_four_matmuls_and_the_paged_scan(self):
+        """One decode layer at the 7B shape (norm+qkv, paged attention, wo,
+        norm+gate_up, down): the fused entry serves both normed matmuls, so
+        no standalone rmsnorm program is noted."""
+        H, K, M, hd, B, S, chunk, page = 4096, 8, 4, 128, 1, 2048, 512, 64
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+        qm, x, wgt = _qm_shape(H, H), _x_shape(1, H), f32(H)
+        kv, pool = f32(B, S, K, hd), f32(8, page, K, hd)
+        ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        def layer(x, wgt, qm, qg, keys, values, pos, pool_k, pool_v, tables, matched):
+            return (
+                rmsnorm_q40_matmul(x, wgt, qm, role="wqkv"),
+                att.batched_decode_attention(
+                    qg, (keys, values), pos, chunk, paged=(pool_k, pool_v, tables, matched)),
+                q40_matmul(x, qm, role="wo"),
+                rmsnorm_q40_matmul(x, wgt, qm, role="gate_up"),
+                q40_matmul(x, qm, role="down"),
+            )
+
+        assert _noted(
+            layer, x, wgt, qm, f32(B, K, M, hd), kv, kv, ints(B), pool, pool,
+            ints(B, S // page), ints(B),
+        ) == {
+            "q40_matmul/mxu_int8_fusedq": 2, "q40_matmul/mxu_int8": 2,
+            "paged_attention/xla_segmented": 1,
+        }
+
+    def test_ops_reads_one_environment_variable(self):
+        """``DLT_ALLREDUCE`` (the all-reduce arm, until a 4-chip cell
+        decides it) is the kernel layer's only switch."""
+        import pathlib
+
+        import distributed_llama_tpu.ops as ops
+
+        found = set()
+        for path in pathlib.Path(ops.__file__).parent.glob("*.py"):
+            found |= set(re.findall(r"\b(?:DLT|DLLAMA)_[A-Z0-9_]+", path.read_text()))
+        assert found == {"DLT_ALLREDUCE"}
 
 
 def _mk_half(rng, shape, dtype):
@@ -189,49 +289,21 @@ def _mk_half(rng, shape, dtype):
     return jnp.asarray(a).astype(dtype)
 
 
-class TestFusedPagedAttention:
-    """Bit-parity of the fused Pallas hit path vs the segmented scan —
-    the EXACT-EMPTY-PARTIAL merge semantics must survive verbatim."""
+class TestPagedScan:
+    """The segmented paged scan (the one paged-attention path): its
+    EXACT-EMPTY-PARTIAL merge semantics across query widths, and what the
+    dispatch counts."""
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "i8"])
     @pytest.mark.parametrize("B,S,chunk,page", [(3, 64, 16, 8), (2, 96, 24, 8)])
-    def test_bit_parity_vs_segmented_scan(self, dtype, B, S, chunk, page):
-        rng = np.random.RandomState(0)
-        K, M, hd, P_ = 2, 2, 8, 16
-        qg = jnp.asarray(rng.randn(B, K, M, hd).astype(np.float32))
-        keys = _mk_half(rng, (B, S, K, hd), dtype)
-        values = _mk_half(rng, (B, S, K, hd), dtype)
-        pool_k = _mk_half(rng, (P_, page, K, hd), dtype)
-        pool_v = _mk_half(rng, (P_, page, K, hd), dtype)
-        tables = jnp.asarray(rng.randint(0, P_, (B, S // page)).astype(np.int32))
-        matched = jnp.asarray(
-            rng.randint(0, S // page + 1, B).astype(np.int32) * page
-        )
-        pos = jnp.asarray(rng.randint(0, S, B).astype(np.int32))
-        paged = (pool_k, pool_v, tables, matched)
-        # the dispatch default is the segmented scan (the path the chip
-        # runs); the fused kernel is selected by its explicit entry point
-        ref = att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
-        # tentpole (c): the double-buffered DMA schedule only reorders copy
-        # issue/wait around unchanged compute — both arms bit-identical
-        for db in (True, False):
-            got = att.fused_paged_decode_attention(
-                qg, keys, values, pos, chunk, paged, double_buffer=db
-            )
-            assert bool(jnp.all(got == ref)), (db, float(jnp.max(jnp.abs(got - ref))))
-
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "i8"])
-    @pytest.mark.parametrize("B,S,chunk,page", [(3, 64, 16, 8), (2, 96, 24, 8)])
-    def test_verify_bit_parity_and_decode_transitivity(self, dtype, B, S, chunk, page):
-        """Tentpole (d): the fused verify kernel vs the segmented verify
-        scan (bit), both DMA schedules, AND the spec-hit == plain-decode
-        transitivity — query t of a verify window at position pos+t is
-        the same MATH as a plain decode at that position, so the two agree
-        within a few f32 roundings. Bit equality ACROSS query widths is
-        not XLA's to promise and the claim is withdrawn (ops/attention.py):
-        it emits one dot and one loop body per width T and rounds them
-        differently — 1 ulp measured on the XLA of jax 0.9, and at
-        chunk=24 even op-by-op dispatch differs."""
+    def test_verify_decode_transitivity(self, dtype, B, S, chunk, page):
+        """Spec-hit == plain-decode: query t of a verify window at position
+        pos+t is the same MATH as a plain decode at that position, so the
+        two agree within a few f32 roundings. Bit equality ACROSS query
+        widths is not XLA's to promise and the claim is withdrawn
+        (ops/attention.py): it emits one dot and one loop body per width T
+        and rounds them differently — 1 ulp measured on the XLA of jax 0.9,
+        and at chunk=24 even op-by-op dispatch differs."""
         rng = np.random.RandomState(4)
         K, M, hd, P_, T = 2, 2, 8, 16, 3
         qg = jnp.asarray(rng.randn(B, T, K, M, hd).astype(np.float32))
@@ -249,12 +321,6 @@ class TestFusedPagedAttention:
         )
         paged = (pool_k, pool_v, tables, matched)
         ref = att.batched_verify_attention(qg, (keys, values), pos, chunk, paged=paged)
-        for db in (True, False):
-            got = att.fused_paged_verify_attention(
-                qg, keys, values, pos, chunk, paged, double_buffer=db
-            )
-            assert bool(jnp.all(got == ref)), (db, float(jnp.max(jnp.abs(got - ref))))
-        # transitivity: verify query t vs plain decode at pos+t
         t = 1
         dec = att.batched_decode_attention(
             qg[:, t], (keys, values), pos + t, chunk, paged=paged
@@ -263,47 +329,17 @@ class TestFusedPagedAttention:
         atol = 8 * np.finfo(np.float32).eps * float(jnp.max(jnp.abs(dec)))
         np.testing.assert_allclose(np.asarray(ref[:, t]), np.asarray(dec), rtol=0, atol=atol)
 
-    def test_verify_dispatch_counts_fused_path(self, monkeypatch):
+    @pytest.mark.parametrize("T", [None, 2], ids=["decode", "verify"])
+    def test_paged_dispatch_counts_the_segmented_scan(self, T):
         from distributed_llama_tpu import telemetry
 
-        monkeypatch.setenv("DLT_FUSED_PAGED", "1")
-        telemetry.enable()
-        try:
-            telemetry.reset()
-            rng = np.random.RandomState(6)
-            B, S, K, M, hd, chunk, page, P_, T = 2, 32, 2, 1, 8, 8, 4, 8, 2
-            qg = jnp.asarray(rng.randn(B, T, K, M, hd).astype(np.float32))
-            keys = _mk_half(rng, (B, S, K, hd), jnp.float32)
-            values = _mk_half(rng, (B, S, K, hd), jnp.float32)
-            paged = (
-                _mk_half(rng, (P_, page, K, hd), jnp.float32),
-                _mk_half(rng, (P_, page, K, hd), jnp.float32),
-                jnp.zeros((B, S // page), jnp.int32),
-                jnp.asarray([8, 0], jnp.int32),
-            )
-            pos = jnp.asarray([20, 5], jnp.int32)
-            att.batched_verify_attention(qg, (keys, values), pos, chunk, paged=paged)
-            ctr = telemetry.REGISTRY.counter(
-                "dllama_kernel_path_total", labelnames=("kernel", "path")
-            )
-            assert (
-                ctr.labels(kernel="paged_attention", path="pallas_fused_verify").value
-                >= 1
-            )
-        finally:
-            telemetry.reset()
-            telemetry.disable()
-
-    def test_dispatch_takes_fused_path_and_counts_it(self, monkeypatch):
-        from distributed_llama_tpu import telemetry
-
-        monkeypatch.setenv("DLT_FUSED_PAGED", "1")
         telemetry.enable()
         try:
             telemetry.reset()
             rng = np.random.RandomState(1)
             B, S, K, M, hd, chunk, page, P_ = 2, 32, 2, 1, 8, 8, 4, 8
-            qg = jnp.asarray(rng.randn(B, K, M, hd).astype(np.float32))
+            lead = (B,) if T is None else (B, T)
+            qg = jnp.asarray(rng.randn(*lead, K, M, hd).astype(np.float32))
             keys = _mk_half(rng, (B, S, K, hd), jnp.float32)
             values = _mk_half(rng, (B, S, K, hd), jnp.float32)
             paged = (
@@ -313,22 +349,22 @@ class TestFusedPagedAttention:
                 jnp.asarray([8, 0], jnp.int32),
             )
             pos = jnp.asarray([20, 5], jnp.int32)
-            att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
+            attend = att.batched_decode_attention if T is None else att.batched_verify_attention
+            attend(qg, (keys, values), pos, chunk, paged=paged)
             ctr = telemetry.REGISTRY.counter(
                 "dllama_kernel_path_total", labelnames=("kernel", "path")
             )
-            assert ctr.labels(kernel="paged_attention", path="pallas_fused").value >= 1
-            # unset, every platform takes the segmented scan
-            monkeypatch.delenv("DLT_FUSED_PAGED")
-            att.batched_decode_attention(qg, (keys, values), pos, chunk, paged=paged)
-            assert ctr.labels(kernel="paged_attention", path="xla_segmented").value >= 1
+            assert ctr.labels(kernel="paged_attention", path="xla_segmented").value == 1
+            # the plain slab scan is not a paged dispatch
+            attend(qg, (keys, values), pos, chunk)
+            assert ctr.labels(kernel="paged_attention", path="xla_segmented").value == 1
         finally:
             telemetry.reset()
             telemetry.disable()
 
     def test_non_paged_path_untouched(self):
-        """paged=None must never route to the fused kernel (the plain slab
-        scan is the cold path the parity suites pin separately)."""
+        """paged=None is the plain slab scan (the cold path the parity
+        suites pin separately)."""
         rng = np.random.RandomState(2)
         B, S, K, M, hd, chunk = 2, 32, 2, 1, 8, 8
         qg = jnp.asarray(rng.randn(B, K, M, hd).astype(np.float32))
@@ -468,7 +504,7 @@ class TestMatmulAllReduceSeam:
         packs, stacked, xs = self._setup()
         # reference: the sum of per-shard standalone int8 matmuls
         ref = np.sum(
-            [np.asarray(q40_matmul(xs[i], packs[i], path="int8")) for i in range(8)],
+            [np.asarray(q40_matmul(xs[i], packs[i])) for i in range(8)],
             axis=0,
         )
         psum = self._run(mesh, stacked, xs, "psum")

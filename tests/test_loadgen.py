@@ -16,18 +16,11 @@ from distributed_llama_tpu.loadgen.runner import OUTCOMES, RequestResult
 
 
 # ----------------------------------------------------------------------
-# stats.py — the ONE percentile estimator behind bench.py and loadgen
+# stats.py — the ONE percentile estimator behind loadgen
 # ----------------------------------------------------------------------
 
 
 class TestStats:
-    def test_median_of_three_matches_benchs_old_idiom(self):
-        # bench.py used sorted(xs)[1] for its median-of-3 numbers; the
-        # shared helper must be bit-identical on odd N or every historical
-        # bench comparison silently shifts
-        for xs in ([3.0, 1.0, 2.0], [9.9, 9.7, 9.8], [1.0, 1.0, 5.0]):
-            assert stats.median(xs) == sorted(xs)[1]
-
     def test_percentile_interpolates_between_ranks(self):
         xs = [0.0, 10.0]
         assert stats.percentile(xs, 50) == 5.0
@@ -46,13 +39,6 @@ class TestStats:
             stats.percentile([], 50)
         with pytest.raises(ValueError):
             stats.percentile([1.0], 101)
-
-    def test_median_by_returns_the_item(self):
-        rounds = [{"tps": 5.0, "tag": "b"}, {"tps": 9.0, "tag": "c"},
-                  {"tps": 1.0, "tag": "a"}]
-        assert stats.median_by(rounds, key=lambda r: r["tps"])["tag"] == "b"
-        with pytest.raises(ValueError):
-            stats.median_by([], key=lambda r: r)
 
     def test_summarize_shape_and_empty(self):
         s = stats.summarize([1.0, 2.0, 3.0], unit="ms")

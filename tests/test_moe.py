@@ -161,7 +161,7 @@ class TestQ40Moe:
         assert np.all(np.isfinite(out))
 
     def test_q40_bucketed_prefill_pads_stay_out_of_buckets(self, tmp_path):
-        """Regression (ADVICE r5): engine bucket-padding appends zero tokens
+        """Regression: engine bucket-padding appends zero tokens
         that route like real tokens; the bucketed prefill must mask them
         out so per-expert capacity is spent ONLY on real tokens. A padded
         prompt (33 tokens → bucket 64) through a lossy-capacity engine must

@@ -331,6 +331,42 @@ class TestPrefixHitParity:
         again = decode_tokens(s, PROMPT, 0.0, 0.9, 7, 8)
         assert first == again
 
+    def test_quarantine_after_a_hit_frees_no_page_and_the_next_hit_replays(self, tmp_path):
+        """The zero-copy aliasing gate: a row corrupted mid-decode AFTER it
+        took a prefix hit is quarantined; no page of the tree is freed, the
+        dead row's pins are released, and the next request still hits the
+        published prefix (counted) and decodes the pre-fault stream."""
+        from distributed_llama_tpu import telemetry
+        from distributed_llama_tpu.engine import faults
+
+        telemetry.reset()
+        telemetry.enable()
+        try:
+            reg = telemetry.REGISTRY
+            sched = self._sched(build_engine(tmp_path))
+            s0, s1 = sched.new_stream(), sched.new_stream()
+            reference = decode_tokens(s0, PROMPT, 0.0, 0.9, 7, 12)
+            pages_before = reg.gauge("dllama_prefix_cache_pages").value
+            assert pages_before == sched._prefix.pages_in_use() == 2
+            # bind-once: the scheduler predates the plan
+            sched._faults = faults.install(
+                faults.parse("batch.row:kind=nan,row=1,after=1,count=1", seed=0))
+            with pytest.raises(faults.RowQuarantined):
+                decode_tokens(s1, PROMPT, 0.0, 0.9, 7, 12)
+            faults.clear()
+            sched._faults = faults.active_plan()
+            assert reg.gauge("dllama_prefix_cache_pages").value == pages_before
+            assert not s1._alias_ids and s1.matched_len == 0  # pins released
+            sched.check_prefix()  # no page aliased, leaked or freed while read
+            hits = reg.counter("dllama_prefix_cache_hits_total").value
+            assert hits == 1  # the victim's own hit
+            assert decode_tokens(s0, PROMPT, 0.0, 0.9, 7, 12) == reference
+            assert reg.counter("dllama_prefix_cache_hits_total").value == hits + 1
+        finally:
+            faults.clear()
+            telemetry.disable()
+            telemetry.reset()
+
     def test_longer_prompt_extends_published_chain(self, tmp_path):
         """A second request whose prompt extends the published prefix
         publishes only the NEW blocks (the radix property)."""

@@ -301,6 +301,31 @@ class TestSpillReload:
     def test_reload_parity_i8(self, tmp_path):
         self._parity(tmp_path, "i8")
 
+    def test_every_evict_and_rerequest_round_reloads_the_whole_chain(self, tmp_path):
+        """The spill tier keeps serving: a chain that was reloaded, evicted
+        again and asked for again comes back from the host every time, and
+        ``dllama_prefix_spill_reloads_total`` counts each page of it."""
+        from distributed_llama_tpu import telemetry
+
+        telemetry.reset()
+        telemetry.enable()
+        try:
+            sched = build_sched(build_engine(tmp_path))
+            s = sched.new_stream()
+            cold = decode_tokens(s, PROMPT)
+            chain = len(sched._prefix.walk(PROMPT))
+            reloads = telemetry.REGISTRY.counter("dllama_prefix_spill_reloads_total")
+            for r in range(3):
+                churn(s, 100 + 40 * r)
+                assert sched._prefix.walk(PROMPT) == []  # off the device again
+                assert decode_tokens(s, PROMPT) == cold
+                assert reloads.value == (r + 1) * chain
+            s.reset()
+            sched.check_prefix()
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+
     def test_i8_spill_reload_byte_parity_data_and_scales(self, tmp_path):
         """The spilled entry's int8 data AND f32 scales round-trip
         verbatim: bytes downloaded from the pool before eviction ==

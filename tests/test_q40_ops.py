@@ -60,8 +60,8 @@ class TestMatmul:
         x = jnp.asarray(rng.randn(T, n).astype(np.float32))
 
         want = np.asarray(x @ jnp.asarray(dequantize_tpu(qm)))
-        got = np.asarray(q40_matmul(x, qm, block_n=256, block_d=128, interpret=True))
-        # the kernel dequantizes to bf16 (noise << Q40's own error)
+        got = np.asarray(q40_matmul(x, qm, block_n=512, block_d=128, interpret=True))
+        # the kernel rounds x to Q80 (noise << Q40's own error)
         scale = np.abs(want).max()
         np.testing.assert_allclose(got / scale, want / scale, atol=2e-2)
 
@@ -82,7 +82,7 @@ class TestMatmul:
         qm = quantize_q40_tpu(w)
         x = jnp.asarray(rng.randn(1, n).astype(np.float32))
         exact = np.asarray(x) @ w
-        got = np.asarray(q40_matmul(x, qm, block_n=256, block_d=128, interpret=True))
+        got = np.asarray(q40_matmul(x, qm, block_n=512, block_d=128, interpret=True))
         # quantization noise, not kernel error
         rel = np.abs(got - exact).max() / np.abs(exact).max()
         assert rel < 0.12, rel
@@ -185,21 +185,3 @@ class TestInterleavedMigration:
             np.nonzero(perm >= F)[0], npc + np.nonzero(perm >= F)[0]
         ])
         assert np.all(deq[:, pad_cols] == 0.0)
-
-
-class TestEnvTileValidation:
-    def test_bad_env_tile_fails_at_kernel_use_not_import(self, monkeypatch):
-        """A bad DLT_BN value must not make the package unimportable
-        (--help and unrelated subcommands keep working); the error surfaces
-        when the kernel is actually configured, naming the knob."""
-        from distributed_llama_tpu.ops import q40 as q40mod
-
-        monkeypatch.setattr(q40mod, "BLOCK_N", 300)  # not a multiple of 512
-        rng = np.random.RandomState(0)
-        # T=3 keeps the jit signature unique to this test: the validation
-        # runs at trace time, so a shape another test already traced would
-        # hit the cache and never observe the patched value
-        qm = quantize_q40_tpu(rng.randn(512, 128).astype(np.float32))
-        x = jnp.asarray(rng.randn(3, 512).astype(np.float32))
-        with pytest.raises(ValueError, match="DLT_BN=300"):
-            q40_matmul(x, qm, interpret=True)

@@ -2,17 +2,14 @@
 on-device accept/reject (greedy longest-prefix + Leviathan rejection
 sampling), greedy bit-parity of speculative vs plain decode (single-stream,
 batched, i8 cache), mixed spec/non-spec rows in one slab, the
-``engine.spec_verify`` chaos contract, the coalesced (fused) K/V cache
-layout the verify path writes through, and the ISSUE 17 fused paged
-verify-attention kernel's engine-level flag A/B (DLT_FUSED_PAGED on vs
-off must emit the same greedy stream)."""
+``engine.spec_verify`` chaos contract, and the coalesced (fused) K/V cache
+layout the verify path writes through."""
 
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from distributed_llama_tpu.engine import InferenceEngine, faults
 from distributed_llama_tpu.engine.batch import BatchScheduler
@@ -456,56 +453,6 @@ class TestFusedStepChaos:
             assert out0[0] == want_survivor
         finally:
             faults.clear()
-
-
-class TestFusedVerifyPath:
-    """ISSUE 17 tentpole (d): on a paged scheduler at the blocked shape the
-    spec-verify hit path dispatches the fused paged kernel
-    (``pallas_fused_verify``) instead of the segmented-scan chain — and the
-    emitted greedy stream must be identical either way (the kernel shares
-    ``_verify_partial`` with the scan, so parity is by construction; this
-    pins it end-to-end through prefill → draft → verify → accept)."""
-
-    SEQ = 1024  # ATT_CHUNK = 512 divides; chunk % page == 0: fused-eligible
-    PAGE = 64
-    PROMPT = [1, 5, 9, 2, 1, 5, 9, 2, 1, 5]  # repetitive → lookup drafts
-
-    def _streams(self, tmp_path, name, monkeypatch, fused):
-        monkeypatch.setenv("DLT_FUSED_PAGED", "1" if fused else "0")
-        # the dispatch decision happens at trace time inside module-level
-        # jits: without clearing, the second arm would silently reuse the
-        # first arm's compiled program and the A/B would be vacuous
-        jax.clear_caches()
-        engine = build_engine(tmp_path, name, seq_len=self.SEQ)
-        sched = BatchScheduler(engine, n_rows=1, chunk=4, prefix_cache=True,
-                               kv_pages=16, page_size=self.PAGE, spec_draft=K)
-        s = sched.new_stream()
-        cold = spec_stream(s, self.PROMPT, 0.0, 0.9, 7, N_TOKENS)
-        s.reset()
-        hit = spec_stream(s, self.PROMPT, 0.0, 0.9, 7, N_TOKENS)
-        return cold, hit
-
-    @pytest.mark.slow
-    def test_fused_verify_stream_matches_scan(self, tmp_path, monkeypatch):
-        from distributed_llama_tpu import telemetry
-
-        want = self._streams(tmp_path, "scan.m", monkeypatch, fused=False)
-        telemetry.enable()
-        try:
-            telemetry.reset()
-            got = self._streams(tmp_path, "fused.m", monkeypatch, fused=True)
-            ctr = telemetry.REGISTRY.counter(
-                "dllama_kernel_path_total", labelnames=("kernel", "path")
-            )
-            # the fused arm really took the fused verify kernel
-            assert ctr.labels(
-                kernel="paged_attention", path="pallas_fused_verify"
-            ).value >= 1
-        finally:
-            telemetry.reset()
-            telemetry.disable()
-            jax.clear_caches()  # drop the flag-pinned traces
-        assert got == want
 
 
 class TestFusedCacheLayout:
