@@ -1,4 +1,5 @@
-"""Fused Q40 matmul: weights stay 4-bit in HBM, dequantize in VMEM, MXU dot.
+"""Fused Q40 matmul: weights stay 4-bit in HBM, nibbles unpacked in VMEM,
+int8 MXU dot against Q80 activations, scales folded in after the dot.
 
 This replaces the reference's production kernel path — hand-written NEON/AVX2
 `matmulQ40vQ80` (reference: src/funcs.cpp:287-396) — with a Pallas TPU kernel.
@@ -23,6 +24,12 @@ matmul contraction is permutation-invariant when both operands are permuted
 alike). The previous even/odd-row pairing needed strided x[:, 0::2] splits,
 which XLA lowers to gathers costing ~6 ms/token on a 7B decode.
 
+What a launch costs on a v5e (tools/q40_sweep.py, PERF.md §6, PR 31): its
+bytes plus about a third of a microsecond a grid step up to 32 rows (in the
+benchmark's cells 88-89 % of the roofline counted at 18 B per 32 weights,
+which the f32 scales held here, 20 B, cap at 90 %); from 64 rows the
+scale-product epilogue binds and the time doubles with the rows.
+
 On the CPU backend (tests) the kernel runs in Pallas interpret mode.
 """
 
@@ -39,14 +46,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_llama_tpu.quants import QK
 
-# Tile sizes tuned on v5e (profiled in-model on real decode programs):
-# (1024, 1024) runs the kernel at ~375 GB/s of packed bytes in a 7B decode;
-# small divisor tiles (256x256) are ~10x slower — per-grid-step overhead
-# dominates.
-BLOCK_N = 1024  # input tile (multiple of 512: the x window needs
+# Tile targets, measured on a v5e (``_BLOCK_D_BY_ROWS`` below has the numbers
+# and caps the output tile by rows; tools/q40_sweep.py is their provenance)
+BLOCK_N = 1024  # input tile, at every T (multiple of 512: the x window needs
 # bn/2 % 128 == 0 and the scales tile bn/64 % 8 == 0)
-BLOCK_D = 2048  # output tile (multiple of 128; 2048 profiled ~4% faster
-# than 1024 on v5e decode; T>8 shrinks it for VMEM)
+BLOCK_D = 4096  # output tile (multiple of 128): a grid step costs about a
+# third of a microsecond beside its DMA, so fewer and larger steps win
 
 
 def _interpret_default() -> bool:
@@ -464,40 +469,44 @@ def kernel_name(kind: str, role: str | None) -> str:
     return f"{kind}_{role}" if role else kind
 
 
-# VMEM budget for the int8 kernel's per-block sums: it holds [bn/64, T, bd]
-# int32 products and their f32 scaled copy beside the operand tiles. 8 MiB
-# is what the v5e compiler accepts at T=64 with the decode tiles (1024, 2048)
-# and refuses at T=256 ("Ran out of memory in memory space vmem")
-_INT8_BLOCK_SUM_BYTES = 8 << 20
-
-
-def _fit_int8_tiles(qm: QuantizedMatrix, T: int, bn: int, bd: int):
-    """Shrink (bn, bd) until the int8 kernel's [bn/64, T, bd] block sums fit
-    the VMEM budget: output tile first (more grid steps, same contraction
-    order, so results do not depend on T), then the input tile. None when no
-    legal tile fits (T > 2048) — the XLA fallback serves."""
-    while (bn // 64) * T * bd * 4 > _INT8_BLOCK_SUM_BYTES:
-        if bd > 128:
-            bd = _largest_divisor_tile(qm.d_padded, bd // 2, 128)
-        elif bn > 512:
-            bn = _largest_divisor_tile(qm.n_padded, bn // 2, 512)
-        else:
-            return None
-    return bn, bd
+# The output tile by rows, (rows up to, block_d), measured for THIS kernel on a
+# v5e: one launch's device time in a profiler capture, tools/q40_sweep.py
+# (PERF.md §6, PR 31, has the whole table; us at block_d 512 / 1024 / 2048 /
+# 4096 for one Mixtral expert's gate|up, 4096 -> 28672, whose bytes take 81 us):
+#
+#   T=1    161 / 126 / 109 / 100      T=64   391 / 250 / 223 / 216
+#   T=16   164 / 129 / 111 / 102      T=128  708 / 435 / 430 / refused
+#   T=32   211 / 157 / 128 / 125      T=256 1359 / 875 / 843 / refused
+#
+# A grid step costs its DMA plus about a third of a microsecond, so up to 32
+# rows, where the launch is bound by its bytes, the widest tile wins (224
+# steps of 512 columns at 16 rows took 1.6 times as long as 28 of 4096; the
+# other widths read alike). From 64 rows the scale-product epilogue binds
+# and the tile matters less. Each entry is one halving below the widest tile
+# the v5e compiler accepts for every served width at those rows ("Ran out of
+# memory in memory space vmem" past it); the compiler accepts 128 columns up
+# to 640 rows at every served width and nothing from 1024, so a launch of
+# more rows takes the XLA fallback (tests/test_chip_compile.py asks at 1024).
+_BLOCK_D_BY_ROWS = ((32, 4096), (64, 2048), (256, 1024), (640, 128))
 
 
 def _int8_tiles(qm: QuantizedMatrix, T: int, block_n: int, block_d: int):
     """The ONE dispatch decision of the Q40 matmul, from shape alone: the
     (bn, bd) tiles the int8 kernel runs with, dividing the padded dims, or
-    None → the XLA fallback (a matrix too small or odd to tile, or a T
-    whose block sums no legal tile fits into VMEM). block_n granule 512:
-    the x window (T, bn/2) needs bn/2 % 128 == 0 and the scales tile
-    (bn/64, bd) needs bn/64 % 8 == 0 (mosaic sublane/lane tiling rules)."""
+    None → the XLA fallback (a matrix too small or odd to tile, or more rows
+    than any tile of ``_BLOCK_D_BY_ROWS`` holds). Only the output tile
+    follows T: the input tile sets the contraction order, so results do not
+    depend on T. block_n granule 512: the x window (T, bn/2) needs
+    bn/2 % 128 == 0 and the scales tile (bn/64, bd) needs bn/64 % 8 == 0
+    (mosaic sublane/lane tiling rules)."""
+    cap = next((bd for rows, bd in _BLOCK_D_BY_ROWS if T <= rows), None)
+    if cap is None:
+        return None
     block_n = _largest_divisor_tile(qm.n_padded, block_n, 512)
-    block_d = _largest_divisor_tile(qm.d_padded, _shrink_block_d(T, block_d), 128)
+    block_d = _largest_divisor_tile(qm.d_padded, min(block_d, cap), 128)
     if block_n is None or block_d is None:
         return None
-    return _fit_int8_tiles(qm, T, block_n, block_d)
+    return block_n, block_d
 
 
 def q40_matmul(
@@ -538,12 +547,13 @@ def q40_matmul(
 # int8 MXU path: Q40 weights × Q80 activations (ROADMAP item 1)
 # ---------------------------------------------------------------------------
 #
-# Dequantizing the weight tile to floats in VMEM is VPU-bound in the nibble
-# unpack: every weight element pays a cast + mask/shift + scale multiply on
-# the 8×128 VPU before the MXU sees it. The int8 kernel moves the arithmetic
-# onto the MXU's native int8 systolic array instead (reference:
-# matmulQ40vQ80, src/funcs.cpp:287-396 — the reference's production
-# combination for exactly this reason):
+# Dequantizing a weight tile to floats costs every weight element a cast, a
+# mask or shift and a scale multiply on the 8×128 VPU before the MXU sees it
+# (the kernel PR 30 deleted did). This kernel keeps the arithmetic on the
+# MXU's native int8 systolic array instead (reference: matmulQ40vQ80,
+# src/funcs.cpp:287-396 — the reference's production combination for
+# exactly this reason), and the VPU touches a weight only as a quarter of a
+# 32-bit word (``_nibbles``):
 #
 #   * activations quantize to Q80 — per-32-block int8 + f32 scale, the
 #     reference's buffer format — ONE cheap pass over the [T, n] x (tiny
@@ -582,6 +592,20 @@ def quantize_q80(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q.reshape(T, np_), sx
 
 
+def _nibbles(qs_ref) -> tuple[jax.Array, jax.Array]:
+    """The (low, high) nibbles of a packed u8 ``[bn/2, bd]`` tile as two int8
+    tiles of the same shape, values 0..15. Mosaic has no 8-bit shift on v5e,
+    so the tile is read as 32-bit words of four packed bytes each, masked and
+    shifted there (3 vector operations a packed register; widening every
+    byte to an int32 of its own first took about 20) and read back as int8.
+    A per-byte mask does not care which four rows share a word, so the round
+    trip is the identity whatever the packing (interpret mode included)."""
+    w = pltpu.bitcast(qs_ref[:], jnp.uint32)  # [bn/8, bd]
+    lo = w & jnp.uint32(0x0F0F0F0F)
+    hi = (w >> 4) & jnp.uint32(0x0F0F0F0F)
+    return pltpu.bitcast(lo, jnp.int8), pltpu.bitcast(hi, jnp.int8)
+
+
 def _make_q40_int8_kernel():
     """int8 MXU kernel factory: one (d-tile, n-tile) grid step runs one
     exact int32 block-dot per quant block and folds the scale products into
@@ -603,14 +627,10 @@ def _make_q40_int8_kernel():
         def _():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        # widen first: Mosaic has no 8-bit shift or u8->f32 cast on v5e
-        qs = qs_ref[:].astype(jnp.int32)
         # nibbles stay BIASED (0..15, exact in int8); the -8 is the caller's
-        # rank-reduced MXU correction (_int8_core). qs holds u8 values, so
-        # >>4 is already in 0..15 — no mask needed
-        lo = (qs & 0xF).astype(jnp.int8)
-        hi = (qs >> 4).astype(jnp.int8)
-        bn2, bd = qs.shape
+        # rank-reduced MXU correction (_int8_core)
+        lo, hi = _nibbles(qs_ref)
+        bn2, bd = lo.shape
         nbt = bn2 // QK
 
         def half(xq_ref, sx_ref, w_nibbles, sw_ref):
@@ -776,10 +796,8 @@ def _make_q40_grouped_kernel():
 
         @pl.when(on_ref[e] != 0)
         def _():
-            qs = qs_ref[:].astype(jnp.int32)
-            lo = (qs & 0xF).astype(jnp.int8)
-            hi = (qs >> 4).astype(jnp.int8)
-            bn2, bd = qs.shape
+            lo, hi = _nibbles(qs_ref)
+            bn2, bd = lo.shape
             nbt = bn2 // QK
 
             def half(xq_ref, sx_ref, qsum_ref, w_nibbles, sw_ref):
@@ -890,8 +908,9 @@ def q40_grouped_matmul(
         out_shape=jax.ShapeDtypeStruct((E, T, dp), jnp.float32),
         interpret=interpret,
         # the unbiased block sums are one more [bn/64, T, bd] f32 temporary
-        # than the ungrouped kernel holds: 16.3 MiB at 256 rows against the
-        # compiler's default scoped limit of 16 (the chip's VMEM is 128)
+        # than the ungrouped kernel holds: at 256 rows more than the
+        # compiler's default scoped limit of 16 MiB (16.3 already at 512
+        # columns; the chip's VMEM is 128)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=32 << 20,
@@ -980,29 +999,6 @@ def rmsnorm_q40_matmul(
         interpret = _interpret_default()
     _note_path("q40_matmul", "mxu_int8_fusedq")
     return _rmsnorm_q40_matmul_int8(x, weight, qm, *tiles, interpret, eps, role)
-
-
-def _shrink_block_d(T: int, block_d: int) -> int:
-    """Batch-size-dependent output-tile cap, tuned on a v5e by measuring
-    the FULL 7B prefill program per config (an earlier chip run of the f32
-    kernel, record removed, not checked on today's code or for the int8
-    kernel):
-
-      T=16:  bd512 15.9 ms | bd2048 21.2      -> keep 512
-      T=32:  bd512 17.4 | bd1024 14.7 | bd2048 16.1 -> 1024
-      T=64:  bd512 24.0 | bd1024 16.8 | bd2048 14.8 -> full (38% faster
-             than the round-4 decode-tuned 512 cap)
-      T=128: bd512 21.3 | bd2048 17.5           -> full
-      T=256: bd256 34.2 | bd2048 30.5           -> full
-      T=512: bd2048 fails to compile (VMEM), bd1024 75.8 | bd256 84.8 -> 1024
-    """
-    if T <= 8:
-        return block_d  # decode regime: 2048 profiled ~4% over 1024 (round 3)
-    if T <= 16:
-        return min(block_d, 512)
-    if T <= 32 or T > 256:
-        return min(block_d, 1024)
-    return block_d
 
 
 def _largest_divisor_tile(dim: int, target: int, granule: int) -> int | None:

@@ -72,6 +72,8 @@ def _qm_shape(n: int, d: int, sharding) -> q40.QuantizedMatrix:
 # Mixtral-8x7B's two expert widths
 SHAPES_7B = [(4096, 12288), (4096, 4096), (4096, 22016), (11008, 4096), (4096, 32000)]
 SHAPES_MIXTRAL = [(4096, 28672), (14336, 4096)]
+# Solar-Open2's widest dense launch (lin_in, 4096 -> 24896, padded to 25 x 1024 columns)
+SHAPES_SOLAR = [(4096, 25600)]
 
 
 def _compile_q40(kernel, n, d, T, one_chip):
@@ -82,10 +84,13 @@ def _compile_q40(kernel, n, d, T, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("T", [1, 64])
-@pytest.mark.parametrize("n,d", SHAPES_7B + SHAPES_MIXTRAL)
+@pytest.mark.parametrize("T", [1, 16, 32, 64, 256, 640])
+@pytest.mark.parametrize("n,d", SHAPES_7B + SHAPES_MIXTRAL + SHAPES_SOLAR)
 def test_q40_int8_kernel_compiles(one_chip, n, d, T):
-    """The one tiled q40 kernel (CPU tests and chip alike)."""
+    """The one tiled q40 kernel (CPU tests and chip alike), at the row counts
+    the cells decode at (1; Mixtral's 16; Solar's 32) and at the last row
+    count of each further band of ``q40._BLOCK_D_BY_ROWS`` (64; the 256-row
+    prefill chunk; 640, the most the compiler accepts at every width)."""
     _compile_q40(q40._q40_matmul_int8, n, d, T, one_chip)
 
 
@@ -94,7 +99,7 @@ def test_q40_default_dispatch_compiles_at_prefill_widths(one_chip, n, d, T, monk
     """The server prefills in 256-row chunks. At these widths the decode
     tiles overflow VMEM in the int8 kernel (the chip said so: "Ran out of
     memory in memory space vmem", chip_smoke, PR 21); the dispatch must
-    shrink them (``_fit_int8_tiles``) and stay on the kernel."""
+    shrink them (``_BLOCK_D_BY_ROWS``) and stay on the kernel."""
     monkeypatch.setattr(q40, "_interpret_default", lambda: False)  # steer the CPU branch
     qm = _qm_shape(n, d, one_chip)
     x = jax.ShapeDtypeStruct((T, n), jnp.bfloat16, sharding=one_chip)
@@ -102,13 +107,15 @@ def test_q40_default_dispatch_compiles_at_prefill_widths(one_chip, n, d, T, monk
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_q40_dispatch_past_the_vmem_fit_compiles_as_plain_xla(one_chip, monkeypatch):
-    """No tile of the int8 kernel holds 2049 rows' block sums (and the v5e
-    compiler refuses a float-dequantising kernel there too, PR 30): the
-    dispatch hands such a T to the XLA fallback, which compiles."""
+@pytest.mark.parametrize("T", [1024, 2049])
+def test_q40_dispatch_past_the_vmem_fit_compiles_as_plain_xla(one_chip, monkeypatch, T):
+    """The int8 kernel holds all T rows in one block and the v5e compiler
+    refuses it from 1024 rows whatever the tile (and refused a
+    float-dequantising kernel there too, PR 30): the dispatch hands such a T
+    to the XLA fallback, which compiles."""
     monkeypatch.setattr(q40, "_interpret_default", lambda: False)
     qm = _qm_shape(4096, 12288, one_chip)
-    x = jax.ShapeDtypeStruct((2049, 4096), jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((T, 4096), jnp.bfloat16, sharding=one_chip)
     compiled = jax.jit(lambda x, qm: q40.q40_matmul(x, qm)).lower(x, qm).compile()
     assert "tpu_custom_call" not in compiled.as_text()
 

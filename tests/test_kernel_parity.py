@@ -19,6 +19,7 @@ an 8-device virtual mesh):
 """
 
 
+import functools
 import re
 
 import numpy as np
@@ -40,6 +41,7 @@ from distributed_llama_tpu.ops.q40 import (
     quantize_q80,
     rmsnorm_q40_matmul,
     rmsnorm_ref,
+    stack_bank,
 )
 
 
@@ -160,6 +162,79 @@ class TestFusedRmsnormQuantize:
         finally:
             telemetry.reset()
             telemetry.disable()
+
+
+def _parent_nibbles(qs_ref):
+    """The unpack as PR 30's kernel bodies wrote it, on the same tile: every
+    packed byte widened to an int32 of its own, masked and shifted there,
+    narrowed back to int8."""
+    qs = qs_ref[:].astype(jnp.int32)
+    return (qs & 0xF).astype(jnp.int8), (qs >> 4).astype(jnp.int8)
+
+
+# the output tile PR 30's table (``_shrink_block_d`` and the VMEM fit) gave a row count
+PARENT_BLOCK_D = {1: 2048, 16: 512, 32: 1024, 256: 512}
+
+
+class TestPackedUnpackBitParity:
+    """PR 31 unpacks the nibbles on packed 32-bit words (``q40._nibbles``) and
+    takes the output tile from its own table. Integers in, the same integers
+    out, and the output tile is not in the contraction: every launch is
+    bit-equal to the parent's, body and tile, at every row count a cell
+    dispatches."""
+
+    # an eighth of Mixtral's expert gate|up (4096 -> 28672) and Solar's held
+    # gate|up (4096 -> 2560, whose padded 3072 columns tile by 1536) over a
+    # quarter of the hidden size: two input windows, several output tiles
+    @pytest.mark.parametrize("T", sorted(PARENT_BLOCK_D))
+    @pytest.mark.parametrize("n,d", [(1024, 3584), (1024, 2560)], ids=["mixtral", "solar"])
+    @pytest.mark.parametrize("body", ["dense", "grouped"])
+    def test_bit_equal_to_the_parents_kernel(self, monkeypatch, body, n, d, T):
+        from distributed_llama_tpu.ops import q40
+
+        rng = np.random.RandomState(T + d)
+        x = jnp.asarray(rng.randn(T, n).astype(np.float32)).astype(jnp.bfloat16)
+        packs = [quantize_q40_tpu(rng.randn(n, d).astype(np.float32) / np.sqrt(n))
+                 for _ in range(1 if body == "dense" else 3)]
+        bn, _ = q40._int8_tiles(packs[0], T, q40.BLOCK_N, q40.BLOCK_D)
+        assert bn == q40.BLOCK_N
+        parent_tiles = (bn, q40._largest_divisor_tile(packs[0].d_padded, PARENT_BLOCK_D[T], 128))
+        if body == "dense":
+            args = (x, packs[0])
+            entry = q40_matmul
+            parent = lambda x, qm: q40._q40_matmul_int8.__wrapped__(x, qm, *parent_tiles, True)
+        else:
+            args = (x.astype(jnp.float32), stack_bank(packs), jnp.asarray([True, False, True]))
+            entry = q40_grouped_matmul
+            # reads the patched ``_int8_tiles``
+            parent = functools.partial(q40_grouped_matmul.__wrapped__, interpret=True)
+        got = entry(*args, interpret=True)
+        # the parent: its unpack and its tile, the jitted entries' own bodies
+        # under a new jit, so that no cached program of this tree's is reused
+        monkeypatch.setattr(q40, "_nibbles", _parent_nibbles)
+        monkeypatch.setattr(q40, "_int8_tiles", lambda *a: parent_tiles)
+        want = jax.jit(parent)(*args)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("rows,cols", [(512, 2048), (512, 1536), (256, 128)])
+    def test_the_unpack_returns_the_parents_integers(self, rows, cols):
+        """The helper alone, through a launch in interpret mode on tiles of the
+        served shapes, over every byte value."""
+        from jax.experimental import pallas as pl
+
+        from distributed_llama_tpu.ops import q40
+
+        qs = jnp.asarray(np.random.RandomState(cols).randint(0, 256, (rows, cols)).astype(np.uint8))
+
+        def run(unpack):
+            def kernel(qs_ref, lo_ref, hi_ref):
+                lo_ref[:], hi_ref[:] = unpack(qs_ref)
+            shape = jax.ShapeDtypeStruct(qs.shape, jnp.int8)
+            return pl.pallas_call(kernel, out_shape=(shape, shape), interpret=True)(qs)
+
+        for got, want in zip(run(q40._nibbles), run(_parent_nibbles)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert set(np.unique(np.asarray(qs))) == set(range(256))
 
 
 def _noted(fn, *args) -> dict[str, int]:

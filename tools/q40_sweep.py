@@ -1,0 +1,99 @@
+"""Device time of ONE launch of the Q40 int8 kernel by rows, output tile and
+unpack: the provenance of ``ops/q40.py``'s ``_BLOCK_D_BY_ROWS`` (PERF.md §6, PR 31).
+
+    chiprun --timeout 3000 -- python3 tools/q40_sweep.py [shape ...]
+
+For each shape (Mixtral's two expert widths, Mistral's wqkv and wo, Solar's lin_in and its bank
+of held experts), T in ROWS, block_d in TILES (block_n stays 1024) and the unpack on packed words
+(``q40._nibbles``) or widened to int32 (the body before PR 31, kept here): 50 launches over 4 weight
+buffers in turn under a profiler capture, the median of the kernel's own device events. One JSON
+line a point, or a compiler's refusal, on stdout and appended to ``chiprun_out/q40_sweep.jsonl``.
+"""
+
+import json
+import os
+import re
+import statistics
+import sys
+import tempfile
+
+import jax
+import jax.numpy as jnp
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark.harness import trace_reduce  # noqa: E402
+from distributed_llama_tpu.ops import q40  # noqa: E402
+
+# name: (n, d, experts in a bank or 0); the bank goes through q40_grouped_matmul, all on
+SHAPES = {"mixtral_gate_up": (4096, 28672, 0), "mixtral_down": (14336, 4096, 0),
+          "mistral_wqkv": (4096, 6144, 0), "mistral_wo": (4096, 4096, 0),
+          "solar_lin_in": (4096, 25600, 0), "solar_held_bank": (4096, 2560, 20)}
+ROWS, TILES, LAUNCHES, BUFFERS = (1, 8, 16, 32, 64, 128, 256), (512, 1024, 2048, 4096), 50, 4
+
+
+def _widen(qs_ref):
+    qs = qs_ref[:].astype(jnp.int32)
+    return (qs & 0xF).astype(jnp.int8), (qs >> 4).astype(jnp.int8)
+
+
+UNPACKS = {"packed": q40._nibbles, "widen": _widen}
+
+
+def _weights(key, n, d, E):
+    lead, np_, dp = ((E,) if E else ()), q40._n_padded(n), q40._d_padded(d)
+    k1, k2 = jax.random.split(key)
+    scales = jax.random.uniform(k2, lead + (np_ // 32, dp), jnp.float32, 0.5, 1.5) / 300.0
+    return q40.QuantizedMatrix(jax.random.bits(k1, lead + (np_ // 2, dp), dtype=jnp.uint8), scales, n, d)
+
+
+def _entry(x, qm, E, bd, role, interpret=q40._interpret_default()):  # True only in a CPU rehearsal
+    if E:
+        return q40.q40_grouped_matmul.__wrapped__(x, qm, jnp.ones((E,), bool), interpret=interpret, role=role)
+    return q40._q40_matmul_int8.__wrapped__(x, qm, q40.BLOCK_N, bd, interpret, role)
+
+
+def sweep(name):
+    n, d, E = SHAPES[name]
+    mats = [_weights(jax.random.PRNGKey(i), n, d, E) for i in range(BUFFERS)]
+    points = []
+    for T in ROWS:
+        x = jax.random.normal(jax.random.PRNGKey(T), (T, n), jnp.float32).astype(jnp.bfloat16)
+        for bd in sorted({q40._largest_divisor_tile(mats[0].d_padded, want, 128) for want in TILES}):
+            for unpack, fn in UNPACKS.items():
+                q40._nibbles, q40._int8_tiles = fn, lambda *a, bd=bd: (q40.BLOCK_N, bd)
+                point = {"shape": name, "n": n, "d": d, "experts": E, "T": T, "bd": bd, "unpack": unpack}
+                role = f"sweep_{unpack}_t{T}_bd{bd}"
+                # a fresh jit of the served entry: its first call traces with the patched unpack and dispatch
+                run = jax.jit(lambda x, qm, bd=bd, role=role: _entry(x, qm, E, bd, role))
+                try:
+                    run(x, mats[0]).block_until_ready()
+                except Exception as e:  # the compiler's refusal is a result too
+                    yield {**point, "refused": str(e).splitlines()[0][:160]}
+                    continue
+                points.append((point, role, run, x))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _, _, run, x in points:
+            for i in range(LAUNCHES):
+                y = run(x, mats[i % BUFFERS])
+            y.block_until_ready()
+        jax.profiler.stop_trace()
+        planes = trace_reduce.load(trace_dir)
+    events = [e for k, p in planes.items() if k != "_inventory" for e in p.get(trace_reduce.OPS_LINE, [])]
+    # the file's 18 B per 32 weights over the chip's 819 GB/s (benchmark/peaks.json), as the rooflines count
+    floor_us = (E or 1) * (n * d * 18 // 32) / 819e9 * 1e6
+    for point, role, _, _ in points:
+        # the launch's own event: its consumers' events name it among their operands
+        us = [dur / 1e3 for ev, _, dur in events if re.match(rf"%?q40_int8_(grouped_)?{role}(\.\d+)* = ", ev)]
+        assert us, f"no event of {role}; the ops line has: {sorted({e[0][:80] for e in events})[:40]}"
+        yield {**point, "launches": len(us), "median_us": round(statistics.median(us), 2), "min_us": round(min(us), 2),
+               "weights_roofline_pct": round(100 * floor_us / statistics.median(us), 1)}
+
+
+if __name__ == "__main__":
+    assert jax.default_backend() == "tpu", "device times come from the chip only"
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/q40_sweep.jsonl", "a") as out:
+        for line in (line for shape in sys.argv[1:] or SHAPES for line in sweep(shape)):
+            for to in (sys.stdout, out):
+                print(json.dumps(line), file=to, flush=True)
