@@ -29,7 +29,8 @@ from benchmark.harness import client, prom, readers, stats, traffic
 # ``benchmark/check.json`` holds each with its reason, ``load_check`` reads them.
 TRACE_SECONDS = 2.0  # 5 s of a 16-layer server's ops crashed the profiler at stop_trace (PR 22)
 # --trace 2: the lead-in of the traced phase, at most (the mix's own if shorter): long enough
-# for the rows to refill and, in a closed loop, for the callers to fall out of step again
+# for the rows to refill and, in a closed loop, for the callers to fall out of step again. A mix
+# whose callers need longer for that (long prompts) says so itself: ``trace_lead_in_s``
 TRACE_LEAD_IN_S = 6.0
 
 
@@ -50,20 +51,28 @@ def load_json(path: str):
         return json.load(f)
 
 
-def load_check(bench_dir: str = families.BENCH_DIR, config: dict | None = None) -> dict:
+def load_check(bench_dir: str = families.BENCH_DIR, config: dict | None = None,
+               launch: dict | None = None) -> dict:
     """The reference check's numbers: the defaults of ``<bench_dir>/check.json``,
-    each replaced by the value a configuration's ``check`` block gives it. The
-    block carries its reason under ``why``; a name the defaults do not have is
-    an error. ``min_compared``, the positions a verdict needs, follows."""
+    each replaced by the value a configuration's ``check`` block gives it, and
+    that by the value the cell's own block gives it (``launch``: the cell's
+    file), for that cell alone. A block carries its reason under ``why``; a
+    name the defaults do not have is an error. ``min_compared``, the positions
+    a verdict needs, follows."""
     check = {k: v["value"] for k, v in load_json(os.path.join(bench_dir, "check.json")).items()}
-    override = dict((config or {}).get("check") or {})
-    if override:
-        why = override.pop("why", "")
-        unknown = sorted(set(override) - set(check))
-        if unknown or not (isinstance(why, str) and why.strip()):
-            raise BenchFailure(f"configuration {config.get('name')!r}: its check block needs a "
-                               f"\"why\" and may set {sorted(check)} only, not {unknown}")
-        check.update(override)
+    for kind, holder in (("configuration", config), ("cell", launch)):
+        override = dict((holder or {}).get("check") or {})
+        if override:
+            why = override.pop("why", "")
+            unknown = sorted(set(override) - set(check))
+            if unknown or not (isinstance(why, str) and why.strip()):
+                raise BenchFailure(f"{kind} {holder.get('name')!r}: its check block needs a "
+                                   f"\"why\" and may set {sorted(check)} only, not {unknown}")
+            check.update(override)
+    if not 0 <= check["long_probes"] <= check["probes"] or (
+            check["long_probes"] and check["long_probe_prompt"] <= check["probe_prompt"]):
+        raise BenchFailure(f"check: {check['long_probes']} long probes of {check['long_probe_prompt']} "
+                           f"tokens among {check['probes']} of {check['probe_prompt']}")
     check["min_compared"] = int(check["probes"] * check["probe_tokens"] * check["min_compared_share"])
     return check
 
@@ -88,7 +97,7 @@ class Cell:
             self.counts = families.counts(self.config, self.dir)  # and every key is known to it
         except families.FamilyError as e:
             raise BenchFailure(str(e)) from None
-        self.check = load_check(self.dir, self.config)
+        self.check = load_check(self.dir, self.config, self.launch)
         self.mix = load_json(os.path.join(self.dir, "traffic", f"{entry['traffic']}.json"))
         for key in ("config", "traffic", "chips"):
             if self.launch[key] != entry[key]:
@@ -244,24 +253,31 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: int,
 def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, trace_dir: str,
              seed: int, seconds: float, trace: int, require_platform: str,
              t_process: float) -> dict:
-    # probes: alone, not streamed, prefix cache off; they are also the token-count check
+    # probes: alone, not streamed, prefix cache off; they are also the token-count check. The
+    # reference scores each group's answers on the host's cores while the warm-up, which is one
+    # Python thread tracing programs, goes on; it is over before the lead-in, so the window is
+    # not disturbed. The long probes go first: their pass is the reference's longest (minutes at
+    # 7B widths), and it starts while the short ones are still being answered
     check = cell.check
-    probes = traffic.probe_requests(seed, check["probes"], check["probe_prompt"], check["probe_tokens"])
+    probes = traffic.probe_requests(seed, check["probes"], check["probe_prompt"], check["probe_tokens"],
+                                    check["long_probes"], check["long_probe_prompt"])
+    n_short = len(probes) - check["long_probes"]
     t = time.monotonic()
-    answers = [_probe(server, p) for p in probes]
-    for p, a in zip(probes, answers):
-        if a.prompt_tokens != p.prompt_tokens:
-            raise BenchFailure(f"the generator counts {p.prompt_tokens} prompt tokens, the "
-                               f"server {a.prompt_tokens}: one character is not one token")
+    answers: list = [None] * len(probes)
+    references = []
+    for tag, group in (("_long", range(n_short, len(probes))), ("", range(n_short))):
+        for i in group:
+            answers[i] = _probe(server, probes[i])
+            if answers[i].prompt_tokens != probes[i].prompt_tokens:
+                raise BenchFailure(f"the generator counts {probes[i].prompt_tokens} prompt tokens, the "
+                                   f"server {answers[i].prompt_tokens}: one character is not one token")
+        if group:
+            references.append(_Reference(cell, model, cache, [probes[i] for i in group],
+                                         [answers[i] for i in group], tag))
+            server.beside.append(references[-1])  # run_cell stops it if the run fails first
     building = sum(e["seconds"] for e in server.control("/compiles")["events"])
     log(f"[setup] {len(probes)} probes answered in {time.monotonic() - t:.1f} s, {building:.1f} s of it "
         f"building or loading programs; prompt and completion token counts agree with usage")
-
-    # the reference scores the answers on the host's cores while the warm-up,
-    # which is one Python thread tracing programs, goes on; it is over before
-    # the lead-in, so the window is not disturbed
-    reference = _Reference(cell, model, cache, probes, answers)
-    server.beside.append(reference)  # run_cell stops it if the run fails first
 
     mix = cell.mix
     rows = cell.flag("--parallel", 2)
@@ -280,7 +296,7 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
         f"{sum(e['seconds'] for e in built['events']):.1f} s in the compiler; slowest "
         f"{[(e['fun'], round(e['seconds'], 1)) for e in slowest]}")
 
-    log(f"[setup] waited {reference.wait():.1f} s after the warm-up for the reference")
+    log(f"[setup] waited {sum(r.wait() for r in references):.1f} s after the warm-up for the reference")
     lead_in = float(mix["lead_in_s"])
     drain = float(mix["drain_limit_s"])
     t0 = time.monotonic() + 0.2  # the load's clock; the window opens lead_in later
@@ -337,11 +353,15 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
          for r in in_window if r.ok]))
     log(f"[window] end to end: {json.dumps({k: round(v, 3) for k, v in metrics.items()})}")
 
-    # after the drain: the same greedy probe, alone again, must answer the same
+    # after the drain: the same greedy probe, alone again, must answer the same ...
     life1 = server.scrape()
-    again = _probe(server, probes[0])
-    same = again.text == answers[0].text
-    log(f"[check] greedy probe repeated after the drain: {'identical' if same else 'DIFFERENT'}")
+    # ... the first request the server ever served (probe 0; a cell's first long probe where it
+    # has some, since those go first): a later probe's FIRST answer depends on what the server
+    # answered before it (PERF.md §7, PR 36), the first request's never has
+    first = n_short if check["long_probes"] else 0
+    same = _probe(server, probes[first]).text == answers[first].text
+    which = f" (the long one, {probes[first].prompt_tokens} prompt tokens)" if first else ""
+    log(f"[check] greedy probe{which} repeated after the drain: {'identical' if same else 'DIFFERENT'}")
     built = server.control("/compiles")
     # time.monotonic is one clock for every process of a machine (CLOCK_MONOTONIC),
     # so the child's instants compare with the parent's
@@ -369,7 +389,7 @@ def _measure(cell: Cell, server: Server, device: dict, model: str, cache: str, t
         f"({'consistent' if totals_ok else 'INCONSISTENT'}; {sum(not r.ok for r in records)} "
         f"of {len(records)} requests not completed)")
 
-    ref_ok, ref_note = reference.verdict()
+    ref_ok, ref_note = _reference_verdict(references, check)
     log(f"[check] reference: {ref_note}")
     correct = bool(device["platform"] == require_platform and device["count"] >= cell.chips
                    and rc == 0 and same and totals_ok and ref_ok)
@@ -443,25 +463,27 @@ def judge_probes(rows: list[dict], check: dict) -> tuple[bool, str]:
 
 
 class _Reference:
-    """The plain reference over the probes the server answered, in a child on
-    the host's CPU (the chip is the server's), teacher-forced with the
-    server's own tokens."""
+    """The plain reference over a group of probes the server answered (one
+    prompt length a group), in a child on the host's CPU (the chip is the
+    server's), teacher-forced with the server's own tokens."""
 
-    def __init__(self, cell: Cell, model: str, cache: str, probes: list, answers: list):
-        self.proc, self.skipped, self.check = None, 0, cell.check
+    def __init__(self, cell: Cell, model: str, cache: str, probes: list, answers: list, tag: str = ""):
+        self.proc, self.skipped = None, 0
+        self.prompt_tokens = probes[0].prompt_tokens
         items = []
         for p, a in zip(probes, answers):
             ids = traffic.answer_ids(a.text or "", len(a.deltas))
             self.skipped += len(a.deltas) - len(ids)
             if ids:
                 items.append({"prompt": traffic.encode_chat(p.body["messages"]), "answer": ids})
+        self.read_back = len(items)
         if not items:
             return
-        probes_path = os.path.join(cache, "probes.json")
-        self.out_path = os.path.join(cache, "reference.json")
+        probes_path = os.path.join(cache, f"probes{tag}.json")
+        self.out_path = os.path.join(cache, f"reference{tag}.json")
         with open(probes_path, "w") as f:
             json.dump(items, f)
-        self._err = open(os.path.join(cache, "reference.log"), "w+")
+        self._err = open(os.path.join(cache, f"reference{tag}.log"), "w+")
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "benchmark.reference.probe_child", cell.dir, cell.config_path,
              model, probes_path, self.out_path],
@@ -483,26 +505,54 @@ class _Reference:
             self.proc.kill()
             self.proc.wait()
 
-    def verdict(self) -> tuple[bool, str]:
-        if self.proc is None:
-            return False, "no probe answer could be read back as tokens"
+    def rows(self) -> tuple[list[dict], float]:
+        """(a row for every answered position of the group's probes, the
+        child's seconds); raises ``BenchFailure`` if the child gave none."""
         self.wait()
         if self.proc.returncode != 0:
             self._err.seek(0)
-            return False, f"reference child exited with code {self.proc.returncode}: {self._err.read()[-2000:]}"
+            raise BenchFailure(f"reference child exited with code {self.proc.returncode}: "
+                               f"{self._err.read()[-2000:]}")
         out = load_json(self.out_path)
-        rows = [dict(r, probe=i, position=j) for i, probe in enumerate(out["probes"])
-                for j, r in enumerate(probe)]
-        for r in rows:
-            if r["deficit"] > self.check["miss_tol"]:
-                tie = r["router_gap"] is not None and r["router_gap"] < self.check["router_tie"]
-                log(f"[check] over the miss line{' (a routing near-tie, left out)' if tie else ''}: "
-                    f"{json.dumps(r)}")
-        margins = sorted(r["margin"] for r in rows)
-        ok, note = judge_probes(rows, self.check)
-        return ok, (f"{note}; {self.skipped} answered positions not read back as tokens; the "
-                    f"reference's top-1/top-2 margin: median {margins[len(margins) // 2]:.2e}; "
-                    f"{out['seconds']:.1f} s on the host beside the warm-up")
+        return [dict(r, probe=i, position=j, prompt_tokens=self.prompt_tokens)
+                for i, probe in enumerate(out["probes"]) for j, r in enumerate(probe)], out["seconds"]
+
+
+def _reference_verdict(references: list, check: dict) -> tuple[bool, str]:
+    """The reference rule's verdict over the groups of probes (the long
+    ones, where the cell's check block asks for some, and the short ones),
+    all their positions judged together by ``judge_probes``."""
+    if not any(r.proc for r in references):
+        return False, "no probe answer could be read back as tokens"
+    rows, seconds = [], []
+    try:
+        for ref in references:
+            if ref.proc is not None:
+                got, took = ref.rows()
+                rows += got
+                seconds.append(took)
+    except BenchFailure as e:
+        return False, str(e)
+    for r in rows:
+        if r["deficit"] > check["miss_tol"]:
+            tie = r["router_gap"] is not None and r["router_gap"] < check["router_tie"]
+            log(f"[check] over the miss line{' (a routing near-tie, left out)' if tie else ''}: "
+                f"{json.dumps(r)}")
+    margins = sorted(r["margin"] for r in rows)
+    ok, note = judge_probes(rows, check)
+    if check["long_probes"]:
+        # the long context is what such a cell times: every long probe has to be among the compared
+        long_refs = [r for r in references if r.prompt_tokens > check["probe_prompt"]]
+        read_back = sum(r.read_back for r in long_refs)
+        after_long = [r["deficit"] for r in rows if r["prompt_tokens"] > check["probe_prompt"]]
+        ok = ok and read_back == check["long_probes"]
+        note += (f"; {len(after_long)} of the positions answered follow a prompt of "
+                 f"{check['long_probe_prompt']} tokens, worst {max(after_long, default=0.0):.2e}, "
+                 f"{sum(d > check['miss_tol'] for d in after_long)} over the miss line "
+                 f"({read_back} of {check['long_probes']} long probes read back, all are needed)")
+    return ok, (f"{note}; {sum(r.skipped for r in references)} answered positions not read back as "
+                f"tokens; the reference's top-1/top-2 margin: median {margins[len(margins) // 2]:.2e}; "
+                f"{' + '.join(f'{t:.1f}' for t in seconds)} s on the host beside the warm-up")
 
 
 def _traced_phase(server: Server, mix: dict, seed: int, seconds: float, requests,
@@ -535,7 +585,7 @@ def _traced_phase(server: Server, mix: dict, seed: int, seconds: float, requests
     log(f"[trace] profiler started and stopped once, trace thrown away "
         f"({first['stop_trace_seconds']:.2f} s to stop)")
 
-    lead_in = min(float(mix["lead_in_s"]), TRACE_LEAD_IN_S)
+    lead_in = float(mix.get("trace_lead_in_s", min(float(mix["lead_in_s"]), TRACE_LEAD_IN_S)))
     drain = float(mix["drain_limit_s"])
     t0 = time.monotonic() + 0.2
     t_trace = t0 + lead_in

@@ -101,25 +101,27 @@ def open_loop(port: int, schedule: list, t_start: float, t_end: float, timeout: 
 
 def closed_loop(port: int, requests, callers: int, t_stop: float, t_end: float,
                 timeout: float) -> list[Record]:
-    """``callers`` threads, each sending the next request of the shared
-    iterator until ``t_stop``; then wait for the last streams until ``t_end``."""
+    """``callers`` threads, each sending the next request of its own stream
+    (``requests.caller(i)``: ``traffic.ClosedLoop``) until ``t_stop``; then
+    wait for the last streams until ``t_end``."""
     lock = threading.Lock()
     records: list[Record] = []
 
-    def caller() -> None:
+    def caller(mine) -> None:
         while True:
             now = time.monotonic()
             if now >= t_stop:
                 return
             with lock:
-                req = next(requests)
+                req = next(mine)
                 rec = _record(req, now)
                 records.append(rec)
             send(port, rec, req.body, timeout)
             if not rec.ok:
                 time.sleep(0.2)  # a refusing server must not be hammered in a spin
 
-    threads = [threading.Thread(target=caller, daemon=True) for _ in range(callers)]
+    threads = [threading.Thread(target=caller, args=(requests.caller(i),), daemon=True)
+               for i in range(callers)]
     for th in threads:
         th.start()
     for th in threads:

@@ -252,33 +252,93 @@ def open_loop_schedule(mix: dict, seed: int, seconds: float) -> list[Request]:
     return out
 
 
-def closed_loop_requests(mix: dict, seed: int):
-    """An endless stream of single-turn requests for a closed loop: blocks of
-    ``block`` stratified shapes, each block shuffled by the seed."""
+def _sessions(mix: dict, seed: int):
+    """An endless stream of a closed loop's sessions, each a list of requests
+    one caller sends in turn: blocks of ``block`` stratified shapes, each block
+    shuffled by the seed. Without ``documents`` a session is ONE single-turn
+    request. With ``documents`` ``{"tokens": <dist>, "asks": <n>}`` it is a
+    fresh document (a ``system`` message of that many tokens, text from the
+    seed) asked ``asks`` times, each ask with its own question and answer
+    length: the asks share the document's tokens as a prefix and differ after
+    it. A block then holds ``block // asks`` documents, their sizes stratified
+    like the questions' and the answers'."""
     rng = random.Random(seed)
     chars = mix_alphabet(mix)
     block = int(mix.get("block", 64))
     cap = int(mix["context_cap"])
-    index = 0
+    prompt_cap = int(mix.get("prompt_cap", cap))
+    docs = mix.get("documents")
+    asks = int(docs["asks"]) if docs else 1
+    if asks < 1 or block % asks:
+        raise ValueError(f"a block of {block} shapes does not hold whole sessions of {asks} asks")
+    index = count = 0
     while True:
         users = stratified(mix["user_tokens"], block, rng, integer=True)
         outs = stratified(mix["output_tokens"], block, rng, integer=True)
-        for n_user, n_out in zip(users, outs):
-            messages = [{"role": "user", "content": _text(rng, n_user, chars)}]
-            n_prompt = chat_tokens(messages)
-            if n_prompt + n_out > cap:
-                raise ValueError(f"closed-loop shape {n_prompt}+{n_out} exceeds context_cap {cap}")
-            yield Request(index, 0.0, _body(messages, n_out), n_prompt, n_out)
-            index += 1
+        sizes = stratified(docs["tokens"], block // asks, rng, integer=True) if docs else []
+        for s in range(block // asks):
+            head = [{"role": "system", "content": _text(rng, sizes[s], chars)}] if docs else []
+            session = []
+            for turn in range(asks):
+                n_user, n_out = users[s * asks + turn], outs[s * asks + turn]
+                messages = head + [{"role": "user", "content": _text(rng, n_user, chars)}]
+                n_prompt = chat_tokens(messages)
+                if n_prompt + n_out > cap or n_prompt > prompt_cap:
+                    raise ValueError(f"closed-loop shape {n_prompt}+{n_out} exceeds context_cap {cap} "
+                                     f"or prompt_cap {prompt_cap}")
+                session.append(Request(index, 0.0, _body(messages, n_out), n_prompt, n_out, count, turn))
+                index += 1
+            count += bool(docs)  # a mix of single requests numbers no sessions, as before
+            yield session
 
 
-def probe_requests(seed: int, count: int, prompt_tokens: int, max_tokens: int) -> list[Request]:
+class ClosedLoop:
+    """A closed loop's requests. Iterated, it is the sessions' requests one
+    after the other: the stream every caller draws from where a session is one
+    request. ``caller(i)`` is caller ``i``'s own stream, the same one each
+    time it is asked for: whole sessions, each taken from the shared stream
+    when the caller's last one is used up, so that the asks of one document
+    are sent in turn by the one caller that holds it, in a later phase of the
+    run too. Not locked: the load loop draws under its own lock."""
+
+    def __init__(self, mix: dict, seed: int):
+        self._sessions = _sessions(mix, seed)
+        self._callers: dict = {}
+
+    def _stream(self):
+        while True:
+            yield from next(self._sessions)
+
+    def caller(self, i: int):
+        if i not in self._callers:
+            self._callers[i] = self._stream()
+        return self._callers[i]
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> Request:
+        return next(self.caller(None))
+
+
+def closed_loop_requests(mix: dict, seed: int) -> ClosedLoop:
+    """An endless stream of requests for a closed loop (``_sessions`` says
+    what a mix makes of it)."""
+    return ClosedLoop(mix, seed)
+
+
+def probe_requests(seed: int, count: int, prompt_tokens: int, max_tokens: int,
+                   long_count: int = 0, long_prompt_tokens: int = 0) -> list[Request]:
     """Fixed greedy probes (unique text from the seed), sent alone and not
-    streamed: the answers the reference is compared with."""
+    streamed: the answers the reference is compared with. The last
+    ``long_count`` of the ``count`` have prompts of ``long_prompt_tokens``
+    tokens: the positions of a long context are compared too. The short ones
+    come first, so they are the same probes whatever follows them."""
     rng = random.Random(seed ^ 0x5EED)
     out = []
     for i in range(count):
-        n_user = prompt_tokens - chat_tokens([{"role": "user", "content": ""}])
+        n_prompt = long_prompt_tokens if i >= count - long_count else prompt_tokens
+        n_user = n_prompt - chat_tokens([{"role": "user", "content": ""}])
         messages = [{"role": "user", "content": _text(rng, n_user)}]
         body = {**_body(messages, max_tokens), "stream": False, "cache": "off"}
         out.append(Request(i, 0.0, body, chat_tokens(messages), max_tokens))
@@ -290,7 +350,8 @@ def warmup_waves(mix: dict, seed: int, rows: int, pool_tokens: int) -> list[list
     the server compile; a wave's requests are sent ``due_s`` after the wave
     starts and the next wave waits for all of them. First, alone, one prompt
     for each power-of-two count of pages a prefill publishes (1..16, and on
-    doubling while a prompt of that many pages fits the mix's ``prompt_cap``)
+    doubling while a prompt of that many pages fits the mix's ``prompt_cap``,
+    then one of ``prompt_cap`` tokens itself: the bucket of the longest prompt)
     and one whose tail covers each prefill bucket (8..256 rows). Then, where the mix says
     ``warm_pool_overflow`` (its traffic fills the page pool within a run),
     enough unique long prompts to overflow the pool of ``pool_tokens``
@@ -311,6 +372,10 @@ def warmup_waves(mix: dict, seed: int, rows: int, pool_tokens: int) -> list[list
     while 64 * pages + 20 <= cap:  # a mix of longer prompts publishes more pages at once
         lengths.append(64 * pages + 20)
         pages *= 2
+    if pages > 32 and lengths[-1] < cap:
+        # ... and its longest prompt lies between two powers: the page bucket above the last
+        # doubling, and the deepest scan over a row's own positions, are built here too
+        lengths.append(cap)
     # the prefill program is keyed by its chunk's padded rows alone: one tail per bucket
     lengths += [256 + b - 3 for b in (8, 16, 32, 64, 128, 256)]
     if mix.get("warm_pool_overflow"):

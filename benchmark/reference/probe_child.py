@@ -74,19 +74,21 @@ def main(argv: list[str]) -> int:
     with open(probes_path) as f:
         probes = json.load(f)
     qf = QFile(model, family)
-    n_prompt = len(probes[0]["prompt"])
-    if any(len(p["prompt"]) != n_prompt for p in probes):
-        raise ValueError("probes must share one prompt length")
-    n_ans = max(len(p["answer"]) for p in probes)
-    tokens = np.zeros((len(probes), n_prompt + n_ans), np.int32)
-    for i, p in enumerate(probes):
-        tokens[i, :n_prompt] = p["prompt"]
-        tokens[i, n_prompt:n_prompt + len(p["answer"])] = p["answer"]
-    # position n_prompt - 1 + j predicts answer token j
-    positions = np.arange(n_prompt - 1, n_prompt - 1 + n_ans)
-    gaps: list = []
-    logits = family.forward(qf, tokens, positions, gaps)
-    out = score(logits, [p["answer"] for p in probes], gaps)
+    out: list = [None] * len(probes)
+    # one pass for the probes of each prompt length (a cell may send long probes among the short)
+    for n_prompt in sorted({len(p["prompt"]) for p in probes}):
+        group = [i for i, p in enumerate(probes) if len(p["prompt"]) == n_prompt]
+        n_ans = max(len(probes[i]["answer"]) for i in group)
+        tokens = np.zeros((len(group), n_prompt + n_ans), np.int32)
+        for row, i in enumerate(group):
+            tokens[row, :n_prompt] = probes[i]["prompt"]
+            tokens[row, n_prompt:n_prompt + len(probes[i]["answer"])] = probes[i]["answer"]
+        # position n_prompt - 1 + j predicts answer token j
+        positions = np.arange(n_prompt - 1, n_prompt - 1 + n_ans)
+        gaps: list = []
+        logits = family.forward(qf, tokens, positions, gaps)
+        for i, rows in zip(group, score(logits, [probes[i]["answer"] for i in group], gaps)):
+            out[i] = rows
     with open(out_path, "w") as f:
         json.dump({"seconds": time.monotonic() - t0, "probes": out}, f)
     return 0

@@ -7,8 +7,11 @@ two readings: what a faithful engine gives (the harness prints it in every
 run) and what this prints for the nearest precision below, which has to come
 out as not correct. Host CPU only; at a published width it takes minutes.
 
-    JAX_PLATFORMS=cpu python3 benchmark/tools/precision_control.py <config> \
+    JAX_PLATFORMS=cpu python3 benchmark/tools/precision_control.py <config | cell> \
         [--probes 4] [--seed 7] [--variants q80,three_mantissa_bits,...]
+
+A cell (``benchmark/workloads/<cell>.json``) is controlled under ITS rule: its
+configuration's check block with the cell's own over it, long probes included.
 
 Variants (``VARIANTS``): the input of every Q40 matmul rounded to Q80 (the
 engine's own rounding: has to PASS), to bfloat16, to three mantissa bits
@@ -17,8 +20,9 @@ family's reference hands a recurrent state from step to step (``carry``),
 that state held in bfloat16 or float8.
 
 Probes are ``--probes`` rows of ``probe_prompt + probe_tokens`` random
-tokens, teacher-forced: at every answered position the variant's greedy token
-is scored against the float32 reference's logits for the same context.
+tokens (the last ``long_probes`` of them ``long_probe_prompt + probe_tokens``),
+teacher-forced: at every answered position the variant's greedy token is
+scored against the float32 reference's logits for the same context.
 """
 
 from __future__ import annotations
@@ -93,23 +97,35 @@ def control(config: dict, model: str, check: dict, seed: int, names: list[str]) 
 
     ref = families.load(config, "reference")
     qf = QFile(model, ref)
-    n_prompt, n_ans = check["probe_prompt"], check["probe_tokens"]
+    n_ans, n_long = check["probe_tokens"], check["long_probes"]
     rng = np.random.default_rng(seed)
-    tokens = rng.integers(3, config["vocab_size"], (check["probes"], n_prompt + n_ans)).astype(np.int32)
-    tokens[:, 0] = 1
-    positions = np.arange(n_prompt - 1, n_prompt - 1 + n_ans)
-    gaps: list = []
-    want = ref.forward(qf, tokens, positions, gaps)
+    # the short probes, then the long ones: a pass of the reference for each prompt length
+    passes = []
+    for count, n_prompt in ((check["probes"] - n_long, check["probe_prompt"]), (n_long, check["long_probe_prompt"])):
+        if count:
+            tokens = rng.integers(3, config["vocab_size"], (count, n_prompt + n_ans)).astype(np.int32)
+            tokens[:, 0] = 1
+            positions = np.arange(n_prompt - 1, n_prompt - 1 + n_ans)
+            gaps: list = []
+            passes.append((tokens, positions, ref.forward(qf, tokens, positions, gaps), gaps))
     out = {}
     for name in names:
         if not hasattr(ref, VARIANTS[name][0]):
             out[name] = (None, f"the reference of family {config['family']!r} has no {VARIANTS[name][0]!r}")
             continue
-        with rounded(ref, name):
-            got = ref.forward(qf, tokens, positions)
-        rows = [r for probe in score(want, got.argmax(-1).tolist(), gaps or None) for r in probe]
+        rows, off, long_rows = [], 0.0, []
+        for tokens, positions, want, gaps in passes:
+            with rounded(ref, name):
+                got = ref.forward(qf, tokens, positions)
+            scored = [r for probe in score(want, got.argmax(-1).tolist(), gaps or None) for r in probe]
+            rows += scored
+            long_rows = scored if n_long and positions[0] == check["long_probe_prompt"] - 1 else long_rows
+            off = max(off, float(np.abs(got - want).max() / np.abs(want).max()))
         ok, note = cell.judge_probes(rows, check)
-        off = float(np.abs(got - want).max() / np.abs(want).max())
+        if long_rows:
+            deficits = [r["deficit"] for r in long_rows]
+            note += (f"; the {len(deficits)} positions after a prompt of {check['long_probe_prompt']} tokens: worst "
+                     f"{max(deficits):.2e}, {sum(d > check['miss_tol'] for d in deficits)} over the miss line")
         out[name] = (ok, f"{note}; logits off by {off:.2e} of max|logit|")
     return out
 
@@ -131,10 +147,14 @@ def main(argv: list[str]) -> int:
     if jax.devices()[0].platform != "cpu":
         print("run with JAX_PLATFORMS=cpu", file=sys.stderr)
         return 3
-    with open(os.path.join(ROOT, "benchmark", "configs", f"{args.config}.json")) as f:
-        config = json.load(f)
-    check = cell.load_check(config=config)
-    check["probes"] = args.probes
+    if os.path.isfile(os.path.join(ROOT, "benchmark", "workloads", f"{args.config}.json")):
+        found = cell.Cell(ROOT, args.config)  # a cell: its configuration, under the cell's own rule
+        config, check = found.config, dict(found.check)
+    else:
+        with open(os.path.join(ROOT, "benchmark", "configs", f"{args.config}.json")) as f:
+            config = json.load(f)
+        check = cell.load_check(config=config)
+    check["probes"] = max(args.probes, check["long_probes"])
     check["min_compared"] = int(args.probes * check["probe_tokens"] * check["min_compared_share"])
     with tempfile.TemporaryDirectory(dir=os.environ.get("TMPDIR")) as tmp:
         model = args.model or modelfile.write_model(

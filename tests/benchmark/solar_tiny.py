@@ -44,10 +44,11 @@ CELL = "tiny-solar.closed"
 FLAGS = tiny_root.FLAGS + ["--prefill-chunk", "32"]
 
 
-def lay(root: str) -> None:
+def lay(root: str, real: dict | None = None) -> None:
     """The toy configuration and its cell into the miniature checkout
     ``root`` (``tiny_root.build``), reporting what the other one-chip closed
-    loop reports."""
+    loop reports and what the real Solar cell reports beside that (``real``:
+    the manifest that says so, this repository's ``BENCHMARK.json``)."""
     bench = os.path.join(root, "benchmark")
     with open(os.path.join(bench, "configs", "tiny-solar.json"), "w") as f:
         json.dump(CONFIG, f)
@@ -63,10 +64,14 @@ def lay(root: str) -> None:
         for m in manifest[group]:
             if "tiny-moe.closed" in m.get("workloads", []):
                 m["workloads"].append(CELL)
-    with open(os.path.join(tiny_root.REPO, "BENCHMARK.json")) as f:
-        real = json.load(f)
-    # the per-layer entries the real cell brings, on the toy cell
+    if real is None:
+        with open(os.path.join(tiny_root.REPO, "BENCHMARK.json")) as f:
+            real = json.load(f)
+    # the per-layer entries the real cell reports and the miniature does not hold yet (those whose
+    # list names cells without a stand-in here): on the toy cell, whoever else joins their lists
+    held = {m["name"] for m in manifest["per_layer"]}
     manifest["per_layer"] += [{**m, "workloads": [CELL]} for m in real["per_layer"]
-                              if m.get("workloads") == ["solar-open2.batch_prompted"]]
+                              if "solar-open2.batch_prompted" in m.get("workloads", [])
+                              and m["name"] not in held]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(manifest, f)

@@ -114,6 +114,51 @@ def test_a_faulty_system_fails(model, reference_logits, fault):
     assert not ok, note
 
 
+LONG_PROMPT = 448  # 7 pages and, at the miniature cell's 32-row prefill chunks, 14 chunks: with ANSWER under 512
+
+
+@pytest.fixture(scope="module")
+def long_context(model):
+    """A cell's own rule with a long probe (``benchmark/workloads/<cell>.json``,
+    ``long_probes``): 7 probes of PROMPT tokens and 1 of LONG_PROMPT, each the
+    reference's pass over its prompt length, judged together."""
+    rng = np.random.default_rng(5)
+    tokens = rng.integers(3, 259, (1, LONG_PROMPT + ANSWER)).astype(np.int32)
+    tokens[:, 0] = 1
+    short, positions = probe_tokens()
+    return [(short[:PROBES - 1], positions), (tokens, np.arange(LONG_PROMPT - 1, LONG_PROMPT - 1 + ANSWER))]
+
+
+def long_verdict(model, long_context, **served):
+    from benchmark.reference.probe_child import score
+
+    scored = []
+    for tokens, positions in long_context:
+        want = logits_of(model, tokens, positions)
+        got = logits_of(model, tokens, positions, **served)
+        scored += [r for probe in score(want, got.argmax(-1).tolist()) for r in probe]
+    assert len(scored) == PROBES * ANSWER
+    return cell.judge_probes(scored, CHECK), max(r["deficit"] for r in scored[-ANSWER:])
+
+
+def test_with_a_long_probe_among_them_the_engines_roundings_still_pass(model, long_context):
+    (ok, note), worst_long = long_verdict(model, long_context, act=q80)
+    assert ok and worst_long <= CHECK["miss_tol"], note
+
+
+@pytest.mark.parametrize("fault", ["three_mantissa_bits", "dropped_layer", "wrong_rope_pairing"])
+def test_with_a_long_probe_among_them_the_control_and_the_faults_still_fail(model, long_context, fault):
+    """The control (the nearest precision under the engine's Q80) and two
+    faults of the path, with one probe of the eight at a long context as the
+    document cell's rule has it: not correct; and the long probe's own
+    positions show the fault too."""
+    served = {"three_mantissa_bits": dict(act=three_mantissa_bits), "dropped_layer": dict(drop_layers=1),
+              "wrong_rope_pairing": dict(swap_rope=True)}[fault]
+    (ok, note), worst_long = long_verdict(model, long_context, **served)
+    assert not ok, note
+    assert worst_long > CHECK["miss_tol"], note
+
+
 def rows(n, misses=(), router_gap=None):
     out = [{"server": 5, "reference": 5, "deficit": 0.0, "router_gap": router_gap} for _ in range(n)]
     for i, d in enumerate(misses):

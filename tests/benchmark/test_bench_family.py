@@ -82,6 +82,72 @@ def test_a_third_family_supplied_only_as_new_files_runs_to_correct(tmp_path):
         families.load(cell.config, "counts")  # the real benchmark/ has no such family
 
 
+LONG_MIX = {"loop": "closed", "callers": 2, "lead_in_s": 1, "block": 8,
+            "user_tokens": {"dist": "uniform", "min": 4096, "max": 12288},
+            "output_tokens": {"dist": "uniform", "min": 4, "max": 8},
+            "context_cap": 14336, "prompt_cap": 12544, "drain_limit_s": 120, "trace_lead_in_s": 2,
+            "why": "rehearsal: what a model_config PR brings for a context of 16384 positions"}
+
+
+def lay_long_context_cell(root: str) -> None:
+    """A cell of 12288-token prompts at ``--max-seq-len 16384`` into the
+    miniature checkout ``root``: a configuration's file, a mix, a cell's file
+    and their entries, new files only, as the next ``model_config`` PR brings
+    them (no ``documents`` key: single requests)."""
+    bench = os.path.join(root, "benchmark")
+    config = {**tiny_root.CONFIGS["tiny-dense"], "name": "tiny-long", "max_position_embeddings": 16384}
+    with open(os.path.join(bench, "configs", "tiny-long.json"), "w") as f:
+        json.dump(config, f)
+    with open(os.path.join(bench, "traffic", "long.json"), "w") as f:
+        json.dump(LONG_MIX, f)
+    entry = {"name": "tiny-long.closed", "config": "tiny-long", "traffic": "long", "chips": 1, "why": "rehearsal"}
+    flags = [{"512": "16384", "24": "512"}.get(f, f) for f in tiny_root.FLAGS]
+    with open(os.path.join(bench, "workloads", "tiny-long.closed.json"), "w") as f:
+        json.dump({**entry, "flags": flags,
+                   "check": {"why": "one probe past the 16th prefill chunk", "long_probes": 1,
+                             "long_probe_prompt": 4128, "probe_tokens": 8}}, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    manifest["workloads"].append(entry)
+    manifest["configs"].append({"name": "tiny-long", "file": "benchmark/configs/tiny-long.json",
+                                "source": "none", "reduced": [], "why": "rehearsal"})
+    for group in ("end_to_end", "per_layer"):
+        for m in manifest[group]:
+            if "tiny-docs.closed" in m.get("workloads", []):
+                m["workloads"].append("tiny-long.closed")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(manifest, f)
+
+
+def test_a_cell_of_16384_positions_supplied_only_as_new_files_passes_and_runs(tmp_path, capsys):
+    import test_bench_traffic
+
+    before = real_benchmark_files()
+    root = tiny_root.build(str(tmp_path / "checkout"))
+    lay_long_context_cell(root)
+    # what tests/benchmark holds every mix to, with the bounds of the cell that sends it
+    assert test_bench_traffic.served_positions("long", root) == 16384
+    gen = traffic.closed_loop_requests(LONG_MIX, 3)
+    sent = [next(gen) for _ in range(16)]
+    assert max(r.prompt_tokens for r in sent) > 11500  # the top stratum of 8 between 4096 and 12288
+    assert test_bench_traffic.out_of_bounds(LONG_MIX, 16384, sent) == []
+    assert test_bench_traffic.out_of_bounds(LONG_MIX, 2048, sent) != []
+    singles = [w[0].prompt_tokens for w in traffic.warmup_waves(LONG_MIX, 1, 2, 512 * 64) if len(w) == 1]
+    assert singles[5:9] == [2068, 4116, 8212, 12544]  # 196 pages: the bucket of 256 is built before the window
+    cell = cell_mod.Cell(root, "tiny-long.closed")
+    assert (cell.flag("--max-seq-len", 0), cell.check["long_probe_prompt"], cell.check["probe_tokens"]) == \
+        (16384, 4128, 8)
+    result = cell_mod.run_cell(root, "tiny-long.closed", 2**31 + 23, 15.0, 0, "cpu", time.monotonic())
+    # (on a loaded machine no request may be DUE inside so short a window: the two the callers
+    # start with take seconds each; `correct` says their tokens arrived and were counted)
+    assert result["correct"] is True and result["failed"] == 0
+    assert set(result["metrics"]) == test_bench_run.NAMES["tiny-docs.closed"]
+    said = capsys.readouterr().err
+    assert "8 of the positions answered follow a prompt of 4128 tokens" in said
+    assert "programs built inside the window: 0" in said
+    assert real_benchmark_files() == before
+
+
 TINY_DENSE = tiny_root.CONFIGS["tiny-dense"]
 
 
@@ -132,6 +198,40 @@ def test_a_check_block_overrides_by_name_and_carries_its_reason():
     for block in ({"probe_prompt": 600}, {"why": " ", "probe_prompt": 600}, {"why": "x", "probe_len": 600}):
         with pytest.raises(cell_mod.BenchFailure, match="check block"):
             cell_mod.load_check(config={"name": "c", "check": block})
+        with pytest.raises(cell_mod.BenchFailure, match="cell 'w': its check block"):
+            cell_mod.load_check(launch={"name": "w", "check": block})
+
+
+@pytest.mark.parametrize("config_block,cell_block,want", [
+    (None, None, (64, 32, 0, 0)),
+    ({"probe_prompt": 600}, None, (600, 32, 0, 0)),
+    # the cell's own block over its configuration's, name by name, for that cell alone
+    ({"probe_prompt": 600, "probe_tokens": 16}, {"probe_prompt": 320, "long_probes": 1, "long_probe_prompt": 4128},
+     (320, 16, 1, 4128)),
+    (None, {"long_probes": 2, "long_probe_prompt": 4128}, (64, 32, 2, 4128)),
+])
+def test_a_cells_check_block_overrides_its_configurations(config_block, cell_block, want):
+    config = {"name": "c", **({"check": {"why": "the configuration's reason", **config_block}} if config_block else {})}
+    launch = {"name": "w", **({"check": {"why": "the cell's reason", **cell_block}} if cell_block else {})}
+    check = cell_mod.load_check(config=config, launch=launch)
+    assert (check["probe_prompt"], check["probe_tokens"], check["long_probes"], check["long_probe_prompt"]) == want
+    assert check["min_compared"] == int(8 * check["probe_tokens"] * 0.25)
+    probes = traffic.probe_requests(5, check["probes"], check["probe_prompt"], check["probe_tokens"],
+                                    check["long_probes"], check["long_probe_prompt"])
+    short = traffic.probe_requests(5, check["probes"], check["probe_prompt"], check["probe_tokens"])
+    n_long = check["long_probes"]
+    assert [p.prompt_tokens for p in probes] == [want[0]] * (8 - n_long) + [want[3]] * n_long
+    assert [p.body for p in probes[:8 - n_long]] == [p.body for p in short[:8 - n_long]]  # the short ones stay
+
+
+@pytest.mark.parametrize("block,complaint", [
+    ({"long_probes": 9, "long_probe_prompt": 4128}, "9 long probes"),
+    ({"long_probes": 1}, "1 long probes of 0 tokens"),
+    ({"long_probes": 1, "long_probe_prompt": 64}, "1 long probes of 64 tokens among 8 of 64"),
+])
+def test_a_long_probe_has_to_be_longer_than_the_rest_and_one_of_them(block, complaint):
+    with pytest.raises(cell_mod.BenchFailure, match=complaint):
+        cell_mod.load_check(launch={"name": "w", "check": {"why": "x", **block}})
 
 
 def load(*parts):
@@ -215,8 +315,9 @@ def test_the_reduction_hands_on_launches_and_seconds_of_every_op():
     assert sum(op["seconds"] for op in red["ops"].values()) == pytest.approx(red["busy_s"])
 
 
-@pytest.mark.parametrize("cap,more", [(1792, []), (2068, [2068]), (9000, [2068, 4116, 8212])])
-def test_past_16_pages_the_warm_up_goes_on_doubling_while_the_prompt_fits(cap, more):
+@pytest.mark.parametrize("cap,more", [(1792, []), (2068, [2068]), (7424, [2068, 4116, 7424]),
+                                      (8212, [2068, 4116, 8212]), (9000, [2068, 4116, 8212, 9000])])
+def test_past_16_pages_the_warm_up_goes_on_doubling_and_ends_with_the_longest_prompt(cap, more):
     mix = {**load("benchmark", "traffic", "single_stream.json"), "prompt_cap": cap, "context_cap": cap + 200}
     singles = [w[0].prompt_tokens for w in traffic.warmup_waves(mix, 1, 2, 384 * 64) if len(w) == 1]
     assert singles[:5] == [84, 148, 276, 532, 1044] and singles[5:5 + len(more)] == more

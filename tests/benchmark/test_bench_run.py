@@ -20,6 +20,7 @@ NAMES = {  # each miniature cell reports what the real cell it stands for report
     # the 16-row closed loop: pace as a median; ttft and the freeze recorded (.rows16)
     "tiny-moe.closed": OPEN | {"tpot_p50_ms.batch"},
     "tiny-tp4.closed": OPEN | {"ttft_p50_ms", "tpot_p50_ms", "stall_p50_ms"},
+    "tiny-docs.closed": OPEN,  # the 8-row document cell: its three latencies recorded (.rows8)
 }
 
 
@@ -39,11 +40,23 @@ def check_line(result, chips, names):
     json.dumps(result)
 
 
-@pytest.mark.parametrize("cell,chips", [("tiny.open", 1), ("tiny-moe.closed", 1), ("tiny-tp4.closed", 4)])
-def test_a_cell_runs_and_agrees_with_the_reference(root, cell, chips):
+@pytest.mark.parametrize("cell,chips", [("tiny.open", 1), ("tiny-moe.closed", 1), ("tiny-tp4.closed", 4),
+                                        ("tiny-docs.closed", 1)])
+def test_a_cell_runs_and_agrees_with_the_reference(root, cell, chips, capsys):
     result = run_cell(root, cell, 2**31 + 11, 3.0, False, "cpu", time.monotonic())
     check_line(result, chips, NAMES[cell])
     assert not os.path.exists(os.path.join(root, "benchmark", ".cache", "model"))  # gigabytes at full size
+    said = capsys.readouterr().err
+    if cell == "tiny-docs.closed":
+        # the check block of the cell's own file: a probe of 200 prompt tokens among the 8, answered,
+        # compared at every position, and repeated after the drain beside the first
+        assert Cell(root, cell).check["long_probe_prompt"] == 200
+        assert re.search(r"32 of the positions answered follow a prompt of 200 tokens, worst \S+, "
+                         r"0 over the miss line \(1 of 1 long probes read back", said)
+        assert "[check] greedy probe (the long one, 200 prompt tokens) repeated after the drain: identical" in said
+    else:
+        assert "[check] greedy probe repeated after the drain: identical" in said
+        assert "follow a prompt of" not in said
 
 
 def _cpu_trace_as_device(cell, trace_dir):
@@ -69,10 +82,11 @@ NEW_COUNTERS = {"decode_consumed_share", "decode_orphaned_share", "decode_active
 
 
 RECORDED = {"tiny.open": {"ttft_p50_ms.open", "tpot_p50_ms.open", "stall_p50_ms.open"},
-            "tiny-moe.closed": {"ttft_p50_ms.rows16", "stall_p50_ms.rows16"}}
+            "tiny-moe.closed": {"ttft_p50_ms.rows16", "stall_p50_ms.rows16"},
+            "tiny-docs.closed": {"ttft_p50_ms.rows8", "tpot_p50_ms.rows8", "stall_p50_ms.rows8"}}
 
 
-@pytest.mark.parametrize("cell", ["tiny.open", "tiny-moe.closed"])
+@pytest.mark.parametrize("cell", ["tiny.open", "tiny-moe.closed", "tiny-docs.closed"])
 def test_trace_2_measures_as_trace_0_does_and_then_traces_in_the_same_process(root, cell, monkeypatch):
     from benchmark.harness import cell as cell_mod
 
@@ -115,6 +129,9 @@ def test_trace_2_measures_as_trace_0_does_and_then_traces_in_the_same_process(ro
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert 0 < m["decode_consumed_share"] <= 100 and 0 <= m["decode_orphaned_share"] < 100
     assert 0 < m["decode_row_fill_share"] <= 100 and m["program_builds_in_window"] == 0
+    if cell == "tiny-docs.closed":
+        # a document's second ask finds its whole pages in the prefix cache: 1 or 2 of a prompt's 3
+        assert 20 < m["prefix_hit_share.open"] < 50
     assert set(result["device"]) >= {"memory_peak_bytes", "busy_s", "window_s"}
     assert 0 < result["device"]["busy_s"] <= result["device"]["window_s"]
     assert not os.path.exists(os.path.join(root, "benchmark", ".cache", "trace"))  # reduced, deleted

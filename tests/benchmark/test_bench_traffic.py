@@ -17,6 +17,35 @@ def mix(name):
         return json.load(f)
 
 
+def served_positions(name, root=REPO):
+    """``S``: the smallest ``--max-seq-len`` among the flags of the cells of
+    ``<root>/BENCHMARK.json`` that send mix ``name`` (the server's default
+    where a cell names none). A mix's caps follow the cells that use it."""
+    from benchmark.harness.cell import Cell
+
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        cells = [w["name"] for w in json.load(f)["workloads"] if w["traffic"] == name]
+    assert cells, f"no cell of BENCHMARK.json sends {name}"
+    return min(Cell(root, cell).flag("--max-seq-len", 2048) for cell in cells)
+
+
+def out_of_bounds(m, served, requests):
+    """What of mix ``m`` does not fit a row of ``served`` positions: [] if
+    nothing. The context leaves 32 positions (a decode chunk dispatched ahead
+    of a request's end); the prompt leaves 256, because engine/batch.py pads a
+    prefill chunk to a power of two of at most 256 rows unless that passes the
+    row's end, and then compiles a program of the exact length."""
+    wrong = []
+    if m["context_cap"] > served - 32:
+        wrong.append(f"context_cap {m['context_cap']} > {served} - 32")
+    if m.get("prompt_cap", m["context_cap"]) > served - 256:
+        wrong.append(f"prompt_cap {m.get('prompt_cap')} > {served} - 256: an exact-length compile")
+    for r in requests:
+        if r.prompt_tokens + r.max_tokens > m["context_cap"] or r.prompt_tokens > m.get("prompt_cap", m["context_cap"]):
+            wrong.append(f"request {r.index}: {r.prompt_tokens} + {r.max_tokens} tokens")
+    return wrong
+
+
 def requests_of(m, seed, seconds=20.0, n=40):
     if m["loop"] == "open":
         return traffic.open_loop_schedule(m, seed, seconds)
@@ -60,12 +89,26 @@ def test_token_counts_match_the_programs_tokenizer(name, tokenizer):
 @pytest.mark.parametrize("name", MIXES)
 def test_every_request_fits_the_context_and_never_needs_an_exact_length_compile(name):
     m = mix(name)
-    for r in requests_of(m, 11, seconds=45.0, n=200):
-        assert r.prompt_tokens + r.max_tokens <= m["context_cap"] <= 2048 - 32
-        # engine/batch.py pads a prefill chunk to a power of two unless that
-        # passes seq_len: a prompt of at most 1792 tokens never does at 2048
-        assert r.prompt_tokens <= m["prompt_cap"] <= 1792
-        assert r.body["temperature"] == 0.0 and r.body["stream"] is True
+    sent = requests_of(m, 11, seconds=45.0, n=200)
+    assert out_of_bounds(m, served_positions(name), sent) == []
+    assert all(r.body["temperature"] == 0.0 and r.body["stream"] is True for r in sent)
+
+
+@pytest.mark.parametrize("name,served,fits", [
+    ("long_doc_qa", 8192, True),  # its cell's --max-seq-len
+    ("long_doc_qa", 2048, False),  # the same mix under a cell that serves 2048 positions
+    ("long_doc_qa", 7648, False),  # the context fits, the longest prompt's last chunk would not pad
+    ("chat_shared", 2048, True), ("batch_decode", 2048, True), ("single_stream", 2048, True),
+    ("batch_prompted", 2048, True),  # the four accepted mixes, unchanged
+    ("chat_shared", 2016, False),
+])
+def test_a_mixs_bounds_follow_the_positions_its_cell_serves(name, served, fits):
+    m = mix(name)
+    assert (out_of_bounds(m, served, requests_of(m, 11, seconds=45.0, n=64)) == []) is fits
+    if name != "long_doc_qa":
+        assert served_positions(name) == 2048
+    else:
+        assert served_positions(name) == 8192
 
 
 def test_every_seed_sends_the_same_work_at_the_same_instants():
@@ -101,6 +144,51 @@ def test_closed_loop_blocks_hold_the_same_multiset_for_every_seed():
     assert len({r.body["messages"][0]["content"] for r in a + b}) == 2 * m["block"]
 
 
+def test_under_documents_a_callers_stream_is_sessions_that_share_their_document():
+    m = mix("long_doc_qa")
+    asks, block = m["documents"]["asks"], m["block"]
+    loop = traffic.closed_loop_requests(m, 2**31 + 7)
+    mine, other = loop.caller(0), loop.caller(1)
+    a = [next(mine) for _ in range(asks)]
+    b = [next(other) for _ in range(asks)]  # drawn in between: a session stays its caller's
+    a += [next(mine) for _ in range(asks)]
+    for session in (a[:asks], a[asks:], b):
+        ids = [traffic.encode_chat(r.body["messages"]) for r in session]
+        doc = session[0].body["messages"][0]
+        assert doc["role"] == "system" and 4096 <= len(doc["content"]) <= 7168
+        assert all(r.body["messages"][0] == doc and len(r.body["messages"]) == 2 for r in session)
+        assert [r.turn for r in session] == list(range(asks)) and len({r.session for r in session}) == 1
+        shared = 2 + len("<|im_start|>system\n") + len(doc["content"])  # BOS, the space, the document
+        for x, y in zip(ids, ids[1:]):
+            same = next(i for i, (p, q) in enumerate(zip(x, y)) if p != q)
+            assert shared + len("<|im_end|>\n<|im_start|>user\n") == same  # and they differ after it
+        assert all(32 <= len(r.body["messages"][1]["content"]) <= 96 and 64 <= r.max_tokens <= 128
+                   for r in session)
+    assert len({r.body["messages"][0]["content"] for r in a + b}) == 3  # each session a fresh document
+    assert loop.caller(0) is mine  # a later phase of the run goes on where the caller stood
+    assert len({r.index for r in a + b}) == 3 * asks
+    # a block holds the same shapes for every seed: block // asks documents, block questions
+    blocks = []
+    for seed in (1, 2):
+        gen = traffic.closed_loop_requests(m, seed)
+        reqs = [next(gen) for _ in range(block)]
+        blocks.append((sorted(len(r.body["messages"][0]["content"]) for r in reqs[::asks]),
+                       sorted(len(r.body["messages"][1]["content"]) for r in reqs),
+                       sorted(r.max_tokens for r in reqs)))
+        assert [r.turn for r in reqs] == list(range(asks)) * (block // asks)
+    assert blocks[0] == blocks[1] and len(blocks[0][0]) == block // asks
+    with pytest.raises(ValueError, match="whole sessions"):
+        next(traffic.closed_loop_requests(dict(m, block=62), 1))
+
+
+def test_without_documents_every_callers_stream_is_the_one_shared_stream():
+    m = mix("batch_decode")
+    loop, flat = traffic.closed_loop_requests(m, 5), traffic.closed_loop_requests(m, 5)
+    drawn = [next(loop.caller(i % 3)) for i in range(12)]  # three callers by turns
+    assert [(r.index, r.body, r.session, r.turn) for r in drawn] == \
+        [(r.index, r.body, 0, 0) for r in (next(flat) for _ in range(12))]
+
+
 @pytest.mark.parametrize("name", MIXES)
 def test_a_mix_draws_its_text_from_its_own_alphabet_or_the_generators(name):
     m = mix(name)
@@ -123,8 +211,13 @@ def test_an_alphabet_that_could_break_one_character_one_token_is_refused(chars):
 @pytest.mark.parametrize("name", MIXES)
 def test_warmup_touches_every_prefill_bucket_the_mix_can_reach(name):
     m = mix(name)
-    waves = traffic.warmup_waves(dict(m, warm_pool_overflow=True), 1, 16, 384 * 64)
-    assert len(traffic.warmup_waves(dict(m, warm_pool_overflow=False), 1, 16, 384 * 64)) == 12
+    pool = 384 * 64
+    waves = traffic.warmup_waves(dict(m, warm_pool_overflow=True), 1, 16, pool)
+    # past 16 pages: 2068 and 4116 tokens, then the longest prompt itself (long_doc_qa alone)
+    longer = [2068, 4116, m["prompt_cap"]] if m["prompt_cap"] >= 2068 else []
+    plain = traffic.warmup_waves(dict(m, warm_pool_overflow=False), 1, 16, pool)
+    assert len(plain) == 12 + len(longer)
+    assert [w[0].prompt_tokens for w in plain[5:5 + len(longer)]] == longer
     singles = [w[0].prompt_tokens for w in waves if len(w) == 1]
 
     def buckets(n):
@@ -135,10 +228,19 @@ def test_warmup_touches_every_prefill_bucket_the_mix_can_reach(name):
             n -= c
         return out
 
+    def page_bucket(n):  # engine/batch.py: _page_bucket over the whole pages a cold prompt publishes
+        return 1 << max(0, n // 64 - 1).bit_length()
+
+    sent = requests_of(m, 4, n=64)
     warmed = set().union(*(buckets(n) for n in singles))
-    needed = set().union(*(buckets(r.prompt_tokens) for r in requests_of(m, 4, n=64)))
+    needed = set().union(*(buckets(r.prompt_tokens) for r in sent))
     assert needed <= warmed
-    assert sum(n for n in singles if n >= 1024) > 384 * 64  # the pool overflows
+    # ... and every count of pages a prompt of the mix publishes at once, its longest one's too
+    assert {page_bucket(r.prompt_tokens) for r in sent} | {page_bucket(m["prompt_cap"])} <= \
+        {page_bucket(n) for n in singles}
+    if longer:  # the deepest scan over a row's own positions runs before the window
+        assert max(singles) == m["prompt_cap"] >= max(r.prompt_tokens for r in sent)
+    assert sum(n for n in singles if n >= 1024) > pool  # the pool overflows
     ramp = waves[-1]
     assert len(ramp) == 16 and ramp[0].due_s == 0.0 and ramp[-1].due_s > ramp[0].due_s
 

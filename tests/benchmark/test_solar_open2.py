@@ -387,3 +387,28 @@ def test_the_cell_runs_through_the_harness_on_the_cpu(tmp_path, monkeypatch):
     assert 0.05 < metrics["moe_rows_per_expert_mean"]["value"] < 8.0
     # the kernels' shares read nothing at a toy head size (the XLA path serves): left out
     assert "kda_step_roofline" not in metrics
+
+
+@pytest.mark.parametrize("joined", [False, True], ids=["the solar cell alone", "a later cell in its lists"])
+def test_the_toy_cell_finds_the_solar_cells_entries_whoever_else_lists_them(tmp_path, joined):
+    """The Solar cell's own per-layer entries are found by its name IN their
+    lists, so a later cell may join them (a second held-expert model, a
+    second cell of 32 rows) without the toy cell losing them."""
+    import json
+    import os
+
+    with open(os.path.join(tiny_root.REPO, "BENCHMARK.json")) as f:
+        real = json.load(f)
+    own = {m["name"] for m in real["per_layer"] if m.get("workloads") == ["solar-open2.batch_prompted"]}
+    assert {"q40_held_experts_roofline", "q40_dense32_roofline", "moe_held_share",
+            "moe_rows_per_expert_mean", "tpot_p50_ms.rows32"} <= own
+    if joined:
+        for m in real["per_layer"]:
+            if m["name"] in own:
+                m["workloads"].append("later.cell")
+    root = tiny_root.build(str(tmp_path / "checkout"))
+    solar_tiny.lay(root, real)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entries = [m["name"] for m in json.load(f)["per_layer"]
+                   if solar_tiny.CELL in m.get("workloads", [solar_tiny.CELL])]
+    assert own <= set(entries) and len(entries) == len(set(entries))

@@ -31,7 +31,19 @@ TRAFFIC = {
                "user_tokens": {"dist": "uniform", "min": 4, "max": 16},
                "output_tokens": {"dist": "constant", "value": 4},
                "context_cap": 400, "drain_limit_s": 60},
+    # document sessions (mistral7b.long_doc_qa in small): a caller asks each fresh document twice
+    "docs": {"loop": "closed", "callers": 2, "lead_in_s": 1, "block": 8,
+             "documents": {"tokens": {"dist": "uniform", "min": 96, "max": 160}, "asks": 2},
+             "user_tokens": {"dist": "uniform", "min": 4, "max": 16},
+             "output_tokens": {"dist": "constant", "value": 4},
+             "context_cap": 400, "prompt_cap": 256, "drain_limit_s": 60, "warm_pool_overflow": True,
+             "trace_lead_in_s": 1.5},
 }
+# the check block of the document cell's own file: one of its 8 probes has a prompt of 200 tokens,
+# seven prefill chunks of 32 and three pages at this cell's flags
+DOCS_CHECK = {"why": "a rehearsal of a cell whose context is long: the last probe crosses prefill chunks "
+                     "and pages, and its positions are compared like the rest",
+              "long_probes": 1, "long_probe_prompt": 200}
 FLAGS = ["--dtype", "q40", "--parallel", "2", "--max-seq-len", "512", "--kv-pages", "24",
          "--telemetry", "--decode-chunk", "4"]
 
@@ -56,7 +68,9 @@ def build(root: str, device_kind: str = "cpu") -> str:
         "tiny.open": ("tiny-dense", "open", 1, FLAGS),
         "tiny-moe.closed": ("tiny-moe", "closed", 1, FLAGS),
         "tiny-tp4.closed": ("tiny-dense", "closed", 4, FLAGS + ["--tp", "4"]),
+        "tiny-docs.closed": ("tiny-dense", "docs", 1, FLAGS + ["--prefill-chunk", "32"]),
     }
+    checks = {"tiny-docs.closed": DOCS_CHECK}
     for sub in ("configs", "traffic", "workloads"):
         os.makedirs(os.path.join(bench, sub))
     for name, cfg in CONFIGS.items():
@@ -70,11 +84,11 @@ def build(root: str, device_kind: str = "cpu") -> str:
         entry = {"name": name, "config": config, "traffic": mix, "chips": chips, "why": "rehearsal"}
         workloads.append(entry)
         with open(os.path.join(bench, "workloads", f"{name}.json"), "w") as f:
-            json.dump({**entry, "flags": flags}, f)
+            json.dump({**entry, "flags": flags, **({"check": checks[name]} if name in checks else {})}, f)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         real = json.load(f)
     stands_for = {"mistral7b.chat_shared": "tiny.open", "mixtral8x7b.batch_decode": "tiny-moe.closed",
-                  "mistral7b.single_stream": "tiny-tp4.closed"}
+                  "mistral7b.single_stream": "tiny-tp4.closed", "mistral7b.long_doc_qa": "tiny-docs.closed"}
     # the real metrics, on the miniature's cells: a cell with no stand-in here is left out of a
     # metric's list, and a metric of such cells alone is left out whole (a test that wants such
     # a cell in the miniature lays it in itself: test_bench_family.lay_toy_family, solar_tiny.lay)
