@@ -127,6 +127,18 @@ def _page_bucket(n: int) -> int:
     return next_pow2(n)
 
 
+def _padded_pages(ids: list[int], blocks: list[int], drop: int):
+    """``(page ids, source blocks)`` of a publish as int32 arrays padded to
+    their bucket; a padded entry names page ``drop``, past the pool, and its
+    write is dropped."""
+    bucket = _page_bucket(len(ids))
+    padded = np.full(bucket, drop, np.int32)
+    src = np.zeros(bucket, np.int32)
+    padded[: len(ids)] = ids
+    src[: len(blocks)] = blocks
+    return padded, src
+
+
 @jax.jit
 def _slice_page(pool, pid):
     """One pool page's bytes across every layer/half as a flat list (the
@@ -179,6 +191,35 @@ def _publish_pages(page: int, slab, pool, page_ids, src_page, row):
             kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page),
         )
         for leaf, half in zip(slab, pool)
+    ]
+
+
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def _publish_window_pages(page: int, slab, wpool, page_ids, src_page, row):
+    """:func:`_publish_pages` for the window layers: slab row ``row``'s blocks
+    ``src_page``, read out of the rings at their positions' slots, into pages
+    ``page_ids`` of the window layers' pool. ``wpool`` mirrors the slab's
+    layer list (None for a layer of another kind). Only the pool is donated."""
+    return [
+        None if half is None else (
+            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page, ring=True),
+            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page, ring=True),
+        )
+        for leaf, half in zip(slab, wpool)
+    ]
+
+
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+def _restore_window_tail(page: int, slab, wpool, page_ids, dst_page, row):
+    """:func:`_publish_window_pages` in reverse: pages ``page_ids`` of the
+    window layers' pool into the slots of row ``row``'s rings where blocks
+    ``dst_page`` sit (a prefix hit resumes behind them). Only the slab is
+    donated."""
+    return [
+        leaf if half is None else kvc.restore_row_pages(
+            leaf, half[0], half[1], row, dst_page, page_ids, page
+        )
+        for leaf, half in zip(slab, wpool)
     ]
 
 
@@ -685,7 +726,7 @@ class BatchScheduler:
             )
         if n_rows < 1:
             raise ValueError(f"need at least one batch row, got {n_rows}")
-        if engine.cfg.is_recurrent:
+        if not engine.cfg.rewinds_by_position:
             # paths that move or rewind a row by position refuse by name
             if spec_draft and int(spec_draft) > 0:
                 llama.refuse_recurrent(
@@ -695,7 +736,8 @@ class BatchScheduler:
                 llama.refuse_recurrent(engine.cfg, "a sharded (tp/pod) backend")
             if spill_arena is not None or host_spill_bytes > 0:
                 llama.refuse_recurrent(
-                    engine.cfg, "the host spill tier (a state snapshot has no spill form)"
+                    engine.cfg, "the host spill tier (a state snapshot or a window layer's "
+                    "page has no spill form)"
                 )
         self.engine = engine
         self.b_max = n_rows
@@ -708,12 +750,18 @@ class BatchScheduler:
         self.prefill_chunk = max(
             0, 0 if prefill_chunk is None else int(prefill_chunk)
         )
+        if engine.cfg.has_window:
+            # a window layer's ring takes a prompt in pieces that fit it
+            # beside the window (a monolithic dispatch does not)
+            self.prefill_chunk = min(self.prefill_chunk or engine.cfg.ring_piece,
+                                     engine.cfg.ring_piece)
         # radix-tree prefix cache over pool pages (ISSUE 4 tentpole, ISSUE 7
         # zero-copy): an admission prefill binds published KV pages to the
         # row's page table (attention reads them straight out of the pool)
         # and prefills only the unmatched suffix
         self._prefix = None
         self._pool = None
+        self._wpool = None
         if prefix_cache:
             # misconfiguration disables ONLY the prefix cache (with the
             # real reason printed) — it must never take batched decode
@@ -784,6 +832,22 @@ class BatchScheduler:
                 snap_slots = (
                     max(2, kv_pages // SNAPSHOT_PAGES) if engine.cfg.is_recurrent else 0
                 )
+                window_tail = self._window_keep = 0
+                if engine.cfg.has_window:
+                    # the window layers' pool: a hit needs the ``window_tail``
+                    # pages before its end; a publish can read the row's last
+                    # ``_window_keep`` whole pages back out of its rings (what
+                    # the last piece and its padding have not overwritten),
+                    # which are the boundaries a prompt that shares this one's
+                    # head and differs near its end hits. The pool holds that
+                    # tail and a hit's own for every row: it follows
+                    # --parallel, not --kv-pages
+                    cfg = engine.cfg
+                    window_tail = -(-cfg.window // page_size)
+                    self._window_keep = max(
+                        0, (cfg.ring_len - _prefill_bucket(self.prefill_chunk)) // page_size - 1
+                    )
+                window_pages = n_rows * (self._window_keep + window_tail)
                 self._prefix = PrefixCache(
                     kv_pages, page_size,
                     page_bytes=llama.page_pool_bytes(
@@ -794,11 +858,16 @@ class BatchScheduler:
                     owner_id=replica_id,
                     shared_index=shared_index,
                     snap_slots=snap_slots,
+                    window_pages=window_pages, window_tail=window_tail,
                 )
                 if tp_engine is None:
                     self._pool = llama.init_page_pool(
                         engine.cfg, kv_pages, page_size, dtype=engine.cache_dtype
                     )
+                    if window_pages:
+                        self._wpool = llama.init_window_pool(
+                            engine.cfg, window_pages, page_size, dtype=engine.cache_dtype
+                        )
                 else:
                     # the sharded pool (per-shard [P, page, K/tp, hd]
                     # halves): PR 4 deferred multi-chip; the zero-copy read
@@ -902,6 +971,33 @@ class BatchScheduler:
         # (device scalar, tokens) of prefill chunks whose held-expert sums are
         # not read yet
         self._moe_pending: list = []
+        if engine.cfg.has_window:
+            for kind, nbytes in llama.kv_slab_bytes(engine.cfg, n_rows, engine.cache_dtype).items():
+                engine._tel.kv_slab_bytes.labels(kind=kind).set(nbytes)
+            # bytes one position's keys and values take in one layer: what the
+            # programs' counts of positions read are multiplied by
+            self._kv_position_bytes = llama.page_pool_bytes(
+                engine.cfg, 1, engine.cache_dtype, layers=1
+            )
+            if self._wpool is not None:
+                for kind, pages in (("full", kv_pages), ("window", self._prefix.window_pages)):
+                    engine._tel.kv_pool_bytes.labels(kind=kind).set(
+                        pages * page_size * self._kv_position_bytes
+                        * len(engine.cfg.layers_of(kind))
+                    )
+                # both programs are built now, not at the first publish or hit
+                # inside a measured window (ids past the pool drop; page 0
+                # holds zeros, and row 0 starts over before it reads a slot)
+                tail = np.zeros(self._prefix.window_tail, np.int32)
+                self._slab = _restore_window_tail(
+                    page_size, self._slab, self._wpool, tail, tail, jnp.int32(0)
+                )
+                for bucket in sorted({_page_bucket(n) for n in range(1, self._window_keep + 1)}):
+                    ids = np.full(bucket, self._prefix.window_pages, np.int32)
+                    self._wpool = _publish_window_pages(
+                        page_size, self._slab, self._wpool, ids, np.zeros(bucket, np.int32),
+                        jnp.int32(0),
+                    )
         self._snaps = None
         if engine.cfg.is_recurrent:
             engine._tel.recurrent_state_bytes.set(
@@ -1476,6 +1572,26 @@ class BatchScheduler:
                         jnp.int32(chain[-1].snap),
                     )
                 prefix.tel.snapshots_restored.inc()
+            if self._wpool is not None:
+                # the window layers' keys and values of the positions before
+                # the hit's end, into the row's rings: those layers resume
+                # from there as the full ones resume from the pages
+                tail = chain[-prefix.window_tail:]
+                first = len(chain) - len(tail)
+                tail = [tail[0]] * (prefix.window_tail - len(tail)) + tail
+                # host buffers of window_tail ints: they cross with the dispatch
+                pages = np.fromiter((nd.wpage for nd in tail), np.int32, len(tail))
+                blocks = np.fromiter(
+                    (max(first, len(chain) - len(tail) + i) for i in range(len(tail))),
+                    np.int32, len(tail),
+                )
+                with self.engine._tel.span(
+                    "window_tail_restore", batch_row=stream.row, pos=stream.pos
+                ):
+                    self._slab = _restore_window_tail(
+                        prefix.page, self._slab, self._wpool, pages, blocks,
+                        jnp.int32(stream.row),
+                    )
         return chain
 
     def _publish_row(self, stream: BatchStream, tokens: np.ndarray, chain: list) -> None:
@@ -1502,12 +1618,10 @@ class BatchScheduler:
                 # where it was taken (or back, if that block is not there)
                 slot, stream._snap_slot = stream._snap_slot, None
                 prefix.snapshot_attach(tokens, tokens.shape[0] // page * page, slot)
+            if self._wpool is not None:
+                self._publish_window_tail_locked(stream, tokens, len(chain))
             if new_ids:
-                bucket = _page_bucket(len(new_ids))
-                ids = np.full(bucket, prefix.capacity, np.int32)  # pad drops
-                src = np.zeros(bucket, np.int32)
-                ids[: len(new_ids)] = new_ids
-                src[: len(new_ids)] = new_blocks
+                ids, src = _padded_pages(new_ids, new_blocks, prefix.capacity)
                 with self.engine._tel.span(
                     "prefix_publish", pages=len(new_ids), batch_row=stream.row
                 ):
@@ -1533,6 +1647,32 @@ class BatchScheduler:
                         if not isinstance(e, Exception):
                             raise
                         print(f"⚠️ prefix publish failed; pages unwound: {e}")
+
+    def _publish_window_tail_locked(self, stream: BatchStream, tokens: np.ndarray, hit: int) -> None:
+        """Copy the window layers' keys and values of the prompt's last whole
+        pages out of the row's rings into the window layers' pool (cond held;
+        the publish has just put the prompt's blocks into the tree). What the
+        rings still hold of this prompt: nothing older than the last piece and
+        its padding left (``_window_keep`` pages back from the prompt's end),
+        and after a hit at block ``hit`` nothing before the tail it restored.
+        A failed copy leaves pages of garbage attached: they are detached."""
+        prefix = self._prefix
+        n_blocks = tokens.shape[0] // prefix.page
+        first = max(n_blocks - self._window_keep, hit - prefix.window_tail)
+        ids, blocks = prefix.attach_window_pages(tokens, tokens.shape[0], first)
+        if not ids:
+            return
+        padded, src = _padded_pages(ids, blocks, prefix.window_pages)
+        with self.engine._tel.span("window_tail_publish", pages=len(ids), batch_row=stream.row):
+            try:
+                self._wpool = _publish_window_pages(
+                    prefix.page, self._slab, self._wpool, padded, src, jnp.int32(stream.row)
+                )
+            except BaseException as e:
+                prefix.detach_window_pages(tokens, blocks)
+                if not isinstance(e, Exception):
+                    raise
+                print(f"⚠️ window-tail publish failed; pages detached: {e}")
 
     # ------------------------------------------------------------------
     # Zero-copy alias lifetime (ISSUE 7): pins released at reset/
@@ -1737,7 +1877,7 @@ class BatchScheduler:
             stream._joined = True
             # a decode chunk computes whole chunks: from here on the row's
             # recurrent state may be past ``pos``
-            stream._state_ahead = self.engine.cfg.is_recurrent
+            stream._state_ahead = not self.engine.cfg.rewinds_by_position
             stream._chunk_fps = []
             if not isinstance(
                 stream._fetch_error, (faults.RowPreempted, faults.ReplicaLost)
@@ -2446,13 +2586,18 @@ class BatchScheduler:
         if toks is not None:
             # unpack the [chunk + 2, B] bundle: tokens + per-row logit
             # fingerprint + finiteness flag (ONE fetch moved all three)
-            held = integrity.chunk_extra_row(toks, self.chunk)
+            extra = list(integrity.chunk_extra_rows(toks, self.chunk))
             toks, fps, finite = integrity.split_chunk_outputs(toks, self.chunk)
-            if held is not None:
+            if engine.cfg.n_routed_experts and extra:
                 # the expert share's routing sums came with the tokens
+                held = extra.pop(0)
                 if tel.enabled:
                     self._count_moe(int(held.sum()), n_active * self.chunk, self.chunk)
                     self._count_prefill_held()
+            if engine.cfg.has_window and extra and tel.enabled:
+                # ... and the cache positions each row's layers read, by kind
+                tel.kv_read_full.inc(int(extra[0].sum()) * self._kv_position_bytes)
+                tel.kv_read_window.inc(int(extra[1].sum()) * self._kv_position_bytes)
             with self._cond:
                 if self._sdc_logits_pending > 0:
                     # engine.sdc message=logits: shift every token column

@@ -191,6 +191,13 @@ class EngineStream:
             raise ValueError("empty token batch: at least one token required")
         if self.pos + n > engine.cfg.seq_len:
             raise ValueError(f"context overflow: pos {self.pos} + {n} > {engine.cfg.seq_len}")
+        piece = engine.cfg.ring_piece if engine.cfg.has_window else n
+        if n > piece:
+            # a window layer's ring takes a prompt in pieces that fit it beside the window
+            return jnp.concatenate([
+                self._forward_device(tokens[off : off + piece])[: min(piece, n - off)]
+                for off in range(0, n, piece)
+            ])
         if n == 1 or getattr(engine._tp_engine, "prefers_exact_mid_prefill", False):
             # backends that pad/chunk multi-token prompts themselves (sp:
             # fixed-width masked-scatter chunks at any position, seq_len

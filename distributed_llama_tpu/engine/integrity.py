@@ -104,24 +104,22 @@ def fingerprint_fold(h, ok, logits, tokens):
     return h, ok & finite
 
 
-def pack_chunk_outputs(tokens, h, ok, extra=None):
+def pack_chunk_outputs(tokens, h, ok, *extra):
     """Append the fingerprint + finiteness rows to a chunk's token array:
     [n_steps, B] int32 → [n_steps + 2, B] int32, so the whole bundle still
     crosses the host in ONE fetch (row ``n_steps`` = fingerprint bits, row
-    ``n_steps + 1`` = finite flag). ``extra``: one more int32 [B] row a
-    program returns with its tokens (:func:`chunk_extra_row` reads it)."""
+    ``n_steps + 1`` = finite flag). ``extra``: further int32 [B] rows a
+    program returns with its tokens (:func:`chunk_extra_rows` reads them)."""
     fp_row = jax.lax.bitcast_convert_type(h, jnp.int32)[None, :]
     ok_row = ok.astype(jnp.int32)[None, :]
     rows = [tokens.astype(jnp.int32), fp_row, ok_row]
-    if extra is not None:
-        rows.append(extra.astype(jnp.int32)[None, :])
+    rows += [row.astype(jnp.int32)[None, :] for row in extra]
     return jnp.concatenate(rows, axis=0)
 
 
-def chunk_extra_row(arr: np.ndarray, n_steps: int):
-    """The row a program packed past the finite flag, or None."""
-    arr = np.asarray(arr)
-    return arr[n_steps + 2] if arr.shape[0] > n_steps + 2 else None
+def chunk_extra_rows(arr: np.ndarray, n_steps: int) -> np.ndarray:
+    """The rows a program packed past the finite flag, [n, B] (n may be 0)."""
+    return np.asarray(arr)[n_steps + 2:]
 
 
 def split_chunk_outputs(arr: np.ndarray, n_steps: int):

@@ -160,7 +160,8 @@ class SharedPrefixIndex:
 class PageNode:
     """One radix-tree node: a ``page``-token block bound to one pool page."""
 
-    __slots__ = ("key", "page_id", "parent", "children", "refs", "last_use", "snap")
+    __slots__ = ("key", "page_id", "parent", "children", "refs", "last_use", "snap",
+                 "wpage", "w_use")
 
     def __init__(self, key, page_id: int, parent: "PageNode | None"):
         self.key = key  # tuple of the block's token ids (edge label)
@@ -172,6 +173,11 @@ class PageNode:
         # slot of the recurrent-state snapshot taken where this block ENDS
         # (archs with linear-attention layers); None = a row cannot resume here
         self.snap: int | None = None
+        # page of the WINDOW layers' pool holding this block's keys and values
+        # in those layers (archs with window attention), and when a hit last
+        # used it (or its publish attached it); None = aged out or never kept
+        self.wpage: int | None = None
+        self.w_use = 0
 
 
 class PrefixCache:
@@ -180,7 +186,7 @@ class PrefixCache:
     def __init__(
         self, n_pages: int, page: int, page_bytes: int = 0,
         spill=None, page_fetch=None, owner_id: int = 0, shared_index=None,
-        snap_slots: int = 0,
+        snap_slots: int = 0, window_pages: int = 0, window_tail: int = 0,
     ):
         if n_pages < 1:
             raise ValueError(f"need at least one pool page, got {n_pages}")
@@ -210,6 +216,16 @@ class PrefixCache:
         # its snapshot and its publish, or attached to ONE tree node
         self.snap_slots = int(snap_slots)
         self.snap_free: list[int] = list(range(self.snap_slots))
+        # the window layers' pool (archs with window attention; the scheduler
+        # owns the device pool, this is its index): a hit that ends where a
+        # block ends needs those layers' keys and values of the
+        # ``window_tail`` blocks before it and nothing older, so only the last
+        # blocks of a published prompt get a page here, the pages have their
+        # own recency order (:meth:`attach_window_pages`), and the pool's size does
+        # not follow ``n_pages``
+        self.window_pages = int(window_pages)
+        self.window_tail = int(window_tail)
+        self.wfree: list[int] = list(range(self.window_pages))
         self.root = PageNode(None, -1, None)
         self._clock = 0
         # running count of refs>0 nodes, maintained at the 0<->1 ref
@@ -328,6 +344,13 @@ class PrefixCache:
             f"snapshot slot leak: {len(snaps)} attached + {len(self.snap_free)} free + "
             f"{held_snapshots} held != {self.snap_slots}"
         )
+        wpages = [n.wpage for n in seen.values() if n.wpage is not None]
+        assert len(set(wpages)) == len(wpages), f"window page attached twice: {sorted(wpages)}"
+        assert not (set(wpages) & set(self.wfree)), "attached window page on the free list"
+        assert len(wpages) + len(self.wfree) == self.window_pages, (
+            f"window page leak: {len(wpages)} attached + {len(self.wfree)} free "
+            f"!= {self.window_pages}"
+        )
         for ids in row_pages or ():
             for pid in ids:
                 assert pid not in free, (
@@ -377,13 +400,24 @@ class PrefixCache:
         admission. ``resumable``: the row also needs its recurrent state
         where the chain ends, so the chain stops at the deepest matched
         block that has a snapshot and the matched pages past it are
-        dropped (none has one: a miss)."""
+        dropped (none has one: a miss). With a window pool the row needs the
+        window layers' pages of the ``window_tail`` blocks before the chain's
+        end: the chain stops at the deepest block where all of them are
+        still kept (a shorter hit, or a miss; counted by outcome), and those
+        become the most recently used."""
         page = self.page
         chain = self.walk(tokens)
         if resumable:
             deepest = max((i for i, nd in enumerate(chain) if nd.snap is not None), default=-1)
             chain = chain[: deepest + 1]
         t = self._tick()
+        if self.window_tail and chain:
+            walked = len(chain)
+            chain = chain[: self._deepest_with_tail(chain)]
+            for nd in chain[-self.window_tail:]:
+                nd.w_use = t
+            (self.tel.window_tail_hit if len(chain) == walked else
+             self.tel.window_tail_shortened if chain else self.tel.window_tail_miss).inc()
         for nd in chain:
             self._ref(nd)
             nd.last_use = t
@@ -398,6 +432,18 @@ class PrefixCache:
             self.tel.misses.inc()
         self._set_pinned_gauge()
         return chain
+
+    def _deepest_with_tail(self, chain: list[PageNode]) -> int:
+        """The largest ``b <= len(chain)`` such that the ``window_tail`` blocks
+        before block ``b`` (fewer at the row's start) all have their window
+        page; 0 when there is none."""
+        run = 0  # blocks with a window page, counted back from block b
+        best = 0
+        for b, nd in enumerate(chain, start=1):
+            run = run + 1 if nd.wpage is not None else 0
+            if run >= min(self.window_tail, b):
+                best = b
+        return best
 
     def release(self, chain: list[PageNode]) -> None:
         for nd in chain:
@@ -491,6 +537,61 @@ class PrefixCache:
         self.tel.snapshots_published.inc()
         return True
 
+    # ------------------------------------------------------------------
+    # Window pages (archs with window-attention layers)
+    # ------------------------------------------------------------------
+
+    def attach_window_pages(
+        self, tokens, n_total: int, first_block: int
+    ) -> tuple[list[int], list[int]]:
+        """Give the blocks ``first_block ..`` of the published prompt
+        ``tokens[:n_total]`` that are in the tree and have no window page one
+        each. Returns ``(window page ids, block indices)``: the scheduler
+        copies those blocks' window-layer keys and values out of the row's
+        rings. A page comes from the free list, else from the block whose
+        page went longest without a hit using it (that block stays in the
+        tree: a hit can no longer END within ``window_tail`` blocks after it,
+        and is served shorter); never from a block this call has just given
+        one (a pool smaller than one prompt's tail keeps its first blocks)."""
+        chain = self.walk(list(tokens[:n_total]) + [0])  # one token more reaches the last block
+        t = self._tick()
+        wanted = [i for i in range(max(0, first_block), len(chain)) if chain[i].wpage is None]
+        victims: list[PageNode] = []
+        if len(wanted) > len(self.wfree):
+            # one walk of the tree for the whole call, oldest use last (popped first)
+            victims = sorted((nd for nd in self._walk() if nd.wpage is not None and nd.w_use < t),
+                             key=lambda nd: nd.w_use, reverse=True)
+        ids: list[int] = []
+        blocks: list[int] = []
+        for i in range(max(0, first_block), len(chain)):
+            node = chain[i]
+            if node.wpage is None:
+                if self.wfree:
+                    node.wpage = self.wfree.pop()
+                elif victims:
+                    victim = victims.pop()
+                    node.wpage, victim.wpage = victim.wpage, None
+                    self.tel.window_pages_evicted.inc()
+                else:
+                    break
+                ids.append(node.wpage)
+                blocks.append(i)
+            node.w_use = t
+        return ids, blocks
+
+    def detach_window_pages(self, tokens, blocks: list[int]) -> None:
+        """Unwind an :meth:`attach_window_pages` whose device copy failed to
+        dispatch: the pages were never written."""
+        chain = self.walk(list(tokens) + [0])
+        for i in blocks:
+            if i < len(chain):
+                self._drop_window_page(chain[i])
+
+    def _drop_window_page(self, node: PageNode) -> None:
+        if node.wpage is not None:
+            self.wfree.append(node.wpage)
+            node.wpage = None
+
     def _drop_snapshot(self, node: PageNode) -> None:
         if node.snap is not None:
             self.snap_free.append(node.snap)
@@ -523,6 +624,7 @@ class PrefixCache:
             if nd.refs > 0:
                 self._pinned -= 1
             self._drop_snapshot(nd)
+            self._drop_window_page(nd)
             if self.shared_index is not None:
                 # the publish already announced these chains; an unwound
                 # publish must retract them or placement routes to pages
@@ -576,6 +678,7 @@ class PrefixCache:
             self.shared_index.withdraw(self.owner_id, key)
         del victim.parent.children[victim.key]
         self._drop_snapshot(victim)  # snapshot and page go together
+        self._drop_window_page(victim)
         self.free.append(victim.page_id)
         self.tel.evictions.inc()
         self._set_pages_gauges()

@@ -323,8 +323,6 @@ def load_params(
         experts as a list of fused gate|up + down leaves, the shared expert
         as a dense SwiGLU. The router stays float32 at its published width:
         a top 8 of 320 is decided by margins that bf16 weights would move."""
-        from distributed_llama_tpu.ops.q40 import stack_bank
-
         p = f"layers.{l}."
         if cfg.is_softmax_layer(l):
             lp = {"qkvg": fused([p + "q", p + "k", p + "v", p + "gate"])}
@@ -339,8 +337,20 @@ def load_params(
                 "o_norm": f32(p + "o_norm"),
             }
         lp["wo"] = weight(p + "wo")
-        lp["router"] = _t(reader.tensor(p + "moe_router"), np.float32)
-        lp["router_bias"] = f32(p + "router_bias")
+        lp.update(held_experts(p))
+        lp["rms_att"] = f32(p + "rms_att")
+        lp["rms_ffn"] = f32(p + "rms_ffn")
+        return lp
+
+    def held_experts(p: str) -> dict:
+        """The expert half of a layer that holds a share: the router (float32
+        at its published width) and its selection bias, the held experts as
+        one bank of fused gate|up and one of down, the shared expert as a
+        dense SwiGLU."""
+        from distributed_llama_tpu.ops.q40 import stack_bank
+
+        lp = {"router": _t(reader.tensor(p + "moe_router"), np.float32),
+              "router_bias": f32(p + "router_bias")}
         held = [f"{p}experts.{e}." for e in range(cfg.n_experts)]
         bank = stack_bank if quantized else np.stack
         lp["experts_gate_up"] = bank([fused([ep + "gate", ep + "up"]) for ep in held])
@@ -348,18 +358,33 @@ def load_params(
         if cfg.n_shared_experts:
             lp["shared_gate_up"] = fused([p + "shared.gate", p + "shared.up"])
             lp["shared_down"] = weight(p + "shared.down")
-        lp["rms_att"] = f32(p + "rms_att")
-        lp["rms_ffn"] = f32(p + "rms_ffn")
         return lp
 
-    if cfg.arch == ArchType.SOLAR_OPEN2:
+    def window_layer(l: int) -> dict:
+        """One ``ArchType.EXAONE_MOE`` layer: q|k|v as one matrix and the
+        heads' norm weights, whatever the layer's attention kind (the kind
+        decides its cache leaf and its mask, not its tensors); a dense SwiGLU
+        in the leading layers, the held experts after them."""
+        p = f"layers.{l}."
+        lp = {"qkv": fused([p + "q", p + "k", p + "v"]), "q_norm": f32(p + "q_norm"),
+              "k_norm": f32(p + "k_norm"), "wo": weight(p + "wo"),
+              "rms_att": f32(p + "rms_att"), "rms_ffn": f32(p + "rms_ffn")}
+        if cfg.layer_kind(l)[1] == "dense":
+            lp["gate_up"] = fused([p + "gate", p + "up"])
+            lp["down"] = weight(p + "down")
+        else:
+            lp.update(held_experts(p))
+        return lp
+
+    if cfg.arch in (ArchType.SOLAR_OPEN2, ArchType.EXAONE_MOE):
         from distributed_llama_tpu.models.llama import refuse_recurrent
 
         if tp > 1:
             refuse_recurrent(cfg, f"tensor parallelism (--tp {tp})")
+        layer = hybrid_layer if cfg.arch == ArchType.SOLAR_OPEN2 else window_layer
         return {
             "embedding": reader.tensor("embedding").astype(np.float32),
-            "layers": [hybrid_layer(l) for l in range(cfg.n_layers)],
+            "layers": [layer(l) for l in range(cfg.n_layers)],
             "rms_final": reader.tensor("rms_final").astype(np.float32),
             "wcls": weight("wcls"),
             "rope_table": build_rope_table(cfg),

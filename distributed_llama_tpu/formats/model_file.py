@@ -38,6 +38,19 @@ carry the header keys from ``HEAD_SIZE`` up, which no other arch writes:
     shared.up, shared.gate, shared.down (width n_shared * moe_hidden),
     rms_att, rms_ffn
 
+``ArchType.EXAONE_MOE`` (no reference counterpart; :func:`_window_layer`)
+mixes window and full attention (layer l is FULL where ``l % window_period ==
+window_period - 1``), normalises every q and k head, leads with
+``first_dense`` dense layers and holds a share of the routed experts after
+them; its files carry the keys of ``_ARCH_KEYS[ArchType.EXAONE_MOE]``:
+
+  every layer: rms_att, rms_ffn, q [H*hd, dim], k, v [K*hd, dim],
+    q_norm (F32) [hd], k_norm (F32) [hd], wo [dim, H*hd]
+  dense layer (l < first_dense): gate, down, up (width hidden_dim)
+  expert layer: moe_router [n_routed, dim], router_bias (F32) [n_routed],
+    per HELD expert: up, gate [moe_hidden, dim], down [dim, moe_hidden],
+    shared.up, shared.gate, shared.down (width n_shared * moe_hidden)
+
 All matrices are row-major [d_out, d_in] — a matmul computes y = W @ x.
 Q/K projections are stored pre-permuted for interleaved-pair rope
 (reference: converter/convert-hf.py:12-15).
@@ -67,6 +80,9 @@ class ArchType(enum.IntEnum):
     # softmax and gated delta-rule layers in one model, a share of the
     # routed experts and a shared one; not a reference arch
     SOLAR_OPEN2 = 0xABCD03
+    # window and full attention layers in one model, q/k norm, leading dense
+    # layers, a share of the routed experts and a shared one; not a reference arch
+    EXAONE_MOE = 0xABCD04
 
 
 class HiddenAct(enum.IntEnum):
@@ -107,7 +123,7 @@ class HeaderKey(enum.IntEnum):
     ROPE_SCALING_HIGH_FREQ_FACTORY = 16
     ROPE_SCALING_ORIG_MAX_SEQ_LEN = 17
     ROPE_TYPE = 18
-    # from here on: written by ArchType.SOLAR_OPEN2 files only
+    # from here on: written only by the archs of _ARCH_KEYS, each its own keys
     HEAD_SIZE = 19  # a head size that is not dim / n_heads
     MOE_HIDDEN_DIM = 20  # width of one expert
     N_SHARED_EXPERTS = 21
@@ -119,6 +135,10 @@ class HeaderKey(enum.IntEnum):
     LIN_CONV = 27  # taps of the causal depthwise convolution
     LIN_RANK = 28  # rank of the decay's and the output gate's low-rank pairs
     FLAGS = 29  # ArchFlags bits
+    WINDOW = 30  # positions a window layer's query sees, itself included
+    WINDOW_PERIOD = 31  # layer l is a FULL layer where l % period == period - 1, else window
+    FIRST_DENSE = 32  # leading layers whose FFN is dense (hidden_dim wide); experts after them
+    ROUTED_SCALE_MILLI = 33  # the chosen experts' weights are multiplied by this / 1000
 
 
 class ArchFlags(enum.IntFlag):
@@ -129,6 +149,8 @@ class ArchFlags(enum.IntFlag):
     NEG_EIGVAL = 4  # beta = 2 * sigmoid(.), so a state transition may reflect
     NORM_TOPK = 8  # the chosen experts' weights are renormalised to sum to one
     SIGMOID_ROUTER = 16  # router score = sigmoid, chosen with a selection bias
+    QK_NORM = 32  # every q and k head is RMS-normalised with a learned weight
+    ROPE_WINDOW_ONLY = 64  # of the layers, only the window layers rotate q and k
 
 
 _EXTRA_KEYS = {
@@ -144,6 +166,21 @@ _EXTRA_KEYS = {
     HeaderKey.LIN_RANK: "lin_rank",
     HeaderKey.FLAGS: "flags",
 }
+_WINDOW_KEYS = {
+    HeaderKey.HEAD_SIZE: "head_dim",
+    HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim",
+    HeaderKey.N_SHARED_EXPERTS: "n_shared_experts",
+    HeaderKey.N_ROUTED_EXPERTS: "n_routed_experts",
+    HeaderKey.FIRST_EXPERT: "first_expert",
+    HeaderKey.FLAGS: "flags",
+    HeaderKey.WINDOW: "window",
+    HeaderKey.WINDOW_PERIOD: "window_period",
+    HeaderKey.FIRST_DENSE: "first_dense",
+    HeaderKey.ROUTED_SCALE_MILLI: "routed_scale_milli",
+}
+# the keys past ROPE_TYPE an arch's files carry, in the order they are written;
+# an arch that is not here writes none of them
+_ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KEYS}
 
 
 @dataclasses.dataclass
@@ -185,6 +222,10 @@ class ModelSpec:
     lin_conv: int = 0
     lin_rank: int = 0
     flags: int = 0
+    window: int = 0
+    window_period: int = 0
+    first_dense: int = 0
+    routed_scale_milli: int = 0
 
     @property
     def head_size(self) -> int:
@@ -241,8 +282,7 @@ def _header_pairs(spec: ModelSpec) -> list[tuple[int, int]]:
             (HeaderKey.ROPE_SCALING_HIGH_FREQ_FACTORY, int(spec.rope_scaling_high_freq_factor)),
             (HeaderKey.ROPE_SCALING_ORIG_MAX_SEQ_LEN, spec.rope_scaling_orig_max_seq_len),
         ]
-    if spec.arch_type == ArchType.SOLAR_OPEN2:
-        pairs += [(key, getattr(spec, name)) for key, name in _EXTRA_KEYS.items()]
+    pairs += [(key, getattr(spec, name)) for key, name in _ARCH_KEYS.get(spec.arch_type, {}).items()]
     return pairs
 
 
@@ -319,6 +359,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
                 HeaderKey.ROPE_SCALING_ORIG_MAX_SEQ_LEN: "rope_scaling_orig_max_seq_len",
                 HeaderKey.ROPE_TYPE: "rope_type",
                 **_EXTRA_KEYS,
+                **_WINDOW_KEYS,
             }
             for i in range(0, n_ints, 2):
                 key, value = raw[i], raw[i + 1]
@@ -375,6 +416,9 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
         if spec.arch_type == ArchType.SOLAR_OPEN2:
             _hybrid_layer(spec, l, add)
             continue
+        if spec.arch_type == ArchType.EXAONE_MOE:
+            _window_layer(spec, l, add)
+            continue
         add(p + "q", (dim, dim), wt)
         add(p + "k", (kv_dim, dim), wt)
         add(p + "v", (kv_dim, dim), wt)
@@ -400,10 +444,26 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
     return entries
 
 
+def layer_kind(spec, l: int) -> tuple[str, str]:
+    """What layer ``l`` is (``spec``: a ModelSpec or a LlamaConfig), the ONE
+    table of layer kinds: how it mixes positions (``full``: softmax attention
+    over every earlier position; ``window``: over the last ``window``;
+    ``linear``: a gated delta-rule recurrence) and what its feed-forward is
+    (``dense`` or ``experts``). An arch without a period has full layers
+    only; one with experts has them in every layer past ``first_dense``."""
+    if spec.attn_period:
+        mixer = "full" if l % spec.attn_period == 0 else "linear"
+    elif spec.window_period:
+        mixer = "full" if l % spec.window_period == spec.window_period - 1 else "window"
+    else:
+        mixer = "full"
+    return mixer, "experts" if spec.n_experts > 0 and l >= spec.first_dense else "dense"
+
+
 def is_softmax_layer(spec, l: int) -> bool:
-    """Whether layer ``l`` mixes by softmax attention (``spec``: a ModelSpec
-    or a LlamaConfig). Every layer of an arch without a period does."""
-    return not spec.attn_period or l % spec.attn_period == 0
+    """Whether layer ``l`` mixes by softmax attention (full or window), and so
+    keeps keys and values."""
+    return layer_kind(spec, l)[0] != "linear"
 
 
 def _hybrid_layer(spec: ModelSpec, l: int, add) -> None:
@@ -432,9 +492,17 @@ def _hybrid_layer(spec: ModelSpec, l: int, add) -> None:
         add(p + "g_up", (lin, spec.lin_rank), wt)
         add(p + "o_norm", (spec.lin_head_dim,), f32)
         add(p + "wo", (dim, lin), wt)
+    _held_experts(spec, p, add)
+    add(p + "rms_att", (dim,), f32)
+    add(p + "rms_ffn", (dim,), f32)
+
+
+def _held_experts(spec: ModelSpec, p: str, add) -> None:
+    """Router, selection bias, the HELD experts and the shared one of an
+    expert layer that holds a share (the module docstring's lists)."""
+    wt, dim, width = spec.weights_float_type, spec.dim, spec.moe_hidden_dim
     add(p + "moe_router", (spec.n_routed_experts, dim), wt)
-    add(p + "router_bias", (spec.n_routed_experts,), f32)
-    width = spec.moe_hidden_dim
+    add(p + "router_bias", (spec.n_routed_experts,), FloatType.F32)
     for e in range(spec.n_experts):
         ep = f"{p}experts.{e}."
         add(ep + "up", (width, dim), wt)
@@ -445,8 +513,28 @@ def _hybrid_layer(spec: ModelSpec, l: int, add) -> None:
         add(p + "shared.up", (shared, dim), wt)
         add(p + "shared.gate", (shared, dim), wt)
         add(p + "shared.down", (dim, shared), wt)
+
+
+def _window_layer(spec: ModelSpec, l: int, add) -> None:
+    """One ``ArchType.EXAONE_MOE`` layer's tensors (the module docstring's
+    list). Window and full layers hold the same tensors."""
+    wt, f32, dim, hidden = spec.weights_float_type, FloatType.F32, spec.dim, spec.hidden_dim
+    p = f"layers.{l}."
+    q_dim = spec.n_heads * spec.head_size
     add(p + "rms_att", (dim,), f32)
     add(p + "rms_ffn", (dim,), f32)
+    add(p + "q", (q_dim, dim), wt)
+    add(p + "k", (spec.kv_dim, dim), wt)
+    add(p + "v", (spec.kv_dim, dim), wt)
+    add(p + "q_norm", (spec.head_size,), f32)
+    add(p + "k_norm", (spec.head_size,), f32)
+    add(p + "wo", (dim, q_dim), wt)
+    if layer_kind(spec, l)[1] == "dense":
+        add(p + "gate", (hidden, dim), wt)
+        add(p + "down", (dim, hidden), wt)
+        add(p + "up", (hidden, dim), wt)
+    else:
+        _held_experts(spec, p, add)
 
 
 class ModelFileReader:

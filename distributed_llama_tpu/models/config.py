@@ -14,8 +14,15 @@ from distributed_llama_tpu.formats.model_file import (
     HiddenAct,
     ModelSpec,
     RopeType,
-    is_softmax_layer,
+    layer_kind,
 )
+
+# what a window layer's ring holds beyond its window: the largest piece of a
+# prompt written at once (the scheduler's prefill chunk and its bucket), and
+# the positions a publish reads back out of the ring after the last piece (the
+# pages before a prompt's end that a later prompt's prefix hit can end on)
+RING_PIECE = 256
+RING_TAIL = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +74,17 @@ class LlamaConfig:
     n_routed_experts: int = 0
     first_expert: int = 0
     flags: int = 0
+    # window attention beside full attention (ArchType.EXAONE_MOE; 0 elsewhere):
+    # layer l is a FULL layer where l % window_period == window_period - 1, else
+    # its query sees the last ``window`` positions and its cache is a ring of
+    # ``ring_len`` slots (position p at slot p % ring_len); the first
+    # ``first_dense`` layers have a dense FFN hidden_dim wide, the expert layers
+    # after them multiply the chosen experts' weights by ``routed_scale``
+    window: int = 0
+    window_period: int = 0
+    ring_len: int = 0
+    first_dense: int = 0
+    routed_scale: float = 1.0
 
     @property
     def kv_mul(self) -> int:
@@ -81,16 +99,50 @@ class LlamaConfig:
         """Whether some layer keeps a state that is not addressed by position."""
         return self.attn_period > 1
 
+    def layer_kind(self, l: int) -> tuple[str, str]:
+        """(``full`` | ``window`` | ``linear``, ``dense`` | ``experts``): how
+        layer ``l`` mixes positions and what its feed-forward is
+        (``formats.model_file.layer_kind``, the one table)."""
+        return layer_kind(self, l)
+
+    def layers_of(self, mixer: str) -> tuple[int, ...]:
+        return tuple(l for l in range(self.n_layers) if self.layer_kind(l)[0] == mixer)
+
     def is_softmax_layer(self, l: int) -> bool:
-        return is_softmax_layer(self, l)
+        return self.layer_kind(l)[0] != "linear"
+
+    def is_window_layer(self, l: int | None) -> bool:
+        """Whether layer ``l`` is a window layer; a caller that does not say
+        which layer it runs (None) runs a full one."""
+        return l is not None and self.layer_kind(l)[0] == "window"
 
     @property
-    def softmax_layers(self) -> tuple[int, ...]:
-        return tuple(l for l in range(self.n_layers) if self.is_softmax_layer(l))
+    def has_window(self) -> bool:
+        """Whether some layer keeps only the last ``window`` positions."""
+        return self.window_period > 0
+
+    @property
+    def ring_piece(self) -> int:
+        """The most tokens of one row a single dispatch may write into a ring:
+        the largest power of two (a prompt piece is padded to one) that fits
+        the ring beside the window its first query sees."""
+        return 1 << (self.ring_len - self.window + 1).bit_length() - 1
+
+    @property
+    def rewinds_by_position(self) -> bool:
+        """Whether a row can be moved back to any earlier position: not where
+        some layer keeps a recurrent state or a ring instead of every position."""
+        return not (self.is_recurrent or self.has_window)
 
     @property
     def use_rope(self) -> bool:
         return self.arch != ArchType.SOLAR_OPEN2 or self.has(ArchFlags.USE_ROPE)
+
+    def rotates(self, l: int) -> bool:
+        """Whether layer ``l`` rotates its q and k."""
+        return self.use_rope and (
+            not self.has(ArchFlags.ROPE_WINDOW_ONLY) or self.is_window_layer(l)
+        )
 
     @property
     def router_sigmoid(self) -> bool:
@@ -101,7 +153,9 @@ class LlamaConfig:
     def norm_topk(self) -> bool:
         """Whether the chosen experts' weights are renormalised to sum to one
         (always, for the archs that have no flag to say otherwise)."""
-        return self.arch != ArchType.SOLAR_OPEN2 or self.has(ArchFlags.NORM_TOPK)
+        return self.arch not in (ArchType.SOLAR_OPEN2, ArchType.EXAONE_MOE) or self.has(
+            ArchFlags.NORM_TOPK
+        )
 
     def has(self, flag: ArchFlags) -> bool:
         return bool(self.flags & flag)
@@ -111,7 +165,17 @@ class LlamaConfig:
         return self.n_routed_experts or self.n_experts
 
 
+def next_pow2(n: int) -> int:
+    """The smallest power of two that is at least ``n`` (1 for n <= 1)."""
+    return 1 << max(0, n - 1).bit_length()
+
+
 def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
+    if spec.window_period:
+        # a ring never needs more slots than the row has positions
+        overrides.setdefault(
+            "ring_len", min(next_pow2(spec.window + RING_PIECE + RING_TAIL), spec.seq_len)
+        )
     return LlamaConfig(
         arch=spec.arch_type,
         dim=spec.dim,
@@ -142,5 +206,9 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         n_routed_experts=spec.n_routed_experts,
         first_expert=spec.first_expert,
         flags=spec.flags,
+        window=spec.window,
+        window_period=spec.window_period,
+        first_dense=spec.first_dense,
+        routed_scale=spec.routed_scale_milli / 1000.0 if spec.routed_scale_milli else 1.0,
         **overrides,
     )

@@ -113,12 +113,19 @@ def project_qkv(
     lp: Params,
     x: jax.Array,
     rope_rows: jax.Array,
+    layer: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Norm + QKV projection + rope for T tokens: [T, dim] ->
     (q [T, Hl, hd], k [T, Kl, hd], v [T, Kl, hd]). Shared by the dense,
     tensor-parallel and sequence-parallel attention paths (the reference's
     llamaRmsAtt/llamaQkv/llamaRope chain, src/llama2-tasks.cpp:10-52)."""
-    return project_qkvg(cfg, lp, x, rope_rows)[:3]
+    return project_qkvg(cfg, lp, x, rope_rows, layer)[:3]
+
+
+def _head_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """RMS norm of every head [T, H, hd] over its hd values, times the
+    layer's learned weight [hd], in f32."""
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * weight
 
 
 def project_qkvg(
@@ -126,11 +133,14 @@ def project_qkvg(
     lp: Params,
     x: jax.Array,
     rope_rows: jax.Array,
+    layer: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None]:
     """:func:`project_qkv` and, where the layer gates its attention output
     per channel (``qkvg``: q|k|v|gate as one matrix), the gate's
-    pre-activation [T, Hl*hd], else None. An arch without rope gets q and k
-    as projected."""
+    pre-activation [T, Hl*hd], else None. Where the layer normalises its
+    heads (``q_norm``/``k_norm`` in its params) that comes before the
+    rotation. ``layer``: the layer's index, for an arch whose layers do not
+    all rotate (``cfg.rotates``); None = what the arch says of every layer."""
     T = x.shape[0]
     hd = cfg.head_size
     gate = None
@@ -163,7 +173,9 @@ def project_qkvg(
     Hl = q.shape[-1] // hd
     Kl = k.shape[-1] // hd
     q, k = q.reshape(T, Hl, hd), k.reshape(T, Kl, hd)
-    if cfg.use_rope:
+    if "q_norm" in lp:
+        q, k = _head_norm(q, lp["q_norm"]), _head_norm(k, lp["k_norm"])
+    if cfg.use_rope if layer is None else cfg.rotates(layer):
         q, k = apply_rope(q, rope_rows, cfg), apply_rope(k, rope_rows, cfg)
     return q, k, v.reshape(T, Kl, hd), gate
 
@@ -211,7 +223,7 @@ def block_tail(
         x = x + rmsnorm(out.astype(x.dtype), lp["rms_ffn"])
     else:
         x = x + out.astype(x.dtype)
-    if cfg.is_moe:
+    if cfg.is_moe and "router" in lp:  # a leading dense layer of an expert arch has none
         from distributed_llama_tpu.models import moe
 
         x = moe.moe_block(cfg, x, lp, axis_name, ep_axis=ep_axis, n_real=n_real)
@@ -248,6 +260,7 @@ def attention(
     rope_rows: jax.Array,
     axis_name: str | None,
     paged=None,
+    layer: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Causal GQA attention for T new tokens at absolute positions
     pos..pos+T-1. ``cache_l``: this layer's cache — a ``(keys, values)``
@@ -262,6 +275,11 @@ def attention(
     read a virtual row view (``kv_cache.virtual_row``) through the SAME
     einsum path, so both are bit-identical to a row holding page copies.
 
+    ``layer``: the layer's index (``cfg.layer_kind``). A WINDOW layer's
+    ``cache_l`` is a ring [2, R, Kl, hd]: the tokens' K/V go to their
+    positions' slots and each query sees the ``cfg.window`` positions up to
+    its own (``ops.attention.window_attention``); it reads no pool.
+
     Mirrors llamaQkv/llamaRope/llamaMultiheadAtt/llamaAtt
     (reference: src/llama2-tasks.cpp:33-108) with the per-timestep score loop
     replaced by one masked einsum over the whole cache.
@@ -271,8 +289,18 @@ def attention(
     T = x.shape[0]
     S = cache_l[0].shape[0]  # works for tuple (keys, values) and stacked [2, S, ...] forms
     hd = cfg.head_size
-    q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows)
+    q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows, layer)
     Hl, Kl = q.shape[1], k.shape[1]
+
+    if cfg.is_window_layer(layer):
+        from distributed_llama_tpu.ops.attention import window_attention
+
+        if not kvc.is_fused_leaf(cache_l):
+            raise ValueError("a window layer's ring is a fused leaf (the layered cache)")
+        new_cache = kvc.ring_update_rows(cache_l, k, v, pos)
+        qg = q.reshape(T, Kl, Hl // Kl, hd).astype(jnp.float32)
+        att = window_attention(qg, new_cache, pos, cfg.window).astype(jnp.float32)
+        return _gated(att.reshape(T, Hl * hd), gate), new_cache
 
     if kvc.is_fused_leaf(cache_l):
         # fused [2, S, Kl, hd] leaf: keys AND values land in ONE coalesced
@@ -349,11 +377,27 @@ class RecurrentStateError(RuntimeError):
     than go on with a stale state."""
 
 
+class WindowRingError(RuntimeError):
+    """A path that moves or rewinds a row BY POSITION met an arch whose
+    window layers keep a ring of their last positions (``cfg.has_window``):
+    what a rewind would need has been overwritten, and a page of the pool
+    holds no window layer's keys. The same paths refuse as for a recurrent
+    state, by this name."""
+
+
 def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
+    """Refuse ``what`` for an arch that cannot move a row back by position
+    (``not cfg.rewinds_by_position``), by the name of what it keeps instead
+    of every position."""
     if cfg.is_recurrent:
         raise RecurrentStateError(
             f"{what} is not supported for arch {cfg.arch.name}: its linear-attention "
             "layers keep a recurrent state that cannot be rewound or moved by position"
+        )
+    if cfg.has_window:
+        raise WindowRingError(
+            f"{what} is not supported for arch {cfg.arch.name}: its window-attention "
+            f"layers keep a ring of {cfg.ring_len} positions, not every position of a row"
         )
 
 
@@ -478,12 +522,13 @@ def block_forward(
     ep_axis: str | None = None,
     n_real: jax.Array | None = None,
     paged=None,
+    layer: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     if "lin_in" in lp:
         att, new_cache = linear_attention(cfg, x, lp, cache_l, pos, n_real)
     else:
         att, new_cache = attention(
-            cfg, x, lp, cache_l, pos, rope_rows, axis_name, paged=paged
+            cfg, x, lp, cache_l, pos, rope_rows, axis_name, paged=paged, layer=layer
         )
     return (
         block_tail(cfg, x, att, lp, axis_name, ep_axis=ep_axis, n_real=n_real),
@@ -552,13 +597,15 @@ def _forward_tokens(cfg, params, tokens, cache, pos, axis_name, ep_axis, n_real,
                 paged_l = (pool[l][0], pool[l][1], table, matched)
             x, nc = block_forward(
                 cfg, x, lp, cache[l], pos, rope_rows, axis_name, ep_axis=ep_axis,
-                n_real=n_real, paged=paged_l,
+                n_real=n_real, paged=paged_l, layer=l,
             )
             new_layers.append(nc)
         new_cache = type(cache)(new_layers) if cache_is_list else jnp.stack(new_layers)
     else:
         if paged is not None:
             raise ValueError("the paged (pool-aliased) read requires the layered cache")
+        if cfg.has_window:
+            raise ValueError("layers of two kinds need the layered cache layout")
 
         def body(carry, scanned):
             xc = carry
@@ -583,6 +630,7 @@ def attention_batched(
     rope_rows: jax.Array,  # [B, hd/2, 2] per-row rope table rows
     active: jax.Array,  # [B] bool — False rows decode garbage, write nothing
     paged=None,  # (pool_k, pool_v, tables [B, n_table], matched [B])
+    layer: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One decode step of B INDEPENDENT sequences over a slab cache with a
     leading batch axis: row ``b`` writes its K/V at its own ``pos[b]`` and
@@ -594,14 +642,28 @@ def attention_batched(
     are garbage the scheduler discards. ``paged``: row ``b``'s positions
     below ``matched[b]`` are read from the shared page pool through its
     page table (zero-copy prefix aliasing) — bit-identical to a row holding
-    copies of the pages."""
+    copies of the pages. A WINDOW layer (``layer``, ``cfg.layer_kind``) keeps
+    a ring [2, B, R, Kl, hd]: row ``b`` writes at slot ``pos[b] % R`` and
+    reads its own last ``cfg.window`` positions, no pool and no chunk that
+    the mask would hide."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     B = x.shape[0]
     S, cdt, prec = kvc.slab_facts(cache_l)
     hd = cfg.head_size
-    q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows)  # [B, Hl, hd], [B, Kl, hd] x2
+    q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows, layer)  # [B, Hl, hd], [B, Kl, hd] x2
     Hl, Kl = q.shape[1], k.shape[1]
+
+    if cfg.is_window_layer(layer):
+        from distributed_llama_tpu.ops.attention import batched_window_attention
+
+        # S is the ring's length here: an inactive row writes at the dropped slot
+        new_cache = kvc.fused_update_row_batched(cache_l, k, v, jnp.where(active, pos % S, S))
+        qg = q.reshape(B, Kl, Hl // Kl, hd).astype(jnp.float32)
+        att = batched_window_attention(
+            qg, new_cache, jnp.where(active, pos, 0), cfg.window
+        ).astype(jnp.float32)
+        return _gated(att.reshape(B, Hl * hd), gate), new_cache
 
     write_slot = jnp.where(active & (pos < S), pos, S)  # S = dropped
     if kvc.is_fused_leaf(cache_l):
@@ -651,6 +713,9 @@ def attention_batched(
                 qg.astype(jnp.float32), (keys_b, values_b), read_pos, ATT_CHUNK
             ).astype(jnp.float32)
             return _gated(att.reshape(B, Hl * hd), gate), new_cache
+    from distributed_llama_tpu.ops.attention import note_kv_read
+
+    note_kv_read("full", B, S)  # a small or odd cache is read whole
     scores = kvc.scores_einsum_batched(qg, keys_b, prec) / jnp.sqrt(jnp.float32(hd))
     mask = jnp.arange(S)[None, :] <= read_pos[:, None]  # [B, S]
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
@@ -669,6 +734,7 @@ def forward_step_batched(
     axis_name: str | None = None,
     paged=None,  # (pool, tables, matched) — zero-copy prefix aliasing
     held_counts: list | None = None,  # receives int32 [B], as in forward_tokens
+    kv_reads: dict | None = None,  # receives {kind: int32 [B]}: cache positions read
 ) -> tuple[jax.Array, jax.Array]:
     """One batched decode step: B tokens (one per sequence) at per-row
     positions through the whole model, reading each weight matrix ONCE.
@@ -685,11 +751,16 @@ def forward_step_batched(
     experts in bank order, the switch in top-k order); the BIT-parity
     contract of the batched path is exact for dense models only."""
     from distributed_llama_tpu.models import moe
+    from distributed_llama_tpu.ops.attention import collect_kv_reads
 
-    with moe.collect_held(held_counts is not None) as per_layer:
+    with moe.collect_held(held_counts is not None) as per_layer, \
+            collect_kv_reads(kv_reads is not None) as reads:
         out = _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, paged)
     if per_layer:
         held_counts.append(sum(per_layer))
+    for kind, positions in reads or ():
+        # per row, over the step's layers of that kind
+        kv_reads[kind] = kv_reads.get(kind, 0) + positions
     return out
 
 
@@ -711,7 +782,7 @@ def _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, pa
             att, nc = linear_attention_batched(cfg, x, lp, cache[l], active)
         else:
             att, nc = attention_batched(
-                cfg, x, lp, cache[l], pos, rope_rows, active, paged=paged_l
+                cfg, x, lp, cache[l], pos, rope_rows, active, paged=paged_l, layer=l
             )
         x = block_tail(cfg, x, att, lp, axis_name)
         new_layers.append(nc)
@@ -863,11 +934,31 @@ def init_batch_cache(
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
-    shape = (b_max, cfg.seq_len, kl, cfg.head_size)
-    return [
-        kvc.init_fused(shape, dtype) if cfg.is_softmax_layer(l) else init_state_leaf(cfg, (b_max,))
-        for l in range(cfg.n_layers)
-    ]
+    return [_init_layer_leaf(cfg, l, (b_max,), kl, dtype) for l in range(cfg.n_layers)]
+
+
+def _init_layer_leaf(cfg: LlamaConfig, l: int, lead: tuple[int, ...], kl: int, dtype):
+    """Layer ``l``'s cache leaf by its kind: every position of a row for a
+    full layer, a ring of ``cfg.ring_len`` slots for a window layer (it does
+    not grow with ``seq_len``), a state for a linear one."""
+    from distributed_llama_tpu.ops import kv_cache as kvc
+
+    mixer = cfg.layer_kind(l)[0]
+    if mixer == "linear":
+        return init_state_leaf(cfg, lead)
+    slots = cfg.ring_len if mixer == "window" else cfg.seq_len
+    return kvc.init_fused(lead + (slots, kl, cfg.head_size), dtype)
+
+
+def kv_slab_bytes(cfg: LlamaConfig, rows: int, dtype) -> dict[str, int]:
+    """Bytes of keys and values ``rows`` slab rows hold, by layer kind
+    (``full``, ``window``): a full layer's grow with ``seq_len``, a window
+    layer's are its ring's."""
+    per_slot = page_pool_bytes(cfg, 1, dtype, layers=1)
+    return {
+        "full": rows * cfg.seq_len * per_slot * len(cfg.layers_of("full")),
+        "window": rows * cfg.ring_len * per_slot * len(cfg.layers_of("window")),
+    }
 
 
 def init_state_leaf(cfg: LlamaConfig, lead: tuple[int, ...] = ()) -> dict:
@@ -885,7 +976,7 @@ def recurrent_state_bytes(cfg: LlamaConfig, rows: int) -> int:
     """Bytes of recurrent state and convolution tails ``rows`` rows hold."""
     Hl, dl = cfg.lin_heads, cfg.lin_head_dim
     per_layer = 4 * (Hl * dl * dl + (cfg.lin_conv - 1) * 3 * Hl * dl)
-    return rows * per_layer * (cfg.n_layers - len(cfg.softmax_layers))
+    return rows * per_layer * len(cfg.layers_of("linear"))
 
 
 def init_page_pool(
@@ -905,21 +996,40 @@ def init_page_pool(
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
-    # a linear layer has no keys and values: its entry is None
+    # the pool holds the FULL layers' pages: a linear layer has no keys and
+    # values, a window layer's are in its own small pool
+    # (:func:`init_window_pool`); their entries are None
+    return _init_pool(cfg, "full", n_pages, page, kl, dtype)
+
+
+def _init_pool(cfg: LlamaConfig, mixer: str, n_pages: int, page: int, kl: int, dtype) -> list:
+    from distributed_llama_tpu.ops import kv_cache as kvc
+
     return [
         (
             kvc.init_page_pool_half(n_pages, page, kl, cfg.head_size, dtype),
             kvc.init_page_pool_half(n_pages, page, kl, cfg.head_size, dtype),
         )
-        if cfg.is_softmax_layer(l) else None
+        if cfg.layer_kind(l)[0] == mixer else None
         for l in range(cfg.n_layers)
     ]
 
 
-def page_pool_bytes(cfg: LlamaConfig, page: int, dtype) -> int:
-    """Logical KV bytes one pool page holds across all layers and both
-    halves (the telemetry/bench accounting unit for pool occupancy and the
-    copy traffic zero-copy aliasing avoids)."""
+def init_window_pool(cfg: LlamaConfig, n_pages: int, page: int, dtype=jnp.float32) -> list:
+    """The window layers' page pool: ``(keys, values)`` halves of [n_pages,
+    page, K, hd] for a window layer, None for every other. A prefix hit that
+    ends at a page boundary needs these layers' keys and values of the
+    ``cfg.window`` positions before it and nothing older, so this pool holds
+    the last pages of the prompts published lately (``engine.prefix_cache``:
+    its own recency order) and does not grow with ``--kv-pages``."""
+    return _init_pool(cfg, "window", n_pages, page, cfg.n_kv_heads, dtype)
+
+
+def page_pool_bytes(cfg: LlamaConfig, page: int, dtype, layers: int | None = None) -> int:
+    """Logical KV bytes one pool page holds across the pool's layers (the
+    full ones; ``layers``: of that many instead) and both halves (the
+    telemetry/bench accounting unit for pool occupancy and the copy traffic
+    zero-copy aliasing avoids)."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     kl, hd = cfg.n_kv_heads, cfg.head_size
@@ -927,7 +1037,7 @@ def page_pool_bytes(cfg: LlamaConfig, page: int, dtype) -> int:
         per_half = page * kl * hd + page * kl * 4  # int8 data + f32 scales
     else:
         per_half = page * kl * hd * jnp.dtype(dtype).itemsize
-    return 2 * len(cfg.softmax_layers) * per_half
+    return 2 * (len(cfg.layers_of("full")) if layers is None else layers) * per_half
 
 
 def init_cache(
@@ -955,10 +1065,7 @@ def init_cache(
     if kvc.is_quantized_cache_dtype(dtype) and not layered:
         raise ValueError("the i8 KV cache requires the layered cache layout")
     if layered:
-        return [
-            kvc.init_fused(shape, dtype) if cfg.is_softmax_layer(l) else init_state_leaf(cfg)
-            for l in range(cfg.n_layers)
-        ]
-    if cfg.is_recurrent:
+        return [_init_layer_leaf(cfg, l, (), kl, dtype) for l in range(cfg.n_layers)]
+    if cfg.is_recurrent or cfg.has_window:
         raise ValueError("layers of two kinds need the layered cache layout")
     return jnp.zeros((cfg.n_layers, 2) + shape, dtype=dtype)

@@ -53,8 +53,9 @@ def router_topk(
 ) -> tuple[jax.Array, jax.Array]:
     """Top-k routing: ([T, k] weights, [T, k] expert ids) — the single home
     of the select-then-renormalize convention (reference:
-    src/grok1-tasks.cpp:62-114). The score function and whether the chosen
-    weights are renormalised are the config's; ``bias`` [E] is added to the
+    src/grok1-tasks.cpp:62-114). The score function, whether the chosen
+    weights are renormalised and the factor they are multiplied by after
+    that (``routed_scale``) are the config's; ``bias`` [E] is added to the
     scores for CHOOSING only, the weights are the scores themselves."""
     probs = router_probs(cfg, xn, router)
     if bias is None:
@@ -64,6 +65,8 @@ def router_topk(
         top_vals = jnp.take_along_axis(probs, top_idx, axis=-1)
     if cfg.norm_topk:
         top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    if cfg.routed_scale != 1.0:
+        top_vals = top_vals * cfg.routed_scale
     return top_vals, top_idx
 
 
@@ -288,13 +291,22 @@ def collect_held(enabled: bool = True):
         _held_counts = before
 
 
-# rows a held expert's bucket holds, by the rows of the step: a token chooses a
-# given held expert with probability k / routed (1/40 at 8 of 320), so a
-# 256-token prefill chunk gives an expert 6.4 rows (s.d. 2.5) and a 32-row
-# decode step 0.8. A step in which some expert overflows its bucket takes the
-# every-row path instead (exact either way).
-def held_bucket_rows(rows: int) -> int:
-    return 8 if rows <= 64 else 32
+def held_bucket_rows(cfg: LlamaConfig, rows: int) -> int:
+    """Rows a held expert's bucket holds in a step of ``rows`` rows. A token
+    chooses a given held expert with probability ``k / routed`` (the config's
+    experts per token over its router's width), so a step of ``rows`` gives an
+    expert ``rows * k / routed`` of them when routing is even; the bucket is
+    four times that, reckoned for the largest step of its class (64 rows: the
+    decode steps and small pieces; 256: a prefill chunk), as a power of two
+    and at least 8. At 8 of 320 that is 8 and 32 (a chunk gives an expert 6.4
+    rows, s.d. 2.5), at 8 of 128 16 and 64. A step in which some expert
+    overflows its bucket takes the every-row path instead (exact either way)."""
+    import math
+
+    from distributed_llama_tpu.models.config import next_pow2
+
+    expected = (64 if rows <= 64 else 256) * cfg.n_active_experts / cfg.router_width
+    return max(8, next_pow2(math.ceil(4 * expected)))
 
 
 def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) -> jax.Array:
@@ -343,7 +355,7 @@ def _held_experts(
     weights = jnp.where(is_held, top_vals, 0.0)
     counts = jnp.sum(jax.nn.one_hot(local, E + 1, dtype=jnp.int32), axis=(0, 1))[:E]
     on = counts > 0
-    C = held_bucket_rows(T)
+    C = held_bucket_rows(cfg, T)
 
     def every_row():
         held = jnp.einsum("tk,tke->te", weights, jax.nn.one_hot(local, E + 1)[..., :E])

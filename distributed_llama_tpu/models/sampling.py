@@ -478,8 +478,9 @@ def batched_decode_scan(
     requests can join/leave between chunks without a recompile. Returns
     (tokens [n_steps, B], cache, fingerprints uint32 [B], finite bool
     [B]) and, for an arch that holds a share of its experts, int32 [B] the
-    row's expert choices that fell on a held expert — NOTHING else needs to
-    cross the host per chunk: the sampler is
+    row's expert choices that fell on a held expert, and for one with window
+    layers two more, the cache positions the row's full and its window layers
+    read — NOTHING else needs to cross the host per chunk: the sampler is
     stateless, so no advanced keys return and no full-vocab logits are
     ever fetched. ``paged``: each row's matched prompt prefix is read from
     the shared page pool through its page table instead of the slab (the
@@ -500,16 +501,22 @@ def batched_decode_scan(
     # an arch that holds a share of its experts: per row, the choices that
     # fell on a held expert, summed over the chunk's steps and layers
     share = cfg.n_routed_experts > 0
+    # an arch with window layers: per row, the cache positions its full and
+    # its window layers read, summed likewise (two more rows of the bundle)
+    windowed = cfg.has_window
 
     def step(carry, _):
-        tokens, cache_c, p, h, okf, held = carry
+        tokens, cache_c, p, h, okf, held, kv = carry
         counts = [] if share else None
+        reads = {} if windowed else None
         logits, cache_c = llama.forward_step_batched(
             cfg, params, tokens, cache_c, p, active, axis_name=axis_name,
-            paged=paged, held_counts=counts,
+            paged=paged, held_counts=counts, kv_reads=reads,
         )
         if share:
             held = held + jnp.where(active, counts[0], 0)
+        if windowed:
+            kv = (kv[0] + reads["full"], kv[1] + reads["window"])
         cand = None
         if axis_name is not None and logits.shape[-1] != cfg.vocab_size:
             # the tp top-k composition: candidates reduce over the sharded
@@ -525,21 +532,20 @@ def batched_decode_scan(
         if fingerprint:
             h, okf = integrity.fingerprint_fold(h, okf, logits, nxt)
         p2 = jnp.where(active, p + 1, p)
-        return (nxt.astype(jnp.int32), cache_c, p2, h, okf, held), nxt
+        return (nxt.astype(jnp.int32), cache_c, p2, h, okf, held, kv), nxt
 
     h0, ok0 = integrity.fingerprint_init(first_tokens.shape[0])
-    (_, cache, _, h, okf, held), tokens = jax.lax.scan(
+    zeros = jnp.zeros(first_tokens.shape, jnp.int32)
+    (_, cache, _, h, okf, held, kv), tokens = jax.lax.scan(
         step,
         (
             first_tokens.astype(jnp.int32), cache, pos.astype(jnp.int32),
-            h0, ok0, jnp.zeros(first_tokens.shape, jnp.int32),
+            h0, ok0, zeros, (zeros, zeros) if windowed else (),
         ),
         None,
         length=n_steps,
     )
-    if share:
-        return tokens, cache, h, okf, held
-    return tokens, cache, h, okf
+    return (tokens, cache, h, okf) + ((held,) if share else ()) + kv
 
 
 def batched_chunk_from_carry(

@@ -13,10 +13,35 @@ context entirely.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
 from distributed_llama_tpu.ops import kv_cache as kvc
+
+# Trace-time collector of what a batched decode step's attention reads: while
+# one is open (:func:`collect_kv_reads`), every layer's scan appends (kind,
+# int32 [B]): the positions of each row's cache it read (``full``: the chunks
+# up to the bucket's longest row, every row alike; ``window``: the window).
+# The forward that opened it sums by kind and returns the sums with its
+# tokens, as the expert share's counts are (``models.moe.collect_held``).
+_kv_reads: list | None = None
+
+
+@contextlib.contextmanager
+def collect_kv_reads(enabled: bool = True):
+    global _kv_reads
+    before, _kv_reads = _kv_reads, [] if enabled else None
+    try:
+        yield _kv_reads
+    finally:
+        _kv_reads = before
+
+
+def note_kv_read(kind: str, rows: int, positions) -> None:
+    if _kv_reads is not None:
+        _kv_reads.append((kind, jnp.full((rows,), positions, jnp.int32)))
 
 
 def chunk_attention(
@@ -279,6 +304,7 @@ def batched_decode_attention(
         telemetry.note_kernel_path("paged_attention", "xla_segmented")
     live = jnp.clip(jnp.max(pos) + 1, 0, S)
     n_chunks = jax.lax.div(live + chunk - 1, chunk)
+    note_kv_read("full", B, n_chunks * chunk)
     partial = _decode_partial(qg, pos, chunk, cdt, prec)
     m0 = jnp.full((B, K, M), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, K, M), jnp.float32)
@@ -287,6 +313,68 @@ def batched_decode_attention(
         partial, cache, paged, chunk, n_chunks, (m0, l0, o0), rows=B
     )
     return o / jnp.maximum(l, 1e-30)[..., None]
+
+
+def _window_softmax(scores, mask, values, cdt, prec, mix):
+    """Softmax over the gathered window's masked scores, then the value mix
+    (``mix``: the single-row or the batched einsum). A query always sees its
+    own position, so no row is empty."""
+    weights = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    return mix(weights, values, cdt, prec)
+
+
+def window_attention(
+    qg: jax.Array,  # [T, K, M, hd] f32 grouped queries at pos..pos+T-1
+    ring,  # fused single-row ring leaf [2, R, K, hd], the piece's K/V written
+    pos: jax.Array,  # scalar: absolute position of query row 0
+    window: int,
+) -> jax.Array:
+    """Window attention of T query rows of ONE row over its ring: query ``t``
+    sees key ``s`` iff ``t - window < s <= t``. The positions pos - window + 1
+    .. pos + T - 1 are gathered from their slots (``s % R``) and masked by
+    position; nothing else of the ring is read, so the cost does not grow
+    with the context. The ring has to hold them all beside each other:
+    ``T + window - 1 <= R``. Returns [T, K, M, hd] f32."""
+    T, K, M, hd = qg.shape
+    R = ring.shape[1]
+    span = T + window - 1
+    if span > R:
+        raise ValueError(
+            f"a piece of {T} tokens and a window of {window} positions do not fit a ring of "
+            f"{R} slots: a prompt is written in pieces of at most {R - window + 1} tokens"
+        )
+    k_pos = pos - window + 1 + jnp.arange(span)
+    kc, vc = kvc.ring_take(ring, k_pos % R)
+    q_pos = pos + jnp.arange(T)
+    mask = (k_pos[None, :] >= 0) & (k_pos[None, :] <= q_pos[:, None]) & (
+        k_pos[None, :] > q_pos[:, None] - window
+    )
+    cdt, prec = kvc.compute_dtype(kc), kvc.einsum_precision(kc)
+    scores = kvc.scores_einsum(qg.astype(cdt), kc, prec) / jnp.sqrt(jnp.float32(hd))
+    return _window_softmax(scores, mask[:, None, None, :], vc, cdt, prec, kvc.mix_einsum)
+
+
+def batched_window_attention(
+    qg: jax.Array,  # [B, K, M, hd] f32 grouped queries (one token per row)
+    cache,  # the layer's ring slab: fused leaf [2, B_max, R, K, hd]
+    pos: jax.Array,  # [B] per-row absolute positions (inactive rows: 0)
+    window: int,
+) -> jax.Array:
+    """Window attention of B independent single-token queries, each over its
+    own ring row: row ``b`` gathers the slots of positions pos[b] - window + 1
+    .. pos[b], whatever the other rows' positions are (the full layers' shared
+    chunk bound would visit every chunk between the shortest and the longest
+    row). A decode step reads ``window`` positions a row in such a layer,
+    however long the context. Returns [B, K, M, hd] f32."""
+    B, K, M, hd = qg.shape
+    R = cache.shape[2]
+    k_pos = pos[:, None] - window + 1 + jnp.arange(window)[None, :]  # [B, W]
+    kc, vc = kvc.ring_take(cache, k_pos % R, rows=B)
+    note_kv_read("window", B, window)
+    cdt, prec = kvc.compute_dtype(kc), kvc.einsum_precision(kc)
+    scores = kvc.scores_einsum_batched(qg.astype(cdt), kc, prec) / jnp.sqrt(jnp.float32(hd))
+    mask = (k_pos >= 0)[:, None, None, :]
+    return _window_softmax(scores, mask, vc, cdt, prec, kvc.mix_einsum_batched)
 
 
 def batched_verify_attention(

@@ -370,14 +370,18 @@ def pool_page_arrays_per_half(pool_half) -> int:
     return 2 if isinstance(pool_half, QuantizedKV) else 1
 
 
-def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int):
+def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int,
+                      ring: bool = False):
     """Copy slab row ``row``'s page slots ``src_page[i]`` into pool pages
     ``page_ids[i]`` (the prefix-cache publish: the row's completed prefill
     KV becomes an immutable shared page). A ``page_ids`` entry at or beyond
-    P DROPS its write, so padded entries are inert. Returns the updated pool
-    half (callers donate the pool)."""
+    P DROPS its write, so padded entries are inert. ``ring``: the slab half
+    is a window layer's ring, and a position sits at its slot modulo the
+    ring's length. Returns the updated pool half (callers donate the pool)."""
     p_idx = jnp.arange(page)
     slots = (src_page[:, None] * page + p_idx[None, :]).reshape(-1)
+    if ring:
+        slots = slots % slab_half.shape[1]
     n = src_page.shape[0]
     if isinstance(pool_half, QuantizedKV):
         vals = slab_half.data[row, slots]  # [Np*page, K, hd]
@@ -394,6 +398,67 @@ def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int):
     return pool_half.at[page_ids].set(
         vals.reshape((n, page) + vals.shape[1:]), mode="drop"
     )
+
+
+def restore_row_pages(slab_leaf, pool_k, pool_v, row, dst_page, page_ids, page: int):
+    """:func:`publish_row_pages` in reverse, for a ring: pool pages
+    ``page_ids[i]`` (keys and values) into the slots of fused slab leaf
+    ``[2, B, R, K, hd]``'s row ``row`` where positions ``dst_page[i] * page
+    ...`` sit (a prefix hit's window tail). Returns the updated leaf (callers
+    donate the slab)."""
+    R = slab_leaf.shape[2]
+    slots = ((dst_page[:, None] * page + jnp.arange(page)[None, :]).reshape(-1)) % R
+
+    def both(k, v):
+        kv = jnp.stack([k[page_ids], v[page_ids]])  # [2, n, page, K, x]
+        return kv.reshape((2, -1) + kv.shape[3:])
+
+    if isinstance(slab_leaf, QuantizedKV):
+        return QuantizedKV(
+            slab_leaf.data.at[:, row, slots].set(both(pool_k.data, pool_v.data)),
+            slab_leaf.scales.at[:, row, slots].set(both(pool_k.scales, pool_v.scales)),
+        )
+    return slab_leaf.at[:, row, slots].set(both(pool_k, pool_v))
+
+
+# ---------------------------------------------------------------------------
+# Rings (a window layer's cache): position p sits at slot p % R of [.., R, K,
+# hd], so the leaf does not grow with the row's length. A write is a scatter at
+# the positions' slots (a piece may wrap), a read gathers the slots of the
+# positions a query can see; the caller masks by position.
+# ---------------------------------------------------------------------------
+
+
+def ring_update_rows(leaf, k_rows: jax.Array, v_rows: jax.Array, pos):
+    """T tokens' keys and values at positions pos..pos+T-1 into a fused
+    single-row ring leaf [2, R, K, hd], one scatter (two for i8)."""
+    R = leaf.shape[1]
+    slots = (pos + jnp.arange(k_rows.shape[0])) % R
+    if isinstance(leaf, QuantizedKV):
+        kq, ks = quantize_rows(k_rows)
+        vq, vs = quantize_rows(v_rows)
+        return QuantizedKV(
+            leaf.data.at[:, slots].set(jnp.stack([kq, vq])),
+            leaf.scales.at[:, slots].set(jnp.stack([ks, vs])),
+        )
+    return leaf.at[:, slots].set(jnp.stack([k_rows, v_rows]).astype(leaf.dtype))
+
+
+def ring_take(leaf, idx: jax.Array, rows: int | None = None):
+    """Slots ``idx`` of a fused ring leaf -> ``(kc, vc)``. Single row: leaf
+    [2, R, K, hd], idx [n] -> [n, K, hd] each. Slab: leaf [2, B_max, R, K,
+    hd], idx [B, n] -> [B, n, K, hd] each, row b its own slots (``rows`` =
+    B: only the first B slab rows are read)."""
+    def take(a):
+        if rows is None:
+            return a[:, idx]
+        return a[:, jnp.arange(rows)[:, None], idx]
+
+    if isinstance(leaf, QuantizedKV):
+        d, s = take(leaf.data), take(leaf.scales)
+        return QuantizedKV(d[0], s[0]), QuantizedKV(d[1], s[1])
+    c = take(leaf)
+    return c[0], c[1]
 
 
 # ---------------------------------------------------------------------------

@@ -262,10 +262,11 @@ class ApiState:
                 self._shared_index = SharedPrefixIndex(page_sz)
             spill_mb = getattr(args, "host_spill_mb", None)
             if spill_mb is None:
-                # no default spill tier under an arch with recurrent state: a
-                # page's state snapshot has no spill form (asked for by flag,
-                # the scheduler refuses by name)
-                spill_mb = 0.0 if engine.cfg.is_recurrent else 64.0
+                # no default spill tier under an arch with recurrent state or
+                # window layers: a state snapshot and a window layer's page
+                # have no spill form (asked for by flag, the scheduler refuses
+                # by name)
+                spill_mb = 64.0 if engine.cfg.rewinds_by_position else 0.0
             spill_mb = float(spill_mb)
             if spill_mb > 0:
                 from distributed_llama_tpu.engine.spill import HostArena
@@ -1024,10 +1025,11 @@ class ApiState:
             raise DeadlineExceeded("deadline expired before prefill")
 
         start_pos, delta_messages = slot.cache.resolve_delta_prompt(params["messages"])
-        if start_pos and engine.cfg.is_recurrent:
-            # a recurrent state cannot be rewound to where the cached
-            # messages end: the conversation prefills again from 0, through
-            # the prefix cache, which resumes it from its deepest snapshot
+        if start_pos and not engine.cfg.rewinds_by_position:
+            # a recurrent state or a window layer's ring cannot be rewound to
+            # where the cached messages end: the conversation prefills again
+            # from 0, through the prefix cache, which resumes it from its
+            # deepest snapshot or kept window tail
             slot.cache.clear()
             start_pos, delta_messages = 0, params["messages"]
         engine.rollback(min(start_pos, engine.pos))

@@ -334,6 +334,30 @@ class EngineInstruments:
             "Bytes of recurrent state and convolution tails the slab's rows "
             "hold (linear-attention layers; does not grow with a row's length)",
         )
+        kv_read = counter(
+            "dllama_attn_kv_read_bytes_total",
+            "Bytes of keys and values the decode chunks' attention read out of "
+            "slab and pool, by layer kind: full (every chunk up to the "
+            "bucket's longest row, every row of the bucket alike) and window "
+            "(the window's positions of each row's ring); counted by the "
+            "programs from their scans' bounds and returned with their tokens",
+            labelnames=("kind",),
+        )
+        self.kv_read_full = kv_read.labels(kind="full")
+        self.kv_read_window = kv_read.labels(kind="window")
+        self.kv_slab_bytes = gauge(
+            "dllama_kv_slab_bytes",
+            "Bytes of keys and values the slab's rows hold, by layer kind: a "
+            "full layer's grow with --max-seq-len, a window layer's ring does not",
+            labelnames=("kind",),
+        )
+        self.kv_pool_bytes = gauge(
+            "dllama_kv_pool_bytes",
+            "Bytes of the prefix-cache page pools by layer kind: full "
+            "(--kv-pages pages) and window (the last pages of the prompts "
+            "published lately: bounded by rows, not by --kv-pages)",
+            labelnames=("kind",),
+        )
         self.prefill_chunks_ahead = histogram(
             "dllama_prefill_chunks_ahead",
             "Decode chunks in flight on the device (one pending, one being "
@@ -468,6 +492,23 @@ class PrefixCacheInstruments:
         self.snapshots_published = snapshots.labels(event="published")
         self.snapshots_restored = snapshots.labels(event="restored")
         self.snapshots_evicted = snapshots.labels(event="evicted")
+        window_tail = counter(
+            "dllama_prefix_window_tail_total",
+            "Prefix matches of an arch with window-attention layers by what the "
+            "window layers' pool still held: hit (the pages before the matched "
+            "chain's end were there: the whole chain served), shortened (the "
+            "chain was cut back to the deepest block whose tail was kept), "
+            "miss (no block's was: the prompt prefilled from 0)",
+            labelnames=("outcome",),
+        )
+        self.window_tail_hit = window_tail.labels(outcome="hit")
+        self.window_tail_shortened = window_tail.labels(outcome="shortened")
+        self.window_tail_miss = window_tail.labels(outcome="miss")
+        self.window_pages_evicted = counter(
+            "dllama_prefix_window_pages_evicted_total",
+            "Pages of the window layers' pool taken from the block that went "
+            "longest without a hit using it (the block stays in the tree)",
+        )
         # host-RAM / disk spill tier (ISSUE 11, engine/spill.py): the
         # capacity ladder below the HBM pool
         self.spill_pages = counter(

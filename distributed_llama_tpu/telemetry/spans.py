@@ -33,6 +33,8 @@ SPAN_NAMES = (
     "prefix_publish",
     "state_snapshot",
     "state_restore",
+    "window_tail_publish",
+    "window_tail_restore",
     # the scheduler's own phases (ISSUE 23): what the host was doing
     # between device programs; tools read them off a capture's xplane
     "sched_build",
