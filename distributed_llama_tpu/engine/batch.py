@@ -141,11 +141,13 @@ def _padded_pages(ids: list[int], blocks: list[int], drop: int):
 
 @jax.jit
 def _slice_page(pool, pid):
-    """One pool page's bytes across every layer/half as a flat list (the
-    spill-entry layout of kv_cache.download_pool_page) — ONE compiled
-    program + ONE host transfer per spill instead of 2·layers separate
-    fetches (the download runs under the scheduler cond; its wall time is
-    lock hold time for every lane)."""
+    """One pool page's bytes across every layer/half as a flat list of
+    FRESH device buffers (the spill-entry layout of
+    kv_cache.download_pool_page) — ONE compiled program per spilled page,
+    launched under the scheduler cond and never waited for there: the
+    buffers outlive the pool's next donation, and the arena's spiller
+    thread fetches them (``pid`` crosses as a numpy scalar with the
+    dispatch)."""
     out = []
     for pk, pv in filter(None, pool):
         out.extend(kvc.slice_pool_page(pk, pid))
@@ -762,6 +764,7 @@ class BatchScheduler:
         self._prefix = None
         self._pool = None
         self._wpool = None
+        self._own_arena = None  # a spill arena this scheduler made itself
         if prefix_cache:
             # misconfiguration disables ONLY the prefix cache (with the
             # real reason printed) — it must never take batched decode
@@ -819,6 +822,7 @@ class BatchScheduler:
                         ),
                         disk_budget_bytes=int(spill_disk_bytes),
                     )
+                    self._own_arena = arena  # close() stops its spiller
                 if arena is not None and tp_engine is not None:
                     print(
                         "⚠️ host-RAM spill disabled: the sharded tp page "
@@ -854,7 +858,8 @@ class BatchScheduler:
                         engine.cfg, page_size, engine.cache_dtype
                     ),
                     spill=arena,
-                    page_fetch=self._download_page if arena is not None else None,
+                    page_fetch=self._slice_pages_locked if arena is not None else None,
+                    page_land=self._land_pages,
                     owner_id=replica_id,
                     shared_index=shared_index,
                     snap_slots=snap_slots,
@@ -958,7 +963,7 @@ class BatchScheduler:
         if self._prefix is not None and self._prefix.spill is not None:
             # the spill download's program too: the first eviction may come
             # in the middle of serving, under the scheduler's lock
-            self._download_page(0)
+            self._land_pages([_slice_page(self._pool, np.int32(0))])
         # the logits of the newest prompt piece dispatched, and, from each
         # delivery on, of the newest one that was on the device's queue then:
         # the next decode chunk is not enqueued in front of it
@@ -1044,11 +1049,14 @@ class BatchScheduler:
             self._watchdog.start()
 
     def close(self) -> None:
-        """Stop the watchdog thread (tests; a serving scheduler lives for
-        the process)."""
+        """Stop the watchdog thread and, where this scheduler made its own
+        spill arena, that arena's spiller (tests; a serving scheduler lives
+        for the process)."""
         with self._cond:
             self._shutdown = True
             self._cond.notify_all()
+        if self._own_arena is not None:
+            self._own_arena.close()
 
     # ------------------------------------------------------------------
     # Replica loss (ISSUE 9): the whole-scheduler failure domain. A crash
@@ -1459,14 +1467,27 @@ class BatchScheduler:
             with self._cond:
                 self._drop_snapshot_locked(stream)
 
-    def _download_page(self, pid: int) -> list[np.ndarray]:
-        """Host byte arrays of pool page ``pid`` across every layer and
-        half, in the flat spill-entry layout (the PrefixCache eviction
-        hook). One fused slice program + one pytree transfer: the read
-        dispatches before any later publish can recycle the page id
-        (device ordering keeps it exact), and the single blocking
-        device_get bounds the scheduler-cond hold time per spill."""
-        return list(jax.device_get(_slice_page(self._pool, jnp.int32(pid))))
+    def _slice_pages_locked(self, pids: list[int]) -> list:
+        """The PrefixCache eviction hook (cond held; the ``_locked`` name
+        puts this body under LCK-002, which could not see the wait that sat
+        here behind the callback until ISSUE 38): pool pages ``pids`` sliced
+        across every layer and half into fresh device buffers, one handle
+        (a flat list in the spill-entry layout) a page. ENQUEUE ONLY: one
+        launch a page, each dispatched before any later publish can recycle
+        its page id (device ordering keeps the read exact) and none waited
+        for. A publish's victims past what the arena can keep are never
+        sliced (``HostArena.keeps``), so this is at most the arena's budget
+        of launches and of device bytes in flight."""
+        return [_slice_page(self._pool, np.int32(pid)) for pid in pids]
+
+    @staticmethod
+    def _land_pages(handles: list) -> list[list[np.ndarray]]:
+        """Host byte arrays of :meth:`_slice_pages_locked`'s handles: ONE
+        blocking transfer of them all. The arena's spiller thread calls
+        this with no lock held; under ``_cond`` it is an LCK-002 finding
+        (``blocking_calls``) and, behind any callback, the witness's."""
+        lockcheck.note_blocking("BatchScheduler._land_pages")
+        return jax.device_get(handles)
 
     def _page_pytree(self, arrays: list) -> list:
         """Regroup a flat spill entry back into the per-layer (k, v)
@@ -1518,7 +1539,7 @@ class BatchScheduler:
                 # still under _reload_spilled_locked's cond — the AST
                 # can't see through the callback boundary
                 self._pool = _upload_page(  # dllama: noqa[LCK-004]
-                    self._pool, jnp.int32(pid), self._page_pytree(arrays)
+                    self._pool, np.int32(pid), self._page_pytree(arrays)
                 )
 
         return prefix.reload(tokens, upload, pre=pre)
@@ -1537,6 +1558,10 @@ class BatchScheduler:
         tr = stream.trace
         t0 = time.monotonic() if tr is not None else 0.0
         reloaded = 0
+        # pages of this prompt that an eviction sent on their way to the
+        # host a moment ago: this thread waits for them HERE, before the
+        # lock, so that they reload; under the lock a pending page is a miss
+        prefix.await_pending(tokens)
         with self._cond:
             # unwind any stale alias left by a caller that skipped reset
             self._release_pins_locked(stream)

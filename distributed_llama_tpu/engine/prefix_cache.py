@@ -37,15 +37,20 @@ Design
 * The pool size (``--kv-pages``) IS the HBM budget: allocation evicts
   LRU-unreferenced leaves only when the free list runs dry, and fails
   softly (the scheduler simply skips publishing) when everything is
-  pinned. Eviction is an O(pages-in-tree) host scan per reclaimed page —
-  fine at the default budgets (hundreds of pages, tens of µs under the
-  scheduler lock); a last_use-ordered leaf index is the known follow-up
-  if ``--kv-pages`` grows to the tens of thousands.
+  pinned. A publish or reload learns how many pages it is short of and
+  takes its victims TOGETHER: one O(pages-in-tree) host scan yields them
+  in the order one-by-one eviction would (:meth:`_pick_victims`); a
+  last_use-ordered leaf index is the known follow-up if ``--kv-pages``
+  grows to the tens of thousands.
 * **Tiered capacity below HBM** (ISSUE 11, engine/spill.py): with a
   :class:`~distributed_llama_tpu.engine.spill.HostArena` attached,
   eviction no longer discards the page — its bytes (data+scales verbatim
   for i8) spill to bounded host RAM (and optionally an mmap'd disk file,
-  echoing the reference's disc-backed KV), and a later admission match
+  echoing the reference's disc-backed KV). Under the scheduler's lock the
+  victims' pages are only SLICED into fresh device buffers (enqueued, not
+  waited for) and entered in the arena as pending; the arena's spiller
+  thread fetches, checksums and lands them (ISSUE 38: the lock used to be
+  held across a device wait for every page). A later admission match
   that runs out of device-resident chain RELOADS the spilled pages
   (:meth:`reload` — the publish machinery in reverse: alloc a pool page,
   upload the host bytes, re-insert the node). Re-upload is orders of
@@ -67,13 +72,17 @@ Design
 Thread model: the owning :class:`~distributed_llama_tpu.engine.batch.
 BatchScheduler` calls every method under its condition lock; the tree
 itself is lock-free on purpose (one lock, one owner — no ordering hazards
-between tree state and slab/pool dispatches). The shared index and the
-arena have their own LEAF locks (multiple schedulers and the replica
-pool reach them concurrently); neither ever calls back out.
+between tree state and slab/pool dispatches); the one method meant to be
+called WITHOUT it is :meth:`PrefixCache.await_pending`, which touches the
+arena only. The shared index and the arena have their own LEAF locks
+(multiple schedulers and the replica pool reach them concurrently);
+neither ever calls back out.
 """
 
 from __future__ import annotations
 
+import heapq
+import itertools
 import threading
 
 from distributed_llama_tpu import lockcheck, telemetry
@@ -185,7 +194,8 @@ class PrefixCache:
 
     def __init__(
         self, n_pages: int, page: int, page_bytes: int = 0,
-        spill=None, page_fetch=None, owner_id: int = 0, shared_index=None,
+        spill=None, page_fetch=None, page_land=None, owner_id: int = 0,
+        shared_index=None,
         snap_slots: int = 0, window_pages: int = 0, window_tail: int = 0,
     ):
         if n_pages < 1:
@@ -195,15 +205,20 @@ class PrefixCache:
         self.page = page
         self.capacity = n_pages
         # tiered capacity + cross-replica sharing (ISSUE 11): ``spill`` is
-        # the shared HostArena (engine/spill.py), ``page_fetch(page_id)``
-        # the owning scheduler's device→host download of one pool page's
-        # byte arrays (the spill side; the upload side is a reload()
-        # argument — both device programs belong to the scheduler),
-        # ``shared_index`` the pool-wide SharedPrefixIndex this tree
+        # the shared HostArena (engine/spill.py). The spill side is two
+        # callables of the owning scheduler: ``page_fetch(page_ids)``
+        # slices those pool pages into fresh device buffers, one handle a
+        # page, ENQUEUED ONLY (it runs under the scheduler's lock), and
+        # ``page_land(handles)`` turns handles into host byte arrays,
+        # BLOCKING (the arena's spiller thread calls it, off every lock).
+        # The upload side is a reload() argument. ``page_bytes`` is what
+        # one page's entry weighs in the arena. ``shared_index`` is the
+        # pool-wide SharedPrefixIndex this tree
         # reports its chains to, ``owner_id`` this replica's identity in
         # both. All optional: a bare PrefixCache keeps the PR 4 contract.
         self.spill = spill
         self.page_fetch = page_fetch
+        self.page_land = page_land
         self.owner_id = int(owner_id)
         self.shared_index = shared_index
         # logical KV bytes per page across all layers/halves
@@ -278,10 +293,9 @@ class PrefixCache:
         while node is not None and node.key is not None:
             keys.append(node.key)
             node = node.parent
-        out: list[int] = []
-        for k in reversed(keys):
-            out.extend(int(t) for t in k)
-        return tuple(out)
+        # edge keys are tuples of Python ints (publish and reload make them
+        # from one converted list): a chain of a hundred blocks is a copy
+        return tuple(itertools.chain.from_iterable(reversed(keys)))
 
     def walk(self, tokens) -> list[PageNode]:
         """The :meth:`match` walk WITHOUT refs, counters or clock ticks —
@@ -368,18 +382,23 @@ class PrefixCache:
                 )
         if self.spill is not None:
             # spill-tier exclusivity (ISSUE 11): only EVICTED pages live in
-            # the arena. A pinned (row-aliased or publish-held) page that
+            # the arena. A chain is in the tree, PENDING in the arena (its
+            # bytes on their way, ISSUE 38) or landed there: at most one. A
+            # pinned (row-aliased or publish-held) page that
             # also had an arena entry under this owner would mean eviction
-            # spilled a live page, or a reload forgot to retire its source
-            # entry — either way two copies of "the" bytes with no single
-            # owner of truth
+            # spilled a live page, a reload forgot to retire its source
+            # entry, or a pending entry was not cancelled when its chain
+            # came back — either way two copies of "the" bytes with no
+            # single owner of truth
+            self.spill.check()
             for node in seen.values():
                 if node.refs > 0:
                     key = self.chain_key(node)
                     assert not self.spill.has(self.owner_id, key), (
                         f"pinned page {node.page_id} is simultaneously "
-                        "resident in the spill arena (chain of "
-                        f"{len(key)} tokens)"
+                        + ("pending in" if self.spill.is_pending(self.owner_id, key)
+                           else "resident in")
+                        + f" the spill arena (chain of {len(key)} tokens)"
                     )
 
     # ------------------------------------------------------------------
@@ -463,34 +482,48 @@ class PrefixCache:
         of the NEWLY allocated pages — the scheduler copies those blocks
         out of the row; blocks already present (a concurrent request
         published them first) are refreshed, not re-copied. Allocation
-        evicts LRU-unreferenced leaves when the free list is dry and stops
-        early (partial publish) when nothing is evictable."""
+        evicts LRU-unreferenced leaves for what the free list lacks and
+        stops early (partial publish) when nothing more is evictable."""
         node = parent_chain[-1] if parent_chain else self.root
         page = self.page
+        n_blocks = n_total // page
+        ids = self._token_ids(tokens, n_blocks * page)
         new_ids: list[int] = []
         new_blocks: list[int] = []
         t = self._tick()
-        # pin the whole growing chain for the duration of the walk: a
-        # mid-publish _alloc may evict, and an unpinned just-inserted (or
-        # traversed) node is a refcount-0 leaf — the evictor would detach
+        # pin the whole growing chain for the duration of the walk: the
+        # eviction below must not take a traversed node, and an unpinned
+        # just-inserted node is a refcount-0 leaf — an evictor would detach
         # the very chain being built, double-allocating its page and
         # leaking the rest (reproduced: capacity-1 pool, 2-block publish)
         pinned: list[PageNode] = list(parent_chain)
         for nd in pinned:
             self._ref(nd)
         try:
-            for i in range(len(parent_chain), n_total // page):
-                key = tuple(tokens[i * page : (i + 1) * page])
-                child = node.children.get(key)
+            i = len(parent_chain)
+            while i < n_blocks:
+                # blocks a concurrent request published first
+                child = node.children.get(tuple(ids[i * page : (i + 1) * page]))
                 if child is None:
-                    pid = self._alloc()
-                    if pid is None:
-                        break  # budget exhausted and everything pinned
-                    child = PageNode(key, pid, node)
-                    node.children[key] = child
-                    new_ids.append(pid)
-                    new_blocks.append(i)
-                    self._note_insert(child)
+                    break
+                self._ref(child)
+                pinned.append(child)
+                child.last_use = t
+                node = child
+                i += 1
+            # every deeper block is new. What they need beyond the free
+            # list comes from the LRU leaves, taken together: one walk, one
+            # batch for the spill tier
+            self._evict(self._pick_victims(n_blocks - i - len(self.free)))
+            for i in range(i, n_blocks):
+                if not self.free:
+                    break  # budget exhausted and everything pinned
+                pid = self.free.pop()
+                child = PageNode(tuple(ids[i * page : (i + 1) * page]), pid, node)
+                node.children[child.key] = child
+                new_ids.append(pid)
+                new_blocks.append(i)
+                self._note_insert(child, tuple(ids[: (i + 1) * page]))
                 self._ref(child)
                 pinned.append(child)
                 child.last_use = t
@@ -639,63 +672,107 @@ class PrefixCache:
     # Allocation / LRU eviction
     # ------------------------------------------------------------------
 
-    def _alloc(self) -> int | None:
-        if self.free:
-            return self.free.pop()
-        if self._evict_one():
-            return self.free.pop()
-        return None
+    @staticmethod
+    def _token_ids(tokens, n: int) -> list[int]:
+        """``tokens[:n]`` as Python ints, converted ONCE: a chain key is a
+        slice of this (a deep chain's key is thousands of tokens, and a
+        publish or reload makes one a block)."""
+        head = tokens[:n]
+        return head.tolist() if hasattr(head, "tolist") else [int(t) for t in head]
+
+    def _pick_victims(self, k: int) -> list[PageNode]:
+        """The ``k`` nodes (fewer when fewer are evictable) that reclaiming
+        the least-recently-used unreferenced LEAF ``k`` times over would
+        take, in that order, from ONE walk of the tree: children keep their
+        ancestors alive (evicting an interior page would strand the chain
+        below it), so an interior node becomes a candidate when its last
+        child has been picked. Equal ``last_use`` goes by the walk's order,
+        as the one-by-one scan did. Nothing is detached here; the order
+        holds for as long as the caller changes the tree only by evicting
+        these and by inserting pinned nodes."""
+        if k <= 0:
+            return []
+        order: dict[PageNode, int] = {}
+        heap = []
+        for i, node in enumerate(self._walk()):
+            order[node] = i
+            if not node.children and node.refs == 0:
+                heap.append((node.last_use, i, node))
+        heapq.heapify(heap)
+        picked_under: dict[PageNode, int] = {}
+        victims: list[PageNode] = []
+        while heap and len(victims) < k:
+            node = heapq.heappop(heap)[2]
+            victims.append(node)
+            parent = node.parent
+            if parent is not self.root and parent.refs == 0:
+                picked_under[parent] = picked_under.get(parent, 0) + 1
+                if picked_under[parent] == len(parent.children):
+                    heapq.heappush(heap, (parent.last_use, order[parent], parent))
+        return victims
 
     def _evict_one(self) -> bool:
-        """Reclaim the least-recently-used unreferenced LEAF (children keep
-        their ancestors alive: evicting an interior page would strand the
-        chain below it). Returns False when every leaf is pinned. With a
-        spill arena attached the page's bytes are downloaded and spilled
-        BEFORE the page id is freed (the download dispatches against the
-        pre-recycle pool contents; device ordering keeps it exact even
-        though a later publish may reuse the id immediately)."""
-        victim: PageNode | None = None
-        for node in self._walk():
-            if node.children or node.refs > 0:
-                continue
-            if victim is None or node.last_use < victim.last_use:
-                victim = node
-        if victim is None:
-            return False
-        key = None
-        if self.spill is not None or self.shared_index is not None:
-            key = self.chain_key(victim)
-        if self.spill is not None and self.page_fetch is not None:
+        """Reclaim the least-recently-used unreferenced leaf. Returns False
+        when every leaf is pinned."""
+        victims = self._pick_victims(1)
+        self._evict(victims)
+        return bool(victims)
+
+    def _evict(self, victims: list[PageNode]) -> None:
+        """Detach ``victims`` (:meth:`_pick_victims`' order) and free their
+        pages; the first victim's page is the next one allocated. With a
+        spill arena attached their pages are sliced into fresh device
+        buffers first: launches that are ENQUEUED, never waited for, which
+        read the pool as it is before any later publish can recycle a page
+        id (device ordering keeps that exact), so the ids go back on the
+        free list at once. The bytes reach the arena on its spiller thread."""
+        if not victims:
+            return
+        spilling = self.spill is not None and self.page_fetch is not None
+        # of victims put in this order the arena ends holding the last
+        # ``kept``: only those are sliced and fetched
+        kept = self.spill.keeps(len(victims), self.page_bytes) if spilling else 0
+        first = len(victims) - kept
+        keys = [
+            self.chain_key(v) if self.shared_index is not None or i >= first else None
+            for i, v in enumerate(victims)
+        ]
+        if spilling:
             try:
-                self.spill.put(self.owner_id, key, self.page_fetch(victim.page_id))
-                self.tel.spill_pages.inc()
+                handles = self.page_fetch([v.page_id for v in victims[first:]])
             except Exception as e:
-                # spilling is an optimization: a failed download degrades
-                # to the PR 4 behavior (the page simply vanishes)
+                # spilling is an optimization: a failed launch degrades
+                # to the PR 4 behavior (the pages simply vanish)
                 print(f"⚠️ page spill failed; evicting without it: {e}")
+            else:
+                self.spill.put_pending(
+                    self.owner_id, keys[first:], handles, self.page_bytes,
+                    self.page_land, skipped=first,
+                )
+                self.tel.spill_pages.inc(len(victims))
             self._set_spill_gauges()
-        if self.shared_index is not None:
-            self.shared_index.withdraw(self.owner_id, key)
-        del victim.parent.children[victim.key]
-        self._drop_snapshot(victim)  # snapshot and page go together
-        self._drop_window_page(victim)
-        self.free.append(victim.page_id)
-        self.tel.evictions.inc()
+        for victim, key in zip(victims, keys):
+            if self.shared_index is not None:
+                self.shared_index.withdraw(self.owner_id, key)
+            del victim.parent.children[victim.key]
+            self._drop_snapshot(victim)  # snapshot and page go together
+            self._drop_window_page(victim)
+            self.tel.evictions.inc()
+        self.free[:0] = [v.page_id for v in reversed(victims)]
         self._set_pages_gauges()
-        return True
 
     # ------------------------------------------------------------------
     # Spill tier (ISSUE 11, engine/spill.py): reload = publish in reverse
     # ------------------------------------------------------------------
 
-    def _note_insert(self, node: PageNode) -> None:
-        """A node entered the tree (publish or reload): announce the chain
-        to the shared index, and retire any own arena entry — the fresh
-        device copy supersedes it (the exclusivity invariant check()
-        asserts)."""
+    def _note_insert(self, node: PageNode, key: tuple) -> None:
+        """A node entered the tree (publish or reload) under chain ``key``:
+        announce the chain to the shared index, and retire any own arena
+        entry, landed or pending (a pending one is cancelled: its bytes are
+        discarded when they arrive) — the fresh device copy supersedes it
+        (the exclusivity invariant check() asserts)."""
         if self.spill is None and self.shared_index is None:
             return
-        key = self.chain_key(node)
         if self.spill is not None:
             self.spill.drop(self.owner_id, key)
             self._set_spill_gauges()
@@ -705,6 +782,16 @@ class PrefixCache:
     def _set_spill_gauges(self) -> None:
         self.tel.spill_resident_pages.set(self.spill.depth())
         self.tel.spill_bytes.set(self.spill.resident_bytes)
+
+    def await_pending(self, tokens) -> None:
+        """Called by a request's own thread BEFORE it takes the scheduler's
+        lock to reload: wait (bounded; off every lock) for pages of this
+        prompt's prefixes that an eviction sent on their way to the arena a
+        moment ago, so that they reload instead of prefilling cold. The one
+        method of this class that runs without the scheduler's lock: it
+        reads no tree state."""
+        if self.spill is not None:
+            self.spill.wait_pending(tokens)
 
     def spill_depth(self) -> int:
         """Arena entries owned by this replica (the /readyz read)."""
@@ -749,7 +836,11 @@ class PrefixCache:
         spilled bytes, allocate a pool page (may itself evict+spill), run
         the caller's ``upload(page_id, arrays)`` device copy, and insert
         the node. ``pre(chain_key)`` is the scheduler's ``engine.spill``
-        chaos hook. ANY failure — arena miss, CRC drop, allocation dry,
+        chaos hook. A block whose bytes are still PENDING in the arena is a
+        miss here (nothing waits under the scheduler's lock; the caller
+        waited before it took the lock, :meth:`await_pending`): it
+        prefills cold, counted, and its publish cancels the pending entry.
+        ANY failure — arena miss, CRC drop, allocation dry,
         an upload raise, an injected fault — stops the reload cleanly:
         blocks already uploaded stay (they hold verified bytes), deeper
         blocks fall back to the cold prefill, pins taken for the walk are
@@ -762,16 +853,22 @@ class PrefixCache:
         if len(nodes) >= max_blocks:
             return 0
         node = nodes[-1] if nodes else self.root
-        # pin the growing chain exactly like publish: a mid-reload _alloc
-        # may evict, and the evictor must never detach the chain being
+        # pin the growing chain exactly like publish: a mid-reload
+        # eviction must never detach the chain being
         # rebuilt (or the just-walked parents)
         pinned: list[PageNode] = list(nodes)
         for nd in pinned:
             self._ref(nd)
         n_reloaded = 0
+        ids = self._token_ids(tokens, max_blocks * page)
+        # the victims of every block still to come, in order, picked in one
+        # walk when the free list first runs dry; each is evicted (and
+        # spilled) only when its block's turn comes, so the arena sees this
+        # reload's puts and takes in the order it always did
+        victims = None
         try:
             for i in range(len(nodes), max_blocks):
-                chain = tuple(int(t) for t in tokens[: (i + 1) * page])
+                chain = tuple(ids[: (i + 1) * page])
                 try:
                     if pre is not None:
                         pre(chain)
@@ -780,11 +877,15 @@ class PrefixCache:
                     # failure after it would permanently lose them — and
                     # a dry pool is likeliest exactly under the pinned
                     # pressure the spill tier exists for. The chain being
-                    # reloaded is not in the tree, so the eviction _alloc
+                    # reloaded is not in the tree, so the eviction this
                     # may trigger cannot touch it.
-                    pid = self._alloc()
-                    if pid is None:
+                    if not self.free:
+                        if victims is None:
+                            victims = iter(self._pick_victims(max_blocks - i))
+                        self._evict(list(itertools.islice(victims, 1)))
+                    if not self.free:
                         break  # everything pinned: no room to reload into
+                    pid = self.free.pop()
                     arrays = self.spill_take(chain)
                     if arrays is None:
                         self.free.append(pid)
@@ -809,11 +910,11 @@ class PrefixCache:
                         reloaded=n_reloaded, error=type(e).__name__,
                     )
                     break
-                key = tuple(tokens[i * page : (i + 1) * page])
+                key = tuple(ids[i * page : (i + 1) * page])
                 child = PageNode(key, pid, node)
                 node.children[key] = child
                 child.last_use = self._tick()
-                self._note_insert(child)
+                self._note_insert(child, chain)
                 self._ref(child)
                 pinned.append(child)
                 node = child

@@ -31,6 +31,10 @@ the pool/scheduler is built — tests/conftest or the CI step export it),
 and the rank table loads lazily from the same pyproject the analyzer
 reads, so the static rule, the witness and the docs can never drift.
 
+Beside the order, the witness sees a WAIT under a lock: code that is about
+to block on the device or on another thread says so with
+:func:`note_blocking`, and a witnessed lock held there is a violation.
+
 ``Condition.wait`` is handled faithfully: waiting releases the lock, so
 the witness pops its entries for the wait and re-pushes them on wakeup
 WITHOUT an order check (the wakeup re-acquire is wakeup-ordered — the
@@ -49,6 +53,7 @@ __all__ = [
     "make_condition",
     "make_lock",
     "make_rlock",
+    "note_blocking",
     "reset",
     "violations",
 ]
@@ -145,6 +150,27 @@ def _check_order(mode: str, name: str, rank: int, obj_id: int) -> None:
                 " declared hierarchy ([tool.dllama.analysis.locks])"
                 " requires strictly ascending ranks",
             )
+
+
+def note_blocking(what: str) -> None:
+    """Called where a thread is about to wait for the device or for another
+    thread (a host fetch, a wait on the spiller): with the witness armed, a
+    witnessed lock held here is a violation. The static LCK-002 sees such a
+    call only where it is lexically under the lock; this sees it behind a
+    callback too (the spill download sat behind ``page_fetch`` under
+    ``BatchScheduler._cond`` until ISSUE 38, and a trace found it)."""
+    mode = _active_mode()
+    if mode == "off":
+        return
+    held = _held()
+    if held:
+        held_name, held_rank, _ = held[-1]
+        _violate(
+            mode,
+            f"blocking call `{what}` while `{held_name}` (rank {held_rank})"
+            " is held — every thread that needs the lock stalls behind the"
+            " wait",
+        )
 
 
 class _WitnessLock:

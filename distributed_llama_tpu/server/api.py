@@ -1977,6 +1977,9 @@ def serve(args) -> None:
     if telemetry.is_enabled():
         print(f"Metrics:    http://127.0.0.1:{args.port}/metrics")
     server.serve_forever()
+    if state._spill_arena is not None:
+        # drained and shut down: join the arena's spiller thread
+        state._spill_arena.close()
 
 
 def main(argv=None) -> None:

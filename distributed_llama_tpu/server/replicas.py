@@ -250,14 +250,17 @@ class ReplicaPool:
         )
 
     def close(self) -> None:
-        """Stop supervision and the replicas' watchdogs (tests; a serving
-        pool lives for the process)."""
+        """Stop supervision, the replicas' watchdogs and the shared spill
+        arena's spiller thread (tests; a serving pool lives for the
+        process)."""
         with self._cond:
             self._closed = True
             self._cond.notify_all()
         for r in self.replicas:
             if r.scheduler is not None:
                 r.scheduler.close()
+        if self.spill_arena is not None:
+            self.spill_arena.close()
 
     # ------------------------------------------------------------------
     # Placement

@@ -541,6 +541,47 @@ class PrefixCacheInstruments:
         )
 
 
+class SpillArenaInstruments:
+    """What the shared host arena (engine/spill.py) counts itself: it is
+    one object for every replica, and its spiller thread moves some of
+    these with no scheduler's lock held."""
+
+    def __init__(self):
+        self.dropped = counter(
+            "dllama_prefix_spill_dropped_total",
+            "Spilled prefix pages LOST from the capacity ladder: LRU "
+            "overflow past the host/disk budgets, or a CRC mismatch "
+            "detected at reload (the entry is dropped, the block "
+            "prefills cold)",
+        )
+        self.pending = gauge(
+            "dllama_prefix_spill_pending_pages",
+            "Evicted pages entered in the arena whose bytes are still on "
+            "their way from the device (sliced under the scheduler's lock, "
+            "fetched and checksummed by the arena's spiller thread off it); "
+            "counted in dllama_prefix_spill_resident_pages and _bytes too",
+        )
+        self.skipped = counter(
+            "dllama_prefix_spill_skipped_total",
+            "Evicted pages never fetched from the device because the arena "
+            "could not have kept them: the victims of one publish put in "
+            "order overflow a host budget with no disk tier below it, so "
+            "the first of them would be dropped as the last arrive (each is "
+            "counted in spill_pages_total and spill_dropped_total as before)",
+        )
+        pending_reloads = counter(
+            "dllama_prefix_spill_pending_reloads_total",
+            "Reloads that met a page still on its way to the arena, by "
+            "outcome: waited (the request's own thread waited for the "
+            "transfer before it took the scheduler's lock), cold (still "
+            "pending under the lock, where nothing waits: the block "
+            "prefilled cold and its publish cancelled the pending entry)",
+            labelnames=("outcome",),
+        )
+        self.pending_waited = pending_reloads.labels(outcome="waited")
+        self.pending_cold = pending_reloads.labels(outcome="cold")
+
+
 def note_compile_cache_hit() -> None:
     """Count one persistent-compilation-cache hit (a compile served from
     the persistent cache directory instead of a fresh XLA build — the

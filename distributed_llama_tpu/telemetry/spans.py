@@ -30,6 +30,7 @@ SPAN_NAMES = (
     "batch_decode_fetch",
     "spec_verify_chunk",
     "prefix_spill_reload",
+    "prefix_spill_fetch",  # the arena's spiller thread (engine/spill.py)
     "prefix_publish",
     "state_snapshot",
     "state_restore",

@@ -8,6 +8,7 @@ clean under them. The seeded-inversion test is the discriminator — the
 witness that never fires is indistinguishable from no witness at all.
 """
 
+import os
 import threading
 import time
 
@@ -290,3 +291,136 @@ def test_replica_kill_storm_runs_clean_under_witness(tmp_path):
         faults.clear()
         lockcheck.configure()
         lockcheck.reset()
+
+
+# ----------------------------------------------------------------------
+# A WAIT under a lock (ISSUE 38): the spill download sat under
+# BatchScheduler._cond behind the PrefixCache's page_fetch callback, where
+# the lexical LCK-002 could not see it; a trace found it. Both halves now
+# have a test: the witness sees a blocking call behind any callback, and
+# the static rule sees the eviction's two entry points by name.
+# ----------------------------------------------------------------------
+
+
+def test_note_blocking_reports_a_wait_under_a_witnessed_lock(witness):
+    cond = lockcheck.make_condition("Sched._cond")
+    lockcheck.note_blocking("device_get")  # nothing held: fine
+    assert lockcheck.violations() == []
+    with cond:
+        with pytest.raises(LockOrderViolation, match="blocking call `device_get`"):
+            lockcheck.note_blocking("device_get")
+    assert len(lockcheck.violations()) == 1
+    lockcheck.configure(mode="off")
+    with cond:
+        lockcheck.note_blocking("device_get")  # zero-cost and silent when off
+
+
+def _cache_under(fetch, land):
+    import numpy as np
+
+    from distributed_llama_tpu.engine.prefix_cache import PrefixCache
+    from distributed_llama_tpu.engine.spill import HostArena
+
+    arena = HostArena(1 << 20)
+    cache = PrefixCache(2, 4, page_bytes=12, spill=arena, page_fetch=fetch, page_land=land)
+    return cache, arena, (lambda base: np.arange(base, base + 8))
+
+
+def _land(handles):
+    import numpy as np
+
+    lockcheck.note_blocking("page_land")  # what BatchScheduler._land_pages says
+    return [[np.full(3, h, np.float32)] for h in handles]
+
+
+def test_a_blocking_page_fetch_under_the_scheduler_lock_is_reported(witness):
+    """The fault as it was: the eviction hook fetches the bytes itself, so
+    the wait happens under the scheduler's lock, behind the callback."""
+    cond = lockcheck.make_condition("Sched._cond")
+    cache, arena, prompt = _cache_under(lambda pids: _land(pids), _land)
+    with cond:
+        cache.publish(prompt(1), 8, [])  # fills the pool: no eviction yet
+        assert lockcheck.violations() == []
+        # the evictor treats a failed spill as a page that vanishes, so the
+        # violation does not surface as a raise: the ledger has it
+        cache.publish(prompt(101), 8, [])
+    assert any(
+        "`page_land` while `Sched._cond`" in v for v in lockcheck.violations()
+    ), lockcheck.violations()
+    arena.close()
+
+
+def test_the_shipped_eviction_waits_on_the_spiller_thread_only(witness):
+    """The shipped split: slices enqueued under the lock, the blocking land
+    on the arena's spiller thread, which holds no lock: a clean ledger, and
+    the pages land."""
+    lockcheck.configure(ranks=RANKS, mode="warn")  # a spiller's violation must not die silently
+    cond = lockcheck.make_condition("Sched._cond")
+    cache, arena, prompt = _cache_under(lambda pids: list(pids), _land)
+    with cond:
+        cache.publish(prompt(1), 8, [])
+        cache.publish(prompt(101), 8, [])  # evicts both pages
+        assert arena.pending_pages() + arena.depth() >= 2
+    assert arena.flush(10)
+    assert arena.depth(0) == 2 and lockcheck.violations() == []
+    arena.close()
+
+
+def _lck002(tmp_path, source):
+    import textwrap
+
+    from distributed_llama_tpu.analysis import all_rules, analyze
+    from distributed_llama_tpu.analysis.config import load_config
+
+    cfg = load_config(start=os.path.dirname(os.path.abspath(lockcheck.__file__)))
+    cfg.baseline = ""
+    f = tmp_path / "sched.py"
+    f.write_text(textwrap.dedent(source))
+    findings, _ = analyze([str(f)], cfg, rules=all_rules({"LCK-002"}))
+    return [x.format() for x in findings]
+
+
+def test_the_lint_names_the_evictions_fetch_entry_points(tmp_path):
+    """Under the repo's own config: the blocking half called under the lock
+    is a finding, a wait inside the enqueue-only half (a ``_locked`` name:
+    its body is a lock-held region) is a finding, and the shipped shape is
+    clean."""
+    bad = _lck002(tmp_path, """
+        import jax
+
+        class Sched:
+            def _publish_row(self, pids):
+                with self._cond:
+                    return self._land_pages(self._slice_pages_locked(pids))
+
+            def _slice_pages_locked(self, pids):
+                return jax.device_get([self.pool[p] for p in pids])
+
+            def _match(self, tokens):
+                with self._cond:
+                    self.prefix.await_pending(tokens)
+        """)
+    assert len(bad) == 3, bad
+    assert any("_land_pages" in x for x in bad) and any("jax.device_get" in x for x in bad)
+    assert any("await_pending" in x for x in bad)
+    good = _lck002(tmp_path, """
+        import jax
+
+        class Sched:
+            def _publish_row(self, pids):
+                with self._cond:
+                    self.arena.put_pending(self._slice_pages_locked(pids), self._land_pages)
+
+            def _slice_pages_locked(self, pids):
+                return [self.pool[p] for p in pids]
+
+            @staticmethod
+            def _land_pages(handles):
+                return jax.device_get(handles)
+
+            def _match(self, tokens):
+                self.prefix.await_pending(tokens)
+                with self._cond:
+                    return self.prefix.match(tokens)
+        """)
+    assert good == []
