@@ -127,6 +127,16 @@ def _page_bucket(n: int) -> int:
     return next_pow2(n)
 
 
+def eva_tail_pages(rows: int, window_pages: int) -> int:
+    """Pages of an EVA arch's window pool (keys and values of whole pages),
+    the rule stated once: a live prompt ends anywhere in its aligned window of
+    ``window_pages`` pages, so its tail is half a window in the mean and a
+    whole one at worst; the pool holds the mean for every row and one whole
+    window beside them. A tail that has aged out costs a hit its last pages:
+    it resumes at the window's start, from summaries alone."""
+    return rows * (window_pages // 2) + window_pages
+
+
 def _padded_pages(ids: list[int], blocks: list[int], drop: int):
     """``(page ids, source blocks)`` of a publish as int32 arrays padded to
     their bucket; a padded entry names page ``drop``, past the pool, and its
@@ -181,47 +191,61 @@ def _upload_page(pool, pid, page_kvs):
     ]
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _publish_pages(page: int, slab, pool, page_ids, src_page, row):
-    """Copy slab row ``row``'s page slots ``src_page`` into pool pages
-    ``page_ids`` across every layer (the post-prefill publish). The donated
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("base",), donate_argnums=(2,))
+def _publish_pages(page: int, slab, pool, page_ids, src_page, row, base: int = 0):
+    """Copy slab row ``row``'s blocks ``src_page`` into pool pages
+    ``page_ids`` across every layer (the post-prefill publish;
+    ``kvc.BlockSlots(page, base=base)`` says where a block sits). The donated
     pool aliases in place; the slab is read-only here (``leaf[0]``/
     ``leaf[1]`` are contiguous views of the fused leaf)."""
     return [
         None if half is None else (
-            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page),
-            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page),
+            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page, base=base),
+            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page, base=base),
         )
         for leaf, half in zip(slab, pool)
     ]
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def _publish_window_pages(page: int, slab, wpool, page_ids, src_page, row):
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("ring",), donate_argnums=(2,))
+def _publish_window_pages(page: int, slab, wpool, page_ids, src_page, row, *, ring: int):
     """:func:`_publish_pages` for the window layers: slab row ``row``'s blocks
-    ``src_page``, read out of the rings at their positions' slots, into pages
-    ``page_ids`` of the window layers' pool. ``wpool`` mirrors the slab's
-    layer list (None for a layer of another kind). Only the pool is donated."""
+    ``src_page``, read out of the rings at their positions' slots
+    (``kvc.BlockSlots(page, ring)``), into pages ``page_ids`` of the window
+    pool. ``wpool`` mirrors the slab's layer list (None for a layer of
+    another kind). Only the pool is donated."""
     return [
         None if half is None else (
-            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page, ring=True),
-            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page, ring=True),
+            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page, ring=ring),
+            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page, ring=ring),
         )
         for leaf, half in zip(slab, wpool)
     ]
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-def _restore_window_tail(page: int, slab, wpool, page_ids, dst_page, row):
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("ring",), donate_argnums=(1,))
+def _restore_window_tail(page: int, slab, wpool, page_ids, dst_page, row, *, ring: int):
     """:func:`_publish_window_pages` in reverse: pages ``page_ids`` of the
-    window layers' pool into the slots of row ``row``'s rings where blocks
+    window pool into the slots of row ``row``'s rings where blocks
     ``dst_page`` sit (a prefix hit resumes behind them). Only the slab is
     donated."""
     return [
         leaf if half is None else kvc.restore_row_pages(
-            leaf, half[0], half[1], row, dst_page, page_ids, page
+            leaf, half[0], half[1], row, dst_page, page_ids, page, ring=ring
         )
         for leaf, half in zip(slab, wpool)
+    ]
+
+
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("base",), donate_argnums=(1,))
+def _restore_summaries(page: int, slab, pool, page_ids, dst_page, row, *, base: int):
+    """:func:`_publish_pages` in reverse, for an EVA arch: the summaries of
+    blocks ``dst_page`` (``page`` of them a block) out of pool pages
+    ``page_ids`` into row ``row``, behind its window store (a hit copies them;
+    the pool is not read in place). Only the slab is donated."""
+    return [
+        kvc.restore_row_pages(leaf, half[0], half[1], row, dst_page, page_ids, page, base=base)
+        for leaf, half in zip(slab, pool)
     ]
 
 
@@ -752,11 +776,12 @@ class BatchScheduler:
         self.prefill_chunk = max(
             0, 0 if prefill_chunk is None else int(prefill_chunk)
         )
-        if engine.cfg.has_window:
+        if engine.cfg.piece_limit:
             # a window layer's ring takes a prompt in pieces that fit it
-            # beside the window (a monolithic dispatch does not)
-            self.prefill_chunk = min(self.prefill_chunk or engine.cfg.ring_piece,
-                                     engine.cfg.ring_piece)
+            # beside the window, an EVA layer's window store in pieces of at
+            # most a window (a monolithic dispatch does neither)
+            self.prefill_chunk = min(self.prefill_chunk or engine.cfg.piece_limit,
+                                     engine.cfg.piece_limit)
         # radix-tree prefix cache over pool pages (ISSUE 4 tentpole, ISSUE 7
         # zero-copy): an admission prefill binds published KV pages to the
         # row's page table (attention reads them straight out of the pool)
@@ -772,6 +797,15 @@ class BatchScheduler:
             # the server's backend-fallback handler and silently cost the
             # whole one-weight-read-per-step serving path)
             page_ok = 1 <= page_size <= engine.cfg.seq_len
+            page_fault = f"page size {page_size} must be in [1, seq_len {engine.cfg.seq_len}]"
+            if engine.cfg.has_eva and page_ok and (
+                page_size % engine.cfg.eva_chunk or engine.cfg.window % page_size
+            ):
+                # a page of summaries is whole chunks, a window whole pages
+                page_ok, page_fault = False, (
+                    f"page size {page_size} must be whole chunks of {engine.cfg.eva_chunk} "
+                    f"positions and divide the window of {engine.cfg.window} (EVA attention)"
+                )
             slab_pages = n_rows * -(-engine.cfg.seq_len // page_size) if page_ok else 0
             if kv_pages is None and page_ok:
                 # default HBM budget: with zero-copy aliasing the pool is
@@ -783,10 +817,7 @@ class BatchScheduler:
                     slab_pages // 4, -(-engine.cfg.seq_len // page_size)
                 )
             if not page_ok:
-                print(
-                    f"⚠️ prefix cache disabled: page size {page_size} must "
-                    f"be in [1, seq_len {engine.cfg.seq_len}]"
-                )
+                print(f"⚠️ prefix cache disabled: {page_fault}")
             elif kv_pages < 1:
                 print("⚠️ prefix cache disabled: --kv-pages 0")
             else:
@@ -837,7 +868,8 @@ class BatchScheduler:
                     max(2, kv_pages // SNAPSHOT_PAGES) if engine.cfg.is_recurrent else 0
                 )
                 window_tail = self._window_keep = 0
-                if engine.cfg.has_window:
+                cfg = engine.cfg
+                if cfg.has_window:
                     # the window layers' pool: a hit needs the ``window_tail``
                     # pages before its end; a publish can read the row's last
                     # ``_window_keep`` whole pages back out of its rings (what
@@ -846,12 +878,27 @@ class BatchScheduler:
                     # head and differs near its end hits. The pool holds that
                     # tail and a hit's own for every row: it follows
                     # --parallel, not --kv-pages
-                    cfg = engine.cfg
                     window_tail = -(-cfg.window // page_size)
                     self._window_keep = max(
                         0, (cfg.ring_len - _prefill_bucket(self.prefill_chunk)) // page_size - 1
                     )
                 window_pages = n_rows * (self._window_keep + window_tail)
+                window_align = 0
+                # where a block sits in a row's leaf, for the copies between
+                # the row and the window pool (a window layer's ring)
+                self._wslots = kvc.BlockSlots(page_size, ring=cfg.ring_len)
+                if cfg.has_eva:
+                    # an EVA arch's pools: a page under --kv-pages holds a
+                    # block's SUMMARIES (what a later prompt needs of every
+                    # block it shares); the keys and values, which it needs
+                    # only from its last window's start to its end, go to the
+                    # window pool, whose size follows --parallel
+                    window_align = cfg.window // page_size
+                    window_pages = eva_tail_pages(n_rows, window_align)
+                    self._wslots, self._sslots = (
+                        kvc.eva_block_slots(kind, page_size, cfg.window, cfg.eva_chunk)
+                        for kind in ("window", "summary")
+                    )
                 self._prefix = PrefixCache(
                     kv_pages, page_size,
                     page_bytes=llama.page_pool_bytes(
@@ -864,6 +911,7 @@ class BatchScheduler:
                     shared_index=shared_index,
                     snap_slots=snap_slots,
                     window_pages=window_pages, window_tail=window_tail,
+                    window_align=window_align,
                 )
                 if tp_engine is None:
                     self._pool = llama.init_page_pool(
@@ -976,7 +1024,7 @@ class BatchScheduler:
         # (device scalar, tokens) of prefill chunks whose held-expert sums are
         # not read yet
         self._moe_pending: list = []
-        if engine.cfg.has_window:
+        if engine.cfg.kv_read_kinds:
             for kind, nbytes in llama.kv_slab_bytes(engine.cfg, n_rows, engine.cache_dtype).items():
                 engine._tel.kv_slab_bytes.labels(kind=kind).set(nbytes)
             # bytes one position's keys and values take in one layer: what the
@@ -984,6 +1032,7 @@ class BatchScheduler:
             self._kv_position_bytes = llama.page_pool_bytes(
                 engine.cfg, 1, engine.cache_dtype, layers=1
             )
+        if engine.cfg.has_window:
             if self._wpool is not None:
                 for kind, pages in (("full", kv_pages), ("window", self._prefix.window_pages)):
                     engine._tel.kv_pool_bytes.labels(kind=kind).set(
@@ -995,14 +1044,32 @@ class BatchScheduler:
                 # holds zeros, and row 0 starts over before it reads a slot)
                 tail = np.zeros(self._prefix.window_tail, np.int32)
                 self._slab = _restore_window_tail(
-                    page_size, self._slab, self._wpool, tail, tail, jnp.int32(0)
+                    page_size, self._slab, self._wpool, tail, tail, jnp.int32(0),
+                    ring=self._wslots.ring,
                 )
                 for bucket in sorted({_page_bucket(n) for n in range(1, self._window_keep + 1)}):
                     ids = np.full(bucket, self._prefix.window_pages, np.int32)
                     self._wpool = _publish_window_pages(
                         page_size, self._slab, self._wpool, ids, np.zeros(bucket, np.int32),
-                        jnp.int32(0),
+                        jnp.int32(0), ring=self._wslots.ring,
                     )
+        if engine.cfg.has_eva and self._wpool is not None:
+            cfg = engine.cfg
+            per_page = page_size * self._kv_position_bytes * cfg.n_layers
+            engine._tel.kv_pool_bytes.labels(kind="eva_summary").set(
+                kv_pages * per_page // cfg.eva_chunk)
+            engine._tel.kv_pool_bytes.labels(kind="eva_window").set(
+                self._prefix.window_pages * per_page)
+            # the programs of a hit and of a publish's tail are built now,
+            # not inside a measured window (row 0 starts over before it
+            # reads a slot; ids past a pool drop)
+            self._slab = self._eva_restore(self._slab, 0, [0], [0], 0)
+            for bucket in sorted({_page_bucket(n) for n in range(1, self._prefix.window_align)}):
+                self._wpool = _publish_window_pages(
+                    page_size, self._slab, self._wpool,
+                    np.full(bucket, self._prefix.window_pages, np.int32),
+                    np.zeros(bucket, np.int32), jnp.int32(0), ring=self._wslots.ring,
+                )
         self._snaps = None
         if engine.cfg.is_recurrent:
             engine._tel.recurrent_state_bytes.set(
@@ -1379,6 +1446,7 @@ class BatchScheduler:
                             engine.params, jnp.asarray(padded), self._slab,
                             stream.row, stream.pos, c,
                         )
+                    self._note_summaries(stream.pos, c)
                     stream.pos += c
                     self._last_piece = logits
                     if stream.pos == cut:
@@ -1403,6 +1471,14 @@ class BatchScheduler:
     # against — releasing pins mid-flight is therefore safe: any eviction/
     # republish only manifests as a LATER device program).
     # ------------------------------------------------------------------
+
+    def _note_summaries(self, pos: int, n: int) -> None:
+        """Count the chunks that ``n`` positions written from ``pos`` complete
+        (an EVA arch: the program that writes a chunk's last position pools
+        and writes its summary, in every layer)."""
+        c = self.engine.cfg.eva_chunk
+        if c:
+            self.engine._tel.eva_summaries_written.inc((pos + n) // c - pos // c)
 
     def _note_prefill_held_locked(self, held, n_tokens: int) -> None:
         """Keep a prefill chunk's device sum of held-expert choices for the
@@ -1597,7 +1673,18 @@ class BatchScheduler:
                         jnp.int32(chain[-1].snap),
                     )
                 prefix.tel.snapshots_restored.inc()
-            if self._wpool is not None:
+            if self.engine.cfg.has_eva:
+                # copied into the row, not aliased: every block's summaries,
+                # and the keys and values of the hit's own window
+                first = prefix.tail_start(len(chain))
+                with self.engine._tel.span(
+                    "window_tail_restore", batch_row=stream.row, pos=stream.pos
+                ):
+                    self._slab = self._eva_restore(
+                        self._slab, stream.row, [nd.page_id for nd in chain],
+                        [nd.wpage for nd in chain[first:]], first,
+                    )
+            elif self._wpool is not None:
                 # the window layers' keys and values of the positions before
                 # the hit's end, into the row's rings: those layers resume
                 # from there as the full ones resume from the pages
@@ -1615,7 +1702,7 @@ class BatchScheduler:
                 ):
                     self._slab = _restore_window_tail(
                         prefix.page, self._slab, self._wpool, pages, blocks,
-                        jnp.int32(stream.row),
+                        jnp.int32(stream.row), ring=self._wslots.ring,
                     )
         return chain
 
@@ -1651,7 +1738,13 @@ class BatchScheduler:
                     "prefix_publish", pages=len(new_ids), batch_row=stream.row
                 ):
                     try:
-                        if self.engine._tp_engine is None:
+                        if self.engine.cfg.has_eva:
+                            # the pool's page of a block: its summaries
+                            self._pool = _publish_pages(
+                                self._sslots.page, self._slab, self._pool, jnp.asarray(ids),
+                                jnp.asarray(src), jnp.int32(stream.row), base=self._sslots.base,
+                            )
+                        elif self.engine._tp_engine is None:
                             self._pool = _publish_pages(
                                 page, self._slab, self._pool, jnp.asarray(ids),
                                 jnp.asarray(src), jnp.int32(stream.row),
@@ -1673,6 +1766,31 @@ class BatchScheduler:
                             raise
                         print(f"⚠️ prefix publish failed; pages unwound: {e}")
 
+    def _eva_restore(self, slab, row: int, pages: list[int], tail: list[int], first: int):
+        """The (donated) slab with an EVA hit copied into its row ``row``: the
+        summaries of blocks 0 .. len(pages) - 1 out of pool pages ``pages``,
+        and the keys and values of blocks ``first ..`` (the hit's own window
+        up to its end) out of window-pool pages ``tail``. Each copy has ONE
+        shape, the most a row can hold, padded with its first entry (a block
+        written twice reads the same)."""
+
+        def padded(ids: list[int], start: int, n: int):
+            blocks = list(range(start, start + len(ids)))
+            return (np.asarray([ids[0]] * (n - len(ids)) + ids, np.int32),
+                    np.asarray([start] * (n - len(ids)) + blocks, np.int32))
+
+        slab = _restore_summaries(
+            self._sslots.page, slab, self._pool, *padded(pages, 0, self._n_table),
+            jnp.int32(row), base=self._sslots.base,
+        )
+        if tail:
+            slab = _restore_window_tail(
+                self._wslots.page, slab, self._wpool,
+                *padded(tail, first, self._prefix.window_align), jnp.int32(row),
+                ring=self._wslots.ring,
+            )
+        return slab
+
     def _publish_window_tail_locked(self, stream: BatchStream, tokens: np.ndarray, hit: int) -> None:
         """Copy the window layers' keys and values of the prompt's last whole
         pages out of the row's rings into the window layers' pool (cond held;
@@ -1683,7 +1801,12 @@ class BatchScheduler:
         A failed copy leaves pages of garbage attached: they are detached."""
         prefix = self._prefix
         n_blocks = tokens.shape[0] // prefix.page
-        first = max(n_blocks - self._window_keep, hit - prefix.window_tail)
+        if prefix.window_align:
+            # an EVA row's window store holds its current window whole (pad
+            # rows write nothing; a hit restored what lay before it)
+            first = prefix.tail_start(n_blocks)
+        else:
+            first = max(n_blocks - self._window_keep, hit - prefix.window_tail)
         ids, blocks = prefix.attach_window_pages(tokens, tokens.shape[0], first)
         if not ids:
             return
@@ -1691,7 +1814,8 @@ class BatchScheduler:
         with self.engine._tel.span("window_tail_publish", pages=len(ids), batch_row=stream.row):
             try:
                 self._wpool = _publish_window_pages(
-                    prefix.page, self._slab, self._wpool, padded, src, jnp.int32(stream.row)
+                    prefix.page, self._slab, self._wpool, padded, src, jnp.int32(stream.row),
+                    ring=self._wslots.ring,
                 )
             except BaseException as e:
                 prefix.detach_window_pages(tokens, blocks)
@@ -2340,6 +2464,7 @@ class BatchScheduler:
                 # the program left in the carry (no fetch, no per-row slice
                 # on the critical path); its coins re-key from (seed,
                 # position) — nothing else carries over
+                self._note_summaries(s.pos, self.chunk)
                 s.pos += self.chunk
         self._decode_built.add(bucket)
         self._note_dispatched(bucket, len(joined), self.chunk)
@@ -2619,10 +2744,10 @@ class BatchScheduler:
                 if tel.enabled:
                     self._count_moe(int(held.sum()), n_active * self.chunk, self.chunk)
                     self._count_prefill_held()
-            if engine.cfg.has_window and extra and tel.enabled:
+            if engine.cfg.kv_read_kinds and extra and tel.enabled:
                 # ... and the cache positions each row's layers read, by kind
-                tel.kv_read_full.inc(int(extra[0].sum()) * self._kv_position_bytes)
-                tel.kv_read_window.inc(int(extra[1].sum()) * self._kv_position_bytes)
+                for kind, row in zip(engine.cfg.kv_read_kinds, extra):
+                    tel.kv_read[kind].inc(int(row.sum()) * self._kv_position_bytes)
             with self._cond:
                 if self._sdc_logits_pending > 0:
                     # engine.sdc message=logits: shift every token column

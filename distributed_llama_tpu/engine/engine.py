@@ -191,9 +191,10 @@ class EngineStream:
             raise ValueError("empty token batch: at least one token required")
         if self.pos + n > engine.cfg.seq_len:
             raise ValueError(f"context overflow: pos {self.pos} + {n} > {engine.cfg.seq_len}")
-        piece = engine.cfg.ring_piece if engine.cfg.has_window else n
+        piece = engine.cfg.piece_limit or n
         if n > piece:
-            # a window layer's ring takes a prompt in pieces that fit it beside the window
+            # a window layer's ring takes a prompt in pieces that fit it beside
+            # the window, an EVA layer's window store in pieces of at most a window
             return jnp.concatenate([
                 self._forward_device(tokens[off : off + piece])[: min(piece, n - off)]
                 for off in range(0, n, piece)

@@ -197,6 +197,7 @@ class PrefixCache:
         spill=None, page_fetch=None, page_land=None, owner_id: int = 0,
         shared_index=None,
         snap_slots: int = 0, window_pages: int = 0, window_tail: int = 0,
+        window_align: int = 0,
     ):
         if n_pages < 1:
             raise ValueError(f"need at least one pool page, got {n_pages}")
@@ -237,9 +238,12 @@ class PrefixCache:
         # ``window_tail`` blocks before it and nothing older, so only the last
         # blocks of a published prompt get a page here, the pages have their
         # own recency order (:meth:`attach_window_pages`), and the pool's size does
-        # not follow ``n_pages``
+        # not follow ``n_pages``. ``window_align`` (an arch with EVA layers):
+        # windows are ALIGNED blocks of that many pages, and a hit needs the
+        # pages from its window's start to its end, none at a window's start
         self.window_pages = int(window_pages)
         self.window_tail = int(window_tail)
+        self.window_align = int(window_align)
         self.wfree: list[int] = list(range(self.window_pages))
         self.root = PageNode(None, -1, None)
         self._clock = 0
@@ -420,20 +424,20 @@ class PrefixCache:
         where the chain ends, so the chain stops at the deepest matched
         block that has a snapshot and the matched pages past it are
         dropped (none has one: a miss). With a window pool the row needs the
-        window layers' pages of the ``window_tail`` blocks before the chain's
-        end: the chain stops at the deepest block where all of them are
-        still kept (a shorter hit, or a miss; counted by outcome), and those
-        become the most recently used."""
+        window layers' pages of the blocks before the chain's end
+        (:meth:`tail_start`): the chain stops at the deepest block where all
+        of them are still kept (a shorter hit, or a miss; counted by
+        outcome), and those become the most recently used."""
         page = self.page
         chain = self.walk(tokens)
         if resumable:
             deepest = max((i for i, nd in enumerate(chain) if nd.snap is not None), default=-1)
             chain = chain[: deepest + 1]
         t = self._tick()
-        if self.window_tail and chain:
+        if (self.window_tail or self.window_align) and chain:
             walked = len(chain)
             chain = chain[: self._deepest_with_tail(chain)]
-            for nd in chain[-self.window_tail:]:
+            for nd in chain[self.tail_start(len(chain)):]:
                 nd.w_use = t
             (self.tel.window_tail_hit if len(chain) == walked else
              self.tel.window_tail_shortened if chain else self.tel.window_tail_miss).inc()
@@ -452,15 +456,25 @@ class PrefixCache:
         self._set_pinned_gauge()
         return chain
 
+    def tail_start(self, b: int) -> int:
+        """The first block whose window page a hit that ends where block ``b``
+        begins needs (it needs those up to ``b - 1``): the ``window_tail``
+        blocks before ``b`` for a sliding window, the blocks from the start
+        of ``b``'s ALIGNED window for an EVA arch (none where ``b`` starts
+        one: the summaries, which every block of the chain has, are enough)."""
+        if self.window_align:
+            return b // self.window_align * self.window_align
+        return max(0, b - self.window_tail)
+
     def _deepest_with_tail(self, chain: list[PageNode]) -> int:
-        """The largest ``b <= len(chain)`` such that the ``window_tail`` blocks
-        before block ``b`` (fewer at the row's start) all have their window
-        page; 0 when there is none."""
+        """The largest ``b <= len(chain)`` such that the blocks
+        ``tail_start(b) .. b - 1`` all have their window page; 0 when there
+        is none."""
         run = 0  # blocks with a window page, counted back from block b
         best = 0
         for b, nd in enumerate(chain, start=1):
             run = run + 1 if nd.wpage is not None else 0
-            if run >= min(self.window_tail, b):
+            if run >= b - self.tail_start(b):
                 best = b
         return best
 

@@ -16,7 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_llama_tpu.formats.model_file import ArchType, ModelFileReader, ModelSpec
+from distributed_llama_tpu.formats.model_file import (
+    ArchFlags,
+    ArchType,
+    ModelFileReader,
+    ModelSpec,
+)
 from distributed_llama_tpu.models.config import LlamaConfig, config_from_spec
 from distributed_llama_tpu.models.rope import build_rope_table
 
@@ -316,6 +321,13 @@ def load_params(
     def f32(name: str) -> np.ndarray:
         return reader.tensor(name).astype(np.float32)
 
+    def norm(name: str) -> np.ndarray:
+        """A block norm's weight as the forward multiplies by it: ONE PLUS the
+        stored weight where the file says its norms carry a unit offset
+        (``ArchFlags.NORM_UNIT_OFFSET``), added here once so that every norm
+        of the forward stays the one norm."""
+        return 1.0 + f32(name) if cfg.has(ArchFlags.NORM_UNIT_OFFSET) else f32(name)
+
     def hybrid_layer(l: int) -> dict:
         """One ``ArchType.SOLAR_OPEN2`` layer: every input projection of a
         mixer as ONE matrix (``qkvg``: q|k|v|gate of a softmax layer;
@@ -338,8 +350,8 @@ def load_params(
             }
         lp["wo"] = weight(p + "wo")
         lp.update(held_experts(p))
-        lp["rms_att"] = f32(p + "rms_att")
-        lp["rms_ffn"] = f32(p + "rms_ffn")
+        lp["rms_att"] = norm(p + "rms_att")
+        lp["rms_ffn"] = norm(p + "rms_ffn")
         return lp
 
     def held_experts(p: str) -> dict:
@@ -368,7 +380,7 @@ def load_params(
         p = f"layers.{l}."
         lp = {"qkv": fused([p + "q", p + "k", p + "v"]), "q_norm": f32(p + "q_norm"),
               "k_norm": f32(p + "k_norm"), "wo": weight(p + "wo"),
-              "rms_att": f32(p + "rms_att"), "rms_ffn": f32(p + "rms_ffn")}
+              "rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")}
         if cfg.layer_kind(l)[1] == "dense":
             lp["gate_up"] = fused([p + "gate", p + "up"])
             lp["down"] = weight(p + "down")
@@ -376,19 +388,42 @@ def load_params(
             lp.update(held_experts(p))
         return lp
 
-    if cfg.arch in (ArchType.SOLAR_OPEN2, ArchType.EXAONE_MOE):
+    def eva_layer(l: int) -> dict:
+        """One ``ArchType.EVABYTE`` layer: q|k|v and gate|up as one matrix
+        each, and the summariser's two vectors a head in float32."""
+        p = f"layers.{l}."
+        return {"qkv": fused([p + "q", p + "k", p + "v"]), "wo": weight(p + "wo"),
+                "eva_phi": f32(p + "eva_phi"), "eva_mu": f32(p + "eva_mu"),
+                "gate_up": fused([p + "gate", p + "up"]), "down": weight(p + "down"),
+                "rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")}
+
+    def next_token_head():
+        """Rows 0 .. vocab_size - 1 of an output matrix of several prediction
+        heads: the next token's. The others (self-speculation over the tokens
+        after it) are in the file and not served: a step yields one token."""
+        if quantized:
+            from distributed_llama_tpu.ops.q40 import pack_q40_raw
+
+            return pack_q40_raw(reader.raw_rows("wcls", 0, cfg.vocab_size), (cfg.vocab_size, cfg.dim))
+        return cast(_t(reader.tensor_rows("wcls", 0, cfg.vocab_size), np.float32))
+
+    if cfg.arch in (ArchType.SOLAR_OPEN2, ArchType.EXAONE_MOE, ArchType.EVABYTE):
         from distributed_llama_tpu.models.llama import refuse_recurrent
 
         if tp > 1:
             refuse_recurrent(cfg, f"tensor parallelism (--tp {tp})")
-        layer = hybrid_layer if cfg.arch == ArchType.SOLAR_OPEN2 else window_layer
+        layer = {ArchType.SOLAR_OPEN2: hybrid_layer, ArchType.EXAONE_MOE: window_layer,
+                 ArchType.EVABYTE: eva_layer}[cfg.arch]
         return {
             "embedding": reader.tensor("embedding").astype(np.float32),
             "layers": [layer(l) for l in range(cfg.n_layers)],
-            "rms_final": reader.tensor("rms_final").astype(np.float32),
-            "wcls": weight("wcls"),
+            "rms_final": norm("rms_final"),
+            "wcls": next_token_head() if cfg.arch == ArchType.EVABYTE else weight("wcls"),
             "rope_table": build_rope_table(cfg),
         }
+    if cfg.has(ArchFlags.NORM_UNIT_OFFSET):
+        raise ValueError("norms with a unit offset (ArchFlags.NORM_UNIT_OFFSET) are read for the "
+                         "archs whose layers load one by one, not for this one")
 
     layers: dict[str, list] = {}
 

@@ -51,6 +51,20 @@ them; its files carry the keys of ``_ARCH_KEYS[ArchType.EXAONE_MOE]``:
     per HELD expert: up, gate [moe_hidden, dim], down [dim, moe_hidden],
     shared.up, shared.gate, shared.down (width n_shared * moe_hidden)
 
+``ArchType.EVABYTE`` (no reference counterpart; :func:`_eva_layer`) is a dense
+byte-level model whose every layer mixes by EVA attention: a query reads the
+keys of its own ALIGNED window of ``window`` positions exactly and of every
+earlier window one learned summary per ``eva_chunk`` positions, in one
+softmax. Its norms multiply by ``1 + weight`` (``ArchFlags.NORM_UNIT_OFFSET``)
+and its output matrix holds ``n_pred_heads`` heads of ``vocab_size`` rows, of
+which rows 0 .. vocab_size - 1 are the next-token head; its files carry the
+keys of ``_ARCH_KEYS[ArchType.EVABYTE]``:
+
+  every layer: rms_att, rms_ffn, q, k, v [H*hd, dim], eva_phi (F32) [H, hd]
+    (what a chunk's keys are pooled against), eva_mu (F32) [H, hd] (added to
+    the pooled key), wo, gate, down, up
+  wcls [n_pred_heads * vocab_size, dim]
+
 All matrices are row-major [d_out, d_in] — a matmul computes y = W @ x.
 Q/K projections are stored pre-permuted for interleaved-pair rope
 (reference: converter/convert-hf.py:12-15).
@@ -83,6 +97,10 @@ class ArchType(enum.IntEnum):
     # window and full attention layers in one model, q/k norm, leading dense
     # layers, a share of the routed experts and a shared one; not a reference arch
     EXAONE_MOE = 0xABCD04
+    # every layer EVA attention (exact keys inside an aligned window, learned
+    # summaries of the windows before it), norms with a unit offset, several
+    # prediction heads on the output matrix; not a reference arch
+    EVABYTE = 0xABCD05
 
 
 class HiddenAct(enum.IntEnum):
@@ -139,6 +157,8 @@ class HeaderKey(enum.IntEnum):
     WINDOW_PERIOD = 31  # layer l is a FULL layer where l % period == period - 1, else window
     FIRST_DENSE = 32  # leading layers whose FFN is dense (hidden_dim wide); experts after them
     ROUTED_SCALE_MILLI = 33  # the chosen experts' weights are multiplied by this / 1000
+    EVA_CHUNK = 34  # positions one summary of an EVA layer stands for (WINDOW: its aligned window)
+    PRED_HEADS = 35  # heads of vocab_size rows on the output matrix; the first is the next token's
 
 
 class ArchFlags(enum.IntFlag):
@@ -151,6 +171,7 @@ class ArchFlags(enum.IntFlag):
     SIGMOID_ROUTER = 16  # router score = sigmoid, chosen with a selection bias
     QK_NORM = 32  # every q and k head is RMS-normalised with a learned weight
     ROPE_WINDOW_ONLY = 64  # of the layers, only the window layers rotate q and k
+    NORM_UNIT_OFFSET = 128  # an RMS norm multiplies by 1 + its stored weight
 
 
 _EXTRA_KEYS = {
@@ -178,9 +199,16 @@ _WINDOW_KEYS = {
     HeaderKey.FIRST_DENSE: "first_dense",
     HeaderKey.ROUTED_SCALE_MILLI: "routed_scale_milli",
 }
+_EVA_KEYS = {
+    HeaderKey.FLAGS: "flags",
+    HeaderKey.WINDOW: "window",
+    HeaderKey.EVA_CHUNK: "eva_chunk",
+    HeaderKey.PRED_HEADS: "n_pred_heads",
+}
 # the keys past ROPE_TYPE an arch's files carry, in the order they are written;
 # an arch that is not here writes none of them
-_ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KEYS}
+_ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KEYS,
+              ArchType.EVABYTE: _EVA_KEYS}
 
 
 @dataclasses.dataclass
@@ -226,6 +254,8 @@ class ModelSpec:
     window_period: int = 0
     first_dense: int = 0
     routed_scale_milli: int = 0
+    eva_chunk: int = 0
+    n_pred_heads: int = 0
 
     @property
     def head_size(self) -> int:
@@ -360,6 +390,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
                 HeaderKey.ROPE_TYPE: "rope_type",
                 **_EXTRA_KEYS,
                 **_WINDOW_KEYS,
+                **_EVA_KEYS,
             }
             for i in range(0, n_ints, 2):
                 key, value = raw[i], raw[i + 1]
@@ -419,6 +450,9 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
         if spec.arch_type == ArchType.EXAONE_MOE:
             _window_layer(spec, l, add)
             continue
+        if spec.arch_type == ArchType.EVABYTE:
+            _eva_layer(spec, l, add)
+            continue
         add(p + "q", (dim, dim), wt)
         add(p + "k", (kv_dim, dim), wt)
         add(p + "v", (kv_dim, dim), wt)
@@ -440,7 +474,7 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
             add(p + "rms_moe", (dim,), FloatType.F32)
             add(p + "rms_ffn2", (dim,), FloatType.F32)
     add("rms_final", (dim,), FloatType.F32)
-    add("wcls", (vocab, dim), wt)
+    add("wcls", (max(1, spec.n_pred_heads) * vocab, dim), wt)
     return entries
 
 
@@ -451,7 +485,9 @@ def layer_kind(spec, l: int) -> tuple[str, str]:
     ``linear``: a gated delta-rule recurrence) and what its feed-forward is
     (``dense`` or ``experts``). An arch without a period has full layers
     only; one with experts has them in every layer past ``first_dense``."""
-    if spec.attn_period:
+    if spec.eva_chunk:
+        mixer = "eva"
+    elif spec.attn_period:
         mixer = "full" if l % spec.attn_period == 0 else "linear"
     elif spec.window_period:
         mixer = "full" if l % spec.window_period == spec.window_period - 1 else "window"
@@ -535,6 +571,24 @@ def _window_layer(spec: ModelSpec, l: int, add) -> None:
         add(p + "up", (hidden, dim), wt)
     else:
         _held_experts(spec, p, add)
+
+
+def _eva_layer(spec: ModelSpec, l: int, add) -> None:
+    """One ``ArchType.EVABYTE`` layer's tensors (the module docstring's list)."""
+    wt, f32, dim, hidden = spec.weights_float_type, FloatType.F32, spec.dim, spec.hidden_dim
+    p = f"layers.{l}."
+    q_dim = spec.n_heads * spec.head_size
+    add(p + "rms_att", (dim,), f32)
+    add(p + "rms_ffn", (dim,), f32)
+    add(p + "q", (q_dim, dim), wt)
+    add(p + "k", (spec.kv_dim, dim), wt)
+    add(p + "v", (spec.kv_dim, dim), wt)
+    add(p + "eva_phi", (spec.n_kv_heads, spec.head_size), f32)
+    add(p + "eva_mu", (spec.n_kv_heads, spec.head_size), f32)
+    add(p + "wo", (dim, q_dim), wt)
+    add(p + "gate", (hidden, dim), wt)
+    add(p + "down", (dim, hidden), wt)
+    add(p + "up", (hidden, dim), wt)
 
 
 class ModelFileReader:

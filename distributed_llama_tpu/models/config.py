@@ -7,6 +7,7 @@ but frozen, so traced functions can specialize on it.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 from distributed_llama_tpu.formats.model_file import (
     ArchFlags,
@@ -85,6 +86,13 @@ class LlamaConfig:
     ring_len: int = 0
     first_dense: int = 0
     routed_scale: float = 1.0
+    # EVA attention (ArchType.EVABYTE; 0 elsewhere): a query reads the keys of
+    # its own ALIGNED window of ``window`` positions exactly and, of every
+    # earlier window, one learned summary per ``eva_chunk`` positions, in one
+    # softmax. A layer's cache is one leaf a row: ``window`` slots of keys and
+    # values (position p at slot p % window) and behind them ``seq_len /
+    # eva_chunk`` summaries (chunk m at slot window + m)
+    eva_chunk: int = 0
 
     @property
     def kv_mul(self) -> int:
@@ -122,6 +130,48 @@ class LlamaConfig:
         return self.window_period > 0
 
     @property
+    def kv_read_kinds(self) -> tuple[str, ...]:
+        """The kinds a batched decode step's attention counts its cache reads
+        by (``ops.attention.note_kv_read``; empty: it counts none): by layer
+        kind where window layers stand beside full ones, by store where the
+        layers are EVA's."""
+        if self.has_window:
+            return ("full", "window")
+        return ("eva_window", "eva_summary") if self.has_eva else ()
+
+    @property
+    def has_eva(self) -> bool:
+        """Whether the layers keep the current window and summaries of the past."""
+        return self.eva_chunk > 0
+
+    @property
+    def eva_summaries(self) -> int:
+        """Summaries a row can hold: one per ``eva_chunk`` positions."""
+        return self.seq_len // self.eva_chunk if self.eva_chunk else 0
+
+    @property
+    def eva_slots(self) -> int:
+        """Slots of an EVA layer's leaf a row: the window, then the summaries."""
+        return self.window + self.eva_summaries
+
+    @property
+    def eva_scan_chunk(self) -> int:
+        """Slots one step of an EVA scan reads: the largest power of two up to
+        512 that divides window and summary store alike, so that no step
+        straddles the two (512 at a window of 2048 and 16384 positions)."""
+        return math.gcd(512, self.window, self.eva_summaries)
+
+    @property
+    def piece_limit(self) -> int:
+        """The most tokens of one row a single dispatch may write (0: any
+        number): what fits a window layer's ring beside its window
+        (``ring_piece``); what an EVA layer's window store takes without two
+        tokens meeting in one slot, at most 256."""
+        if self.has_window:
+            return self.ring_piece
+        return min(256, 1 << self.window.bit_length() - 1) if self.has_eva else 0
+
+    @property
     def ring_piece(self) -> int:
         """The most tokens of one row a single dispatch may write into a ring:
         the largest power of two (a prompt piece is padded to one) that fits
@@ -131,8 +181,9 @@ class LlamaConfig:
     @property
     def rewinds_by_position(self) -> bool:
         """Whether a row can be moved back to any earlier position: not where
-        some layer keeps a recurrent state or a ring instead of every position."""
-        return not (self.is_recurrent or self.has_window)
+        some layer keeps a recurrent state, a ring, or one window and summaries
+        of the rest instead of every position."""
+        return not (self.is_recurrent or self.has_window or self.has_eva)
 
     @property
     def use_rope(self) -> bool:
@@ -171,6 +222,11 @@ def next_pow2(n: int) -> int:
 
 
 def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
+    if spec.eva_chunk and (spec.window % spec.eva_chunk or spec.seq_len % spec.eva_chunk):
+        raise ValueError(
+            f"EVA attention needs a window ({spec.window}) and a context ({spec.seq_len}) "
+            f"of whole chunks of {spec.eva_chunk} positions"
+        )
     if spec.window_period:
         # a ring never needs more slots than the row has positions
         overrides.setdefault(
@@ -210,5 +266,6 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         window_period=spec.window_period,
         first_dense=spec.first_dense,
         routed_scale=spec.routed_scale_milli / 1000.0 if spec.routed_scale_milli else 1.0,
+        eva_chunk=spec.eva_chunk,
         **overrides,
     )

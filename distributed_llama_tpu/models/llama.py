@@ -261,6 +261,7 @@ def attention(
     axis_name: str | None,
     paged=None,
     layer: int | None = None,
+    n_real: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Causal GQA attention for T new tokens at absolute positions
     pos..pos+T-1. ``cache_l``: this layer's cache — a ``(keys, values)``
@@ -278,7 +279,13 @@ def attention(
     ``layer``: the layer's index (``cfg.layer_kind``). A WINDOW layer's
     ``cache_l`` is a ring [2, R, Kl, hd]: the tokens' K/V go to their
     positions' slots and each query sees the ``cfg.window`` positions up to
-    its own (``ops.attention.window_attention``); it reads no pool.
+    its own (``ops.attention.window_attention``); it reads no pool. An EVA
+    layer's is [2, window + summaries, Kl, hd] (``cfg.eva_slots``): the
+    tokens' K/V go to their positions' window slots, the chunks they complete
+    are summarised behind them, and a query reads its aligned window and the
+    earlier windows' summaries (``ops.attention.eva_prefill_attention``); rows
+    at and past ``n_real`` (a padded piece) write nothing there. A prefix hit
+    COPIES what it needs into the row, so it reads no pool either.
 
     Mirrors llamaQkv/llamaRope/llamaMultiheadAtt/llamaAtt
     (reference: src/llama2-tasks.cpp:33-108) with the per-timestep score loop
@@ -301,6 +308,15 @@ def attention(
         qg = q.reshape(T, Kl, Hl // Kl, hd).astype(jnp.float32)
         att = window_attention(qg, new_cache, pos, cfg.window).astype(jnp.float32)
         return _gated(att.reshape(T, Hl * hd), gate), new_cache
+
+    if cfg.has_eva:
+        from distributed_llama_tpu.ops.attention import eva_prefill_attention
+
+        att, new_cache = eva_prefill_attention(
+            q.reshape(T, Kl, Hl // Kl, hd).astype(jnp.float32), k, v, cache_l, pos, n_real,
+            lp["eva_phi"], lp["eva_mu"], cfg.window, cfg.eva_chunk, cfg.eva_scan_chunk,
+        )
+        return att.reshape(T, Hl * hd), new_cache
 
     if kvc.is_fused_leaf(cache_l):
         # fused [2, S, Kl, hd] leaf: keys AND values land in ONE coalesced
@@ -385,6 +401,14 @@ class WindowRingError(RuntimeError):
     state, by this name."""
 
 
+class EvaWindowError(RuntimeError):
+    """A path that moves or rewinds a row BY POSITION met an arch whose layers
+    mix by EVA attention (``cfg.has_eva``): a finished window's keys have been
+    overwritten by the next one's and only their summaries are left, and a
+    page of the pool holds summaries, not keys. The same paths refuse as for
+    a recurrent state or a ring, by this name."""
+
+
 def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
     """Refuse ``what`` for an arch that cannot move a row back by position
     (``not cfg.rewinds_by_position``), by the name of what it keeps instead
@@ -398,6 +422,12 @@ def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
         raise WindowRingError(
             f"{what} is not supported for arch {cfg.arch.name}: its window-attention "
             f"layers keep a ring of {cfg.ring_len} positions, not every position of a row"
+        )
+    if cfg.has_eva:
+        raise EvaWindowError(
+            f"{what} is not supported for arch {cfg.arch.name}: its EVA layers keep the "
+            f"keys of the current window of {cfg.window} positions and one summary per "
+            f"{cfg.eva_chunk} positions of the windows before it, not every position of a row"
         )
 
 
@@ -528,7 +558,8 @@ def block_forward(
         att, new_cache = linear_attention(cfg, x, lp, cache_l, pos, n_real)
     else:
         att, new_cache = attention(
-            cfg, x, lp, cache_l, pos, rope_rows, axis_name, paged=paged, layer=layer
+            cfg, x, lp, cache_l, pos, rope_rows, axis_name, paged=paged, layer=layer,
+            n_real=n_real,
         )
     return (
         block_tail(cfg, x, att, lp, axis_name, ep_axis=ep_axis, n_real=n_real),
@@ -645,7 +676,10 @@ def attention_batched(
     copies of the pages. A WINDOW layer (``layer``, ``cfg.layer_kind``) keeps
     a ring [2, B, R, Kl, hd]: row ``b`` writes at slot ``pos[b] % R`` and
     reads its own last ``cfg.window`` positions, no pool and no chunk that
-    the mask would hide."""
+    the mask would hide. An EVA layer keeps [2, B, window + summaries, Kl,
+    hd]: row ``b`` writes at slot ``pos[b] % window``, summarises the chunk
+    that position ends, and reads its aligned window and the earlier
+    windows' summaries (``ops.attention.eva_*``), no pool."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     B = x.shape[0]
@@ -664,6 +698,19 @@ def attention_batched(
             qg, new_cache, jnp.where(active, pos, 0), cfg.window
         ).astype(jnp.float32)
         return _gated(att.reshape(B, Hl * hd), gate), new_cache
+
+    if cfg.has_eva:
+        from distributed_llama_tpu.ops import attention as att_ops
+
+        new_cache = att_ops.eva_decode_write(
+            cache_l, k, v, pos, active, lp["eva_phi"], lp["eva_mu"], cfg.window, cfg.eva_chunk
+        )
+        qg = q.reshape(B, Kl, Hl // Kl, hd).astype(jnp.float32)
+        att = att_ops.eva_batched_decode_attention(
+            qg, new_cache, jnp.where(active, pos, 0), cfg.window, cfg.eva_chunk,
+            cfg.eva_scan_chunk,
+        )
+        return att.reshape(B, Hl * hd), new_cache
 
     write_slot = jnp.where(active & (pos < S), pos, S)  # S = dropped
     if kvc.is_fused_leaf(cache_l):
@@ -940,21 +987,29 @@ def init_batch_cache(
 def _init_layer_leaf(cfg: LlamaConfig, l: int, lead: tuple[int, ...], kl: int, dtype):
     """Layer ``l``'s cache leaf by its kind: every position of a row for a
     full layer, a ring of ``cfg.ring_len`` slots for a window layer (it does
-    not grow with ``seq_len``), a state for a linear one."""
+    not grow with ``seq_len``), a state for a linear one, the window store and
+    the summaries behind it (``cfg.eva_slots``) for an EVA one."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     mixer = cfg.layer_kind(l)[0]
     if mixer == "linear":
         return init_state_leaf(cfg, lead)
-    slots = cfg.ring_len if mixer == "window" else cfg.seq_len
+    if mixer == "eva" and kvc.is_quantized_cache_dtype(dtype):
+        raise ValueError("an EVA layer's summaries have no i8 form: serve it with a plain KV dtype")
+    slots = {"window": cfg.ring_len, "eva": cfg.eva_slots}.get(mixer, cfg.seq_len)
     return kvc.init_fused(lead + (slots, kl, cfg.head_size), dtype)
 
 
 def kv_slab_bytes(cfg: LlamaConfig, rows: int, dtype) -> dict[str, int]:
     """Bytes of keys and values ``rows`` slab rows hold, by layer kind
     (``full``, ``window``): a full layer's grow with ``seq_len``, a window
-    layer's are its ring's."""
+    layer's are its ring's. An EVA arch: by store (``eva_window``: the window's
+    slots, which do not grow with ``seq_len``; ``eva_summary``: one entry per
+    ``eva_chunk`` positions)."""
     per_slot = page_pool_bytes(cfg, 1, dtype, layers=1)
+    if cfg.has_eva:
+        return {"eva_window": rows * cfg.window * per_slot * cfg.n_layers,
+                "eva_summary": rows * cfg.eva_summaries * per_slot * cfg.n_layers}
     return {
         "full": rows * cfg.seq_len * per_slot * len(cfg.layers_of("full")),
         "window": rows * cfg.ring_len * per_slot * len(cfg.layers_of("window")),
@@ -998,7 +1053,13 @@ def init_page_pool(
     kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
     # the pool holds the FULL layers' pages: a linear layer has no keys and
     # values, a window layer's are in its own small pool
-    # (:func:`init_window_pool`); their entries are None
+    # (:func:`init_window_pool`); their entries are None. An EVA layer's page
+    # here holds the block's SUMMARIES (page / eva_chunk entries, a sixteenth
+    # of a page of keys and values at a chunk of 16): what a later prompt needs
+    # of every block it shares; the keys themselves, which it needs of its last
+    # window only, are in the window pool
+    if cfg.has_eva:
+        return _init_pool(cfg, "eva", n_pages, page // cfg.eva_chunk, kl, dtype)
     return _init_pool(cfg, "full", n_pages, page, kl, dtype)
 
 
@@ -1021,8 +1082,10 @@ def init_window_pool(cfg: LlamaConfig, n_pages: int, page: int, dtype=jnp.float3
     ends at a page boundary needs these layers' keys and values of the
     ``cfg.window`` positions before it and nothing older, so this pool holds
     the last pages of the prompts published lately (``engine.prefix_cache``:
-    its own recency order) and does not grow with ``--kv-pages``."""
-    return _init_pool(cfg, "window", n_pages, page, cfg.n_kv_heads, dtype)
+    its own recency order) and does not grow with ``--kv-pages``. For an EVA
+    arch every layer has one: a hit inside a window needs the keys and values
+    of that window's positions before it (at most ``window / page`` pages)."""
+    return _init_pool(cfg, "eva" if cfg.has_eva else "window", n_pages, page, cfg.n_kv_heads, dtype)
 
 
 def page_pool_bytes(cfg: LlamaConfig, page: int, dtype, layers: int | None = None) -> int:
@@ -1033,6 +1096,9 @@ def page_pool_bytes(cfg: LlamaConfig, page: int, dtype, layers: int | None = Non
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     kl, hd = cfg.n_kv_heads, cfg.head_size
+    if layers is None and cfg.has_eva:
+        # an EVA arch's pool page holds the block's summaries, in every layer
+        layers, page = cfg.n_layers, page // cfg.eva_chunk
     if kvc.is_quantized_cache_dtype(dtype):
         per_half = page * kl * hd + page * kl * 4  # int8 data + f32 scales
     else:

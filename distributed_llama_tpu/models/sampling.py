@@ -479,8 +479,9 @@ def batched_decode_scan(
     (tokens [n_steps, B], cache, fingerprints uint32 [B], finite bool
     [B]) and, for an arch that holds a share of its experts, int32 [B] the
     row's expert choices that fell on a held expert, and for one with window
-    layers two more, the cache positions the row's full and its window layers
-    read — NOTHING else needs to cross the host per chunk: the sampler is
+    or EVA layers two more, the cache positions the row's layers read by
+    kind (``cfg.kv_read_kinds``) — NOTHING else needs to cross the host per
+    chunk: the sampler is
     stateless, so no advanced keys return and no full-vocab logits are
     ever fetched. ``paged``: each row's matched prompt prefix is read from
     the shared page pool through its page table instead of the slab (the
@@ -501,22 +502,22 @@ def batched_decode_scan(
     # an arch that holds a share of its experts: per row, the choices that
     # fell on a held expert, summed over the chunk's steps and layers
     share = cfg.n_routed_experts > 0
-    # an arch with window layers: per row, the cache positions its full and
-    # its window layers read, summed likewise (two more rows of the bundle)
-    windowed = cfg.has_window
+    # an arch with window or EVA layers: per row, the cache positions its
+    # layers read, by kind, summed likewise (two more rows of the bundle)
+    kinds = cfg.kv_read_kinds
 
     def step(carry, _):
         tokens, cache_c, p, h, okf, held, kv = carry
         counts = [] if share else None
-        reads = {} if windowed else None
+        reads = {} if kinds else None
         logits, cache_c = llama.forward_step_batched(
             cfg, params, tokens, cache_c, p, active, axis_name=axis_name,
             paged=paged, held_counts=counts, kv_reads=reads,
         )
         if share:
             held = held + jnp.where(active, counts[0], 0)
-        if windowed:
-            kv = (kv[0] + reads["full"], kv[1] + reads["window"])
+        if kinds:
+            kv = tuple(n + reads[kind] for n, kind in zip(kv, kinds))
         cand = None
         if axis_name is not None and logits.shape[-1] != cfg.vocab_size:
             # the tp top-k composition: candidates reduce over the sharded
@@ -540,7 +541,7 @@ def batched_decode_scan(
         step,
         (
             first_tokens.astype(jnp.int32), cache, pos.astype(jnp.int32),
-            h0, ok0, zeros, (zeros, zeros) if windowed else (),
+            h0, ok0, zeros, tuple(zeros for _ in kinds),
         ),
         None,
         length=n_steps,

@@ -23,7 +23,8 @@ from distributed_llama_tpu.ops import kv_cache as kvc
 # Trace-time collector of what a batched decode step's attention reads: while
 # one is open (:func:`collect_kv_reads`), every layer's scan appends (kind,
 # int32 [B]): the positions of each row's cache it read (``full``: the chunks
-# up to the bucket's longest row, every row alike; ``window``: the window).
+# up to the bucket's longest row, every row alike; ``window``: the window;
+# ``eva_window`` / ``eva_summary``: an EVA scan's chunks of each store).
 # The forward that opened it sums by kind and returns the sums with its
 # tokens, as the expert share's counts are (``models.moe.collect_held``).
 _kv_reads: list | None = None
@@ -59,6 +60,14 @@ def chunk_attention(
     cache slice to f32 would materialize 2x the cache bytes per layer per
     token (the same fix as llama.attention's score/value einsums).
     """
+    mask = (k_positions[None, :] <= q_positions[:, None])[:, None, None, :]
+    return masked_chunk_attention(q, k, v, mask)
+
+
+def masked_chunk_attention(q, k, v, mask):
+    """:func:`chunk_attention` under any ``mask`` (broadcastable to [Tq, K, M,
+    Tk]; True = the query sees the key): the (m, l, o) partials of one chunk.
+    A query that sees nothing of the chunk gets the EMPTY partial."""
     hd = q.shape[-1]
     # compute dtype follows the cache half (bf16 for an i8 half); f32 caches
     # (parity tests) keep true-f32 multiplies, mirroring llama.attention —
@@ -67,7 +76,6 @@ def chunk_attention(
     cdt = kvc.compute_dtype(k)
     prec = kvc.einsum_precision(k)
     scores = kvc.scores_einsum(q.astype(cdt), k, prec) / jnp.sqrt(jnp.float32(hd))
-    mask = (k_positions[None, :] <= q_positions[:, None])[:, None, None, :]
     scores = jnp.where(mask, scores, -jnp.inf)
     m = jnp.max(scores, axis=-1)  # [Tq, K, M]
     # fully-masked rows (no kv visible in this chunk) keep m = -inf: the
@@ -216,10 +224,15 @@ def blocked_partials(
     return jax.lax.fori_loop(0, n_chunks, body, (m0, l0, o0))
 
 
-def _decode_partial(qg, pos, chunk: int, cdt, prec):
+def _decode_partial(qg, pos, chunk: int, cdt, prec, sees=None):
     """The per-chunk online-softmax arithmetic of the batched decode scan,
-    handed to :func:`_segmented_batched_scan` once per segment."""
+    handed to :func:`_segmented_batched_scan` once per segment. ``sees(slots
+    [chunk], pos [B]) -> [B, chunk]``: which of a chunk's slots each row's
+    query sees; None = causal (slot s is position s)."""
     hd = qg.shape[-1]
+    if sees is None:
+        def sees(k_pos, pos):
+            return k_pos[None, :] <= pos[:, None]
 
     def partial(kc, vc, start, carry):
         m, l, o = carry
@@ -227,7 +240,7 @@ def _decode_partial(qg, pos, chunk: int, cdt, prec):
         scores = kvc.scores_einsum_batched(qg.astype(cdt), kc, prec) / jnp.sqrt(
             jnp.float32(hd)
         )  # [B, K, M, chunk]
-        mask = (k_pos[None, :] <= pos[:, None])[:, None, None, :]
+        mask = sees(k_pos, pos)[:, None, None, :]
         scores = jnp.where(mask, scores, -jnp.inf)
         ms = jnp.max(scores, axis=-1)
         # keep m = -inf for fully-masked chunks (the exact-identity empty
@@ -375,6 +388,197 @@ def batched_window_attention(
     scores = kvc.scores_einsum_batched(qg.astype(cdt), kc, prec) / jnp.sqrt(jnp.float32(hd))
     mask = (k_pos >= 0)[:, None, None, :]
     return _window_softmax(scores, mask, vc, cdt, prec, kvc.mix_einsum_batched)
+
+
+# ---------------------------------------------------------------------------
+# EVA attention: the sequence is cut into ALIGNED windows of W positions; a
+# query at t (window b = t // W) reads the keys of positions b*W .. t exactly
+# and, of every earlier window, one learned summary per chunk of c positions,
+# in ONE softmax. A layer's leaf holds a row's window store (position p at
+# slot p % W) and behind it its summaries (chunk m at slot W + m). Writers put
+# a chunk's summary there when its last position is written, in prefill and in
+# decode alike; the READ mask decides what a query sees (window slots up to t
+# % W, summaries of chunks below (W / c) * b), so nothing happens at a
+# window's end and rows that cross one at different steps need no branch.
+# ---------------------------------------------------------------------------
+
+
+def eva_summarise(keys, values, phi, mu):
+    """One summary a chunk and head: ``keys``/``values`` [..., c, K, hd] (a
+    chunk's rotated keys and its values), ``phi``/``mu`` [K, hd] the layer's
+    learned vectors. ``w = softmax_j(<k_j, phi> / sqrt(hd))`` over the chunk,
+    ``k~ = sum_j w_j k_j + mu``, ``v~ = sum_j w_j v_j``; float32 throughout.
+    Returns ([..., K, hd], [..., K, hd])."""
+    keys, values = keys.astype(jnp.float32), values.astype(jnp.float32)
+    hd = keys.shape[-1]
+    logits = jnp.sum(keys * phi, axis=-1) / jnp.sqrt(jnp.float32(hd))  # [..., c, K]
+    w = jax.nn.softmax(logits, axis=-2)[..., None]
+    return jnp.sum(w * keys, axis=-3) + mu, jnp.sum(w * values, axis=-3)
+
+
+def _eva_steps(win_slots, sum_slots, window: int, chunk: int):
+    """The steps of ONE loop over an EVA leaf that reads the first
+    ``win_slots`` slots of the window store and then the first ``sum_slots``
+    summaries, a chunk a step: ``(n_steps, n_win, n_sum, start)`` with
+    ``start(i)`` the first slot of step ``i``'s chunk."""
+    n_win = jax.lax.div(win_slots + chunk - 1, chunk)
+    n_sum = jax.lax.div(sum_slots + chunk - 1, chunk)
+
+    def start(i):
+        return jnp.where(i < n_win, i * chunk, window + (i - n_win) * chunk)
+
+    return n_win + n_sum, n_win, n_sum, start
+
+
+def eva_prefill_attention(
+    qg: jax.Array,  # [T, K, M, hd] f32 grouped queries at pos..pos+T-1
+    k: jax.Array,  # [T, K, hd] f32 rotated keys of the piece
+    v: jax.Array,  # [T, K, hd]
+    leaf,  # the row's leaf [2, W + C, K, hd] as it was BEFORE this piece
+    pos: jax.Array,  # scalar: absolute position of row 0 of the piece
+    n_real,  # real rows of a padded piece (None: all)
+    phi: jax.Array,  # [K, hd]
+    mu: jax.Array,  # [K, hd]
+    window: int,
+    c: int,
+    chunk: int,
+):
+    """EVA attention of one piece of ONE row, and the leaf after it. The
+    piece may start anywhere and cross a window's end (a prefix hit resumes at
+    a page, not at a window): a query reads (1) of the OLD leaf the window
+    slots below ``pos`` if it is in the window ``pos`` is in, and the
+    summaries of earlier windows' chunks that ended before ``pos``; (2) the
+    piece's own keys up to itself that share its window; (3) the summaries of
+    chunks that END inside the piece and belong to a window before its own
+    (there are some only when the piece crosses a window's end). Nothing is
+    read back that the piece itself overwrites. Pad rows (at and past
+    ``n_real``) write nothing: a pad's slot may hold a position of the
+    current window that later rows still read. Needs T <= W (no two rows of
+    the piece in one slot). Returns ([T, K, M, hd] f32, new leaf)."""
+    T, K, M, hd = qg.shape
+    W, per = window, window // c
+    dt = leaf.dtype
+    n_real = T if n_real is None else n_real
+    if T > W:
+        raise ValueError(f"a piece of {T} tokens does not fit a window store of {W} slots")
+    rows = jnp.arange(T)
+    q_pos = pos + rows
+    b_t = q_pos // W  # [T] the queries' windows
+    # what later reads will find in the leaf: the cache's own rounding
+    kr, vr = k.astype(dt), v.astype(dt)
+
+    # the chunks that end inside the piece's real rows, pooled from the
+    # piece's keys and, where a chunk began before the piece, the old leaf's
+    n_c = T // c + 1
+    m_i = pos // c + jnp.arange(n_c)
+    ends = c * m_i + c - 1 < pos + n_real  # chunk pos // c is the first that can
+    kp = c * m_i[:, None] + jnp.arange(c)[None, :]  # [n_c, c] positions
+    inside = (kp >= pos)[..., None, None]
+    at = jnp.clip(kp - pos, 0, T - 1)
+    old_k, old_v = kvc.ring_take(leaf, kp % W)
+    sk, sv = eva_summarise(
+        jnp.where(inside, kr[at], old_k), jnp.where(inside, vr[at], old_v), phi, mu
+    )
+    sk, sv = sk.astype(dt), sv.astype(dt)
+
+    # (2) + (3): the piece's own keys and the summaries it completes
+    same_window = (rows[None, :] <= rows[:, None]) & (b_t[None, :] == b_t[:, None])
+    earlier_window = ends[None, :] & (m_i[None, :] < per * b_t[:, None])
+    fresh = jnp.concatenate([same_window, earlier_window], axis=1)[:, None, None, :]
+    part = masked_chunk_attention(
+        qg, jnp.concatenate([kr, sk]), jnp.concatenate([vr, sv]), fresh
+    )
+
+    # (1): the old leaf, a chunk of slots at a time and only as far as it is live
+    b0 = pos // W
+    n_steps, _, _, start = _eva_steps(
+        pos % W, jnp.minimum(per * ((pos + T - 1) // W), pos // c), W, chunk
+    )
+
+    def body(i, carry):
+        kc, vc = jax.lax.dynamic_slice(leaf, (0, start(i), 0, 0), (2, chunk, K, hd))
+        g = start(i) + jnp.arange(chunk)
+        in_window = (b_t[:, None] == b0) & (g[None, :] < pos % W)
+        m = g - W
+        summary = (m[None, :] < per * b_t[:, None]) & ((c * m + c - 1)[None, :] < pos)
+        mask = jnp.where((g < W)[None, :], in_window, summary)[:, None, None, :]
+        return merge_partials(*carry, *masked_chunk_attention(qg, kc, vc, mask))
+
+    m_, l_, o_ = jax.lax.fori_loop(0, n_steps, body, part)
+    att = o_ / jnp.maximum(l_, 1e-30)[..., None]
+
+    # one scatter: the real rows' keys and values at their positions' slots,
+    # the completed chunks' summaries at theirs; everything else is dropped
+    drop = leaf.shape[1]
+    slots = jnp.concatenate([
+        jnp.where(rows < n_real, q_pos % W, drop), jnp.where(ends, W + m_i, drop)
+    ])
+    both = jnp.stack([jnp.concatenate([kr, sk]), jnp.concatenate([vr, sv])])
+    return att, leaf.at[:, slots].set(both, mode="drop")
+
+
+def eva_decode_write(leaf, k, v, pos, active, phi, mu, window: int, c: int):
+    """One decode step's writes into an EVA layer's slab leaf [2, B_max, W +
+    C, K, hd]: row ``b``'s key and value at slot ``pos[b] % W`` and, where
+    that position ends a chunk, the chunk's summary (pooled from the window
+    store's own ``c`` slots, the new key among them) at slot ``W + pos[b] //
+    c``. Inactive rows, and rows past the leaf's summaries, write nothing."""
+    W = window
+    B, drop = k.shape[0], leaf.shape[2]
+    live = active & (pos // c < drop - W)
+    leaf = kvc.fused_update_row_batched(leaf, k, v, jnp.where(live, pos % W, drop))
+    back = (pos[:, None] - (c - 1) + jnp.arange(c)[None, :]) % W  # [B, c]
+    kc, vc = kvc.ring_take(leaf, back, rows=B)
+    sk, sv = eva_summarise(kc, vc, phi, mu)
+    ends = live & (pos % c == c - 1)
+    return kvc.fused_update_row_batched(leaf, sk, sv, jnp.where(ends, W + pos // c, drop))
+
+
+def _eva_sees(window: int, c: int):
+    """The read mask of an EVA leaf's slots for queries at ``pos`` [B]."""
+    W, per = window, window // c
+
+    def sees(g, pos):
+        in_window = g[None, :] <= (pos % W)[:, None]
+        summary = (g - W)[None, :] < (per * (pos // W))[:, None]
+        return jnp.where((g < W)[None, :], in_window, summary)
+
+    return sees
+
+
+def eva_batched_decode_attention(
+    qg: jax.Array,  # [B, K, M, hd] f32 grouped queries (one token per row)
+    cache,  # the layer's slab leaf [2, B_max, W + C, K, hd], this step's writes in it
+    pos: jax.Array,  # [B] per-row absolute positions (inactive rows: 0)
+    window: int,
+    c: int,
+    chunk: int,
+) -> jax.Array:
+    """EVA attention of B independent single-token queries: row ``b`` sees
+    window slots 0 .. pos[b] % W (its aligned window up to itself) and the
+    summaries of the chunks of its earlier windows. ONE loop reads the leaf
+    (a leaf that feeds two is re-laid out: :func:`_segmented_batched_scan`),
+    first the window store's chunks up to the longest row's slot, then the
+    summaries' up to the deepest row's; a row sees of them what its own
+    position allows. Returns [B, K, M, hd] f32."""
+    B, K, M, hd = qg.shape
+    W = window
+    _, cdt, prec = kvc.slab_facts(cache)
+    n_steps, n_win, n_sum, start = _eva_steps(
+        jnp.max(pos % W) + 1, jnp.max((W // c) * (pos // W)), W, chunk
+    )
+    note_kv_read("eva_window", B, n_win * chunk)
+    note_kv_read("eva_summary", B, n_sum * chunk)
+    partial = _decode_partial(qg, pos, chunk, cdt, prec, sees=_eva_sees(W, c))
+
+    def body(i, carry):
+        return partial(*kvc.slab_chunk(cache, start(i), chunk, B), start(i), carry)
+
+    m0 = jnp.full((B, K, M), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((B, K, M), jnp.float32)
+    o0 = jnp.zeros((B, K, M, hd), jnp.float32)
+    m, l, o = jax.lax.fori_loop(0, n_steps, body, (m0, l0, o0))
+    return o / jnp.maximum(l, 1e-30)[..., None]
 
 
 def batched_verify_attention(

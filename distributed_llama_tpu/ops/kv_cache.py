@@ -29,6 +29,7 @@ stay int8 in both reads.
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -370,18 +371,45 @@ def pool_page_arrays_per_half(pool_half) -> int:
     return 2 if isinstance(pool_half, QuantizedKV) else 1
 
 
-def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int,
-                      ring: bool = False):
-    """Copy slab row ``row``'s page slots ``src_page[i]`` into pool pages
-    ``page_ids[i]`` (the prefix-cache publish: the row's completed prefill
-    KV becomes an immutable shared page). A ``page_ids`` entry at or beyond
-    P DROPS its write, so padded entries are inert. ``ring``: the slab half
-    is a window layer's ring, and a position sits at its slot modulo the
-    ring's length. Returns the updated pool half (callers donate the pool)."""
-    p_idx = jnp.arange(page)
-    slots = (src_page[:, None] * page + p_idx[None, :]).reshape(-1)
+class BlockSlots(NamedTuple):
+    """How blocks of a row sit in its leaf: entry j of block b at slot ``base
+    + (b * page + j) % ring`` (``ring`` 0: nothing wraps). A full layer's leaf
+    holds a position at its own slot (``page`` positions a block, no ring, no
+    base), a window layer's ring at the position modulo the ring's length; an
+    EVA layer's leaf holds two stores (:func:`eva_block_slots`)."""
+
+    page: int
+    ring: int = 0
+    base: int = 0
+
+
+def eva_block_slots(kind: str, page: int, window: int, chunk: int) -> BlockSlots:
+    """Where a block of ``page`` positions sits in an EVA layer's leaf: the
+    layout that the copies between leaf and pools follow, stated here for both
+    stores. ``"window"``: the block's keys and values, position p at slot ``p
+    % window``. ``"summary"``: its ``page // chunk`` summaries, chunk m at slot
+    ``window + m``, behind the window store."""
+    if kind == "window":
+        return BlockSlots(page, ring=window)
+    return BlockSlots(page // chunk, base=window)
+
+
+def block_slots(blocks, page: int, ring: int = 0, base: int = 0):
+    """The slots of blocks ``blocks`` (an int array) under :class:`BlockSlots`."""
+    slots = (blocks[:, None] * page + jnp.arange(page)[None, :]).reshape(-1)
     if ring:
-        slots = slots % slab_half.shape[1]
+        slots = slots % ring
+    return slots + base if base else slots
+
+
+def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int,
+                      ring: int = 0, base: int = 0):
+    """Copy slab row ``row``'s blocks ``src_page[i]`` (:func:`block_slots`)
+    into pool pages ``page_ids[i]`` (the prefix-cache publish: the row's
+    completed prefill KV becomes an immutable shared page). A ``page_ids``
+    entry at or beyond P DROPS its write, so padded entries are inert.
+    Returns the updated pool half (callers donate the pool)."""
+    slots = block_slots(src_page, page, ring, base)
     n = src_page.shape[0]
     if isinstance(pool_half, QuantizedKV):
         vals = slab_half.data[row, slots]  # [Np*page, K, hd]
@@ -400,14 +428,13 @@ def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int,
     )
 
 
-def restore_row_pages(slab_leaf, pool_k, pool_v, row, dst_page, page_ids, page: int):
-    """:func:`publish_row_pages` in reverse, for a ring: pool pages
-    ``page_ids[i]`` (keys and values) into the slots of fused slab leaf
-    ``[2, B, R, K, hd]``'s row ``row`` where positions ``dst_page[i] * page
-    ...`` sit (a prefix hit's window tail). Returns the updated leaf (callers
-    donate the slab)."""
-    R = slab_leaf.shape[2]
-    slots = ((dst_page[:, None] * page + jnp.arange(page)[None, :]).reshape(-1)) % R
+def restore_row_pages(slab_leaf, pool_k, pool_v, row, dst_page, page_ids, page: int,
+                      ring: int = 0, base: int = 0):
+    """:func:`publish_row_pages` in reverse: pool pages ``page_ids[i]`` (keys
+    and values) into the slots of fused slab leaf ``[2, B, R, K, hd]``'s row
+    ``row`` where blocks ``dst_page[i]`` sit (:func:`block_slots`). Returns
+    the updated leaf (callers donate the slab)."""
+    slots = block_slots(dst_page, page, ring, base)
 
     def both(k, v):
         kv = jnp.stack([k[page_ids], v[page_ids]])  # [2, n, page, K, x]

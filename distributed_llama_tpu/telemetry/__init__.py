@@ -339,23 +339,38 @@ class EngineInstruments:
             "Bytes of keys and values the decode chunks' attention read out of "
             "slab and pool, by layer kind: full (every chunk up to the "
             "bucket's longest row, every row of the bucket alike) and window "
-            "(the window's positions of each row's ring); counted by the "
+            "(the window's positions of each row's ring); for an arch with EVA "
+            "layers by store: eva_window (the window store's chunks up to the "
+            "bucket's farthest row in its window) and eva_summary (the "
+            "summaries' chunks up to its deepest row); counted by the "
             "programs from their scans' bounds and returned with their tokens",
             labelnames=("kind",),
         )
-        self.kv_read_full = kv_read.labels(kind="full")
-        self.kv_read_window = kv_read.labels(kind="window")
+        self.kv_read = {
+            kind: kv_read.labels(kind=kind)
+            for kind in ("full", "window", "eva_window", "eva_summary")
+        }
+        self.eva_summaries_written = counter(
+            "dllama_eva_summaries_written_total",
+            "Chunks an arch with EVA layers summarised: a prompt piece or a "
+            "decode chunk that writes a chunk's last position pools the "
+            "chunk's keys and values into one summary a head, in every layer; "
+            "counted by the scheduler from the positions it dispatches",
+        )
         self.kv_slab_bytes = gauge(
             "dllama_kv_slab_bytes",
             "Bytes of keys and values the slab's rows hold, by layer kind: a "
-            "full layer's grow with --max-seq-len, a window layer's ring does not",
+            "full layer's grow with --max-seq-len, a window layer's ring does "
+            "not; an EVA arch's by store (eva_window, eva_summary)",
             labelnames=("kind",),
         )
         self.kv_pool_bytes = gauge(
             "dllama_kv_pool_bytes",
             "Bytes of the prefix-cache page pools by layer kind: full "
             "(--kv-pages pages) and window (the last pages of the prompts "
-            "published lately: bounded by rows, not by --kv-pages)",
+            "published lately: bounded by rows, not by --kv-pages); an EVA "
+            "arch's: eva_summary (--kv-pages pages of summaries) and eva_window "
+            "(keys and values of live prompts' last windows: bounded by rows)",
             labelnames=("kind",),
         )
         self.prefill_chunks_ahead = histogram(
@@ -497,8 +512,9 @@ class PrefixCacheInstruments:
             "Prefix matches of an arch with window-attention layers by what the "
             "window layers' pool still held: hit (the pages before the matched "
             "chain's end were there: the whole chain served), shortened (the "
-            "chain was cut back to the deepest block whose tail was kept), "
-            "miss (no block's was: the prompt prefilled from 0)",
+            "chain was cut back to the deepest block whose tail was kept; for "
+            "EVA layers at the latest to its window's start, which needs "
+            "summaries only), miss (no block's was: the prompt prefilled from 0)",
             labelnames=("outcome",),
         )
         self.window_tail_hit = window_tail.labels(outcome="hit")

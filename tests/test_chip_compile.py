@@ -329,6 +329,46 @@ def test_served_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch, r
     assert _aliased_outputs(compiled.as_text())[carry_out] == carry_in
 
 
+def test_served_eva_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
+    """The decode chunk of ``evabyte.doc_sessions`` (EvaByte's widths, 2 of
+    its layers, 8 rows of 2048 window slots and 1024 summaries): a step
+    writes a layer's leaf twice in place (the row's key and value, then the
+    summary of the chunk that position may end) and its one loop reads the
+    leaf a chunk at a time; nothing else of the leaf's size forms."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    rows, layers = 8, 2
+    cfg = LlamaConfig(
+        arch=ArchType.EVABYTE, dim=4096, hidden_dim=11008, n_layers=layers, n_heads=32,
+        n_kv_heads=32, vocab_size=320, seq_len=16384, head_size=128, kv_dim=4096,
+        rope_theta=1e5, window=2048, eva_chunk=16,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layer = dict(
+        qkv=_qm_shape(4096, 12288, one_chip), wo=_qm_shape(4096, 4096, one_chip),
+        gate_up=_qm_shape(4096, 22016, one_chip), down=_qm_shape(11008, 4096, one_chip),
+        rms_att=s((4096,), jnp.float32), rms_ffn=s((4096,), jnp.float32),
+        eva_phi=s((32, 128), jnp.float32), eva_mu=s((32, 128), jnp.float32),
+    )
+    params = dict(
+        embedding=s((320, 4096), jnp.float32), layers=[layer] * layers,
+        rms_final=s((4096,), jnp.float32), rope_table=s((16384, 64, 2), jnp.float32),
+        wcls=_qm_shape(4096, 320, one_chip),
+    )
+    slab = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+        lambda: llama.init_batch_cache(cfg, rows, dtype=jnp.bfloat16)))
+    assert [leaf.shape for leaf in slab] == [(2, rows, 3072, 32, 128)] * layers
+    compiled = sampling.decode_chunk_batched.lower(
+        cfg, params, s((rows,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+        s((rows,), jnp.uint32)).compile()
+    writes, others = _slab_sized_results(compiled.as_text(), slab[0].size // 2)
+    assert len(writes) == 2 * layers, writes
+    assert not others, "slab-sized buffers besides the cache writes:\n" + "\n".join(others)
+
+
 def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
     """``sampling.spec_verify_chunk_batched_paged`` (``--spec-draft 4``, 16
     rows): one forward per dispatch, so the whole program is the step."""
