@@ -38,18 +38,26 @@ Architecture
 * With ``prefix_cache=True`` the scheduler also owns a page pool
   (``llama.init_page_pool`` single-chip, the tp engine's sharded pool on
   multi-chip) and a radix tree over token blocks (``engine/
-  prefix_cache.py``): an admission prefill (row position 0) binds its
-  matched prefix pages to the row as ``(page_ids, matched_len)`` — the
-  row's decode/verify/prefill attention then reads those positions
-  **zero-copy through its page table over the pool** (ops.attention paged
-  variants) while only the unmatched suffix prefills into the slab row;
-  completed full pages are published back (the only copy left in the
-  system). Because rows alias tree pages, the matched chain stays
+  prefix_cache.py``): an admission prefill (row position 0) finds its
+  longest published prefix and only the unmatched suffix prefills into
+  the slab row; completed full pages are published back. Where the
+  matched prefix lives while the row decodes differs by backend. On ONE
+  CHIP the matched pages are **copied into the row once, at admission**
+  (``_restore_pages``: block b at slots ``[b*page, (b+1)*page)``, the
+  pool's own bytes), and the row's programs are handed an EMPTY read
+  alias (zero table, ``matched`` 0): every decode, verify and prefill
+  step reads ONE source, the slab, as for a cold row (ISSUE 40: read in
+  place, every chunk of every decode step paid a pool gather and a
+  per-position select beside the slab read as soon as one live row had
+  no hit). The TP backend still binds the pages to the row as
+  ``(page_ids, matched_len)`` and its attention reads those positions
+  **in place through the page table over the sharded pool**
+  (ops.attention paged variants). Either way the matched chain stays
   ref-pinned for the ROW'S LIFETIME (released at reset/quarantine/
-  rollback-truncation), so eviction can never recycle a page a live row
-  is attending over — chaos-enforced, and a prefix-hit stream is
+  rollback-truncation), so eviction can never recycle a page a live tp
+  row is attending over — chaos-enforced, and a prefix-hit stream is
   bit-identical to the cold prefill (tests/test_prefix_cache.py,
-  tests/test_paged_attention.py).
+  tests/test_paged_attention.py, tests/test_prefix_restore.py).
 * Per-row seeds, temperatures, top-p and top-k ride the batched program;
   sampling is fused into the scan on counter-PRNG coins keyed
   ``(seed, position)`` (ISSUE 13), so a row's token stream is
@@ -93,7 +101,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_llama_tpu import lockcheck, retry
+from distributed_llama_tpu import lockcheck, retry, telemetry
 from distributed_llama_tpu.engine import faults, integrity
 from distributed_llama_tpu.engine.engine import TokenStats, _prefill_bucket, next_pow2
 from distributed_llama_tpu.engine.speculative import PromptLookupDrafter
@@ -207,6 +215,31 @@ def _publish_pages(page: int, slab, pool, page_ids, src_page, row, base: int = 0
     ]
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _restore_pages(slab, pool, page_ids, second, row):
+    """:func:`_publish_pages` in reverse, for a prefix hit on one chip: a
+    chain's pool pages into blocks ``0 .. n - 1`` of slab row ``row`` across
+    every layer that has a pool half. ``page_ids`` is ``[2, h]``, ``h`` the
+    largest power of two that is at most ``n``: the chain's first ``h`` pages
+    go to blocks ``0 .. h - 1`` and its last ``h`` to blocks ``second ..
+    n - 1`` (``second = n - h``), two contiguous writes a leaf
+    (``kvc.restore_row_blocks``). Every entry is a page of the chain at its
+    own block (where the runs overlap a block is written twice with the same
+    bytes: a hit copies ``2 h`` pages' bytes, between one and two times its
+    own), so no shape has padding and nothing but those blocks of that row
+    changes; one program a power of two. The bytes are the pool's own, so the
+    row is a cold prefill's; from here on its programs are handed an empty
+    read alias and read the slab alone. Only the slab is donated."""
+    telemetry.note_kernel_path("paged_attention", "slab_restored")
+    out = []
+    for leaf, half in zip(slab, pool):
+        if half is not None:
+            leaf = kvc.restore_row_blocks(leaf, half[0], half[1], row, 0, page_ids[0])
+            leaf = kvc.restore_row_blocks(leaf, half[0], half[1], row, second, page_ids[1])
+        out.append(leaf)
+    return out
+
+
 @functools.partial(jax.jit, static_argnums=(0,), static_argnames=("ring",), donate_argnums=(2,))
 def _publish_window_pages(page: int, slab, wpool, page_ids, src_page, row, *, ring: int):
     """:func:`_publish_pages` for the window layers: slab row ``row``'s blocks
@@ -303,11 +336,12 @@ def _slab_prefill_single(cfg: LlamaConfig, params, tokens, slab, row, pos, n_rea
 def _slab_prefill_single_paged(
     cfg: LlamaConfig, params, tokens, slab, pool, row, pos, n_real, table, matched
 ):
-    """:func:`_slab_prefill_single` with zero-copy prefix aliasing: the
-    row's attention reads positions below ``matched`` from the page pool
-    through ``table`` (the admission-time suffix prefill and any later
-    continuation prefill on an aliased row). The pool is read-only — only
-    the slab is donated."""
+    """:func:`_slab_prefill_single` with a read alias: the row's attention
+    reads positions below ``matched`` from the page pool through ``table``.
+    The scheduler hands it ``matched`` 0 and a zero table (a hit's pages were
+    copied into the row: the select takes the slab's byte at every
+    position); the program is the one every pool-enabled prefill runs. The
+    pool is read-only — only the slab is donated."""
     row_cache = [kvc.fused_take_row(leaf, row) for leaf in slab]
     counts = [] if cfg.n_routed_experts else None
     logits, new_rows = llama.forward_tokens(
@@ -372,13 +406,14 @@ class BatchStream:
         # False skips BOTH the admission match and the post-prefill publish
         # for this row (ISSUE 4); serving restores True between requests
         self.prefix_cache_enabled = True
-        # zero-copy prefix aliasing (ISSUE 7): the admission match binds the
-        # matched radix chain to this row — attention reads positions below
-        # ``matched_len`` THROUGH ``_alias_ids`` (the row's page table) over
-        # the shared pool instead of slab copies. ``_alias_chain`` holds the
-        # ref-pinned PageNodes for the row's lifetime; the scheduler
-        # releases them at reset/quarantine and truncates them on rollback
-        # below ``matched_len`` (all under its cond lock)
+        # the admission match binds the matched radix chain to this row:
+        # positions below ``matched_len`` were not prefilled but taken from
+        # pages ``_alias_ids`` of the shared pool: copied into the slab row
+        # at admission on one chip (ISSUE 40), read in place through the
+        # row's page table on the tp backend (ISSUE 7). ``_alias_chain``
+        # holds the ref-pinned PageNodes for the row's lifetime; the
+        # scheduler releases them at reset/quarantine and truncates them on
+        # rollback below ``matched_len`` (all under its cond lock)
         self._alias_chain: list = []
         self._alias_ids: list[int] = []
         self.matched_len = 0
@@ -428,9 +463,9 @@ class BatchStream:
 
     def reset(self) -> None:
         self.scheduler._leave(self)
-        # release the row's zero-copy page pins: the next occupant matches
-        # its own chain, and the old pages become evictable once no other
-        # row aliases them
+        # release the row's page pins: the next occupant matches its own
+        # chain, and the old pages become evictable once no other row
+        # holds them
         self.scheduler._release_row_pins(self)
         self.pos = 0
         # same cadence no-op contract as EngineStream.reset(): clearing this
@@ -473,12 +508,12 @@ class BatchStream:
         Slab slots beyond ``pos`` — including any written by an in-flight
         speculative chunk — are stale but unreachable: attention masks
         s <= pos and the next prefill overwrites them before the position
-        pointer crosses. A rollback BELOW the aliased prefix truncates the
+        pointer crosses. A rollback BELOW the matched prefix truncates the
         alias to ``pos`` (the rolled-back-onto tokens are a shared prefix,
-        so the pool bytes below ``pos`` stay valid) and releases the pins
-        of pages the shortened table no longer reaches — the next prefill
-        writes the slab at ``pos`` and must be read from the slab, not the
-        pool."""
+        so the bytes below ``pos``, in the slab row or in the pool, stay
+        valid) and releases the pins of pages the shortened chain no longer
+        reaches — the next prefill writes the slab at ``pos`` and must be
+        read from the slab, not the pool."""
         if not 0 <= pos <= self.pos:
             raise ValueError(f"cannot rollback to {pos} from {self.pos}")
         if 0 < pos < self.pos:
@@ -782,13 +817,17 @@ class BatchScheduler:
             # most a window (a monolithic dispatch does neither)
             self.prefill_chunk = min(self.prefill_chunk or engine.cfg.piece_limit,
                                      engine.cfg.piece_limit)
-        # radix-tree prefix cache over pool pages (ISSUE 4 tentpole, ISSUE 7
-        # zero-copy): an admission prefill binds published KV pages to the
-        # row's page table (attention reads them straight out of the pool)
-        # and prefills only the unmatched suffix
+        # radix-tree prefix cache over pool pages (ISSUE 4 tentpole): an
+        # admission prefill finds its longest published prefix and prefills
+        # only the unmatched suffix. On one chip the matched pages are COPIED
+        # into the row at admission (``_hit_restores``: the row is then a
+        # cold prefill's and every later step reads its slab alone, ISSUE
+        # 40); the tp backend binds them to the row's page table and its
+        # attention reads them in place out of the sharded pool (ISSUE 7)
         self._prefix = None
         self._pool = None
         self._wpool = None
+        self._hit_restores = False
         self._own_arena = None  # a spill arena this scheduler made itself
         if prefix_cache:
             # misconfiguration disables ONLY the prefix cache (with the
@@ -808,11 +847,11 @@ class BatchScheduler:
                 )
             slab_pages = n_rows * -(-engine.cfg.seq_len // page_size) if page_ok else 0
             if kv_pages is None and page_ok:
-                # default HBM budget: with zero-copy aliasing the pool is
-                # the PRIMARY store of cached prefixes (rows hold no
-                # duplicates), so size it to hold every row's worth of
-                # prefix plus headroom for prefixes outliving their rows
-                # (--parallel x ceil(seq_len/page) + 25%, at least one row)
+                # default HBM budget: a live row pins the pages it matched
+                # for its lifetime, so size the pool to hold every row's
+                # worth of prefix plus headroom for prefixes outliving their
+                # rows (--parallel x ceil(seq_len/page) + 25%, at least one
+                # row)
                 kv_pages = slab_pages + max(
                     slab_pages // 4, -(-engine.cfg.seq_len // page_size)
                 )
@@ -825,10 +864,10 @@ class BatchScheduler:
                     print(
                         f"⚠️ --kv-pages {kv_pages} is smaller than one "
                         f"slab's worth ({slab_pages} pages for {n_rows} "
-                        f"rows x seq_len {engine.cfg.seq_len}): the pool is "
-                        "the primary prefix store under zero-copy paged "
-                        "attention, so concurrent long prompts will "
-                        "contend for pages (pinned-page soft failures)"
+                        f"rows x seq_len {engine.cfg.seq_len}): a live row "
+                        "pins the pages it matched, so concurrent long "
+                        "prompts will contend for pages (pinned-page soft "
+                        "failures)"
                     )
                 from distributed_llama_tpu.engine.prefix_cache import PrefixCache
 
@@ -917,6 +956,8 @@ class BatchScheduler:
                     self._pool = llama.init_page_pool(
                         engine.cfg, kv_pages, page_size, dtype=engine.cache_dtype
                     )
+                    # an EVA arch copies its own two kinds of page (_eva_restore)
+                    self._hit_restores = not cfg.has_eva
                     if window_pages:
                         self._wpool = llama.init_window_pool(
                             engine.cfg, window_pages, page_size, dtype=engine.cache_dtype
@@ -1032,6 +1073,16 @@ class BatchScheduler:
             self._kv_position_bytes = llama.page_pool_bytes(
                 engine.cfg, 1, engine.cache_dtype, layers=1
             )
+        if self._hit_restores:
+            # every shape of a hit's copy is built now, not at the first hit
+            # inside a measured window (page 0 into row 0, which starts over
+            # before it reads a slot)
+            h = 1
+            while h <= engine.cfg.seq_len // page_size:
+                self._slab = _restore_pages(
+                    self._slab, self._pool, *self._restore_runs([0] * h), np.int32(0)
+                )
+                h *= 2
         if engine.cfg.has_window:
             if self._wpool is not None:
                 for kind, pages in (("full", kv_pages), ("window", self._prefix.window_pages)):
@@ -1287,10 +1338,10 @@ class BatchScheduler:
         """Prefill ``tokens`` into ``stream``'s slab row. On an ADMISSION
         prefill (row position 0, prefix cache active, request not opted
         out) the radix tree is consulted first: the matched chain is BOUND
-        to the row as its zero-copy page table (no bytes move) and only
-        the unmatched suffix is dispatched — its attention reads the
-        matched prefix straight out of the pool; the completed prefill's
-        full pages are then published back into the tree. Returns
+        to the row (its pages copied into the slab row on one chip, its
+        page table on the tp backend: :meth:`_match_alias`) and only the
+        unmatched suffix is dispatched; the completed prefill's full pages
+        are then published back into the tree. Returns
         ``(logits, last)`` — the final dispatch's device logits and the
         index of the last REAL token's row within them."""
         engine = self.engine
@@ -1587,7 +1638,7 @@ class BatchScheduler:
     def _reload_spilled_locked(self, tokens: np.ndarray) -> int:
         """Pull spilled pages of this prompt's prefix back into the pool
         BEFORE the radix match (cond held): the match then binds the
-        reloaded chain zero-copy exactly like always-resident pages. The
+        reloaded chain exactly like always-resident pages. The
         ``engine.spill`` chaos site fires per candidate block (``row=``
         selects the REPLICA id, like engine.sdc): a raise aborts the
         reload — already-uploaded blocks stay, deeper blocks prefill cold
@@ -1622,10 +1673,15 @@ class BatchScheduler:
 
     def _match_alias(self, stream: BatchStream, tokens: np.ndarray) -> list:
         """Walk the radix tree for the prompt's longest published prefix
-        and bind it to the row ZERO-COPY: the row records the chain's page
-        ids as its page table and advances its position past the matched
-        tokens — no bytes move; the suffix prefill's (and every later
-        step's) attention reads the pages through the table. The chain's
+        and bind it to the row: the row records the chain's page ids and
+        advances its position past the matched tokens. On one chip the
+        chain's pages of every layer that has a pool half are then COPIED
+        into the slab row at their own positions (:func:`_restore_pages`,
+        enqueued and never waited for): the row is a cold prefill's from
+        there on, and the suffix prefill's and every later step's programs
+        are handed an empty read alias (:meth:`_alias_arrays_locked`). On
+        the tp backend no bytes move and the attention reads the pages in
+        place through the row's table. The chain's
         refs stay held for the row's lifetime. With a spill arena, pages
         of this prefix that were evicted to host RAM (by this replica or
         a peer) are re-uploaded first, so the match sees the full
@@ -1673,6 +1729,19 @@ class BatchScheduler:
                         jnp.int32(chain[-1].snap),
                     )
                 prefix.tel.snapshots_restored.inc()
+            if self._hit_restores:
+                # copied into the row, not aliased: the full layers' pages at
+                # their own blocks. Enqueued, never waited for; the ids and
+                # the scalars are host buffers that cross with the dispatch
+                with self.engine._tel.span(
+                    "prefix_restore", batch_row=stream.row, pages=len(chain)
+                ):
+                    self._slab = _restore_pages(
+                        self._slab, self._pool, *self._restore_runs(stream._alias_ids),
+                        np.int32(stream.row),
+                    )
+                prefix.tel.restores.inc()
+                prefix.tel.restored_bytes.inc(len(chain) * prefix.page_bytes)
             if self.engine.cfg.has_eva:
                 # copied into the row, not aliased: every block's summaries,
                 # and the keys and values of the hit's own window
@@ -1708,11 +1777,12 @@ class BatchScheduler:
 
     def _publish_row(self, stream: BatchStream, tokens: np.ndarray, chain: list) -> None:
         """Publish the admission prefill's completed full pages back into
-        the tree (blocks beyond the matched chain) — the ONLY copy in the
-        zero-copy design: the row's private suffix KV becomes immutable
-        shared pages. The matched chain's refs are NOT released here: the
-        row keeps reading those pages through its table until it resets,
-        quarantines or rolls back below them."""
+        the tree (blocks beyond the matched chain): the row's private
+        suffix KV becomes immutable shared pages. The matched chain's refs
+        are NOT released here: a tp row keeps reading those pages through
+        its table until it resets, quarantines or rolls back below them
+        (a one-chip row has copied them; releasing its pins after the copy
+        would change the eviction order and is its own change)."""
         prefix = self._prefix
         page = prefix.page
         with self._cond:
@@ -1765,6 +1835,15 @@ class BatchScheduler:
                         if not isinstance(e, Exception):
                             raise
                         print(f"⚠️ prefix publish failed; pages unwound: {e}")
+
+    @staticmethod
+    def _restore_runs(ids: list[int]):
+        """``(page_ids [2, h], second)`` of :func:`_restore_pages` for a chain
+        of pages ``ids``: its first and its last ``h`` pages, ``h`` the largest
+        power of two that is at most their number, and the block at which the
+        second run starts."""
+        h = next_pow2(len(ids) + 1) // 2
+        return np.asarray([ids[:h], ids[-h:]], np.int32), np.int32(len(ids) - h)
 
     def _eva_restore(self, slab, row: int, pages: list[int], tail: list[int], first: int):
         """The (donated) slab with an EVA hit copied into its row ``row``: the
@@ -1824,9 +1903,9 @@ class BatchScheduler:
                 print(f"⚠️ window-tail publish failed; pages detached: {e}")
 
     # ------------------------------------------------------------------
-    # Zero-copy alias lifetime (ISSUE 7): pins released at reset/
-    # quarantine, truncated on rollback; page tables materialized per
-    # dispatch under the cond lock.
+    # Alias lifetime (ISSUE 7): pins released at reset/quarantine,
+    # truncated on rollback; the read alias (page tables, empty where hits
+    # are copied into rows) materialized per dispatch under the cond lock.
     # ------------------------------------------------------------------
 
     def _release_pins_locked(self, stream: BatchStream) -> None:
@@ -1852,11 +1931,11 @@ class BatchScheduler:
 
     def _truncate_alias(self, stream: BatchStream, pos: int) -> None:
         """Shrink ``stream``'s alias to ``pos`` after a rollback below its
-        matched prefix: positions < pos keep reading the pool (a rollback
-        lands on a shared TOKEN prefix, so those pages' bytes stay the
-        right KV), pages wholly at or beyond ``pos`` lose their pins. The
-        next prefill writes the slab from ``pos`` up, and the per-position
-        select reads it there."""
+        matched prefix: positions < pos keep their bytes (a rollback lands
+        on a shared TOKEN prefix, so what the pages held, in the row's slab
+        or read in place, stays the right KV), pages wholly at or beyond
+        ``pos`` lose their pins. The next prefill writes the slab from
+        ``pos`` up, and every read takes it from there."""
         with self._cond:
             if stream.matched_len <= pos:
                 return
@@ -1921,10 +2000,15 @@ class BatchScheduler:
     def _alias_arrays_locked(self, rows, live):
         """Per-dispatch page tables [len(rows), n_table] + matched lengths
         (cond held; ``live`` is :meth:`_row_dispatch_arrays_locked`'s
-        liveness mask — the ONE definition — not re-derived here): LIVE
-        rows without an alias (a miss, or retired mid-build) get matched 0
-        — the paged program reads their slab rows only, byte-identical to
-        the unpaged dispatch. Bucket-padding rows (not joined: outputs
+        liveness mask — the ONE definition — not re-derived here). Where a
+        hit's pages were copied into its row (``_hit_restores``: one chip)
+        every row's read alias is EMPTY: zero tables and matched 0, so
+        ``paged_segments`` yields no pool-only and no mixed chunk and the
+        scan reads the slab alone, as for a cold row. Where rows alias the
+        pool (the tp backend): LIVE rows without an alias (a miss, or
+        retired mid-build) get matched 0 — the paged program reads their
+        slab rows only, byte-identical to the unpaged dispatch.
+        Bucket-padding rows (not joined: outputs
         discarded, cache writes dropped) instead get the max LIVE matched
         length, so a partially-occupied bucket never drags
         ``paged_segments``' pool-only bound down to the mixed path (which
@@ -1932,6 +2016,8 @@ class BatchScheduler:
         page 0 garbage, which nothing observes."""
         tables = np.zeros((len(rows), self._n_table), np.int32)
         matched = np.zeros(len(rows), np.int32)
+        if self._hit_restores:
+            return tables, matched
         for b, s in enumerate(rows):
             if live[b] and s._alias_ids:
                 tables[b, : len(s._alias_ids)] = s._alias_ids
@@ -1945,7 +2031,7 @@ class BatchScheduler:
         builders (cond held): the liveness mask (which is the program's
         ``active``) plus positions / sampling params / folded seeds, inert
         defaults in non-live slots (bucket padding, or rows retired
-        mid-build), and the zero-copy alias arrays when the pool is on
+        mid-build), and the read-alias arrays when the pool is on
         (None otherwise). All of them host numpy buffers: they cross to
         the device with the dispatch, as whole vectors, and building them
         issues no device operation whatever the bucket. One definition so
@@ -1974,10 +2060,13 @@ class BatchScheduler:
 
     def _alias_row_arrays_locked(self, stream: BatchStream):
         """Single-row form of :meth:`_alias_arrays_locked` (the chunked
-        prefill dispatch)."""
+        prefill dispatch): empty where the hit was copied into the row."""
         table = np.zeros(self._n_table, np.int32)
-        table[: len(stream._alias_ids)] = stream._alias_ids
-        return jnp.asarray(table), jnp.int32(stream.matched_len)
+        matched = 0
+        if not self._hit_restores:
+            table[: len(stream._alias_ids)] = stream._alias_ids
+            matched = stream.matched_len
+        return jnp.asarray(table), jnp.int32(matched)
 
     def check_prefix(self) -> None:
         """Tree invariants extended with alias tracking: no page freed or

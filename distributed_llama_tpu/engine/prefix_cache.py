@@ -447,10 +447,6 @@ class PrefixCache:
         if chain:
             self.tel.hits.inc()
             self.tel.matched_tokens.observe(len(chain) * page)
-            # the copy design gathered every matched page into the slab row
-            # (and kept the duplicate for the row's lifetime): count the
-            # copy traffic the zero-copy read avoids per hit
-            self.tel.copy_bytes_saved.inc(len(chain) * self.page_bytes)
         else:
             self.tel.misses.inc()
         self._set_pinned_gauge()

@@ -1043,9 +1043,10 @@ def init_page_pool(
 ) -> list[tuple[jax.Array, jax.Array]]:
     """Prefix-cache page pool: a list of per-layer ``(keys, values)`` halves
     of [n_pages, page, Kl, hd] (engine.prefix_cache). Pages hold immutable,
-    refcounted KV prefixes published from slab rows; decode attention reads
-    them zero-copy through per-row page tables (ops.attention paged
-    variants), so each cached byte exists exactly once. The HBM budget is
+    refcounted KV prefixes published from slab rows; a prefix hit copies
+    them into its row at admission (one chip) or reads them in place through
+    its page table (the tp backend: ops.attention paged variants). The HBM
+    budget is
     n_pages * :func:`page_pool_bytes` — configured with ``--kv-pages`` on
     the serving surface."""
     from distributed_llama_tpu.ops import kv_cache as kvc
@@ -1091,8 +1092,8 @@ def init_window_pool(cfg: LlamaConfig, n_pages: int, page: int, dtype=jnp.float3
 def page_pool_bytes(cfg: LlamaConfig, page: int, dtype, layers: int | None = None) -> int:
     """Logical KV bytes one pool page holds across the pool's layers (the
     full ones; ``layers``: of that many instead) and both halves (the
-    telemetry/bench accounting unit for pool occupancy and the copy traffic
-    zero-copy aliasing avoids)."""
+    telemetry accounting unit for pool occupancy and for the bytes a prefix
+    hit copies into its row)."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     kl, hd = cfg.n_kv_heads, cfg.head_size

@@ -242,12 +242,13 @@ def slab_put_row(half, row_half, row):
 # Page pool (engine.prefix_cache): immutable prefix KV pages shared across
 # requests. A pool half is [P, page, K, hd] — the same dtype/pytree rules as
 # the slab (i8 pools carry [P, page, K, 1] scales), so published pages hold
-# the EXACT cache bytes of the row they came from, and the zero-copy paged
-# read (pool_chunk/select_kv below, consumed by ops.attention's paged
-# variants) sees bytes identical to what the PR 4 copy design gathered into
-# the slab (the prefix-hit == cold-prefill bit-parity contract). Cached
-# bytes exist ONCE — in the pool — and rows alias them through per-row page
-# tables instead of holding duplicates.
+# the EXACT cache bytes of the row they came from. A prefix hit takes them
+# back one of two ways, both bit-identical to a cold prefill (the prefix-hit
+# == cold-prefill parity contract): on one chip the scheduler COPIES the
+# matched pages into the row's slab once, at admission
+# (:func:`restore_row_blocks`), and every later read is a slab read; the tp
+# backend reads them IN PLACE through per-row page tables
+# (pool_chunk/select_kv below, consumed by ops.attention's paged variants).
 # ---------------------------------------------------------------------------
 
 
@@ -265,10 +266,10 @@ def pool_page_size(pool_half) -> int:
 
 def gather_pool_pages(pool_half, ids):
     """Read pool pages ``ids`` [..., n] -> [..., n*page, K, hd]: the
-    zero-copy page-table read. The gathered positions are CONSUMED by the
-    attention einsums in-register — nothing is written back to the slab, so
-    cached bytes exist exactly once (in the pool). Out-of-bounds ids clamp
-    (jnp gather default); callers mask those positions out by ``matched``."""
+    page-table read. The gathered positions are CONSUMED by the attention
+    einsums in-register — nothing is written back to the slab. Out-of-bounds
+    ids clamp (jnp gather default); callers mask those positions out by
+    ``matched``."""
     if isinstance(pool_half, QuantizedKV):
         d = pool_half.data[ids]  # [..., n, page, K, hd]
         s = pool_half.scales[ids]
@@ -446,6 +447,29 @@ def restore_row_pages(slab_leaf, pool_k, pool_v, row, dst_page, page_ids, page: 
             slab_leaf.scales.at[:, row, slots].set(both(pool_k.scales, pool_v.scales)),
         )
     return slab_leaf.at[:, row, slots].set(both(pool_k, pool_v))
+
+
+def restore_row_blocks(slab_leaf, pool_k, pool_v, row, first, page_ids):
+    """Pool pages ``page_ids[i]`` (keys and values) into block ``first + i`` of
+    fused slab leaf ``[2, B, S, K, hd]``'s row ``row``: consecutive blocks,
+    so ONE contiguous ``dynamic_update_slice`` a leaf (two for i8) and no
+    scatter (:func:`restore_row_pages` is the form for blocks that wrap a ring
+    or sit behind a base). Every entry is real: nothing but those blocks of
+    that row changes. Needs ``(first + len(page_ids)) * page <= S`` (a start
+    that would pass the row's end is clamped). Returns the updated leaf
+    (callers donate the slab)."""
+
+    def put(a, k, v):
+        kv = jnp.stack([k[page_ids], v[page_ids]])  # [2, n, page, K, x]
+        run = kv.reshape((2, 1, kv.shape[1] * kv.shape[2]) + kv.shape[3:])
+        return jax.lax.dynamic_update_slice(a, run, (0, row, first * kv.shape[2], 0, 0))
+
+    if isinstance(slab_leaf, QuantizedKV):
+        return QuantizedKV(
+            put(slab_leaf.data, pool_k.data, pool_v.data),
+            put(slab_leaf.scales, pool_k.scales, pool_v.scales),
+        )
+    return put(slab_leaf, pool_k, pool_v)
 
 
 # ---------------------------------------------------------------------------

@@ -2097,22 +2097,24 @@ def main(argv=None) -> None:
         "single-chip and --tp backends, --decode device). "
         "--no-batch-decode restores independent per-request dispatches",
     )
-    # zero-copy paged prefix cache (ISSUE 4 + 7, docs/PERF.md)
+    # paged prefix cache (ISSUE 4, 7, 40, docs/PERF.md)
     parser.add_argument(
         "--prefix-cache", action=argparse.BooleanOptionalAction, default=True,
         help="reuse published KV pages for repeated prompt prefixes "
-        "(radix tree over token blocks; a hit binds the matched pages to "
-        "the row's page table — decode reads them zero-copy out of the "
-        "shared pool — and only the unmatched suffix prefills: the chat "
-        "system-prompt workload's TTFT and HBM win). Requests opt out per "
-        "call with body field 'cache': \"off\". Batched serving on the "
-        "single-chip and --tp backends",
+        "(radix tree over token blocks; a hit skips the matched tokens' "
+        "prefill and only the unmatched suffix prefills: the chat "
+        "system-prompt workload's TTFT win. On one chip the matched pages "
+        "are copied into the request's row once, at admission, and decode "
+        "reads the row alone; under --tp the row reads them in place "
+        "through its page table). Requests opt out per call with body "
+        "field 'cache': \"off\". Batched serving on the single-chip and "
+        "--tp backends",
     )
     parser.add_argument(
         "--kv-pages", type=int, default=None,
-        help="page-pool HBM budget in pages for --prefix-cache. With "
-        "zero-copy aliasing the pool is the PRIMARY store of cached "
-        "prefixes (rows hold no duplicates), so the default is "
+        help="page-pool HBM budget in pages for --prefix-cache. A live row "
+        "pins the pages it matched for its lifetime, and the pool holds "
+        "what later prompts resume from, so the default is "
         "--parallel x ceil(seq_len/page) plus 25%% headroom; a pool "
         "smaller than one slab's worth warns (concurrent long prompts "
         "contend for pinned pages), 0 disables the prefix cache. The LRU "

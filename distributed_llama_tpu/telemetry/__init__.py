@@ -470,21 +470,30 @@ class PrefixCacheInstruments:
         self.bytes = gauge(
             "dllama_prefix_cache_bytes",
             "Logical KV bytes held by the radix tree's pool pages (pages "
-            "gauge x per-page bytes across all layers and both halves) — "
-            "with zero-copy aliasing this is the ONLY resident copy of "
-            "cached prefixes",
+            "gauge x per-page bytes across all layers and both halves): "
+            "what a later prompt can resume from (a live hit row on one "
+            "chip holds a copy of its matched pages in its slab besides)",
         )
         self.pinned_pages = gauge(
             "dllama_prefix_cache_pinned_pages",
             "Pool pages ref-pinned against eviction — held for the "
-            "lifetime of rows reading them zero-copy through their page "
-            "tables (plus publishes in flight)",
+            "lifetime of the rows that matched them (a tp row reads them "
+            "in place through its page table; a one-chip row has copied "
+            "them and keeps the pins all the same), plus publishes in "
+            "flight",
         )
-        self.copy_bytes_saved = counter(
-            "dllama_prefix_cache_copy_bytes_saved_total",
-            "HBM copy traffic avoided by zero-copy paged attention: bytes "
-            "the copy design would have gathered into the slab row per "
-            "prefix hit (matched pages x per-page bytes)",
+        self.restores = counter(
+            "dllama_prefix_cache_restores_total",
+            "Prefix hits whose matched pages were copied into the row's "
+            "slab at admission (one chip: the row then decodes from its "
+            "slab alone; a tp backend reads the pool in place and counts "
+            "nothing here)",
+        )
+        self.restored_bytes = counter(
+            "dllama_prefix_cache_restored_bytes_total",
+            "KV bytes copied from pool pages into rows by those restores "
+            "(matched pages x per-page bytes per hit): what a hit pays "
+            "once so that no decode step reads the pool",
         )
         self.matched_tokens = histogram(
             "dllama_prefix_cache_matched_tokens",
@@ -653,8 +662,9 @@ def note_kernel_path(kernel: str, path: str) -> None:
             "dllama_kernel_path_total",
             "Kernel dispatch decisions by kernel (q40_matmul / "
             "paged_attention / all_reduce) and selected path (mxu_int8 / "
-            "mxu_int8_fusedq / xla_segmented / ici_ring / ring_xla / "
-            "psum / xla_fallback); counted at trace time per program build",
+            "mxu_int8_fusedq / xla_segmented / slab_restored / ici_ring / "
+            "ring_xla / psum / xla_fallback); counted at trace time per "
+            "program build",
             labelnames=("kernel", "path"),
         ).labels(kernel=kernel, path=path).inc()
 

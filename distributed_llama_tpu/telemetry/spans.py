@@ -32,6 +32,7 @@ SPAN_NAMES = (
     "prefix_spill_reload",
     "prefix_spill_fetch",  # the arena's spiller thread (engine/spill.py)
     "prefix_publish",
+    "prefix_restore",  # a one-chip hit's pages enqueued into its row (ISSUE 40)
     "state_snapshot",
     "state_restore",
     "window_tail_publish",

@@ -384,6 +384,40 @@ def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
     _assert_no_slab_sized_temporaries(compiled, slab)
 
 
+@pytest.mark.parametrize("run", [1, 64, 128])
+def test_prefix_restore_writes_the_slab_in_place(one_chip, run):
+    """``engine.batch._restore_pages`` at ``mistral7b.long_doc_qa``'s shapes
+    (8 rows x 8192 positions, 1024 pages of 64, bf16; 2 of its layers), for a
+    hit of one page, of 64–127 and of a whole row: every leaf comes back in
+    the buffer it was donated in, written by two contiguous in-place updates,
+    and nothing else of half a leaf's size forms (a copy of the slab a hit
+    would cost more than the decode steps it saves)."""
+    from distributed_llama_tpu.engine import batch
+
+    rows, seq, pages = 8, 8192, 1024
+    cfg = LlamaConfig(
+        arch=ArchType.LLAMA, dim=4096, hidden_dim=14336, n_layers=SERVED_LAYERS,
+        n_heads=32, n_kv_heads=8, vocab_size=32000, seq_len=seq, head_size=128,
+        kv_dim=1024, rope_theta=1e6,
+    )
+
+    def placed(make):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), jax.eval_shape(make))
+
+    slab = placed(lambda: llama.init_batch_cache(cfg, rows, dtype=jnp.bfloat16))
+    pool = placed(lambda: llama.init_page_pool(cfg, pages, SERVED_PAGE, dtype=jnp.bfloat16))
+    assert [leaf.shape for leaf in slab] == [(2, rows, seq, 8, 128)] * SERVED_LAYERS
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((2, run), jnp.int32, sharding=one_chip)
+    compiled = batch._restore_pages.lower(slab, pool, ids, scalar, scalar).compile()
+    writes, others = _slab_sized_results(compiled.as_text(), slab[0].size // 2)
+    assert len(writes) == 2 * SERVED_LAYERS, writes
+    assert not others, "slab-sized buffers besides the two writes a leaf:\n" + "\n".join(others)
+    assert _aliased_outputs(compiled.as_text()) == {l: l for l in range(SERVED_LAYERS)}
+    assert compiled.memory_analysis().temp_size_in_bytes < slab[0].size // 64
+
+
 @pytest.mark.parametrize(
     "impl,marker",
     [("psum", "all-reduce"), ("ring_xla", "collective-permute"), ("ring", "tpu_custom_call")],

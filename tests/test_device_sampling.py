@@ -167,9 +167,9 @@ class TestHostDeviceParity:
             assert outs[i] == host, (i, outs[i], host)
 
     def test_paged_parity(self, tmp_path):
-        """A sampled prefix-cache HIT (decode reading pool pages zero-copy)
-        must still replay on the host — the paged read changes where KV
-        comes from, never what is sampled."""
+        """A sampled prefix-cache HIT (its pages copied into the row, or read
+        in place under tp) must still replay on the host — a hit changes
+        where KV comes from, never what is sampled."""
         t, tp, k, sd = 0.9, 0.8, 0, 29
         prompt = [1, 5, 9, 2, 8, 4, 6, 3] * 2  # spans full pages
         engine = build_engine(tmp_path, "paged.m")

@@ -545,12 +545,12 @@ class TestPinLifetime:
 
 
 # ---------------------------------------------------------------------------
-# Telemetry: pool bytes / pinned pages / copy-bytes-saved
+# Telemetry: pool bytes / pinned pages / restored bytes
 # ---------------------------------------------------------------------------
 
 
 class TestPagedTelemetry:
-    def test_gauges_and_saved_counter(self, tmp_path):
+    def test_gauges_and_restored_counter(self, tmp_path):
         from distributed_llama_tpu import telemetry
         from distributed_llama_tpu.models import llama
 
@@ -569,14 +569,15 @@ class TestPagedTelemetry:
             reg = telemetry.REGISTRY
             assert reg.gauge("dllama_prefix_cache_bytes").value == 2 * page_bytes
             assert reg.counter(
-                "dllama_prefix_cache_copy_bytes_saved_total"
+                "dllama_prefix_cache_restored_bytes_total"
             ).value == 0  # no hit yet
             s.reset()
-            s.prefill(PROMPT)  # hit: 2 pages aliased, pinned for the row
+            s.prefill(PROMPT)  # hit: 2 pages copied into the row, pinned for it
             assert reg.gauge("dllama_prefix_cache_pinned_pages").value == 2
             assert reg.counter(
-                "dllama_prefix_cache_copy_bytes_saved_total"
+                "dllama_prefix_cache_restored_bytes_total"
             ).value == 2 * page_bytes
+            assert reg.counter("dllama_prefix_cache_restores_total").value == 1
             s.reset()
             assert reg.gauge("dllama_prefix_cache_pinned_pages").value == 0
         finally:

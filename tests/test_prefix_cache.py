@@ -115,8 +115,8 @@ class TestPageOps:
         """seq_len not a multiple of the page size: the virtual page table
         covers ceil(S/page) entries and clamps its over-gather back to S —
         a prefix hit must stream bit-identically to the cold run, and the
-        row's slab tail holds no stray writes (zero-copy admission writes
-        nothing at all below matched)."""
+        row's slab tail holds no stray writes (a hit's copy writes the
+        chain's own blocks and nothing past them)."""
         spec = tiny_spec(seq_len=90)  # 90 % 4 != 0
         path = str(tmp_path / "unaligned.m")
         write_model_file(path, spec, random_tensors(spec, seed=0))
@@ -133,7 +133,7 @@ class TestPageOps:
             (np.asarray(leaf[0])[0, 80:].copy(), np.asarray(leaf[1])[0, 80:].copy())
             for leaf in sched._slab
         ]
-        hit = decode_tokens(s, prompt, 0.0, 0.9, 7, 4)  # 3-page alias bind
+        hit = decode_tokens(s, prompt, 0.0, 0.9, 7, 4)  # 3 pages copied into the row
         assert hit == cold
         for l, ((kb, vb), leaf) in enumerate(zip(tail_before, sched._slab)):
             np.testing.assert_array_equal(
@@ -473,8 +473,8 @@ class TestMisconfiguration:
             assert decode_tokens(s, PROMPT, 0.0, 0.9, 7, 4)
 
     def test_default_budget_is_slab_plus_headroom(self, tmp_path):
-        """With zero-copy aliasing the pool is the PRIMARY prefix store
-        (rows hold no duplicates), so the default budget is one slab's
+        """A live row pins the pages it matched for its lifetime and the
+        pool holds what later prompts resume from, so the default budget is one slab's
         worth of pages plus 25% headroom (at least one row's worth) for
         prefixes outliving their rows."""
         engine = build_engine(tmp_path, seq_len=96)
