@@ -96,6 +96,7 @@ import collections
 import functools
 import threading
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -108,7 +109,7 @@ from distributed_llama_tpu.engine.speculative import PromptLookupDrafter
 from distributed_llama_tpu.models import llama
 from distributed_llama_tpu.models.config import LlamaConfig
 from distributed_llama_tpu.ops import kv_cache as kvc
-from distributed_llama_tpu.telemetry import Stopwatch, flight
+from distributed_llama_tpu.telemetry import Stopwatch, device_ledger, flight
 
 
 # pool pages per recurrent-state snapshot slot: `--kv-pages` // 16 slots. A
@@ -379,6 +380,10 @@ class BatchStream:
         # never per pop)
         self._delivered = 0
         self._joined = False
+        # the position at which this row's request stops asking for tokens
+        # (stream_decode sets it): a joined row below it needs another chunk,
+        # which is what the completion ledger calls work in hand
+        self._stop_pos = engine.cfg.seq_len
         self._epoch = 0  # bumped per join/leave: stale fetches can't deliver
         self._seed32 = 0  # folded uint32 request seed (stateless counter PRNG)
         self._temperature = 0.0
@@ -554,7 +559,6 @@ class BatchStream:
         if engine._tel.enabled:
             engine._tel.prompt_tokens.inc(n)
             engine._tel.prefill_latency.observe(entry.generation_ms / 1000.0)
-            engine._tel.kv_occupancy.set(self.pos / engine.cfg.seq_len)
         return out
 
     def prefill_device(self, tokens, temperature, topp, seed: int, topk: int = 0):
@@ -585,6 +589,7 @@ class BatchStream:
                         jnp.int32(self.pos - 1), jnp.float32(temperature),
                         jnp.float32(topp), jnp.int32(topk),
                     )
+                    self.scheduler._ledger.counted("sample_row")
             entry = engine._split_stats(sw.elapsed_ms(), n_tokens=n)
             self.stats.append(entry)
             self._pending_prefill_entry = entry
@@ -621,7 +626,6 @@ class BatchStream:
                 tel.prefill_latency.observe(entry.generation_ms / 1000.0)
                 tel.tokens_generated.inc(1)
                 tel.device_sampled_tokens.inc(1)
-                tel.kv_occupancy.set(self.pos / engine.cfg.seq_len)
         return tok
 
     def _hold_depth(self) -> None:
@@ -694,6 +698,7 @@ class BatchStream:
             self._history.append(prev)
             self._spec_on = bool(spec_draft and spec_draft > 0)
             first_token = prev  # host int: the next verify window's feed[0]
+        self._stop_pos = stop  # the chunks must carry the row's position this far
         sched._join(self, first_token, temperature, topp, seed, topk)
         # the consumer's side of the pump on the profiler's timeline, one
         # span per refill of this row's queue (a span that began before a
@@ -803,6 +808,18 @@ class BatchScheduler:
         self.engine = engine
         self.b_max = n_rows
         self.chunk = int(chunk)
+        # the completion ledger (ISSUE 41, telemetry/device_ledger.py): when
+        # each dispatched program finished, so that a window's device time
+        # divides by program; NULL_LEDGER (no thread, no queue) with
+        # telemetry off. Bound before the programs this constructor builds
+        # (it counts their launches too). ``_prompts_open``: prompts inside
+        # _prefill_row, which with the joined rows that need another chunk is
+        # the work the ledger's idle is told apart by
+        self._ledger = device_ledger.bind(engine._tel.enabled)
+        if self._ledger.enabled:
+            # a scheduler that is dropped without close() takes its watcher with it
+            weakref.finalize(self, self._ledger.close)
+        self._prompts_open = 0
         # Sarathi-style chunked prefill (ISSUE 4 satellite): a long prompt
         # is dispatched in prefill_chunk-token pieces with the scheduler
         # lock RELEASED between dispatches, so decode chunks for other rows
@@ -1060,6 +1077,8 @@ class BatchScheduler:
         # allocated past their request's own use
         self._last_piece = None
         self._pieces_first = None
+        # their entries on the completion ledger, kept beside them
+        self._last_piece_entry = self._pieces_first_entry = device_ledger.NULL_ENTRY
         # row buckets whose plain decode program has been dispatched (built)
         self._decode_built: set[int] = set()
         # (device scalar, tokens) of prefill chunks whose held-expert sums are
@@ -1148,7 +1167,7 @@ class BatchScheduler:
         self._cond = lockcheck.make_condition("BatchScheduler._cond")
         # one dispatched-but-unfetched chunk at a time: (mode, tokens_dev,
         # epoch snapshot, bucket, active count, stopwatch, spec draft lens,
-        # build-start and dispatched instants)
+        # build-start and dispatched instants, the chunk's ledger entry)
         self._pending = None
         self._fetching = False
         # fetch generation: bumped when a thread takes the pending chunk; the
@@ -1175,6 +1194,7 @@ class BatchScheduler:
             self._cond.notify_all()
         if self._own_arena is not None:
             self._own_arena.close()
+        self._ledger.close()
 
     # ------------------------------------------------------------------
     # Replica loss (ISSUE 9): the whole-scheduler failure domain. A crash
@@ -1373,28 +1393,54 @@ class BatchScheduler:
         chain: list = []
         suffix = tokens
         stream._snap_at = None
-        if admission:
-            chain = self._match_alias(stream, tokens)
-            if chain:
-                suffix = tokens[len(chain) * self._prefix.page :]
-            if self._snaps is not None:
-                # the state where the last full page ends is what a later
-                # prompt resumes from: a prefill chunk must end there
-                stream._snap_at = n // self._prefix.page * self._prefix.page
+        self._prompt_open(+1)
         try:
-            logits, last = self._dispatch_prefill_chunks(stream, suffix)
-        except BaseException:
-            # a failed suffix prefill fails the request: unwind the alias
-            # bind (release the chain pins, reset the position) so the
-            # row is clean for its next occupant and the pages evictable
-            self._drop_row_snapshot(stream)
-            if chain:
-                self._release_row_pins(stream)
-                stream.pos = 0
-            raise
-        if admission:
-            self._publish_row(stream, tokens, chain)
+            if admission:
+                chain = self._match_alias(stream, tokens)
+                if chain:
+                    suffix = tokens[len(chain) * self._prefix.page :]
+                if self._snaps is not None:
+                    # the state where the last full page ends is what a later
+                    # prompt resumes from: a prefill chunk must end there
+                    stream._snap_at = n // self._prefix.page * self._prefix.page
+            try:
+                logits, last = self._dispatch_prefill_chunks(stream, suffix)
+            except BaseException:
+                # a failed suffix prefill fails the request: unwind the alias
+                # bind (release the chain pins, reset the position) so the
+                # row is clean for its next occupant and the pages evictable
+                self._drop_row_snapshot(stream)
+                if chain:
+                    self._release_row_pins(stream)
+                    stream.pos = 0
+                raise
+            if admission:
+                self._publish_row(stream, tokens, chain)
+        finally:
+            self._prompt_open(-1)
         return logits, last
+
+    def _prompt_open(self, step: int) -> None:
+        """A prompt enters (+1) or leaves (-1) :meth:`_prefill_row`: with the
+        ledger on, the scheduler's work in hand changes with it."""
+        if self._ledger.enabled:
+            with self._cond:
+                self._prompts_open += step
+                self._note_work_locked()
+
+    def _note_work_locked(self) -> None:
+        """Tell the ledger whether the scheduler has work in hand (cond
+        held; called where that can change: a prompt enters or leaves, a row
+        joins or leaves, a chunk moves its rows' positions on): a prompt not
+        yet wholly dispatched, or a joined row whose request needs another
+        chunk. Idle time of the device from then on is ``work_waiting``."""
+        if self._ledger.enabled:
+            if self._prompts_open > 0 or any(
+                s._joined and s.pos < s._stop_pos for s in self._streams
+            ):
+                self._ledger.work_began()
+            else:
+                self._ledger.work_ended()
 
     def _dispatch_prefill_chunks(self, stream: BatchStream, tokens: np.ndarray):
         """Dispatch a (suffix-offset) prompt at ``stream.pos``, chunked at
@@ -1467,6 +1513,7 @@ class BatchScheduler:
                         )
                         stream._fetch_error = None
                         raise err
+                    held = None
                     if self._pool is not None:
                         # pool-enabled scheduler: every prefill runs the paged
                         # program — an unaliased row dispatches with matched 0
@@ -1499,7 +1546,15 @@ class BatchScheduler:
                         )
                     self._note_summaries(stream.pos, c)
                     stream.pos += c
+                    # the smallest output nothing donates onward is what the
+                    # ledger's watcher waits for
                     self._last_piece = logits
+                    self._last_piece_entry = self._ledger.dispatched(
+                        "prefill_piece", logits if held is None else held, trace=tr,
+                        bucket=bucket, rows=c, row=stream.row,
+                        request="" if tr is None else tr.request_id,
+                    )
+                    self._ledger.piece_rows(c, bucket - c)
                     if stream.pos == cut:
                         self._take_snapshot_locked(stream)
             off += c
@@ -1581,6 +1636,7 @@ class BatchScheduler:
             self._snaps = _snapshot_take(
                 self._slab, self._snaps, jnp.int32(stream.row), jnp.int32(slot)
             )
+        self._ledger.counted("snapshot")
         stream._snap_slot = slot
         self._prefix.tel.snapshots_taken.inc()
 
@@ -1605,6 +1661,7 @@ class BatchScheduler:
         for. A publish's victims past what the arena can keep are never
         sliced (``HostArena.keeps``), so this is at most the arena's budget
         of launches and of device bytes in flight."""
+        self._ledger.counted("spill_slice", len(pids))
         return [_slice_page(self._pool, np.int32(pid)) for pid in pids]
 
     @staticmethod
@@ -1668,6 +1725,7 @@ class BatchScheduler:
                 self._pool = _upload_page(  # dllama: noqa[LCK-004]
                     self._pool, np.int32(pid), self._page_pytree(arrays)
                 )
+                self._ledger.counted("spill_reload")
 
         return prefix.reload(tokens, upload, pre=pre)
 
@@ -1728,6 +1786,7 @@ class BatchScheduler:
                         self._slab, self._snaps, jnp.int32(stream.row),
                         jnp.int32(chain[-1].snap),
                     )
+                self._ledger.counted("snapshot")
                 prefix.tel.snapshots_restored.inc()
             if self._hit_restores:
                 # copied into the row, not aliased: the full layers' pages at
@@ -1740,6 +1799,7 @@ class BatchScheduler:
                         self._slab, self._pool, *self._restore_runs(stream._alias_ids),
                         np.int32(stream.row),
                     )
+                self._ledger.counted("restore")
                 prefix.tel.restores.inc()
                 prefix.tel.restored_bytes.inc(len(chain) * prefix.page_bytes)
             if self.engine.cfg.has_eva:
@@ -1773,6 +1833,7 @@ class BatchScheduler:
                         prefix.page, self._slab, self._wpool, pages, blocks,
                         jnp.int32(stream.row), ring=self._wslots.ring,
                     )
+                self._ledger.counted("window_tail")
         return chain
 
     def _publish_row(self, stream: BatchStream, tokens: np.ndarray, chain: list) -> None:
@@ -1823,6 +1884,7 @@ class BatchScheduler:
                             self._pool = self.engine._tp_engine.publish_pages(
                                 self._slab, self._pool, ids, src, stream.row,
                             )
+                        self._ledger.counted("publish")
                     except BaseException as e:
                         # the copy never dispatched: the just-inserted
                         # nodes map blocks to pages holding garbage (or
@@ -1862,12 +1924,14 @@ class BatchScheduler:
             self._sslots.page, slab, self._pool, *padded(pages, 0, self._n_table),
             jnp.int32(row), base=self._sslots.base,
         )
+        self._ledger.counted("restore")
         if tail:
             slab = _restore_window_tail(
                 self._wslots.page, slab, self._wpool,
                 *padded(tail, first, self._prefix.window_align), jnp.int32(row),
                 ring=self._wslots.ring,
             )
+            self._ledger.counted("window_tail")
         return slab
 
     def _publish_window_tail_locked(self, stream: BatchStream, tokens: np.ndarray, hit: int) -> None:
@@ -1896,6 +1960,7 @@ class BatchScheduler:
                     prefix.page, self._slab, self._wpool, padded, src, jnp.int32(stream.row),
                     ring=self._wslots.ring,
                 )
+                self._ledger.counted("window_tail")
             except BaseException as e:
                 prefix.detach_window_pages(tokens, blocks)
                 if not isinstance(e, Exception):
@@ -2105,6 +2170,7 @@ class BatchScheduler:
                 self._carry = _carry_put(
                     self._carry, np.int32(stream.row), first_token
                 )
+                self._ledger.counted("carry_put")
             stream._temperature = float(temperature)
             stream._topp = float(topp)
             stream._topk = int(topk)
@@ -2129,6 +2195,7 @@ class BatchScheduler:
                 # (retract_preemption), and a lost replica never seats a
                 # new request (placement skips dead replicas)
                 stream._fetch_error = None
+            self._note_work_locked()
             self._cond.notify_all()
 
     # ------------------------------------------------------------------
@@ -2232,6 +2299,7 @@ class BatchScheduler:
             stream._joined = False
             stream._queue.clear()
             stream._epoch += 1
+            self._note_work_locked()
             self._cond.notify_all()
         # a request that stopped at its fused first token (immediate EOS)
         # may leave its kicked chunk dispatched-but-unfetched; if no joined
@@ -2300,11 +2368,12 @@ class BatchScheduler:
                     return stream._queue.popleft()
                 if not stream._joined:
                     raise RuntimeError("next_token on a stream that left the batch")
-                piece = None
+                piece = piece_entry = None
                 if self._pending is None:
                     # a no-op while another thread is mid-fetch: the next
                     # chunk goes out once that one is delivered
                     piece = self._dispatch_locked()
+                    piece_entry = self._pieces_first_entry
                     if stream._fetch_error is not None:
                         continue  # the dispatch retired this row: re-loop
                         # raises the typed error without a wait cycle
@@ -2325,6 +2394,7 @@ class BatchScheduler:
                         piece.block_until_ready()
                     except Exception:
                         pass  # a failed piece fails its own request
+                    piece_entry.observed(time.monotonic())
                 continue
             self._fetch(pend, gen)
 
@@ -2556,11 +2626,17 @@ class BatchScheduler:
                 self._note_summaries(s.pos, self.chunk)
                 s.pos += self.chunk
         self._decode_built.add(bucket)
-        self._note_dispatched(bucket, len(joined), self.chunk)
+        self._note_dispatched(bucket, joined, self.chunk)
         self._pending = (
             "chunk", out, [(s, s._epoch) for s in joined], bucket,
             len(joined), sw, None, t_build, time.monotonic(),
+            self._ledger.dispatched(
+                "decode_chunk", out, bucket=bucket, rows=len(joined), gen=self._fetch_gen + 1
+            ),
         )
+        # after the entry took the claim the gap in FRONT of this chunk was
+        # waited under: a row whose request ends inside the chunk needs no other
+        self._note_work_locked()
 
     def _built_bucket(self, bucket: int) -> int:
         """The row bucket to dispatch when ``bucket`` rows would do: itself
@@ -2576,14 +2652,18 @@ class BatchScheduler:
             return bucket
         return min((b for b in self._decode_built if b > bucket), default=bucket)
 
-    def _note_dispatched(self, bucket: int, n_active: int, steps: int) -> None:
-        """Per dispatched chunk: its joined and bucket rows, and the
-        ledger's ``masked`` fate — the bucket rows that ran inactive."""
+    def _note_dispatched(self, bucket: int, joined: list, steps: int) -> None:
+        """Per dispatched chunk: its joined and bucket rows, the ledger's
+        ``masked`` fate — the bucket rows that ran inactive — and how full
+        the slab is: the joined rows' positions over rows x seq_len."""
         tel = self.engine._tel
         if tel.enabled:
-            tel.chunk_rows_active.observe(n_active)
+            tel.chunk_rows_active.observe(len(joined))
             tel.chunk_rows_bucket.observe(bucket)
-            tel.row_steps_masked.inc((bucket - n_active) * steps)
+            tel.row_steps_masked.inc((bucket - len(joined)) * steps)
+            tel.kv_occupancy.set(
+                sum(s.pos for s in joined) / (self.b_max * self.engine.cfg.seq_len)
+            )
 
     def _dispatch_spec_locked(self) -> None:
         """Build and dispatch one batched speculative VERIFY step (cond
@@ -2676,10 +2756,13 @@ class BatchScheduler:
         # and data-dependent); sampler coins re-key from (seed, position)
         engine._tel.spec_draft_tokens.inc(int(lens.sum()))
         # a verify step is one weight read: a masked row is 1 row-step
-        self._note_dispatched(bucket, len(joined), 1)
+        self._note_dispatched(bucket, joined, 1)
         self._pending = (
             "spec", out, [(s, s._epoch) for s in joined], bucket, len(joined),
             sw, lens.copy(), t_build, time.monotonic(),
+            self._ledger.dispatched(
+                "spec_verify", out, bucket=bucket, rows=len(joined), gen=self._fetch_gen + 1
+            ),
         )
 
     def _fetch(self, pend, gen: int) -> None:
@@ -2695,7 +2778,7 @@ class BatchScheduler:
         all."""
         engine = self.engine
         (mode, tokens_dev, snapshot, bucket, n_active, sw, spec_lens, t_build,
-         t_dispatched) = pend
+         t_dispatched, ledger_entry) = pend
         toks = None
         error: Exception | None = None
         t_fetch = time.monotonic()  # since t_dispatched: queued behind a fetch
@@ -2717,7 +2800,9 @@ class BatchScheduler:
             with engine._tel.span("batch_decode_fetch", bucket=bucket):
                 t = time.monotonic()
                 out = np.asarray(tokens_dev)  # [chunk, bucket]
-                waited = time.monotonic() - t
+                done = time.monotonic()
+                ledger_entry.observed(done)  # perhaps before the ledger's watcher woke
+                waited = done - t
                 return out
 
         try:
@@ -2876,6 +2961,7 @@ class BatchScheduler:
             # queued) strictly after chunk N's tokens are in the queues
             self._fetching = False
             self._pieces_first = self._last_piece
+            self._pieces_first_entry = self._last_piece_entry
             for s, epoch in snapshot:
                 if not (s._joined and s._epoch == epoch):
                     # the row left (or its slot has a new occupant) while
@@ -2986,6 +3072,7 @@ class BatchScheduler:
         with self._cond:
             self._fetching = False
             self._pieces_first = self._last_piece
+            self._pieces_first_entry = self._last_piece_entry
             for s, epoch in snapshot:
                 if not (s._joined and s._epoch == epoch):
                     orphaned += len(emits.get(s.row, ())) or 1
@@ -3034,6 +3121,7 @@ class BatchScheduler:
                     if int(lens[s.row]) > 0:
                         tel.spec_acceptance.observe((n_emit - 1) / int(lens[s.row]))
                     tel.spec_step_advance.observe(n_emit)
+            self._note_work_locked()  # the advance was applied here, not at dispatch
             self._cond.notify_all()
         if tel.enabled:
             tel.row_steps_orphaned.inc(orphaned)

@@ -252,8 +252,9 @@ class EngineInstruments:
         )
         self.kv_occupancy = gauge(
             "dllama_kv_cache_occupancy",
-            "KV-cache occupancy of the most recently active stream "
-            "(position / seq_len, 0..1)",
+            "KV-cache occupancy, 0..1: under the batched scheduler the joined "
+            "rows' positions over rows x seq_len, set once a chunk dispatch; "
+            "on the single-stream engine the stream's position / seq_len",
         )
         self.active_streams = gauge(
             "dllama_engine_streams",
@@ -433,6 +434,51 @@ class EngineInstruments:
             "(accepted drafts + 1; plain decode is identically 1)",
             buckets=(1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0, 32.0),
         )
+
+
+class DeviceLedgerInstruments:
+    """The completion ledger's metric surface (bound once per
+    telemetry/device_ledger.py DeviceLedger: one a scheduler). Every series
+    exists at 0 from the bind, so a window's delta can be read from the
+    first scrape on."""
+
+    def __init__(self):
+        from distributed_llama_tpu.telemetry import device_ledger as dl
+
+        self.span = span_factory()
+        seconds = counter(
+            "dllama_device_seconds_total",
+            "Wall seconds since the scheduler bound its ledger, by what the "
+            "device was doing: state=busy by the observed program that ran "
+            "(decode_chunk, prefill_piece, spec_verify; from max(previous "
+            "completion, dispatch) to the earliest instant a thread saw the "
+            "program finished; launches that cannot be waited for fall into "
+            "the next observed interval), state=idle by whether the scheduler "
+            "had work in hand (work_waiting: a joined stream that needs "
+            "another chunk, or a prompt not yet wholly dispatched) or not "
+            "(no_work); the five series sum to the wall time",
+            labelnames=("state", "program"),
+        )
+        self.busy = {p: seconds.labels(state="busy", program=p) for p in dl.OBSERVED}
+        self.idle = {p: seconds.labels(state="idle", program=p) for p in dl.IDLE}
+        programs = counter(
+            "dllama_device_programs_total",
+            "Device programs the scheduler launched, by program: the observed "
+            "ones (decode_chunk, prefill_piece, spec_verify) and, counted at "
+            "dispatch and never waited for, those whose outputs are all "
+            "donated onward (publish, restore, window_tail, spill_slice, "
+            "spill_reload, carry_put, sample_row, snapshot)",
+            labelnames=("program",),
+        )
+        self.launches = {p: programs.labels(program=p) for p in dl.OBSERVED + dl.COUNTED}
+        piece_rows = counter(
+            "dllama_prefill_piece_rows_total",
+            "Rows of the dispatched prompt pieces: kind=real the prompt's "
+            "tokens, kind=pad what the power-of-two bucket added to them",
+            labelnames=("kind",),
+        )
+        self.piece_rows_real = piece_rows.labels(kind="real")
+        self.piece_rows_pad = piece_rows.labels(kind="pad")
 
 
 class PrefixCacheInstruments:

@@ -44,11 +44,16 @@ SPAN_NAMES = (
     "sched_deliver",
     "sched_wait",
     "prefill_chunk_dispatch",
+    # the completion ledger's watcher thread (ISSUE 41,
+    # telemetry/device_ledger.py): one per observed device program, open
+    # while the watcher waits for it
+    "device_interval",
     # request-trace spans (ISSUE 16, telemetry/trace.py): the per-request
     # tree assembled by RequestTraceStore and served at /debug/trace/<id>
     "queue_wait",
     "placement",
     "prefill_chunk",
+    "prefill_chunk_device",  # that piece's interval on the device (the ledger's)
     "decode_stream",
     "batch_decode_chunk_row",
     "spec_verify_row",

@@ -111,3 +111,30 @@ def test_null_instrument_calls_are_submicrosecond():
         g.set(1.0)
     per_call_us = (time.perf_counter() - t0) / (3 * n) * 1e6
     assert per_call_us < 5.0, f"null instrument call costs {per_call_us:.2f} µs"
+
+
+def test_with_telemetry_off_the_scheduler_starts_no_ledger_thread_and_appends_nothing(tmp_path):
+    """The completion ledger (ISSUE 41) is on where ``--telemetry`` is on and
+    nowhere else: with it off the scheduler binds the shared null ledger (no
+    watcher thread, no queue, no clock read) and a served request leaves the
+    registry untouched."""
+    import threading
+
+    from distributed_llama_tpu.engine.batch import BatchScheduler
+    from distributed_llama_tpu.telemetry import device_ledger
+
+    from tests.test_batch_decode import PROMPTS, batch_stream_tokens, build_engine
+
+    telemetry.reset()
+    telemetry.disable()
+    before = set(threading.enumerate())  # an earlier test's schedulers may have left theirs
+    engine = build_engine(tmp_path)
+    sched = BatchScheduler(engine, n_rows=2, chunk=4)
+    assert sched._ledger is device_ledger.NULL_LEDGER and not sched._ledger.enabled
+    stream = sched.new_stream()
+    assert len(batch_stream_tokens(stream, PROMPTS[0], 0.0, 0.9, 3, 6)) == 6
+    assert sched._last_piece_entry is device_ledger.NULL_ENTRY
+    assert sched._prompts_open == 0  # the work in hand is not tracked either
+    assert {t.name for t in set(threading.enumerate()) - before} == set()
+    assert telemetry.REGISTRY.names() == []
+    sched.close()

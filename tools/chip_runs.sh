@@ -7,7 +7,8 @@
 # directory .gitignore lists: `git archive <tree> | tar -x -C <dir>`; `.` is the tree as it
 # stands), and keeps each run's output under chiprun_out/<tag>/. <mode> is 0, 1 or 2 (run.py
 # --trace <mode>), `gaps` (a --trace 2 run through benchmark/tools/gaps_by_span.py, which also
-# prints the capture's tables), or 0c / 2c: the same under a HOME, XDG_CACHE_HOME and TMPDIR
+# prints the capture's tables), `ledger` (the same through benchmark/tools/ledger_vs_trace.py: the
+# completion ledger against the capture, and the window's sum), or 0c / 2c: the same under a HOME, XDG_CACHE_HOME and TMPDIR
 # of their own that start empty, as the driver's check runs them. A run is not started once
 # <budget_s> seconds of the call are gone, nor, with NEED="metric,metric" in the environment,
 # after a run whose last line is not `correct` or lacks one of those metrics (the chip's
@@ -33,6 +34,8 @@ for spec in "$@"; do
   esac
   if [ "$mode" = gaps ]; then
     cmd=(python3 benchmark/tools/gaps_by_span.py --workload "$cell" --seed "$seed" --seconds 51)
+  elif [ "$mode" = ledger ]; then
+    cmd=(python3 benchmark/tools/ledger_vs_trace.py --workload "$cell" --seed "$seed" --seconds 51)
   else
     cmd=(python3 benchmark/run.py --workload "$cell" --seed "$seed" --seconds 51 --trace "$mode")
   fi
