@@ -306,12 +306,18 @@ def _snapshot_restore(slab, store, row, slot):
     ]
 
 
-def _held_of_real(counts, n_real):
-    """The expert choices of a prefill chunk's REAL tokens that fell on a held
-    expert (an int32 scalar), or None for an arch that holds every expert."""
-    if not counts:
+def _moe_of_piece(counts, paths, n_real):
+    """What a prefill chunk's expert layers did, as ONE int32 [3] (one fetch
+    reads it all): the expert choices of its REAL tokens that fell on a held
+    expert (0 for an arch that holds every expert), how many of the layers
+    ran every expert over every row, and how many ran each expert over its
+    own bucket. None for an arch with no expert layer."""
+    if not paths:
         return None
-    return jnp.sum(jnp.where(jnp.arange(counts[0].shape[0]) < n_real, counts[0], 0))
+    held = jnp.int32(0)
+    if counts:
+        held = jnp.sum(jnp.where(jnp.arange(counts[0].shape[0]) < n_real, counts[0], 0))
+    return jnp.concatenate([held[None], paths[0]])
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(3,))
@@ -323,14 +329,16 @@ def _slab_prefill_single(cfg: LlamaConfig, params, tokens, slab, row, pos, n_rea
     aliases every other row in place. Returns (logits [T, vocab], new slab)."""
     row_cache = [kvc.fused_take_row(leaf, row) for leaf in slab]
     counts = [] if cfg.n_routed_experts else None
+    paths = [] if cfg.is_moe else None
     logits, new_rows = llama.forward_tokens(
-        cfg, params, tokens, row_cache, pos, n_real=n_real, held_counts=counts
+        cfg, params, tokens, row_cache, pos, n_real=n_real, held_counts=counts,
+        piece_paths=paths,
     )
     new_slab = [
         kvc.fused_put_row(leaf, new_leaf, row)
         for leaf, new_leaf in zip(slab, new_rows)
     ]
-    return logits, new_slab, _held_of_real(counts, n_real)
+    return logits, new_slab, _moe_of_piece(counts, paths, n_real)
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(3,))
@@ -345,15 +353,16 @@ def _slab_prefill_single_paged(
     pool is read-only — only the slab is donated."""
     row_cache = [kvc.fused_take_row(leaf, row) for leaf in slab]
     counts = [] if cfg.n_routed_experts else None
+    paths = [] if cfg.is_moe else None
     logits, new_rows = llama.forward_tokens(
         cfg, params, tokens, row_cache, pos, n_real=n_real,
-        paged=(pool, table, matched), held_counts=counts,
+        paged=(pool, table, matched), held_counts=counts, piece_paths=paths,
     )
     new_slab = [
         kvc.fused_put_row(leaf, new_leaf, row)
         for leaf, new_leaf in zip(slab, new_rows)
     ]
-    return logits, new_slab, _held_of_real(counts, n_real)
+    return logits, new_slab, _moe_of_piece(counts, paths, n_real)
 
 
 class BatchStream:
@@ -1081,8 +1090,8 @@ class BatchScheduler:
         self._last_piece_entry = self._pieces_first_entry = device_ledger.NULL_ENTRY
         # row buckets whose plain decode program has been dispatched (built)
         self._decode_built: set[int] = set()
-        # (device scalar, tokens) of prefill chunks whose held-expert sums are
-        # not read yet
+        # (device int32 [3], tokens) of prefill chunks whose expert layers'
+        # counts are not read yet
         self._moe_pending: list = []
         if engine.cfg.kv_read_kinds:
             for kind, nbytes in llama.kv_slab_bytes(engine.cfg, n_rows, engine.cache_dtype).items():
@@ -1513,7 +1522,7 @@ class BatchScheduler:
                         )
                         stream._fetch_error = None
                         raise err
-                    held = None
+                    moe = None
                     if self._pool is not None:
                         # pool-enabled scheduler: every prefill runs the paged
                         # program — an unaliased row dispatches with matched 0
@@ -1521,12 +1530,12 @@ class BatchScheduler:
                         # so one compiled program serves hits and misses
                         table, matched = self._alias_row_arrays_locked(stream)
                         if engine._tp_engine is None:
-                            logits, self._slab, held = _slab_prefill_single_paged(
+                            logits, self._slab, moe = _slab_prefill_single_paged(
                                 engine.cfg, engine.params, jnp.asarray(padded),
                                 self._slab, self._pool, jnp.int32(stream.row),
                                 jnp.int32(stream.pos), jnp.int32(c), table, matched,
                             )
-                            self._note_prefill_held_locked(held, c)
+                            self._note_prefill_moe_locked(moe, c)
                         else:
                             logits, self._slab = engine._tp_engine.slab_forward_paged(
                                 engine.params, jnp.asarray(padded), self._slab,
@@ -1534,11 +1543,11 @@ class BatchScheduler:
                                 matched,
                             )
                     elif engine._tp_engine is None:
-                        logits, self._slab, held = _slab_prefill_single(
+                        logits, self._slab, moe = _slab_prefill_single(
                             engine.cfg, engine.params, jnp.asarray(padded), self._slab,
                             jnp.int32(stream.row), jnp.int32(stream.pos), jnp.int32(c),
                         )
-                        self._note_prefill_held_locked(held, c)
+                        self._note_prefill_moe_locked(moe, c)
                     else:
                         logits, self._slab = engine._tp_engine.slab_forward(
                             engine.params, jnp.asarray(padded), self._slab,
@@ -1550,7 +1559,7 @@ class BatchScheduler:
                     # ledger's watcher waits for
                     self._last_piece = logits
                     self._last_piece_entry = self._ledger.dispatched(
-                        "prefill_piece", logits if held is None else held, trace=tr,
+                        "prefill_piece", logits if moe is None else moe, trace=tr,
                         bucket=bucket, rows=c, row=stream.row,
                         request="" if tr is None else tr.request_id,
                     )
@@ -1586,12 +1595,12 @@ class BatchScheduler:
         if c:
             self.engine._tel.eva_summaries_written.inc((pos + n) // c - pos // c)
 
-    def _note_prefill_held_locked(self, held, n_tokens: int) -> None:
-        """Keep a prefill chunk's device sum of held-expert choices for the
-        next decode chunk's delivery to count (cond held). Telemetry off,
-        nothing is kept and nothing is read."""
-        if held is not None and self.engine._tel.enabled:
-            self._moe_pending.append((held, n_tokens))
+    def _note_prefill_moe_locked(self, moe, n_tokens: int) -> None:
+        """Keep what a prefill chunk's expert layers did (``_moe_of_piece``,
+        on the device) for a later decode chunk's delivery to count (cond
+        held). Telemetry off, nothing is kept and nothing is read."""
+        if moe is not None and self.engine._tel.enabled:
+            self._moe_pending.append((moe, n_tokens))
 
     def _count_moe(self, held: int, tokens: int, forwards: int) -> None:
         """``tokens`` tokens made ``held`` of their expert choices, over all
@@ -1601,26 +1610,39 @@ class BatchScheduler:
         tel.moe_assigned_absent.inc(tokens * cfg.n_layers * cfg.n_active_experts - held)
         tel.moe_rows_per_expert.observe(held / (forwards * cfg.n_layers * cfg.n_experts))
 
-    def _count_prefill_held(self) -> None:
-        """Count the sums of the prefill chunks dispatched so far (telemetry
-        on: nothing is pending otherwise). The read is a device-to-host
-        fetch on the delivery path and WAITS for those chunks, the ones
-        queued on the device behind the pending decode chunk too: while
-        prompt work is queued, the next decode dispatch comes later. That is
-        a cost of counting, not a rule of the scheduler, and it has a
-        measured side (PERF.md §6 and §7, PR 26: against a read of the ready
-        sums only, `out_tok_s` 502-541 over 9 runs against 435-503 over 11,
-        `stall` 10 % longer). A rule "no decode dispatch while prefill
-        chunks are queued", for every architecture and whether or not
-        anything is counted, is ROADMAP Speed's to propose and measure."""
+    def _count_prefill_moe(self, wait: bool) -> None:
+        """Count what the expert layers of the prefill chunks dispatched so
+        far did (telemetry on: nothing is pending otherwise): the held-expert
+        sums of an arch that holds a share, and the layers by the path they
+        took. ``wait``: the read is a device-to-host fetch on the delivery
+        path and WAITS for those chunks, the ones queued on the device
+        behind the pending decode chunk too: while prompt work is queued,
+        the next decode dispatch comes later. That is a cost of counting,
+        not a rule of the scheduler, and it has a measured side (PERF.md §6
+        and §7, PR 26: against a read of the ready sums only, `out_tok_s`
+        502-541 over 9 runs against 435-503 over 11, `stall` 10 % longer),
+        which the cells of the archs that hold a share were accepted with. A
+        rule "no decode dispatch while prefill chunks are queued", for every
+        architecture and whether or not anything is counted, is ROADMAP
+        Speed's to propose and measure. Without ``wait`` only the chunks
+        already complete are read (they complete in dispatch order) and the
+        rest are left for the next delivery: no wait is added."""
         with self._cond:
-            pending, self._moe_pending = self._moe_pending, []
-        for held, n_tokens in pending:
+            pending = self._moe_pending
+            n = len(pending)
+            if not wait:
+                n = next((i for i, (moe, _) in enumerate(pending) if not moe.is_ready()), n)
+            pending, self._moe_pending = pending[:n], pending[n:]
+        cfg, tel = self.engine.cfg, self.engine._tel
+        for moe, n_tokens in pending:
             try:
-                held = int(held)
+                held, every_row, bucketed = (int(v) for v in np.asarray(moe))
             except Exception:  # the chunk failed on the device: its request says so
                 continue
-            self._count_moe(held, n_tokens, 1)
+            if cfg.n_routed_experts:
+                self._count_moe(held, n_tokens, 1)
+            tel.moe_piece_every_row.inc(every_row)
+            tel.moe_piece_bucketed.inc(bucketed)
 
     def _take_snapshot_locked(self, stream: BatchStream) -> None:
         """Copy ``stream``'s recurrent state, as the prefill chunk just
@@ -2567,44 +2589,9 @@ class BatchScheduler:
                 "batch_decode_chunk", bucket=bucket, active=len(joined),
                 steps=self.chunk,
             ):
-                from distributed_llama_tpu.models import sampling
-
-                # slab and carry are donated and come back advanced; the
-                # row vectors are host buffers that cross with the call
-                if engine._tp_engine is None:
-                    if self._pool is not None:
-                        out, self._slab, self._carry = (
-                            sampling.decode_chunk_batched_paged(
-                                engine.cfg, engine.params, self._carry,
-                                self._slab, pos, active, self._pool,
-                                self.chunk, temps, topps, topks, seeds,
-                                tables, matched,
-                            )
-                        )
-                    else:
-                        out, self._slab, self._carry = (
-                            sampling.decode_chunk_batched(
-                                engine.cfg, engine.params, self._carry,
-                                self._slab, pos, active, self.chunk, temps,
-                                topps, topks, seeds,
-                            )
-                        )
-                elif self._pool is not None:
-                    out, self._slab, self._carry = (
-                        engine._tp_engine.batched_decode_chunk_paged(
-                            engine.params, self._carry, self._slab,
-                            self._pool, pos, active, self.chunk, temps, topps,
-                            topks, seeds, tables, matched,
-                        )
-                    )
-                else:
-                    out, self._slab, self._carry = (
-                        engine._tp_engine.batched_decode_chunk(
-                            engine.params, self._carry, self._slab, pos,
-                            active, self.chunk, temps, topps, topks, seeds,
-                        )
-                    )
-            return out
+                return self._decode_chunk_program(
+                    active, pos, temps, topps, topks, seeds, tables, matched
+                )
 
         out = self._run_dispatch_locked(
             joined, dispatch,
@@ -2637,6 +2624,58 @@ class BatchScheduler:
         # after the entry took the claim the gap in FRONT of this chunk was
         # waited under: a row whose request ends inside the chunk needs no other
         self._note_work_locked()
+
+    def _decode_chunk_program(self, active, pos, temps, topps, topks, seeds, tables, matched):
+        """Enqueue the plain decode program over the rows the vectors cover
+        (cond held) and return its token bundle. Slab and carry are donated
+        and come back advanced; the row vectors are host buffers that cross
+        with the call."""
+        from distributed_llama_tpu.models import sampling
+
+        engine = self.engine
+        if engine._tp_engine is None:
+            if self._pool is not None:
+                out, self._slab, self._carry = sampling.decode_chunk_batched_paged(
+                    engine.cfg, engine.params, self._carry, self._slab, pos,
+                    active, self._pool, self.chunk, temps, topps, topks, seeds,
+                    tables, matched,
+                )
+            else:
+                out, self._slab, self._carry = sampling.decode_chunk_batched(
+                    engine.cfg, engine.params, self._carry, self._slab, pos,
+                    active, self.chunk, temps, topps, topks, seeds,
+                )
+        elif self._pool is not None:
+            out, self._slab, self._carry = engine._tp_engine.batched_decode_chunk_paged(
+                engine.params, self._carry, self._slab, self._pool, pos, active,
+                self.chunk, temps, topps, topks, seeds, tables, matched,
+            )
+        else:
+            out, self._slab, self._carry = engine._tp_engine.batched_decode_chunk(
+                engine.params, self._carry, self._slab, pos, active, self.chunk,
+                temps, topps, topks, seeds,
+            )
+        return out
+
+    def build_widest_decode_program(self) -> None:
+        """Run the plain decode program over ALL rows once, every row
+        inactive (nothing is written, the carry stays), so that it is built
+        before the server takes traffic. The first chunk that needs it comes
+        when more than half the rows are busy at once, in the middle of
+        serving, and a build stalls every lane for its length (seconds from
+        the compile cache, tens of seconds in a fresh checkout); which
+        buckets the traffic before that happened to visit must not decide
+        it (PERF.md §6, PR 42: a warm-up whose streams ended sooner no longer
+        held nine lanes busy, and the 16-row program was built inside the
+        measured window). The bucket is NOT noted as built: a smaller bucket
+        still builds its own program (``_built_bucket`` would have every one
+        ride this). Call with every row's stream made and none joined."""
+        if self.spec_draft > 0:
+            return  # its chunks run the verify program
+        with self._cond:
+            rows = self._streams[: decode_bucket(self.b_max, self.b_max)]
+            out = self._decode_chunk_program(*self._row_dispatch_arrays_locked(rows))
+        jax.block_until_ready(out)
 
     def _built_bucket(self, bucket: int) -> int:
         """The row bucket to dispatch when ``bucket`` rows would do: itself
@@ -2917,7 +2956,11 @@ class BatchScheduler:
                 held = extra.pop(0)
                 if tel.enabled:
                     self._count_moe(int(held.sum()), n_active * self.chunk, self.chunk)
-                    self._count_prefill_held()
+                    self._count_prefill_moe(wait=True)
+            elif engine.cfg.is_moe and tel.enabled:
+                # every expert held: nothing is read here but the layers'
+                # paths, and those only where the piece is already complete
+                self._count_prefill_moe(wait=False)
             if engine.cfg.kv_read_kinds and extra and tel.enabled:
                 # ... and the cache positions each row's layers read, by kind
                 for kind, row in zip(engine.cfg.kv_read_kinds, extra):

@@ -201,7 +201,7 @@ def block_tail(
     banks are sharded over it and the MoE FFN runs the dispatch/combine
     exchange (parallel.expert_parallel). ``n_real``: number of REAL rows in
     a bucket-padded batch (rows >= n_real are engine pad zeros) — the
-    capacity-bucketed MoE prefill masks pads out of its expert buckets."""
+    bucketed MoE prefill masks pads out of its expert buckets."""
     if axis_name is None:
         out = _matmul(att.astype(lp["wo"].dtype), lp["wo"], "wo")  # [T, dim]
     else:
@@ -578,6 +578,7 @@ def forward_tokens(
     n_real: jax.Array | None = None,
     paged=None,
     held_counts: list | None = None,
+    piece_paths: list | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Run T tokens through the model starting at absolute position ``pos``.
 
@@ -586,21 +587,31 @@ def forward_tokens(
     (logits f32 [T, vocab], updated cache in the same form). The per-token
     path of the reference's Inference::infer (src/tasks.cpp:173-184) is the
     T=1 case. ``n_real``: real (non-pad) token count of a bucket-padded
-    prompt — only the capacity-bucketed MoE prefill consumes it (pad rows
-    must not spend per-expert bucket capacity); None = all rows real.
+    prompt (pad rows write no cache or state and spend no row of an
+    expert's bucket); None = all rows real.
     ``paged``: ``(pool, table, matched)`` — this row's cache positions
     below ``matched`` live in the shared prefix-page pool (per-layer
     ``(keys, values)`` halves, read through ``table``); requires the
     layered cache layout. ``held_counts``: a list that receives one int32
     [T] array, per token how many of its expert choices, summed over the
     layers, fell on an expert held here (archs that hold a share).
+    ``piece_paths``: a list that receives one int32 [2] array, how many of
+    the program's expert layers ran every expert over every row and how
+    many ran each expert over its own bucket (``models.moe``; an expert arch
+    in the layered params layout).
     """
     from distributed_llama_tpu.models import moe
 
-    with moe.collect_held(held_counts is not None) as per_layer:
+    # a scanned layer body cannot hand its tracers to a list outside it
+    layered = isinstance(params["layers"], (list, tuple))
+    with moe.collect_held(held_counts is not None) as per_layer, \
+            moe.collect_piece_paths(piece_paths is not None and layered) as paths:
         out = _forward_tokens(cfg, params, tokens, cache, pos, axis_name, ep_axis, n_real, paged)
     if per_layer:
         held_counts.append(sum(per_layer))
+    if paths:
+        every_row = sum(paths)
+        piece_paths.append(jnp.stack([every_row, len(paths) - every_row]))
     return out
 
 

@@ -15,9 +15,12 @@ TPU-first design notes:
 * Decode (T == 1) computes ONLY the top-k experts: each selected expert runs
   under a `lax.lax.switch` whose branches close over one expert's weights, so
   HBM reads and MXU flops scale with k, not E (top-2-of-8 Mixtral decode
-  touches 4x less expert memory than dense mixing). Prefill (T > 1) keeps
-  dense one-hot mixing: tokens fan out across experts anyway and the batched
-  einsum keeps the MXU fed without per-token gathers.
+  touches 4x less expert memory than dense mixing). A few rows (T > 1: the
+  decode buckets and small pieces) keep dense one-hot mixing: tokens fan
+  out across experts anyway. From 64 rows on (a prompt piece) an expert
+  multiplies only the REAL rows that chose it, gathered into its bucket; a
+  piece in which some expert has more rows than its bucket runs every expert
+  over every row instead, so the result is exact either way.
 * Expert banks may be Q40: `engine.weights` loads each expert as fused
   gate|up + down `QuantizedMatrix` leaves (an ``experts`` list in the layer
   params), so a Q40 Mixtral file occupies ~file-size HBM instead of
@@ -141,6 +144,25 @@ def bucket_capacity(factor: float, n_tokens: int, k: int, n_buckets: int) -> int
     return min(n_tokens, max(4, -(-math.ceil(factor * n_tokens * k / n_buckets) // 4) * 4))
 
 
+# a program of this many rows or more computes each expert over the REAL rows
+# that chose it (exact: the every-row loop is its overflow arm). Below it
+# (every decode bucket) the experts' bytes bound the step and nearly every
+# expert is hit anyway
+MOE_EXACT_MIN_T = 64
+
+
+def exact_bucket_rows(n_tokens: int, k: int, n_buckets: int) -> int:
+    """Rows of an expert's bucket on the exact path: twice an even share of
+    a FULL program, as a power of two (128 of 256 rows at top-2 of 8). A
+    static function of the padded shape alone; a program in which some
+    expert has more REAL rows than this takes the every-row arm."""
+    import math
+
+    from distributed_llama_tpu.models.config import next_pow2
+
+    return next_pow2(math.ceil(2 * n_tokens * k / n_buckets))
+
+
 def bucket_rank(top_idx: jax.Array, n_buckets: int):
     """Rank every (token, choice) within its target expert — the "sort" of
     the compacted buckets without an actual sort. top_idx: [T, k] expert
@@ -187,28 +209,39 @@ def bucket_combine(
     return jnp.einsum("tk,tkd->td", top_vals * valid, gathered)
 
 
+def _all_experts(cfg: LlamaConfig, xn: jax.Array, lp, weights: jax.Array) -> jax.Array:
+    """Every expert over every row, mixed by the mostly-zero [T, E] weights."""
+    out = jnp.zeros(xn.shape, jnp.float32)
+    for e in range(cfg.n_experts):
+        out = out + weights[:, e : e + 1] * _expert_ffn(cfg, xn, _expert_weights(lp, e))
+    return out
+
+
 def _moe_dense(
     cfg: LlamaConfig, xn: jax.Array, lp, n_real: jax.Array | None = None
 ) -> jax.Array:
-    """Prefill path: every expert computed, mixed by the mostly-zero [T, E]
-    weight matrix. For stacked bf16 banks this is one batched einsum; for
-    per-expert q40 leaves: serial all-E by default (exact), or — with an
-    opted-in capacity factor (cfg.moe_capacity_factor, the --moe-capacity
-    flag) — gather-to-expert-buckets + per-expert batched fused matmuls
-    (each expert computes only ~factor·T·k/E rows instead of all T, at the
-    cost of capacity drops under routing imbalance). ``n_real`` marks the
-    real-token prefix of a bucket-padded batch; the bucketed path masks the
-    pad rows out of its expert buckets (they must not spend capacity)."""
+    """Prefill path. For stacked bf16 banks: every expert computed in one
+    batched einsum and mixed by the mostly-zero [T, E] weight matrix. For
+    per-expert q40 leaves, in a program of ``MOE_EXACT_MIN_T`` rows or more,
+    an expert multiplies the REAL rows that chose it (:func:`_moe_bucketed`:
+    buckets of :func:`exact_bucket_rows` rows, the every-row loop where some
+    expert overflows its bucket; exact either way); below that, the loop
+    over every expert and every row. With an opted-in capacity factor
+    (cfg.moe_capacity_factor, the --moe-capacity flag) the buckets hold
+    ~factor·T·k/E rows and there is no overflow arm: rows past a bucket's
+    capacity drop. ``n_real`` marks the real-token prefix of a bucket-padded
+    batch: pad rows choose no expert (they must not spend a bucket's rows)."""
     if "experts" in lp:
-        if cfg.moe_capacity_factor > 0 and xn.shape[0] >= MOE_BUCKETED_MIN_T:
-            return _moe_dense_bucketed(cfg, xn, lp, n_real=n_real)
-        weights = router_weights(cfg, xn, lp["router"])  # [T, E] f32
-        out = jnp.zeros(xn.shape, jnp.float32)
-        for e in range(cfg.n_experts):
-            out = out + weights[:, e : e + 1] * _expert_ffn(
-                cfg, xn, _expert_weights(lp, e)
-            )
-        return out
+        T, k, E = xn.shape[0], cfg.n_active_experts, cfg.n_experts
+        if cfg.moe_capacity_factor > 0 and T >= MOE_BUCKETED_MIN_T:
+            C = bucket_capacity(cfg.moe_capacity_factor, T, k, E)
+            return _moe_bucketed(cfg, xn, lp, C, n_real=n_real, exact=False)
+        C = exact_bucket_rows(T, k, E)
+        if T >= MOE_EXACT_MIN_T and C < T:
+            return _moe_bucketed(cfg, xn, lp, C, n_real=n_real)
+        _note_piece_path(1)
+        return _all_experts(cfg, xn, lp, router_weights(cfg, xn, lp["router"]))
+    _note_piece_path(1)
     weights = router_weights(cfg, xn, lp["router"])  # [T, E] f32
     from distributed_llama_tpu.models.llama import _activation
 
@@ -236,41 +269,58 @@ def _moe_dense(
     return jnp.einsum("te,ted->td", weights, down, precision=jax.lax.Precision.HIGHEST)
 
 
-def _moe_dense_bucketed(
-    cfg: LlamaConfig, xn: jax.Array, lp, n_real: jax.Array | None = None
+def _moe_bucketed(
+    cfg: LlamaConfig, xn: jax.Array, lp, C: int,
+    n_real: jax.Array | None = None, exact: bool = True,
 ) -> jax.Array:
-    """Capacity-bucketed q40 prefill: rank every (token, choice) within its
-    expert, gather each expert's rows into a fixed [C, D] bucket, run ONE
-    fused q40 FFN per expert over its bucket, and combine outputs with the
-    renormalized top-k weights. Compute per expert drops from T rows to
-    C ≈ factor·T·k/E (4x less for Mixtral's 2-of-8 at factor 2; measured
-    +15% prefill at T=128, docs/PERF.md); the expert-weight HBM reads are
-    identical, so the win scales with T. The bucket algebra
-    (bucket_rank/scatter/combine) is shared with the expert-parallel
-    dispatch (parallel.expert_parallel._ep_dispatch).
+    """Bucketed q40 prefill: rank every (token, choice) within its expert,
+    gather each expert's rows into a fixed [C, D] bucket, run ONE fused q40
+    FFN per expert over its bucket, and combine outputs with the
+    renormalized top-k weights. Compute per expert drops from T rows to C;
+    the expert-weight HBM reads are identical, so the win scales with T.
+    The bucket algebra (bucket_rank/scatter/combine) is shared with the
+    held experts' buckets and the expert-parallel dispatch
+    (parallel.expert_parallel._ep_dispatch).
+
+    ``exact``: where some expert has more rows than ``C`` the layer runs
+    every expert over every row instead (one ``lax.cond``; both arms live in
+    the one program), so no row is ever left out. Not exact (the capacity
+    factor's path): rows ranked past ``C`` drop.
 
     Engine bucket-padding appends zero tokens past ``n_real``; those rows
     route like real tokens (identical embeddings → identical experts), so
     unmasked they would pile into a few experts' buckets. They are routed
-    to a sink index E instead: the one-hot rank treats them as absent and
-    the scatter drops them, so capacity is spent ONLY on real tokens (the
-    capacity C itself must stay a static function of the padded T)."""
-    T, D = xn.shape
-    E = cfg.n_experts
-    k = cfg.n_active_experts
+    to a sink index E with weight 0 instead: the one-hot rank and the
+    counts treat them as absent and the scatter drops them, so a bucket's
+    rows are spent ONLY on real tokens (the capacity C itself must stay a
+    static function of the padded T)."""
+    T, E = xn.shape[0], cfg.n_experts
     top_vals, top_idx = router_topk(cfg, xn, lp["router"])  # [T, k]
     if n_real is not None:
-        valid = jnp.arange(T) < n_real
-        top_idx = jnp.where(valid[:, None], top_idx, E)  # sink: pads drop
+        valid = (jnp.arange(T) < n_real)[:, None]
+        top_idx = jnp.where(valid, top_idx, E)  # sink: pads drop
+        top_vals = jnp.where(valid, top_vals, 0.0)
+    flat_e, rank, t_ids = bucket_rank(top_idx, E + 1)
 
-    C = bucket_capacity(cfg.moe_capacity_factor, T, k, E)
-    flat_e, rank, t_ids = bucket_rank(top_idx, E)
-    buckets = bucket_scatter(xn, flat_e, rank, t_ids, E, C)
+    def bucketed():
+        buckets = bucket_scatter(xn, flat_e, rank, t_ids, E, C)
+        outs = jnp.stack([
+            _expert_ffn(cfg, buckets[e], _expert_weights(lp, e)) for e in range(E)
+        ])  # [E, C, D] f32
+        return bucket_combine(outs, jnp.minimum(top_idx, E - 1), rank, top_vals, C)
 
-    outs = jnp.stack([
-        _expert_ffn(cfg, buckets[e], _expert_weights(lp, e)) for e in range(E)
-    ])  # [E, C, D] f32
-    return bucket_combine(outs, top_idx, rank, top_vals, C)
+    if not exact:
+        _note_piece_path(0)
+        return bucketed()
+
+    def every_row():
+        weights = jnp.einsum("tk,tke->te", top_vals, jax.nn.one_hot(top_idx, E))
+        return _all_experts(cfg, xn, lp, weights)
+
+    counts = jnp.sum(jax.nn.one_hot(top_idx, E, dtype=jnp.int32), axis=(0, 1))
+    over = jnp.max(counts) > C
+    _note_piece_path(over)
+    return jax.lax.cond(over, every_row, bucketed)
 
 
 # Trace-time collector of the expert share's routing sums: while one is open
@@ -289,6 +339,46 @@ def collect_held(enabled: bool = True):
         yield _held_counts
     finally:
         _held_counts = before
+
+
+# The same for the path an expert layer took: while one is open
+# (:func:`collect_piece_paths`), every expert layer of the trace appends an
+# int32 scalar, 1 where it ran every expert over every row of the program
+# (an overflowing bucket, or a program too small to bucket) and 0 where each
+# expert ran over its own bucket.
+_piece_paths: list | None = None
+
+
+@contextlib.contextmanager
+def collect_piece_paths(enabled: bool = True):
+    global _piece_paths
+    before, _piece_paths = _piece_paths, [] if enabled else None
+    try:
+        yield _piece_paths
+    finally:
+        _piece_paths = before
+
+
+def _note_piece_path(every_row) -> None:
+    if _piece_paths is not None:
+        _piece_paths.append(jnp.asarray(every_row, jnp.int32))
+
+
+# Trace-time too: the real rows of the padded piece whose expert share is
+# being traced (None: every row is real). :func:`_moe_share` is wrapped with
+# its three arguments by the benchmark's tests, so ``moe_ffn`` hands it the
+# piece's ``n_real`` here and not as a fourth.
+_share_n_real: jax.Array | None = None
+
+
+@contextlib.contextmanager
+def _real_rows(n_real: jax.Array | None):
+    global _share_n_real
+    before, _share_n_real = _share_n_real, n_real
+    try:
+        yield
+    finally:
+        _share_n_real = before
 
 
 def held_bucket_rows(cfg: LlamaConfig, rows: int) -> int:
@@ -337,7 +427,8 @@ def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) ->
 
 
 def _held_experts(
-    cfg: LlamaConfig, xn: jax.Array, lp, top_vals: jax.Array, top_idx: jax.Array
+    cfg: LlamaConfig, xn: jax.Array, lp, top_vals: jax.Array, top_idx: jax.Array,
+    n_real: jax.Array | None = None,
 ) -> jax.Array:
     """Sum over the chosen experts HELD here of ``w * SwiGLU_e(xn)``, from
     the routing's ([T, k] weights, [T, k] ids over the router's width). Each
@@ -347,10 +438,14 @@ def _held_experts(
     of the capacity-bucketed prefill and the expert-parallel dispatch): an
     expert computes its own rows, not every row of the step. Where some
     expert has more rows than its bucket, the step is computed with every
-    held expert over every row instead: exact either way."""
+    held expert over every row instead: exact either way. Rows at and past
+    ``n_real`` (a padded piece) choose no expert: they fill no bucket, trip
+    no overflow, and an expert only they chose is not read."""
     T, E = xn.shape[0], cfg.n_experts
     local = top_idx - cfg.first_expert
     is_held = (local >= 0) & (local < E)
+    if n_real is not None:
+        is_held &= (jnp.arange(T) < n_real)[:, None]
     local = jnp.where(is_held, local, E)  # E: the sink the scatter drops
     weights = jnp.where(is_held, top_vals, 0.0)
     counts = jnp.sum(jax.nn.one_hot(local, E + 1, dtype=jnp.int32), axis=(0, 1))[:E]
@@ -363,6 +458,7 @@ def _held_experts(
                           precision=jax.lax.Precision.HIGHEST)
 
     if C >= T:
+        _note_piece_path(1)
         return every_row()
 
     def bucketed():
@@ -371,7 +467,9 @@ def _held_experts(
         return bucket_combine(_held_ffn(cfg, buckets, lp, on, T), jnp.minimum(local, E - 1), rank,
                               weights, C)
 
-    return jax.lax.cond(jnp.max(counts) > C, every_row, bucketed)
+    over = jnp.max(counts) > C
+    _note_piece_path(over)
+    return jax.lax.cond(over, every_row, bucketed)
 
 
 def _moe_share(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:
@@ -379,7 +477,9 @@ def _moe_share(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:
     and the renormalisation run over the router's whole width; the sum runs
     over the chosen experts held here (``first_expert`` onwards), and what
     the absent ones would add is left out. The shared expert, a dense SwiGLU
-    every token takes, is added whole. ``xn`` [T, dim] -> [T, dim] f32."""
+    every token takes, is added whole. ``xn`` [T, dim] -> [T, dim] f32. In a
+    padded piece (:func:`_real_rows`) the rows past the real ones choose no
+    held expert."""
     from distributed_llama_tpu.models.llama import _activation, _matmul
 
     top_vals, top_idx = router_topk(cfg, xn, lp["router"], lp.get("router_bias"))
@@ -390,7 +490,7 @@ def _moe_share(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:
             _held_counts.append(
                 jnp.sum((local >= 0) & (local < cfg.n_experts), axis=1, dtype=jnp.int32)
             )
-        out = _held_experts(cfg, xn, lp, top_vals, top_idx)
+        out = _held_experts(cfg, xn, lp, top_vals, top_idx, _share_n_real)
     if "shared_gate_up" in lp:
         fused = _matmul(xn.astype(lp["shared_gate_up"].dtype), lp["shared_gate_up"], "gate_up")
         hidden = lp["shared_down"].shape[-2]
@@ -408,14 +508,16 @@ def moe_ffn(
     in ``lp`` are SHARDED over that mesh axis (device owns E/ep whole
     experts) and the exchange runs in parallel.expert_parallel — the psum
     over ``axis_name`` (hidden-slice partial sums under TP) still applies on
-    top. ``n_real`` (bucket-padded prefill) reaches only the capacity-
-    bucketed dense path; the exact paths compute pads harmlessly."""
+    top. ``n_real`` (bucket-padded prefill): rows at and past it choose no
+    expert on the bucketed paths (the expert-parallel exchange and the
+    small programs' every-row loop compute pads harmlessly)."""
     if ep_axis is not None:
         from distributed_llama_tpu.parallel.expert_parallel import ep_moe_ffn
 
         out = ep_moe_ffn(cfg, xn, lp, ep_axis)
     elif cfg.n_routed_experts:
-        out = _moe_share(cfg, xn, lp)
+        with _real_rows(n_real):
+            out = _moe_share(cfg, xn, lp)
     elif xn.shape[0] == 1:
         out = _moe_topk(cfg, xn, lp)
     else:

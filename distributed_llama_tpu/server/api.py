@@ -587,6 +587,8 @@ class ApiState:
         sched = self._make_scheduler(engine, idx)
         if sched is not None:
             streams = [sched.new_stream() for _ in range(self._lanes)]
+            # ... and the program of all lanes busy at once, before any is
+            sched.build_widest_decode_program()
         else:
             streams = [engine.default_stream] + [
                 engine.new_stream() for _ in range(self._lanes - 1)

@@ -330,6 +330,19 @@ class EngineInstruments:
             "the mean over its steps, layers and held experts",
             buckets=(0.25, 0.5, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0),
         )
+        moe_piece_layers = counter(
+            "dllama_moe_piece_layers_total",
+            "Expert layers of the prompt pieces, one count a layer a piece, by "
+            "the path the layer took: bucketed (each expert multiplied the "
+            "real rows that chose it, in its own bucket) or every_row (some "
+            "expert had more rows than its bucket, or the piece was too small "
+            "to bucket, and every expert multiplied every row); returned by "
+            "the prefill programs and read when a later decode chunk is "
+            "delivered",
+            labelnames=("path",),
+        )
+        self.moe_piece_bucketed = moe_piece_layers.labels(path="bucketed")
+        self.moe_piece_every_row = moe_piece_layers.labels(path="every_row")
         self.recurrent_state_bytes = gauge(
             "dllama_recurrent_state_bytes",
             "Bytes of recurrent state and convolution tails the slab's rows "

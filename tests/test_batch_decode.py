@@ -626,6 +626,39 @@ class TestBuiltBuckets:
         assert set(buckets) == {2}
 
 
+    @pytest.mark.parametrize("paged", [False, True], ids=["plain", "paged"])
+    def test_the_widest_program_is_built_before_traffic_and_noted_nowhere(self, tmp_path, paged):
+        """``build_widest_decode_program`` runs the all-rows program with
+        every row inactive: slab and carry keep their bytes, no bucket is
+        noted as built (a lone stream still builds and runs the one-row
+        program), and the first chunk of all rows builds nothing."""
+        from distributed_llama_tpu.models import sampling
+
+        prompt, n = PROMPTS[0], 6
+        temp, topp, seed = SAMPLING[0]
+        # a context of its own: the programs' cache is the process's, and keyed by the config
+        want = single_stream_tokens(
+            build_engine(tmp_path, "a.m", seq_len=88), prompt, temp, topp, seed, n)
+        program = sampling.decode_chunk_batched_paged if paged else sampling.decode_chunk_batched
+        kw = dict(prefix_cache=True, kv_pages=8, page_size=8) if paged else {}
+        sched = BatchScheduler(build_engine(tmp_path, "b.m", seq_len=88), n_rows=4, chunk=4, **kw)
+        streams = [sched.new_stream() for _ in range(4)]
+        before = [np.asarray(a).copy() for a in jax.tree.leaves((sched._slab, sched._carry))]
+        built = program._cache_size()
+        sched.build_widest_decode_program()
+        assert program._cache_size() == built + 1 and sched._decode_built == set()
+        for was, now in zip(before, jax.tree.leaves((sched._slab, sched._carry))):
+            np.testing.assert_array_equal(was, np.asarray(now))
+        buckets = []
+        real = sched._note_dispatched
+        sched._note_dispatched = lambda bucket, *a: (buckets.append(bucket), real(bucket, *a))[1]
+        assert batch_stream_tokens(streams[0], prompt, temp, topp, seed, n) == want
+        assert set(buckets) == {1} and program._cache_size() == built + 2
+        # a chunk of all four rows takes the same vectors to the same program: it is there
+        sched.build_widest_decode_program()
+        assert program._cache_size() == built + 2
+
+
 class TestBatchApi:
     """The API server's StreamSlots submit into the shared scheduler:
     completions through the batched path match the classic per-stream

@@ -13,6 +13,7 @@ worker that is handed this file loads it and every other worker never does.
 All compiles happen in the test's own process.
 """
 
+import dataclasses
 import os
 import re
 
@@ -118,6 +119,19 @@ def test_q40_dispatch_past_the_vmem_fit_compiles_as_plain_xla(one_chip, monkeypa
     x = jax.ShapeDtypeStruct((T, 4096), jnp.bfloat16, sharding=one_chip)
     compiled = jax.jit(lambda x, qm: q40.q40_matmul(x, qm)).lower(x, qm).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [128, 64, 32])
+@pytest.mark.parametrize("n,d", SHAPES_MIXTRAL)
+def test_q40_experts_launch_compiles_at_a_buckets_rows(one_chip, monkeypatch, n, d, T):
+    """One expert over its bucket of a prompt piece (``moe._moe_bucketed``):
+    half the rows of a piece of 256, 128 or 64, through the default dispatch
+    under the role the trace reads it by."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)  # steer the CPU branch
+    qm = _qm_shape(n, d, one_chip)
+    x = jax.ShapeDtypeStruct((T, n), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda x, qm: q40.q40_matmul(x, qm, role="experts")).lower(x, qm).compile()
+    assert "q40_int8_experts" in compiled.as_text()
 
 
 # Solar-Open2's shapes: 20 held experts of width 1280 over a hidden size of 4096 (gate|up
@@ -327,6 +341,34 @@ def test_served_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch, r
     # weights' leaves, then the carry
     carry_out, carry_in = 1 + len(jax.tree.leaves(slab)), len(jax.tree.leaves(params))
     assert _aliased_outputs(compiled.as_text())[carry_out] == carry_in
+
+
+def test_served_mixtral_piece_holds_both_arms_of_its_expert_layers(one_chip, monkeypatch):
+    """A 256-row prompt piece of ``mixtral8x7b.batch_decode`` (Mixtral's
+    widths, 2 of its layers, the pool-enabled program the cell dispatches):
+    ONE program holds, for each expert layer, the bucketed arm (8 experts
+    over 128 rows each) and the every-row arm (8 over 256), so an overflow
+    builds nothing inside a window; it returns the layers' counts."""
+    from distributed_llama_tpu.engine import batch
+
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)  # steer the CPU branch
+    cfg, params, slab, pool, s = _served_program_shapes(one_chip)
+    cfg = dataclasses.replace(cfg, arch=ArchType.MIXTRAL, n_experts=8, n_active_experts=2)
+    layer = {k: v for k, v in params["layers"][0].items() if k not in ("gate_up", "down")}
+    expert = dict(gate_up=_qm_shape(4096, 28672, one_chip), down=_qm_shape(14336, 4096, one_chip))
+    layer.update(router=s((4096, 8), jnp.bfloat16), experts=[expert] * 8)
+    params = dict(params, layers=[layer] * SERVED_LAYERS)
+    compiled = batch._slab_prefill_single_paged.lower(
+        cfg, params, s((256,), jnp.int32), slab, pool, s((), jnp.int32), s((), jnp.int32),
+        s((), jnp.int32), s((2048 // SERVED_PAGE,), jnp.int32), s((), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == SERVED_LAYERS
+    for rows in (128, 256):  # both arms' launches, under the role the trace reads them by
+        for width in (28672, 4096):
+            assert re.search(rf"f32\[{rows},{width}\]\S* custom-call\(.*q40_int8_experts", text), (rows, width)
+    moe_counts = compiled.out_info[2]
+    assert (moe_counts.shape, moe_counts.dtype) == ((3,), jnp.int32)
 
 
 def test_served_eva_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):

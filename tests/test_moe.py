@@ -5,6 +5,7 @@ Mixtral test at all (SURVEY.md §4); both are covered here."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from distributed_llama_tpu.engine import InferenceEngine
 from distributed_llama_tpu.formats.model_file import ArchType, HiddenAct
@@ -223,3 +224,156 @@ class TestQ40Moe:
         q4.prefill([1, 2, 3])
         got = q4.generate_on_device(4, 6, temperature=0.0)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _program_text(fn, *args) -> str:
+    """The lowered text of ``fn(*args)`` under one module name, so that two
+    formulations of one program compare byte for byte."""
+    import jax
+
+    def program(*a):
+        return fn(*a)
+
+    return jax.jit(program).lower(*args).as_text()
+
+
+class TestExactBuckets:
+    """Per-expert Q40 leaves, programs of 64 rows or more: an expert
+    multiplies the REAL rows that chose it (``moe._moe_bucketed``), the loop
+    over every expert and every row is the overflow arm, and both give what
+    the loop alone gave. 8 experts, top 2: a bucket is half the program."""
+
+    TOL = 2e-3
+
+    @pytest.fixture(scope="class")
+    def layer(self, tmp_path_factory):
+        spec = TestQ40Moe()._spec(n_experts=8, seq_len=512)
+        path = str(tmp_path_factory.mktemp("exact") / "moe_q40_8.m")
+        write_model_file(path, spec, random_tensors(spec, seed=7))
+        engine = InferenceEngine(path, dtype="q40")
+        return engine.cfg, engine.params["layers"][0]
+
+    @staticmethod
+    def rows(T, n_real, dim, seed=0):
+        """[T, dim] normed activations: ``n_real`` distinct rows, then the
+        padding's identical rows (token 0 each: they route alike)."""
+        rng = np.random.RandomState(seed)
+        x = rng.randn(T, dim).astype(np.float32)
+        x[n_real:] = rng.randn(dim).astype(np.float32)
+        return x
+
+    @staticmethod
+    def run(cfg, lp, xn, n_real):
+        """(the layer's output, 1 where it took the every-row arm)."""
+        import jax
+
+        from distributed_llama_tpu.models import moe
+
+        def f(lp, xn, n_real):
+            with moe.collect_piece_paths() as paths:
+                out = moe.moe_ffn(cfg, xn, lp, None, n_real=n_real)
+            return out, paths[0]
+
+        out, every_row = jax.jit(f)(lp, xn, None if n_real is None else jnp.int32(n_real))
+        return np.asarray(out), int(every_row)
+
+    @staticmethod
+    def loop(cfg, lp, xn):
+        """The parent's path: all E experts over all T rows."""
+        import jax
+
+        from distributed_llama_tpu.models import moe
+
+        return np.asarray(jax.jit(
+            lambda lp, xn: moe._all_experts(cfg, xn, lp, moe.router_weights(cfg, xn, lp["router"]))
+        )(lp, xn))
+
+    def check(self, got, want, n_real):
+        np.testing.assert_allclose(got[:n_real], want[:n_real], rtol=self.TOL, atol=self.TOL)
+        np.testing.assert_array_equal(got[:n_real].argmax(-1), want[:n_real].argmax(-1))
+
+    @pytest.mark.parametrize("share", ["all", "two_thirds", "one_third", "one"])
+    @pytest.mark.parametrize("T", [64, 128, 256])
+    def test_buckets_give_what_the_every_row_loop_gave(self, layer, T, share):
+        cfg, lp = layer
+        n_real = {"all": T, "two_thirds": 2 * T // 3, "one_third": T // 3, "one": 1}[share]
+        xn = self.rows(T, n_real, cfg.dim, seed=T)
+        got, every_row = self.run(cfg, lp, xn, n_real)
+        # the padding's rows alone (2T/3 of them choose the same two experts:
+        # more than a bucket's T/2) never trip the overflow test
+        assert every_row == 0
+        self.check(got, self.loop(cfg, lp, xn), n_real)
+        # a pad row adds nothing to its residual
+        assert not got[n_real:].any()
+
+    @pytest.mark.parametrize("T", [64, 128, 256])
+    def test_an_expert_with_more_real_rows_than_its_bucket_takes_every_row(self, layer, T):
+        cfg, lp = layer
+        # rigged: every row's first choice is expert 0 (T rows > T/2)
+        xn = jnp.abs(self.rows(T, T, cfg.dim, seed=T + 1))
+        router = np.asarray(lp["router"]).copy()
+        router[:, 0] = 1.0
+        rigged = {**lp, "router": jnp.asarray(router)}
+        n_real = 2 * T // 3 + 1
+        got, every_row = self.run(cfg, rigged, xn, n_real)
+        assert every_row == 1
+        self.check(got, self.loop(cfg, rigged, xn), n_real)
+        assert not got[n_real:].any()
+
+    @pytest.mark.parametrize("T", [64, 256])
+    def test_without_n_real_every_row_is_real(self, layer, T):
+        cfg, lp = layer
+        xn = self.rows(T, T, cfg.dim, seed=T + 2)
+        got, every_row = self.run(cfg, lp, xn, None)
+        assert every_row == 0
+        self.check(got, self.loop(cfg, lp, xn), T)
+
+    @pytest.mark.parametrize("T", [2, 8, 16, 32, 48])
+    def test_a_program_under_64_rows_lowers_to_the_parents_text(self, layer, T):
+        """Every decode bucket reaches ``_moe_dense`` too: there the step is
+        bound by the experts' bytes, and its program is the loop's, byte for
+        byte, whether or not the piece is padded."""
+        from distributed_llama_tpu.models import moe
+
+        cfg, lp = layer
+        xn = self.rows(T, T, cfg.dim)
+
+        def parents(lp, xn, n_real):  # models/moe.py's _moe_dense before the buckets
+            weights = moe.router_weights(cfg, xn, lp["router"])
+            out = jnp.zeros(xn.shape, jnp.float32)
+            for e in range(cfg.n_experts):
+                out = out + weights[:, e : e + 1] * moe._expert_ffn(
+                    cfg, xn, moe._expert_weights(lp, e)
+                )
+            return out
+
+        want = _program_text(parents, lp, xn, jnp.int32(T))
+        for masked in (False, True):
+            got = _program_text(
+                lambda lp, xn, n: moe.moe_ffn(cfg, xn, lp, None, n_real=n if masked else None),
+                lp, xn, jnp.int32(max(1, T // 3)),
+            )
+            assert got == want
+
+    def test_the_capacity_factor_keeps_its_dropping_buckets(self, layer):
+        """--moe-capacity's path has no overflow arm: the rigged router's
+        rows past a bucket's capacity drop, as they did."""
+        import dataclasses
+
+        cfg, lp = layer
+        lossy = dataclasses.replace(cfg, moe_capacity_factor=1.0)
+        T = 64
+        xn = jnp.abs(self.rows(T, T, cfg.dim, seed=9))
+        router = np.asarray(lp["router"]).copy()
+        router[:, 0] = 1.0
+        rigged = {**lp, "router": jnp.asarray(router)}
+        got, every_row = self.run(lossy, rigged, xn, T)
+        want = self.loop(cfg, rigged, xn)
+        assert every_row == 0 and np.all(np.isfinite(got))
+        assert np.abs(got - want).max() > 10 * self.TOL  # expert 0 dropped rows
+
+    def test_a_bucket_is_twice_an_even_share_of_a_full_program(self):
+        from distributed_llama_tpu.models import moe
+
+        assert [moe.exact_bucket_rows(T, 2, 8) for T in (64, 128, 256, 512)] == [32, 64, 128, 256]
+        assert moe.exact_bucket_rows(256, 2, 4) == 256  # as large as the program: the loop serves
