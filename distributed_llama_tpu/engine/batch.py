@@ -168,9 +168,9 @@ def _slice_page(pool, pid):
     thread fetches them (``pid`` crosses as a numpy scalar with the
     dispatch)."""
     out = []
-    for pk, pv in filter(None, pool):
-        out.extend(kvc.slice_pool_page(pk, pid))
-        out.extend(kvc.slice_pool_page(pv, pid))
+    for halves in filter(None, pool):  # (keys, values), or a latent layer's one
+        for half in halves:
+            out.extend(kvc.slice_pool_page(half, pid))
     return out
 
 
@@ -206,9 +206,13 @@ def _publish_pages(page: int, slab, pool, page_ids, src_page, row, base: int = 0
     ``page_ids`` across every layer (the post-prefill publish;
     ``kvc.BlockSlots(page, base=base)`` says where a block sits). The donated
     pool aliases in place; the slab is read-only here (``leaf[0]``/
-    ``leaf[1]`` are contiguous views of the fused leaf)."""
+    ``leaf[1]`` are contiguous views of the fused leaf; a latent layer's leaf
+    and pool entry hold one array each)."""
     return [
-        None if half is None else (
+        None if half is None
+        else (kvc.publish_latent_pages(half[0], leaf, row, src_page, page_ids, page),)
+        if kvc.is_latent_leaf(leaf)
+        else (
             kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page, base=base),
             kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page, base=base),
         )
@@ -234,7 +238,10 @@ def _restore_pages(slab, pool, page_ids, second, row):
     telemetry.note_kernel_path("paged_attention", "slab_restored")
     out = []
     for leaf, half in zip(slab, pool):
-        if half is not None:
+        if half is not None and kvc.is_latent_leaf(leaf):
+            leaf = kvc.restore_latent_blocks(leaf, half[0], row, 0, page_ids[0])
+            leaf = kvc.restore_latent_blocks(leaf, half[0], row, second, page_ids[1])
+        elif half is not None:
             leaf = kvc.restore_row_blocks(leaf, half[0], half[1], row, 0, page_ids[0])
             leaf = kvc.restore_row_blocks(leaf, half[0], half[1], row, second, page_ids[1])
         out.append(leaf)
@@ -814,6 +821,10 @@ class BatchScheduler:
                     engine.cfg, "the host spill tier (a state snapshot or a window layer's "
                     "page has no spill form)"
                 )
+        if tp_engine is not None:
+            llama.refuse_latent(engine.cfg, "a sharded (tp/pod) backend")
+        if spec_draft and int(spec_draft) > 0:
+            llama.refuse_latent(engine.cfg, f"speculative decode (--spec-draft {spec_draft})")
         self.engine = engine
         self.b_max = n_rows
         self.chunk = int(chunk)
@@ -1101,6 +1112,15 @@ class BatchScheduler:
             self._kv_position_bytes = llama.page_pool_bytes(
                 engine.cfg, 1, engine.cache_dtype, layers=1
             )
+        if engine.cfg.has_latent:
+            # ... read off the slab's own leaf: what a row really stores of a
+            # position (it would rise 15-fold if keys and values were kept)
+            stored = self._slab[0][kvc.LATENT]
+            self._kv_position_bytes = stored.nbytes // (stored.shape[0] * stored.shape[2])
+            if self._prefix is not None:
+                engine._tel.kv_pool_bytes.labels(kind="latent").set(
+                    kv_pages * page_size * self._kv_position_bytes * engine.cfg.n_layers
+                )
         if self._hit_restores:
             # every shape of a hit's copy is built now, not at the first hit
             # inside a measured window (page 0 into row 0, which starts over
@@ -1606,9 +1626,11 @@ class BatchScheduler:
         """``tokens`` tokens made ``held`` of their expert choices, over all
         layers, on experts held here, in ``forwards`` forward steps."""
         cfg, tel = self.engine.cfg, self.engine._tel
+        # the layers that route: not an arch's leading dense ones
+        layers = cfg.n_layers - min(cfg.first_dense, cfg.n_layers)
         tel.moe_assigned_held.inc(held)
-        tel.moe_assigned_absent.inc(tokens * cfg.n_layers * cfg.n_active_experts - held)
-        tel.moe_rows_per_expert.observe(held / (forwards * cfg.n_layers * cfg.n_experts))
+        tel.moe_assigned_absent.inc(tokens * layers * cfg.n_active_experts - held)
+        tel.moe_rows_per_expert.observe(held / (forwards * layers * cfg.n_experts))
 
     def _count_prefill_moe(self, wait: bool) -> None:
         """Count what the expert layers of the prefill chunks dispatched so
@@ -1696,23 +1718,26 @@ class BatchScheduler:
         return jax.device_get(handles)
 
     def _page_pytree(self, arrays: list) -> list:
-        """Regroup a flat spill entry back into the per-layer (k, v)
-        array-list pairs :func:`_upload_page` consumes. Raises on a layout
-        mismatch (a spill entry from an incompatible config must fall
-        back to a cold prefill, never upload misshapen bytes)."""
-        halves: list[list] = []
+        """Regroup a flat spill entry back into the per-layer tuples of
+        array lists :func:`_upload_page` consumes ((k, v); a latent layer's
+        one). Raises on a layout mismatch (a spill entry from an incompatible
+        config must fall back to a cold prefill, never upload misshapen
+        bytes)."""
+        layers: list[tuple] = []
         i = 0
-        for pk, pv in filter(None, self._pool):
-            for half in (pk, pv):
+        for halves in filter(None, self._pool):
+            entry = []
+            for half in halves:
                 n = kvc.pool_page_arrays_per_half(half)
-                halves.append(list(arrays[i : i + n]))
+                entry.append(list(arrays[i : i + n]))
                 i += n
+            layers.append(tuple(entry))
         if i != len(arrays):
             raise ValueError(
                 f"spill entry layout mismatch: {len(arrays)} arrays, "
                 f"expected {i}"
             )
-        return [(halves[2 * l], halves[2 * l + 1]) for l in range(len(halves) // 2)]
+        return layers
 
     def _reload_spilled_locked(self, tokens: np.ndarray) -> int:
         """Pull spilled pages of this prompt's prefix back into the pool
@@ -2965,6 +2990,9 @@ class BatchScheduler:
                 # ... and the cache positions each row's layers read, by kind
                 for kind, row in zip(engine.cfg.kv_read_kinds, extra):
                     tel.kv_read[kind].inc(int(row.sum()) * self._kv_position_bytes)
+                    if kind == "latent":
+                        # a row's sum runs over its layers: positions, once each
+                        tel.kv_read_positions[kind].inc(int(row.sum()) // engine.cfg.n_layers)
             with self._cond:
                 if self._sdc_logits_pending > 0:
                     # engine.sdc message=logits: shift every token column

@@ -397,6 +397,33 @@ def load_params(
                 "gate_up": fused([p + "gate", p + "up"]), "down": weight(p + "down"),
                 "rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")}
 
+    def latent_layer(l: int) -> dict:
+        """One ``ArchType.GLM4_MOE_LITE`` layer: the two down-projections as
+        ONE matrix (``qkv_a``: q_a|kv_a, read by the normed input), the
+        query's up-projection ``q_b``, and the keys' and values' up-projection
+        ``kv_b`` SLICED by head into ``w_uk`` [H, nope, rank] and ``w_uv`` [H,
+        rank, v] for the absorbed attention (``models.llama.latent_project``:
+        no key or value of a cached position is ever expanded). The two are
+        multiplied per head against 20 small operands, which no Q40 kernel
+        tiles, so they are kept DEQUANTISED in the matmul dtype: 2 B a weight
+        where the file has 18/32 B (9.2 MB a layer against 2.6 MB at the
+        published widths; a step reads them once)."""
+        p = f"layers.{l}."
+        H, nope, v = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+        kv_b = reader.tensor(p + "kv_b").reshape(H, nope + v, cfg.kv_lora_rank)
+        lp = {"qkv_a": fused([p + "q_a", p + "kv_a"]), "q_a_norm": f32(p + "q_a_norm"),
+              "kv_a_norm": f32(p + "kv_a_norm"), "q_b": weight(p + "q_b"),
+              "w_uk": cast(np.ascontiguousarray(kv_b[:, :nope])),
+              "w_uv": cast(np.ascontiguousarray(kv_b[:, nope:].transpose(0, 2, 1))),
+              "wo": weight(p + "wo"),
+              "rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")}
+        if cfg.layer_kind(l)[1] == "dense":
+            lp["gate_up"] = fused([p + "gate", p + "up"])
+            lp["down"] = weight(p + "down")
+        else:
+            lp.update(held_experts(p))
+        return lp
+
     def next_token_head():
         """Rows 0 .. vocab_size - 1 of an output matrix of several prediction
         heads: the next token's. The others (self-speculation over the tokens
@@ -407,13 +434,15 @@ def load_params(
             return pack_q40_raw(reader.raw_rows("wcls", 0, cfg.vocab_size), (cfg.vocab_size, cfg.dim))
         return cast(_t(reader.tensor_rows("wcls", 0, cfg.vocab_size), np.float32))
 
-    if cfg.arch in (ArchType.SOLAR_OPEN2, ArchType.EXAONE_MOE, ArchType.EVABYTE):
-        from distributed_llama_tpu.models.llama import refuse_recurrent
+    by_layer = {ArchType.SOLAR_OPEN2: hybrid_layer, ArchType.EXAONE_MOE: window_layer,
+                ArchType.EVABYTE: eva_layer, ArchType.GLM4_MOE_LITE: latent_layer}
+    if cfg.arch in by_layer:
+        from distributed_llama_tpu.models.llama import refuse_latent, refuse_recurrent
 
         if tp > 1:
             refuse_recurrent(cfg, f"tensor parallelism (--tp {tp})")
-        layer = {ArchType.SOLAR_OPEN2: hybrid_layer, ArchType.EXAONE_MOE: window_layer,
-                 ArchType.EVABYTE: eva_layer}[cfg.arch]
+            refuse_latent(cfg, f"tensor parallelism (--tp {tp})")
+        layer = by_layer[cfg.arch]
         return {
             "embedding": reader.tensor("embedding").astype(np.float32),
             "layers": [layer(l) for l in range(cfg.n_layers)],

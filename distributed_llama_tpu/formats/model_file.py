@@ -65,6 +65,20 @@ keys of ``_ARCH_KEYS[ArchType.EVABYTE]``:
     the pooled key), wo, gate, down, up
   wcls [n_pred_heads * vocab_size, dim]
 
+``ArchType.GLM4_MOE_LITE`` (no reference counterpart; :func:`_latent_layer`)
+mixes by latent attention: a layer's cache holds, a position, one row of
+``kv_lora_rank + qk_rope_head_dim`` values (the normed latent and ONE rotated
+key slice that every head shares), no head axis and no key or value; leading
+``first_dense`` dense layers, then expert layers that hold ``n_experts`` of
+the router's ``n_routed_experts`` (all of them where the two are equal) beside
+a shared one; its files carry the keys of ``_ARCH_KEYS[ArchType.GLM4_MOE_LITE]``:
+
+  every layer: rms_att, rms_ffn, q_a [q_lora_rank, dim], q_a_norm (F32)
+    [q_lora_rank], q_b [H*(nope+rope), q_lora_rank], kv_a [kv_lora_rank+rope,
+    dim], kv_a_norm (F32) [kv_lora_rank], kv_b [H*(nope+v), kv_lora_rank] (a
+    head's rows: its nope key rows, then its value rows), wo [dim, H*v]
+  dense layer / expert layer: as ``ArchType.EXAONE_MOE``
+
 All matrices are row-major [d_out, d_in] — a matmul computes y = W @ x.
 Q/K projections are stored pre-permuted for interleaved-pair rope
 (reference: converter/convert-hf.py:12-15).
@@ -101,6 +115,11 @@ class ArchType(enum.IntEnum):
     # summaries of the windows before it), norms with a unit offset, several
     # prediction heads on the output matrix; not a reference arch
     EVABYTE = 0xABCD05
+    # every layer latent attention (two low-rank projections with a norm inside
+    # each, one rotated key slice a position for all heads, a cache of latents),
+    # a leading dense layer, every routed expert held beside a shared one; not
+    # a reference arch
+    GLM4_MOE_LITE = 0xABCD06
 
 
 class HiddenAct(enum.IntEnum):
@@ -159,6 +178,11 @@ class HeaderKey(enum.IntEnum):
     ROUTED_SCALE_MILLI = 33  # the chosen experts' weights are multiplied by this / 1000
     EVA_CHUNK = 34  # positions one summary of an EVA layer stands for (WINDOW: its aligned window)
     PRED_HEADS = 35  # heads of vocab_size rows on the output matrix; the first is the next token's
+    Q_LORA_RANK = 36  # width of the query's latent (a latent-attention layer)
+    KV_LORA_RANK = 37  # width of the keys' and values' latent: what the cache holds of a position
+    QK_NOPE_HEAD_DIM = 38  # values of a q/k head that are not rotated
+    QK_ROPE_HEAD_DIM = 39  # values of a q head that are; the ONE key slice of that width is cached
+    V_HEAD_DIM = 40
 
 
 class ArchFlags(enum.IntFlag):
@@ -205,10 +229,25 @@ _EVA_KEYS = {
     HeaderKey.EVA_CHUNK: "eva_chunk",
     HeaderKey.PRED_HEADS: "n_pred_heads",
 }
+_LATENT_KEYS = {
+    HeaderKey.HEAD_SIZE: "head_dim",  # of a q/k head: nope + rope
+    HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim",
+    HeaderKey.N_SHARED_EXPERTS: "n_shared_experts",
+    HeaderKey.N_ROUTED_EXPERTS: "n_routed_experts",
+    HeaderKey.FIRST_EXPERT: "first_expert",
+    HeaderKey.FLAGS: "flags",
+    HeaderKey.FIRST_DENSE: "first_dense",
+    HeaderKey.ROUTED_SCALE_MILLI: "routed_scale_milli",
+    HeaderKey.Q_LORA_RANK: "q_lora_rank",
+    HeaderKey.KV_LORA_RANK: "kv_lora_rank",
+    HeaderKey.QK_NOPE_HEAD_DIM: "qk_nope_head_dim",
+    HeaderKey.QK_ROPE_HEAD_DIM: "qk_rope_head_dim",
+    HeaderKey.V_HEAD_DIM: "v_head_dim",
+}
 # the keys past ROPE_TYPE an arch's files carry, in the order they are written;
 # an arch that is not here writes none of them
 _ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KEYS,
-              ArchType.EVABYTE: _EVA_KEYS}
+              ArchType.EVABYTE: _EVA_KEYS, ArchType.GLM4_MOE_LITE: _LATENT_KEYS}
 
 
 @dataclasses.dataclass
@@ -256,6 +295,11 @@ class ModelSpec:
     routed_scale_milli: int = 0
     eva_chunk: int = 0
     n_pred_heads: int = 0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @property
     def head_size(self) -> int:
@@ -391,6 +435,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
                 **_EXTRA_KEYS,
                 **_WINDOW_KEYS,
                 **_EVA_KEYS,
+                **_LATENT_KEYS,
             }
             for i in range(0, n_ints, 2):
                 key, value = raw[i], raw[i + 1]
@@ -453,6 +498,9 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
         if spec.arch_type == ArchType.EVABYTE:
             _eva_layer(spec, l, add)
             continue
+        if spec.arch_type == ArchType.GLM4_MOE_LITE:
+            _latent_layer(spec, l, add)
+            continue
         add(p + "q", (dim, dim), wt)
         add(p + "k", (kv_dim, dim), wt)
         add(p + "v", (kv_dim, dim), wt)
@@ -482,10 +530,15 @@ def layer_kind(spec, l: int) -> tuple[str, str]:
     """What layer ``l`` is (``spec``: a ModelSpec or a LlamaConfig), the ONE
     table of layer kinds: how it mixes positions (``full``: softmax attention
     over every earlier position; ``window``: over the last ``window``;
-    ``linear``: a gated delta-rule recurrence) and what its feed-forward is
+    ``linear``: a gated delta-rule recurrence; ``eva``: exact keys inside an
+    aligned window and summaries of the windows before it; ``latent``: softmax
+    attention over every earlier position whose cache holds one latent row a
+    position and no key or value) and what its feed-forward is
     (``dense`` or ``experts``). An arch without a period has full layers
     only; one with experts has them in every layer past ``first_dense``."""
-    if spec.eva_chunk:
+    if spec.kv_lora_rank:
+        mixer = "latent"
+    elif spec.eva_chunk:
         mixer = "eva"
     elif spec.attn_period:
         mixer = "full" if l % spec.attn_period == 0 else "linear"
@@ -565,6 +618,29 @@ def _window_layer(spec: ModelSpec, l: int, add) -> None:
     add(p + "q_norm", (spec.head_size,), f32)
     add(p + "k_norm", (spec.head_size,), f32)
     add(p + "wo", (dim, q_dim), wt)
+    if layer_kind(spec, l)[1] == "dense":
+        add(p + "gate", (hidden, dim), wt)
+        add(p + "down", (dim, hidden), wt)
+        add(p + "up", (hidden, dim), wt)
+    else:
+        _held_experts(spec, p, add)
+
+
+def _latent_layer(spec: ModelSpec, l: int, add) -> None:
+    """One ``ArchType.GLM4_MOE_LITE`` layer's tensors (the module docstring's
+    list): seven of attention, then a dense SwiGLU or the expert layer."""
+    wt, f32, dim, hidden = spec.weights_float_type, FloatType.F32, spec.dim, spec.hidden_dim
+    p = f"layers.{l}."
+    heads, rope = spec.n_heads, spec.qk_rope_head_dim
+    add(p + "rms_att", (dim,), f32)
+    add(p + "rms_ffn", (dim,), f32)
+    add(p + "q_a", (spec.q_lora_rank, dim), wt)
+    add(p + "q_a_norm", (spec.q_lora_rank,), f32)
+    add(p + "q_b", (heads * (spec.qk_nope_head_dim + rope), spec.q_lora_rank), wt)
+    add(p + "kv_a", (spec.kv_lora_rank + rope, dim), wt)
+    add(p + "kv_a_norm", (spec.kv_lora_rank,), f32)
+    add(p + "kv_b", (heads * (spec.qk_nope_head_dim + spec.v_head_dim), spec.kv_lora_rank), wt)
+    add(p + "wo", (dim, heads * spec.v_head_dim), wt)
     if layer_kind(spec, l)[1] == "dense":
         add(p + "gate", (hidden, dim), wt)
         add(p + "down", (dim, hidden), wt)

@@ -94,6 +94,18 @@ class LlamaConfig:
     # values (position p at slot p % window) and behind them ``seq_len /
     # eva_chunk`` summaries (chunk m at slot window + m)
     eva_chunk: int = 0
+    # latent attention (ArchType.GLM4_MOE_LITE; 0 elsewhere): the query goes
+    # through a latent of ``q_lora_rank`` values, keys and values through one
+    # of ``kv_lora_rank``; a head's q and k are ``qk_nope_head_dim`` values
+    # that are not rotated and ``qk_rope_head_dim`` that are (``head_size`` is
+    # their sum), its value ``v_head_dim``. A layer's cache holds a position as
+    # ONE row of ``latent_dim`` values: the normed latent and, behind it, the
+    # rotated key slice every head shares
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @property
     def kv_mul(self) -> int:
@@ -109,7 +121,7 @@ class LlamaConfig:
         return self.attn_period > 1
 
     def layer_kind(self, l: int) -> tuple[str, str]:
-        """(``full`` | ``window`` | ``linear``, ``dense`` | ``experts``): how
+        """(``full`` | ``window`` | ``linear`` | ``eva`` | ``latent``, ``dense`` | ``experts``): how
         layer ``l`` mixes positions and what its feed-forward is
         (``formats.model_file.layer_kind``, the one table)."""
         return layer_kind(self, l)
@@ -138,7 +150,26 @@ class LlamaConfig:
         layers are EVA's."""
         if self.has_window:
             return ("full", "window")
+        if self.has_latent:
+            return ("latent",)
         return ("eva_window", "eva_summary") if self.has_eva else ()
+
+    @property
+    def has_latent(self) -> bool:
+        """Whether the layers keep one latent row a position and no key or value."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a latent layer's cache holds of one position: the latent,
+        then the shared rotated key slice (512 + 64 = 576 as published)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """Values of a head that are rotated: all of them, but for a latent
+        layer's ``qk_rope_head_dim``."""
+        return self.qk_rope_head_dim or self.head_size
 
     @property
     def has_eva(self) -> bool:
@@ -205,7 +236,9 @@ class LlamaConfig:
     def norm_topk(self) -> bool:
         """Whether the chosen experts' weights are renormalised to sum to one
         (always, for the archs that have no flag to say otherwise)."""
-        return self.arch not in (ArchType.SOLAR_OPEN2, ArchType.EXAONE_MOE) or self.has(
+        return self.arch not in (
+            ArchType.SOLAR_OPEN2, ArchType.EXAONE_MOE, ArchType.GLM4_MOE_LITE
+        ) or self.has(
             ArchFlags.NORM_TOPK
         )
 
@@ -223,6 +256,11 @@ def next_pow2(n: int) -> int:
 
 
 def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
+    if spec.kv_lora_rank and spec.head_size != spec.qk_nope_head_dim + spec.qk_rope_head_dim:
+        raise ValueError(
+            f"a latent-attention head of {spec.head_size} values is not its "
+            f"{spec.qk_nope_head_dim} unrotated and {spec.qk_rope_head_dim} rotated ones"
+        )
     if spec.eva_chunk and (spec.window % spec.eva_chunk or spec.seq_len % spec.eva_chunk):
         raise ValueError(
             f"EVA attention needs a window ({spec.window}) and a context ({spec.seq_len}) "
@@ -268,5 +306,10 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         first_dense=spec.first_dense,
         routed_scale=spec.routed_scale_milli / 1000.0 if spec.routed_scale_milli else 1.0,
         eva_chunk=spec.eva_chunk,
+        q_lora_rank=spec.q_lora_rank,
+        kv_lora_rank=spec.kv_lora_rank,
+        qk_nope_head_dim=spec.qk_nope_head_dim,
+        qk_rope_head_dim=spec.qk_rope_head_dim,
+        v_head_dim=spec.v_head_dim,
         **overrides,
     )

@@ -385,6 +385,129 @@ def attention(
     return _gated(att, gate), new_cache
 
 
+def _per_head(x: jax.Array, w: jax.Array) -> jax.Array:
+    """x [T, H, a] times a matrix a head, w [H, a, b] -> [T, H, b] f32 (the
+    head leads both operands: the one batched form every backend's dot takes
+    at bfloat16)."""
+    out = jnp.einsum(
+        "hta,hab->htb", jnp.swapaxes(x, 0, 1).astype(w.dtype), w,
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+    return jnp.swapaxes(out, 0, 1)
+
+
+def latent_project(
+    cfg: LlamaConfig, lp: Params, x: jax.Array, rope_rows: jax.Array
+) -> tuple[jax.Array, jax.Array]:
+    """Norm + the latent layer's projections for T tokens: [T, dim] ->
+    (ABSORBED queries [T, H, latent_dim] f32, the tokens' cache rows [T,
+    latent_dim] f32).
+
+    ``[c_q | c | k_r] = rmsnorm(x) W_a`` (one matrix, ``qkv_a``); ``q =
+    rmsnorm(c_q) W_qb``, a head ``[q_nope | q_rope]``; ``c_kv = rmsnorm(c)``.
+    The cache row of a position is ``[c_kv | rot(k_r)]``: ONE rotated key slice
+    for every head. A head's query against such a row is ``[q_nope W_UK |
+    rot(q_rope)]`` (``w_uk`` the head's nope-key rows of the keys' and values'
+    up-projection): ``q_nope . (c_kv W_UK^T) = (q_nope W_UK) . c_kv``, so no key
+    is expanded for a cached position."""
+    T = x.shape[0]
+    H, nope, rope = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    with jax.named_scope("mla_project"):
+        fused = _norm_matmul(x, lp["rms_att"], lp["qkv_a"], "wqkv")
+        c_q = rmsnorm(fused[:, :qr], lp["q_a_norm"])
+        c_kv = rmsnorm(fused[:, qr : qr + kr], lp["kv_a_norm"])
+        k_rope = apply_rope(fused[:, None, qr + kr : qr + kr + rope], rope_rows, cfg)[:, 0]
+        q = _matmul(c_q.astype(lp["q_b"].dtype), lp["q_b"], "mla_project")[:, : H * (nope + rope)]
+        q = q.reshape(T, H, nope + rope)
+        q_rope = apply_rope(q[..., nope:], rope_rows, cfg)
+        q_abs = _per_head(q[..., :nope], lp["w_uk"])
+        return jnp.concatenate([q_abs, q_rope], axis=-1), jnp.concatenate([c_kv, k_rope], axis=-1)
+
+
+def latent_output(cfg: LlamaConfig, lp: Params, mix: jax.Array) -> jax.Array:
+    """The scan's result [T, H, latent_dim] (softmax-weighted sums of cache
+    rows) -> the heads' outputs [T, H * v]: the first ``kv_lora_rank`` columns
+    are ``sum p c_kv``, which a head's value rows of the up-projection
+    (``w_uv``) take to its ``v_head_dim`` values; the columns behind them (the
+    key slice's) are dropped. No value is expanded for a cached position."""
+    return _per_head(mix[..., : cfg.kv_lora_rank], lp["w_uv"]).reshape(mix.shape[0], -1)
+
+
+# positions one step of a latent scan reads: a row of 576 values is a quarter
+# of the keys and values the full layers' chunk of 512 positions was measured
+# with (8 heads of 128, twice), so four times the positions move the same
+# bytes a step; at 512 a step of the scan cost 21 us for 4.7 MB on the chip
+# (225 GB/s: fixed costs of its three fusions), PERF.md section 6, PR 43
+LATENT_CHUNK = 4 * ATT_CHUNK
+
+
+def _latent_chunk(S: int) -> int:
+    """Positions one step of a latent scan reads: ``LATENT_CHUNK``, the full
+    layers' chunk where the cache is no multiple of that, or a small or odd
+    cache (the tests' toy models) whole."""
+    for chunk in (LATENT_CHUNK, ATT_CHUNK):
+        if S % chunk == 0 and S > chunk:
+            return chunk
+    return S
+
+
+def latent_attention(
+    cfg: LlamaConfig, x: jax.Array, lp: Params, cache_l: dict, pos: jax.Array,
+    rope_rows: jax.Array,
+) -> tuple[jax.Array, dict]:
+    """Latent attention for T new tokens of ONE row at positions pos..pos+T-1.
+    ``cache_l``: the row's latent leaf ``{LATENT: [latent_dim, S]}``. The
+    tokens' rows are written, then every query reads the row up to itself
+    ABSORBED, as decode does and through the same scan
+    (``ops.attention.latent_attention_scan``): against expanding a chunk of
+    512 cached positions to keys and values for a piece of T rows (9.2 MFLOP a
+    position), absorbing costs T x 23 kFLOP a position more in scores and mix,
+    less below T of about 400, and a piece has at most 256. The published
+    softmax scale is ``head_size ** -0.5`` (``head_size`` = nope + rope).
+    Returns (the heads' outputs [T, H * v], the leaf)."""
+    from distributed_llama_tpu.ops import kv_cache as kvc
+    from distributed_llama_tpu.ops.attention import latent_attention_scan
+
+    T, H = x.shape[0], cfg.n_heads
+    queries, rows = latent_project(cfg, lp, x, rope_rows)
+    leaf = kvc.latent_update_rows(cache_l, rows, pos)
+    with jax.named_scope("mla_prefill"):
+        latents = leaf[kvc.LATENT][None]  # [1, D, S]
+        q_pos = jnp.repeat(pos + jnp.arange(T), H)[None]  # [1, T * H]: a token's heads sit together
+        mix, _ = latent_attention_scan(
+            queries.reshape(1, T * H, -1), q_pos, latents, _latent_chunk(latents.shape[2]),
+            cfg.head_size ** -0.5,
+        )
+        return latent_output(cfg, lp, mix.reshape(T, H, -1)), leaf
+
+
+def latent_attention_batched(
+    cfg: LlamaConfig, x: jax.Array, lp: Params, cache_l: dict, pos: jax.Array,
+    rope_rows: jax.Array, active: jax.Array,
+) -> tuple[jax.Array, dict]:
+    """One decode step of B independent rows through a latent layer: row ``b``
+    writes its latent row at ``pos[b]`` of ``{LATENT: [B_max, latent_dim, S]}``
+    and its heads' absorbed queries read its own row up to there, every chunk
+    up to the bucket's longest row (``ops.attention.latent_attention_scan``).
+    A hit's pages were copied into the row, so it reads no pool. Inactive rows
+    write nothing and read from position 0."""
+    from distributed_llama_tpu.ops import kv_cache as kvc
+    from distributed_llama_tpu.ops.attention import latent_attention_scan, note_kv_read
+
+    B, H = x.shape[0], cfg.n_heads
+    S = cache_l[kvc.LATENT].shape[2]
+    queries, rows = latent_project(cfg, lp, x, rope_rows)
+    leaf = kvc.latent_update_row_batched(cache_l, rows, jnp.where(active & (pos < S), pos, S))
+    with jax.named_scope("mla_decode"):
+        q_pos = jnp.broadcast_to(jnp.where(active, pos, 0)[:, None], (B, H))
+        mix, read = latent_attention_scan(
+            queries, q_pos, leaf[kvc.LATENT], _latent_chunk(S), cfg.head_size ** -0.5
+        )
+        note_kv_read("latent", B, read)
+        return latent_output(cfg, lp, mix), leaf
+
+
 class RecurrentStateError(RuntimeError):
     """A path that moves or rewinds a row BY POSITION met an arch some of
     whose layers keep a state that is not addressed by position
@@ -407,6 +530,24 @@ class EvaWindowError(RuntimeError):
     overwritten by the next one's and only their summaries are left, and a
     page of the pool holds summaries, not keys. The same paths refuse as for
     a recurrent state or a ring, by this name."""
+
+
+class LatentCacheError(RuntimeError):
+    """A path that shards, narrows or batches a cache BY HEAD, or scores more
+    than one new token a row in a step, met an arch whose layers keep latents
+    (``cfg.has_latent``): a position's row has no head axis to shard or to
+    scale by, and the multi-token verify window has no latent form here. Such
+    a row still rewinds by position, as keys and values do."""
+
+
+def refuse_latent(cfg: LlamaConfig, what: str) -> None:
+    """Refuse ``what`` for an arch whose cache holds latents, by name."""
+    if cfg.has_latent:
+        raise LatentCacheError(
+            f"{what} is not supported for arch {cfg.arch.name}: its latent-attention layers "
+            f"keep one row of {cfg.latent_dim} values a position, with no head axis and no "
+            "key or value"
+        )
 
 
 def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
@@ -554,8 +695,13 @@ def block_forward(
     paged=None,
     layer: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    if "lin_in" in lp:
+    # a caller that does not say which layer it runs (a scanned body) runs an
+    # arch whose layers are all full ones
+    mixer = "full" if layer is None else cfg.layer_kind(layer)[0]
+    if mixer == "linear":
         att, new_cache = linear_attention(cfg, x, lp, cache_l, pos, n_real)
+    elif mixer == "latent":
+        att, new_cache = latent_attention(cfg, x, lp, cache_l, pos, rope_rows)
     else:
         att, new_cache = attention(
             cfg, x, lp, cache_l, pos, rope_rows, axis_name, paged=paged, layer=layer,
@@ -634,7 +780,9 @@ def _forward_tokens(cfg, params, tokens, cache, pos, axis_name, ep_axis, n_real,
         new_layers = []
         for l, lp in enumerate(params["layers"]):
             paged_l = None
-            if paged is not None and paged[0][l] is not None:
+            if paged is not None and cfg.layer_kind(l)[0] == "full":
+                # the one kind whose scan reads a pool in place; a hit of
+                # every other kind was copied into the row
                 pool, table, matched = paged
                 paged_l = (pool[l][0], pool[l][1], table, matched)
             x, nc = block_forward(
@@ -646,8 +794,8 @@ def _forward_tokens(cfg, params, tokens, cache, pos, axis_name, ep_axis, n_real,
     else:
         if paged is not None:
             raise ValueError("the paged (pool-aliased) read requires the layered cache")
-        if cfg.has_window:
-            raise ValueError("layers of two kinds need the layered cache layout")
+        if cfg.has_window or cfg.has_latent:
+            raise ValueError("layers of two kinds, and latent layers, need the layered cache layout")
 
         def body(carry, scanned):
             xc = carry
@@ -832,12 +980,15 @@ def _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, pa
         raise ValueError("batched decode requires the per-layer-list params layout")
     new_layers = []
     for l, lp in enumerate(layers):
+        mixer = cfg.layer_kind(l)[0]
         paged_l = None
-        if paged is not None and paged[0][l] is not None:
+        if paged is not None and mixer == "full":
             pool, tables, matched = paged
             paged_l = (pool[l][0], pool[l][1], tables, matched)
-        if "lin_in" in lp:
+        if mixer == "linear":
             att, nc = linear_attention_batched(cfg, x, lp, cache[l], active)
+        elif mixer == "latent":
+            att, nc = latent_attention_batched(cfg, x, lp, cache[l], pos, rope_rows, active)
         else:
             att, nc = attention_batched(
                 cfg, x, lp, cache[l], pos, rope_rows, active, paged=paged_l, layer=l
@@ -953,6 +1104,7 @@ def forward_verify_batched(
     if not isinstance(cache, (list, tuple)):
         raise ValueError("batched verify requires the layered (per-layer list) cache")
     refuse_recurrent(cfg, "speculative verify (a rejected draft rewinds the row)")
+    refuse_latent(cfg, "speculative verify (a window of several new tokens a row)")
     B, T = tokens.shape
     x = embed(cfg, params, tokens.reshape(-1)).reshape(B, T, -1)
     offsets = pos[:, None] + jnp.arange(T)[None, :]
@@ -999,12 +1151,17 @@ def _init_layer_leaf(cfg: LlamaConfig, l: int, lead: tuple[int, ...], kl: int, d
     """Layer ``l``'s cache leaf by its kind: every position of a row for a
     full layer, a ring of ``cfg.ring_len`` slots for a window layer (it does
     not grow with ``seq_len``), a state for a linear one, the window store and
-    the summaries behind it (``cfg.eva_slots``) for an EVA one."""
+    the summaries behind it (``cfg.eva_slots``) for an EVA one, one row of
+    ``cfg.latent_dim`` values a position, with no head axis, for a latent one."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     mixer = cfg.layer_kind(l)[0]
     if mixer == "linear":
         return init_state_leaf(cfg, lead)
+    if mixer == "latent":
+        if kvc.is_quantized_cache_dtype(dtype):
+            refuse_latent(cfg, "an i8 cache (its scales are one a head)")
+        return kvc.init_latent(lead, cfg.seq_len, cfg.latent_dim, dtype)
     if mixer == "eva" and kvc.is_quantized_cache_dtype(dtype):
         raise ValueError("an EVA layer's summaries have no i8 form: serve it with a plain KV dtype")
     slots = {"window": cfg.ring_len, "eva": cfg.eva_slots}.get(mixer, cfg.seq_len)
@@ -1016,8 +1173,10 @@ def kv_slab_bytes(cfg: LlamaConfig, rows: int, dtype) -> dict[str, int]:
     (``full``, ``window``): a full layer's grow with ``seq_len``, a window
     layer's are its ring's. An EVA arch: by store (``eva_window``: the window's
     slots, which do not grow with ``seq_len``; ``eva_summary``: one entry per
-    ``eva_chunk`` positions)."""
+    ``eva_chunk`` positions). A latent arch: ``latent``, every position's row."""
     per_slot = page_pool_bytes(cfg, 1, dtype, layers=1)
+    if cfg.has_latent:
+        return {"latent": rows * cfg.seq_len * per_slot * cfg.n_layers}
     if cfg.has_eva:
         return {"eva_window": rows * cfg.window * per_slot * cfg.n_layers,
                 "eva_summary": rows * cfg.eva_summaries * per_slot * cfg.n_layers}
@@ -1072,6 +1231,11 @@ def init_page_pool(
     # window only, are in the window pool
     if cfg.has_eva:
         return _init_pool(cfg, "eva", n_pages, page // cfg.eva_chunk, kl, dtype)
+    if cfg.has_latent:
+        # a latent layer's page: ``page`` rows of latent_dim values, ONE half, flat
+        if kvc.is_quantized_cache_dtype(dtype):
+            refuse_latent(cfg, "an i8 cache (its scales are one a head)")
+        return [(kvc.init_latent_pool(n_pages, page, cfg.latent_dim, dtype),) for _ in range(cfg.n_layers)]
     return _init_pool(cfg, "full", n_pages, page, kl, dtype)
 
 
@@ -1108,6 +1272,10 @@ def page_pool_bytes(cfg: LlamaConfig, page: int, dtype, layers: int | None = Non
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     kl, hd = cfg.n_kv_heads, cfg.head_size
+    if cfg.has_latent:
+        # one row a position and layer, no halves
+        return ((cfg.n_layers if layers is None else layers) * page * cfg.latent_dim
+                * jnp.dtype(dtype).itemsize)
     if layers is None and cfg.has_eva:
         # an EVA arch's pool page holds the block's summaries, in every layer
         layers, page = cfg.n_layers, page // cfg.eva_chunk
@@ -1144,6 +1312,6 @@ def init_cache(
         raise ValueError("the i8 KV cache requires the layered cache layout")
     if layered:
         return [_init_layer_leaf(cfg, l, (), kl, dtype) for l in range(cfg.n_layers)]
-    if cfg.is_recurrent or cfg.has_window:
-        raise ValueError("layers of two kinds need the layered cache layout")
+    if cfg.is_recurrent or cfg.has_window or cfg.has_latent:
+        raise ValueError("layers of two kinds, and latent layers, need the layered cache layout")
     return jnp.zeros((cfg.n_layers, 2) + shape, dtype=dtype)

@@ -437,8 +437,12 @@ def _held_experts(
     (``bucket_rank`` / ``bucket_scatter`` / ``bucket_combine``, the algebra
     of the capacity-bucketed prefill and the expert-parallel dispatch): an
     expert computes its own rows, not every row of the step. Where some
-    expert has more rows than its bucket, the step is computed with every
-    held expert over every row instead: exact either way. Rows at and past
+    expert has more rows than its bucket, the buckets are twice as large
+    (a document of few distinct tokens routes alike: 8 to 28 % of a piece's
+    expert layers overflowed a bucket of four times the even share, and the
+    every-row arm costs four times a bucket's launch, PERF.md section 6, PR
+    43), and where one overflows that too the step is computed with every
+    held expert over every row instead: exact each way. Rows at and past
     ``n_real`` (a padded piece) choose no expert: they fill no bucket, trip
     no overflow, and an expert only they chose is not read."""
     T, E = xn.shape[0], cfg.n_experts
@@ -461,15 +465,27 @@ def _held_experts(
         _note_piece_path(1)
         return every_row()
 
-    def bucketed():
-        flat_e, rank, t_ids = bucket_rank(local, E + 1)
-        buckets = bucket_scatter(xn, flat_e, rank, t_ids, E, C)
-        return bucket_combine(_held_ffn(cfg, buckets, lp, on, T), jnp.minimum(local, E - 1), rank,
-                              weights, C)
+    def bucketed(C):
+        def run():
+            flat_e, rank, t_ids = bucket_rank(local, E + 1)
+            buckets = bucket_scatter(xn, flat_e, rank, t_ids, E, C)
+            return bucket_combine(_held_ffn(cfg, buckets, lp, on, T), jnp.minimum(local, E - 1),
+                                  rank, weights, C)
+        return run
 
-    over = jnp.max(counts) > C
-    _note_piece_path(over)
-    return jax.lax.cond(over, every_row, bucketed)
+    most = jnp.max(counts)
+    if T <= 64 or 2 * C >= T:
+        # a decode step (the class of at most 64 rows), or no room for a
+        # second bucket: the bucket, or every expert over every row
+        over = most > C
+        _note_piece_path(over)
+        return jax.lax.cond(over, every_row, bucketed(C))
+    # a prompt piece: the bucket; a bucket of twice its rows where some expert
+    # overflows the first; every expert over every row where one overflows
+    # that too: which of them, from the step's own counts
+    level = (most > C).astype(jnp.int32) + (most > 2 * C).astype(jnp.int32)
+    _note_piece_path(level == 2)
+    return jax.lax.switch(level, [bucketed(C), bucketed(2 * C), every_row])
 
 
 def _moe_share(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:

@@ -46,10 +46,11 @@ def _llama3_scale_freqs(freqs: np.ndarray, cfg: LlamaConfig) -> np.ndarray:
 
 
 def build_rope_table(cfg: LlamaConfig) -> np.ndarray:
-    """Precompute [seq_len, head_size/2, 2] (cos, sin) in float32."""
-    half = cfg.head_size // 2
+    """Precompute [seq_len, rope_dim/2, 2] (cos, sin) in float32; ``rope_dim``
+    is the head size, but for a latent layer's rotated slice."""
+    half = cfg.rope_dim // 2
     j = np.arange(half, dtype=np.float64)
-    freqs = 1.0 / (cfg.rope_theta ** (2.0 * j / cfg.head_size))
+    freqs = 1.0 / (cfg.rope_theta ** (2.0 * j / cfg.rope_dim))
     if cfg.rope_type == RopeType.LLAMA3_1 and not cfg.rope_llama3_reference_quirk:
         freqs = _llama3_scale_freqs(freqs.astype(np.float64), cfg)
     pos = np.arange(cfg.seq_len, dtype=np.float64)
@@ -82,7 +83,7 @@ def apply_rope_interleaved(
     """Rotate interleaved pairs. ``x``: [T, n_heads, head_size];
     ``table_slice``: [T, head_size/2, 2] rows already gathered by position."""
     shape = x.shape
-    xp = x.reshape(*shape[:-1], cfg.head_size // 2, 2)
+    xp = x.reshape(*shape[:-1], shape[-1] // 2, 2)
     cos = table_slice[:, None, :, 0]
     sin = table_slice[:, None, :, 1]
     v0 = xp[..., 0]
@@ -97,8 +98,9 @@ def apply_rope_interleaved(
 
 def apply_rope_neox(x: jax.Array, table_slice: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """Falcon/neox-style rotation of pairs (j, j+half). Same table (the
-    frequency for pair j is theta^(-2j/head_size) in both layouts)."""
-    half = cfg.head_size // 2
+    frequency for pair j is theta^(-2j/head_size) in both layouts). ``x`` is
+    as wide as the table's pairs say (a latent layer's rotated slice)."""
+    half = x.shape[-1] // 2
     v0 = x[..., :half]
     v1 = x[..., half:]
     cos = table_slice[:, None, :, 0]

@@ -23,7 +23,8 @@ from distributed_llama_tpu.ops import kv_cache as kvc
 # Trace-time collector of what a batched decode step's attention reads: while
 # one is open (:func:`collect_kv_reads`), every layer's scan appends (kind,
 # int32 [B]): the positions of each row's cache it read (``full``: the chunks
-# up to the bucket's longest row, every row alike; ``window``: the window;
+# up to the bucket's longest row, every row alike, ``latent`` where the layer
+# keeps latents; ``window``: the window;
 # ``eva_window`` / ``eva_summary``: an EVA scan's chunks of each store).
 # The forward that opened it sums by kind and returns the sums with its
 # tokens, as the expert share's counts are (``models.moe.collect_held``).
@@ -388,6 +389,53 @@ def batched_window_attention(
     scores = kvc.scores_einsum_batched(qg.astype(cdt), kc, prec) / jnp.sqrt(jnp.float32(hd))
     mask = (k_pos >= 0)[:, None, None, :]
     return _window_softmax(scores, mask, vc, cdt, prec, kvc.mix_einsum_batched)
+
+
+def latent_attention_scan(
+    q: jax.Array,  # [B, Q, D] f32 absorbed queries: Q = heads (decode) or tokens x heads (a piece)
+    q_pos: jax.Array,  # [B, Q] the position each query sits at (it sees positions up to there)
+    latents: jax.Array,  # a latent leaf's array [B_max, D, S], positions minor, the new rows in it
+    chunk: int,
+    scale: float,
+) -> jax.Array:
+    """Causal softmax attention ABSORBED over a cache of latents: a position is
+    one row of D values which is key and value of every head at once (``score =
+    q . row``, ``out = sum p row``; the caller keeps the first ``rank`` columns
+    of the result and folds the rest of the up-projection into ``q`` before and
+    into the output after: ``models.llama.latent_project``). The first B rows
+    of ``latents`` are read a chunk [D, chunk] at a time up to the farthest
+    query, online softmax, as the full layers' scans do; nothing else of the
+    leaf is read and, the chunk being the score product's right-hand side as
+    it lies and the mix's transposed, nothing of the leaf's size forms
+    (tests/test_chip_compile.py). One loop serves a decode step's rows and a
+    piece's tokens. Returns ([B, Q, D] f32, the positions read a row)."""
+    B, Q, D = q.shape
+    S = latents.shape[2]
+    cdt, prec = latents.dtype, kvc.einsum_precision(latents)
+    n_chunks = jax.lax.div(jnp.clip(jnp.max(q_pos) + 1, 0, S) + chunk - 1, chunk)
+    qc = q.astype(cdt)
+
+    def body(i, carry):
+        c = jax.lax.dynamic_slice(latents, (0, 0, i * chunk), (B, D, chunk))
+        scores = scale * jnp.einsum(
+            "bqd,bds->bqs", qc, c, precision=prec, preferred_element_type=jnp.float32
+        )
+        mask = (i * chunk + jnp.arange(chunk))[None, None, :] <= q_pos[:, :, None]
+        scores = jnp.where(mask, scores, -jnp.inf)
+        ms = jnp.max(scores, axis=-1)
+        # a fully-masked chunk keeps m = -inf, the empty partial (merge_partials)
+        safe_m = jnp.where(jnp.isfinite(ms), ms, 0.0)
+        p = jnp.where(mask, jnp.exp(scores - safe_m[..., None]), 0.0)
+        os_ = jnp.einsum(
+            "bqs,bds->bqd", p.astype(cdt), c, precision=prec, preferred_element_type=jnp.float32
+        )
+        return merge_partials(*carry, ms, jnp.sum(p, axis=-1), os_)
+
+    m0 = jnp.full((B, Q), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((B, Q), jnp.float32)
+    o0 = jnp.zeros((B, Q, D), jnp.float32)
+    m, l, o = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, o0))
+    return o / jnp.maximum(l, 1e-30)[..., None], n_chunks * chunk
 
 
 # ---------------------------------------------------------------------------

@@ -473,6 +473,96 @@ def restore_row_blocks(slab_leaf, pool_k, pool_v, row, first, page_ids):
 
 
 # ---------------------------------------------------------------------------
+# Latent rows (a latent-attention layer's cache): a position is ONE row of D
+# values, the normed latent and behind it the rotated key slice every head
+# shares; there is no head axis and there are no key and value halves. A
+# layer's leaf is ``{LATENT: [.., D, S]}``, POSITIONS MINOR: D (576 as
+# published) is no multiple of the 128 lanes of a tile, and for a leaf stored
+# [.., S, D] the v5e compiler chooses the positions-minor layout itself and
+# copies the whole leaf into it and back in every step of every layer
+# (tests/test_chip_compile.py holds the program to in-place writes alone).
+# Stored so, a scan's chunk [D, n] is the right-hand side of the score
+# product as it lies and of the value mix transposed, which the MXU takes. The
+# leaf is a dict, as a linear layer's state is, so :func:`fused_take_row` and
+# :func:`fused_put_row` move its rows. The layer's pool entry is the 1-tuple
+# ``([P, page * D],)`` (:func:`init_latent_pool`): a page's rows as a row
+# reads them, copied and transposed at publish and restore;
+# :func:`slice_pool_page` and :func:`upload_pool_page` take such a half as they
+# take any plain one.
+# ---------------------------------------------------------------------------
+
+LATENT = "latent"
+
+
+def init_latent(lead: tuple[int, ...], slots: int, dim: int, dtype) -> dict:
+    """A latent layer's cache leaf: ``slots`` positions of ``dim`` values."""
+    return {LATENT: jnp.zeros(lead + (dim, slots), dtype)}
+
+
+def is_latent_leaf(cache_l) -> bool:
+    return isinstance(cache_l, dict) and LATENT in cache_l
+
+
+def latent_update_rows(leaf: dict, rows: jax.Array, pos) -> dict:
+    """T positions' rows [T, D] at slots pos..pos+T-1 of a single-row latent
+    leaf [D, S]: one dynamic_update_slice."""
+    a = leaf[LATENT]
+    return {LATENT: jax.lax.dynamic_update_slice(a, rows.astype(a.dtype).T, (0, pos))}
+
+
+def latent_update_row_batched(leaf: dict, rows: jax.Array, slot: jax.Array) -> dict:
+    """The batched decode write: row ``b``'s latent row [D] at slot ``slot[b]``
+    of slab row ``b`` of [B_max, D, S]; a slot >= S writes nothing. A row's
+    write is the 128 positions around its slot read, the one column replaced,
+    and written back with one ``dynamic_update_slice`` (a lane tile wide: 147
+    KB a row at the published width). Not a scatter, and not a single column:
+    either wants the D values minor in its operand, the scans read positions
+    minor, and the compiler then converts the whole leaf between the two in
+    every step of every layer (tests/test_chip_compile.py)."""
+    a = leaf[LATENT]
+    D, S = a.shape[1], a.shape[2]
+    W = 128 if S % 128 == 0 else S
+    rows = rows.astype(a.dtype)
+    for b in range(rows.shape[0]):
+        start = jnp.minimum(slot[b], S - 1) // W * W
+        old = jax.lax.dynamic_slice(a, (b, 0, start), (1, D, W))
+        here = (start + jnp.arange(W) == slot[b])[None, None, :]
+        a = jax.lax.dynamic_update_slice(a, jnp.where(here, rows[b][None, :, None], old), (b, 0, start))
+    return {LATENT: a}
+
+
+def init_latent_pool(n_pages: int, page: int, dim: int, dtype):
+    """A latent layer's pool half: [P, page * D], a page one row (its ``page``
+    positions' rows of D values behind one another). Flat, because a scatter
+    of pages into [P, page, D] makes the compiler copy the WHOLE pool into
+    another layout and back at every publish (D = 576 is no multiple of a
+    tile's 128 lanes; 64 x 576 is): 0.75 ms a copy, twice a layer a publish,
+    on the chip (PERF.md section 6, PR 43)."""
+    return jnp.zeros((n_pages, page * dim), dtype)
+
+
+def publish_latent_pages(pool_half, leaf: dict, row, src_page, page_ids, page: int):
+    """:func:`publish_row_pages` for a latent leaf [B, D, S]: row ``row``'s
+    blocks ``src_page[i]`` into pool pages ``page_ids[i]`` of [P, page * D]
+    (:func:`init_latent_pool`); an id at or beyond P drops its write."""
+    slots = block_slots(src_page, page)
+    own = jax.lax.dynamic_index_in_dim(leaf[LATENT], row, 0, keepdims=False)  # [D, S]
+    vals = jnp.take(own, slots, axis=1).T  # [n * page, D]
+    return pool_half.at[page_ids].set(vals.reshape((src_page.shape[0], -1)), mode="drop")
+
+
+def restore_latent_blocks(leaf: dict, pool_half, row, first, page_ids) -> dict:
+    """:func:`restore_row_blocks` for a latent leaf [B, D, S]: pool pages
+    ``page_ids[i]`` of [P, page * D] into block ``first + i`` of row ``row``,
+    one contiguous ``dynamic_update_slice``."""
+    a = leaf[LATENT]
+    D = a.shape[1]
+    run = pool_half[page_ids].reshape((-1, D)).T[None]  # [1, D, n * page]
+    page = pool_half.shape[1] // D
+    return {LATENT: jax.lax.dynamic_update_slice(a, run, (row, 0, first * page))}
+
+
+# ---------------------------------------------------------------------------
 # Rings (a window layer's cache): position p sits at slot p % R of [.., R, K,
 # hd], so the leaf does not grow with the row's length. A write is a scatter at
 # the positions' slots (a piece may wrap), a read gathers the slots of the

@@ -356,14 +356,27 @@ class EngineInstruments:
             "(the window's positions of each row's ring); for an arch with EVA "
             "layers by store: eva_window (the window store's chunks up to the "
             "bucket's farthest row in its window) and eva_summary (the "
-            "summaries' chunks up to its deepest row); counted by the "
-            "programs from their scans' bounds and returned with their tokens",
+            "summaries' chunks up to its deepest row); for an arch of latent-"
+            "attention layers: latent (every chunk up to the bucket's longest "
+            "row, of rows that hold one latent a position and no key or value); "
+            "counted by the programs from their scans' bounds and returned "
+            "with their tokens",
             labelnames=("kind",),
         )
         self.kv_read = {
             kind: kv_read.labels(kind=kind)
-            for kind in ("full", "window", "eva_window", "eva_summary")
+            for kind in ("full", "window", "eva_window", "eva_summary", "latent")
         }
+        kv_read_positions = counter(
+            "dllama_attn_kv_read_positions_total",
+            "Cache positions the batched decode steps' attention scans read, a "
+            "position counted once however many layers read it (kind latent: an "
+            "arch of latent-attention layers): dllama_attn_kv_read_bytes_total "
+            "of the kind over this is the bytes a row stores of one position, "
+            "over all layers",
+            labelnames=("kind",),
+        )
+        self.kv_read_positions = {"latent": kv_read_positions.labels(kind="latent")}
         self.eva_summaries_written = counter(
             "dllama_eva_summaries_written_total",
             "Chunks an arch with EVA layers summarised: a prompt piece or a "

@@ -411,6 +411,54 @@ def test_served_eva_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatc
     assert not others, "slab-sized buffers besides the cache writes:\n" + "\n".join(others)
 
 
+def test_served_latent_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
+    """The decode chunk of ``glm-4.7-flash.doc_sessions`` (GLM-4.7-Flash's
+    attention widths, 2 layers with a dense feed-forward, 8 rows of 16384
+    latent rows of 576 values, positions minor): a step writes a layer's leaf
+    in place, a lane tile around each row's new position, and the latent scan
+    reads it a chunk at a time, as keys and as values; nothing else of the
+    leaf's size forms. Stored [rows, positions, 576], or written by a scatter
+    or a column at a time, the same program copied every layer's whole leaf
+    into the positions-minor layout and back, in every step (576 is no
+    multiple of a tile's 128 lanes, and the compiler reads it positions-minor
+    whatever it is given)."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    rows, layers = 8, 2
+    cfg = LlamaConfig(
+        arch=ArchType.GLM4_MOE_LITE, dim=2048, hidden_dim=10240, n_layers=layers, n_heads=20,
+        n_kv_heads=20, vocab_size=154880, seq_len=16384, head_size=256, kv_dim=5120,
+        rope_theta=1e6, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layer = dict(
+        qkv_a=_qm_shape(2048, 1344, one_chip), q_b=_qm_shape(768, 5120, one_chip),
+        q_a_norm=s((768,), jnp.float32), kv_a_norm=s((512,), jnp.float32),
+        w_uk=s((20, 192, 512), jnp.bfloat16), w_uv=s((20, 512, 256), jnp.bfloat16),
+        wo=_qm_shape(5120, 2048, one_chip),
+        gate_up=_qm_shape(2048, 20480, one_chip), down=_qm_shape(10240, 2048, one_chip),
+        rms_att=s((2048,), jnp.float32), rms_ffn=s((2048,), jnp.float32),
+    )
+    params = dict(
+        embedding=s((154880, 2048), jnp.float32), layers=[layer] * layers,
+        rms_final=s((2048,), jnp.float32), rope_table=s((16384, 32, 2), jnp.float32),
+        wcls=_qm_shape(2048, 154880, one_chip),
+    )
+    slab = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+        lambda: llama.init_batch_cache(cfg, rows, dtype=jnp.bfloat16)))
+    assert [leaf["latent"].shape for leaf in slab] == [(rows, 576, 16384)] * layers
+    compiled = sampling.decode_chunk_batched.lower(
+        cfg, params, s((rows,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+        s((rows,), jnp.uint32)).compile()
+    writes, others = _slab_sized_results(compiled.as_text(), slab[0]["latent"].size // 2)
+    assert len(writes) == rows * layers, writes
+    assert not others, "slab-sized buffers besides the cache writes:\n" + "\n".join(others)
+
+
 def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
     """``sampling.spec_verify_chunk_batched_paged`` (``--spec-draft 4``, 16
     rows): one forward per dispatch, so the whole program is the step."""

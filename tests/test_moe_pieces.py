@@ -166,8 +166,17 @@ def test_without_n_real_the_held_experts_lower_to_the_parents_text(engines, arch
     xn = jnp.asarray(padded(T, T, cfg.dim, seed=1))
     top_vals, top_idx = moe.router_topk(cfg, xn, lp["router"], lp.get("router_bias"))
     args = (lp, xn, top_vals, top_idx)
-    assert _program_text(lambda lp, xn, v, i: moe._held_experts(cfg, xn, lp, v, i), *args) \
-        == _program_text(parents, *args)
+
+    def ours(lp, xn, v, i):
+        return moe._held_experts(cfg, xn, lp, v, i)
+
+    if T > 64 and 2 * moe.held_bucket_rows(cfg, T) < T:
+        # 256 rows are a piece's, and a piece's program did move in PR 43 (a bucket of twice the
+        # rows between the bucket and every row): not the parent's text, the parent's numbers
+        assert _program_text(ours, *args) != _program_text(parents, *args)
+        assert off(np.asarray(jax.jit(ours)(*args)), np.asarray(jax.jit(parents)(*args))) <= TOL
+        return
+    assert _program_text(ours, *args) == _program_text(parents, *args)
 
 
 @pytest.mark.parametrize("arch", ["dense", "evabyte"])
