@@ -2654,22 +2654,21 @@ class BatchScheduler:
         """Enqueue the plain decode program over the rows the vectors cover
         (cond held) and return its token bundle. Slab and carry are donated
         and come back advanced; the row vectors are host buffers that cross
-        with the call."""
+        with the call. On one chip the program is the one WITHOUT pages
+        whether or not a pool is on: a hit was copied into its row at
+        admission (``_restore_pages``, an EVA arch's ``_eva_restore``), so no
+        decode step reads a page, and an attention that is handed no pages
+        may bound each row's reads by the row (``ops.decode_attention``: a
+        scan handed pages keeps the XLA loop). ``tables`` and ``matched``
+        are the tp backend's, which reads the sharded pool in place."""
         from distributed_llama_tpu.models import sampling
 
         engine = self.engine
         if engine._tp_engine is None:
-            if self._pool is not None:
-                out, self._slab, self._carry = sampling.decode_chunk_batched_paged(
-                    engine.cfg, engine.params, self._carry, self._slab, pos,
-                    active, self._pool, self.chunk, temps, topps, topks, seeds,
-                    tables, matched,
-                )
-            else:
-                out, self._slab, self._carry = sampling.decode_chunk_batched(
-                    engine.cfg, engine.params, self._carry, self._slab, pos,
-                    active, self.chunk, temps, topps, topks, seeds,
-                )
+            out, self._slab, self._carry = sampling.decode_chunk_batched(
+                engine.cfg, engine.params, self._carry, self._slab, pos,
+                active, self.chunk, temps, topps, topks, seeds,
+            )
         elif self._pool is not None:
             out, self._slab, self._carry = engine._tp_engine.batched_decode_chunk_paged(
                 engine.params, self._carry, self._slab, self._pool, pos, active,

@@ -18,14 +18,17 @@ import contextlib
 import jax
 import jax.numpy as jnp
 
+from distributed_llama_tpu.ops import decode_attention
 from distributed_llama_tpu.ops import kv_cache as kvc
 
 # Trace-time collector of what a batched decode step's attention reads: while
 # one is open (:func:`collect_kv_reads`), every layer's scan appends (kind,
-# int32 [B]): the positions of each row's cache it read (``full``: the chunks
-# up to the bucket's longest row, every row alike, ``latent`` where the layer
-# keeps latents; ``window``: the window;
-# ``eva_window`` / ``eva_summary``: an EVA scan's chunks of each store).
+# int32 [B]): the positions of each row's cache it read (``full``: the row's
+# OWN chunks where the row-bounded kernel serves, the chunks up to the
+# bucket's longest row, every row alike, where the XLA scan does; ``latent``
+# where the layer keeps latents; ``window``: the window;
+# ``eva_window`` / ``eva_summary``: an EVA scan's chunks of each store,
+# by row or by bucket likewise).
 # The forward that opened it sums by kind and returns the sums with its
 # tokens, as the expert share's counts are (``models.moe.collect_held``).
 _kv_reads: list | None = None
@@ -42,8 +45,18 @@ def collect_kv_reads(enabled: bool = True):
 
 
 def note_kv_read(kind: str, rows: int, positions) -> None:
+    """``positions``: one count for every row alike, or int32 [rows]."""
     if _kv_reads is not None:
-        _kv_reads.append((kind, jnp.full((rows,), positions, jnp.int32)))
+        _kv_reads.append((kind, jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (rows,))))
+
+
+def _note_path(kernel: str, path: str) -> None:
+    """Which scan an attention call took, counted where the program is traced
+    (``dllama_kernel_path_total``; ``decode_attention``: a batched decode
+    step's scan, ``paged_attention``: a scan that reads pages)."""
+    from distributed_llama_tpu import telemetry
+
+    telemetry.note_kernel_path(kernel, path)
 
 
 def chunk_attention(
@@ -154,7 +167,10 @@ def _segmented_batched_scan(partial, cache, paged, chunk: int, n_chunks, init, r
     single mixed body). Chunk indices, chunk bytes and merge order are
     those of the unpaged scan. Bit-parity vs the copy path is
     test-enforced on the CPU mesh; the hit-vs-cold parity tests are the
-    tripwire on any new backend."""
+    tripwire on any new backend. A decode step over a fused bf16 or f32 slab
+    without pages does NOT come here (``decode_attention.slab_decode_scan``,
+    per-row bounds), so a verify step and the decode it replaces agree to a
+    tolerance there, not to the bit (:func:`batched_verify_attention`)."""
 
     def slab_only(kc, vc, i, carry):
         return partial(kc, vc, i * chunk, carry)
@@ -282,6 +298,28 @@ def _verify_partial(qg, pos, chunk: int, cdt, prec):
     return partial
 
 
+# A bucket of ONE row over a slab this short keeps the XLA loop: a single
+# row's bound is the bucket's, so the per-row bound saves nothing, and on the
+# chip the program around the kernel lost more than the kernel won (XLA
+# prefetches `down` into VMEM beside it and waits for it in the open):
+# mistral7b.single_stream tpot_p50_ms 3.661 on the loop against 3.889 on the
+# kernel, one pair, 16 x 2048 slots (PERF.md section 6, PR 44). Longer slabs
+# were not paired at one row; there the kernel alone wins more a chunk.
+ONE_ROW_LOOP_SLOTS = 2048
+
+
+def _causal_tables(pos, S: int, chunk: int):
+    """What each row of a causal decode step visits (the tables of
+    ``decode_attention.slab_decode_scan``): row ``b`` walks chunks 0 ..
+    pos[b] // chunk of its own row and sees of chunk ``i`` the first
+    ``clip(pos[b] + 1 - chunk i, 0, chunk)`` slots."""
+    first = chunk * jnp.arange(S // chunk, dtype=jnp.int32)
+    live = jnp.clip(pos + 1, 0, S)
+    starts = jnp.broadcast_to(first, (pos.shape[0], first.shape[0]))
+    visible = jnp.clip(live[:, None] - first[None, :], 0, chunk)
+    return starts, visible, jax.lax.div(live + chunk - 1, chunk)
+
+
 def batched_decode_attention(
     qg: jax.Array,  # [B, K, M, hd] f32 grouped queries (one token per row)
     cache,  # the layer's slab: fused leaf [2, B_max, S, K, hd] or (keys, values)
@@ -291,16 +329,26 @@ def batched_decode_attention(
 ) -> jax.Array:
     """Blocked causal attention of B independent single-token queries, each
     over its OWN slab cache row, masked by its OWN position: row ``b`` sees
-    slots 0..pos[b]. One fori_loop covers all rows with a shared DYNAMIC
-    chunk bound (max over pos), so slots beyond the longest live context are
-    never read; rows shorter than the bound are masked per chunk and fully-
-    masked chunks contribute zero via the online-softmax merge. Returns
-    [B, K, M, hd] f32. Requires S % chunk == 0 (callers fall back to the
-    full-S einsum otherwise, exactly like the single-stream path). The
-    slab may hold MORE rows than B (a dispatch bucket below B_max): only
-    the first B rows are read. ``cache`` is passed as the layer stores it
-    (array or QuantizedKV leaf, or the tp backend's ``(keys, values)``
-    halves) and only chunk-sized pieces of it are ever sliced out.
+    slots 0..pos[b]. Returns [B, K, M, hd] f32. Requires S % chunk == 0
+    (callers fall back to the full-S einsum otherwise, exactly like the
+    single-stream path). The slab may hold MORE rows than B (a dispatch
+    bucket below B_max): only the first B rows are read. ``cache`` is passed
+    as the layer stores it (array or QuantizedKV leaf, or the tp backend's
+    ``(keys, values)`` halves) and only chunk-sized pieces of it are ever
+    sliced out.
+
+    Two scans serve, chosen from the input. A fused array leaf read without
+    pages takes the row-bounded kernel (``decode_attention.slab_decode_scan``):
+    each row reads the chunks up to ITS position and nothing past them, so a
+    short row beside long ones, an inactive lane (position 0: one chunk) and
+    a row whose request ended cost their own K/V. Everything else (``paged``,
+    an i8 leaf, the tp backend's tuple, and a bucket of ONE row over a slab
+    of at most ``ONE_ROW_LOOP_SLOTS`` slots) takes the XLA scan: one fori_loop
+    covers all rows with a shared DYNAMIC chunk bound (max over pos), so
+    slots beyond the longest live context are never read; rows shorter than
+    the bound are masked per chunk and fully-masked chunks contribute zero
+    via the online-softmax merge. Chunk indices, chunk size and merge order
+    are the same in both.
 
     With ``paged`` set (zero-copy prefix aliasing), row ``b``'s positions
     below ``matched[b]`` are read from the shared page pool THROUGH its page
@@ -312,10 +360,15 @@ def batched_decode_attention(
     the virtual-row einsum otherwise)."""
     B, K, M, hd = qg.shape
     S, cdt, prec = kvc.slab_facts(cache)
+    short_one_row = B == 1 and S <= ONE_ROW_LOOP_SLOTS
+    if paged is None and not short_one_row and decode_attention.supports(cache, chunk):
+        _note_path("decode_attention", "pallas_rowbound")
+        starts, visible, n_steps = _causal_tables(pos, S, chunk)
+        note_kv_read("full", B, n_steps * chunk)
+        return decode_attention.slab_decode_scan(qg, cache, starts, visible, n_steps, chunk)
+    _note_path("decode_attention", "xla_scan")
     if paged is not None:
-        from distributed_llama_tpu import telemetry
-
-        telemetry.note_kernel_path("paged_attention", "xla_segmented")
+        _note_path("paged_attention", "xla_segmented")
     live = jnp.clip(jnp.max(pos) + 1, 0, S)
     n_chunks = jax.lax.div(live + chunk - 1, chunk)
     note_kv_read("full", B, n_chunks * chunk)
@@ -594,6 +647,25 @@ def _eva_sees(window: int, c: int):
     return sees
 
 
+def _eva_tables(pos, slots: int, window: int, c: int, chunk: int):
+    """What each row of an EVA decode step visits (the tables of
+    ``decode_attention.slab_decode_scan``): the window store's chunks up to
+    the row's slot ``pos % W``, then the summaries' up to the chunks of its
+    earlier windows (:func:`_eva_sees` by row; the leaf's own summaries at
+    most), each chunk seen as a prefix. Returns ``(starts, visible, n_win,
+    n_sum)``; a row walks ``n_win + n_sum`` steps."""
+    W = window
+    first = chunk * jnp.arange(slots // chunk, dtype=jnp.int32)[None, :]
+    win = (pos % W + 1)[:, None]
+    summ = jnp.minimum((W // c) * (pos // W), slots - W)[:, None]
+    n_win = jax.lax.div(win + chunk - 1, chunk)
+    in_window = first < n_win * chunk
+    behind = first - n_win * chunk  # the summaries' chunks follow the row's window chunks
+    starts = jnp.where(in_window, first, jnp.minimum(W + behind, slots - chunk))
+    visible = jnp.clip(jnp.where(in_window, win - first, summ - behind), 0, chunk)
+    return starts, visible, n_win[:, 0], jax.lax.div(summ[:, 0] + chunk - 1, chunk)
+
+
 def eva_batched_decode_attention(
     qg: jax.Array,  # [B, K, M, hd] f32 grouped queries (one token per row)
     cache,  # the layer's slab leaf [2, B_max, W + C, K, hd], this step's writes in it
@@ -604,14 +676,25 @@ def eva_batched_decode_attention(
 ) -> jax.Array:
     """EVA attention of B independent single-token queries: row ``b`` sees
     window slots 0 .. pos[b] % W (its aligned window up to itself) and the
-    summaries of the chunks of its earlier windows. ONE loop reads the leaf
-    (a leaf that feeds two is re-laid out: :func:`_segmented_batched_scan`),
-    first the window store's chunks up to the longest row's slot, then the
-    summaries' up to the deepest row's; a row sees of them what its own
-    position allows. Returns [B, K, M, hd] f32."""
+    summaries of the chunks of its earlier windows. A fused array leaf of
+    whole chunks takes the row-bounded kernel: each row reads the window
+    store's chunks up to ITS slot and the summaries' up to ITS depth.
+    Otherwise ONE XLA loop reads the leaf (a leaf that feeds two is re-laid
+    out: :func:`_segmented_batched_scan`), first the window store's chunks up
+    to the longest row's slot, then the summaries' up to the deepest row's; a
+    row sees of them what its own position allows. Returns [B, K, M, hd] f32."""
     B, K, M, hd = qg.shape
     W = window
-    _, cdt, prec = kvc.slab_facts(cache)
+    slots, cdt, prec = kvc.slab_facts(cache)
+    if decode_attention.supports(cache, chunk) and W % chunk == 0:
+        _note_path("decode_attention", "pallas_rowbound")
+        starts, visible, n_win, n_sum = _eva_tables(pos, slots, W, c, chunk)
+        note_kv_read("eva_window", B, n_win * chunk)
+        note_kv_read("eva_summary", B, n_sum * chunk)
+        return decode_attention.slab_decode_scan(
+            qg, cache, starts, visible, n_win + n_sum, chunk
+        )
+    _note_path("decode_attention", "xla_scan")
     n_steps, n_win, n_sum, start = _eva_steps(
         jnp.max(pos % W) + 1, jnp.max((W // c) * (pos // W)), W, chunk
     )
@@ -642,8 +725,13 @@ def batched_verify_attention(
     fori_loop covers all rows with a shared dynamic chunk bound
     (max(pos) + T), so slots beyond the longest live window are never
     read; fully-masked chunks merge as exact identities (empty partials),
-    which keeps each query's output bit-identical to the single-token
-    decode step at the same position. Returns [B, T, K, M, hd] f32.
+    which keeps each query's output the single-token decode step's at the
+    same position: to the bit where that step takes this XLA scan too, and
+    to the tolerance tests/test_kernel_parity.py holds the pair to (8 f32
+    eps of the largest output) where it takes the row-bounded kernel (a
+    fused bf16 or f32 slab read without pages, :func:`batched_decode_attention`):
+    verify stays on the XLA scan, another dot in another order of summation.
+    Returns [B, T, K, M, hd] f32.
     Requires S % chunk == 0 (callers fall back to the full-S einsum).
 
     ``paged``: the zero-copy prefix read, segmented exactly like
@@ -653,9 +741,7 @@ def batched_verify_attention(
     B, T, K, M, hd = qg.shape
     S, cdt, prec = kvc.slab_facts(cache)
     if paged is not None:
-        from distributed_llama_tpu import telemetry
-
-        telemetry.note_kernel_path("paged_attention", "xla_segmented")
+        _note_path("paged_attention", "xla_segmented")
     live = jnp.clip(jnp.max(pos) + T, 0, S)
     n_chunks = jax.lax.div(live + chunk - 1, chunk)
     partial = _verify_partial(qg, pos, chunk, cdt, prec)

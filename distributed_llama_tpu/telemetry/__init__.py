@@ -351,12 +351,14 @@ class EngineInstruments:
         kv_read = counter(
             "dllama_attn_kv_read_bytes_total",
             "Bytes of keys and values the decode chunks' attention read out of "
-            "slab and pool, by layer kind: full (every chunk up to the "
-            "bucket's longest row, every row of the bucket alike) and window "
+            "slab and pool, by layer kind: full (each row's own chunks where "
+            "the row-bounded kernel serves; under the XLA scan every chunk up "
+            "to the bucket's longest row, every row alike) and window "
             "(the window's positions of each row's ring); for an arch with EVA "
             "layers by store: eva_window (the window store's chunks up to the "
-            "bucket's farthest row in its window) and eva_summary (the "
-            "summaries' chunks up to its deepest row); for an arch of latent-"
+            "row's slot in its window) and eva_summary (the summaries' chunks "
+            "up to the row's depth; under the XLA scan the bucket's farthest "
+            "and deepest row's); for an arch of latent-"
             "attention layers: latent (every chunk up to the bucket's longest "
             "row, of rows that hold one latent a position and no key or value); "
             "counted by the programs from their scans' bounds and returned "
@@ -733,10 +735,10 @@ def note_kernel_path(kernel: str, path: str) -> None:
         REGISTRY.counter(
             "dllama_kernel_path_total",
             "Kernel dispatch decisions by kernel (q40_matmul / "
-            "paged_attention / all_reduce) and selected path (mxu_int8 / "
-            "mxu_int8_fusedq / xla_segmented / slab_restored / ici_ring / "
-            "ring_xla / psum / xla_fallback); counted at trace time per "
-            "program build",
+            "paged_attention / decode_attention / all_reduce) and selected "
+            "path (mxu_int8 / mxu_int8_fusedq / xla_segmented / slab_restored / "
+            "pallas_rowbound / xla_scan / ici_ring / ring_xla / psum / "
+            "xla_fallback); counted at trace time per program build",
             labelnames=("kernel", "path"),
         ).labels(kernel=kernel, path=path).inc()
 

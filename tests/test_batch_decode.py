@@ -131,6 +131,50 @@ class TestBatchedParity:
         assert first == want
         assert second == want
 
+    def test_rows_on_lane_wide_heads_decode_through_the_row_bounded_kernel(self, tmp_path):
+        """Heads of 128 over a slab of two whole chunks: a bucket of two
+        rows attends through ``decode_attention.slab_decode_scan`` (interpret
+        mode here), each row bounded by its own position; a bucket of ONE
+        row over so short a slab keeps the XLA loop
+        (``attention.ONE_ROW_LOOP_SLOTS``); and a greedy row's tokens are its
+        solo run's (which reads its cache through the single-stream scan)."""
+        from distributed_llama_tpu import telemetry
+
+        def build(name):
+            spec = tiny_spec(dim=256, n_heads=2, n_kv_heads=1, seq_len=1024)
+            write_model_file(str(tmp_path / name), spec, random_tensors(spec, seed=3))
+            return InferenceEngine(str(tmp_path / name), dtype=jnp.float32)
+
+        ref_engine = build("ref.m")
+        prompts = [[1, 5, 9, 2, 8, 3], [2, 4]]
+        refs = [single_stream_tokens(ref_engine, p, 0.0, 0.9, 5, 6) for p in prompts]
+        telemetry.enable()
+        try:
+            def taken():
+                paths = telemetry.REGISTRY.counter(
+                    "dllama_kernel_path_total", labelnames=("kernel", "path"))
+                return tuple(paths.labels(kernel="decode_attention", path=p).value > 0
+                             for p in ("pallas_rowbound", "xla_scan"))
+
+            telemetry.reset()
+            sched = BatchScheduler(build("bat.m"), n_rows=2, chunk=4)
+            streams = [sched.new_stream() for _ in prompts]
+            outs = [join(sched, s, (p, 0.0, 0.9, 5)) for s, p in zip(streams, prompts)]
+            sched.kick()
+            outs = [out + take(sched, s, 5) for out, s in zip(outs, streams)]
+            assert sched._decode_built == {2} and taken() == (True, False)
+            telemetry.reset()
+            alone = BatchScheduler(build("one.m"), n_rows=2, chunk=4)
+            s = alone.new_stream()
+            out = join(alone, s, (prompts[0], 0.0, 0.9, 5))
+            alone.kick()
+            out += take(alone, s, 5)
+            assert alone._decode_built == {1} and taken() == (False, True)
+        finally:
+            telemetry.reset()
+            telemetry.disable()
+        assert outs == refs and out == refs[0]
+
     def test_join_mid_stream(self, tmp_path):
         """A second request joining BETWEEN chunks (bucket grows 1 → 2)
         must not perturb the already-running row, and both rows must match
@@ -596,8 +640,10 @@ def test_a_dispatch_issues_the_same_device_work_at_any_row_count(tmp_path, joine
 
     programs, transfers = device_work(dispatch)
     assert sched._pending is not None and len(sched._pending[2]) == joined
-    row_vectors = 8 if paged else 6  # pos, active, temps, topps, topks, seeds (+ tables, matched)
-    assert (programs, transfers) == (1, row_vectors)
+    # pos, active, temps, topps, topks, seeds; a one-chip scheduler with a pool
+    # copies hits into their rows and dispatches the program without pages
+    # (no tables, no matched): the same six
+    assert (programs, transfers) == (1, 6)
 
 
 class TestBuiltBuckets:
@@ -636,12 +682,15 @@ class TestBuiltBuckets:
 
         prompt, n = PROMPTS[0], 6
         temp, topp, seed = SAMPLING[0]
-        # a context of its own: the programs' cache is the process's, and keyed by the config
+        # a context of its own (and one a case, now that both build the same
+        # program): the programs' cache is the process's, and keyed by the config
+        seq_len = 80 if paged else 88
         want = single_stream_tokens(
-            build_engine(tmp_path, "a.m", seq_len=88), prompt, temp, topp, seed, n)
-        program = sampling.decode_chunk_batched_paged if paged else sampling.decode_chunk_batched
+            build_engine(tmp_path, "a.m", seq_len=seq_len), prompt, temp, topp, seed, n)
+        # with a pool too: hits are copied into rows, the chunk reads no pages
+        program = sampling.decode_chunk_batched
         kw = dict(prefix_cache=True, kv_pages=8, page_size=8) if paged else {}
-        sched = BatchScheduler(build_engine(tmp_path, "b.m", seq_len=88), n_rows=4, chunk=4, **kw)
+        sched = BatchScheduler(build_engine(tmp_path, "b.m", seq_len=seq_len), n_rows=4, chunk=4, **kw)
         streams = [sched.new_stream() for _ in range(4)]
         before = [np.asarray(a).copy() for a in jax.tree.leaves((sched._slab, sched._carry))]
         built = program._cache_size()

@@ -27,7 +27,7 @@ from distributed_llama_tpu.formats.model_file import ArchType
 from distributed_llama_tpu.models import llama, sampling
 from distributed_llama_tpu.models.config import LlamaConfig
 from distributed_llama_tpu.ops import attention as att
-from distributed_llama_tpu.ops import collectives, q40
+from distributed_llama_tpu.ops import collectives, decode_attention, q40
 
 
 @pytest.fixture(scope="module")
@@ -201,6 +201,47 @@ def test_paged_decode_attention_scan_compiles(one_chip, K, M):
     jax.jit(f).lower(**_paged_decode_args(one_chip, K, M)).compile()
 
 
+# the cells' slabs: (leaf, query heads a kv head, rows of the bucket)
+DECODE_SCAN_SHAPES = {
+    "long_doc": ((2, 8, 8192, 8, 128), 4, 8),
+    "evabyte": ((2, 8, 3072, 32, 128), 1, 8),
+    "single_stream": ((2, 16, 2048, 8, 128), 4, 1),
+    "rows16": ((2, 16, 2048, 8, 128), 4, 16),
+    "solar_rows32": ((2, 32, 2048, 8, 128), 8, 32),
+    "k_exaone": ((2, 8, 16384, 8, 128), 8, 8),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_SCAN_SHAPES))
+def test_row_bounded_decode_scan_compiles(one_chip, monkeypatch, cell):
+    """``decode_attention.slab_decode_scan`` at the cells' slab shapes: one
+    Mosaic kernel that takes the leaf as stored (no half of it, no copy of
+    it: nothing of the leaf's size in the program but its parameter). A
+    bucket of one row over 2048 slots is served by the XLA loop
+    (``att.ONE_ROW_LOOP_SLOTS``: the chip's pair); the kernel is held to
+    compiling at that shape all the same, the threshold may move."""
+    monkeypatch.setattr(decode_attention, "_interpret_default", lambda: False)
+    monkeypatch.setattr(att, "ONE_ROW_LOOP_SLOTS", 0)
+    leaf, M, rows = DECODE_SCAN_SHAPES[cell]
+    _, _, slots, K, hd = leaf
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def f(qg, cache, pos):
+        if cell == "evabyte":
+            return att.eva_batched_decode_attention(qg, cache, pos, 2048, 16, 512)
+        return att.batched_decode_attention(qg, cache, pos, 512)
+
+    compiled = jax.jit(f).lower(
+        s((rows, K, M, hd), jnp.float32), s(leaf, jnp.bfloat16), s((rows,), jnp.int32)
+    ).compile()
+    hlo = compiled.as_text()
+    assert "slab_decode_scan" in hlo and "tpu_custom_call" in hlo
+    writes, others = _slab_sized_results(hlo, int(np.prod(leaf)) // 2)
+    assert not writes and not others, writes + others
+
+
 # ---------------------------------------------------------------------------
 # The served batched programs at the benchmark cells' shapes (Mistral-7B
 # widths, 2 of its layers, the 16-row x 2048 bf16 slab, the 384-page pool):
@@ -319,13 +360,16 @@ def _aliased_outputs(hlo: str) -> dict:
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "unpaged"])
 @pytest.mark.parametrize("rows", [1, SERVED_ROWS])
 def test_served_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch, rows, paged):
-    """``sampling.decode_chunk_batched[_paged]`` as the cells dispatch it
-    (bucket 1: ``single_stream``; bucket 16: ``chat_shared``, ``batch_decode``):
+    """``sampling.decode_chunk_batched`` as the cells dispatch it (bucket 1:
+    ``single_stream``; bucket 16: ``chat_shared``, ``batch_decode``; since
+    PR 44 a one-chip scheduler hands its chunks no pages, hits being copied
+    into their rows) and its ``_paged`` twin (rows that alias the pool):
     in the whole program, the scan's body included, only the per-layer cache
     write has a result of half a slab leaf or more. The scheduler's carry of
     first tokens (one entry a slab row, whatever the bucket) adds none, and
     comes back in the buffer it was donated in."""
     monkeypatch.setattr(q40, "_interpret_default", lambda: False)  # steer the CPU branch
+    monkeypatch.setattr(decode_attention, "_interpret_default", lambda: False)
     cfg, params, slab, pool, s = _served_program_shapes(one_chip)
     head = (cfg, params, s((SERVED_ROWS,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_))
     sampler = (32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
@@ -337,6 +381,9 @@ def test_served_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch, r
         lowered = sampling.decode_chunk_batched.lower(*head, *sampler)
     compiled = lowered.compile()
     _assert_no_slab_sized_temporaries(compiled, slab)
+    # a fused slab read without pages takes the row-bounded kernel, as stored;
+    # a bucket of one row over this short a slab keeps the loop
+    assert ("slab_decode_scan" in compiled.as_text()) == (not paged and rows > 1)
     # results: the bundle, the slab's leaves, the carry; arguments: the
     # weights' leaves, then the carry
     carry_out, carry_in = 1 + len(jax.tree.leaves(slab)), len(jax.tree.leaves(params))
@@ -378,6 +425,7 @@ def test_served_eva_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatc
     summary of the chunk that position may end) and its one loop reads the
     leaf a chunk at a time; nothing else of the leaf's size forms."""
     monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    monkeypatch.setattr(decode_attention, "_interpret_default", lambda: False)
     rows, layers = 8, 2
     cfg = LlamaConfig(
         arch=ArchType.EVABYTE, dim=4096, hidden_dim=11008, n_layers=layers, n_heads=32,
@@ -409,6 +457,7 @@ def test_served_eva_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatc
     writes, others = _slab_sized_results(compiled.as_text(), slab[0].size // 2)
     assert len(writes) == 2 * layers, writes
     assert not others, "slab-sized buffers besides the cache writes:\n" + "\n".join(others)
+    assert "slab_decode_scan" in compiled.as_text()
 
 
 def test_served_latent_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):

@@ -12,6 +12,9 @@ an 8-device virtual mesh):
   the identical op sequence, so any drift is a bug, not tolerance.
 * the paged segmented scan: the spec-hit == plain-decode transitivity
   across bf16/f32/i8 and bucket shapes, and what the dispatch counts.
+* the row-bounded decode scan (``ops/decode_attention.py``): the kernel
+  against the XLA scan it stands in for, causal and EVA tables, ragged
+  rows, NaN past every row's own bound, what it counts.
 * ring all-reduce + the matmul_all_reduce seam: the ring schedule
   (ppermute realization — remote DMA has no interpret mode) vs psum
   under the CPU mesh mocks. The fused matmul+ring kernel is TPU-compiled
@@ -29,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from distributed_llama_tpu.ops import attention as att
+from distributed_llama_tpu.ops import decode_attention
 from distributed_llama_tpu.ops import kv_cache as kvc
 from distributed_llama_tpu.ops.q40 import (
     QuantizedMatrix,
@@ -338,7 +342,7 @@ class TestDispatchTable:
             ints(B, S // page), ints(B),
         ) == {
             "q40_matmul/mxu_int8_fusedq": 2, "q40_matmul/mxu_int8": 2,
-            "paged_attention/xla_segmented": 1,
+            "paged_attention/xla_segmented": 1, "decode_attention/xla_scan": 1,
         }
 
     def test_ops_reads_one_environment_variable(self):
@@ -448,6 +452,291 @@ class TestPagedScan:
         pos = jnp.asarray([20, 5], jnp.int32)
         out = att.batched_decode_attention(qg, (keys, values), pos, chunk)
         assert out.shape == (B, K, M, hd)
+
+
+# the slabs of the row-bounded scan's tests: chunks of 32 slots, heads of 128
+# (the kernel takes whole lane rows; toy heads keep the XLA scan)
+ROW_CHUNK, ROW_HD = 32, 128
+EVA_W, EVA_C, EVA_SUMMARIES = 64, 4, 32  # a window of two chunks, one chunk of summaries
+
+
+def _row_inputs(dtype, B, B_max, slots, K, M, seed=0):
+    """``(qg [B, K, M, hd] f32, leaf [2, B_max, slots, K, hd])``. A bf16 case
+    draws small whole numbers: every product and every sum of a score is then
+    exact in f32 whatever the order of summation, so both scans round the
+    same weights to bf16 (a score that differs in its last bit can flip that
+    rounding, 2**-8 of a weight: not a fault of either scan, and far over the
+    f32 tolerance); a f32 case draws normals."""
+    rng = np.random.RandomState(seed)
+    if dtype == jnp.bfloat16:
+        draw = lambda *shape: rng.randint(-2, 3, shape).astype(np.float32)  # noqa: E731
+    else:
+        draw = lambda *shape: rng.randn(*shape).astype(np.float32)  # noqa: E731
+    return jnp.asarray(draw(B, K, M, ROW_HD)), jnp.asarray(draw(2, B_max, slots, K, ROW_HD)).astype(dtype)
+
+
+def _attend_rows(kind, qg, leaf, pos, chunk=ROW_CHUNK):
+    if kind == "eva":
+        return att.eva_batched_decode_attention(qg, leaf, pos, EVA_W, EVA_C, chunk)
+    return att.batched_decode_attention(qg, leaf, pos, chunk)
+
+
+def _xla_scan(kind, qg, leaf, pos, chunk=ROW_CHUNK):
+    """The scan the kernel stands in for, on the same inputs: what the two
+    callers run when the kernel does not take the leaf (for causal tables
+    :func:`_segmented_batched_scan`, for EVA the caller's own loop)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(decode_attention, "supports", lambda *a: False)
+        return _attend_rows(kind, qg, leaf, pos, chunk)
+
+
+def _assert_close_as_verify_and_decode(got, want):
+    atol = 8 * np.finfo(np.float32).eps * float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=atol)
+
+
+def _past_own_bound(kind, pos, B_max, slots):
+    """bool [B_max, slots]: the slots row ``b``'s query does not see, and
+    every slot of a row past the bucket."""
+    B = len(pos)
+    pos = np.concatenate([np.asarray(pos), np.full(B_max - B, -1)])[:, None]
+    g = np.arange(slots)[None, :]
+    if kind == "eva":
+        seen = np.where(g < EVA_W, g <= pos % EVA_W, g - EVA_W < (EVA_W // EVA_C) * (pos // EVA_W))
+    else:
+        seen = g <= pos
+    return ~seen | (np.arange(B_max)[:, None] >= B)
+
+
+# per kind: a full bucket (a row at 0, at a chunk's last slot, at the slab's
+# end, at a chunk's first slot) and a bucket below B_max
+ROW_POSITIONS = {
+    "causal": {"full": [0, 31, 127, 32], "below": [64, 0, 127]},
+    "eva": {"full": [0, 63, 191, 64], "below": [130, 0, 95]},
+}
+ROW_SLOTS = {"causal": 128, "eva": EVA_W + EVA_SUMMARIES}
+
+
+class TestRowBoundedDecodeScan:
+    """``decode_attention.slab_decode_scan`` (interpret mode) behind the
+    causal and the EVA decode attention: the XLA scan's result to the
+    tolerance verify and decode are held to, from each row's OWN chunks."""
+
+    @pytest.mark.parametrize("bucket", ["full", "below"])
+    @pytest.mark.parametrize("kind", ["causal", "eva"])
+    @pytest.mark.parametrize("K,M", [(8, 4), (32, 1)])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    def test_kernel_equals_the_xla_scan(self, dtype, K, M, kind, bucket):
+        pos = ROW_POSITIONS[kind][bucket]
+        qg, leaf = _row_inputs(dtype, len(pos), 4, ROW_SLOTS[kind], K, M)
+        pos = jnp.asarray(pos, jnp.int32)
+        assert decode_attention.supports(leaf, ROW_CHUNK)
+        _assert_close_as_verify_and_decode(
+            _attend_rows(kind, qg, leaf, pos), _xla_scan(kind, qg, leaf, pos)
+        )
+
+    @pytest.mark.parametrize("kind", ["causal", "eva"])
+    def test_a_chunk_of_several_score_blocks(self, kind):
+        """32 kv heads over a chunk of 64 slots: the flattened chunk is two
+        blocks of ``SUB_ROWS`` rows, scored and mixed one after the other."""
+        chunk, slots = 64, {"causal": 256, "eva": EVA_W + 64}[kind]
+        assert chunk * 32 == 2 * decode_attention.SUB_ROWS
+        pos = jnp.asarray({"causal": [200, 0, 63], "eva": [130, 0, 64]}[kind], jnp.int32)
+        qg, leaf = _row_inputs(jnp.float32, 3, 3, slots, 32, 1, seed=3)
+        _assert_close_as_verify_and_decode(
+            _attend_rows(kind, qg, leaf, pos, chunk), _xla_scan(kind, qg, leaf, pos, chunk)
+        )
+
+    @pytest.mark.parametrize("kind", ["causal", "eva"])
+    @pytest.mark.parametrize("K,M,chunk", [(12, 2, 32), (40, 1, 16)])
+    def test_kv_heads_that_do_not_divide_a_score_block_in_one_block(self, K, M, chunk, kind):
+        """12 or 40 kv heads (Llama-2-13B's) over a chunk that is ONE score
+        block: the block starts at kv head 0 and the kernel takes it, with
+        the query heads padded to whole tiles (24 -> 32, 40 -> 48)."""
+        slots = {"causal": 128, "eva": EVA_W + EVA_SUMMARIES}[kind]
+        pos = jnp.asarray({"causal": [100, 0, chunk - 1], "eva": [191, 0, 64]}[kind], jnp.int32)
+        qg, leaf = _row_inputs(jnp.float32, 3, 3, slots, K, M, seed=4)
+        assert decode_attention.supports(leaf, chunk) and chunk * K <= decode_attention.SUB_ROWS
+        _assert_close_as_verify_and_decode(
+            _attend_rows(kind, qg, leaf, pos, chunk), _xla_scan(kind, qg, leaf, pos, chunk)
+        )
+
+    @pytest.mark.parametrize("kind", ["causal", "eva"])
+    @pytest.mark.parametrize("K,chunk", [(12, 256), (40, 128), (10, 512)])
+    def test_kv_heads_that_do_not_divide_a_score_block_keep_the_xla_scan(self, K, chunk, kind):
+        """The kernel adds ONE block's kv-head bias to every score block of a
+        chunk, which is right only where a block starts at kv head 0
+        (``SUB_ROWS % K == 0``). 10, 12 or 40 heads over several blocks tile
+        the chunk but not a block: ``supports`` says no, the caller keeps the
+        XLA scan (counted as such) and attends over each head's own columns
+        (a dense softmax over the visible slots says so)."""
+        from distributed_llama_tpu import telemetry
+
+        rows = chunk * K
+        assert rows > decode_attention.SUB_ROWS and rows % decode_attention.SUB_ROWS == 0
+        assert decode_attention.SUB_ROWS % K
+        slots = {"causal": 2 * chunk, "eva": 2 * chunk}[kind]
+        W = chunk  # EVA: a window of one chunk, then a chunk of summaries
+        pos = [chunk + 5, 0] if kind == "causal" else [W + 5, 0]
+        qg, leaf = _row_inputs(jnp.float32, 2, 2, slots, K, 1, seed=5)
+        assert not decode_attention.supports(leaf, chunk)
+        telemetry.enable()
+        try:
+            telemetry.reset()
+            ctr = telemetry.REGISTRY.counter("dllama_kernel_path_total", labelnames=("kernel", "path"))
+            if kind == "eva":
+                got = att.eva_batched_decode_attention(qg, leaf, jnp.asarray(pos, jnp.int32), W, EVA_C, chunk)
+            else:
+                got = att.batched_decode_attention(qg, leaf, jnp.asarray(pos, jnp.int32), chunk)
+            assert ctr.labels(kernel="decode_attention", path="xla_scan").value == 1
+            assert ctr.labels(kernel="decode_attention", path="pallas_rowbound").value == 0
+        finally:
+            telemetry.reset()
+            telemetry.disable()
+        g = np.arange(slots)
+        for b, p in enumerate(pos):
+            if kind == "eva":
+                seen = np.where(g < W, g <= p % W, g - W < (W // EVA_C) * (p // W))
+            else:
+                seen = g <= p
+            keys, values = (np.asarray(leaf[h, b], np.float64)[seen] for h in (0, 1))  # [n, K, hd]
+            s = np.einsum("kd,nkd->kn", np.asarray(qg[b, :, 0], np.float64), keys) / np.sqrt(ROW_HD)
+            w = np.exp(s - s.max(axis=1, keepdims=True))
+            want = np.einsum("kn,nkd->kd", w / w.sum(axis=1, keepdims=True), values)
+            np.testing.assert_allclose(np.asarray(got[b, :, 0]), want, rtol=0, atol=1e-5)
+
+    @pytest.mark.parametrize("kind", ["causal", "eva"])
+    @pytest.mark.parametrize("K,M", [(8, 4), (32, 1)])
+    def test_bf16_weights_of_a_real_softmax(self, K, M, kind):
+        """Normal draws in a bf16 slab: the scores are no whole numbers, so
+        the weights that both scans round to bf16 before the value mix
+        (``p.astype(v.dtype)``) are a real softmax's. A score's last bit may
+        flip one such rounding (2**-8 of a weight), so the two agree to a
+        bf16-sized bound, 2**-8 of the largest output, and no closer; a wrong
+        cast or mask in the mix pass is far outside it."""
+        pos = ROW_POSITIONS[kind]["full"]
+        rng = np.random.RandomState(7)
+        qg = jnp.asarray(rng.randn(len(pos), K, M, ROW_HD).astype(np.float32))
+        leaf = jnp.asarray(rng.randn(2, 4, ROW_SLOTS[kind], K, ROW_HD).astype(np.float32)).astype(jnp.bfloat16)
+        pos = jnp.asarray(pos, jnp.int32)
+        got, want = _attend_rows(kind, qg, leaf, pos), _xla_scan(kind, qg, leaf, pos)
+        bound = 2.0**-8 * float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=bound)
+        # ... and the kernel is no farther from the f32 softmax of the same
+        # bf16 slab than the XLA scan is, to that same bound
+        exact = _xla_scan(kind, qg, leaf.astype(jnp.float32), pos)
+        assert float(jnp.max(jnp.abs(got - exact))) <= float(jnp.max(jnp.abs(want - exact))) + bound
+
+    @pytest.mark.parametrize("kind", ["causal", "eva"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    def test_nan_past_a_rows_own_bound_is_never_read(self, dtype, kind):
+        """THE test that the bound is per row: NaN in every slot past each
+        row's own bound (a short row's chunks that the bucket's longest row
+        visits, the rest of its last chunk) and in every row past the bucket;
+        the output is finite and equals the clean slab's, bit for bit."""
+        pos = {"causal": [64, 0, 100], "eva": [130, 0, 95]}[kind]
+        qg, leaf = _row_inputs(dtype, len(pos), 4, ROW_SLOTS[kind], 8, 4, seed=1)
+        poison = _past_own_bound(kind, pos, 4, ROW_SLOTS[kind])[None, :, :, None, None]
+        poisoned = jnp.where(poison, jnp.nan, leaf.astype(jnp.float32)).astype(dtype)
+        assert bool(jnp.isnan(poisoned).any(axis=(0, 2, 3, 4)).all())  # every row holds some
+        pos = jnp.asarray(pos, jnp.int32)
+        got = _attend_rows(kind, qg, poisoned, pos)
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(_attend_rows(kind, qg, leaf, pos)))
+        _assert_close_as_verify_and_decode(got, _xla_scan(kind, qg, leaf, pos))
+
+    def test_a_row_of_no_steps_and_an_empty_chunk(self):
+        """A row with ``n_steps`` 0 returns zeros and reads nothing; a chunk
+        of which the query sees nothing merges as the exact identity."""
+        qg, leaf = _row_inputs(jnp.float32, 3, 3, 128, 8, 4, seed=2)
+        first = ROW_CHUNK * jnp.arange(4, dtype=jnp.int32)
+        starts = jnp.broadcast_to(first, (3, 4))
+        visible = jnp.asarray([[32, 32, 7, 0], [32, 32, 32, 32], [32, 0, 0, 0]], jnp.int32)
+        n_steps = jnp.asarray([3, 0, 1], jnp.int32)
+        got = decode_attention.slab_decode_scan(qg, leaf, starts, visible, n_steps, ROW_CHUNK)
+        want = _xla_scan("causal", qg, leaf, jnp.asarray([70, 0, 31], jnp.int32))
+        _assert_close_as_verify_and_decode(got[0], want[0])
+        _assert_close_as_verify_and_decode(got[2], want[2])
+        assert not np.asarray(got[1]).any()
+        # an empty chunk in the middle of row 0's walk changes no bit of it
+        holed = decode_attention.slab_decode_scan(
+            qg, leaf, starts.at[0].set(jnp.asarray([0, 96, 32, 64])),
+            visible.at[0].set(jnp.asarray([32, 0, 32, 7])), n_steps.at[0].set(4), ROW_CHUNK,
+        )
+        np.testing.assert_array_equal(np.asarray(holed[0]), np.asarray(got[0]))
+
+    def test_what_the_kernel_does_not_take_keeps_the_xla_scan(self):
+        _, leaf = _row_inputs(jnp.bfloat16, 1, 2, 128, 8, 4)
+        assert decode_attention.supports(leaf, ROW_CHUNK)
+        assert not decode_attention.supports((leaf[0], leaf[1]), ROW_CHUNK)  # the tp backend's halves
+        assert not decode_attention.supports(kvc.init_fused((2, 128, 8, ROW_HD), jnp.int8), ROW_CHUNK)
+        assert not decode_attention.supports(leaf[:, :, :100], ROW_CHUNK)  # not whole chunks
+        assert not decode_attention.supports(leaf[..., :64], ROW_CHUNK)  # a toy head
+        with pytest.raises(ValueError, match="does not take"):
+            decode_attention.slab_decode_scan(
+                jnp.zeros((1, 8, 4, 64)), leaf[..., :64], jnp.zeros((1, 4), jnp.int32),
+                jnp.zeros((1, 4), jnp.int32), jnp.zeros((1,), jnp.int32), ROW_CHUNK,
+            )
+
+    @pytest.mark.parametrize("slots,kernel", [(128, False), (4096, True)])
+    def test_a_bucket_of_one_row_keeps_the_xla_scan_over_a_short_slab(self, monkeypatch, slots, kernel):
+        """One row's bound is the bucket's, so the kernel's per-row bound
+        saves nothing there, and on the chip the program around it lost more
+        than it won (``att.ONE_ROW_LOOP_SLOTS``, the pair in PERF.md): up to
+        that many slots a bucket of ONE row takes the XLA scan, past them and
+        at two rows the kernel; the answer is the same either way."""
+        paths = []
+        monkeypatch.setattr(att, "_note_path", lambda kernel, path: paths.append(path))
+        assert att.ONE_ROW_LOOP_SLOTS == 2048
+        qg, leaf = _row_inputs(jnp.float32, 2, 2, slots, 8, 4, seed=6)
+        pos = jnp.asarray([slots - 29, 3], jnp.int32)
+        one = att.batched_decode_attention(qg[:1], leaf, pos[:1], ROW_CHUNK)
+        two = att.batched_decode_attention(qg, leaf, pos, ROW_CHUNK)
+        assert paths == ["pallas_rowbound" if kernel else "xla_scan", "pallas_rowbound"]
+        _assert_close_as_verify_and_decode(one[0], two[0])
+        _assert_close_as_verify_and_decode(two, _xla_scan("causal", qg, leaf, pos))
+
+    @pytest.mark.parametrize("kind", ["causal", "eva"])
+    def test_a_row_is_counted_for_its_own_reads(self, kind):
+        """``note_kv_read`` under the kernel: each row's OWN chunks (two rows
+        of different length read different amounts, an inactive row, handed
+        in at position 0, one chunk); under the XLA scan the bucket's bound."""
+        pos = {"causal": [100, 0, 40], "eva": [191, 0, 70]}[kind]
+        own = {
+            "causal": {"full": [128, 32, 64]},
+            "eva": {"eva_window": [64, 32, 32], "eva_summary": [32, 0, 32]},
+        }[kind]
+        qg, leaf = _row_inputs(jnp.float32, 3, 3, ROW_SLOTS[kind], 8, 4)
+        with att.collect_kv_reads() as reads:
+            _attend_rows(kind, qg, leaf, jnp.asarray(pos, jnp.int32))
+        assert {k: np.asarray(v).tolist() for k, v in reads} == own
+        with att.collect_kv_reads() as reads:
+            _xla_scan(kind, qg, leaf, jnp.asarray(pos, jnp.int32))
+        assert {k: np.asarray(v).tolist() for k, v in reads} == {
+            k: [max(v)] * 3 for k, v in own.items()
+        }
+
+    def test_each_path_is_counted_once_a_program_build(self):
+        from distributed_llama_tpu import telemetry
+
+        qg, leaf = _row_inputs(jnp.float32, 2, 2, 128, 8, 4)
+        pos = jnp.asarray([100, 3], jnp.int32)
+        telemetry.enable()
+        try:
+            telemetry.reset()
+            ctr = telemetry.REGISTRY.counter("dllama_kernel_path_total", labelnames=("kernel", "path"))
+            count = lambda path: ctr.labels(kernel="decode_attention", path=path).value  # noqa: E731
+            fused = jax.jit(lambda q, a, p: att.batched_decode_attention(q, a, p, ROW_CHUNK))
+            halves = jax.jit(lambda q, k, v, p: att.batched_decode_attention(q, (k, v), p, ROW_CHUNK))
+            for _ in range(2):  # the second call builds nothing
+                fused(qg, leaf, pos)
+                halves(qg, leaf[0], leaf[1], pos)
+            assert (count("pallas_rowbound"), count("xla_scan")) == (1, 1)
+            jax.jit(lambda q, a, p: _attend_rows("eva", q, a, p))(qg, leaf[:, :, :96], pos)
+            assert (count("pallas_rowbound"), count("xla_scan")) == (2, 1)
+        finally:
+            telemetry.reset()
+            telemetry.disable()
 
 
 class TestRingAllReduce:

@@ -149,35 +149,40 @@ class TestRestoredRow:
         assert all(nd.refs == 0 for nd in sched._prefix._walk())
 
     def test_the_programs_are_handed_an_empty_read_alias(self, tmp_path, monkeypatch):
-        """The hit's suffix prefill and every decode chunk get a zero table
-        and ``matched`` 0 for the restored row, while ``matched_len`` says
-        what the cache saved."""
-        seen = {"prefill": [], "decode": []}
-        prefill, decode = batch._slab_prefill_single_paged, sampling.decode_chunk_batched_paged
+        """The hit's suffix prefill gets a zero table and ``matched`` 0 for
+        the restored row and every decode chunk is the program WITHOUT pages
+        (an empty alias reads what no alias reads, and a scan that is handed
+        no pages may bound each row's reads by the row), while
+        ``matched_len`` says what the cache saved."""
+        seen = {"prefill": [], "decode": 0}
+        prefill, decode = batch._slab_prefill_single_paged, sampling.decode_chunk_batched
 
         def spy_prefill(*args):
             seen["prefill"].append((np.asarray(args[-2]), int(args[-1])))
             return prefill(*args)
 
         def spy_decode(*args):
-            seen["decode"].append((np.asarray(args[-2]), np.asarray(args[-1])))
+            seen["decode"] += 1
             return decode(*args)
 
+        def no_pages(*args):
+            raise AssertionError("a decode chunk of restored rows was handed pages")
+
         monkeypatch.setattr(batch, "_slab_prefill_single_paged", spy_prefill)
-        monkeypatch.setattr(sampling, "decode_chunk_batched_paged", spy_decode)
+        monkeypatch.setattr(sampling, "decode_chunk_batched", spy_decode)
+        monkeypatch.setattr(sampling, "decode_chunk_batched_paged", no_pages)
         engine = build_engine(tmp_path)
         sched = _sched(engine)
         assert sched._hit_restores
         s = sched.new_stream()
         decode_tokens(s, PROMPT, 0.0, 0.9, 7, 2)
-        seen["prefill"].clear(), seen["decode"].clear()
+        seen["prefill"].clear()
+        seen["decode"] = 0
         decode_tokens(s, PROMPT, 0.0, 0.9, 7, 6)  # the hit
         assert s.matched_len == 2 * PAGE
         assert seen["prefill"] and seen["decode"]
         for table, matched in seen["prefill"]:
             assert matched == 0 and not table.any()
-        for tables, matched in seen["decode"]:
-            assert not matched.any() and not tables.any()
         with sched._cond:
             table, matched = sched._alias_row_arrays_locked(s)
         assert int(matched) == 0 and not np.asarray(table).any()
