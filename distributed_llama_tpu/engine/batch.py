@@ -1170,6 +1170,8 @@ class BatchScheduler:
                     np.zeros(bucket, np.int32), jnp.int32(0), ring=self._wslots.ring,
                 )
         self._snaps = None
+        # layers that keep a state a row (0: none): what a token through them is counted by
+        self._state_layers = len(engine.cfg.layers_of(engine.cfg.state_mixer or ""))
         if engine.cfg.is_recurrent:
             engine._tel.recurrent_state_bytes.set(
                 llama.recurrent_state_bytes(engine.cfg, n_rows)
@@ -1574,6 +1576,7 @@ class BatchScheduler:
                             stream.row, stream.pos, c,
                         )
                     self._note_summaries(stream.pos, c)
+                    self._note_state_tokens("prefill", c)
                     stream.pos += c
                     # the smallest output nothing donates onward is what the
                     # ledger's watcher waits for
@@ -3092,6 +3095,17 @@ class BatchScheduler:
                 tel.tokens_generated.inc(self.chunk * delivered)
                 tel.device_sampled_tokens.inc(self.chunk * delivered)
                 tel.decode_latency.observe(per_token_ms / 1000.0)
+            self._note_state_tokens("decode", self.chunk * n_active)
+
+    def _note_state_tokens(self, phase: str, tokens: int) -> None:
+        """``tokens`` went through every recurrent layer (a decode chunk's
+        joined rows x steps, a prompt piece's real tokens): counted by the
+        layers' kind (``dllama_state_layer_tokens_total``)."""
+        tel = self.engine._tel
+        if self._state_layers and tel.enabled:
+            tel.state_layer_tokens[self.engine.cfg.state_mixer, phase].inc(
+                tokens * self._state_layers
+            )
 
     def _deliver_spec(self, toks, snapshot, sw, lens, error) -> None:
         """Deliver one fetched batched VERIFY step: row ``b``'s column is

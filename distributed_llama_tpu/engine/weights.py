@@ -424,6 +424,25 @@ def load_params(
             lp.update(held_experts(p))
         return lp
 
+    def ssm_layer(l: int) -> dict:
+        """One ``ArchType.GRANITE_HYBRID`` layer: the state-space mixer's ONE
+        input matrix as the file has it (``ssm_in``: z | x|B|C | dt, 8512 rows
+        as published, which the Q40 kernel pads to 9 tiles of 1024: one launch
+        with 8 % of its columns zero, against three launches with more padding
+        each if it were split) or q|k|v of a softmax layer as one matrix, and a
+        dense SwiGLU; the recurrence's vectors stay float32."""
+        p = f"layers.{l}."
+        if cfg.is_softmax_layer(l):
+            lp = {"qkv": fused([p + "q", p + "k", p + "v"])}
+        else:
+            lp = {"ssm_in": weight(p + "ssm_in")}
+            lp.update({k: f32(p + k) for k in
+                       ("conv", "conv_bias", "dt_bias", "a_log", "ssm_d", "ssm_norm")})
+        lp.update({"wo": weight(p + "wo"), "gate_up": fused([p + "gate", p + "up"]),
+                   "down": weight(p + "down"),
+                   "rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")})
+        return lp
+
     def next_token_head():
         """Rows 0 .. vocab_size - 1 of an output matrix of several prediction
         heads: the next token's. The others (self-speculation over the tokens
@@ -435,7 +454,8 @@ def load_params(
         return cast(_t(reader.tensor_rows("wcls", 0, cfg.vocab_size), np.float32))
 
     by_layer = {ArchType.SOLAR_OPEN2: hybrid_layer, ArchType.EXAONE_MOE: window_layer,
-                ArchType.EVABYTE: eva_layer, ArchType.GLM4_MOE_LITE: latent_layer}
+                ArchType.EVABYTE: eva_layer, ArchType.GLM4_MOE_LITE: latent_layer,
+                ArchType.GRANITE_HYBRID: ssm_layer}
     if cfg.arch in by_layer:
         from distributed_llama_tpu.models.llama import refuse_latent, refuse_recurrent
 

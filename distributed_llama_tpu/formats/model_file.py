@@ -79,6 +79,23 @@ a shared one; its files carry the keys of ``_ARCH_KEYS[ArchType.GLM4_MOE_LITE]``
     head's rows: its nope key rows, then its value rows), wo [dim, H*v]
   dense layer / expert layer: as ``ArchType.EXAONE_MOE``
 
+``ArchType.GRANITE_HYBRID`` (no reference counterpart; :func:`_ssm_layer`)
+mixes state-space layers (Mamba-2's SSD recurrence: ``ssm_heads`` heads of
+``ssm_head_dim`` values, a state of ``ssm_state`` values a head value, ONE
+input and one output projection of the state for all heads) with softmax
+layers that do not rotate (layer l is a softmax layer where ``l % attn_period
+== attn_offset``); every layer has a dense SwiGLU; four multipliers (of the
+embedding, of every block's output before the residual add, of the attention
+scores, and a divisor of the logits) ride the header in millionths; its files
+carry the keys of ``_ARCH_KEYS[ArchType.GRANITE_HYBRID]``:
+
+  state-space layer: ssm_in [2*Hs*P + 2*N + Hs, dim] (rows: the gate z, then
+    x|B|C which the convolution reads, then dt), conv (F32) [Hs*P + 2*N, taps],
+    conv_bias (F32) [Hs*P + 2*N], dt_bias, a_log, ssm_d (F32) [Hs], ssm_norm
+    (F32) [Hs*P], wo [dim, Hs*P]
+  softmax layer: q [H*hd, dim], k, v [K*hd, dim], wo [dim, H*hd]
+  every layer: gate, down, up (width hidden_dim), rms_att, rms_ffn
+
 All matrices are row-major [d_out, d_in] — a matmul computes y = W @ x.
 Q/K projections are stored pre-permuted for interleaved-pair rope
 (reference: converter/convert-hf.py:12-15).
@@ -120,6 +137,11 @@ class ArchType(enum.IntEnum):
     # a leading dense layer, every routed expert held beside a shared one; not
     # a reference arch
     GLM4_MOE_LITE = 0xABCD06
+    # state-space (Mamba-2 SSD) layers with a softmax layer at one index of
+    # every period, no rotation, a dense SwiGLU in every layer, multipliers on
+    # embedding, residual branches, attention scores and logits; not a
+    # reference arch
+    GRANITE_HYBRID = 0xABCD07
 
 
 class HiddenAct(enum.IntEnum):
@@ -183,6 +205,14 @@ class HeaderKey(enum.IntEnum):
     QK_NOPE_HEAD_DIM = 38  # values of a q/k head that are not rotated
     QK_ROPE_HEAD_DIM = 39  # values of a q head that are; the ONE key slice of that width is cached
     V_HEAD_DIM = 40
+    ATTN_OFFSET = 41  # with ATTN_PERIOD: layer l is a softmax layer where l % period == offset
+    SSM_HEADS = 42  # heads of a state-space layer
+    SSM_HEAD_DIM = 43  # values of one head
+    SSM_STATE = 44  # state values a head value keeps; width of the shared B and C
+    EMBED_SCALE_MICRO = 45  # the embedding row is multiplied by this / 1e6
+    RESIDUAL_SCALE_MICRO = 46  # a block's output is, before it is added to the stream
+    ATTN_SCALE_MICRO = 47  # the softmax scale, where it is not head_size ** -0.5
+    LOGITS_DIVISOR_MICRO = 48  # the logits are divided by this / 1e6
 
 
 class ArchFlags(enum.IntFlag):
@@ -244,10 +274,23 @@ _LATENT_KEYS = {
     HeaderKey.QK_ROPE_HEAD_DIM: "qk_rope_head_dim",
     HeaderKey.V_HEAD_DIM: "v_head_dim",
 }
+_SSM_KEYS = {
+    HeaderKey.ATTN_PERIOD: "attn_period",
+    HeaderKey.ATTN_OFFSET: "attn_offset",
+    HeaderKey.LIN_CONV: "lin_conv",
+    HeaderKey.SSM_HEADS: "ssm_heads",
+    HeaderKey.SSM_HEAD_DIM: "ssm_head_dim",
+    HeaderKey.SSM_STATE: "ssm_state",
+    HeaderKey.EMBED_SCALE_MICRO: "embed_scale_micro",
+    HeaderKey.RESIDUAL_SCALE_MICRO: "residual_scale_micro",
+    HeaderKey.ATTN_SCALE_MICRO: "attn_scale_micro",
+    HeaderKey.LOGITS_DIVISOR_MICRO: "logits_divisor_micro",
+}
 # the keys past ROPE_TYPE an arch's files carry, in the order they are written;
 # an arch that is not here writes none of them
 _ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KEYS,
-              ArchType.EVABYTE: _EVA_KEYS, ArchType.GLM4_MOE_LITE: _LATENT_KEYS}
+              ArchType.EVABYTE: _EVA_KEYS, ArchType.GLM4_MOE_LITE: _LATENT_KEYS,
+              ArchType.GRANITE_HYBRID: _SSM_KEYS}
 
 
 @dataclasses.dataclass
@@ -300,6 +343,14 @@ class ModelSpec:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    attn_offset: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    embed_scale_micro: int = 0
+    residual_scale_micro: int = 0
+    attn_scale_micro: int = 0
+    logits_divisor_micro: int = 0
 
     @property
     def head_size(self) -> int:
@@ -436,6 +487,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
                 **_WINDOW_KEYS,
                 **_EVA_KEYS,
                 **_LATENT_KEYS,
+                **_SSM_KEYS,
             }
             for i in range(0, n_ints, 2):
                 key, value = raw[i], raw[i + 1]
@@ -501,6 +553,9 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
         if spec.arch_type == ArchType.GLM4_MOE_LITE:
             _latent_layer(spec, l, add)
             continue
+        if spec.arch_type == ArchType.GRANITE_HYBRID:
+            _ssm_layer(spec, l, add)
+            continue
         add(p + "q", (dim, dim), wt)
         add(p + "k", (kv_dim, dim), wt)
         add(p + "v", (kv_dim, dim), wt)
@@ -526,22 +581,29 @@ def tensor_layout(spec: ModelSpec) -> list[TensorEntry]:
     return entries
 
 
+# the mixers whose cache is a state that is not addressed by position
+STATE_MIXERS = ("linear", "ssm")
+
+
 def layer_kind(spec, l: int) -> tuple[str, str]:
     """What layer ``l`` is (``spec``: a ModelSpec or a LlamaConfig), the ONE
     table of layer kinds: how it mixes positions (``full``: softmax attention
     over every earlier position; ``window``: over the last ``window``;
-    ``linear``: a gated delta-rule recurrence; ``eva``: exact keys inside an
-    aligned window and summaries of the windows before it; ``latent``: softmax
-    attention over every earlier position whose cache holds one latent row a
-    position and no key or value) and what its feed-forward is
-    (``dense`` or ``experts``). An arch without a period has full layers
-    only; one with experts has them in every layer past ``first_dense``."""
+    ``linear``: a gated delta-rule recurrence; ``ssm``: a state-space (SSD)
+    recurrence; ``eva``: exact keys inside an aligned window and summaries of
+    the windows before it; ``latent``: softmax attention over every earlier
+    position whose cache holds one latent row a position and no key or value)
+    and what its feed-forward is (``dense`` or ``experts``). An arch without a
+    period has full layers only; one with experts has them in every layer past
+    ``first_dense``."""
     if spec.kv_lora_rank:
         mixer = "latent"
     elif spec.eva_chunk:
         mixer = "eva"
     elif spec.attn_period:
-        mixer = "full" if l % spec.attn_period == 0 else "linear"
+        # the period's one softmax layer sits at ``attn_offset``; the others keep a state
+        mixer = ("full" if l % spec.attn_period == spec.attn_offset
+                 else "ssm" if spec.ssm_state else "linear")
     elif spec.window_period:
         mixer = "full" if l % spec.window_period == spec.window_period - 1 else "window"
     else:
@@ -551,8 +613,8 @@ def layer_kind(spec, l: int) -> tuple[str, str]:
 
 def is_softmax_layer(spec, l: int) -> bool:
     """Whether layer ``l`` mixes by softmax attention (full or window), and so
-    keeps keys and values."""
-    return layer_kind(spec, l)[0] != "linear"
+    keeps keys and values and no recurrent state."""
+    return layer_kind(spec, l)[0] not in STATE_MIXERS
 
 
 def _hybrid_layer(spec: ModelSpec, l: int, add) -> None:
@@ -647,6 +709,35 @@ def _latent_layer(spec: ModelSpec, l: int, add) -> None:
         add(p + "up", (hidden, dim), wt)
     else:
         _held_experts(spec, p, add)
+
+
+def _ssm_layer(spec: ModelSpec, l: int, add) -> None:
+    """One ``ArchType.GRANITE_HYBRID`` layer's tensors (the module docstring's
+    list): a state-space or a softmax mixer, then a dense SwiGLU."""
+    wt, f32, dim, hidden = spec.weights_float_type, FloatType.F32, spec.dim, spec.hidden_dim
+    p = f"layers.{l}."
+    if is_softmax_layer(spec, l):
+        q_dim = spec.n_heads * spec.head_size
+        add(p + "q", (q_dim, dim), wt)
+        add(p + "k", (spec.kv_dim, dim), wt)
+        add(p + "v", (spec.kv_dim, dim), wt)
+        add(p + "wo", (dim, q_dim), wt)
+    else:
+        inner = spec.ssm_heads * spec.ssm_head_dim
+        conv = inner + 2 * spec.ssm_state
+        add(p + "ssm_in", (inner + conv + spec.ssm_heads, dim), wt)
+        add(p + "conv", (conv, spec.lin_conv), f32)
+        add(p + "conv_bias", (conv,), f32)
+        add(p + "dt_bias", (spec.ssm_heads,), f32)
+        add(p + "a_log", (spec.ssm_heads,), f32)
+        add(p + "ssm_d", (spec.ssm_heads,), f32)
+        add(p + "ssm_norm", (inner,), f32)
+        add(p + "wo", (dim, inner), wt)
+    add(p + "gate", (hidden, dim), wt)
+    add(p + "down", (dim, hidden), wt)
+    add(p + "up", (hidden, dim), wt)
+    add(p + "rms_att", (dim,), f32)
+    add(p + "rms_ffn", (dim,), f32)
 
 
 def _eva_layer(spec: ModelSpec, l: int, add) -> None:

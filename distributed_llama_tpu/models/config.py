@@ -11,6 +11,7 @@ import math
 
 from distributed_llama_tpu.formats.model_file import (
     ArchFlags,
+    STATE_MIXERS,
     ArchType,
     HiddenAct,
     ModelSpec,
@@ -106,6 +107,56 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # state-space layers beside softmax ones (ArchType.GRANITE_HYBRID; 0
+    # elsewhere): layer l is a softmax layer where l % attn_period ==
+    # attn_offset, else Mamba-2's SSD recurrence over ``ssm_heads`` heads of
+    # ``ssm_head_dim`` values, each value with a state of ``ssm_state`` (ONE B
+    # and C for all heads), behind a causal convolution of ``lin_conv`` taps.
+    # The four multipliers: ``embed_scale`` on the embedding row,
+    # ``residual_scale`` on a block's output before it joins the stream,
+    # ``attn_scale`` the softmax scale where it is not head_size ** -0.5 (0:
+    # it is), ``logits_divisor`` under the logits
+    attn_offset: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0
+    logits_divisor: float = 1.0
+    # kv heads that share one row of the cache's minor axis (1: each its own):
+    # a head of 64 values fills half a lane tile, and the v5e compiler copies a
+    # whole [.., 8, 64] leaf into another layout in every decode step; two such
+    # heads side by side are a row of 128 that every scan and the row-bounded
+    # kernel read as stored. A query head then scores the pair's row with its
+    # own half and zeros in the other: the same products, and zeros
+    kv_head_pack: int = 1
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """Rows a position has in a softmax layer's cache: the kv heads, ``kv_head_pack`` to a row."""
+        return self.n_kv_heads // self.kv_head_pack
+
+    @property
+    def cache_head_size(self) -> int:
+        """Values of one such row."""
+        return self.head_size * self.kv_head_pack
+
+    @property
+    def softmax_scale(self) -> float:
+        """What a softmax layer's scores are multiplied by: ``head_size **
+        -0.5`` unless the file states another (``attn_scale``)."""
+        return self.attn_scale or self.head_size ** -0.5
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of a state-space layer's inner stream: heads x head values."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the state-space layer's convolution reads: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_state
 
     @property
     def kv_mul(self) -> int:
@@ -117,11 +168,20 @@ class LlamaConfig:
 
     @property
     def is_recurrent(self) -> bool:
-        """Whether some layer keeps a state that is not addressed by position."""
+        """Whether some layer keeps a state that is not addressed by position:
+        a period's layers other than its softmax one do (``linear`` or
+        ``ssm``, by the arch)."""
         return self.attn_period > 1
 
+    @property
+    def state_mixer(self) -> str | None:
+        """The kind of this arch's recurrent layers (``linear`` | ``ssm``), or None."""
+        if not self.is_recurrent:
+            return None
+        return "ssm" if self.ssm_state else "linear"
+
     def layer_kind(self, l: int) -> tuple[str, str]:
-        """(``full`` | ``window`` | ``linear`` | ``eva`` | ``latent``, ``dense`` | ``experts``): how
+        """(``full`` | ``window`` | ``linear`` | ``ssm`` | ``eva`` | ``latent``, ``dense`` | ``experts``): how
         layer ``l`` mixes positions and what its feed-forward is
         (``formats.model_file.layer_kind``, the one table)."""
         return layer_kind(self, l)
@@ -130,7 +190,7 @@ class LlamaConfig:
         return tuple(l for l in range(self.n_layers) if self.layer_kind(l)[0] == mixer)
 
     def is_softmax_layer(self, l: int) -> bool:
-        return self.layer_kind(l)[0] != "linear"
+        return self.layer_kind(l)[0] not in STATE_MIXERS
 
     def is_window_layer(self, l: int | None) -> bool:
         """Whether layer ``l`` is a window layer; a caller that does not say
@@ -147,9 +207,12 @@ class LlamaConfig:
         """The kinds a batched decode step's attention counts its cache reads
         by (``ops.attention.note_kv_read``; empty: it counts none): by layer
         kind where window layers stand beside full ones, by store where the
-        layers are EVA's."""
+        layers are EVA's; the few softmax layers among state-space ones as
+        ``full``."""
         if self.has_window:
             return ("full", "window")
+        if self.ssm_state:
+            return ("full",)
         if self.has_latent:
             return ("latent",)
         return ("eva_window", "eva_summary") if self.has_eva else ()
@@ -219,7 +282,10 @@ class LlamaConfig:
 
     @property
     def use_rope(self) -> bool:
-        return self.arch != ArchType.SOLAR_OPEN2 or self.has(ArchFlags.USE_ROPE)
+        return (
+            self.arch not in (ArchType.SOLAR_OPEN2, ArchType.GRANITE_HYBRID)
+            or self.has(ArchFlags.USE_ROPE)
+        )
 
     def rotates(self, l: int) -> bool:
         """Whether layer ``l`` rotates its q and k."""
@@ -255,6 +321,11 @@ def next_pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
 
 
+def _micro(value: int, default: float) -> float:
+    """A header value in millionths (0: the header does not carry it)."""
+    return value / 1e6 if value else default
+
+
 def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
     if spec.kv_lora_rank and spec.head_size != spec.qk_nope_head_dim + spec.qk_rope_head_dim:
         raise ValueError(
@@ -271,6 +342,9 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         overrides.setdefault(
             "ring_len", min(next_pow2(spec.window + RING_PIECE + RING_TAIL), spec.seq_len)
         )
+    if spec.arch_type == ArchType.GRANITE_HYBRID:
+        # heads narrower than a lane tile share one (two of 64 at the published sizes)
+        overrides.setdefault("kv_head_pack", math.gcd(spec.n_kv_heads, max(1, 128 // spec.head_size)))
     return LlamaConfig(
         arch=spec.arch_type,
         dim=spec.dim,
@@ -311,5 +385,13 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         qk_nope_head_dim=spec.qk_nope_head_dim,
         qk_rope_head_dim=spec.qk_rope_head_dim,
         v_head_dim=spec.v_head_dim,
+        attn_offset=spec.attn_offset,
+        ssm_heads=spec.ssm_heads,
+        ssm_head_dim=spec.ssm_head_dim,
+        ssm_state=spec.ssm_state,
+        embed_scale=_micro(spec.embed_scale_micro, 1.0),
+        residual_scale=_micro(spec.residual_scale_micro, 1.0),
+        attn_scale=_micro(spec.attn_scale_micro, 0.0),
+        logits_divisor=_micro(spec.logits_divisor_micro, 1.0),
         **overrides,
     )

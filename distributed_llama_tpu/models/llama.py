@@ -25,12 +25,13 @@ Numerical conventions matching the reference kernels:
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from distributed_llama_tpu.formats.model_file import HiddenAct
+from distributed_llama_tpu.formats.model_file import STATE_MIXERS, HiddenAct
 from distributed_llama_tpu.models.config import LlamaConfig
 from distributed_llama_tpu.models.rope import apply_rope
 
@@ -177,7 +178,47 @@ def project_qkvg(
         q, k = _head_norm(q, lp["q_norm"]), _head_norm(k, lp["k_norm"])
     if cfg.use_rope if layer is None else cfg.rotates(layer):
         q, k = apply_rope(q, rope_rows, cfg), apply_rope(k, rope_rows, cfg)
-    return q, k, v.reshape(T, Kl, hd), gate
+    v = v.reshape(T, Kl, hd)
+    if cfg.kv_head_pack > 1:
+        q, k, v = _share_rows(cfg, q, k, v)
+    if cfg.attn_scale or cfg.kv_head_pack > 1:
+        # every scan and einsum behind this divides its scores by the root of
+        # the head size it is given: a file that states another softmax scale
+        # (``cfg.softmax_scale``), and heads that share a row, get the quotient
+        # of the two on their q, once, for all of them
+        q = q * (cfg.softmax_scale * cfg.cache_head_size ** 0.5)
+    return q, k, v, gate
+
+
+def _share_rows(cfg: LlamaConfig, q: jax.Array, k: jax.Array, v: jax.Array):
+    """``cfg.kv_head_pack`` kv heads side by side as one cache row (q [T, H,
+    hd], k, v [T, K, hd] -> q [T, H, pack * hd], k, v [T, K / pack, pack *
+    hd]): kv head ``k`` is part ``k % pack`` of row ``k // pack``, and a query
+    head holds its values in its kv head's part and zeros in the others, so
+    its score with the row is its score with its own head. The query heads of
+    a row's kv heads are consecutive: the grouped reshape the scans use holds."""
+    T, H, hd = q.shape
+    K, pack = k.shape[1], cfg.kv_head_pack
+    mine = jax.nn.one_hot(_row_part(H, K, pack), pack, dtype=q.dtype)  # [H, pack]
+    q = (q[:, :, None, :] * mine[None, :, :, None]).reshape(T, H, pack * hd)
+    return q, k.reshape(T, K // pack, pack * hd), v.reshape(T, K // pack, pack * hd)
+
+
+def _row_part(H: int, K: int, pack: int) -> jax.Array:
+    """[H]: which part of its cache row a query head reads: its kv head's."""
+    return (jnp.arange(H) // (H // K)) % pack
+
+
+def _own_part(cfg: LlamaConfig, att: jax.Array, H: int) -> jax.Array:
+    """The attention mix over shared rows [T, H * pack * hd] -> [T, H * hd]:
+    of the row's weighted sum, a query head keeps its own kv head's part."""
+    pack = cfg.kv_head_pack
+    if pack == 1:
+        return att
+    T, hd = att.shape[0], cfg.head_size
+    part = _row_part(H, cfg.n_kv_heads, pack)
+    parts = att.reshape(T, H, pack, hd)
+    return jnp.take_along_axis(parts, part[None, :, None, None], axis=2).reshape(T, H * hd)
 
 
 def _gated(att: jax.Array, gate: jax.Array | None) -> jax.Array:
@@ -222,14 +263,20 @@ def block_tail(
         # add (reference: src/grok1-tasks.cpp:16-41)
         x = x + rmsnorm(out.astype(x.dtype), lp["rms_ffn"])
     else:
-        x = x + out.astype(x.dtype)
+        x = x + _branch(cfg, out).astype(x.dtype)
     if cfg.is_moe and "router" in lp:  # a leading dense layer of an expert arch has none
         from distributed_llama_tpu.models import moe
 
         x = moe.moe_block(cfg, x, lp, axis_name, ep_axis=ep_axis, n_real=n_real)
     else:
-        x = x + ffn(cfg, x, lp, axis_name).astype(x.dtype)
+        x = x + _branch(cfg, ffn(cfg, x, lp, axis_name)).astype(x.dtype)
     return x
+
+
+def _branch(cfg: LlamaConfig, out: jax.Array) -> jax.Array:
+    """A block's output as it joins the residual stream: times
+    ``cfg.residual_scale`` where the file states one."""
+    return out if cfg.residual_scale == 1.0 else out * cfg.residual_scale
 
 
 def final_logits(cfg: LlamaConfig, params: Params, x: jax.Array) -> jax.Array:
@@ -240,6 +287,8 @@ def final_logits(cfg: LlamaConfig, params: Params, x: jax.Array) -> jax.Array:
     logits = _norm_matmul(x, params["rms_final"], params["wcls"], "logits")
     if cfg.arch.name == "GROK1":
         logits = logits * 0.5773502691896257
+    if cfg.logits_divisor != 1.0:
+        logits = logits / cfg.logits_divisor
     return logits
 
 
@@ -248,6 +297,8 @@ def embed(cfg: LlamaConfig, params: Params, tokens: jax.Array) -> jax.Array:
     x = params["embedding"][tokens].astype(jnp.float32)
     if cfg.arch.name == "GROK1":
         x = x * 78.38367176906169
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     return x
 
 
@@ -295,7 +346,7 @@ def attention(
 
     T = x.shape[0]
     S = cache_l[0].shape[0]  # works for tuple (keys, values) and stacked [2, S, ...] forms
-    hd = cfg.head_size
+    hd = cfg.cache_head_size  # of a cache row: the head's, or of the heads that share it
     q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows, layer)
     Hl, Kl = q.shape[1], k.shape[1]
 
@@ -373,7 +424,7 @@ def attention(
         att = blocked_attention(
             qg.astype(jnp.float32), keys, values, pos, ATT_CHUNK, paged=paged
         ).astype(jnp.float32).reshape(T, Hl * hd)
-        return _gated(att, gate), new_cache
+        return _gated(_own_part(cfg, att, Hl), gate), new_cache
     scores = kvc.scores_einsum(qg, keys, prec) / jnp.sqrt(jnp.float32(hd))
     # causal mask: query t (absolute pos+t) sees cache slots 0..pos+t
     t_idx = pos + jnp.arange(T)[:, None]
@@ -382,7 +433,7 @@ def attention(
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     att = kvc.mix_einsum(weights, values, cdt, prec).reshape(T, Hl * hd)
-    return _gated(att, gate), new_cache
+    return _gated(_own_part(cfg, att, Hl), gate), new_cache
 
 
 def _per_head(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -556,7 +607,8 @@ def refuse_recurrent(cfg: LlamaConfig, what: str) -> None:
     of every position."""
     if cfg.is_recurrent:
         raise RecurrentStateError(
-            f"{what} is not supported for arch {cfg.arch.name}: its linear-attention "
+            f"{what} is not supported for arch {cfg.arch.name}: its "
+            f"{'state-space' if cfg.state_mixer == 'ssm' else 'linear-attention'} "
             "layers keep a recurrent state that cannot be rewound or moved by position"
         )
     if cfg.has_window:
@@ -659,6 +711,79 @@ def linear_attention_batched(
     return _linear_output(cfg, lp, o, gate), {"S": S, "conv": tail}
 
 
+def _ssm_inputs(cfg: LlamaConfig, lp: Params, x: jax.Array):
+    """Norm + the state-space layer's ONE input projection for T tokens
+    (``ssm_in``, rows in the published order z | xBC | dt): (gate z [T,
+    inner], x|B|C before the convolution [T, inner + 2N], dt before its bias
+    and softplus [T, Hs])."""
+    inner, conv = cfg.ssm_inner, cfg.ssm_conv_dim
+    fused = _norm_matmul(x, lp["rms_att"], lp["ssm_in"], "lin_in")
+    return (fused[:, :inner], fused[:, inner : inner + conv],
+            fused[:, inner + conv : inner + conv + cfg.ssm_heads])
+
+
+def _ssm_heads(cfg: LlamaConfig, lp: Params, xbc: jax.Array, dt: jax.Array):
+    """Convolved x|B|C [T, inner + 2N] (bias and SiLU applied here) and dt's
+    pre-activation [T, Hs] -> x [T, Hs, P], B, C [T, N], dt [T, Hs] > 0 and
+    the heads' rates ``a`` [Hs] < 0 (a step decays a head's state by
+    ``exp(dt * a)``)."""
+    T, inner, N = xbc.shape[0], cfg.ssm_inner, cfg.ssm_state
+    xbc = jax.nn.silu(xbc + lp["conv_bias"])
+    x = xbc[:, :inner].reshape(T, cfg.ssm_heads, cfg.ssm_head_dim)
+    return (x, xbc[:, inner : inner + N], xbc[:, inner + N :],
+            jax.nn.softplus(dt + lp["dt_bias"]), -jnp.exp(lp["a_log"]))
+
+
+def _ssm_output(cfg: LlamaConfig, lp: Params, y: jax.Array, x: jax.Array, z: jax.Array):
+    """The recurrence's output [T, Hs, P] plus the skip ``D x``, gated by
+    ``silu(z)`` and THEN RMS-normalised over the whole inner width (one group)
+    with a learned weight -> [T, inner] (``wo`` follows in :func:`block_tail`)."""
+    y = (y + lp["ssm_d"][None, :, None] * x).reshape(z.shape)
+    return rmsnorm(y * jax.nn.silu(z), lp["ssm_norm"])
+
+
+def ssm_mixer(
+    cfg: LlamaConfig, x: jax.Array, lp: Params, cache_l: dict, pos: jax.Array,
+    n_real: jax.Array | None = None,
+) -> tuple[jax.Array, dict]:
+    """The state-space (SSD) mixer for T new tokens of ONE row whose first
+    token sits at ``pos``. ``cache_l``: ``{"S": the heads' states in the
+    layout of ``ops.ssd.state_shape``, f32, "conv": [taps-1, inner + 2N]
+    f32}``, as the tokens before ``pos`` left them; a row that starts over
+    (``pos == 0``) starts from zeros whatever the leaf holds. Tokens at and
+    past ``n_real`` (bucket padding) leave state and tail untouched. Returns
+    (mix [T, inner], the leaf after the last real token)."""
+    from distributed_llama_tpu.ops import kda, ssd
+
+    fresh = pos == 0
+    S0 = jnp.where(fresh, 0.0, cache_l["S"])
+    tail = jnp.where(fresh, 0.0, cache_l["conv"])
+    z, xbc, dt = _ssm_inputs(cfg, lp, x)
+    xbc, tail = kda.causal_conv(xbc, tail, lp["conv"], n_real)
+    xs, Bm, Cm, dt, a = _ssm_heads(cfg, lp, xbc, dt)
+    y, S = ssd.ssd_chunk(S0, xs, Bm, Cm, dt, a, n_real)
+    return _ssm_output(cfg, lp, y, xs, z), {"S": S, "conv": tail}
+
+
+def ssm_mixer_batched(
+    cfg: LlamaConfig, x: jax.Array, lp: Params, cache_l: dict, active: jax.Array,
+) -> tuple[jax.Array, dict]:
+    """One decode step of B independent rows through the state-space mixer:
+    ``cache_l`` holds ``[b_max, ...]`` leaves of which the first B rows step;
+    rows where ``active`` is False keep state and tail."""
+    from distributed_llama_tpu.ops import kda, ssd
+
+    B = x.shape[0]
+    tail_all = cache_l["conv"]
+    z, xbc, dt = _ssm_inputs(cfg, lp, x)
+    xbc, tail = kda.causal_conv_step(xbc, tail_all[:B], lp["conv"], active)
+    xs, Bm, Cm, dt, a = _ssm_heads(cfg, lp, xbc, dt)
+    y, S = ssd.ssd_step(cache_l["S"], xs, Bm, Cm, dt, a, active)
+    if tail_all.shape[0] != B:
+        tail = jax.lax.dynamic_update_slice_in_dim(tail_all, tail, 0, axis=0)
+    return _ssm_output(cfg, lp, y, xs, z), {"S": S, "conv": tail}
+
+
 def ffn(cfg: LlamaConfig, x: jax.Array, lp: Params, axis_name: str | None) -> jax.Array:
     """SwiGLU FFN (reference: src/llama2-tasks.cpp:158-212)."""
     if "gate_up" in lp:
@@ -700,6 +825,8 @@ def block_forward(
     mixer = "full" if layer is None else cfg.layer_kind(layer)[0]
     if mixer == "linear":
         att, new_cache = linear_attention(cfg, x, lp, cache_l, pos, n_real)
+    elif mixer == "ssm":
+        att, new_cache = ssm_mixer(cfg, x, lp, cache_l, pos, n_real)
     elif mixer == "latent":
         att, new_cache = latent_attention(cfg, x, lp, cache_l, pos, rope_rows)
     else:
@@ -843,7 +970,7 @@ def attention_batched(
 
     B = x.shape[0]
     S, cdt, prec = kvc.slab_facts(cache_l)
-    hd = cfg.head_size
+    hd = cfg.cache_head_size
     q, k, v, gate = project_qkvg(cfg, lp, x, rope_rows, layer)  # [B, Hl, hd], [B, Kl, hd] x2
     Hl, Kl = q.shape[1], k.shape[1]
 
@@ -898,7 +1025,7 @@ def attention_batched(
         att = batched_decode_attention(
             qg.astype(jnp.float32), new_cache, read_pos, ATT_CHUNK, paged=paged
         ).astype(jnp.float32)
-        return _gated(att.reshape(B, Hl * hd), gate), new_cache
+        return _gated(_own_part(cfg, att.reshape(B, Hl * hd), Hl), gate), new_cache
     # small/odd caches read all of S anyway: the halves may form here.
     # A dispatch bucket below B_max reads only its own slab rows
     keys, values = new_cache[0], new_cache[1]
@@ -918,7 +1045,7 @@ def attention_batched(
             att = batched_decode_attention(
                 qg.astype(jnp.float32), (keys_b, values_b), read_pos, ATT_CHUNK
             ).astype(jnp.float32)
-            return _gated(att.reshape(B, Hl * hd), gate), new_cache
+            return _gated(_own_part(cfg, att.reshape(B, Hl * hd), Hl), gate), new_cache
     from distributed_llama_tpu.ops.attention import note_kv_read
 
     note_kv_read("full", B, S)  # a small or odd cache is read whole
@@ -927,7 +1054,7 @@ def attention_batched(
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     att = kvc.mix_einsum_batched(weights, values_b, cdt, prec).reshape(B, Hl * hd)
-    return _gated(att, gate), new_cache
+    return _gated(_own_part(cfg, att, Hl), gate), new_cache
 
 
 def forward_step_batched(
@@ -987,6 +1114,8 @@ def _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, pa
             paged_l = (pool[l][0], pool[l][1], tables, matched)
         if mixer == "linear":
             att, nc = linear_attention_batched(cfg, x, lp, cache[l], active)
+        elif mixer == "ssm":
+            att, nc = ssm_mixer_batched(cfg, x, lp, cache[l], active)
         elif mixer == "latent":
             att, nc = latent_attention_batched(cfg, x, lp, cache[l], pos, rope_rows, active)
         else:
@@ -1143,7 +1272,7 @@ def init_batch_cache(
     backend keeps its own sharded (keys, values)-tuple slab."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
-    kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
+    kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.cache_kv_heads
     return [_init_layer_leaf(cfg, l, (b_max,), kl, dtype) for l in range(cfg.n_layers)]
 
 
@@ -1156,7 +1285,7 @@ def _init_layer_leaf(cfg: LlamaConfig, l: int, lead: tuple[int, ...], kl: int, d
     from distributed_llama_tpu.ops import kv_cache as kvc
 
     mixer = cfg.layer_kind(l)[0]
-    if mixer == "linear":
+    if mixer in STATE_MIXERS:
         return init_state_leaf(cfg, lead)
     if mixer == "latent":
         if kvc.is_quantized_cache_dtype(dtype):
@@ -1165,7 +1294,7 @@ def _init_layer_leaf(cfg: LlamaConfig, l: int, lead: tuple[int, ...], kl: int, d
     if mixer == "eva" and kvc.is_quantized_cache_dtype(dtype):
         raise ValueError("an EVA layer's summaries have no i8 form: serve it with a plain KV dtype")
     slots = {"window": cfg.ring_len, "eva": cfg.eva_slots}.get(mixer, cfg.seq_len)
-    return kvc.init_fused(lead + (slots, kl, cfg.head_size), dtype)
+    return kvc.init_fused(lead + (slots, kl, cfg.cache_head_size), dtype)
 
 
 def kv_slab_bytes(cfg: LlamaConfig, rows: int, dtype) -> dict[str, int]:
@@ -1187,21 +1316,36 @@ def kv_slab_bytes(cfg: LlamaConfig, rows: int, dtype) -> dict[str, int]:
 
 
 def init_state_leaf(cfg: LlamaConfig, lead: tuple[int, ...] = ()) -> dict:
-    """A linear layer's cache: the recurrent state ``S`` [*lead, Hl, dl, dl]
-    and the convolution's last inputs ``conv`` [*lead, taps-1, 3*L], both
-    f32 whatever the K/V dtype. It does not grow with the row's length."""
-    Hl, dl = cfg.lin_heads, cfg.lin_head_dim
+    """A recurrent layer's cache (``cfg.state_mixer``): the state ``S`` and
+    the convolution's last inputs ``conv`` [*lead, taps-1, channels], both f32
+    whatever the K/V dtype. It does not grow with the row's length. A linear
+    layer: ``S`` [*lead, Hl, dl, dl], channels 3*L; a state-space one: ``S``
+    in the layout of ``ops.ssd.state_shape`` (Hs * P * N values), channels
+    inner + 2N."""
+    S, channels = _state_shape(cfg)
     return {
-        "S": jnp.zeros(lead + (Hl, dl, dl), jnp.float32),
-        "conv": jnp.zeros(lead + (cfg.lin_conv - 1, 3 * Hl * dl), jnp.float32),
+        "S": jnp.zeros(lead + S, jnp.float32),
+        "conv": jnp.zeros(lead + (cfg.lin_conv - 1, channels), jnp.float32),
     }
+
+
+def _state_shape(cfg: LlamaConfig) -> tuple[tuple[int, ...], int]:
+    """(a row's state shape in one recurrent layer, its convolution's channels)."""
+    if cfg.state_mixer == "ssm":
+        from distributed_llama_tpu.ops import ssd
+
+        return ssd.state_shape(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), cfg.ssm_conv_dim
+    Hl, dl = cfg.lin_heads, cfg.lin_head_dim
+    return (Hl, dl, dl), 3 * Hl * dl
 
 
 def recurrent_state_bytes(cfg: LlamaConfig, rows: int) -> int:
     """Bytes of recurrent state and convolution tails ``rows`` rows hold."""
-    Hl, dl = cfg.lin_heads, cfg.lin_head_dim
-    per_layer = 4 * (Hl * dl * dl + (cfg.lin_conv - 1) * 3 * Hl * dl)
-    return rows * per_layer * len(cfg.layers_of("linear"))
+    if not cfg.is_recurrent:
+        return 0
+    S, channels = _state_shape(cfg)
+    per_layer = 4 * (math.prod(S) + (cfg.lin_conv - 1) * channels)
+    return rows * per_layer * len(cfg.layers_of(cfg.state_mixer))
 
 
 def init_page_pool(
@@ -1221,7 +1365,7 @@ def init_page_pool(
     the serving surface."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
-    kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
+    kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.cache_kv_heads
     # the pool holds the FULL layers' pages: a linear layer has no keys and
     # values, a window layer's are in its own small pool
     # (:func:`init_window_pool`); their entries are None. An EVA layer's page
@@ -1244,8 +1388,8 @@ def _init_pool(cfg: LlamaConfig, mixer: str, n_pages: int, page: int, kl: int, d
 
     return [
         (
-            kvc.init_page_pool_half(n_pages, page, kl, cfg.head_size, dtype),
-            kvc.init_page_pool_half(n_pages, page, kl, cfg.head_size, dtype),
+            kvc.init_page_pool_half(n_pages, page, kl, cfg.cache_head_size, dtype),
+            kvc.init_page_pool_half(n_pages, page, kl, cfg.cache_head_size, dtype),
         )
         if cfg.layer_kind(l)[0] == mixer else None
         for l in range(cfg.n_layers)
@@ -1306,8 +1450,8 @@ def init_cache(
     backends build their own sharded ``(keys, values)``-tuple caches."""
     from distributed_llama_tpu.ops import kv_cache as kvc
 
-    kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.n_kv_heads
-    shape = (cfg.seq_len, kl, cfg.head_size)
+    kl = n_kv_heads_local if n_kv_heads_local is not None else cfg.cache_kv_heads
+    shape = (cfg.seq_len, kl, cfg.cache_head_size)
     if kvc.is_quantized_cache_dtype(dtype) and not layered:
         raise ValueError("the i8 KV cache requires the layered cache layout")
     if layered:

@@ -346,8 +346,23 @@ class EngineInstruments:
         self.recurrent_state_bytes = gauge(
             "dllama_recurrent_state_bytes",
             "Bytes of recurrent state and convolution tails the slab's rows "
-            "hold (linear-attention layers; does not grow with a row's length)",
+            "hold (linear-attention or state-space layers; does not grow with "
+            "a row's length)",
         )
+        state_tokens = counter(
+            "dllama_state_layer_tokens_total",
+            "Tokens the recurrent layers advanced a row's state by, summed "
+            "over the layers of that kind (mixer: linear = the gated delta "
+            "rule, kernels kda_step / kda_chunk; ssm = the state-space "
+            "recurrence, kernels ssd_step / ssd_chunk): phase=decode the "
+            "row-steps of the decode chunks delivered (joined rows x steps), "
+            "phase=prefill the real tokens of the prompt pieces dispatched",
+            labelnames=("mixer", "phase"),
+        )
+        self.state_layer_tokens = {
+            (mixer, phase): state_tokens.labels(mixer=mixer, phase=phase)
+            for mixer in ("linear", "ssm") for phase in ("decode", "prefill")
+        }
         kv_read = counter(
             "dllama_attn_kv_read_bytes_total",
             "Bytes of keys and values the decode chunks' attention read out of "

@@ -290,12 +290,18 @@ def _served_program_shapes(one_chip):
     return cfg, params, slab, pool, s
 
 
-def _slab_sized_results(hlo: str, half_elements: int):
+def _slab_sized_results(hlo: str, half_elements: int, dtype: str = "bf16"):
     """Read a compiled program's text: ``(in_place_writes, others)``, the
-    instructions outside fusions whose result holds a bf16 array of at least
-    half a slab leaf. A write is a dynamic-update-slice or scatter into the
-    leaf (alone or as a fusion's body): it updates its operand's buffer.
-    ``others`` are buffers of their own, each one pass over the slab."""
+    instructions outside fusions whose result holds a ``dtype`` array of at
+    least half a slab leaf. A write is a dynamic-update-slice or scatter into
+    the leaf (alone or as a fusion's body), or a kernel whose result aliases
+    its operand: it updates its operand's buffer. ``others`` are buffers of
+    their own in the chip's main memory, each one pass over the slab. Not
+    among them: what the compiler's memory-space assignment stages in the
+    chip's VMEM (``S(1)`` in a result's layout; a v5e has 128 MiB of it, and a
+    leaf of 64 MiB may be prefetched there ahead of its kernel and written
+    back behind it: the same one read and one write of main memory, moved in
+    time), and the ``copy-done`` that writes such a result back."""
     computations, name = {}, None
     for line in hlo.splitlines():
         m = None if line.startswith(" ") else _COMPUTATION.match(line)
@@ -308,11 +314,13 @@ def _slab_sized_results(hlo: str, half_elements: int):
     def big(result_type: str) -> bool:
         return any(
             np.prod([int(d) for d in dims.split(",")]) >= half_elements
-            for dims in re.findall(r"bf16\[([\d,]+)\]", result_type)
+            for dims in re.findall(dtype + r"\[([\d,]+)\]", result_type)
         )
 
     def writes_in_place(op: str, line: str) -> bool:
         if op in ("dynamic-update-slice", "scatter"):
+            return True
+        if op == "custom-call" and "output_to_operand_aliasing" in line:
             return True
         called = re.search(r"calls=%?([\w.\-]+)", line)
         return op == "fusion" and called is not None and any(
@@ -325,13 +333,30 @@ def _slab_sized_results(hlo: str, half_elements: int):
         m.group(1) for lines in computations.values() for line in lines
         if " fusion(" in line and (m := re.search(r"calls=%?([\w.\-]+)", line))
     }
+    def in_vmem(result_type: str) -> bool:
+        """Whether every leaf-sized array of the result lives in VMEM."""
+        layouts = [
+            layout for dims, layout in re.findall(dtype + r"\[([\d,]+)\](\{[^}]*\})?", result_type)
+            if np.prod([int(d) for d in dims.split(",")]) >= half_elements
+        ]
+        return bool(layouts) and all("S(1)" in layout for layout in layouts)
+
+    # an asynchronous move's start holds its operand and its destination, no buffer of its own;
+    # one that names VMEM on either side is the staging above, and so is its done
+    moves = {
+        m.group(1): "S(1)" in m.group(2) for lines in computations.values() for line in lines
+        if (m := _INSTRUCTION.match(line)) and m.group(3) in ("copy-start", "slice-start")
+    }
     writes, others = [], []
     for comp, lines in computations.items():
         if comp in fused:
             continue
         for line in lines:
             m = _INSTRUCTION.match(line)
-            if not m or m.group(3) in _NO_BUFFER_OPS or not big(m.group(2)):
+            if not m or m.group(3) in _NO_BUFFER_OPS or m.group(1) in moves or not big(m.group(2)):
+                continue
+            done = re.search(r"(?:copy|slice)-done\(%?([\w.\-]+)", line)
+            if in_vmem(m.group(2)) or (done and moves.get(done.group(1))):
                 continue
             found = f"{comp}: %{m.group(1)} = {m.group(2)[:120]} {m.group(3)}"
             (writes if writes_in_place(m.group(3), line) else others).append(found)
@@ -506,6 +531,101 @@ def test_served_latent_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeyp
     writes, others = _slab_sized_results(compiled.as_text(), slab[0]["latent"].size // 2)
     assert len(writes) == rows * layers, writes
     assert not others, "slab-sized buffers besides the cache writes:\n" + "\n".join(others)
+
+
+def _granite_program_shapes(one_chip, periods: int, rows: int):
+    """(cfg, params, slab, pool, s) of ``granite-4.0-h-micro.batch_prompted``
+    as shapes on the described chip: Granite-4.0-H-Micro's published widths,
+    ``periods`` periods of ten layers (state-space but for index 5), ``rows``
+    rows of 2048 positions, bf16 keys and values, float32 state."""
+    layers = 10 * periods
+    cfg = LlamaConfig(
+        arch=ArchType.GRANITE_HYBRID, dim=2048, hidden_dim=8192, n_layers=layers, n_heads=32,
+        n_kv_heads=8, vocab_size=100352, seq_len=2048, head_size=64, kv_dim=512, attn_period=10,
+        attn_offset=5, lin_conv=4, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+        embed_scale=12.0, residual_scale=0.22, attn_scale=0.015625, logits_divisor=8.0,
+        kv_head_pack=2,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    f32 = lambda *shape: s(shape, jnp.float32)
+    dense = dict(gate_up=_qm_shape(2048, 16384, one_chip), down=_qm_shape(8192, 2048, one_chip),
+                 rms_att=f32(2048), rms_ffn=f32(2048))
+    ssm = dict(ssm_in=_qm_shape(2048, 8512, one_chip), conv=f32(4352, 4), conv_bias=f32(4352),
+               dt_bias=f32(64), a_log=f32(64), ssm_d=f32(64), ssm_norm=f32(4096),
+               wo=_qm_shape(4096, 2048, one_chip), **dense)
+    softmax = dict(qkv=_qm_shape(2048, 3072, one_chip), wo=_qm_shape(2048, 2048, one_chip), **dense)
+    params = dict(
+        embedding=f32(100352, 2048),
+        layers=[softmax if cfg.layer_kind(l)[0] == "full" else ssm for l in range(layers)],
+        rms_final=f32(2048), rope_table=f32(2048, 32, 2), wcls=_qm_shape(2048, 100352, one_chip),
+    )
+    placed = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    slab = placed(jax.eval_shape(lambda: llama.init_batch_cache(cfg, rows, dtype=jnp.bfloat16)))
+    pool = placed(jax.eval_shape(
+        lambda: llama.init_page_pool(cfg, SERVED_PAGES, SERVED_PAGE, dtype=jnp.bfloat16)))
+    return cfg, params, slab, pool, s
+
+
+@pytest.mark.parametrize("kernel,tokens", [("ssd_step", 32), ("ssd_chunk", 256), ("ssd_chunk", 8)])
+def test_the_state_space_kernels_compile(one_chip, monkeypatch, kernel, tokens):
+    """The decode step over 32 rows of a 32-row slab (the state aliased in
+    place) and one row's prefill piece, at 64 heads of 64 with 128 state
+    values: two heads a row of lanes, ``[32, 128, 128]`` a row's state."""
+    from distributed_llama_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "_interpret_default", lambda: False)
+    H, P, N = 64, 64, 128
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    state = ssd.state_shape(H, P, N)
+    assert state == (32, 128, 128)
+    tokenwise = (s(tokens, H, P), s(tokens, N), s(tokens, N), s(tokens, H), s(H))
+    if kernel == "ssd_step":
+        active = jax.ShapeDtypeStruct((tokens,), jnp.bool_, sharding=one_chip)
+        lowered = jax.jit(ssd.ssd_step, donate_argnums=(0,)).lower(s(tokens, *state), *tokenwise, active)
+    else:
+        lowered = jax.jit(ssd.ssd_chunk).lower(
+            s(*state), *tokenwise, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    text = lowered.compile().as_text()
+    assert f"%{kernel}" in text and "tpu_custom_call" in text
+
+
+def test_served_state_space_decode_chunk_forms_nothing_of_a_leafs_size(one_chip, monkeypatch):
+    """The decode chunk of ``granite-4.0-h-micro.batch_prompted`` (one period
+    of ten layers, 32 rows): a step updates each state-space layer's state in
+    place through ``ssd_step`` (16.8 M float32 values a layer: copied once,
+    it would be as many bytes again as the step reads) and writes the softmax
+    layer's keys and values in place. Its kv heads of 64 share cache rows of
+    128 two by two (``cfg.kv_head_pack``): stored [.., 8, 64] the same program
+    copied the whole leaf into a positions-minor layout and back in EVERY
+    step (the compiler's own choice for a minor axis of half a lane tile), as
+    GLM's [.., positions, 576] leaf was; stored [.., 4, 128] the row-bounded
+    kernel reads it as it lies. Nothing else of either leaf's size forms,
+    the convolution tails (13056 values a row) are far below it."""
+    from distributed_llama_tpu.ops import ssd
+
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    monkeypatch.setattr(ssd, "_interpret_default", lambda: False)
+    monkeypatch.setattr(decode_attention, "_interpret_default", lambda: False)
+    rows = 32
+    cfg, params, slab, _, s = _granite_program_shapes(one_chip, 1, rows)
+    assert slab[0]["S"].shape == (rows, 32, 128, 128) and slab[5].shape == (2, rows, 2048, 4, 128)
+    compiled = sampling.decode_chunk_batched.lower(
+        cfg, params, s((rows,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+        s((rows,), jnp.uint32)).compile()
+    text = compiled.as_text()
+    writes, others = _slab_sized_results(text, slab[0]["S"].size // 2, "f32")
+    # nine steps, each aliasing its state; the compiler may stage one of them through VMEM
+    steps = set(re.findall(r"%(ssd_step[\w.]*) = [^\n]*output_to_operand_aliasing", text))
+    assert len(steps) == 9 and 7 <= len(writes) <= 9 and all("%ssd_step" in w for w in writes), writes
+    assert not others, "state-sized buffers besides the in-place step:\n" + "\n".join(others)
+    writes, others = _slab_sized_results(text, slab[5].size // 2)
+    assert len(writes) == 1, writes
+    assert not others, "slab-sized buffers besides the cache write:\n" + "\n".join(others)
+    assert "ssd_step" in text and "slab_decode_scan" in text
 
 
 def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
