@@ -2147,17 +2147,21 @@ class BatchScheduler:
         ``active``) plus positions / sampling params / folded seeds, inert
         defaults in non-live slots (bucket padding, or rows retired
         mid-build), and the read-alias arrays when the pool is on
-        (None otherwise). All of them host numpy buffers: they cross to
-        the device with the dispatch, as whole vectors, and building them
-        issues no device operation whatever the bucket. One definition so
-        a lifecycle change to what counts as a live row can never reach
-        one dispatch path and skip the other."""
+        (None otherwise). A slot that is not live has temperature 0.0: its
+        token is never read and never fed to a live row, and the program
+        skips the sampler's softmax and top-k in a step in which no row
+        samples (``sampling.fused_sample_batched``), so a padding row must
+        not be the one that asks for a sample. All of them host numpy
+        buffers: they cross to the device with the dispatch, as whole
+        vectors, and building them issues no device operation whatever the
+        bucket. One definition so a lifecycle change to what counts as a
+        live row can never reach one dispatch path and skip the other."""
         n = len(rows)
         live = np.fromiter(
             (s._joined and s._fetch_error is None for s in rows), bool, n
         )
         pos = np.zeros(n, np.int32)
-        temps = np.ones(n, np.float32)
+        temps = np.zeros(n, np.float32)
         topps = np.full(n, 0.9, np.float32)
         topks = np.zeros(n, np.int32)
         seeds = np.zeros(n, np.uint32)
@@ -2642,6 +2646,11 @@ class BatchScheduler:
                 s.pos += self.chunk
         self._decode_built.add(bucket)
         self._note_dispatched(bucket, joined, self.chunk)
+        # from the vector the program was handed: the arm its steps took
+        if np.any(temps != 0.0):
+            engine._tel.chunk_sampler_sampled.inc()
+        else:
+            engine._tel.chunk_sampler_greedy.inc()
         self._pending = (
             "chunk", out, [(s, s._epoch) for s in joined], bucket,
             len(joined), sw, None, t_build, time.monotonic(),

@@ -317,14 +317,33 @@ def fused_sample_batched(
     """Fused temperature/top-k/top-p sampling with the counter PRNG:
     one coin per row keyed ``(seed, pos, draw)``, greedy rows
     (``temperature == 0``) take the exact raw-logits argmax — bit-identical
-    to a pure-greedy dispatch, coins never consumed."""
+    to a pure-greedy dispatch, coins never consumed.
+
+    The softmax, the coin and the pick sit in the true arm of ONE
+    ``lax.cond`` on "some row samples": a step whose rows all take the
+    argmax runs none of them (the top-k over the vocabulary was 19 % of a
+    32-row decode step at 100352 logits: PERF.md §6, PR 46). A greedy row
+    beside a sampling one still takes ``greedy`` through the arm's
+    ``where``, so every row's token is the same in every mix. ``cand``, a
+    function of no arguments, gives :func:`fused_pick` its candidate ids
+    and is called inside the arm (the tp composition, a collective: every
+    shard holds the same temperatures and takes the same arm)."""
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    probs = jax.nn.softmax(scaled, axis=-1)
-    coin = prng.device_coin(seeds, pos, draw)
-    tok = fused_pick(probs, scaled, coin, topp, topk, cand=cand)
-    return jnp.where(temperature == 0.0, greedy, tok.astype(jnp.int32))
+
+    def sampled(_):
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        probs = jax.nn.softmax(scaled, axis=-1)
+        coin = prng.device_coin(seeds, pos, draw)
+        tok = fused_pick(
+            probs, scaled, coin, topp, topk,
+            cand=None if cand is None else cand(),
+        )
+        return jnp.where(temperature == 0.0, greedy, tok.astype(jnp.int32))
+
+    return jax.lax.cond(
+        jnp.any(temperature != 0.0), sampled, lambda _: greedy, None
+    )
 
 
 def sample_token(
@@ -489,8 +508,9 @@ def batched_decode_scan(
     donation).
 
     Under a vocab-sharded tp head the candidate top-k is composed over the
-    shards (:func:`sharded_topk_indices`) before the logits all-gather
-    that the fingerprint fold needs.
+    shards (:func:`sharded_topk_indices`), inside the sampler's arm; the
+    logits all-gather that the argmax and the fingerprint fold need runs
+    every step.
 
     ``fingerprint`` folds each step's per-row logit argmax + token into an
     FNV-1a hash and a finiteness flag ON DEVICE (engine/integrity.py —
@@ -521,10 +541,12 @@ def batched_decode_scan(
         cand = None
         if axis_name is not None and logits.shape[-1] != cfg.vocab_size:
             # the tp top-k composition: candidates reduce over the sharded
-            # vocab BEFORE the full gather (selection by raw logits —
-            # temperature scaling is order-preserving)
-            cand = sharded_topk_indices(
-                logits, axis_name, min(TOPP_FAST_K, cfg.vocab_size)
+            # vocab, not the gathered one (selection by raw logits —
+            # temperature scaling is order-preserving), and only in a step
+            # where some row samples: the sampler calls it inside its arm
+            cand = functools.partial(
+                sharded_topk_indices, logits, axis_name,
+                min(TOPP_FAST_K, cfg.vocab_size),
             )
             logits = jax.lax.all_gather(logits, axis_name, axis=1, tiled=True)
         nxt = fused_sample_batched(

@@ -291,6 +291,17 @@ class EngineInstruments:
         )
         self.chunk_rows_active = chunk_rows.labels(kind="active")
         self.chunk_rows_bucket = chunk_rows.labels(kind="bucket")
+        chunk_sampler = counter(
+            "dllama_decode_chunk_sampler_total",
+            "Dispatched batched decode chunks by the arm their steps' sampler "
+            "took, read from the temperature vector the program was handed: "
+            "greedy (every row's temperature is 0: argmax alone, the softmax, "
+            "the coin and the top-k are skipped) or sampled (some row "
+            "samples: the whole sampler runs for every row)",
+            labelnames=("path",),
+        )
+        self.chunk_sampler_greedy = chunk_sampler.labels(path="greedy")
+        self.chunk_sampler_sampled = chunk_sampler.labels(path="sampled")
         self.chunk_host = histogram(
             "dllama_chunk_host_seconds",
             "Host time of one batched decode chunk NOT spent waiting on "

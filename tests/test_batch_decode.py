@@ -646,6 +646,85 @@ def test_a_dispatch_issues_the_same_device_work_at_any_row_count(tmp_path, joine
     assert (programs, transfers) == (1, 6)
 
 
+@pytest.fixture
+def counted(monkeypatch):
+    """Telemetry on, and a function that binds an engine's instruments while
+    it is: ``chunks()`` then reads the decode chunks counted by the arm
+    their sampler took."""
+    from distributed_llama_tpu import telemetry
+
+    telemetry.reset()
+    telemetry.enable()
+
+    def bind(engine):
+        monkeypatch.setattr(engine, "_tel", telemetry.EngineInstruments())
+        return engine
+
+    def chunks() -> dict:
+        c = telemetry.REGISTRY.get("dllama_decode_chunk_sampler_total")
+        return {p: c.labels(path=p).value for p in ("greedy", "sampled")}
+
+    yield bind, chunks
+    telemetry.disable()
+    telemetry.reset()
+
+
+def handed_temperatures(sched) -> list:
+    """Record the temperature vector of every decode chunk the scheduler
+    hands its program from here on."""
+    seen = []
+    real = sched._decode_chunk_program
+    sched._decode_chunk_program = lambda active, pos, temps, *a: (
+        seen.append((active.copy(), temps.copy())), real(active, pos, temps, *a))[1]
+    return seen
+
+
+class TestSamplerArmCounted:
+    """ISSUE 46: the program skips the sampler's softmax and top-k in a step
+    whose temperatures are all 0, so a row that is not live must not ask for
+    a sample, and the chunk is counted from the vector the program reads."""
+
+    def test_a_row_that_is_not_live_asks_for_no_sample(self, tmp_path, counted):
+        bind, chunks = counted
+        greedy = [(LONG_PROMPTS[0], 0.0, 0.9, 100), (LONG_PROMPTS[2], 0.0, 0.9, 102)]
+        want = reference(tmp_path, greedy, 9)
+        sched = BatchScheduler(bind(build_engine(tmp_path, "bat.m")), n_rows=4, chunk=4)
+        streams = [sched.new_stream() for _ in range(4)]
+        seen = handed_temperatures(sched)
+        # rows 0 and 2 live, row 1 never joined: a bucket of 4 with two dead rows
+        outs = [join(sched, streams[0], greedy[0]), join(sched, streams[2], greedy[1])]
+        outs[0] += take(sched, streams[0], 8)
+        outs[1] += take(sched, streams[2], 8)
+        assert outs == want
+        assert seen and all(active.tolist() == [True, False, True, False] for active, _ in seen)
+        assert all(temps.tolist() == [0.0] * 4 for _, temps in seen)
+        assert chunks() == {"greedy": len(seen), "sampled": 0}
+
+    def test_one_sampling_row_counts_the_chunk_sampled_and_moves_no_greedy_token(
+            self, tmp_path, counted):
+        bind, chunks = counted
+        reqs = [(LONG_PROMPTS[0], 0.0, 0.9, 100), (LONG_PROMPTS[1], 0.9, 0.8, 101),
+                (LONG_PROMPTS[2], 0.0, 0.9, 102)]
+        want = reference(tmp_path, reqs, 9)  # each request alone
+        sched = BatchScheduler(bind(build_engine(tmp_path, "bat.m")), n_rows=4, chunk=4)
+        streams = [sched.new_stream() for _ in range(4)]
+        seen = handed_temperatures(sched)
+        outs = [join(sched, s, r) for s, r in zip(streams, reqs)]
+        for out, s in zip(outs, streams):
+            out += take(sched, s, 8)
+        assert outs == want
+        # the sampling row's temperature as it is; the dead row's, 0
+        assert seen and all(
+            temps.tolist() == [0.0, np.float32(0.9), 0.0, 0.0] for _, temps in seen)
+        assert chunks() == {"greedy": 0, "sampled": len(seen)}
+        # the sampling request gone, the greedy ones' next chunks skip the sampler again
+        streams[1].reset()
+        seen.clear()
+        take(sched, streams[0], 4)
+        assert seen and all(not temps.any() for _, temps in seen)
+        assert chunks()["greedy"] == len(seen)
+
+
 class TestBuiltBuckets:
     def test_a_bucket_never_built_rides_a_larger_one_that_was(self, tmp_path):
         """Under load a program build stalls every lane: a row bucket first

@@ -290,6 +290,19 @@ def _served_program_shapes(one_chip):
     return cfg, params, slab, pool, s
 
 
+def _computations(hlo: str) -> dict:
+    """``{computation name: its instruction lines}`` of a compiled program's text."""
+    computations, name = {}, None
+    for line in hlo.splitlines():
+        m = None if line.startswith(" ") else _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+    return computations
+
+
 def _slab_sized_results(hlo: str, half_elements: int, dtype: str = "bf16"):
     """Read a compiled program's text: ``(in_place_writes, others)``, the
     instructions outside fusions whose result holds a ``dtype`` array of at
@@ -302,14 +315,7 @@ def _slab_sized_results(hlo: str, half_elements: int, dtype: str = "bf16"):
     leaf of 64 MiB may be prefetched there ahead of its kernel and written
     back behind it: the same one read and one write of main memory, moved in
     time), and the ``copy-done`` that writes such a result back."""
-    computations, name = {}, None
-    for line in hlo.splitlines():
-        m = None if line.startswith(" ") else _COMPUTATION.match(line)
-        if m:
-            name = m.group(1)
-            computations[name] = []
-        elif name is not None:
-            computations[name].append(line)
+    computations = _computations(hlo)
 
     def big(result_type: str) -> bool:
         return any(
@@ -380,6 +386,105 @@ def _aliased_outputs(hlo: str) -> dict:
         int(out): int(param)
         for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
     }
+
+
+_CALLED = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\b(?:branch_computations|called_computations)=\{([^}]*)\}"
+)
+
+
+def _conditionals_over(hlo: str, wanted) -> dict:
+    """Read a compiled program's text: for every instruction whose line
+    ``wanted`` accepts, the ``conditional`` instructions that stand between
+    it and the entry computation, outermost last. ``{"<computation>:
+    %<instruction>": [conditional names]}``; an empty list is an instruction
+    that runs whatever any predicate says."""
+    computations = _computations(hlo)
+    # callee -> (caller computation, the calling instruction if it is a conditional)
+    callers = {}
+    for comp, lines in computations.items():
+        for line in lines:
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            for single, several in _CALLED.findall(line):
+                for callee in [single] if single else re.findall(r"%?([\w.\-]+)", several):
+                    callers.setdefault(callee, []).append(
+                        (comp, m.group(1) if m.group(3) == "conditional" else None))
+
+    def guards(comp, seen=()):
+        found = []
+        for caller, conditional in callers.get(comp, ()):
+            if caller not in seen:
+                found += ([conditional] if conditional else []) + guards(caller, seen + (comp,))
+        return found
+
+    return {
+        f"{comp}: %{m.group(1)}": guards(comp)
+        for comp, lines in computations.items() for line in lines
+        if (m := _INSTRUCTION.match(line)) and wanted(m.group(3), line)
+    }
+
+
+def _is_top_k(op: str, line: str) -> bool:
+    """The sampler's candidate selection as the v5e compiler leaves it: the
+    ``TopK`` custom call (rows of 8 and more) or the sorts it is rewritten
+    to (one row); a full-vocabulary sort of the pick's fallbacks too."""
+    return op == "sort" or (op == "custom-call" and 'custom_call_target="TopK"' in line)
+
+
+@pytest.mark.parametrize("cell", ["mistral-one-row", "granite-32-rows", "tp4-head"])
+def test_served_decode_chunk_selects_candidates_only_behind_the_samplers_condition(
+        one_chip, mesh4, monkeypatch, cell):
+    """ISSUE 46: in the decode chunk the cells dispatch (Mistral's one-row
+    bucket, two layers; Granite's 32 rows, one period of ten layers) every
+    top-k and sort belongs to a computation that a ``conditional`` calls, and
+    ONE conditional a step stands over all of them (the pick's own conditions
+    lie inside it): a step whose rows all take the argmax launches none. The
+    tp arm (a vocab-sharded head over the described 2x2 mesh, 16 rows of
+    Mistral's 32000 logits): the candidates' composition is called inside the
+    arm, and its all-gather compiles behind the same conditional."""
+    from distributed_llama_tpu.ops import ssd
+
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)  # steer the CPU branch
+    monkeypatch.setattr(ssd, "_interpret_default", lambda: False)
+    monkeypatch.setattr(decode_attention, "_interpret_default", lambda: False)
+    if cell == "tp4-head":
+        rows, vocab = SERVED_ROWS, 32000
+
+        def step(local, seeds, pos, temperature, topp, topk):
+            cand = lambda: sampling.sharded_topk_indices(local, "tp", sampling.TOPP_FAST_K)
+            logits = jax.lax.all_gather(local, "tp", axis=1, tiled=True)
+            return sampling.fused_sample_batched(logits, seeds, pos, temperature, topp, topk, cand=cand)
+
+        s = lambda shape, dt, spec=P(): jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh4, spec))
+        row = (rows,)
+        text = jax.jit(jax.shard_map(
+            step, mesh=mesh4, in_specs=(P(None, "tp"),) + (P(),) * 5, out_specs=P(), check_vma=False,
+        )).lower(
+            s((rows, vocab), jnp.float32, P(None, "tp")), s(row, jnp.uint32), s(row, jnp.int32),
+            s(row, jnp.float32), s(row, jnp.float32), s(row, jnp.int32),
+        ).compile().as_text()
+        gathers = _conditionals_over(text, lambda op, line: op in ("all-gather", "all-gather-start"))
+        # the logits' gather runs every step (the argmax and the fingerprint
+        # read it); the candidates' two ride the arm
+        assert sorted(len(g) > 0 for g in gathers.values()) == [False, True, True], gathers
+    else:
+        if cell == "mistral-one-row":
+            rows, carry = 1, SERVED_ROWS
+            cfg, params, slab, _, s = _served_program_shapes(one_chip)
+        else:
+            rows = carry = 32
+            cfg, params, slab, _, s = _granite_program_shapes(one_chip, 1, rows)
+        text = sampling.decode_chunk_batched.lower(
+            cfg, params, s((carry,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_),
+            32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+            s((rows,), jnp.uint32)).compile().as_text()
+    selections = _conditionals_over(text, _is_top_k)
+    assert selections, "the reader above no longer sees the sampler's top-k"
+    assert all(selections.values()), selections
+    assert len({guards[-1] for guards in selections.values()}) == 1, selections
 
 
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "unpaged"])

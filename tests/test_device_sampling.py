@@ -20,11 +20,11 @@ from tests.model_utils import random_tensors, tiny_spec, write_model_file
 
 
 def build_engine(tmp_path, name="model.m", seed=0, seq_len=96, dtype=jnp.float32,
-                 cache_dtype=None):
+                 cache_dtype=None, tp=1):
     spec = tiny_spec(seq_len=seq_len)
     path = str(tmp_path / name)
     write_model_file(path, spec, random_tensors(spec, seed=seed))
-    return InferenceEngine(path, dtype=dtype, cache_dtype=cache_dtype)
+    return InferenceEngine(path, dtype=dtype, cache_dtype=cache_dtype, tp=tp)
 
 
 class TestCounterPrng:
@@ -451,3 +451,45 @@ class TestShardedTopK:
         got = np.asarray(fn(jnp.asarray(logits)))
         want = np.asarray(jax.lax.top_k(jnp.asarray(logits), K)[1])
         assert (got == want).all()
+
+    def test_tp_rows_sample_through_the_composition_inside_the_arm(self, tmp_path, monkeypatch):
+        """ISSUE 46: under tp the candidates' composition (a collective) is
+        called inside the sampler's arm. Rows that sample beside a greedy
+        one, on a vocab-sharded head, still replay on the host sampler; a
+        chunk whose rows are all greedy takes the other arm of the same
+        program."""
+        from distributed_llama_tpu.models import sampling
+
+        if len(jax.devices()) < 2:
+            pytest.skip("needs the 8-device virtual CPU mesh")
+        composed = []
+        real = sampling.sharded_topk_indices
+        monkeypatch.setattr(
+            sampling, "sharded_topk_indices",
+            lambda *a: (composed.append(a[0].shape), real(*a))[1],
+        )
+        engine = build_engine(tmp_path, "tp.m", tp=2)
+        sched = BatchScheduler(engine, n_rows=2, chunk=4)
+        prompts = [[1, 5, 9], [2, 4, 6, 8]]
+        streams = [sched.new_stream() for _ in range(2)]
+        for settings in ([SETTINGS[3], SETTINGS[1]], [SETTINGS[3], SETTINGS[3]]):
+            firsts = [
+                s.prefill_device(p, t, tp, sd, k)
+                for s, p, (t, tp, k, sd) in zip(streams, prompts, settings)
+            ]
+            for s, first, (t, tp, k, sd) in zip(streams, firsts, settings):
+                sched._join(s, first, t, tp, sd, k)
+            outs = [
+                [s.fetch_first_token(first)] + [sched.next_token(s) for _ in range(7)]
+                for s, first in zip(streams, firsts)
+            ]
+            for i, (t, tp, k, sd) in enumerate(settings):
+                host = _host_replay(
+                    build_engine(tmp_path, f"host{i}.m"), prompts[i], t, tp, k, sd, 8,
+                    engine.cfg.vocab_size,
+                )
+                assert outs[i] == host, (settings, i, outs[i], host)
+            for s in streams:
+                s.reset()
+        # the head was sharded: the composition was traced, over half the vocabulary
+        assert composed and all(shape == (2, engine.cfg.vocab_size // 2) for shape in composed)

@@ -3,7 +3,9 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from distributed_llama_tpu import prng
 from distributed_llama_tpu.engine import InferenceEngine
 from distributed_llama_tpu.models.sampling import (
     TOPP_FAST_K,
@@ -192,6 +194,89 @@ class TestGreedyRowsInSampledBatch:
         )
         assert int(out[0]) == 0  # argmax: first of the tied max entries
         assert int(out[1]) < TOPP_FAST_K  # sampled row stays in-nucleus
+
+
+def _primitives_outside_cond(jaxpr) -> list:
+    """Names of a jaxpr's primitives, those of its nested calls (``pjit``,
+    ``custom_jvp_call``) included, but nothing inside a ``cond``'s branches."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "cond":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _primitives_outside_cond(sub)
+    return names
+
+
+def _sampling_arm(logits, seeds, pos, temperature, topp, topk):
+    """What the sampler computed for EVERY row before the arm had a
+    condition in front of it (ISSUE 46): the reference the rows that sample
+    are held to, bit for bit."""
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    probs = jax.nn.softmax(scaled, axis=-1)
+    coin = prng.device_coin(seeds, pos, prng.DRAW_SAMPLE)
+    return fused_pick(probs, scaled, coin, topp, topk).astype(jnp.int32)
+
+
+class TestSamplerBehindItsCondition:
+    """ISSUE 46: the softmax, the coin and the pick run in the true arm of
+    one ``lax.cond`` on "some row samples"; a step whose rows all take the
+    argmax runs none of them, and no row's token changes in any mix."""
+
+    @pytest.mark.parametrize("rows", [1, 8, 32])
+    @pytest.mark.parametrize("vocab", [320, 32768, 100352])
+    def test_one_cond_holds_everything_but_the_argmax(self, vocab, rows):
+        s = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(fused_sample_batched)(
+            s((rows, vocab), jnp.bfloat16), s((rows,), jnp.uint32), s((rows,), jnp.int32),
+            s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+        ).jaxpr
+        assert [e.primitive.name for e in jaxpr.eqns].count("cond") == 1
+        outside = _primitives_outside_cond(jaxpr)
+        assert "argmax" in outside
+        assert not {"top_k", "sort", "cumsum", "exp", "div", "reduce_sum"} & set(outside), outside
+        # and the arm is the whole sampler, not an empty shell
+        (cond,) = (e for e in jaxpr.eqns if e.primitive.name == "cond")
+        inside = {
+            name for branch in cond.params["branches"]
+            for name in _primitives_outside_cond(branch.jaxpr)
+        }
+        assert {"top_k", "exp"} <= inside
+
+    @pytest.mark.parametrize(
+        "topp,topk", [(0.9, 0), (0.0, 40), (0.9, 40), (0.0, 0)],
+        ids=["top-p", "top-k", "both", "filters-off"],
+    )
+    @pytest.mark.parametrize(
+        "temps",
+        [(0.0, 0.0, 0.0, 0.0), (0.8, 1.3, 0.5, 1.0), (0.0, 0.9, 0.0, 1.3)],
+        ids=["all-greedy", "all-sampled", "mixed"],
+    )
+    def test_every_rows_token_is_what_it_was(self, temps, topp, topk):
+        rng = np.random.RandomState(3)
+        V = 4352  # past TOPP_PARTITION_MIN_V: every route of the pick is in the program
+        logits = jnp.asarray(rng.randn(4, V).astype(np.float32) * 3.0)
+        seeds = jnp.asarray([5, 6, 7, 2**31 + 8], jnp.uint32)
+        temps = jnp.asarray(temps, jnp.float32)
+        topps, topks = jnp.full(4, topp, jnp.float32), jnp.full(4, topk, jnp.int32)
+        sample = jax.jit(fused_sample_batched)
+        for step in range(6):  # other positions, other coins
+            pos = jnp.asarray([3, 9, 2, 7], jnp.int32) + step
+            got = np.asarray(sample(logits, seeds, pos, temps, topps, topks))
+            arm = np.asarray(_sampling_arm(logits, seeds, pos, temps, topps, topks))
+            want = np.where(np.asarray(temps) == 0.0, np.argmax(np.asarray(logits), axis=-1), arm)
+            assert got.dtype == np.int32 and got.tolist() == want.tolist()
+
+    def test_a_traced_temperature_of_zero_takes_the_argmax(self):
+        rng = np.random.RandomState(4)
+        logits = jnp.asarray(rng.randn(700).astype(np.float32))
+        one = jax.jit(lambda t, p, k: sample_token(logits, jnp.uint32(9), jnp.int32(5), t, p, k))
+        assert int(one(0.0, 0.9, 0)) == int(np.argmax(np.asarray(logits)))
+        # the same program samples when asked to
+        seen = {int(jax.jit(lambda t, sd: sample_token(logits, sd, jnp.int32(5), t, 0.0, 0))(
+            jnp.float32(1.5), jnp.uint32(sd))) for sd in range(12)}
+        assert len(seen) > 1
 
 
 class TestDecodeLoop:
