@@ -205,17 +205,15 @@ def _publish_pages(page: int, slab, pool, page_ids, src_page, row, base: int = 0
     """Copy slab row ``row``'s blocks ``src_page`` into pool pages
     ``page_ids`` across every layer (the post-prefill publish;
     ``kvc.BlockSlots(page, base=base)`` says where a block sits). The donated
-    pool aliases in place; the slab is read-only here (``leaf[0]``/
-    ``leaf[1]`` are contiguous views of the fused leaf; a latent layer's leaf
-    and pool entry hold one array each)."""
+    pool aliases in place; the slab is read-only here, and read as it is
+    stored: one gather a half takes half, row and slots out of the fused leaf
+    (``kvc.publish_leaf_pages``), so nothing but the pages published is read
+    or formed (a latent layer's leaf and pool entry hold one array each)."""
     return [
         None if half is None
         else (kvc.publish_latent_pages(half[0], leaf, row, src_page, page_ids, page),)
         if kvc.is_latent_leaf(leaf)
-        else (
-            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page, base=base),
-            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page, base=base),
-        )
+        else kvc.publish_leaf_pages(*half, leaf, row, src_page, page_ids, page, base=base)
         for leaf, half in zip(slab, pool)
     ]
 
@@ -253,13 +251,12 @@ def _publish_window_pages(page: int, slab, wpool, page_ids, src_page, row, *, ri
     """:func:`_publish_pages` for the window layers: slab row ``row``'s blocks
     ``src_page``, read out of the rings at their positions' slots
     (``kvc.BlockSlots(page, ring)``), into pages ``page_ids`` of the window
-    pool. ``wpool`` mirrors the slab's layer list (None for a layer of
-    another kind). Only the pool is donated."""
+    pool, each half by one gather on the fused leaf as it is stored.
+    ``wpool`` mirrors the slab's layer list (None for a layer of another
+    kind). Only the pool is donated."""
     return [
-        None if half is None else (
-            kvc.publish_row_pages(half[0], leaf[0], row, src_page, page_ids, page, ring=ring),
-            kvc.publish_row_pages(half[1], leaf[1], row, src_page, page_ids, page, ring=ring),
-        )
+        None if half is None
+        else kvc.publish_leaf_pages(*half, leaf, row, src_page, page_ids, page, ring=ring)
         for leaf, half in zip(slab, wpool)
     ]
 

@@ -403,29 +403,48 @@ def block_slots(blocks, page: int, ring: int = 0, base: int = 0):
     return slots + base if base else slots
 
 
+def _publish_blocks(pool_half, src, at: tuple, src_page, page_ids, page: int, ring: int, base: int):
+    """``src[at + (slots,)]``, the slots of blocks ``src_page``
+    (:func:`block_slots`), into pool pages ``page_ids``: ONE gather on ``src``
+    as it is stored, so nothing larger than the pages read forms. A
+    ``page_ids`` entry at or beyond P DROPS its write."""
+    slots = block_slots(src_page, page, ring, base)
+    n = src_page.shape[0]
+
+    def put(pool, a):
+        vals = a[at + (slots,)]  # [n * page, K, x]
+        return pool.at[page_ids].set(vals.reshape((n, page) + vals.shape[1:]), mode="drop")
+
+    if isinstance(pool_half, QuantizedKV):
+        return QuantizedKV(put(pool_half.data, src.data), put(pool_half.scales, src.scales))
+    return put(pool_half, src)
+
+
 def publish_row_pages(pool_half, slab_half, row, src_page, page_ids, page: int,
                       ring: int = 0, base: int = 0):
     """Copy slab row ``row``'s blocks ``src_page[i]`` (:func:`block_slots`)
     into pool pages ``page_ids[i]`` (the prefix-cache publish: the row's
     completed prefill KV becomes an immutable shared page). A ``page_ids``
     entry at or beyond P DROPS its write, so padded entries are inert.
-    Returns the updated pool half (callers donate the pool)."""
-    slots = block_slots(src_page, page, ring, base)
-    n = src_page.shape[0]
-    if isinstance(pool_half, QuantizedKV):
-        vals = slab_half.data[row, slots]  # [Np*page, K, hd]
-        scal = slab_half.scales[row, slots]
-        return QuantizedKV(
-            pool_half.data.at[page_ids].set(
-                vals.reshape((n, page) + vals.shape[1:]), mode="drop"
-            ),
-            pool_half.scales.at[page_ids].set(
-                scal.reshape((n, page) + scal.shape[1:]), mode="drop"
-            ),
-        )
-    vals = slab_half[row, slots]
-    return pool_half.at[page_ids].set(
-        vals.reshape((n, page) + vals.shape[1:]), mode="drop"
+    ``slab_half`` is a half ``[B, S, K, hd]`` that stands alone (the tp and
+    pod engines' shards); for a half of a fused leaf see
+    :func:`publish_leaf_pages`. Returns the updated pool half (callers donate
+    the pool)."""
+    return _publish_blocks(pool_half, slab_half, (row,), src_page, page_ids, page, ring, base)
+
+
+def publish_leaf_pages(pool_k, pool_v, slab_leaf, row, src_page, page_ids, page: int,
+                       ring: int = 0, base: int = 0):
+    """:func:`publish_row_pages` out of both halves of a fused slab leaf ``[2,
+    B, S, K, hd]`` (:func:`restore_row_pages` in reverse), bit for bit what it
+    writes from ``slab_leaf[0]`` and ``slab_leaf[1]``: half, row and slots are
+    addressed in one read of the leaf as it is stored. (A ``slab_leaf[0]``
+    handed to :func:`publish_row_pages` is no view: the v5e compiler
+    materialises the whole half for it, every layer and every publish.)
+    Returns the updated ``(keys, values)`` pool halves."""
+    return tuple(
+        _publish_blocks(pool, slab_leaf, (half, row), src_page, page_ids, page, ring, base)
+        for half, pool in enumerate((pool_k, pool_v))
     )
 
 

@@ -782,6 +782,53 @@ def test_prefix_restore_writes_the_slab_in_place(one_chip, run):
     assert compiled.memory_analysis().temp_size_in_bytes < slab[0].size // 64
 
 
+# the cells whose lines carried the publish's whole-half slice (ledger, PR 46), two layers of
+# each: (slab leaves [2, rows, slots, K, hd], pool page's slots, pool entries, BlockSlots keywords)
+_EVA_LEAF, _DOC_LEAF = (2, 8, 3072, 32, 128), (2, 8, 8192, 8, 128)
+_EXAONE_LEAVES = ((2, 8, 1024, 8, 128), (2, 8, 16384, 8, 128))  # a window layer's ring, a full layer
+_PUBLISHES = {
+    "evabyte-summaries": ("_publish_pages", (_EVA_LEAF,) * 2, 4, (True, True), {"base": 2048}),
+    "evabyte-window": ("_publish_window_pages", (_EVA_LEAF,) * 2, 64, (True, True), {"ring": 2048}),
+    "long-documents": ("_publish_pages", (_DOC_LEAF,) * 2, 64, (True, True), {}),
+    "k-exaone-full": ("_publish_pages", _EXAONE_LEAVES, 64, (False, True), {}),
+    "k-exaone-rings": ("_publish_window_pages", _EXAONE_LEAVES, 64, (True, False), {"ring": 1024}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PUBLISHES))
+def test_prefix_publish_forms_nothing_of_a_rows_size(one_chip, case):
+    """``engine.batch._publish_pages`` and ``_publish_window_pages`` at the
+    shapes of the three cells that publish most (EvaByte's summaries behind a
+    base and its window store as a ring, long documents, K-EXAONE's full
+    layers and its rings; bf16, 8 ids): every pool half comes back in the
+    buffer it was donated in, written in place, and the read forms nothing as
+    large as ONE ROW of one half of the leaf it reads. A ``leaf[0]`` handed on
+    as a half is no view to this compiler: it materialised the whole half, 201
+    MB in EvaByte's cell, twice a layer and a publish."""
+    from distributed_llama_tpu.engine import batch
+
+    program, leaves, block, pooled, slots = _PUBLISHES[case]
+    s = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    slab = [s(leaf) for leaf in leaves]
+    pages = 512
+    pool = [(s((pages, block) + leaf[3:]),) * 2 if held else None
+            for leaf, held in zip(leaves, pooled)]
+    ids = s((8,), jnp.int32)
+    compiled = getattr(batch, program).lower(block, slab, pool, ids, ids, s((), jnp.int32), **slots).compile()
+    read = [leaf for leaf, held in zip(leaves, pooled) if held]
+    halves, text = 2 * len(read), compiled.as_text()
+    # one in-place scatter a pool half, or the reader no longer sees the program
+    writes, _ = _slab_sized_results(text, pages * block * int(np.prod(read[0][3:])))
+    assert len(writes) == halves, writes
+    _, others = _slab_sized_results(text, min(int(np.prod(leaf[2:])) for leaf in read))
+    assert not others, "results as large as a row of a half:\n" + "\n".join(others)
+    # the parameters are the leaves the program reads (a layer of the other kind is no
+    # argument of the compiled program), then the pool's halves
+    assert _aliased_outputs(text) == {o: len(read) + o for o in range(halves)}
+    leaf_bytes = 2 * min(int(np.prod(leaf)) for leaf in read)
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes // 64
+
+
 @pytest.mark.parametrize(
     "impl,marker",
     [("psum", "all-reduce"), ("ring_xla", "collective-permute"), ("ring", "tpu_custom_call")],

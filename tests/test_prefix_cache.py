@@ -6,6 +6,7 @@ per-request opt-out, and the API-level repeated-prefix flow."""
 import threading
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -165,6 +166,44 @@ class TestPageOps:
             slab[0], pool, jnp.full(n_table, 99, jnp.int32), jnp.int32(0)
         )
         np.testing.assert_array_equal(np.asarray(virt), np.asarray(slab[0]))
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, "i8"], ids=["bf16", "i8"])
+    @pytest.mark.parametrize(
+        "blocks,ids,slots",
+        [
+            ([0, 2, 5], [4, 2, 0], {}),
+            ([2, 3, 4], [1, 5, 3], {"ring": 18}),  # block 4 (slots 16..19) wraps at 18
+            ([0, 3, 1], [0, 1, 2], {"base": 16}),  # behind another store, as EVA's summaries
+            ([1, 0, 6], [3, P, P + 2], {}),  # padded ids past the pool: dropped
+            ([5, 1, 4], [P, 2, 0], {"ring": 18}),
+        ],
+        ids=["plain", "ring-wraps", "base", "padded-ids", "ring-padded"],
+    )
+    def test_a_fused_leafs_publish_is_the_halves_publish(self, dtype, blocks, ids, slots):
+        """``publish_leaf_pages`` reads half, row and slots out of the fused
+        leaf ``[2, B, S, K, hd]`` in one gather a half; what reaches a pool half is bit
+        for bit what ``publish_row_pages`` writes from ``leaf[0]`` and
+        ``leaf[1]`` and what the layout says by hand, and nothing else of the
+        pool changes."""
+        rng = np.random.RandomState(3)
+        leaf = kvc.init_fused((self.B, self.S, self.K, self.HD), dtype)
+        pool = kvc.init_page_pool_half(self.P, PAGE, self.K, self.HD, dtype)
+        fill = lambda a: jnp.asarray(rng.randn(*a.shape) * 7).astype(a.dtype)
+        leaf, pool = jax.tree.map(fill, (leaf, pool))  # an i8's data and scales alike
+        src, page_ids, row = jnp.asarray(blocks, jnp.int32), jnp.asarray(ids, jnp.int32), jnp.int32(1)
+        both = kvc.publish_leaf_pages(pool, pool, leaf, row, src, page_ids, PAGE, **slots)
+        for half, got in enumerate(both):
+            want = kvc.publish_row_pages(pool, leaf[half], row, src, page_ids, PAGE, **slots)
+            # data, and an i8's scales
+            for g, w, before, a in zip(*map(jax.tree.leaves, (got, want, pool, leaf))):
+                expect = np.asarray(before).copy()  # by hand: the blocks' slots of row 1, page by page
+                for block, pid in zip(blocks, ids):
+                    at = np.arange(block * PAGE, (block + 1) * PAGE)
+                    if pid < self.P:
+                        at = at % slots["ring"] if "ring" in slots else at
+                        expect[pid] = np.asarray(a)[half, 1, at + slots.get("base", 0)]
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+                np.testing.assert_array_equal(np.asarray(g), expect)
 
 
 # ---------------------------------------------------------------------------
