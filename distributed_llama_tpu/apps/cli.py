@@ -59,7 +59,7 @@ def build_parser() -> argparse.ArgumentParser:
         help="one-process pod serving on a single ('data','model') mesh "
         "(e.g. 2x2): tensor parallelism over 'model' inside every slice, "
         "data-parallel replicas as slices of the SAME mesh sharing ONE "
-        "weights tree (no N-replica weight copies; ROADMAP item 3). The "
+        "weights tree (no N-replica weight copies). The "
         "server runs one supervised replica per data slice — a mesh-slice "
         "failure IS a replica loss with the PR 9/10 failover/replay/"
         "restart contract, and a slice rebuild never reloads weights. "
@@ -78,16 +78,6 @@ def build_parser() -> argparse.ArgumentParser:
         "n_experts/ep whole experts; prefill routes tokens with all_to_all "
         "dispatch/combine, decode runs local experts + psum (composes with "
         "--tp on a 2-D tp x ep mesh)",
-    )
-    p.add_argument(
-        "--moe-capacity", type=float, default=0.0,
-        help="MoE prefill capacity factor: per-expert buckets hold "
-        "ceil(F*T*k/E) rows, overflow DROPS (lossy, standard capacity "
-        "semantics; ~15%% faster Mixtral prefill at 2.0). 0 = exact "
-        "(default): worst-case drop-free buckets. Applies to the q40 "
-        "per-expert layout (prompts >= 32 tokens) and the --ep dispatch; "
-        "the bf16 stacked-bank prefill ignores it (already one batched "
-        "einsum)",
     )
     p.add_argument(
         "--dtype",
@@ -231,7 +221,6 @@ def make_pod_group(args):
         dtype=dtype,
         max_seq_len=args.max_seq_len,
         cache_dtype=cache_dtype,
-        moe_capacity_factor=getattr(args, "moe_capacity", 0.0) or 0.0,
     )
     tokenizer = Tokenizer.from_file(args.tokenizer, group.cfg.vocab_size)
     return group, tokenizer, _make_sampler(args, group.cfg.vocab_size)
@@ -251,7 +240,6 @@ def make_engine(args):
         args.model, dtype=dtype, max_seq_len=args.max_seq_len, tp=args.tp,
         sp=getattr(args, "sp", 1), ep=getattr(args, "ep", 1),
         cache_dtype=cache_dtype,
-        moe_capacity_factor=getattr(args, "moe_capacity", 0.0) or 0.0,
     )
     tokenizer = Tokenizer.from_file(args.tokenizer, engine.cfg.vocab_size)
     return engine, tokenizer, _make_sampler(args, engine.cfg.vocab_size)

@@ -906,12 +906,6 @@ class InferenceEngine:
             reader, self.cfg, dtype=dtype, tp=tp, mesh=mesh
         )
         reader.close()
-        if quantized:
-            # the block-interleaved activation basis is retired (the int8
-            # MXU kernel's scale-product epilogue made it moot — ops/q40.py
-            # legacy section); basis-era snapshots still load via the
-            # unconditional migration inverse (no-op on standard trees)
-            host_params = weights_lib.remove_basis_interleave(host_params, self.cfg)
         if self._tp_engine is not None:
             self.params = self._tp_engine.shard_params(host_params)
             self._forward = self._tp_engine.forward

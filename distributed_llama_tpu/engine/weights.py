@@ -584,152 +584,6 @@ def load_params(
     }
 
 
-def interleave_eligible(cfg: LlamaConfig) -> bool:
-    """Whether the RETIRED block-interleaved activation basis (ops.q40
-    legacy section) could apply to this config: every matmul input basis
-    kernel-eligible and the residual basis D unpadded. Kept because the
-    migration inverse (:func:`remove_basis_interleave`) needs the same
-    predicate to know which leaves a basis-era snapshot permuted."""
-    from distributed_llama_tpu.ops.q40 import _n_padded, interleave_window
-
-    D, F = cfg.dim, cfg.hidden_dim
-    if _n_padded(D) != D:
-        return False
-    return (
-        interleave_window(_n_padded(D)) is not None
-        and interleave_window(_n_padded(F)) is not None
-    )
-
-
-def apply_basis_interleave(params: Params, cfg: LlamaConfig) -> Params:
-    """LEGACY producer: move a q40 params tree (fused qkv/gate_up layout,
-    tp=1) into the RETIRED block-interleaved activation basis — an EXACT
-    row/column-gather transform. The engine no longer calls this (the int8
-    MXU kernel's scale-product epilogue made the basis moot and the matmul
-    entry points now reject interleaved packs); it is retained so the
-    migration test can synthesize a basis-era params tree and prove
-    :func:`remove_basis_interleave` restores it bit-exactly.
-    DLT_INTERLEAVE=0 disables."""
-    import os
-
-    from distributed_llama_tpu.ops import q40 as q
-
-    if os.environ.get("DLT_INTERLEAVE") == "0" or not interleave_eligible(cfg):
-        return params
-    from distributed_llama_tpu.ops.q40 import (
-        _n_padded,
-        interleave_perm,
-        interleave_window,
-    )
-
-    D, F = cfg.dim, cfg.hidden_dim
-    perm_d = jnp.asarray(interleave_perm(_n_padded(D), interleave_window(_n_padded(D))))
-    out = dict(params)
-    out["embedding"] = q.interleave_vector(params["embedding"], D)
-    out["rms_final"] = q.interleave_vector(params["rms_final"], D)
-    out["wcls"] = q.interleave_input_rows(params["wcls"])
-    layers = []
-    for lp in params["layers"]:
-        lp = dict(lp)
-        lp["qkv"] = q.interleave_input_rows(lp["qkv"])  # input D; output heads
-        # wo: input is the attention-head basis (NOT interleaved — rope and
-        # head reshapes own that order); output columns move to basis D
-        lp["wo"] = q.interleaved_output_cols(lp["wo"], D)
-        if "experts" in lp:
-            # MoE: each expert's FFN follows the dense pattern — gate_up
-            # reads D / writes its own F basis, down reads F / writes D;
-            # the router (a plain array) reads D, so its rows permute
-            lp["router"] = jnp.take(jnp.asarray(lp["router"]), perm_d, axis=0)
-            lp["experts"] = [
-                {
-                    "gate_up": q.interleaved_output_cols(
-                        q.interleave_input_rows(e["gate_up"]), F, halves=2
-                    ),
-                    "down": q.interleaved_output_cols(
-                        q.interleave_input_rows(e["down"]), D
-                    ),
-                }
-                for e in lp["experts"]
-            ]
-        else:
-            lp["gate_up"] = q.interleaved_output_cols(
-                q.interleave_input_rows(lp["gate_up"]), F, halves=2
-            )
-            lp["down"] = q.interleaved_output_cols(q.interleave_input_rows(lp["down"]), D)
-        lp["rms_att"] = q.interleave_vector(lp["rms_att"], D)
-        lp["rms_ffn"] = q.interleave_vector(lp["rms_ffn"], D)
-        if "rms_moe" in lp:
-            lp["rms_moe"] = q.interleave_vector(lp["rms_moe"], D)
-        if "rms_ffn2" in lp:
-            lp["rms_ffn2"] = q.interleave_vector(lp["rms_ffn2"], D)
-        layers.append(lp)
-    out["layers"] = layers
-    return out
-
-
-def remove_basis_interleave(params: Params, cfg: LlamaConfig) -> Params:
-    """The converter-side migration shim: move a basis-era params tree
-    (one that went through :func:`apply_basis_interleave` before the basis
-    was retired — e.g. an external snapshot of the placed tree) back to
-    the standard basis, bit-exactly. A standard-basis tree passes through
-    unchanged, so loaders can apply this unconditionally to trees of
-    unknown vintage. Detection is the layer-0 qkv ``interleaved`` flag:
-    the producer always row-interleaved qkv, and the flag rides the pack's
-    pytree aux data through any serialization that preserves it."""
-    from distributed_llama_tpu.ops import q40 as q
-
-    layers_in = params.get("layers") or []
-    if not layers_in or not getattr(layers_in[0].get("qkv"), "interleaved", False):
-        return params
-    from distributed_llama_tpu.ops.q40 import (
-        _n_padded,
-        interleave_perm,
-        interleave_window,
-    )
-
-    D, F = cfg.dim, cfg.hidden_dim
-    perm_d = interleave_perm(_n_padded(D), interleave_window(_n_padded(D)))
-    inv_d = jnp.asarray(np.argsort(perm_d))
-    out = dict(params)
-    out["embedding"] = q.deinterleave_vector(params["embedding"], D)
-    out["rms_final"] = q.deinterleave_vector(params["rms_final"], D)
-    out["wcls"] = q.deinterleave_input_rows(params["wcls"])
-    layers = []
-    for lp in params["layers"]:
-        lp = dict(lp)
-        lp["qkv"] = q.deinterleave_input_rows(lp["qkv"])
-        lp["wo"] = q.deinterleave_output_cols(lp["wo"], D)
-        if "experts" in lp:
-            lp["router"] = jnp.take(jnp.asarray(lp["router"]), inv_d, axis=0)
-            lp["experts"] = [
-                {
-                    "gate_up": q.deinterleave_input_rows(
-                        q.deinterleave_output_cols(e["gate_up"], F, halves=2)
-                    ),
-                    "down": q.deinterleave_input_rows(
-                        q.deinterleave_output_cols(e["down"], D)
-                    ),
-                }
-                for e in lp["experts"]
-            ]
-        else:
-            lp["gate_up"] = q.deinterleave_input_rows(
-                q.deinterleave_output_cols(lp["gate_up"], F, halves=2)
-            )
-            lp["down"] = q.deinterleave_input_rows(
-                q.deinterleave_output_cols(lp["down"], D)
-            )
-        lp["rms_att"] = q.deinterleave_vector(lp["rms_att"], D)
-        lp["rms_ffn"] = q.deinterleave_vector(lp["rms_ffn"], D)
-        if "rms_moe" in lp:
-            lp["rms_moe"] = q.deinterleave_vector(lp["rms_moe"], D)
-        if "rms_ffn2" in lp:
-            lp["rms_ffn2"] = q.deinterleave_vector(lp["rms_ffn2"], D)
-        layers.append(lp)
-    out["layers"] = layers
-    return out
-
-
 def _synthetic_params(
     cfg: LlamaConfig, mat, ones, embedding, rope_table, layered: bool = False
 ) -> Params:

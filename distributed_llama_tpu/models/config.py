@@ -54,14 +54,6 @@ class LlamaConfig:
     # the correct frequency scaling matches HF and gives the intended
     # long-context behavior.
     rope_llama3_reference_quirk: bool = False
-    # MoE prefill/dispatch capacity factor: per-expert bucket size is
-    # ceil(factor * tokens * k / E) rows, overflow rows DROP (standard
-    # capacity semantics — faster, but lossy under routing imbalance).
-    # 0.0 (default) = exact: no row is ever dropped (a prompt piece's
-    # buckets have an every-row arm for an overflow, the expert-parallel
-    # dispatch sizes them for the worst case); opt into e.g. 2.0 via the
-    # CLI/server --moe-capacity flag for smaller buckets that drop.
-    moe_capacity_factor: float = 0.0
     # the layer table and the expert share (ArchType.SOLAR_OPEN2; 0 elsewhere):
     # layer l is a softmax layer where l % attn_period == 0, else a gated
     # delta-rule layer of lin_heads x lin_head_dim; the router is

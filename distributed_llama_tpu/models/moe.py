@@ -125,25 +125,6 @@ def _moe_topk(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:
     return out
 
 
-# below this many tokens the serial all-E path is used instead of the
-# bucketed one: the capacity estimate is noisy at small T (drops bite) and
-# expert-weight HBM reads dominate anyway, so bucketing's compute savings
-# buy nothing
-MOE_BUCKETED_MIN_T = 32
-
-
-def bucket_capacity(factor: float, n_tokens: int, k: int, n_buckets: int) -> int:
-    """Per-expert bucket rows. factor <= 0 = EXACT: n_tokens rows (a token
-    routes to a given expert at most once, so that is the drop-free worst
-    case). factor > 0 = standard capacity semantics: ceil(factor·T·k/E)
-    rounded up to a multiple of 4, overflow rows drop."""
-    import math
-
-    if factor <= 0:
-        return n_tokens
-    return min(n_tokens, max(4, -(-math.ceil(factor * n_tokens * k / n_buckets) // 4) * 4))
-
-
 # a program of this many rows or more computes each expert over the REAL rows
 # that chose it (exact: the every-row loop is its overflow arm). Below it
 # (every decode bucket) the experts' bytes bound the step and nearly every
@@ -226,16 +207,11 @@ def _moe_dense(
     an expert multiplies the REAL rows that chose it (:func:`_moe_bucketed`:
     buckets of :func:`exact_bucket_rows` rows, the every-row loop where some
     expert overflows its bucket; exact either way); below that, the loop
-    over every expert and every row. With an opted-in capacity factor
-    (cfg.moe_capacity_factor, the --moe-capacity flag) the buckets hold
-    ~factor·T·k/E rows and there is no overflow arm: rows past a bucket's
-    capacity drop. ``n_real`` marks the real-token prefix of a bucket-padded
-    batch: pad rows choose no expert (they must not spend a bucket's rows)."""
+    over every expert and every row. ``n_real`` marks the real-token prefix
+    of a bucket-padded batch: pad rows choose no expert (they must not spend
+    a bucket's rows)."""
     if "experts" in lp:
         T, k, E = xn.shape[0], cfg.n_active_experts, cfg.n_experts
-        if cfg.moe_capacity_factor > 0 and T >= MOE_BUCKETED_MIN_T:
-            C = bucket_capacity(cfg.moe_capacity_factor, T, k, E)
-            return _moe_bucketed(cfg, xn, lp, C, n_real=n_real, exact=False)
         C = exact_bucket_rows(T, k, E)
         if T >= MOE_EXACT_MIN_T and C < T:
             return _moe_bucketed(cfg, xn, lp, C, n_real=n_real)
@@ -270,8 +246,7 @@ def _moe_dense(
 
 
 def _moe_bucketed(
-    cfg: LlamaConfig, xn: jax.Array, lp, C: int,
-    n_real: jax.Array | None = None, exact: bool = True,
+    cfg: LlamaConfig, xn: jax.Array, lp, C: int, n_real: jax.Array | None = None
 ) -> jax.Array:
     """Bucketed q40 prefill: rank every (token, choice) within its expert,
     gather each expert's rows into a fixed [C, D] bucket, run ONE fused q40
@@ -282,10 +257,9 @@ def _moe_bucketed(
     held experts' buckets and the expert-parallel dispatch
     (parallel.expert_parallel._ep_dispatch).
 
-    ``exact``: where some expert has more rows than ``C`` the layer runs
-    every expert over every row instead (one ``lax.cond``; both arms live in
-    the one program), so no row is ever left out. Not exact (the capacity
-    factor's path): rows ranked past ``C`` drop.
+    Where some expert has more rows than ``C`` the layer runs every expert
+    over every row instead (one ``lax.cond``; both arms live in the one
+    program), so no row is ever left out.
 
     Engine bucket-padding appends zero tokens past ``n_real``; those rows
     route like real tokens (identical embeddings → identical experts), so
@@ -308,10 +282,6 @@ def _moe_bucketed(
             _expert_ffn(cfg, buckets[e], _expert_weights(lp, e)) for e in range(E)
         ])  # [E, C, D] f32
         return bucket_combine(outs, jnp.minimum(top_idx, E - 1), rank, top_vals, C)
-
-    if not exact:
-        _note_piece_path(0)
-        return bucketed()
 
     def every_row():
         weights = jnp.einsum("tk,tke->te", top_vals, jax.nn.one_hot(top_idx, E))

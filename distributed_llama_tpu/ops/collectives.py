@@ -1,4 +1,4 @@
-"""The all-reduce seam + ICI ring all-reduce kernel (ROADMAP item 1).
+"""The all-reduce seam + ICI ring all-reduce kernel.
 
 Every tensor-parallel layer pays exactly two all-reduces (after wo and
 after down — ``models.llama.block_tail``/``ffn``; the reference's two
@@ -36,8 +36,8 @@ the arm that runs: a ring kernel that fails to trace or lower fails the
 program, it never degrades to psum. The ring kernels compile for a
 described v5e 2x2 (tests/test_chip_compile.py) but have not run on a
 chip: the two receive slots are reused without a capacity handshake, so
-a neighbour running ahead could overwrite an unread slot — ROADMAP
-Speed 8. Every selection is counted in
+a neighbour running ahead could overwrite an unread slot (ROADMAP,
+Speed: collectives on a real mesh). Every selection is counted in
 ``dllama_kernel_path_total{kernel="all_reduce"}`` so the implementation
 actually serving is visible in /metrics.
 """
@@ -62,7 +62,7 @@ def _note(path: str) -> None:
 def default_impl() -> str:
     """psum unless ``DLT_ALLREDUCE`` pins otherwise, on every platform:
     the ring kernel is an explicit opt-in until a chip run has judged it
-    (module docstring; ROADMAP Speed 8)."""
+    (module docstring)."""
     return _os.environ.get("DLT_ALLREDUCE") or "psum"
 
 
@@ -350,7 +350,6 @@ def matmul_all_reduce(
         if (
             n > 1
             and isinstance(w, QuantizedMatrix)
-            and not w.interleaved
             and _fused_ring_eligible(x, w, n)
         ):
             out = fused_matmul_ring_all_reduce(x, w, axis_name, n, role)

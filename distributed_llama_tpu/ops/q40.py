@@ -78,22 +78,12 @@ class QuantizedMatrix:
     plain array. The packed arrays may be PADDED up to tile-friendly sizes
     (padding carries zero *scales*, so padded rows/columns dequantize to
     exact zeros); ``n``/``d`` are the logical (unpadded) matmul dims.
-
-    ``interleaved``: the input rows are stored in the RETIRED
-    block-interleaved basis (see the legacy section below) — such packs
-    only exist transiently at load time now; every matmul entry point
-    rejects them, and ``deinterleave_input_rows`` /
-    ``weights.remove_basis_interleave`` move them back to the standard
-    basis. ``packed_bn`` records the block_n the interleave was built for
-    (the inverse gather needs exactly that window).
     """
 
     qs: jax.Array  # uint8 [..., n_pad/2, d_pad]
     scales: jax.Array  # f32 [..., n_pad/32, d_pad]
     n_logical: int = 0  # 0 = unpadded (use packed size)
     d_logical: int = 0
-    interleaved: bool = False
-    packed_bn: int = 0
 
     @property
     def n(self) -> int:
@@ -120,9 +110,7 @@ class QuantizedMatrix:
         return jnp.bfloat16  # activation dtype the matmul expects
 
     def tree_flatten(self):
-        return (self.qs, self.scales), (
-            self.n_logical, self.d_logical, self.interleaved, self.packed_bn,
-        )
+        return (self.qs, self.scales), (self.n_logical, self.d_logical)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -263,19 +251,9 @@ def concat_shard_packs(mats: list[QuantizedMatrix], axis: str) -> QuantizedMatri
     return QuantizedMatrix(qs, scales, n_logical=m0.n, d_logical=m0.d)
 
 
-def _reject_interleaved(qm: QuantizedMatrix) -> None:
-    if qm.interleaved:
-        raise ValueError(
-            "interleaved pack: the block-interleaved basis is retired — "
-            "de-interleave at load (q40.deinterleave_input_rows / "
-            "weights.remove_basis_interleave)"
-        )
-
-
 def dequantize_tpu(qm: QuantizedMatrix) -> np.ndarray:
-    """Reference unpacking of the TPU layout → f32 [n, d] (standard basis).
+    """Reference unpacking of the TPU layout → f32 [n, d].
     Trims any tile padding back to the logical dims."""
-    _reject_interleaved(qm)
     qs = np.asarray(qm.qs)
     scales = np.asarray(qm.scales)
     # half-split: low nibbles are logical rows [0, half), high [half, n_pad)
@@ -284,178 +262,6 @@ def dequantize_tpu(qm: QuantizedMatrix) -> np.ndarray:
     vals = np.concatenate([lo, hi], axis=0)
     scale_full = np.repeat(scales, QK, axis=0)
     return (vals.astype(np.float32) * scale_full)[: qm.n, : qm.d]
-
-
-# ---------------------------------------------------------------------------
-# Legacy block-interleaved feature basis (migration shims only)
-# ---------------------------------------------------------------------------
-#
-# Rounds 5-13 reordered kernel-eligible input rows so block membership was
-# p % nb, letting the f32 VPU-dequant kernel broadcast scales with the cheap
-# tiled pltpu.repeat (measured ~+18% on a 7B decode). The int8 MXU path made
-# that win moot — its scale product is a per-block epilogue, not a per-row
-# broadcast — so the basis (and its load-time permutes of every producer)
-# is RETIRED: the kernels below dispatch on the standard basis only, and
-# ``q40_matmul`` rejects interleaved packs outright. What remains here is
-# the migration surface: the permutation math, the legacy producers (so
-# tests can synthesize basis-era params trees), and the EXACT inverse
-# gathers (``deinterleave_*``) that move an interleaved checkpoint back to
-# the standard basis at load time (engine.weights.remove_basis_interleave).
-
-
-def interleave_window(n_pad: int) -> int | None:
-    """The packed-row window the interleave is built for: half the kernel's
-    block_n tile. None = matrix not kernel-eligible (no interleave)."""
-    bn = _largest_divisor_tile(n_pad, BLOCK_N, 512)
-    # the hi half must start on a window boundary: (n_pad/2) % W == 0
-    if bn is None or (n_pad // 2) % (bn // 2) != 0:
-        return None
-    return bn // 2
-
-
-def interleave_perm(n: int, W: int) -> np.ndarray:
-    """Permutation over a feature axis of size ``n`` (a multiple of W):
-    new position p holds original feature perm[p]."""
-    nb = W // QK
-    o = np.arange(W)
-    idx = (o % nb) * QK + o // nb  # in-window source offsets
-    base = (np.arange(n) // W) * W
-    return base + idx[np.arange(n) % W]
-
-
-def interleave_input_rows(qm: QuantizedMatrix) -> QuantizedMatrix:
-    """LEGACY producer: reorder a standard pack's input rows into the
-    interleaved basis — a pure row gather (scales unchanged); exact. The
-    runtime no longer consumes this basis; the producer is retained so
-    migration tests can synthesize basis-era packs and round-trip them
-    through :func:`deinterleave_input_rows`.
-    Returns the matrix unchanged if not kernel-eligible or already done."""
-    if qm.interleaved:
-        return qm
-    n_pad = qm.n_padded
-    W = interleave_window(n_pad)
-    if W is None:
-        return qm
-    half = n_pad // 2
-    perm = jnp.asarray(interleave_perm(half, W))
-    qs = jnp.take(jnp.asarray(qm.qs), perm, axis=0)
-    return QuantizedMatrix(
-        qs, qm.scales, qm.n_logical, qm.d_logical,
-        interleaved=True, packed_bn=2 * W,
-    )
-
-
-def deinterleave_input_rows(qm: QuantizedMatrix) -> QuantizedMatrix:
-    """The migration shim: move an interleaved pack's input rows back to
-    the standard basis — the EXACT inverse gather of
-    :func:`interleave_input_rows` (scales were never permuted, so only the
-    packed qs rows move). Standard packs pass through unchanged, so the
-    loader can apply this unconditionally to a checkpoint of unknown
-    vintage."""
-    if not qm.interleaved:
-        return qm
-    half = qm.n_padded // 2
-    perm = interleave_perm(half, qm.packed_bn // 2)
-    inv = jnp.asarray(np.argsort(perm))
-    qs = jnp.take(jnp.asarray(qm.qs), inv, axis=0)
-    return QuantizedMatrix(qs, qm.scales, qm.n_logical, qm.d_logical)
-
-
-def deinterleave_output_cols(
-    qm: QuantizedMatrix, n_consumer_logical: int, halves: int = 1
-) -> QuantizedMatrix:
-    """Inverse of :func:`interleaved_output_cols`: gather the producer's
-    output columns back to the standard feature order and restore the
-    original d padding (the consumer-basis pad positions sourced zero-scale
-    columns, and zero-scale columns are exactly what the standard pack's d
-    padding holds — so the round trip is bit-exact)."""
-    npc = _n_padded(n_consumer_logical)
-    W = interleave_window(npc)
-    if W is None or qm.d != halves * npc:
-        return qm  # never moved to the consumer basis
-    perm = interleave_perm(npc, W)
-    inv = np.argsort(perm)[:n_consumer_logical]  # drop consumer-basis pads
-    cols = np.concatenate([h * npc + inv for h in range(halves)])
-    d_orig = halves * n_consumer_logical
-    d_pad = _d_padded(d_orig)
-    qs = np.asarray(jnp.take(jnp.asarray(qm.qs), jnp.asarray(cols), axis=1))
-    scales = np.asarray(
-        jnp.take(jnp.asarray(qm.scales), jnp.asarray(cols), axis=1)
-    )
-    if d_pad != d_orig:
-        qs = np.pad(qs, ((0, 0), (0, d_pad - d_orig)))
-        scales = np.pad(scales, ((0, 0), (0, d_pad - d_orig)))
-    return QuantizedMatrix(
-        jnp.asarray(qs), jnp.asarray(scales), qm.n_logical, d_orig,
-        interleaved=qm.interleaved, packed_bn=qm.packed_bn,
-    )
-
-
-def deinterleave_vector(v, n_logical: int):
-    """Inverse of :func:`interleave_vector`: un-permute a feature vector
-    (or an embedding table's last axis) and trim the basis padding."""
-    npc = _n_padded(n_logical)
-    W = interleave_window(npc)
-    v = jnp.asarray(v)
-    if W is None or v.shape[-1] != npc:
-        return v
-    perm = interleave_perm(npc, W)
-    inv = jnp.asarray(np.argsort(perm))
-    return jnp.take(v, inv, axis=-1)[..., :n_logical]
-
-
-def interleaved_output_cols(
-    qm: QuantizedMatrix, n_consumer_logical: int, halves: int = 1
-) -> QuantizedMatrix:
-    """Permute a producer's OUTPUT columns into the consumer basis's
-    interleaved order, padding-aware: the consumer reads n_pad features, so
-    positions mapping to original features >= n_consumer_logical source a
-    zero-scale pad column (exact zeros). ``halves`` = 2 applies the same
-    per-half permutation to a fused [a|b] output (gate_up). The returned
-    d_logical grows to halves * n_pad_consumer — consumers must NOT trim."""
-    d_pad_src = qm.d_padded
-    npc = _n_padded(n_consumer_logical)
-    W = interleave_window(npc)
-    if W is None:
-        return qm
-    perm = interleave_perm(npc, W)
-    cols = np.empty(halves * npc, np.int64)
-    # a guaranteed zero-scale column for consumer-basis pad positions
-    has_pad_col = d_pad_src > qm.d
-    for h in range(halves):
-        src_base = h * n_consumer_logical
-        valid = perm < n_consumer_logical
-        if not has_pad_col and not valid.all():
-            raise ValueError(
-                "consumer basis needs pad columns but the producer has no "
-                f"zero d-padding (d={qm.d}, d_pad={d_pad_src})"
-            )
-        cols[h * npc : (h + 1) * npc] = np.where(
-            valid, src_base + perm, d_pad_src - 1
-        )
-    cols_j = jnp.asarray(cols)
-    return QuantizedMatrix(
-        jnp.take(jnp.asarray(qm.qs), cols_j, axis=1),
-        jnp.take(jnp.asarray(qm.scales), cols_j, axis=1),
-        qm.n_logical, halves * npc,
-        interleaved=qm.interleaved, packed_bn=qm.packed_bn,
-    )
-
-
-def interleave_vector(v, n_logical: int):
-    """Permute a feature vector (rmsnorm weight) or the last axis of an
-    embedding table into the interleaved basis; pads with zeros when the
-    basis is padded."""
-    npc = _n_padded(n_logical)
-    W = interleave_window(npc)
-    if W is None:
-        return v
-    perm = interleave_perm(npc, W)
-    v = jnp.asarray(v)
-    if v.shape[-1] < npc:
-        pad = [(0, 0)] * (v.ndim - 1) + [(0, npc - v.shape[-1])]
-        v = jnp.pad(v, pad)
-    return jnp.take(v, jnp.asarray(perm), axis=-1)
 
 
 def kernel_name(kind: str, role: str | None) -> str:
@@ -532,7 +338,6 @@ def q40_matmul(
     (mxu_int8 / mxu_int8_fusedq / xla_fallback) so a silent fallback to the
     slow path is visible in /metrics. ``role`` names the matrix in the
     kernel's trace name (:func:`kernel_name`)."""
-    _reject_interleaved(qm)
     tiles = _int8_tiles(qm, x.shape[0], block_n, block_d)
     if tiles is None:
         _note_path("q40_matmul", "xla_fallback")
@@ -544,7 +349,7 @@ def q40_matmul(
 
 
 # ---------------------------------------------------------------------------
-# int8 MXU path: Q40 weights × Q80 activations (ROADMAP item 1)
+# int8 MXU path: Q40 weights × Q80 activations
 # ---------------------------------------------------------------------------
 #
 # Dequantizing a weight tile to floats costs every weight element a cast, a
@@ -578,8 +383,8 @@ def q40_matmul(
 
 def quantize_q80(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Quantize activations [T, n_pad] to Q80: (int8 values [T, n_pad],
-    f32 scales [T, n_pad/32]), one scale per 32 consecutive elements —
-    the standard basis, matching the weight scales' block order directly
+    f32 scales [T, n_pad/32]), one scale per 32 consecutive elements,
+    matching the weight scales' block order directly
     (symmetric, scale = max|x|/127 — the reference's Q80 rule,
     src/quants.cpp:98-122)."""
     T = x.shape[0]
@@ -986,7 +791,6 @@ def rmsnorm_q40_matmul(
     the int8 kernel serves (noted ``mxu_int8_fusedq``); otherwise the
     unfused reference sequence into the XLA fallback. Bit-identical to the
     unfused sequence either way."""
-    _reject_interleaved(qm)
     tiles = _int8_tiles(qm, x.shape[0], block_n, block_d)
     if tiles is None:
         # the standalone rmsnorm is its own program ahead of the matmul's —

@@ -19,11 +19,9 @@ Two compute paths, chosen per batch shape inside one jitted program family:
   ``[E, Ce, D]`` send buffer, and two ``all_to_all``s move rows to expert
   owners and outputs back. Each local expert computes ONE dense
   [ep·Ce, D] matmul — no masking in the hot compute, no Tl·k sparse slots
-  (the round-4 prototype's layout). ``Ce`` follows
-  ``cfg.moe_capacity_factor``: 0 (default) sizes buckets for the drop-free
-  worst case (EXACT outputs); >0 uses the standard lossy capacity
-  semantics (``ceil(factor·Tl·k/E)``, overflow drops) — opt-in via
-  ``--moe-capacity``.
+  (the round-4 prototype's layout). ``Ce`` is the shard's own rows, the
+  drop-free worst case (a row chooses a given expert at most once), so the
+  outputs are EXACT.
 * **Dense-local (decode / tiny batches)** — every shard runs its El local
   experts on the (replicated) tokens, weights them with its slice of the
   router matrix, and a psum over ``ep`` combines. For T=1 this costs El
@@ -123,13 +121,9 @@ def _ep_dense_local(cfg, xn, lp, ep_axis: str, ep: int) -> jax.Array:
 def _ep_dispatch(cfg, xn, lp, ep_axis: str, ep: int) -> jax.Array:
     """Prefill path: sort-compacted capacity buckets + two all_to_alls
     (dispatch/combine) + one all_gather (token re-replication). Bucket
-    algebra shared with the dense bucketed prefill (models.moe). Capacity
-    follows cfg.moe_capacity_factor: 0 (default) = drop-free worst-case
-    buckets (exact), >0 = standard capacity-drop semantics."""
+    algebra shared with the dense bucketed prefill (models.moe)."""
     from distributed_llama_tpu.models.moe import (
-        MOE_BUCKETED_MIN_T,
         _expert_ffn,
-        bucket_capacity,
         bucket_combine,
         bucket_rank,
         bucket_scatter,
@@ -139,16 +133,11 @@ def _ep_dispatch(cfg, xn, lp, ep_axis: str, ep: int) -> jax.Array:
     T, D = xn.shape
     E = cfg.n_experts
     El = _n_local_experts(cfg, lp)
-    k = cfg.n_active_experts
     Tl = T // ep
     idx = jax.lax.axis_index(ep_axis)
-    # the dense path guards lossy capacity bucketing behind
-    # MOE_BUCKETED_MIN_T; apply the same guard per shard — below it the
-    # capacity estimate is noisy (drops bite hard at small Tl) and the
-    # exchange is expert-HBM-bound anyway, so fall back to the drop-free
-    # worst-case buckets (factor<=0 semantics: Ce = Tl, exact)
-    factor = cfg.moe_capacity_factor if Tl >= MOE_BUCKETED_MIN_T else 0.0
-    Ce = bucket_capacity(factor, Tl, k, E)
+    # a row chooses a given expert at most once, so a bucket of the shard's
+    # own rows can never overflow: no row is dropped
+    Ce = Tl
 
     x_local = jax.lax.dynamic_slice(xn, (idx * Tl, 0), (Tl, D))
     top_vals, top_idx = router_topk(cfg, x_local, lp["router"])  # [Tl, k]
@@ -178,7 +167,7 @@ def _ep_dispatch(cfg, xn, lp, ep_axis: str, ep: int) -> jax.Array:
     )  # [ep, El, Ce, D] -> global expert order is (owner, local) = e_global
     back = back.reshape(E, Ce, D)
 
-    # combine on the home shard: dropped choices contribute zero
+    # combine on the home shard
     out_local = bucket_combine(back, top_idx, rank, top_vals, Ce)  # [Tl, D] f32
 
     # re-replicate the token axis for the (replicated) rest of the network

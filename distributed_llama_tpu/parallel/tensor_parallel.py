@@ -358,9 +358,6 @@ class TensorParallelForward(TransferProbeMixin):
     # ------------------------------------------------------------------
 
     def shard_params(self, host_params) -> Any:
-        # (the partial block-interleaved TP basis that used to be applied
-        # here is retired — ops/q40.py legacy section; packs place in the
-        # standard basis and the int8 kernel consumes them directly)
         return place_params(host_params, self._specs, self.mesh)
 
     def _decode_jitted(self, n_steps: int, temperature: float, topp: float, topk: int):

@@ -1,7 +1,8 @@
 """Expert parallelism on the virtual CPU mesh: the dispatch/combine
-exchange against the dense (single-device) MoE path, capacity-drop
-semantics, the full engine backend (--ep) against the dense engine, and a
-micro-benchmark against the TP-sliced expert layout."""
+exchange against the dense (single-device) MoE path (the drop-free worst
+case, every row of a shard on one expert, among them), the full engine
+backend (--ep) against the dense engine, and a micro-benchmark against the
+TP-sliced expert layout."""
 
 import time
 
@@ -15,21 +16,14 @@ from distributed_llama_tpu.parallel.expert_parallel import ExpertParallelMoE
 from tests.model_utils import random_tensors, tiny_spec, write_model_file
 
 
-@pytest.fixture
-def drop_free():
-    """The engine default IS drop-free (moe_capacity_factor=0 sizes buckets
-    for the worst case); kept as an explicit marker on parity tests."""
-    yield
-
-
-def _moe_setup(E=4, k=2, T=8, D=32, H=64, seed=0, capacity=0.0):
+def _moe_setup(E=4, k=2, T=8, D=32, H=64, seed=0):
     from distributed_llama_tpu.formats.model_file import ArchType
 
     spec = tiny_spec(
         arch_type=ArchType.MIXTRAL, dim=D, hidden_dim=H, n_experts=E,
         n_active_experts=k, vocab_size=64, seq_len=32,
     )
-    cfg = config_from_spec(spec, moe_capacity_factor=capacity)
+    cfg = config_from_spec(spec)
     rng = np.random.RandomState(seed)
     xn = rng.randn(T, D).astype(np.float32)
     router = rng.randn(D, E).astype(np.float32) / np.sqrt(D)
@@ -54,21 +48,21 @@ def _dense_reference(cfg, xn, router, gate, up, down):
 
 class TestExpertParallel:
     @pytest.mark.parametrize("ep", [2, 4])
-    def test_matches_dense_moe(self, ep, drop_free):
+    def test_matches_dense_moe(self, ep):
         cfg, xn, router, gate, up, down = _moe_setup()
         want = _dense_reference(cfg, xn, router, gate, up, down)
         epm = ExpertParallelMoE(cfg, ep)
         got = np.asarray(epm(xn, router, gate, up, down))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-    def test_single_device_degenerates(self, drop_free):
+    def test_single_device_degenerates(self):
         cfg, xn, router, gate, up, down = _moe_setup(T=4)
         want = _dense_reference(cfg, xn, router, gate, up, down)
         epm = ExpertParallelMoE(cfg, 1)
         got = np.asarray(epm(xn, router, gate, up, down))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-    def test_uneven_tokens_fall_back_to_dense_local(self, drop_free):
+    def test_uneven_tokens_fall_back_to_dense_local(self):
         """T not divisible by ep cannot shard the token axis; the dense-local
         path (every shard runs its experts on all tokens + psum) must still
         produce the exact MoE output."""
@@ -78,28 +72,39 @@ class TestExpertParallel:
         got = np.asarray(epm(xn, router, gate, up, down))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-    def test_larger_expert_count(self, drop_free):
+    def test_larger_expert_count(self):
         cfg, xn, router, gate, up, down = _moe_setup(E=8, k=2, T=8, seed=3)
         want = _dense_reference(cfg, xn, router, gate, up, down)
         epm = ExpertParallelMoE(cfg, 4)
         got = np.asarray(epm(xn, router, gate, up, down))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-    def test_capacity_drop_is_bounded_and_finite(self):
-        """With an opted-in capacity factor, overloaded experts drop their
-        overflow: the output must stay finite and equal the dense reference
-        on every token whose choices all fit (here: compare only the
-        overall error bound — dropped rows zero their contribution, so the
-        EP output is a damped version of the dense one, never NaN/inf)."""
-        cfg, xn, router, gate, up, down = _moe_setup(E=4, k=2, T=16, seed=7, capacity=1.0)
-        epm = ExpertParallelMoE(cfg, 4)
-        got = np.asarray(epm(xn, router, gate, up, down))
-        assert np.all(np.isfinite(got))
-        want = _dense_reference(cfg, xn, router, gate, up, down)
-        # each token's output is a partial sum of its dense expert mix
-        assert np.max(np.abs(got)) <= np.max(np.abs(want)) * 4 + 1.0
+    @pytest.mark.parametrize("ep", [2, 4])
+    @pytest.mark.parametrize("rows_a_shard", [8, 32, 64])
+    def test_a_shard_whose_rows_all_choose_one_expert_loses_none(self, rows_a_shard, ep):
+        """The worst case the dispatch's buckets are sized for (``Ce = Tl``):
+        a router rigged so that every row of shard ``s`` puts expert ``s`` first
+        and ``s + 1`` second fills those two buckets to the last row, and
+        the exchange still gives what the dense path gives."""
+        from distributed_llama_tpu.models.moe import router_topk
 
-    def test_benchmark_vs_tp_sliced(self, capsys, drop_free):
+        E = 4
+        cfg, xn, router, gate, up, down = _moe_setup(E=E, T=rows_a_shard * ep, seed=11)
+        shard = np.arange(xn.shape[0]) // rows_a_shard
+        xn[:, :ep] = 0.0
+        xn[np.arange(xn.shape[0]), shard] = 8.0
+        for s in range(ep):
+            router[s, s % E], router[s, (s + 1) % E] = 4.0, 2.0
+        _, top_idx = router_topk(cfg, jnp.asarray(xn), jnp.asarray(router))
+        np.testing.assert_array_equal(
+            np.asarray(top_idx), np.stack([shard % E, (shard + 1) % E], axis=1)
+        )
+
+        want = _dense_reference(cfg, xn, router, gate, up, down)
+        got = np.asarray(ExpertParallelMoE(cfg, ep)(xn, router, gate, up, down))
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+    def test_benchmark_vs_tp_sliced(self, capsys):
         """Informational micro-benchmark (no assertion on timings — CPU-mesh
         wall clocks are not the TPU story): EP all-to-all routing vs the
         TP-sliced expert layout on the same 4-device mesh."""
@@ -186,21 +191,32 @@ class TestExpertParallelEngine:
         np.testing.assert_allclose(got_step, want_step, rtol=tol, atol=tol)
         return ep_engine
 
-    def test_engine_ep2_matches_dense(self, tmp_path, drop_free):
+    def test_engine_ep2_matches_dense(self, tmp_path):
         path = _mixtral_file(tmp_path)
         self._run(path, jnp.float32, 2e-4, ep=2)
 
-    def test_engine_ep2_tp2_matches_dense(self, tmp_path, drop_free):
+    def test_engine_ep2_a_prompt_of_64_rows_matches_dense(self, tmp_path):
+        """32 rows a shard through the engine's own dispatch: every row's
+        experts answer, as in the dense engine."""
+        from distributed_llama_tpu.engine import InferenceEngine
+
+        path = _mixtral_file(tmp_path, seq_len=96)
+        prompt = list(np.random.RandomState(3).randint(1, 64, 64))
+        want = InferenceEngine(path, dtype=jnp.float32).forward(prompt)
+        got = InferenceEngine(path, dtype=jnp.float32, ep=2).forward(prompt)
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+    def test_engine_ep2_tp2_matches_dense(self, tmp_path):
         path = _mixtral_file(tmp_path)
         self._run(path, jnp.float32, 2e-4, ep=2, tp=2)
 
-    def test_engine_ep2_q40(self, tmp_path, drop_free):
+    def test_engine_ep2_q40(self, tmp_path):
         """Q40 expert banks under EP: stacked QuantizedMatrix leaves sharded
         by expert must match the q40 dense engine."""
         path = _mixtral_file(tmp_path)
         self._run(path, "q40", 5e-2, ep=2)
 
-    def test_engine_ep_decode_chunks(self, tmp_path, drop_free):
+    def test_engine_ep_decode_chunks(self, tmp_path):
         """The jitted EP decode chunk (the serving fast path) agrees with
         the dense engine's greedy stream."""
         from distributed_llama_tpu.engine import InferenceEngine
@@ -233,7 +249,7 @@ class TestExpertParallelEngine:
         with pytest.raises(ValueError, match="do not compose"):
             InferenceEngine(path, dtype=jnp.float32, ep=2, sp=2)
 
-    def test_engine_ep_i8_cache(self, tmp_path, drop_free):
+    def test_engine_ep_i8_cache(self, tmp_path):
         """EP composes with the quantized KV cache (QuantizedKV halves
         replicated-over-ep, tp-sharded when composed): parity within i8
         quantization noise of the dense f32-cache engine."""

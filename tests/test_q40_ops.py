@@ -5,6 +5,8 @@ The reference validates its quant matmuls by cross-dtype tolerance checks
 against dequantize-then-matmul, and the repack is checked bit-exactly against
 the file format."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -49,6 +51,18 @@ class TestPacking:
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), qm, qm)
         assert stacked.qs.shape == (2, 32, 64)  # n=32 padded to 64, half-split
 
+    def test_a_leaf_carries_its_logical_dims_and_nothing_else(self):
+        """One basis: what rides a Q40 leaf beside its two arrays is the
+        logical (unpadded) dims, and there is no field to say otherwise."""
+        qm = quantize_q40_tpu(np.ones((96, 64), np.float32))
+        children, aux = qm.tree_flatten()
+        assert len(children) == 2 and aux == (96, 64)
+        assert [f.name for f in dataclasses.fields(QuantizedMatrix)] == [
+            "qs", "scales", "n_logical", "d_logical",
+        ]
+        with pytest.raises(TypeError, match="interleaved"):
+            QuantizedMatrix(qm.qs, qm.scales, interleaved=True)
+
 
 class TestMatmul:
     @pytest.mark.parametrize("T", [1, 8])
@@ -86,102 +100,3 @@ class TestMatmul:
         # quantization noise, not kernel error
         rel = np.abs(got - exact).max() / np.abs(exact).max()
         assert rel < 0.12, rel
-
-
-class TestInterleavedMigration:
-    """The block-interleaved activation basis is RETIRED (ops.q40 legacy
-    section): the runtime is standard-only, the legacy producers survive
-    solely so basis-era snapshots can be synthesized, and the converter
-    shims must invert them bit-exactly."""
-
-    def _pair(self, n=512, d=256, seed=5):
-        from distributed_llama_tpu.ops.q40 import interleave_input_rows
-
-        rng = np.random.RandomState(seed)
-        w = rng.randn(n, d).astype(np.float32) / np.sqrt(n)
-        qm = quantize_q40_tpu(w)
-        qi = interleave_input_rows(qm)
-        assert qi.interleaved and qi.packed_bn > 0
-        return qm, qi
-
-    def test_retired_basis_rejected_at_every_entry_point(self):
-        """An interleaved pack reaching the runtime is a migration bug, not
-        a layout to dispatch on — dequantize and both matmul entry points
-        must fail loudly instead of silently misreading the row order."""
-        from distributed_llama_tpu.ops.q40 import rmsnorm_q40_matmul
-
-        qm, qi = self._pair()
-        x = jnp.ones((1, qm.n_padded), jnp.float32)
-        with pytest.raises(ValueError, match="interleav"):
-            dequantize_tpu(qi)
-        with pytest.raises(ValueError, match="interleav"):
-            q40_matmul(x, qi, interpret=True)
-        with pytest.raises(ValueError, match="interleav"):
-            rmsnorm_q40_matmul(
-                x[:, : qm.n], jnp.ones((qm.n,), jnp.float32), qi, interpret=True
-            )
-
-    def test_input_row_round_trip_bit_exact(self):
-        from distributed_llama_tpu.ops.q40 import deinterleave_input_rows
-
-        qm, qi = self._pair()
-        back = deinterleave_input_rows(qi)
-        assert not back.interleaved
-        np.testing.assert_array_equal(np.asarray(back.qs), np.asarray(qm.qs))
-        np.testing.assert_array_equal(np.asarray(back.scales), np.asarray(qm.scales))
-        np.testing.assert_array_equal(
-            np.asarray(dequantize_tpu(back)), np.asarray(dequantize_tpu(qm))
-        )
-
-    def test_output_col_round_trip_bit_exact(self):
-        """gate_up's consumer-basis column permutation (halves=2, padded
-        consumer dims — the hardest case) must invert exactly, restoring
-        the original zero d-padding."""
-        from distributed_llama_tpu.ops.q40 import (
-            deinterleave_output_cols,
-            interleaved_output_cols,
-        )
-
-        rng = np.random.RandomState(9)
-        F = 544  # pads to 1024 -> basis has interspersed pad positions
-        qm = quantize_q40_tpu(rng.randn(512, 2 * F).astype(np.float32) / 16)
-        qo = interleaved_output_cols(qm, F, halves=2)
-        back = deinterleave_output_cols(qo, F, halves=2)
-        assert back.d == qm.d and back.d_padded == qm.d_padded
-        np.testing.assert_array_equal(np.asarray(back.qs), np.asarray(qm.qs))
-        np.testing.assert_array_equal(np.asarray(back.scales), np.asarray(qm.scales))
-
-    def test_vector_round_trip_bit_exact(self):
-        from distributed_llama_tpu.ops.q40 import deinterleave_vector, interleave_vector
-
-        rng = np.random.RandomState(11)
-        v = jnp.asarray(rng.randn(512).astype(np.float32))
-        vi = interleave_vector(v, 512)
-        np.testing.assert_array_equal(
-            np.asarray(deinterleave_vector(vi, 512)), np.asarray(v)
-        )
-
-    def test_output_cols_pad_positions_are_zero(self):
-        """interleaved_output_cols on a padded consumer basis must emit
-        exact zeros at the interspersed pad positions (they feed silu/mul
-        and the next matmul's zero-scale rows)."""
-        from distributed_llama_tpu.ops.q40 import (
-            interleave_perm,
-            interleave_window,
-            interleaved_output_cols,
-        )
-        from distributed_llama_tpu.ops.q40 import _n_padded
-
-        rng = np.random.RandomState(9)
-        F = 544  # pads to 1024 -> basis has interspersed pad positions
-        npc = _n_padded(F)
-        w = rng.randn(512, 2 * F).astype(np.float32) / 16  # fused [a|b]
-        qm = quantize_q40_tpu(w)
-        qo = interleaved_output_cols(qm, F, halves=2)
-        assert qo.d == 2 * npc
-        deq = dequantize_tpu(qo)  # columns in the consumer basis
-        perm = interleave_perm(npc, interleave_window(npc))
-        pad_cols = np.concatenate([
-            np.nonzero(perm >= F)[0], npc + np.nonzero(perm >= F)[0]
-        ])
-        assert np.all(deq[:, pad_cols] == 0.0)
