@@ -108,6 +108,7 @@ from distributed_llama_tpu.engine.engine import TokenStats, _prefill_bucket, nex
 from distributed_llama_tpu.engine.speculative import PromptLookupDrafter
 from distributed_llama_tpu.models import llama
 from distributed_llama_tpu.models.config import LlamaConfig
+from distributed_llama_tpu.models.moe import held_bucket_rows
 from distributed_llama_tpu.ops import kv_cache as kvc
 from distributed_llama_tpu.telemetry import Stopwatch, device_ledger, flight
 
@@ -1622,15 +1623,31 @@ class BatchScheduler:
         if moe is not None and self.engine._tel.enabled:
             self._moe_pending.append((moe, n_tokens))
 
+    def _routing_layers(self) -> int:
+        """The layers that route: not an arch's leading dense ones."""
+        cfg = self.engine.cfg
+        return cfg.n_layers - min(cfg.first_dense, cfg.n_layers)
+
     def _count_moe(self, held: int, tokens: int, forwards: int) -> None:
         """``tokens`` tokens made ``held`` of their expert choices, over all
         layers, on experts held here, in ``forwards`` forward steps."""
         cfg, tel = self.engine.cfg, self.engine._tel
-        # the layers that route: not an arch's leading dense ones
-        layers = cfg.n_layers - min(cfg.first_dense, cfg.n_layers)
+        layers = self._routing_layers()
         tel.moe_assigned_held.inc(held)
         tel.moe_assigned_absent.inc(tokens * layers * cfg.n_active_experts - held)
         tel.moe_rows_per_expert.observe(held / (forwards * layers * cfg.n_experts))
+
+    def _count_expert_rows(self, phase: str, chosen: int, every_row: int, layers: int, rows: int) -> None:
+        """``dllama_moe_expert_rows_total``: of ``layers`` expert layers of
+        programs of ``rows`` rows, in which ``chosen`` rows in all chose a held
+        expert, ``every_row`` ran every held expert over every row of the
+        program and the rest each expert over the rows that chose it. The
+        step's own counts: the chosen rows come summed over the layers, so
+        the bucketed layers are given their even part of them."""
+        tel, held = self.engine._tel, self.engine.cfg.n_experts
+        tel.moe_rows_chosen[phase].inc(chosen)
+        tel.moe_rows_computed[phase].inc(
+            every_row * held * rows + chosen * (layers - every_row) / max(layers, 1))
 
     def _count_prefill_moe(self, wait: bool) -> None:
         """Count what the expert layers of the prefill chunks dispatched so
@@ -1663,6 +1680,10 @@ class BatchScheduler:
                 continue
             if cfg.n_routed_experts:
                 self._count_moe(held, n_tokens, 1)
+                # the rows of the padded program the piece ran as (but for a piece cut to its
+                # tokens at the context's very end)
+                self._count_expert_rows("piece", held, every_row, every_row + bucketed,
+                                        _prefill_bucket(n_tokens))
             tel.moe_piece_every_row.inc(every_row)
             tel.moe_piece_bucketed.inc(bucketed)
 
@@ -2989,6 +3010,13 @@ class BatchScheduler:
                 held = extra.pop(0)
                 if tel.enabled:
                     self._count_moe(int(held.sum()), n_active * self.chunk, self.chunk)
+                    # a decode step does not say which arm it took: it is the every-row
+                    # arm for certain where the bucket is the whole step (models.moe.
+                    # _held_experts), else it is counted as the bucket that fits
+                    layers = self._routing_layers() * self.chunk
+                    whole = held_bucket_rows(engine.cfg, bucket) >= bucket
+                    self._count_expert_rows("decode", int(held.sum()), layers if whole else 0,
+                                            layers, bucket)
                     self._count_prefill_moe(wait=True)
             elif engine.cfg.is_moe and tel.enabled:
                 # every expert held: nothing is read here but the layers'

@@ -906,6 +906,9 @@ class InferenceEngine:
             reader, self.cfg, dtype=dtype, tp=tp, mesh=mesh
         )
         reader.close()
+        if quantized and self._tel.enabled:
+            for role, nbytes in weights_lib.q40_padded_bytes(host_params).items():
+                self._tel.q40_padded_weight_bytes.labels(role=role).set(nbytes)
         if self._tp_engine is not None:
             self.params = self._tp_engine.shard_params(host_params)
             self._forward = self._tp_engine.forward

@@ -10,6 +10,7 @@ stacked on a leading axis for ``lax.scan``, and the result is `device_put`
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -356,13 +357,14 @@ def load_params(
 
     def held_experts(p: str) -> dict:
         """The expert half of a layer that holds a share: the router (float32
-        at its published width) and its selection bias, the held experts as
-        one bank of fused gate|up and one of down, the shared expert as a
-        dense SwiGLU."""
+        at its published width) and, of a sigmoid router, its selection bias,
+        the held experts as one bank of fused gate|up and one of down, the
+        shared expert as a dense SwiGLU."""
         from distributed_llama_tpu.ops.q40 import stack_bank
 
-        lp = {"router": _t(reader.tensor(p + "moe_router"), np.float32),
-              "router_bias": f32(p + "router_bias")}
+        lp = {"router": _t(reader.tensor(p + "moe_router"), np.float32)}
+        if p + "router_bias" in reader.entries:
+            lp["router_bias"] = f32(p + "router_bias")
         held = [f"{p}experts.{e}." for e in range(cfg.n_experts)]
         bank = stack_bank if quantized else np.stack
         lp["experts_gate_up"] = bank([fused([ep + "gate", ep + "up"]) for ep in held])
@@ -430,7 +432,8 @@ def load_params(
         as published, which the Q40 kernel pads to 9 tiles of 1024: one launch
         with 8 % of its columns zero, against three launches with more padding
         each if it were split) or q|k|v of a softmax layer as one matrix, and a
-        dense SwiGLU; the recurrence's vectors stay float32."""
+        dense SwiGLU or, in a file with experts, the held experts' leaves behind
+        either mixer; the recurrence's vectors stay float32."""
         p = f"layers.{l}."
         if cfg.is_softmax_layer(l):
             lp = {"qkv": fused([p + "q", p + "k", p + "v"])}
@@ -438,9 +441,12 @@ def load_params(
             lp = {"ssm_in": weight(p + "ssm_in")}
             lp.update({k: f32(p + k) for k in
                        ("conv", "conv_bias", "dt_bias", "a_log", "ssm_d", "ssm_norm")})
-        lp.update({"wo": weight(p + "wo"), "gate_up": fused([p + "gate", p + "up"]),
-                   "down": weight(p + "down"),
-                   "rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")})
+        lp["wo"] = weight(p + "wo")
+        if cfg.layer_kind(l)[1] == "experts":
+            lp.update(held_experts(p))
+        else:
+            lp.update({"gate_up": fused([p + "gate", p + "up"]), "down": weight(p + "down")})
+        lp.update({"rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")})
         return lp
 
     def next_token_head():
@@ -637,6 +643,33 @@ def _synthetic_params(
         "wcls": mat(D, V),
         "rope_table": rope_table,
     }
+
+
+def q40_padded_bytes(params: Params) -> dict[str, int]:
+    """Bytes of the Q40 leaves of ``params`` that are tile padding, by the
+    leaf's name (``dllama_q40_padded_weight_bytes{role}``): a pack's nibbles
+    and scales hold ``n_padded x d_padded`` weights of which ``n x d`` are the
+    matrix's (``ops.q40._n_padded`` / ``_d_padded``); the rest are zero-scale
+    rows and columns the kernel's tiles read like any. Every Q40 leaf's name
+    is in the result, 0 where its matrices divide their tiles."""
+    from distributed_llama_tpu.ops.q40 import QuantizedMatrix
+
+    out: dict[str, int] = {}
+
+    def walk(name: str, node) -> None:
+        if isinstance(node, QuantizedMatrix):
+            held = math.prod(node.qs.shape) + 4 * math.prod(node.scales.shape)
+            used = node.n * node.d / (node.n_padded * node.d_padded)
+            out[name] = out.get(name, 0) + round(held * (1.0 - used))
+        elif isinstance(node, dict):
+            for key, value in node.items():
+                walk(key, value)
+        elif isinstance(node, (list, tuple)):
+            for value in node:
+                walk(name, value)
+
+    walk("", params)
+    return out
 
 
 def random_params(

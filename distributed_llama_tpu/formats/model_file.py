@@ -84,17 +84,26 @@ mixes state-space layers (Mamba-2's SSD recurrence: ``ssm_heads`` heads of
 ``ssm_head_dim`` values, a state of ``ssm_state`` values a head value, ONE
 input and one output projection of the state for all heads) with softmax
 layers that do not rotate (layer l is a softmax layer where ``l % attn_period
-== attn_offset``); every layer has a dense SwiGLU; four multipliers (of the
-embedding, of every block's output before the residual add, of the attention
-scores, and a divisor of the logits) ride the header in millionths; its files
-carry the keys of ``_ARCH_KEYS[ArchType.GRANITE_HYBRID]``:
+== attn_offset``); every layer's feed-forward is a dense SwiGLU (the dense
+members: ``n_experts`` 0) or, where the header carries the expert keys, a
+softmax router over ``n_routed_experts`` of which this file HOLDS
+``n_experts`` (``first_expert`` onwards) beside a shared expert, with no
+selection bias; four multipliers (of the embedding, of every block's output
+before the residual add, of the attention scores, and a divisor of the
+logits) ride the header in millionths, the attention scores' in billionths
+where it is no whole millionth; its files carry the keys of
+``_ARCH_KEYS[ArchType.GRANITE_HYBRID]`` and, of
+``_ARCH_OPTIONAL_KEYS[ArchType.GRANITE_HYBRID]``, those that are not zero:
 
   state-space layer: ssm_in [2*Hs*P + 2*N + Hs, dim] (rows: the gate z, then
     x|B|C which the convolution reads, then dt), conv (F32) [Hs*P + 2*N, taps],
     conv_bias (F32) [Hs*P + 2*N], dt_bias, a_log, ssm_d (F32) [Hs], ssm_norm
     (F32) [Hs*P], wo [dim, Hs*P]
   softmax layer: q [H*hd, dim], k, v [K*hd, dim], wo [dim, H*hd]
-  every layer: gate, down, up (width hidden_dim), rms_att, rms_ffn
+  every layer: gate, down, up (width hidden_dim) or, in a file with experts,
+    moe_router [n_routed, dim], per HELD expert: up, gate [moe_hidden, dim],
+    down [dim, moe_hidden], shared.up, shared.gate, shared.down (width
+    n_shared * moe_hidden); then rms_att, rms_ffn
 
 All matrices are row-major [d_out, d_in] — a matmul computes y = W @ x.
 Q/K projections are stored pre-permuted for interleaved-pair rope
@@ -138,9 +147,9 @@ class ArchType(enum.IntEnum):
     # a reference arch
     GLM4_MOE_LITE = 0xABCD06
     # state-space (Mamba-2 SSD) layers with a softmax layer at one index of
-    # every period, no rotation, a dense SwiGLU in every layer, multipliers on
-    # embedding, residual branches, attention scores and logits; not a
-    # reference arch
+    # every period, no rotation, in every layer a dense SwiGLU or a share of
+    # the routed experts beside a shared one, multipliers on embedding,
+    # residual branches, attention scores and logits; not a reference arch
     GRANITE_HYBRID = 0xABCD07
 
 
@@ -213,6 +222,7 @@ class HeaderKey(enum.IntEnum):
     RESIDUAL_SCALE_MICRO = 46  # a block's output is, before it is added to the stream
     ATTN_SCALE_MICRO = 47  # the softmax scale, where it is not head_size ** -0.5
     LOGITS_DIVISOR_MICRO = 48  # the logits are divided by this / 1e6
+    ATTN_SCALE_NANO = 49  # the softmax scale in billionths, where it is no whole millionth
 
 
 class ArchFlags(enum.IntFlag):
@@ -286,11 +296,23 @@ _SSM_KEYS = {
     HeaderKey.ATTN_SCALE_MICRO: "attn_scale_micro",
     HeaderKey.LOGITS_DIVISOR_MICRO: "logits_divisor_micro",
 }
+# a state-space arch's expert tail (the keys the other share-holding archs' files use) and
+# its softmax scale where ATTN_SCALE_MICRO cannot state it (1/128 is 7812.5 millionths)
+_SSM_OPTIONAL_KEYS = {
+    HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim",
+    HeaderKey.N_SHARED_EXPERTS: "n_shared_experts",
+    HeaderKey.N_ROUTED_EXPERTS: "n_routed_experts",
+    HeaderKey.FIRST_EXPERT: "first_expert",
+    HeaderKey.ATTN_SCALE_NANO: "attn_scale_nano",
+}
 # the keys past ROPE_TYPE an arch's files carry, in the order they are written;
 # an arch that is not here writes none of them
 _ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KEYS,
               ArchType.EVABYTE: _EVA_KEYS, ArchType.GLM4_MOE_LITE: _LATENT_KEYS,
               ArchType.GRANITE_HYBRID: _SSM_KEYS}
+# ... and behind them the keys a file carries only where its value is not zero, so that a
+# file that states none of them (a dense member of the arch) is byte for byte what it was
+_ARCH_OPTIONAL_KEYS = {ArchType.GRANITE_HYBRID: _SSM_OPTIONAL_KEYS}
 
 
 @dataclasses.dataclass
@@ -351,6 +373,7 @@ class ModelSpec:
     residual_scale_micro: int = 0
     attn_scale_micro: int = 0
     logits_divisor_micro: int = 0
+    attn_scale_nano: int = 0
 
     @property
     def head_size(self) -> int:
@@ -408,6 +431,8 @@ def _header_pairs(spec: ModelSpec) -> list[tuple[int, int]]:
             (HeaderKey.ROPE_SCALING_ORIG_MAX_SEQ_LEN, spec.rope_scaling_orig_max_seq_len),
         ]
     pairs += [(key, getattr(spec, name)) for key, name in _ARCH_KEYS.get(spec.arch_type, {}).items()]
+    pairs += [(key, getattr(spec, name))
+              for key, name in _ARCH_OPTIONAL_KEYS.get(spec.arch_type, {}).items() if getattr(spec, name)]
     return pairs
 
 
@@ -488,6 +513,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
                 **_EVA_KEYS,
                 **_LATENT_KEYS,
                 **_SSM_KEYS,
+                **_SSM_OPTIONAL_KEYS,
             }
             for i in range(0, n_ints, 2):
                 key, value = raw[i], raw[i + 1]
@@ -649,11 +675,13 @@ def _hybrid_layer(spec: ModelSpec, l: int, add) -> None:
 
 
 def _held_experts(spec: ModelSpec, p: str, add) -> None:
-    """Router, selection bias, the HELD experts and the shared one of an
-    expert layer that holds a share (the module docstring's lists)."""
+    """Router, selection bias (a sigmoid router's: a softmax router chooses by
+    its scores alone), the HELD experts and the shared one of an expert layer
+    that holds a share (the module docstring's lists)."""
     wt, dim, width = spec.weights_float_type, spec.dim, spec.moe_hidden_dim
     add(p + "moe_router", (spec.n_routed_experts, dim), wt)
-    add(p + "router_bias", (spec.n_routed_experts,), FloatType.F32)
+    if spec.flags & ArchFlags.SIGMOID_ROUTER:
+        add(p + "router_bias", (spec.n_routed_experts,), FloatType.F32)
     for e in range(spec.n_experts):
         ep = f"{p}experts.{e}."
         add(ep + "up", (width, dim), wt)
@@ -713,7 +741,8 @@ def _latent_layer(spec: ModelSpec, l: int, add) -> None:
 
 def _ssm_layer(spec: ModelSpec, l: int, add) -> None:
     """One ``ArchType.GRANITE_HYBRID`` layer's tensors (the module docstring's
-    list): a state-space or a softmax mixer, then a dense SwiGLU."""
+    list): a state-space or a softmax mixer, then a dense SwiGLU or, where the
+    one table says ``experts``, the router, the held experts and the shared one."""
     wt, f32, dim, hidden = spec.weights_float_type, FloatType.F32, spec.dim, spec.hidden_dim
     p = f"layers.{l}."
     if is_softmax_layer(spec, l):
@@ -733,9 +762,12 @@ def _ssm_layer(spec: ModelSpec, l: int, add) -> None:
         add(p + "ssm_d", (spec.ssm_heads,), f32)
         add(p + "ssm_norm", (inner,), f32)
         add(p + "wo", (dim, inner), wt)
-    add(p + "gate", (hidden, dim), wt)
-    add(p + "down", (dim, hidden), wt)
-    add(p + "up", (hidden, dim), wt)
+    if layer_kind(spec, l)[1] == "experts":
+        _held_experts(spec, p, add)
+    else:
+        add(p + "gate", (hidden, dim), wt)
+        add(p + "down", (dim, hidden), wt)
+        add(p + "up", (hidden, dim), wt)
     add(p + "rms_att", (dim,), f32)
     add(p + "rms_ffn", (dim,), f32)
 

@@ -107,7 +107,10 @@ class LlamaConfig:
     # The four multipliers: ``embed_scale`` on the embedding row,
     # ``residual_scale`` on a block's output before it joins the stream,
     # ``attn_scale`` the softmax scale where it is not head_size ** -0.5 (0:
-    # it is), ``logits_divisor`` under the logits
+    # it is), ``logits_divisor`` under the logits. A file of this arch whose
+    # header carries the expert keys has, in every layer, a softmax router
+    # over ``n_routed_experts`` with ``n_experts`` of them held and a shared
+    # expert where the dense members have their SwiGLU
     attn_offset: int = 0
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -383,7 +386,8 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         ssm_state=spec.ssm_state,
         embed_scale=_micro(spec.embed_scale_micro, 1.0),
         residual_scale=_micro(spec.residual_scale_micro, 1.0),
-        attn_scale=_micro(spec.attn_scale_micro, 0.0),
+        attn_scale=(spec.attn_scale_nano / 1e9 if spec.attn_scale_nano
+                    else _micro(spec.attn_scale_micro, 0.0)),
         logits_divisor=_micro(spec.logits_divisor_micro, 1.0),
         **overrides,
     )

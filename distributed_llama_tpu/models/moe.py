@@ -1,9 +1,15 @@
-"""Mixture-of-experts FFN (Mixtral, Grok-1).
+"""Mixture-of-experts FFN: every expert held (Mixtral, Grok-1; GLM-4.7-Flash's
+64 beside a shared one), or a SHARE of the routed experts held beside a
+shared expert (:func:`_moe_share`: Solar-Open2, K-EXAONE, Granite-4.0-H-Small,
+whose router runs over its whole published width and whose absent experts'
+part is left out), behind softmax, window, linear, latent or state-space
+mixers alike.
 
 Parity with the reference's MoE task chain (reference:
 src/grok1-tasks.cpp:56-263, composed into Mixtral at
-src/mixtral-tasks.cpp:25-44): router matmul → softmax → top-k →
-renormalized weights → per-expert SwiGLU → weighted sum of expert downs.
+src/mixtral-tasks.cpp:25-44): router matmul → softmax (or sigmoid, by the
+file's flags) → top-k → renormalized weights → per-expert SwiGLU → weighted
+sum of expert downs.
 
 TPU-first design notes:
 * The reference routes on the root with scalar code and broadcasts indexes
@@ -357,16 +363,23 @@ def held_bucket_rows(cfg: LlamaConfig, rows: int) -> int:
     experts per token over its router's width), so a step of ``rows`` gives an
     expert ``rows * k / routed`` of them when routing is even; the bucket is
     four times that, reckoned for the largest step of its class (64 rows: the
-    decode steps and small pieces; 256: a prefill chunk), as a power of two
-    and at least 8. At 8 of 320 that is 8 and 32 (a chunk gives an expert 6.4
-    rows, s.d. 2.5), at 8 of 128 16 and 64. A step in which some expert
-    overflows its bucket takes the every-row path instead (exact either way)."""
+    decode steps and small pieces; 256: a prefill chunk), as a power of two,
+    at least 8 and at most HALF the class's rows. At 8 of 320 that is 8 and 32
+    (a chunk gives an expert 6.4 rows, s.d. 2.5), at 8 of 128 and 4 of 64 16
+    and 64. Where a token chooses a seventh of the experts (10 of 72) four
+    times the share is the whole step (36 -> 64 of 64, 142 -> 256 of 256), a
+    bucket of every row that is no bucket: the cap leaves it 32 and 128, twice
+    the share as a power of two, so that a 256-row piece computes the rows
+    that chose an expert and not 7.2 times as many. A step in which some
+    expert overflows its bucket takes the every-row path instead (exact
+    either way)."""
     import math
 
     from distributed_llama_tpu.models.config import next_pow2
 
-    expected = (64 if rows <= 64 else 256) * cfg.n_active_experts / cfg.router_width
-    return max(8, next_pow2(math.ceil(4 * expected)))
+    largest = 64 if rows <= 64 else 256
+    expected = largest * cfg.n_active_experts / cfg.router_width
+    return max(8, min(next_pow2(math.ceil(4 * expected)), largest // 2))
 
 
 def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) -> jax.Array:
@@ -522,14 +535,16 @@ def moe_block(
     ep_axis: str | None = None, n_real: jax.Array | None = None,
 ) -> jax.Array:
     """The FFN half of a MoE block, *after* the attention residual has been
-    applied by the caller. Handles the Mixtral-vs-Grok norm placement."""
-    from distributed_llama_tpu.models.llama import rmsnorm
+    applied by the caller. Handles the Mixtral-vs-Grok norm placement; the
+    experts' sum joins the stream as every block's output does (times the
+    file's residual multiplier, where it states one)."""
+    from distributed_llama_tpu.models.llama import _branch, rmsnorm
 
     if cfg.arch == ArchType.GROK1:
         xn = rmsnorm(x, lp["rms_moe"])
         out = moe_ffn(cfg, xn, lp, axis_name, ep_axis=ep_axis, n_real=n_real)
         return x + rmsnorm(out.astype(x.dtype), lp["rms_ffn2"])
     xn = rmsnorm(x, lp["rms_ffn"])
-    return x + moe_ffn(
-        cfg, xn, lp, axis_name, ep_axis=ep_axis, n_real=n_real
+    return x + _branch(
+        cfg, moe_ffn(cfg, xn, lp, axis_name, ep_axis=ep_axis, n_real=n_real)
     ).astype(x.dtype)

@@ -354,6 +354,33 @@ class EngineInstruments:
         )
         self.moe_piece_bucketed = moe_piece_layers.labels(path="bucketed")
         self.moe_piece_every_row = moe_piece_layers.labels(path="every_row")
+        moe_expert_rows = counter(
+            "dllama_moe_expert_rows_total",
+            "Rows the HELD experts of an arch that holds a share multiplied "
+            "(rows=computed) against rows that chose them (rows=chosen), "
+            "summed over layers and held experts, from the programs' own "
+            "counts: a layer on a bucketed arm that fits computes the rows "
+            "that chose (1.0), a layer on the every-row arm every row of the "
+            "program in every held expert (routed / k times the chosen when "
+            "routing is even). phase=piece the prompt pieces (their arm comes "
+            "back with their results), phase=decode the decode chunks (every "
+            "row where the bucket is the whole step; a step that overflows a "
+            "smaller bucket is counted as if it fit)",
+            labelnames=("rows", "phase"),
+        )
+        self.moe_rows_computed = {p: moe_expert_rows.labels(rows="computed", phase=p)
+                                  for p in ("decode", "piece")}
+        self.moe_rows_chosen = {p: moe_expert_rows.labels(rows="chosen", phase=p)
+                                for p in ("decode", "piece")}
+        self.q40_padded_weight_bytes = gauge(
+            "dllama_q40_padded_weight_bytes",
+            "Bytes of the resident Q40 leaves of one role (the params leaf's "
+            "name: ssm_in, experts_down, wcls, ...) that are tile padding: "
+            "zero-scale rows and columns the int8 kernel's tiles read and "
+            "multiply like any, set at load; 0 for a role whose matrices "
+            "divide their tiles",
+            labelnames=("role",),
+        )
         self.recurrent_state_bytes = gauge(
             "dllama_recurrent_state_bytes",
             "Bytes of recurrent state and convolution tails the slab's rows "

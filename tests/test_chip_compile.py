@@ -733,6 +733,144 @@ def test_served_state_space_decode_chunk_forms_nothing_of_a_leafs_size(one_chip,
     assert "ssd_step" in text and "slab_decode_scan" in text
 
 
+def _granite_moe_program_shapes(one_chip, rows: int):
+    """(cfg, params, slab, pool, s) of ``granite-4.0-h-small.batch_prompted``
+    as shapes on the described chip: Granite-4.0-H-Small's published widths,
+    its leading period of ten layers (state-space but for index 5), 18 of 72
+    experts held in every layer beside a shared one, a quarter of the
+    vocabulary, ``rows`` rows of 2048 positions, bf16 keys and values,
+    float32 state."""
+    layers, held = 10, 18
+    cfg = LlamaConfig(
+        arch=ArchType.GRANITE_HYBRID, dim=4096, hidden_dim=1536, n_layers=layers, n_heads=32,
+        n_kv_heads=8, vocab_size=25088, seq_len=2048, head_size=128, kv_dim=1024, attn_period=10,
+        attn_offset=5, lin_conv=4, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+        n_experts=held, n_active_experts=10, moe_hidden_dim=768, n_shared_experts=2,
+        n_routed_experts=72, first_expert=0,
+        embed_scale=12.0, residual_scale=0.22, attn_scale=0.0078125, logits_divisor=16.0,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def bank(n, d):
+        one = _qm_shape(n, d, one_chip)
+        return q40.QuantizedMatrix(s((held,) + one.qs.shape, jnp.uint8),
+                                   s((held,) + one.scales.shape, jnp.float32), n, d)
+
+    f32 = lambda *shape: s(shape, jnp.float32)
+    tail = dict(router=f32(4096, 72), experts_gate_up=bank(4096, 1536), experts_down=bank(768, 4096),
+                shared_gate_up=_qm_shape(4096, 3072, one_chip), shared_down=_qm_shape(1536, 4096, one_chip),
+                rms_att=f32(4096), rms_ffn=f32(4096))
+    ssm = dict(ssm_in=_qm_shape(4096, 16768, one_chip), conv=f32(8448, 4), conv_bias=f32(8448),
+               dt_bias=f32(128), a_log=f32(128), ssm_d=f32(128), ssm_norm=f32(8192),
+               wo=_qm_shape(8192, 4096, one_chip), **tail)
+    softmax = dict(qkv=_qm_shape(4096, 6144, one_chip), wo=_qm_shape(4096, 4096, one_chip), **tail)
+    params = dict(
+        embedding=f32(25088, 4096),
+        layers=[softmax if cfg.layer_kind(l)[0] == "full" else ssm for l in range(layers)],
+        rms_final=f32(4096), rope_table=f32(2048, 64, 2), wcls=_qm_shape(4096, 25088, one_chip),
+    )
+    placed = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    slab = placed(jax.eval_shape(lambda: llama.init_batch_cache(cfg, rows, dtype=jnp.bfloat16)))
+    pool = placed(jax.eval_shape(
+        lambda: llama.init_page_pool(cfg, SERVED_PAGES, SERVED_PAGE, dtype=jnp.bfloat16)))
+    return cfg, params, slab, pool, s
+
+
+def _kernel_paths():
+    """{(kernel, path): dispatch decisions counted so far} of ``dllama_kernel_path_total``."""
+    from distributed_llama_tpu import telemetry
+
+    counter = telemetry.REGISTRY.get("dllama_kernel_path_total")
+    return {key: child.value for key, child in counter._children.items()} if counter else {}
+
+
+@pytest.mark.parametrize("program", ["the 32-row decode chunk", "a 256-row piece"])
+def test_served_granite_experts_programs_form_nothing_of_a_leafs_size(one_chip, monkeypatch, program):
+    """The two big programs of ``granite-4.0-h-small.batch_prompted`` at the
+    published widths (the leading period, 18 of 72 experts held): the state
+    ``[64, 128, 128]`` a row and layer (twice H-Micro's) is stepped in place
+    and a piece takes ONE row's out and puts it back; the softmax layer's keys
+    and values, heads of 128, are written in place; nothing else of either
+    leaf's size forms. The 768-wide ``down`` bank goes through the grouped int8
+    kernel (its contraction padded to the 1024 of one input tile, which the
+    gauge ``dllama_q40_padded_weight_bytes`` counts), asserted by the
+    dispatch counter and by the launch's name, not left to a silent
+    fallback. The programs' temporaries are stated (the cell's memory:
+    PERF.md section 4)."""
+    from distributed_llama_tpu import telemetry
+    from distributed_llama_tpu.engine import batch
+    from distributed_llama_tpu.models import moe
+    from distributed_llama_tpu.ops import ssd
+
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    monkeypatch.setattr(ssd, "_interpret_default", lambda: False)
+    monkeypatch.setattr(decode_attention, "_interpret_default", lambda: False)
+    rows = 32
+    cfg, params, slab, pool, s = _granite_moe_program_shapes(one_chip, rows)
+    assert slab[0]["S"].shape == (rows, 64, 128, 128) and slab[5].shape == (2, rows, 2048, 8, 128)
+    assert cfg.kv_head_pack == 1 and cfg.softmax_scale == 1 / 128
+    assert (moe.held_bucket_rows(cfg, 32), moe.held_bucket_rows(cfg, 256)) == (32, 128)
+    was_on = telemetry.is_enabled()
+    telemetry.enable()
+    try:
+        before = _kernel_paths()
+        if program == "the 32-row decode chunk":
+            compiled = sampling.decode_chunk_batched.lower(
+                cfg, params, s((rows,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_),
+                32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+                s((rows,), jnp.uint32)).compile()
+        else:
+            compiled = batch._slab_prefill_single_paged.lower(
+                cfg, params, s((256,), jnp.int32), slab, pool, s((), jnp.int32), s((), jnp.int32),
+                s((), jnp.int32), s((2048 // SERVED_PAGE,), jnp.int32), s((), jnp.int32),
+            ).compile()
+        after = _kernel_paths()
+    finally:
+        if not was_on:
+            telemetry.disable()
+    moved = {key: after[key] - before.get(key, 0) for key in after if after[key] != before.get(key, 0)}
+    # the grouped launches took the int8 kernel: one decision for each bank's shape at this
+    # program's rows (the launch is jitted: the layers after the first reuse its trace), the
+    # 768-wide down bank's among them
+    assert moved.get(("q40_grouped_matmul", "mxu_int8"), 0) >= 2, moved
+    assert ("q40_grouped_matmul", "xla_fallback") not in moved, moved
+    text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"[granite-4.0-h-small] {program}: temporaries {temp / 1e6:.1f} MB")
+    if program == "the 32-row decode chunk":
+        # the every-row arm for certain (the bucket is the whole step): [18, 32, columns]
+        for width in (2048, 4096):
+            assert re.search(rf"f32\[18,32,{width}\]\S* custom-call\(.*q40_int8_grouped_held_experts_t32", text), width
+        writes, others = _slab_sized_results(text, slab[0]["S"].size // 2, "f32")
+        steps = set(re.findall(r"%(ssd_step[\w.]*) = [^\n]*output_to_operand_aliasing", text))
+        assert len(steps) == 9 and 7 <= len(writes) <= 9 and all("%ssd_step" in w for w in writes), writes
+        assert not others, "state-sized buffers besides the in-place step:\n" + "\n".join(others)
+        writes, others = _slab_sized_results(text, slab[5].size // 2)
+        assert len(writes) == 1, writes
+        assert not others, "slab-sized buffers besides the cache write:\n" + "\n".join(others)
+        assert "slab_decode_scan" in text and temp < 1.5e9
+    else:
+        # ONE conditional a layer: the bucket of 128 rows, the every-row arm behind it
+        assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
+        for arm_rows in (128, 256):
+            assert re.search(rf"f32\[18,{arm_rows},4096\]\S* custom-call\(.*q40_int8_grouped_held_experts_t256",
+                             text), arm_rows
+        assert "%ssd_chunk" in text
+        # a row's leaf is taken out and put back: nothing of the WHOLE leaf's size but the
+        # in-place writes
+        # (the softmax layer's scores, 256 queries x 32 heads x 2048 positions, are float32 of
+        # half a state leaf's size and no copy of one)
+        writes, others = _slab_sized_results(text, slab[0]["S"].size // 2, "f32")
+        others = [o for o in others if "64,128,128]" in o]
+        assert not others, "state-sized buffers in a piece:\n" + "\n".join(others)
+        writes, others = _slab_sized_results(text, slab[5].size // 2)
+        assert not others, "slab-sized buffers in a piece:\n" + "\n".join(others)
+        moe_counts = compiled.out_info[2]
+        assert (moe_counts.shape, moe_counts.dtype) == ((3,), jnp.int32) and temp < 2.5e9
+
+
 def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):
     """``sampling.spec_verify_chunk_batched_paged`` (``--spec-draft 4``, 16
     rows): one forward per dispatch, so the whole program is the step."""

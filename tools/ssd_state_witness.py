@@ -4,8 +4,14 @@ the chip: what ``correct`` cannot see (a rule over greedy tokens is blind to a
 state kept in bfloat16: ``benchmark/workloads/granite-4.0-h-micro.batch_prompted.json``).
 
     chiprun --timeout 1200 -- python3 tools/ssd_state_witness.py [--variants served,state_bf16,state_e4m3]
+    chiprun --timeout 1500 -- python3 tools/ssd_state_witness.py --config granite-4.0-h-small-q40-10l-ep4 --held 8
 
-Granite-4.0-H-Micro's published widths, its first period of ten layers (nine
+Granite-4.0-H-Micro's published widths (``--config``: another configuration
+of the lineage, as Granite-4.0-H-Small's 128 heads, a state of ``[64, 128,
+128]`` a row and layer, behind whose mixers stand held experts; ``--held``
+holds fewer of them, since float32 weights are 4 B where the served Q40 is
+0.6: 18 experts a layer in float32 are 11.6 GB beside the state, 8 are 7.9),
+its first period of ten layers (nine
 state-space, the softmax layer at index 5), seeded weights, the program's own
 forwards in FLOAT32 (so that no Q80 rounding hides the state): a few rows are
 prefilled in pieces of 256 tokens through ``llama.forward_tokens`` (the state
@@ -144,6 +150,9 @@ def main(argv: list[str]) -> int:
     ap.add_argument("--steps", type=int, default=512)
     ap.add_argument("--every", type=int, default=64)
     ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--config", default="granite-4.0-h-micro-q40",
+                    help="a configuration of the lineage under benchmark/configs/")
+    ap.add_argument("--held", type=int, help="hold this many of the configuration's routed experts")
     args = ap.parse_args(argv)
     import jax
 
@@ -151,8 +160,10 @@ def main(argv: list[str]) -> int:
 
     dev = jax.devices()[0]
     print(json.dumps({"platform": dev.platform, "kind": dev.device_kind}), flush=True)
-    with open(os.path.join(ROOT, "benchmark", "configs", "granite-4.0-h-micro-q40.json")) as f:
-        config = dict(json.load(f), num_hidden_layers=10, name="granite-4.0-h-micro-q40.10l")
+    with open(os.path.join(ROOT, "benchmark", "configs", f"{args.config}.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=10, name=f"{args.config}.10l")
+    if args.held:
+        config["num_local_experts"] = args.held
     cache = os.path.join(ROOT, "benchmark", ".cache")  # where a run's own files go; git ignores it
     os.makedirs(cache, exist_ok=True)
     with tempfile.TemporaryDirectory(dir=cache) as tmp:
