@@ -128,7 +128,7 @@ def _n_padded(n: int) -> int:
 def _d_padded(d: int) -> int:
     """Padded output dim: only pad dims that exceed the tile target — small
     matrices take small tiles (or the XLA fallback) without a blow-up."""
-    return -(-d // 1024) * 1024 if d > 1024 else d
+    return _least_width_of_no_more_tiles(d) if d > 1024 else d
 
 
 def _pack_halves(vals_t: np.ndarray, scales_t: np.ndarray, n: int, d: int) -> QuantizedMatrix:
@@ -840,3 +840,25 @@ def _q40_matmul_fallback(x: jax.Array, qm: QuantizedMatrix) -> jax.Array:
         precision=jax.lax.Precision.HIGHEST,
     )
     return out[:, : qm.d] if dp != qm.d else out
+
+
+def _least_width_of_no_more_tiles(d: int) -> int:
+    """:func:`_d_padded` for a width over 1024 (down here so that every kernel
+    body above keeps its line: a program's text carries them). The next
+    multiple of 1024 always tiles; the answer is the LEAST multiple of 128
+    from ``d`` up that needs no more output tiles than that one does at any
+    row class served with a tile of 1024 or more (``_BLOCK_D_BY_ROWS``), so a
+    launch reads fewer zero-scale columns and takes no more grid steps: 1536
+    stays 1536 (one tile, or two of 768), 1344 takes 1536 (1408 is eleven
+    tiles of 128), 2560 keeps 3072 (a fourth tile of 640 at 256 rows)."""
+    caps = [cap for _, cap in _BLOCK_D_BY_ROWS if cap >= 1024]
+
+    def tiles(width: int) -> list[int]:
+        return [width // _largest_divisor_tile(width, cap, 128) for cap in caps]
+
+    top = -(-d // 1024) * 1024
+    most = tiles(top)
+    for width in range(-(-d // 128) * 128, top, 128):
+        if all(a <= b for a, b in zip(tiles(width), most)):
+            return width
+    return top

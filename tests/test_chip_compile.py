@@ -14,6 +14,7 @@ All compiles happen in the test's own process.
 """
 
 import dataclasses
+import json
 import os
 import re
 
@@ -152,6 +153,35 @@ def test_q40_grouped_kernel_compiles_over_a_bank_of_held_experts(one_chip, monke
     on = jax.ShapeDtypeStruct((E,), jnp.bool_, sharding=one_chip)
     compiled = q40.q40_grouped_matmul.lower(x, bank, on, role=f"held_experts_t{rows}").compile()
     assert f"q40_int8_grouped_held_experts_t{rows}" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [32, 64, 128, 256])
+def test_q40_grouped_kernel_compiles_at_1536_columns_of_a_4096_deep_bank(one_chip, monkeypatch, rows):
+    """Granite-4.0-H-Small's bank of 18 held experts' gate|up, 4096 -> 1536,
+    whose pack keeps its 1536 columns (``q40._d_padded``; 2048 until PR 51):
+    the compiler accepts one tile of 1536 columns at 32 and 64 rows and two of
+    768 at a piece's buckets of 128 and 256 rows, inside the 32 MiB of scoped
+    VMEM the launch states, and the launch's name and result are what the
+    roofline's reader matches (``benchmark/layer_metrics/q40_held_experts_roofline.json``)."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    n, d, E = 4096, 1536, 18
+    one = _qm_shape(n, d, one_chip)
+    assert one.qs.shape == (2048, 1536) and one.scales.shape == (128, 1536)
+    assert q40._int8_tiles(one, rows, q40.BLOCK_N, q40.BLOCK_D) == (1024, 1536 if rows <= 64 else 768)
+    bank = q40.QuantizedMatrix(
+        jax.ShapeDtypeStruct((E,) + one.qs.shape, jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((E,) + one.scales.shape, jnp.float32, sharding=one_chip), n, d)
+    x = jax.ShapeDtypeStruct((E, rows, n), jnp.float32, sharding=one_chip)
+    on = jax.ShapeDtypeStruct((E,), jnp.bool_, sharding=one_chip)
+    role = f"held_experts_t{2 * rows}"  # a bucket is at most half its step's rows
+    text = q40.q40_grouped_matmul.lower(x, bank, on, role=role).compile().as_text()
+    launch = re.search(rf"%q40_int8_grouped_{role}[.\d]* = (f32\[[\d,]+\])\S* custom-call\(", text)
+    assert launch, [line for line in text.splitlines() if "custom-call" in line][:4]
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+                           "q40_held_experts_roofline.json")) as f:
+        pattern = json.load(f)["reader"]["ops"]
+    assert launch.group(1) == f"f32[{E},{rows},1536]"
+    assert re.match(pattern, f"q40_int8_grouped_{role} {launch.group(1)}")
 
 
 @pytest.mark.parametrize("kernel,tokens", [("kda_step", 32), ("kda_chunk", 256), ("kda_chunk", 8)])
@@ -840,8 +870,9 @@ def test_served_granite_experts_programs_form_nothing_of_a_leafs_size(one_chip, 
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"[granite-4.0-h-small] {program}: temporaries {temp / 1e6:.1f} MB")
     if program == "the 32-row decode chunk":
-        # the every-row arm for certain (the bucket is the whole step): [18, 32, columns]
-        for width in (2048, 4096):
+        # the every-row arm for certain (the bucket is the whole step): [18, 32, columns], the
+        # gate|up bank's 1536 columns as its pack holds them (2048 until PR 51)
+        for width in (1536, 4096):
             assert re.search(rf"f32\[18,32,{width}\]\S* custom-call\(.*q40_int8_grouped_held_experts_t32", text), width
         writes, others = _slab_sized_results(text, slab[0]["S"].size // 2, "f32")
         steps = set(re.findall(r"%(ssd_step[\w.]*) = [^\n]*output_to_operand_aliasing", text))
@@ -855,8 +886,9 @@ def test_served_granite_experts_programs_form_nothing_of_a_leafs_size(one_chip, 
         # ONE conditional a layer: the bucket of 128 rows, the every-row arm behind it
         assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
         for arm_rows in (128, 256):
-            assert re.search(rf"f32\[18,{arm_rows},4096\]\S* custom-call\(.*q40_int8_grouped_held_experts_t256",
-                             text), arm_rows
+            for width in (1536, 4096):
+                assert re.search(rf"f32\[18,{arm_rows},{width}\]\S* custom-call\(.*q40_int8_grouped_held_experts_t256",
+                                 text), (arm_rows, width)
         assert "%ssd_chunk" in text
         # a row's leaf is taken out and put back: nothing of the WHOLE leaf's size but the
         # in-place writes

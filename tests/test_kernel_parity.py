@@ -187,9 +187,10 @@ class TestPackedUnpackBitParity:
     bit-equal to the parent's, body and tile, at every row count a cell
     dispatches."""
 
-    # an eighth of Mixtral's expert gate|up (4096 -> 28672) and Solar's held
-    # gate|up (4096 -> 2560, whose padded 3072 columns tile by 1536) over a
-    # quarter of the hidden size: two input windows, several output tiles
+    # an eighth of Mixtral's expert gate|up (4096 -> 28672; its 3584 columns
+    # are their own pack since PR 51, tiled by 3584, 1792 and 896) and Solar's
+    # held gate|up (4096 -> 2560, whose padded 3072 columns tile by 1536) over
+    # a quarter of the hidden size: two input windows, several output tiles
     @pytest.mark.parametrize("T", sorted(PARENT_BLOCK_D))
     @pytest.mark.parametrize("n,d", [(1024, 3584), (1024, 2560)], ids=["mixtral", "solar"])
     @pytest.mark.parametrize("body", ["dense", "grouped"])
@@ -239,6 +240,81 @@ class TestPackedUnpackBitParity:
         for got, want in zip(run(q40._nibbles), run(_parent_nibbles)):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         assert set(np.unique(np.asarray(qs))) == set(range(256))
+
+
+def _repadded(qm: QuantizedMatrix, columns: int) -> QuantizedMatrix:
+    """The same matrix (or bank) with its pack padded out to ``columns``
+    zero-scale columns: the pack as the rule before PR 51 stored it."""
+    pad = ((0, 0),) * (qm.qs.ndim - 1) + ((0, columns - qm.d_padded),)
+    return QuantizedMatrix(np.pad(np.asarray(qm.qs), pad), np.pad(np.asarray(qm.scales), pad), qm.n, qm.d)
+
+
+class TestColumnPaddingBitParity:
+    """PR 51 pads a pack's columns to what its tiles need (``q40._d_padded``:
+    1536 stays 1536, 1344 takes 1536) where every width over 1024 took the
+    next multiple of 1024. Output columns are independent in both kernels
+    (exact int8 dots, f32 sums over input blocks and input tiles, never over
+    columns) and the input tile does not move, so every real column of every
+    launch carries the bits it carried under 2048 columns, whatever tile the
+    narrower pack takes (one of 1536, or two of 768 at 128 and 256 rows)."""
+
+    N = 2048  # GLM's depth: two input tiles, so the sum over input tiles is in the comparison
+
+    def _packs(self, d, count):
+        rng = np.random.RandomState(d + count)
+        packs = [quantize_q40_tpu(rng.randn(self.N, d).astype(np.float32) / np.sqrt(self.N))
+                 for _ in range(count)]
+        assert {p.d_padded for p in packs} == {1536} and {p.n_padded for p in packs} == {self.N}
+        return packs, rng
+
+    def _close_to_dequant(self, got, x, pack):
+        want = np.asarray(x, np.float32) @ dequantize_tpu(pack)
+        scale = np.abs(want).max()
+        np.testing.assert_allclose(np.asarray(got) / scale, want / scale, atol=2e-2)
+
+    @pytest.mark.parametrize("T", [1, 32, 256])
+    @pytest.mark.parametrize("d", [1536, 1344])
+    def test_the_dense_launch_gives_the_bits_of_2048_columns(self, d, T):
+        """The dense entry takes the nibbles' +8 bias off AFTER the launch, by
+        an XLA dot over the pack's scales (``_int8_core``), and that dot's
+        last bit is its backend's: the CPU's picks its blocking by the
+        matrix's width between 8 and 32 rows (the chip's is held to the bit
+        by tools/q40_pad_parity.py). So the LAUNCH is held to the bit on rows
+        whose Q80 blocks sum to zero (the second half of each block the
+        first's negative: the correction is then exactly zero and the entry's
+        output is the launch's), and the entry on any rows to a float32
+        rounding of the correction."""
+        (pack,), rng = self._packs(d, 1)
+        before = _repadded(pack, 2048)
+        half = rng.randn(T, self.N // 32, 16).astype(np.float32)
+        zero_sum = jnp.asarray(np.concatenate([half, -half], axis=-1).reshape(T, self.N)).astype(jnp.bfloat16)
+        xq, _ = quantize_q80(zero_sum)
+        assert not np.asarray(xq, np.int32).reshape(T, -1, 32).sum(-1).any()
+        got = q40_matmul(zero_sum, pack, interpret=True)
+        assert got.shape == (T, d)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(q40_matmul(zero_sum, before, interpret=True)))
+        self._close_to_dequant(got, zero_sum, pack)
+        x = jnp.asarray(rng.randn(T, self.N).astype(np.float32)).astype(jnp.bfloat16)
+        got = np.asarray(q40_matmul(x, pack, interpret=True))
+        np.testing.assert_allclose(got, np.asarray(q40_matmul(x, before, interpret=True)),
+                                   rtol=0, atol=1e-5 * np.abs(got).max())
+        self._close_to_dequant(got, x, pack)
+
+    @pytest.mark.parametrize("T", [32, 128])
+    @pytest.mark.parametrize("rows", ["shared", "per_expert"])
+    @pytest.mark.parametrize("d", [1536, 1344])
+    def test_the_grouped_launch_gives_the_bits_of_2048_columns(self, d, rows, T):
+        packs, rng = self._packs(d, 3)
+        bank, on = stack_bank(packs), jnp.asarray([True, False, True])
+        x = jnp.asarray(rng.randn(*((T,) if rows == "shared" else (3, T)), self.N).astype(np.float32))
+        got = q40_grouped_matmul(x, bank, on, interpret=True)
+        before = q40_grouped_matmul(x, _repadded(bank, 2048), on, interpret=True)
+        assert got.shape == (3, T, 1536) and before.shape == (3, T, 2048)
+        np.testing.assert_array_equal(np.asarray(got)[..., :d], np.asarray(before)[..., :d])
+        # the padding's columns and the expert no row chose: exact zeros
+        assert not np.asarray(got)[..., d:].any() and not np.asarray(got)[1].any()
+        for e in (0, 2):
+            self._close_to_dequant(got[e, :, :d], x if rows == "shared" else x[e], packs[e])
 
 
 def _noted(fn, *args) -> dict[str, int]:

@@ -4,10 +4,12 @@ unpack: the provenance of ``ops/q40.py``'s ``_BLOCK_D_BY_ROWS`` (PERF.md §6, PR
     chiprun --timeout 3000 -- python3 tools/q40_sweep.py [shape ...]
 
 For each shape (Mixtral's two expert widths, Mistral's wqkv and wo, Solar's lin_in and its bank
-of held experts), T in ROWS, block_d in TILES (block_n stays 1024) and the unpack on packed words
-(``q40._nibbles``) or widened to int32 (the body before PR 31, kept here): 50 launches over 4 weight
-buffers in turn under a profiler capture, the median of the kernel's own device events. One JSON
-line a point, or a compiler's refusal, on stdout and appended to ``chiprun_out/q40_sweep.jsonl``.
+of held experts, Granite-4.0-H-Small's bank of held experts' gate|up at the 1536 columns its pack
+has since PR 51 and at the 2048 it was padded to before), T in ROWS, block_d in TILES (block_n
+stays 1024) and the unpack on packed words (``q40._nibbles``) or widened to int32 (the body before
+PR 31, kept here): 50 launches over 4 weight buffers in turn under a profiler capture, the median
+of the kernel's own device events. One JSON line a point, or a compiler's refusal, on stdout and
+appended to ``chiprun_out/q40_sweep.jsonl``.
 """
 
 import json
@@ -24,10 +26,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.harness import trace_reduce  # noqa: E402
 from distributed_llama_tpu.ops import q40  # noqa: E402
 
-# name: (n, d, experts in a bank or 0); the bank goes through q40_grouped_matmul, all on
+# name: (n, d, experts in a bank or 0[, the columns the pack holds where not q40._d_padded(d)]);
+# the bank goes through q40_grouped_matmul, all on
 SHAPES = {"mixtral_gate_up": (4096, 28672, 0), "mixtral_down": (14336, 4096, 0),
           "mistral_wqkv": (4096, 6144, 0), "mistral_wo": (4096, 4096, 0),
-          "solar_lin_in": (4096, 25600, 0), "solar_held_bank": (4096, 2560, 20)}
+          "solar_lin_in": (4096, 25600, 0), "solar_held_bank": (4096, 2560, 20),
+          "granite_small_held_bank": (4096, 1536, 18), "granite_small_held_bank_2048": (4096, 1536, 18, 2048)}
 ROWS, TILES, LAUNCHES, BUFFERS = (1, 8, 16, 32, 64, 128, 256), (512, 1024, 2048, 4096), 50, 4
 
 
@@ -39,10 +43,11 @@ def _widen(qs_ref):
 UNPACKS = {"packed": q40._nibbles, "widen": _widen}
 
 
-def _weights(key, n, d, E):
-    lead, np_, dp = ((E,) if E else ()), q40._n_padded(n), q40._d_padded(d)
+def _weights(key, n, d, E, dp=None):
+    lead, np_, dp = ((E,) if E else ()), q40._n_padded(n), dp or q40._d_padded(d)
     k1, k2 = jax.random.split(key)
     scales = jax.random.uniform(k2, lead + (np_ // 32, dp), jnp.float32, 0.5, 1.5) / 300.0
+    scales = jnp.where(jnp.arange(dp) < d, scales, 0.0)  # the padding's columns hold zero scales
     return q40.QuantizedMatrix(jax.random.bits(k1, lead + (np_ // 2, dp), dtype=jnp.uint8), scales, n, d)
 
 
@@ -53,8 +58,8 @@ def _entry(x, qm, E, bd, role, interpret=q40._interpret_default()):  # True only
 
 
 def sweep(name):
-    n, d, E = SHAPES[name]
-    mats = [_weights(jax.random.PRNGKey(i), n, d, E) for i in range(BUFFERS)]
+    n, d, E, *held = SHAPES[name]
+    mats = [_weights(jax.random.PRNGKey(i), n, d, E, *held) for i in range(BUFFERS)]
     points = []
     for T in ROWS:
         x = jax.random.normal(jax.random.PRNGKey(T), (T, n), jnp.float32).astype(jnp.bfloat16)

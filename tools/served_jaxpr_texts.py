@@ -17,6 +17,11 @@ Widths are the toys': no line of the compared code reads one.
     (cd .parent_check && PYTHONPATH=$PWD python3 tools/served_jaxpr_texts.py /tmp/served/parent)   # the tool copied in
     PYTHONPATH=$PWD python3 tools/served_jaxpr_texts.py /tmp/served/change
     diff -r /tmp/served/parent /tmp/served/change && echo byte-equal
+
+With ``--published`` (PR 51: a change to the rule that pads a Q40 pack, which reads WIDTHS) the
+same two programs of every file of ``benchmark/configs/`` at its published widths, the params as
+shapes from the tree's own loader over a reader that holds no bytes (``tests/q40_leaf_shapes.py``,
+copied into the other tree beside the tool): nothing is written or allocated, a minute for all.
 """
 import hashlib
 import os
@@ -34,9 +39,11 @@ import exaone_tiny
 import glm_tiny
 import granite_tiny
 import solar_tiny
+from benchmark import families
 from benchmark.harness import modelfile
 from distributed_llama_tpu.engine import InferenceEngine, batch
 from distributed_llama_tpu.models import llama, sampling
+from distributed_llama_tpu.models.config import config_from_spec
 
 out = sys.argv[1]
 print("tree:", TREE)
@@ -63,18 +70,29 @@ def shapes(tree):
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
 
-with tempfile.TemporaryDirectory() as tmp:
-    for name, config in CONFIGS.items():
-        model = modelfile.write_model(os.path.join(tmp, name + ".m"), {**config, "name": name}, SEQ, 7)
-        engine = InferenceEngine(model, dtype="q40", max_seq_len=SEQ)
-        cfg, params = engine.cfg, shapes(engine.params)
-        slab = shapes(jax.eval_shape(lambda: llama.init_batch_cache(cfg, ROWS, dtype=jnp.bfloat16)))
-        pool = shapes(jax.eval_shape(lambda: llama.init_page_pool(cfg, PAGES, PAGE, dtype=jnp.bfloat16)))
-        s = jax.ShapeDtypeStruct
-        rows = lambda dt: s((ROWS,), dt)
-        write(f"{name}.decode_chunk_{ROWS}", sampling.decode_chunk_batched, (0, 6), cfg, params, rows(jnp.int32),
-              slab, rows(jnp.int32), rows(jnp.bool_), 32, rows(jnp.float32), rows(jnp.float32),
-              rows(jnp.int32), rows(jnp.uint32))
-        scalar = s((), jnp.int32)
-        write(f"{name}.piece_{PIECE}", batch._slab_prefill_single_paged, (0,), cfg, params,
-              s((PIECE,), jnp.int32), slab, pool, scalar, scalar, scalar, s((SEQ // PAGE,), jnp.int32), scalar)
+def served(name, cfg, params):
+    slab = shapes(jax.eval_shape(lambda: llama.init_batch_cache(cfg, ROWS, dtype=jnp.bfloat16)))
+    pool = shapes(jax.eval_shape(lambda: llama.init_page_pool(cfg, PAGES, PAGE, dtype=jnp.bfloat16)))
+    s = jax.ShapeDtypeStruct
+    rows = lambda dt: s((ROWS,), dt)
+    write(f"{name}.decode_chunk_{ROWS}", sampling.decode_chunk_batched, (0, 6), cfg, params, rows(jnp.int32),
+          slab, rows(jnp.int32), rows(jnp.bool_), 32, rows(jnp.float32), rows(jnp.float32),
+          rows(jnp.int32), rows(jnp.uint32))
+    scalar = s((), jnp.int32)
+    write(f"{name}.piece_{PIECE}", batch._slab_prefill_single_paged, (0,), cfg, params,
+          s((PIECE,), jnp.int32), slab, pool, scalar, scalar, scalar, s((SEQ // PAGE,), jnp.int32), scalar)
+
+
+if "--published" in sys.argv[2:]:
+    from tests import q40_leaf_shapes
+
+    for name in q40_leaf_shapes.CONFIGS:
+        config = q40_leaf_shapes.config_of(name)
+        cfg = config_from_spec(families.load(config, "modelfile").model_spec(config, SEQ))
+        served(name, cfg, shapes(q40_leaf_shapes.param_shapes(name)))
+else:
+    with tempfile.TemporaryDirectory() as tmp:
+        for name, config in CONFIGS.items():
+            model = modelfile.write_model(os.path.join(tmp, name + ".m"), {**config, "name": name}, SEQ, 7)
+            engine = InferenceEngine(model, dtype="q40", max_seq_len=SEQ)
+            served(name, engine.cfg, shapes(engine.params))
