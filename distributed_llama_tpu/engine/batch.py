@@ -108,7 +108,6 @@ from distributed_llama_tpu.engine.engine import TokenStats, _prefill_bucket, nex
 from distributed_llama_tpu.engine.speculative import PromptLookupDrafter
 from distributed_llama_tpu.models import llama
 from distributed_llama_tpu.models.config import LlamaConfig
-from distributed_llama_tpu.models.moe import held_bucket_rows
 from distributed_llama_tpu.ops import kv_cache as kvc
 from distributed_llama_tpu.telemetry import Stopwatch, device_ledger, flight
 
@@ -3006,17 +3005,14 @@ class BatchScheduler:
             extra = list(integrity.chunk_extra_rows(toks, self.chunk))
             toks, fps, finite = integrity.split_chunk_outputs(toks, self.chunk)
             if engine.cfg.n_routed_experts and extra:
-                # the expert share's routing sums came with the tokens
-                held = extra.pop(0)
+                # the expert share's routing sums came with the tokens, and the layer-steps
+                # of the chunk that ran every held expert over every row (no bucket under
+                # the step's rows, or one that overflowed: models.moe._held_experts)
+                held, every_row = extra.pop(0), int(extra.pop(0)[0])
                 if tel.enabled:
                     self._count_moe(int(held.sum()), n_active * self.chunk, self.chunk)
-                    # a decode step does not say which arm it took: it is the every-row
-                    # arm for certain where the bucket is the whole step (models.moe.
-                    # _held_experts), else it is counted as the bucket that fits
-                    layers = self._routing_layers() * self.chunk
-                    whole = held_bucket_rows(engine.cfg, bucket) >= bucket
-                    self._count_expert_rows("decode", int(held.sum()), layers if whole else 0,
-                                            layers, bucket)
+                    self._count_expert_rows("decode", int(held.sum()), every_row,
+                                            self._routing_layers() * self.chunk, bucket)
                     self._count_prefill_moe(wait=True)
             elif engine.cfg.is_moe and tel.enabled:
                 # every expert held: nothing is read here but the layers'

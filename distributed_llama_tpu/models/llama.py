@@ -1068,6 +1068,8 @@ def forward_step_batched(
     paged=None,  # (pool, tables, matched) — zero-copy prefix aliasing
     held_counts: list | None = None,  # receives int32 [B], as in forward_tokens
     kv_reads: dict | None = None,  # receives {kind: int32 [B]}: cache positions read
+    every_row: list | None = None,  # receives an int32 scalar: the expert layers that ran
+    # every held expert over every row of the step (``models.moe``: no bucket, or one overflowed)
 ) -> tuple[jax.Array, jax.Array]:
     """One batched decode step: B tokens (one per sequence) at per-row
     positions through the whole model, reading each weight matrix ONCE.
@@ -1087,10 +1089,13 @@ def forward_step_batched(
     from distributed_llama_tpu.ops.attention import collect_kv_reads
 
     with moe.collect_held(held_counts is not None) as per_layer, \
+            moe.collect_piece_paths(every_row is not None) as paths, \
             collect_kv_reads(kv_reads is not None) as reads:
         out = _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, paged)
     if per_layer:
         held_counts.append(sum(per_layer))
+    if paths:
+        every_row.append(sum(paths))
     for kind, positions in reads or ():
         # per row, over the step's layers of that kind
         kv_reads[kind] = kv_reads.get(kind, 0) + positions

@@ -364,22 +364,28 @@ def held_bucket_rows(cfg: LlamaConfig, rows: int) -> int:
     expert ``rows * k / routed`` of them when routing is even; the bucket is
     four times that, reckoned for the largest step of its class (64 rows: the
     decode steps and small pieces; 256: a prefill chunk), as a power of two,
-    at least 8 and at most HALF the class's rows. At 8 of 320 that is 8 and 32
-    (a chunk gives an expert 6.4 rows, s.d. 2.5), at 8 of 128 and 4 of 64 16
-    and 64. Where a token chooses a seventh of the experts (10 of 72) four
-    times the share is the whole step (36 -> 64 of 64, 142 -> 256 of 256), a
-    bucket of every row that is no bucket: the cap leaves it 32 and 128, twice
-    the share as a power of two, so that a 256-row piece computes the rows
-    that chose an expert and not 7.2 times as many. A step in which some
-    expert overflows its bucket takes the every-row path instead (exact
-    either way)."""
+    at least 8. At 8 of 320 that is 8 and 32 (a chunk gives an expert 6.4
+    rows, s.d. 2.5), at 8 of 128 and 4 of 64 16 and 64. Where that is MORE
+    than half the class (a token chooses a seventh of the experts, 10 of 72:
+    36 -> 64 of 64, 142 -> 256 of 256, a bucket of every row that is no
+    bucket for any step of the class), the bucket is reckoned from the step's
+    own rows instead: twice its even share, as a power of two (16 of a 32-row
+    decode step, where an expert expects 4.4 rows, s.d. 1.96; 32 of 64, 64
+    of 128, 128 of 256), and a step of fewer than 32 rows keeps every row (a
+    launch of at most 16 rows is its weights' bytes: a bucket buys nothing
+    and costs its gather and combine). A step in which some expert
+    overflows its bucket takes the every-row path instead (exact either
+    way)."""
     import math
 
     from distributed_llama_tpu.models.config import next_pow2
 
     largest = 64 if rows <= 64 else 256
-    expected = largest * cfg.n_active_experts / cfg.router_width
-    return max(8, min(next_pow2(math.ceil(4 * expected)), largest // 2))
+    share = cfg.n_active_experts / cfg.router_width
+    bucket = max(8, next_pow2(math.ceil(4 * largest * share)))
+    if bucket <= largest // 2:
+        return bucket
+    return rows if rows < 32 else next_pow2(math.ceil(2 * rows * share))
 
 
 def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) -> jax.Array:
@@ -409,6 +415,25 @@ def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) ->
                       preferred_element_type=jnp.float32)
 
 
+def _bucket_slots(local: jax.Array, weights: jax.Array, E: int, C: int):
+    """A step's buckets as two [T, E * C] matrices over its slots (row ``c``
+    of expert ``e``'s bucket is slot ``e * C + c``): ``place`` marks the slot
+    each of a token's choices takes, ``mix`` holds the choice's weight there,
+    so that gathering the buckets and scattering their results back are two
+    small matmuls (``place.T @ x`` picks one row a slot, exactly; ``mix @
+    outs`` is the weighted sum) and no row is moved one by one: a step's
+    ``T * k`` row-sized scatter updates and gathers cost what its bucket
+    saved (PERF.md section 6, PR 52). ``local`` [T, k]: the chosen experts,
+    ``E`` the sink (no held expert, a pad row: no slot). A choice ranks
+    behind the earlier tokens that chose its expert (a token's k choices are
+    distinct); one ranked past ``C`` takes no slot."""
+    chose = jnp.sum(jax.nn.one_hot(local, E + 1, dtype=jnp.float32), axis=1)  # [T, E + 1]
+    rank = jnp.take_along_axis(jnp.cumsum(chose, axis=0) - chose, local, axis=1).astype(jnp.int32)
+    slot = jnp.where((local < E) & (rank < C), local * C + rank, E * C)  # E * C: one_hot's zero row
+    hot = jax.nn.one_hot(slot, E * C, dtype=jnp.float32)  # [T, k, E * C]
+    return jnp.sum(hot, axis=1), jnp.einsum("tk,tks->ts", weights, hot)
+
+
 def _held_experts(
     cfg: LlamaConfig, xn: jax.Array, lp, top_vals: jax.Array, top_idx: jax.Array,
     n_real: jax.Array | None = None,
@@ -417,8 +442,7 @@ def _held_experts(
     the routing's ([T, k] weights, [T, k] ids over the router's width). Each
     held expert's rows are gathered into a bucket of ``held_bucket_rows``
     rows and computed there, the results scattered back by the weights
-    (``bucket_rank`` / ``bucket_scatter`` / ``bucket_combine``, the algebra
-    of the capacity-bucketed prefill and the expert-parallel dispatch): an
+    (:func:`_bucket_slots`: both as one matmul over the step's slots): an
     expert computes its own rows, not every row of the step. Where some
     expert has more rows than its bucket, the buckets are twice as large
     (a document of few distinct tokens routes alike: 8 to 28 % of a piece's
@@ -450,10 +474,12 @@ def _held_experts(
 
     def bucketed(C):
         def run():
-            flat_e, rank, t_ids = bucket_rank(local, E + 1)
-            buckets = bucket_scatter(xn, flat_e, rank, t_ids, E, C)
-            return bucket_combine(_held_ffn(cfg, buckets, lp, on, T), jnp.minimum(local, E - 1),
-                                  rank, weights, C)
+            place, mix = _bucket_slots(local, weights, E, C)
+            hi = jax.lax.Precision.HIGHEST
+            buckets = jnp.einsum("ts,td->sd", place.astype(xn.dtype), xn, precision=hi,
+                                 preferred_element_type=xn.dtype).reshape(E, C, -1)
+            outs = _held_ffn(cfg, buckets, lp, on, T)
+            return jnp.einsum("ts,sd->td", mix, outs.reshape(E * C, -1), precision=hi)
         return run
 
     most = jnp.max(counts)

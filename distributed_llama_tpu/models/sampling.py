@@ -497,7 +497,9 @@ def batched_decode_scan(
     requests can join/leave between chunks without a recompile. Returns
     (tokens [n_steps, B], cache, fingerprints uint32 [B], finite bool
     [B]) and, for an arch that holds a share of its experts, int32 [B] the
-    row's expert choices that fell on a held expert, and for one with window
+    row's expert choices that fell on a held expert and int32 [B] (one number
+    in every column) the chunk's expert layer-steps that ran every held expert
+    over every row of the step, and for one with window
     or EVA layers two more, the cache positions the row's layers read by
     kind (``cfg.kv_read_kinds``) — NOTHING else needs to cross the host per
     chunk: the sampler is
@@ -520,22 +522,25 @@ def batched_decode_scan(
     hashes) — the overhead-bound test compiles both and compares."""
 
     # an arch that holds a share of its experts: per row, the choices that
-    # fell on a held expert, summed over the chunk's steps and layers
+    # fell on a held expert, summed over the chunk's steps and layers; and the
+    # layer-steps that took the every-row arm (the step's own counts: a bucket
+    # that overflowed is not counted as if it fit)
     share = cfg.n_routed_experts > 0
     # an arch with window or EVA layers: per row, the cache positions its
     # layers read, by kind, summed likewise (two more rows of the bundle)
     kinds = cfg.kv_read_kinds
 
     def step(carry, _):
-        tokens, cache_c, p, h, okf, held, kv = carry
-        counts = [] if share else None
+        tokens, cache_c, p, h, okf, held, whole, kv = carry
+        counts, paths = ([], []) if share else (None, None)
         reads = {} if kinds else None
         logits, cache_c = llama.forward_step_batched(
             cfg, params, tokens, cache_c, p, active, axis_name=axis_name,
-            paged=paged, held_counts=counts, kv_reads=reads,
+            paged=paged, held_counts=counts, kv_reads=reads, every_row=paths,
         )
         if share:
             held = held + jnp.where(active, counts[0], 0)
+            whole = whole + paths[0]
         if kinds:
             kv = tuple(n + reads[kind] for n, kind in zip(kv, kinds))
         cand = None
@@ -555,20 +560,21 @@ def batched_decode_scan(
         if fingerprint:
             h, okf = integrity.fingerprint_fold(h, okf, logits, nxt)
         p2 = jnp.where(active, p + 1, p)
-        return (nxt.astype(jnp.int32), cache_c, p2, h, okf, held, kv), nxt
+        return (nxt.astype(jnp.int32), cache_c, p2, h, okf, held, whole, kv), nxt
 
     h0, ok0 = integrity.fingerprint_init(first_tokens.shape[0])
     zeros = jnp.zeros(first_tokens.shape, jnp.int32)
-    (_, cache, _, h, okf, held, kv), tokens = jax.lax.scan(
+    (_, cache, _, h, okf, held, whole, kv), tokens = jax.lax.scan(
         step,
         (
             first_tokens.astype(jnp.int32), cache, pos.astype(jnp.int32),
-            h0, ok0, zeros, tuple(zeros for _ in kinds),
+            h0, ok0, zeros, jnp.int32(0) if share else None, tuple(zeros for _ in kinds),
         ),
         None,
         length=n_steps,
     )
-    return (tokens, cache, h, okf) + ((held,) if share else ()) + kv
+    shared = (held, jnp.broadcast_to(whole, held.shape)) if share else ()
+    return (tokens, cache, h, okf) + shared + kv
 
 
 def batched_chunk_from_carry(

@@ -363,9 +363,9 @@ class EngineInstruments:
             "that chose (1.0), a layer on the every-row arm every row of the "
             "program in every held expert (routed / k times the chosen when "
             "routing is even). phase=piece the prompt pieces (their arm comes "
-            "back with their results), phase=decode the decode chunks (every "
-            "row where the bucket is the whole step; a step that overflows a "
-            "smaller bucket is counted as if it fit)",
+            "back with their results), phase=decode the decode chunks (the "
+            "layer-steps that took the every-row arm, no bucket under the "
+            "step's rows or one that overflowed, come back with their tokens)",
             labelnames=("rows", "phase"),
         )
         self.moe_rows_computed = {p: moe_expert_rows.labels(rows="computed", phase=p)

@@ -841,7 +841,7 @@ def test_served_granite_experts_programs_form_nothing_of_a_leafs_size(one_chip, 
     cfg, params, slab, pool, s = _granite_moe_program_shapes(one_chip, rows)
     assert slab[0]["S"].shape == (rows, 64, 128, 128) and slab[5].shape == (2, rows, 2048, 8, 128)
     assert cfg.kv_head_pack == 1 and cfg.softmax_scale == 1 / 128
-    assert (moe.held_bucket_rows(cfg, 32), moe.held_bucket_rows(cfg, 256)) == (32, 128)
+    assert (moe.held_bucket_rows(cfg, 32), moe.held_bucket_rows(cfg, 256)) == (16, 128)
     was_on = telemetry.is_enabled()
     telemetry.enable()
     try:
@@ -870,10 +870,13 @@ def test_served_granite_experts_programs_form_nothing_of_a_leafs_size(one_chip, 
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"[granite-4.0-h-small] {program}: temporaries {temp / 1e6:.1f} MB")
     if program == "the 32-row decode chunk":
-        # the every-row arm for certain (the bucket is the whole step): [18, 32, columns], the
-        # gate|up bank's 1536 columns as its pack holds them (2048 until PR 51)
-        for width in (1536, 4096):
-            assert re.search(rf"f32\[18,32,{width}\]\S* custom-call\(.*q40_int8_grouped_held_experts_t32", text), width
+        # a bucket of 16 rows an expert, twice the even share of the step's own 32 rows, and the
+        # every-row arm behind it (until PR 52 the every-row arm for certain): [18, rows,
+        # columns], the gate|up bank's 1536 columns as its pack holds them (2048 until PR 51)
+        for arm_rows in (16, 32):
+            for width in (1536, 4096):
+                assert re.search(rf"f32\[18,{arm_rows},{width}\]\S* custom-call\(.*q40_int8_grouped_held_experts_t32",
+                                 text), (arm_rows, width)
         writes, others = _slab_sized_results(text, slab[0]["S"].size // 2, "f32")
         steps = set(re.findall(r"%(ssd_step[\w.]*) = [^\n]*output_to_operand_aliasing", text))
         assert len(steps) == 9 and 7 <= len(writes) <= 9 and all("%ssd_step" in w for w in writes), writes

@@ -155,11 +155,13 @@ def test_without_n_real_the_held_experts_lower_to_the_parents_text(engines, arch
         if C >= T:
             return every_row()
 
-        def bucketed():
-            flat_e, rank, t_ids = moe.bucket_rank(local, E + 1)
-            buckets = moe.bucket_scatter(xn, flat_e, rank, t_ids, E, C)
-            return moe.bucket_combine(moe._held_ffn(cfg, buckets, lp, on, T),
-                                      jnp.minimum(local, E - 1), rank, weights, C)
+        def bucketed():  # a bucket's rows by one matmul each way since PR 52 (moe._bucket_slots)
+            place, mix = moe._bucket_slots(local, weights, E, C)
+            hi = jax.lax.Precision.HIGHEST
+            buckets = jnp.einsum("ts,td->sd", place.astype(xn.dtype), xn, precision=hi,
+                                 preferred_element_type=xn.dtype).reshape(E, C, -1)
+            return jnp.einsum("ts,sd->td", mix, moe._held_ffn(cfg, buckets, lp, on, T).reshape(E * C, -1),
+                              precision=hi)
 
         return jax.lax.cond(jnp.max(counts) > C, every_row, bucketed)
 
@@ -279,3 +281,69 @@ def test_a_piece_not_yet_complete_is_left_for_the_next_delivery(engines, enabled
     sched._count_prefill_moe(wait=False)
     assert sched._moe_pending == [(queued, 64), (done, 8)]
     assert piece_layers() == {"bucketed": 3, "every_row": 1}
+
+
+@pytest.mark.parametrize("routing,every_row", [("even", 0), ("one_expert", 1)])
+def test_the_decode_chunk_returns_the_layer_steps_that_took_every_row(engines, monkeypatch, routing, every_row):
+    """A 64-row step at 2 of 16 holds a bucket of 32 and the every-row arm (one
+    ``cond`` a layer): the chunk's scan returns, beside the rows' held choices,
+    how many of its expert layer-steps took every row, from the steps' own
+    counts: none where the rows go round the experts evenly, every one where
+    all 64 rows choose one held expert."""
+    from distributed_llama_tpu.models import sampling
+
+    engine = engines("solar")
+    cfg, B, steps = engine.cfg, 64, 2
+    assert moe.held_bucket_rows(cfg, B) == 32 and cfg.n_active_experts == 2 and cfg.router_width == 16
+
+    def router_topk(cfg, xn, router, bias=None):
+        T = xn.shape[0]
+        first = 2 * jnp.arange(T) % 16 if routing == "even" else jnp.full(T, cfg.first_expert)
+        return jnp.full((T, 2), 0.5), jnp.stack([first, first + 1], axis=1).astype(jnp.int32)
+
+    monkeypatch.setattr(moe, "router_topk", router_topk)
+    zeros = jnp.zeros(B, jnp.int32)
+
+    def chunk(params, cache):  # a jit of its own: traced here, under the planted routing
+        return sampling.batched_decode_scan(
+            cfg, params, jnp.arange(B, dtype=jnp.int32) + 5, cache, zeros, jnp.ones(B, bool),
+            zeros.astype(jnp.uint32), steps, jnp.zeros(B), jnp.full(B, 0.9), zeros)
+
+    _, _, _, _, held, whole = jax.jit(chunk)(engine.params, llama.init_batch_cache(cfg, B, dtype=jnp.float32))
+    layers = sum("router" in lp for lp in engine.params["layers"])
+    # one number in every column of its row of the bundle
+    assert np.asarray(whole).tolist() == [every_row * layers * steps] * B
+    # 4 of 16 experts held: a quarter of the even choices, both of a row's where all choose them
+    assert int(np.asarray(held).sum()) == layers * steps * (2 * B if every_row else B // 2)
+
+
+@pytest.mark.parametrize("chosen", ["one_held_expert", "no_held_expert"])
+def test_the_decode_rows_counter_follows_the_chunks_own_arm(engines, enabled, monkeypatch, chosen):
+    """``dllama_moe_expert_rows_total{phase="decode"}`` is fed from the row the
+    chunk returns, not from the rule: one stream in the LAST of 64 rows decodes
+    in the 64-row program (bucket 32, or every row where one overflows). A
+    router bias that sends every row, the 63 idle ones too, to one held expert
+    overflows it in every layer-step, and every held expert is counted over
+    all 64 rows; one that sends them all to absent experts overflows nothing
+    and computes nothing."""
+    engine = engines("solar")
+    cfg = engine.cfg
+    monkeypatch.setattr(engine, "_tel", telemetry.EngineInstruments())
+    to = cfg.first_expert if chosen == "one_held_expert" else 0
+    bias = jnp.zeros(cfg.router_width, jnp.float32).at[to:to + cfg.n_active_experts].set(100.0)
+    monkeypatch.setitem(engine.params, "layers", [
+        {**lp, "router_bias": bias} if "router" in lp else lp for lp in engine.params["layers"]])
+    sched = BatchScheduler(engine, n_rows=64, chunk=2)
+    stream = [sched.new_stream() for _ in range(64)][-1]
+    decode(stream, stream.prefill([5, 6, 7]), 4)
+    rows = telemetry.REGISTRY.get("dllama_moe_expert_rows_total")
+    computed, took = (rows.labels(rows=r, phase="decode").value for r in ("computed", "chosen"))
+    layers = sum("router" in lp for lp in engine.params["layers"])
+    if chosen == "no_held_expert":
+        assert (computed, took) == (0, 0)
+        return
+    # whole chunks of 2 steps, every layer-step on the every-row arm: 4 held experts x 64 rows
+    chunks = computed / (layers * 2 * cfg.n_experts * 64)
+    assert chunks >= 2 and chunks == int(chunks)
+    # the one live row's choices, both on held experts, in every layer of every step
+    assert took == chunks * 2 * layers * cfg.n_active_experts

@@ -5,7 +5,8 @@ unpack: the provenance of ``ops/q40.py``'s ``_BLOCK_D_BY_ROWS`` (PERF.md §6, PR
 
 For each shape (Mixtral's two expert widths, Mistral's wqkv and wo, Solar's lin_in and its bank
 of held experts, Granite-4.0-H-Small's bank of held experts' gate|up at the 1536 columns its pack
-has since PR 51 and at the 2048 it was padded to before), T in ROWS, block_d in TILES (block_n
+has since PR 51 and at the 2048 it was padded to before, and that bank's two launches with
+PER-EXPERT rows ``[18, T, n]``, a held expert's bucket: PR 52), T in ROWS, block_d in TILES (block_n
 stays 1024) and the unpack on packed words (``q40._nibbles``) or widened to int32 (the body before
 PR 31, kept here): 50 launches over 4 weight buffers in turn under a profiler capture, the median
 of the kernel's own device events. One JSON line a point, or a compiler's refusal, on stdout and
@@ -31,8 +32,11 @@ from distributed_llama_tpu.ops import q40  # noqa: E402
 SHAPES = {"mixtral_gate_up": (4096, 28672, 0), "mixtral_down": (14336, 4096, 0),
           "mistral_wqkv": (4096, 6144, 0), "mistral_wo": (4096, 4096, 0),
           "solar_lin_in": (4096, 25600, 0), "solar_held_bank": (4096, 2560, 20),
-          "granite_small_held_bank": (4096, 1536, 18), "granite_small_held_bank_2048": (4096, 1536, 18, 2048)}
+          "granite_small_held_bank": (4096, 1536, 18), "granite_small_held_bank_2048": (4096, 1536, 18, 2048),
+          "granite_small_bucket_gate_up": (4096, 1536, 18), "granite_small_bucket_down": (768, 4096, 18)}
 ROWS, TILES, LAUNCHES, BUFFERS = (1, 8, 16, 32, 64, 128, 256), (512, 1024, 2048, 4096), 50, 4
+# the banks swept with PER-EXPERT rows, x [experts, T, n] (a held expert's bucket: models/moe.py), and their T
+BUCKETS, BUCKET_ROWS = ("granite_small_bucket_gate_up", "granite_small_bucket_down"), (8, 16, 32, 64, 128)
 
 
 def _widen(qs_ref):
@@ -61,12 +65,14 @@ def sweep(name):
     n, d, E, *held = SHAPES[name]
     mats = [_weights(jax.random.PRNGKey(i), n, d, E, *held) for i in range(BUFFERS)]
     points = []
-    for T in ROWS:
-        x = jax.random.normal(jax.random.PRNGKey(T), (T, n), jnp.float32).astype(jnp.bfloat16)
+    lead = (E,) if name in BUCKETS else ()
+    for T in BUCKET_ROWS if lead else ROWS:
+        x = jax.random.normal(jax.random.PRNGKey(T), lead + (T, n), jnp.float32).astype(jnp.bfloat16)
         for bd in sorted({q40._largest_divisor_tile(mats[0].d_padded, want, 128) for want in TILES}):
             for unpack, fn in UNPACKS.items():
                 q40._nibbles, q40._int8_tiles = fn, lambda *a, bd=bd: (q40.BLOCK_N, bd)
-                point = {"shape": name, "n": n, "d": d, "experts": E, "T": T, "bd": bd, "unpack": unpack}
+                point = {"shape": name, "n": n, "d": d, "experts": E, "T": T, "bd": bd, "unpack": unpack,
+                         "rows": "per_expert" if lead else "shared"}
                 role = f"sweep_{unpack}_t{T}_bd{bd}"
                 # a fresh jit of the served entry: its first call traces with the patched unpack and dispatch
                 run = jax.jit(lambda x, qm, bd=bd, role=role: _entry(x, qm, E, bd, role))
