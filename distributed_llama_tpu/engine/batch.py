@@ -208,10 +208,11 @@ def _publish_pages(page: int, slab, pool, page_ids, src_page, row, base: int = 0
     pool aliases in place; the slab is read-only here, and read as it is
     stored: one gather a half takes half, row and slots out of the fused leaf
     (``kvc.publish_leaf_pages``), so nothing but the pages published is read
-    or formed (a latent layer's leaf and pool entry hold one array each)."""
+    or formed (a latent layer's leaf and pool entry hold one array each, two
+    where the layer has an indexer: its index keys ride every page)."""
     return [
         None if half is None
-        else (kvc.publish_latent_pages(half[0], leaf, row, src_page, page_ids, page),)
+        else kvc.publish_latent_pages(half, leaf, row, src_page, page_ids, page)
         if kvc.is_latent_leaf(leaf)
         else kvc.publish_leaf_pages(*half, leaf, row, src_page, page_ids, page, base=base)
         for leaf, half in zip(slab, pool)
@@ -237,8 +238,8 @@ def _restore_pages(slab, pool, page_ids, second, row):
     out = []
     for leaf, half in zip(slab, pool):
         if half is not None and kvc.is_latent_leaf(leaf):
-            leaf = kvc.restore_latent_blocks(leaf, half[0], row, 0, page_ids[0])
-            leaf = kvc.restore_latent_blocks(leaf, half[0], row, second, page_ids[1])
+            leaf = kvc.restore_latent_blocks(leaf, half, row, 0, page_ids[0])
+            leaf = kvc.restore_latent_blocks(leaf, half, row, second, page_ids[1])
         elif half is not None:
             leaf = kvc.restore_row_blocks(leaf, half[0], half[1], row, 0, page_ids[0])
             leaf = kvc.restore_row_blocks(leaf, half[0], half[1], row, second, page_ids[1])
@@ -818,6 +819,11 @@ class BatchScheduler:
                     engine.cfg, "the host spill tier (a state snapshot or a window layer's "
                     "page has no spill form)"
                 )
+        if engine.cfg.has_indexer and (spill_arena is not None or host_spill_bytes > 0):
+            llama.refuse_latent(
+                engine.cfg, "the host spill tier (a page of a layer with an indexer is two arrays, "
+                "latents and index keys, which no spill entry has carried)"
+            )
         if tp_engine is not None:
             llama.refuse_latent(engine.cfg, "a sharded (tp/pod) backend")
         if spec_draft and int(spec_draft) > 0:
@@ -1101,6 +1107,8 @@ class BatchScheduler:
         # (device int32 [3], tokens) of prefill chunks whose expert layers'
         # counts are not read yet
         self._moe_pending: list = []
+        # bytes a slot of a latent leaf's arrays holds, by the array's name (other archs: none)
+        self._kv_bytes_by_kind: dict[str, int] = {}
         if engine.cfg.kv_read_kinds:
             for kind, nbytes in llama.kv_slab_bytes(engine.cfg, n_rows, engine.cache_dtype).items():
                 engine._tel.kv_slab_bytes.labels(kind=kind).set(nbytes)
@@ -1112,12 +1120,16 @@ class BatchScheduler:
         if engine.cfg.has_latent:
             # ... read off the slab's own leaf: what a row really stores of a
             # position (it would rise 15-fold if keys and values were kept)
-            stored = self._slab[0][kvc.LATENT]
-            self._kv_position_bytes = stored.nbytes // (stored.shape[0] * stored.shape[2])
+            # (a leaf with an indexer: of each of its two arrays, by name)
+            self._kv_bytes_by_kind = {
+                name: a.nbytes // (a.shape[0] * a.shape[2]) for name, a in self._slab[0].items()
+            }
+            self._kv_position_bytes = self._kv_bytes_by_kind[kvc.LATENT]
             if self._prefix is not None:
-                engine._tel.kv_pool_bytes.labels(kind="latent").set(
-                    kv_pages * page_size * self._kv_position_bytes * engine.cfg.n_layers
-                )
+                for name, nbytes in self._kv_bytes_by_kind.items():
+                    engine._tel.kv_pool_bytes.labels(kind=name).set(
+                        kv_pages * page_size * nbytes * engine.cfg.n_layers
+                    )
         if self._hit_restores:
             # every shape of a hit's copy is built now, not at the first hit
             # inside a measured window (page 0 into row 0, which starts over
@@ -3021,8 +3033,14 @@ class BatchScheduler:
             if engine.cfg.kv_read_kinds and extra and tel.enabled:
                 # ... and the cache positions each row's layers read, by kind
                 for kind, row in zip(engine.cfg.kv_read_kinds, extra):
-                    tel.kv_read[kind].inc(int(row.sum()) * self._kv_position_bytes)
-                    if kind == "latent":
+                    if kind == "dsa_visible":
+                        # what the rows' queries could see, against what was selected and read
+                        tel.dsa_visible_positions.inc(int(row.sum()) // engine.cfg.n_layers)
+                        continue
+                    # an index key's bytes are its own; a selected row is a latent row
+                    nbytes = self._kv_bytes_by_kind.get(kind, self._kv_position_bytes)
+                    tel.kv_read[kind].inc(int(row.sum()) * nbytes)
+                    if kind in tel.kv_read_positions:
                         # a row's sum runs over its layers: positions, once each
                         tel.kv_read_positions[kind].inc(int(row.sum()) // engine.cfg.n_layers)
             with self._cond:

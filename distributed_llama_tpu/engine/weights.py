@@ -409,16 +409,26 @@ def load_params(
         multiplied per head against 20 small operands, which no Q40 kernel
         tiles, so they are kept DEQUANTISED in the matmul dtype: 2 B a weight
         where the file has 18/32 B (9.2 MB a layer against 2.6 MB at the
-        published widths; a step reads them once)."""
+        published widths; a step reads them once). A layer with an indexer
+        (``cfg.has_indexer``) rides the same two launches: the index key's and
+        the index heads' weights' matrices (128 and 32 columns as published,
+        narrower than any tile) stand behind q_a|kv_a in ``qkv_a``, the index
+        heads' queries behind ``q_b``; the index key's LayerNorm stays f32."""
         p = f"layers.{l}."
         H, nope, v = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
         kv_b = reader.tensor(p + "kv_b").reshape(H, nope + v, cfg.kv_lora_rank)
-        lp = {"qkv_a": fused([p + "q_a", p + "kv_a"]), "q_a_norm": f32(p + "q_a_norm"),
-              "kv_a_norm": f32(p + "kv_a_norm"), "q_b": weight(p + "q_b"),
+        down, up = [p + "q_a", p + "kv_a"], [p + "q_b"]
+        if cfg.has_indexer:
+            down, up = down + [p + "index_k", p + "index_w"], up + [p + "index_q"]
+        lp = {"qkv_a": fused(down), "q_a_norm": f32(p + "q_a_norm"),
+              "kv_a_norm": f32(p + "kv_a_norm"),
+              "q_b": fused(up) if cfg.has_indexer else weight(p + "q_b"),
               "w_uk": cast(np.ascontiguousarray(kv_b[:, :nope])),
               "w_uv": cast(np.ascontiguousarray(kv_b[:, nope:].transpose(0, 2, 1))),
               "wo": weight(p + "wo"),
               "rms_att": norm(p + "rms_att"), "rms_ffn": norm(p + "rms_ffn")}
+        if cfg.has_indexer:
+            lp["index_k_norm"] = f32(p + "index_k_norm")
         if cfg.layer_kind(l)[1] == "dense":
             lp["gate_up"] = fused([p + "gate", p + "up"])
             lp["down"] = weight(p + "down")

@@ -79,6 +79,20 @@ a shared one; its files carry the keys of ``_ARCH_KEYS[ArchType.GLM4_MOE_LITE]``
     head's rows: its nope key rows, then its value rows), wo [dim, H*v]
   dense layer / expert layer: as ``ArchType.EXAONE_MOE``
 
+A file of this arch whose header carries the optional keys ``INDEX_N_HEADS``,
+``INDEX_HEAD_DIM`` and ``INDEX_TOPK`` (GLM-5, ``glm_moe_dsa``) has in EVERY
+layer a learned sparse selection in front of the latent attention: an indexer
+of ``index_n_heads`` heads scores every earlier position for a query and the
+attention reads the ``index_topk`` best of them; the layer's cache holds, a
+position, an index key of ``index_head_dim`` values beside the latent row.
+Between ``wo`` and the feed-forward such a layer has four tensors more:
+
+  index_q [index_n_heads*index_head_dim, q_lora_rank] (read by the normed
+    query latent), index_k [index_head_dim, dim], index_k_norm (F32)
+    [2, index_head_dim] (a LayerNorm's weight, then its bias), index_w
+    [index_n_heads, dim] (the heads' weights; both read by the block's normed
+    input)
+
 ``ArchType.GRANITE_HYBRID`` (no reference counterpart; :func:`_ssm_layer`)
 mixes state-space layers (Mamba-2's SSD recurrence: ``ssm_heads`` heads of
 ``ssm_head_dim`` values, a state of ``ssm_state`` values a head value, ONE
@@ -223,6 +237,9 @@ class HeaderKey(enum.IntEnum):
     ATTN_SCALE_MICRO = 47  # the softmax scale, where it is not head_size ** -0.5
     LOGITS_DIVISOR_MICRO = 48  # the logits are divided by this / 1e6
     ATTN_SCALE_NANO = 49  # the softmax scale in billionths, where it is no whole millionth
+    INDEX_N_HEADS = 50  # heads of a latent layer's indexer (a learned sparse selection)
+    INDEX_HEAD_DIM = 51  # values of an indexer head, and of the ONE index key a position caches
+    INDEX_TOPK = 52  # positions a query's attention reads: the best by the indexer's score
 
 
 class ArchFlags(enum.IntFlag):
@@ -305,6 +322,12 @@ _SSM_OPTIONAL_KEYS = {
     HeaderKey.FIRST_EXPERT: "first_expert",
     HeaderKey.ATTN_SCALE_NANO: "attn_scale_nano",
 }
+# a latent arch's indexer: the three keys of the learned sparse selection
+_INDEX_KEYS = {
+    HeaderKey.INDEX_N_HEADS: "index_n_heads",
+    HeaderKey.INDEX_HEAD_DIM: "index_head_dim",
+    HeaderKey.INDEX_TOPK: "index_topk",
+}
 # the keys past ROPE_TYPE an arch's files carry, in the order they are written;
 # an arch that is not here writes none of them
 _ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KEYS,
@@ -312,7 +335,8 @@ _ARCH_KEYS = {ArchType.SOLAR_OPEN2: _EXTRA_KEYS, ArchType.EXAONE_MOE: _WINDOW_KE
               ArchType.GRANITE_HYBRID: _SSM_KEYS}
 # ... and behind them the keys a file carries only where its value is not zero, so that a
 # file that states none of them (a dense member of the arch) is byte for byte what it was
-_ARCH_OPTIONAL_KEYS = {ArchType.GRANITE_HYBRID: _SSM_OPTIONAL_KEYS}
+_ARCH_OPTIONAL_KEYS = {ArchType.GRANITE_HYBRID: _SSM_OPTIONAL_KEYS,
+                       ArchType.GLM4_MOE_LITE: _INDEX_KEYS}
 
 
 @dataclasses.dataclass
@@ -374,6 +398,9 @@ class ModelSpec:
     attn_scale_micro: int = 0
     logits_divisor_micro: int = 0
     attn_scale_nano: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     @property
     def head_size(self) -> int:
@@ -514,6 +541,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
                 **_LATENT_KEYS,
                 **_SSM_KEYS,
                 **_SSM_OPTIONAL_KEYS,
+                **_INDEX_KEYS,
             }
             for i in range(0, n_ints, 2):
                 key, value = raw[i], raw[i + 1]
@@ -718,7 +746,8 @@ def _window_layer(spec: ModelSpec, l: int, add) -> None:
 
 def _latent_layer(spec: ModelSpec, l: int, add) -> None:
     """One ``ArchType.GLM4_MOE_LITE`` layer's tensors (the module docstring's
-    list): seven of attention, then a dense SwiGLU or the expert layer."""
+    list): seven of attention, the indexer's four where the file has one, then
+    a dense SwiGLU or the expert layer."""
     wt, f32, dim, hidden = spec.weights_float_type, FloatType.F32, spec.dim, spec.hidden_dim
     p = f"layers.{l}."
     heads, rope = spec.n_heads, spec.qk_rope_head_dim
@@ -731,6 +760,11 @@ def _latent_layer(spec: ModelSpec, l: int, add) -> None:
     add(p + "kv_a_norm", (spec.kv_lora_rank,), f32)
     add(p + "kv_b", (heads * (spec.qk_nope_head_dim + spec.v_head_dim), spec.kv_lora_rank), wt)
     add(p + "wo", (dim, heads * spec.v_head_dim), wt)
+    if spec.index_n_heads:
+        add(p + "index_q", (spec.index_n_heads * spec.index_head_dim, spec.q_lora_rank), wt)
+        add(p + "index_k", (spec.index_head_dim, dim), wt)
+        add(p + "index_k_norm", (2, spec.index_head_dim), f32)
+        add(p + "index_w", (spec.index_n_heads, dim), wt)
     if layer_kind(spec, l)[1] == "dense":
         add(p + "gate", (hidden, dim), wt)
         add(p + "down", (dim, hidden), wt)

@@ -99,6 +99,16 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # a learned sparse selection in front of the latent attention (a file of
+    # that arch with the indexer's header keys; 0 elsewhere): every layer's
+    # indexer scores the earlier positions for a query with ``index_n_heads``
+    # heads of ``index_head_dim`` values against ONE index key a position,
+    # which the layer's cache holds beside the latent row, and the attention
+    # reads the ``index_topk`` positions of largest score (every visible one
+    # while there are no more than that)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # state-space layers beside softmax ones (ArchType.GRANITE_HYBRID; 0
     # elsewhere): layer l is a softmax layer where l % attn_period ==
     # attn_offset, else Mamba-2's SSD recurrence over ``ssm_heads`` heads of
@@ -208,6 +218,11 @@ class LlamaConfig:
             return ("full", "window")
         if self.ssm_state:
             return ("full",)
+        if self.has_indexer:
+            # latent: the rows a layer's scan reads; index: the index keys its
+            # indexer scores; latent_selected: the rows the softmax runs over;
+            # dsa_visible: the positions a query could see
+            return ("latent", "index", "latent_selected", "dsa_visible")
         if self.has_latent:
             return ("latent",)
         return ("eva_window", "eva_summary") if self.has_eva else ()
@@ -216,6 +231,12 @@ class LlamaConfig:
     def has_latent(self) -> bool:
         """Whether the layers keep one latent row a position and no key or value."""
         return self.kv_lora_rank > 0
+
+    @property
+    def has_indexer(self) -> bool:
+        """Whether a latent layer's attention reads a selection of the earlier
+        positions, chosen by an indexer over cached index keys."""
+        return self.index_topk > 0
 
     @property
     def latent_dim(self) -> int:
@@ -327,6 +348,12 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
             f"a latent-attention head of {spec.head_size} values is not its "
             f"{spec.qk_nope_head_dim} unrotated and {spec.qk_rope_head_dim} rotated ones"
         )
+    if spec.index_topk and not (spec.kv_lora_rank and spec.index_n_heads and spec.index_head_dim
+                                and spec.qk_rope_head_dim <= spec.index_head_dim):
+        raise ValueError(
+            "an indexer (index_topk) stands in front of latent attention and needs its heads "
+            "and a head at least as wide as the rotated slice"
+        )
     if spec.eva_chunk and (spec.window % spec.eva_chunk or spec.seq_len % spec.eva_chunk):
         raise ValueError(
             f"EVA attention needs a window ({spec.window}) and a context ({spec.seq_len}) "
@@ -380,6 +407,9 @@ def config_from_spec(spec: ModelSpec, **overrides) -> LlamaConfig:
         qk_nope_head_dim=spec.qk_nope_head_dim,
         qk_rope_head_dim=spec.qk_rope_head_dim,
         v_head_dim=spec.v_head_dim,
+        index_n_heads=spec.index_n_heads,
+        index_head_dim=spec.index_head_dim,
+        index_topk=spec.index_topk,
         attn_offset=spec.attn_offset,
         ssm_heads=spec.ssm_heads,
         ssm_head_dim=spec.ssm_head_dim,

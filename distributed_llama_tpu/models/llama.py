@@ -447,12 +447,26 @@ def _per_head(x: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.swapaxes(out, 0, 1)
 
 
+def _layernorm(x: jax.Array, weight_bias: jax.Array, eps: float = 1e-6) -> jax.Array:
+    """LayerNorm over the last axis in f32; ``weight_bias`` [2, n]: the weight, then the bias."""
+    x = x.astype(jnp.float32)
+    centred = x - jnp.mean(x, axis=-1, keepdims=True)
+    normed = centred * jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
+    return normed * weight_bias[0] + weight_bias[1]
+
+
+def _rope_head(x: jax.Array, rope_rows: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """x [T, heads, n]: the first ``cfg.rope_dim`` values of every head rotated, the rest kept."""
+    r = cfg.rope_dim
+    return jnp.concatenate([apply_rope(x[..., :r], rope_rows, cfg), x[..., r:]], axis=-1)
+
+
 def latent_project(
     cfg: LlamaConfig, lp: Params, x: jax.Array, rope_rows: jax.Array
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, dict, tuple | None]:
     """Norm + the latent layer's projections for T tokens: [T, dim] ->
-    (ABSORBED queries [T, H, latent_dim] f32, the tokens' cache rows [T,
-    latent_dim] f32).
+    (ABSORBED queries [T, H, latent_dim] f32, the tokens' cache rows ``{LATENT:
+    [T, latent_dim]}`` f32, None).
 
     ``[c_q | c | k_r] = rmsnorm(x) W_a`` (one matrix, ``qkv_a``); ``q =
     rmsnorm(c_q) W_qb``, a head ``[q_nope | q_rope]``; ``c_kv = rmsnorm(c)``.
@@ -460,7 +474,16 @@ def latent_project(
     for every head. A head's query against such a row is ``[q_nope W_UK |
     rot(q_rope)]`` (``w_uk`` the head's nope-key rows of the keys' and values'
     up-projection): ``q_nope . (c_kv W_UK^T) = (q_nope W_UK) . c_kv``, so no key
-    is expanded for a cached position."""
+    is expanded for a cached position.
+
+    A layer with an indexer (``cfg.has_indexer``) reads two more slices of the
+    same two launches: behind ``k_r`` the index key ``k_I = layernorm(u W_Ik)``
+    (its first ``rope_dim`` values rotated; the second cache row, ``INDEX``)
+    and the index heads' weights ``w = u W_Iw``; behind ``q`` the index heads
+    ``q_I = rmsnorm(c_q) W_Iq``, each head's first ``rope_dim`` values rotated.
+    The third result is then ``(q_I [T, J, I], w [T, J])``, both f32."""
+    from distributed_llama_tpu.ops import kv_cache as kvc
+
     T = x.shape[0]
     H, nope, rope = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
@@ -469,11 +492,20 @@ def latent_project(
         c_q = rmsnorm(fused[:, :qr], lp["q_a_norm"])
         c_kv = rmsnorm(fused[:, qr : qr + kr], lp["kv_a_norm"])
         k_rope = apply_rope(fused[:, None, qr + kr : qr + kr + rope], rope_rows, cfg)[:, 0]
-        q = _matmul(c_q.astype(lp["q_b"].dtype), lp["q_b"], "mla_project")[:, : H * (nope + rope)]
-        q = q.reshape(T, H, nope + rope)
+        up = _matmul(c_q.astype(lp["q_b"].dtype), lp["q_b"], "mla_project")
+        q = up[:, : H * (nope + rope)].reshape(T, H, nope + rope)
         q_rope = apply_rope(q[..., nope:], rope_rows, cfg)
         q_abs = _per_head(q[..., :nope], lp["w_uk"])
-        return jnp.concatenate([q_abs, q_rope], axis=-1), jnp.concatenate([c_kv, k_rope], axis=-1)
+        queries = jnp.concatenate([q_abs, q_rope], axis=-1)
+        rows = {kvc.LATENT: jnp.concatenate([c_kv, k_rope], axis=-1)}
+        if not cfg.has_indexer:
+            return queries, rows, None
+        J, I = cfg.index_n_heads, cfg.index_head_dim
+        at = qr + kr + rope
+        k_idx = _layernorm(fused[:, at : at + I], lp["index_k_norm"])
+        rows[kvc.INDEX] = _rope_head(k_idx[:, None], rope_rows, cfg)[:, 0]
+        q_idx = up[:, H * (nope + rope) : H * (nope + rope) + J * I].reshape(T, J, I)
+        return queries, rows, (_rope_head(q_idx, rope_rows, cfg), fused[:, at + I : at + I + J])
 
 
 def latent_output(cfg: LlamaConfig, lp: Params, mix: jax.Array) -> jax.Array:
@@ -516,19 +548,29 @@ def latent_attention(
     position), absorbing costs T x 23 kFLOP a position more in scores and mix,
     less below T of about 400, and a piece has at most 256. The published
     softmax scale is ``head_size ** -0.5`` (``head_size`` = nope + rope).
+    A layer with an indexer writes the tokens' index keys too and every
+    token's heads read the positions ITS indexer selects
+    (``ops.attention.dsa_selection``), as a mask over the same scan.
     Returns (the heads' outputs [T, H * v], the leaf)."""
     from distributed_llama_tpu.ops import kv_cache as kvc
-    from distributed_llama_tpu.ops.attention import latent_attention_scan
+    from distributed_llama_tpu.ops.attention import dsa_selection, latent_attention_scan
 
     T, H = x.shape[0], cfg.n_heads
-    queries, rows = latent_project(cfg, lp, x, rope_rows)
+    queries, rows, index = latent_project(cfg, lp, x, rope_rows)
     leaf = kvc.latent_update_rows(cache_l, rows, pos)
-    with jax.named_scope("mla_prefill"):
-        latents = leaf[kvc.LATENT][None]  # [1, D, S]
-        q_pos = jnp.repeat(pos + jnp.arange(T), H)[None]  # [1, T * H]: a token's heads sit together
+    latents = leaf[kvc.LATENT][None]  # [1, D, S]
+    S = latents.shape[2]
+    q_pos = jnp.repeat(pos + jnp.arange(T), H)[None]  # [1, T * H]: a token's heads sit together
+    selection = {}  # the scan's one more argument, where the layer has an indexer
+    if index is not None:
+        selection["selected"], _ = dsa_selection(
+            index[0][None], index[1][None], (pos + jnp.arange(T))[None], leaf[kvc.INDEX][None],
+            ATT_CHUNK if S % ATT_CHUNK == 0 else S, cfg.index_topk,
+        )
+    with jax.named_scope("mla_selected" if selection else "mla_prefill"):
         mix, _ = latent_attention_scan(
-            queries.reshape(1, T * H, -1), q_pos, latents, _latent_chunk(latents.shape[2]),
-            cfg.head_size ** -0.5,
+            queries.reshape(1, T * H, -1), q_pos, latents, _latent_chunk(S), cfg.head_size ** -0.5,
+            **selection,
         )
         return latent_output(cfg, lp, mix.reshape(T, H, -1)), leaf
 
@@ -542,20 +584,38 @@ def latent_attention_batched(
     and its heads' absorbed queries read its own row up to there, every chunk
     up to the bucket's longest row (``ops.attention.latent_attention_scan``).
     A hit's pages were copied into the row, so it reads no pool. Inactive rows
-    write nothing and read from position 0."""
+    write nothing and read from position 0. A layer with an indexer writes the
+    row's index key too, scores the row's index keys up to ``pos[b]`` and runs
+    the softmax over the ``index_topk`` best (``ops.attention.dsa_selection``),
+    MASKED: the scan reads the chunks as before, and the counts say so
+    (``latent``: rows read; ``index``: index keys read; ``latent_selected``:
+    rows the softmax ran over; ``dsa_visible``: rows the step could see)."""
     from distributed_llama_tpu.ops import kv_cache as kvc
-    from distributed_llama_tpu.ops.attention import latent_attention_scan, note_kv_read
+    from distributed_llama_tpu.ops.attention import dsa_selection, latent_attention_scan, note_kv_read
 
     B, H = x.shape[0], cfg.n_heads
     S = cache_l[kvc.LATENT].shape[2]
-    queries, rows = latent_project(cfg, lp, x, rope_rows)
+    queries, rows, index = latent_project(cfg, lp, x, rope_rows)
     leaf = kvc.latent_update_row_batched(cache_l, rows, jnp.where(active & (pos < S), pos, S))
-    with jax.named_scope("mla_decode"):
-        q_pos = jnp.broadcast_to(jnp.where(active, pos, 0)[:, None], (B, H))
+    at = jnp.where(active, pos, 0)
+    q_pos = jnp.broadcast_to(at[:, None], (B, H))
+    selection = {}  # the scan's one more argument, where the layer has an indexer
+    if index is not None:
+        selection["selected"], scored = dsa_selection(
+            index[0][:, None], index[1][:, None], at[:, None], leaf[kvc.INDEX], _latent_chunk(S),
+            cfg.index_topk,
+        )
+    with jax.named_scope("mla_selected" if selection else "mla_decode"):
         mix, read = latent_attention_scan(
-            queries, q_pos, leaf[kvc.LATENT], _latent_chunk(S), cfg.head_size ** -0.5
+            queries, q_pos, leaf[kvc.LATENT], _latent_chunk(S), cfg.head_size ** -0.5, **selection
         )
         note_kv_read("latent", B, read)
+        if selection:
+            # what the ROWS' queries attended and could see: a row that sits the step out has none
+            attended = jnp.sum(selection["selected"][:, 0].astype(jnp.int32), axis=-1)
+            note_kv_read("index", B, scored)
+            note_kv_read("latent_selected", B, jnp.where(active, attended, 0))
+            note_kv_read("dsa_visible", B, jnp.where(active, pos + 1, 0))
         return latent_output(cfg, lp, mix), leaf
 
 
@@ -1295,7 +1355,7 @@ def _init_layer_leaf(cfg: LlamaConfig, l: int, lead: tuple[int, ...], kl: int, d
     if mixer == "latent":
         if kvc.is_quantized_cache_dtype(dtype):
             refuse_latent(cfg, "an i8 cache (its scales are one a head)")
-        return kvc.init_latent(lead, cfg.seq_len, cfg.latent_dim, dtype)
+        return kvc.init_latent(lead, cfg.seq_len, cfg.latent_dim, dtype, cfg.index_head_dim)
     if mixer == "eva" and kvc.is_quantized_cache_dtype(dtype):
         raise ValueError("an EVA layer's summaries have no i8 form: serve it with a plain KV dtype")
     slots = {"window": cfg.ring_len, "eva": cfg.eva_slots}.get(mixer, cfg.seq_len)
@@ -1307,10 +1367,15 @@ def kv_slab_bytes(cfg: LlamaConfig, rows: int, dtype) -> dict[str, int]:
     (``full``, ``window``): a full layer's grow with ``seq_len``, a window
     layer's are its ring's. An EVA arch: by store (``eva_window``: the window's
     slots, which do not grow with ``seq_len``; ``eva_summary``: one entry per
-    ``eva_chunk`` positions). A latent arch: ``latent``, every position's row."""
+    ``eva_chunk`` positions). A latent arch: ``latent``, every position's row,
+    and ``index``, every position's index key, where its layers have an indexer."""
     per_slot = page_pool_bytes(cfg, 1, dtype, layers=1)
     if cfg.has_latent:
-        return {"latent": rows * cfg.seq_len * per_slot * cfg.n_layers}
+        per_value = rows * cfg.seq_len * cfg.n_layers * jnp.dtype(dtype).itemsize
+        held = {"latent": per_value * cfg.latent_dim}
+        if cfg.has_indexer:
+            held["index"] = per_value * cfg.index_head_dim
+        return held
     if cfg.has_eva:
         return {"eva_window": rows * cfg.window * per_slot * cfg.n_layers,
                 "eva_summary": rows * cfg.eva_summaries * per_slot * cfg.n_layers}
@@ -1381,10 +1446,13 @@ def init_page_pool(
     if cfg.has_eva:
         return _init_pool(cfg, "eva", n_pages, page // cfg.eva_chunk, kl, dtype)
     if cfg.has_latent:
-        # a latent layer's page: ``page`` rows of latent_dim values, ONE half, flat
+        # a latent layer's page: ``page`` rows of latent_dim values, ONE half, flat; behind it,
+        # where the layer has an indexer, the rows' index keys as a second flat half
         if kvc.is_quantized_cache_dtype(dtype):
             refuse_latent(cfg, "an i8 cache (its scales are one a head)")
-        return [(kvc.init_latent_pool(n_pages, page, cfg.latent_dim, dtype),) for _ in range(cfg.n_layers)]
+        dims = (cfg.latent_dim, cfg.index_head_dim) if cfg.has_indexer else (cfg.latent_dim,)
+        return [tuple(kvc.init_latent_pool(n_pages, page, dim, dtype) for dim in dims)
+                for _ in range(cfg.n_layers)]
     return _init_pool(cfg, "full", n_pages, page, kl, dtype)
 
 
@@ -1422,9 +1490,9 @@ def page_pool_bytes(cfg: LlamaConfig, page: int, dtype, layers: int | None = Non
 
     kl, hd = cfg.n_kv_heads, cfg.head_size
     if cfg.has_latent:
-        # one row a position and layer, no halves
-        return ((cfg.n_layers if layers is None else layers) * page * cfg.latent_dim
-                * jnp.dtype(dtype).itemsize)
+        # one row a position and layer, no halves (and its index key, where there is an indexer)
+        return ((cfg.n_layers if layers is None else layers) * page
+                * (cfg.latent_dim + cfg.index_head_dim) * jnp.dtype(dtype).itemsize)
     if layers is None and cfg.has_eva:
         # an EVA arch's pool page holds the block's summaries, in every layer
         layers, page = cfg.n_layers, page // cfg.eva_chunk

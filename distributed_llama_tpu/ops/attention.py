@@ -26,7 +26,9 @@ from distributed_llama_tpu.ops import kv_cache as kvc
 # int32 [B]): the positions of each row's cache it read (``full``: the row's
 # OWN chunks where the row-bounded kernel serves, the chunks up to the
 # bucket's longest row, every row alike, where the XLA scan does; ``latent``
-# where the layer keeps latents; ``window``: the window;
+# where the layer keeps latents (and, where it has an indexer, ``index``: the
+# index keys scored, ``latent_selected``: the rows its softmax ran over,
+# ``dsa_visible``: the positions the row could see); ``window``: the window;
 # ``eva_window`` / ``eva_summary``: an EVA scan's chunks of each store,
 # by row or by bucket likewise).
 # The forward that opened it sums by kind and returns the sums with its
@@ -450,6 +452,7 @@ def latent_attention_scan(
     latents: jax.Array,  # a latent leaf's array [B_max, D, S], positions minor, the new rows in it
     chunk: int,
     scale: float,
+    selected: jax.Array | None = None,  # bool [B, Q / heads, S]: the positions a token's heads read
 ) -> jax.Array:
     """Causal softmax attention ABSORBED over a cache of latents: a position is
     one row of D values which is key and value of every head at once (``score =
@@ -461,7 +464,11 @@ def latent_attention_scan(
     leaf is read and, the chunk being the score product's right-hand side as
     it lies and the mix's transposed, nothing of the leaf's size forms
     (tests/test_chip_compile.py). One loop serves a decode step's rows and a
-    piece's tokens. Returns ([B, Q, D] f32, the positions read a row)."""
+    piece's tokens. ``selected`` (a layer with an indexer,
+    :func:`dsa_selection`): the softmax runs over the marked positions alone,
+    every head of a token over the same ones; the chunks are read all the
+    same, the MASKED form of the selected attention. Returns ([B, Q, D] f32,
+    the positions read a row)."""
     B, Q, D = q.shape
     S = latents.shape[2]
     cdt, prec = latents.dtype, kvc.einsum_precision(latents)
@@ -474,6 +481,10 @@ def latent_attention_scan(
             "bqd,bds->bqs", qc, c, precision=prec, preferred_element_type=jnp.float32
         )
         mask = (i * chunk + jnp.arange(chunk))[None, None, :] <= q_pos[:, :, None]
+        if selected is not None:
+            G = selected.shape[1]
+            sel = jax.lax.dynamic_slice(selected, (0, 0, i * chunk), (B, G, chunk))
+            mask = (mask.reshape(B, G, Q // G, chunk) & sel[:, :, None, :]).reshape(B, Q, chunk)
         scores = jnp.where(mask, scores, -jnp.inf)
         ms = jnp.max(scores, axis=-1)
         # a fully-masked chunk keeps m = -inf, the empty partial (merge_partials)
@@ -489,6 +500,91 @@ def latent_attention_scan(
     o0 = jnp.zeros((B, Q, D), jnp.float32)
     m, l, o = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, o0))
     return o / jnp.maximum(l, 1e-30)[..., None], n_chunks * chunk
+
+
+# ---------------------------------------------------------------------------
+# A learned sparse selection in front of latent attention (DeepSeek Sparse
+# Attention; GLM-5's ``glm_moe_dsa``). Every position caches ONE index key of I
+# values beside its latent row. A query at t brings J index heads ``q_I[j]`` and
+# J weights ``w[j]`` and scores every position it can see: ``I[t, s] = sum_j
+# w[t, j] * relu(q_I[t, j] . k_I[s])``. Its attention then runs over the k
+# positions of largest score (over every visible one while there are at most
+# k), chosen EXACTLY: the k-th largest score is found bit by bit, which costs
+# 32 counts over the scores and no sort, and a tie at that score is broken
+# towards the earlier position.
+# ---------------------------------------------------------------------------
+
+
+def dsa_index_scores(
+    q_idx: jax.Array,  # [B, G, J, I] f32: a query token's index heads (G tokens a row)
+    w_idx: jax.Array,  # [B, G, J] f32: the heads' weights
+    q_pos: jax.Array,  # [B, G] the position each token sits at
+    index_keys: jax.Array,  # a latent leaf's index array [B_max, I, S], positions minor
+    chunk: int,
+) -> tuple[jax.Array, jax.Array]:
+    """The indexer's scores of every token against every position it sees,
+    [B, G, S] f32 (``-inf`` at the positions it does not see), the keys read a
+    chunk [I, chunk] at a time up to the farthest token; and the positions
+    read a row. Keys in the cache's dtype, products accumulated in float32."""
+    B, G, J, I = q_idx.shape
+    S = index_keys.shape[2]
+    cdt, prec = index_keys.dtype, kvc.einsum_precision(index_keys)
+    n_chunks = jax.lax.div(jnp.clip(jnp.max(q_pos) + 1, 0, S) + chunk - 1, chunk)
+    qc = q_idx.reshape(B, G * J, I).astype(cdt)
+
+    def body(i, scores):
+        c = jax.lax.dynamic_slice(index_keys, (0, 0, i * chunk), (B, I, chunk))
+        dots = jnp.einsum("bqi,bis->bqs", qc, c, precision=prec, preferred_element_type=jnp.float32)
+        part = jnp.sum(w_idx[..., None] * jax.nn.relu(dots.reshape(B, G, J, chunk)), axis=2)
+        seen = (i * chunk + jnp.arange(chunk))[None, None, :] <= q_pos[:, :, None]
+        return jax.lax.dynamic_update_slice(scores, jnp.where(seen, part, -jnp.inf), (0, 0, i * chunk))
+
+    scores = jax.lax.fori_loop(0, n_chunks, body, jnp.full((B, G, S), -jnp.inf, jnp.float32))
+    return scores, n_chunks * chunk
+
+
+def _ordered_bits(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 whose unsigned order is the floats' order (-inf least)."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def dsa_select(scores: jax.Array, k: int) -> jax.Array:
+    """bool [.., S]: the ``k`` largest of ``scores`` [.., S] along the last
+    axis, exactly ``k`` of them where S >= k (every one otherwise). The k-th
+    largest value's bits are found from the top bit down (the largest
+    threshold that at least ``k`` scores reach); everything above it is kept
+    and, of the scores equal to it, the earliest positions until ``k`` are."""
+    key = _ordered_bits(scores)
+    kth = jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32)
+    for bit in range(31, -1, -1):
+        cand = kth | jnp.uint32(1 << bit)
+        reach = jnp.sum((key >= cand).astype(jnp.int32), axis=-1, keepdims=True)
+        kth = jnp.where(reach >= k, cand, kth)
+    above = key > kth
+    ties = key == kth
+    room = k - jnp.sum(above.astype(jnp.int32), axis=-1, keepdims=True)
+    return above | (ties & (jnp.cumsum(ties.astype(jnp.int32), axis=-1) <= room))
+
+
+def dsa_selection(
+    q_idx: jax.Array, w_idx: jax.Array, q_pos: jax.Array, index_keys: jax.Array, chunk: int, k: int,
+) -> tuple[jax.Array, jax.Array]:
+    """bool [B, G, S], the positions each token's attention reads: the ``k``
+    it sees with the largest indexer score (:func:`dsa_index_scores`,
+    :func:`dsa_select`), every position it sees while no token of the step
+    sees more than ``k`` (the indexer is then not run: its scores could not
+    cut). And the index keys read a row."""
+    S = index_keys.shape[2]
+    visible = jnp.arange(S)[None, None, :] <= q_pos[:, :, None]
+
+    def indexed():
+        with jax.named_scope("dsa_index"):
+            scores, read = dsa_index_scores(q_idx, w_idx, q_pos, index_keys, chunk)
+        with jax.named_scope("dsa_select"):
+            return dsa_select(scores, k) & visible, read
+
+    return jax.lax.cond(jnp.max(q_pos) < k, lambda: (visible, jnp.int32(0)), indexed)
 
 
 # ---------------------------------------------------------------------------

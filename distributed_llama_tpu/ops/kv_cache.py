@@ -511,43 +511,63 @@ def restore_row_blocks(slab_leaf, pool_k, pool_v, row, first, page_ids):
 # ---------------------------------------------------------------------------
 
 LATENT = "latent"
+# ... and, in a layer with an indexer (a learned sparse selection in front of
+# the attention), a second array beside it: the rotated, normed INDEX KEY of
+# every position, ``{LATENT: [.., D, S], INDEX: [.., I, S]}`` (I = 128 as
+# published), positions minor for the same reasons; the pool entry is then the
+# 2-tuple of flat halves ``([P, page * D], [P, page * I])``, in that order
+# (:func:`leaf_arrays`; a dict that has crossed a jit boundary comes back with
+# its keys sorted, so no loop here goes by the dict's own order).
+INDEX = "index"
 
 
-def init_latent(lead: tuple[int, ...], slots: int, dim: int, dtype) -> dict:
-    """A latent layer's cache leaf: ``slots`` positions of ``dim`` values."""
-    return {LATENT: jnp.zeros(lead + (dim, slots), dtype)}
+def leaf_arrays(leaf: dict) -> tuple[str, ...]:
+    """The names of a latent leaf's arrays in the order of its pool entry's halves."""
+    return (LATENT, INDEX) if INDEX in leaf else (LATENT,)
+
+
+def init_latent(lead: tuple[int, ...], slots: int, dim: int, dtype, index_dim: int = 0) -> dict:
+    """A latent layer's cache leaf: ``slots`` positions of ``dim`` values and,
+    for a layer with an indexer, of ``index_dim`` index-key values."""
+    leaf = {LATENT: jnp.zeros(lead + (dim, slots), dtype)}
+    if index_dim:
+        leaf[INDEX] = jnp.zeros(lead + (index_dim, slots), dtype)
+    return leaf
 
 
 def is_latent_leaf(cache_l) -> bool:
     return isinstance(cache_l, dict) and LATENT in cache_l
 
 
-def latent_update_rows(leaf: dict, rows: jax.Array, pos) -> dict:
-    """T positions' rows [T, D] at slots pos..pos+T-1 of a single-row latent
-    leaf [D, S]: one dynamic_update_slice."""
-    a = leaf[LATENT]
-    return {LATENT: jax.lax.dynamic_update_slice(a, rows.astype(a.dtype).T, (0, pos))}
+def latent_update_rows(leaf: dict, rows: dict, pos) -> dict:
+    """T positions' rows (``rows[name]`` [T, D] for each array of the leaf)
+    at slots pos..pos+T-1 of a single-row latent leaf [D, S]: one
+    dynamic_update_slice an array."""
+    return {name: jax.lax.dynamic_update_slice(leaf[name], rows[name].astype(leaf[name].dtype).T, (0, pos))
+            for name in leaf_arrays(leaf)}
 
 
-def latent_update_row_batched(leaf: dict, rows: jax.Array, slot: jax.Array) -> dict:
-    """The batched decode write: row ``b``'s latent row [D] at slot ``slot[b]``
-    of slab row ``b`` of [B_max, D, S]; a slot >= S writes nothing. A row's
-    write is the 128 positions around its slot read, the one column replaced,
-    and written back with one ``dynamic_update_slice`` (a lane tile wide: 147
-    KB a row at the published width). Not a scatter, and not a single column:
-    either wants the D values minor in its operand, the scans read positions
-    minor, and the compiler then converts the whole leaf between the two in
-    every step of every layer (tests/test_chip_compile.py)."""
-    a = leaf[LATENT]
-    D, S = a.shape[1], a.shape[2]
-    W = 128 if S % 128 == 0 else S
-    rows = rows.astype(a.dtype)
-    for b in range(rows.shape[0]):
-        start = jnp.minimum(slot[b], S - 1) // W * W
-        old = jax.lax.dynamic_slice(a, (b, 0, start), (1, D, W))
-        here = (start + jnp.arange(W) == slot[b])[None, None, :]
-        a = jax.lax.dynamic_update_slice(a, jnp.where(here, rows[b][None, :, None], old), (b, 0, start))
-    return {LATENT: a}
+def latent_update_row_batched(leaf: dict, rows: dict, slot: jax.Array) -> dict:
+    """The batched decode write: row ``b``'s latent row [D] (and index key)
+    at slot ``slot[b]`` of slab row ``b`` of [B_max, D, S]; a slot >= S writes
+    nothing. A row's write is the 128 positions around its slot read, the one
+    column replaced, and written back with one ``dynamic_update_slice`` (a
+    lane tile wide: 147 KB a row at the published width). Not a scatter, and
+    not a single column: either wants the D values minor in its operand, the
+    scans read positions minor, and the compiler then converts the whole leaf
+    between the two in every step of every layer (tests/test_chip_compile.py)."""
+    out = {}
+    for name in leaf_arrays(leaf):
+        a, new = leaf[name], rows[name].astype(leaf[name].dtype)
+        D, S = a.shape[1], a.shape[2]
+        W = 128 if S % 128 == 0 else S
+        for b in range(new.shape[0]):
+            start = jnp.minimum(slot[b], S - 1) // W * W
+            old = jax.lax.dynamic_slice(a, (b, 0, start), (1, D, W))
+            here = (start + jnp.arange(W) == slot[b])[None, None, :]
+            a = jax.lax.dynamic_update_slice(a, jnp.where(here, new[b][None, :, None], old), (b, 0, start))
+        out[name] = a
+    return out
 
 
 def init_latent_pool(n_pages: int, page: int, dim: int, dtype):
@@ -560,25 +580,32 @@ def init_latent_pool(n_pages: int, page: int, dim: int, dtype):
     return jnp.zeros((n_pages, page * dim), dtype)
 
 
-def publish_latent_pages(pool_half, leaf: dict, row, src_page, page_ids, page: int):
+def publish_latent_pages(pool_halves: tuple, leaf: dict, row, src_page, page_ids, page: int) -> tuple:
     """:func:`publish_row_pages` for a latent leaf [B, D, S]: row ``row``'s
-    blocks ``src_page[i]`` into pool pages ``page_ids[i]`` of [P, page * D]
-    (:func:`init_latent_pool`); an id at or beyond P drops its write."""
+    blocks ``src_page[i]`` into pool pages ``page_ids[i]`` of each array's
+    half [P, page * D] (:func:`init_latent_pool`, :func:`leaf_arrays`); an id
+    at or beyond P drops its write."""
     slots = block_slots(src_page, page)
-    own = jax.lax.dynamic_index_in_dim(leaf[LATENT], row, 0, keepdims=False)  # [D, S]
-    vals = jnp.take(own, slots, axis=1).T  # [n * page, D]
-    return pool_half.at[page_ids].set(vals.reshape((src_page.shape[0], -1)), mode="drop")
+    out = []
+    for name, pool_half in zip(leaf_arrays(leaf), pool_halves):
+        own = jax.lax.dynamic_index_in_dim(leaf[name], row, 0, keepdims=False)  # [D, S]
+        vals = jnp.take(own, slots, axis=1).T  # [n * page, D]
+        out.append(pool_half.at[page_ids].set(vals.reshape((src_page.shape[0], -1)), mode="drop"))
+    return tuple(out)
 
 
-def restore_latent_blocks(leaf: dict, pool_half, row, first, page_ids) -> dict:
+def restore_latent_blocks(leaf: dict, pool_halves: tuple, row, first, page_ids) -> dict:
     """:func:`restore_row_blocks` for a latent leaf [B, D, S]: pool pages
-    ``page_ids[i]`` of [P, page * D] into block ``first + i`` of row ``row``,
-    one contiguous ``dynamic_update_slice``."""
-    a = leaf[LATENT]
-    D = a.shape[1]
-    run = pool_half[page_ids].reshape((-1, D)).T[None]  # [1, D, n * page]
-    page = pool_half.shape[1] // D
-    return {LATENT: jax.lax.dynamic_update_slice(a, run, (row, 0, first * page))}
+    ``page_ids[i]`` of each array's half [P, page * D] into block ``first +
+    i`` of row ``row``, one contiguous ``dynamic_update_slice`` an array."""
+    out = {}
+    for name, pool_half in zip(leaf_arrays(leaf), pool_halves):
+        a = leaf[name]
+        D = a.shape[1]
+        run = pool_half[page_ids].reshape((-1, D)).T[None]  # [1, D, n * page]
+        page = pool_half.shape[1] // D
+        out[name] = jax.lax.dynamic_update_slice(a, run, (row, 0, first * page))
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -263,10 +263,12 @@ class ApiState:
             spill_mb = getattr(args, "host_spill_mb", None)
             if spill_mb is None:
                 # no default spill tier under an arch with recurrent state or
-                # window layers: a state snapshot and a window layer's page
-                # have no spill form (asked for by flag, the scheduler refuses
-                # by name)
-                spill_mb = 64.0 if engine.cfg.rewinds_by_position else 0.0
+                # window layers, or whose latent layers have an indexer: a
+                # state snapshot, a window layer's page and a page of latents
+                # AND index keys have no spill form (asked for by flag, the
+                # scheduler refuses by name)
+                cfg = engine.cfg
+                spill_mb = 64.0 if cfg.rewinds_by_position and not cfg.has_indexer else 0.0
             spill_mb = float(spill_mb)
             if spill_mb > 0:
                 from distributed_llama_tpu.engine.spill import HostArena

@@ -413,25 +413,44 @@ class EngineInstruments:
             "up to the row's depth; under the XLA scan the bucket's farthest "
             "and deepest row's); for an arch of latent-"
             "attention layers: latent (every chunk up to the bucket's longest "
-            "row, of rows that hold one latent a position and no key or value); "
+            "row, of rows that hold one latent a position and no key or value) "
+            "and, where such a layer has an indexer (a learned sparse "
+            "selection), index (the index keys its indexer scored, every chunk "
+            "up to the bucket's longest row) and latent_selected (the latent "
+            "rows the softmax ran over: the selected ones, part of what kind "
+            "latent read while the selected attention is a masked pass); "
             "counted by the programs from their scans' bounds and returned "
             "with their tokens",
             labelnames=("kind",),
         )
         self.kv_read = {
             kind: kv_read.labels(kind=kind)
-            for kind in ("full", "window", "eva_window", "eva_summary", "latent")
+            for kind in ("full", "window", "eva_window", "eva_summary", "latent", "index",
+                         "latent_selected")
         }
         kv_read_positions = counter(
             "dllama_attn_kv_read_positions_total",
             "Cache positions the batched decode steps' attention scans read, a "
             "position counted once however many layers read it (kind latent: an "
-            "arch of latent-attention layers): dllama_attn_kv_read_bytes_total "
+            "arch of latent-attention layers; index and latent_selected: its "
+            "indexer's keys and the rows selected, the mean over the layers, "
+            "which select each for itself): dllama_attn_kv_read_bytes_total "
             "of the kind over this is the bytes a row stores of one position, "
             "over all layers",
             labelnames=("kind",),
         )
-        self.kv_read_positions = {"latent": kv_read_positions.labels(kind="latent")}
+        self.kv_read_positions = {
+            kind: kv_read_positions.labels(kind=kind)
+            for kind in ("latent", "index", "latent_selected")
+        }
+        self.dsa_visible_positions = counter(
+            "dllama_dsa_visible_positions_total",
+            "Cache positions the batched decode steps' queries could see (a "
+            "row at position t sees t + 1) in an arch whose latent layers "
+            "have an indexer, a position counted once however many layers: "
+            "dllama_attn_kv_read_positions_total{kind=latent_selected} over "
+            "this is the share of its context a query's attention ran over",
+        )
         self.eva_summaries_written = counter(
             "dllama_eva_summaries_written_total",
             "Chunks an arch with EVA layers summarised: a prompt piece or a "

@@ -668,6 +668,91 @@ def test_served_latent_decode_chunk_forms_nothing_of_slab_size(one_chip, monkeyp
     assert not others, "slab-sized buffers besides the cache writes:\n" + "\n".join(others)
 
 
+def _dsa_program_shapes(one_chip, rows: int = 8, layers: int = 2):
+    """(cfg, params, slab, pool, s) of ``glm-5.doc_sessions`` as shapes on the
+    described chip: GLM-5's attention and indexer widths (64 heads, a query
+    latent of 2048, 32 index heads of 128, the 2048 best positions), ``layers``
+    layers with a dense feed-forward, ``rows`` rows of 16384 positions: a
+    latent row of 576 values and an index key of 128 a position, bf16,
+    positions minor; a pool of 64 pages of 64 positions."""
+    cfg = LlamaConfig(
+        arch=ArchType.GLM4_MOE_LITE, dim=6144, hidden_dim=12288, n_layers=layers, n_heads=64,
+        n_kv_heads=64, vocab_size=19360, seq_len=16384, head_size=256, kv_dim=16384,
+        rope_theta=1e6, q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, index_n_heads=32, index_head_dim=128, index_topk=2048,
+    )
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layer = dict(
+        qkv_a=_qm_shape(6144, 2048 + 576 + 128 + 32, one_chip), q_b=_qm_shape(2048, 16384 + 4096, one_chip),
+        q_a_norm=s((2048,), jnp.float32), kv_a_norm=s((512,), jnp.float32),
+        index_k_norm=s((2, 128), jnp.float32),
+        w_uk=s((64, 192, 512), jnp.bfloat16), w_uv=s((64, 512, 256), jnp.bfloat16),
+        wo=_qm_shape(16384, 6144, one_chip),
+        gate_up=_qm_shape(6144, 24576, one_chip), down=_qm_shape(12288, 6144, one_chip),
+        rms_att=s((6144,), jnp.float32), rms_ffn=s((6144,), jnp.float32),
+    )
+    params = dict(
+        embedding=s((19360, 6144), jnp.float32), layers=[layer] * layers,
+        rms_final=s((6144,), jnp.float32), rope_table=s((16384, 32, 2), jnp.float32),
+        wcls=_qm_shape(6144, 19360, one_chip),
+    )
+    placed = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    slab = placed(jax.eval_shape(lambda: llama.init_batch_cache(cfg, rows, dtype=jnp.bfloat16)))
+    pool = placed(jax.eval_shape(lambda: llama.init_page_pool(cfg, 64, 64, dtype=jnp.bfloat16)))
+    assert [(leaf["latent"].shape, leaf["index"].shape) for leaf in slab] == [
+        ((rows, 576, 16384), (rows, 128, 16384))] * layers
+    assert [tuple(h.shape for h in halves) for halves in pool] == [((64, 64 * 576), (64, 64 * 128))] * layers
+    return cfg, params, slab, pool, s
+
+
+def test_served_dsa_decode_chunk_forms_nothing_of_a_leafs_size(one_chip, monkeypatch):
+    """The decode chunk of ``glm-5.doc_sessions``: a step writes both arrays
+    of a layer's leaf in place (a lane tile around each row's new position:
+    the latent row, the index key), the indexer reads the index keys a chunk
+    at a time, the selection works on a row's scores (16384 floats) and the
+    masked latent scan reads the latents a chunk at a time; nothing of the
+    size of the index array (the smaller of the two: rows x 128 x 16384)
+    forms besides the writes, no leaf is copied."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    rows, layers = 8, 2
+    cfg, params, slab, _, s = _dsa_program_shapes(one_chip, rows, layers)
+    compiled = sampling.decode_chunk_batched.lower(
+        cfg, params, s((rows,), jnp.int32), slab, s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        32, s((rows,), jnp.float32), s((rows,), jnp.float32), s((rows,), jnp.int32),
+        s((rows,), jnp.uint32)).compile()
+    writes, others = _slab_sized_results(compiled.as_text(), slab[0]["index"].size // 2)
+    assert len(writes) == 2 * rows * layers, writes
+    assert not others, "leaf-sized buffers besides the cache writes:\n" + "\n".join(others)
+
+
+def test_served_dsa_prompt_piece_forms_nothing_of_a_leafs_size(one_chip, monkeypatch):
+    """A 256-row piece of the same cell: the row's two arrays are taken out of
+    the slab and put back, written once each, and the indexer's scores and the
+    selection of 256 tokens over 16384 positions (a float32 and a mask of
+    [256, 16384]) are no array of the index leaf's size. What does form,
+    once a layer: ONE row's latents transposed ([1, 16384, 576], 19 MB, an
+    eighth of the latent leaf; the piece of a file without an indexer forms
+    none: the mask over the scan makes the compiler hoist the mix's operand
+    out of the loop; PERF.md section 7, PR 53). No leaf is copied."""
+    from distributed_llama_tpu.engine import batch
+
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    rows, layers = 8, 2
+    cfg, params, slab, pool, s = _dsa_program_shapes(one_chip, rows, layers)
+    compiled = batch._slab_prefill_single_paged.lower(
+        cfg, params, s((256,), jnp.int32), slab, pool, s((), jnp.int32), s((), jnp.int32),
+        s((), jnp.int32), s((16384 // 64,), jnp.int32), s((), jnp.int32),
+    ).compile()
+    writes, others = _slab_sized_results(compiled.as_text(), slab[0]["index"].size // 2)
+    one_row = [line for line in others if "bf16[1,16384,576]" in line]
+    assert len(one_row) <= layers and len(one_row) == len(others), (
+        "leaf-sized buffers besides the cache writes:\n" + "\n".join(others))
+    assert writes
+
+
 def _granite_program_shapes(one_chip, periods: int, rows: int):
     """(cfg, params, slab, pool, s) of ``granite-4.0-h-micro.batch_prompted``
     as shapes on the described chip: Granite-4.0-H-Micro's published widths,

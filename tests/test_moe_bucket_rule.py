@@ -34,6 +34,10 @@ ROWS = (1, 8, 16, 32, 64, 128, 256)
     # granite-4.0-h-small-q40-10l-ep4: four times the share is the whole class, so the bucket is
     # twice the even share of the step's OWN rows, and under 32 rows there is none (every row)
     (10, 72, (1, 8, 16, 16, 32, 64, 128)),
+    # glm-5-q40-5l-ep16: a thirty-second of the router's width a token, the fifth ratio; four times
+    # the even share of the class's largest step is 8 of 64 and 32 of 256, Solar's buckets: a
+    # decode step gives a held expert a quarter of a row, a 256-row piece 8 (s.d. 2.8)
+    (8, 256, (8, 8, 8, 8, 8, 32, 32)),
 ])
 def test_the_bucket_by_rows_for_experts_a_token_over_the_routers_width(k, routed, buckets):
     cfg = config(k, routed, 4)

@@ -50,6 +50,40 @@ def test_glms_qkv_a_is_padded_by_192_columns_not_704():
     assert held["qkv_a"] < round(9 * 2048 * 704 * Q40_BYTES)
 
 
+# glm-5-q40-5l-ep16: role -> (packs in the cell's five layers, rows held, columns held, the
+# matrix's rows and columns, MB of padding). The index key's and the index heads' weights'
+# matrices (6144 -> 128 and 6144 -> 32, narrower than any output tile) stand behind q_a|kv_a in
+# ``qkv_a``: 2048 + 576 + 128 + 32 = 2784 columns in 3072 (2816 is 11 tiles of 256 at 256 rows,
+# 2944 is 23 of 128); the index heads' queries behind ``q_b``: 16384 + 4096 = 20480, no padding
+GLM5 = {
+    "qkv_a": (5, 6144, 3072, 6144, 2784, 5.5),
+    "q_b": (5, 2048, 20480, 2048, 20480, 0.0),
+    "wo": (5, 16384, 6144, 16384, 6144, 0.0),
+    "experts_gate_up": (4 * 16, 6144, 4096, 6144, 4096, 0.0),
+    "experts_down": (4 * 16, 2048, 6144, 2048, 6144, 0.0),
+    "wcls": (1, 6144, 19456, 6144, 19360, 0.4),
+}
+
+
+@pytest.fixture(scope="module")
+def glm5():
+    return q40_padded_bytes(leaf_shapes.param_shapes("glm-5-q40-5l-ep16"))
+
+
+@pytest.mark.parametrize("role", sorted(GLM5))
+def test_glm5s_padding_by_leaf(glm5, role):
+    packs, rows, cols, n, d, megabytes = GLM5[role]
+    assert glm5[role] == round(packs * (rows * cols - n * d) * Q40_BYTES)
+    assert round(glm5[role] / 1e6, 1) == megabytes
+
+
+def test_glm5s_indexer_has_no_leaf_of_its_own_and_pads_only_qkv_a(glm5):
+    assert {role for role, held in glm5.items() if held} == {"qkv_a", "wcls"}
+    assert set(glm5) == {"qkv_a", "q_b", "wo", "gate_up", "down", "experts_gate_up", "experts_down",
+                         "shared_gate_up", "shared_down", "wcls"}
+    assert q40._d_padded(2784) == 3072 and q40._d_padded(20480) == 20480
+
+
 @pytest.mark.parametrize("name", leaf_shapes.CONFIGS)
 def test_every_q40_leaf_is_named_and_counts_what_its_shape_holds(name):
     params = leaf_shapes.param_shapes(name)

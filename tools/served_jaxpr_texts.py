@@ -1,8 +1,8 @@
 """The texts of ``jax.make_jaxpr`` of the two big SERVED programs, the batched
 decode chunk (``sampling.decode_chunk_batched``, 32 rows) and a 256-row prompt
-piece (``engine.batch._slab_prefill_single_paged``), for the benchmark's four
+piece (``engine.batch._slab_prefill_single_paged``), for the five of the benchmark's
 accepted configurations that hold experts or a recurrent state
-(Granite-4.0-H-Micro, Solar-Open2, K-EXAONE, GLM-4.7-Flash), written into a
+(Granite-4.0-H-Micro, Solar-Open2, K-EXAONE, GLM-4.7-Flash, GLM-5), written into a
 directory. ``tools/jaxpr_texts.py``'s method for a change to ``models/moe.py``,
 the held experts' file layout or the state-space loader that says it leaves
 those programs as they were: run it from two trees and compare the files.
@@ -10,7 +10,7 @@ those programs as they were: run it from two trees and compare the files.
 Each configuration is its family's toy of ``tests/benchmark/`` (a seeded Q40
 file of a few megabytes, loaded by the tree's own loader) with the REAL
 configuration's experts a token, router width and held experts over it (8 of
-320 with 20 held, 8 of 128 with 16, 4 of 64 with 64): what a bucket rule reads.
+320 with 20 held, 8 of 128 with 16, 4 of 64 with 64, 8 of 256 with 16): what a bucket rule reads.
 Widths are the toys': no line of the compared code reads one.
 
     git archive <parent> | tar -x -C .parent_check
@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 import exaone_tiny
+import glm5_tiny
 import glm_tiny
 import granite_tiny
 import solar_tiny
@@ -56,6 +57,8 @@ CONFIGS = {
     "k-exaone": {**exaone_tiny.CONFIG, "num_experts": 16, "num_experts_per_tok": 8,
                  "reduced_from": {"num_experts": 128}, "first_routed_expert": 64},
     "glm-4.7-flash": {**glm_tiny.CONFIG, "n_routed_experts": 64, "num_experts_per_tok": 4},
+    "glm-5": {**glm5_tiny.CONFIG, "n_routed_experts": 16, "num_experts_per_tok": 8,
+              "reduced_from": {"n_routed_experts": 256}, "first_routed_expert": 0},
 }
 
 
