@@ -316,7 +316,8 @@ def _moe_of_piece(counts, paths, n_real):
     reads it all): the expert choices of its REAL tokens that fell on a held
     expert (0 for an arch that holds every expert), how many of the layers
     ran every expert over every row, and how many ran each expert over its
-    own bucket. None for an arch with no expert layer."""
+    own bucket; [4] for an arch that holds a share: and the rows the held
+    experts' launches multiplied. None for an arch with no expert layer."""
     if not paths:
         return None
     held = jnp.int32(0)
@@ -1648,14 +1649,19 @@ class BatchScheduler:
         tel.moe_assigned_absent.inc(tokens * layers * cfg.n_active_experts - held)
         tel.moe_rows_per_expert.observe(held / (forwards * layers * cfg.n_experts))
 
-    def _count_expert_rows(self, phase: str, chosen: int, every_row: int, layers: int, rows: int) -> None:
+    def _count_expert_rows(
+        self, phase: str, chosen: int, every_row: int, layers: int, rows: int, launched: int
+    ) -> None:
         """``dllama_moe_expert_rows_total``: of ``layers`` expert layers of
         programs of ``rows`` rows, in which ``chosen`` rows in all chose a held
         expert, ``every_row`` ran every held expert over every row of the
         program and the rest each expert over the rows that chose it. The
         step's own counts: the chosen rows come summed over the layers, so
-        the bucketed layers are given their even part of them."""
+        the bucketed layers are given their even part of them. ``launched``:
+        the rows the layers' grouped launches multiplied, a bucket's pad rows
+        up to its last live row tile among them."""
         tel, held = self.engine._tel, self.engine.cfg.n_experts
+        tel.moe_rows_launched[phase].inc(launched)
         tel.moe_rows_chosen[phase].inc(chosen)
         tel.moe_rows_computed[phase].inc(
             every_row * held * rows + chosen * (layers - every_row) / max(layers, 1))
@@ -1686,7 +1692,7 @@ class BatchScheduler:
         cfg, tel = self.engine.cfg, self.engine._tel
         for moe, n_tokens in pending:
             try:
-                held, every_row, bucketed = (int(v) for v in np.asarray(moe))
+                held, every_row, bucketed, *launched = (int(v) for v in np.asarray(moe))
             except Exception:  # the chunk failed on the device: its request says so
                 continue
             if cfg.n_routed_experts:
@@ -1694,7 +1700,7 @@ class BatchScheduler:
                 # the rows of the padded program the piece ran as (but for a piece cut to its
                 # tokens at the context's very end)
                 self._count_expert_rows("piece", held, every_row, every_row + bucketed,
-                                        _prefill_bucket(n_tokens))
+                                        _prefill_bucket(n_tokens), *launched)
             tel.moe_piece_every_row.inc(every_row)
             tel.moe_piece_bucketed.inc(bucketed)
 
@@ -3017,14 +3023,15 @@ class BatchScheduler:
             extra = list(integrity.chunk_extra_rows(toks, self.chunk))
             toks, fps, finite = integrity.split_chunk_outputs(toks, self.chunk)
             if engine.cfg.n_routed_experts and extra:
-                # the expert share's routing sums came with the tokens, and the layer-steps
+                # the expert share's routing sums came with the tokens, the layer-steps
                 # of the chunk that ran every held expert over every row (no bucket under
-                # the step's rows, or one that overflowed: models.moe._held_experts)
-                held, every_row = extra.pop(0), int(extra.pop(0)[0])
+                # the step's rows, or one that overflowed: models.moe._held_experts), and
+                # the rows the held experts' launches multiplied
+                held, every_row, launched = extra.pop(0), int(extra.pop(0)[0]), int(extra.pop(0)[0])
                 if tel.enabled:
                     self._count_moe(int(held.sum()), n_active * self.chunk, self.chunk)
                     self._count_expert_rows("decode", int(held.sum()), every_row,
-                                            self._routing_layers() * self.chunk, bucket)
+                                            self._routing_layers() * self.chunk, bucket, launched)
                     self._count_prefill_moe(wait=True)
             elif engine.cfg.is_moe and tel.enabled:
                 # every expert held: nothing is read here but the layers'

@@ -931,20 +931,22 @@ def forward_tokens(
     ``piece_paths``: a list that receives one int32 [2] array, how many of
     the program's expert layers ran every expert over every row and how
     many ran each expert over its own bucket (``models.moe``; an expert arch
-    in the layered params layout).
+    in the layered params layout); [3] for an arch that holds a share of its
+    experts: and the rows their grouped launches multiplied.
     """
     from distributed_llama_tpu.models import moe
 
     # a scanned layer body cannot hand its tracers to a list outside it
     layered = isinstance(params["layers"], (list, tuple))
     with moe.collect_held(held_counts is not None) as per_layer, \
-            moe.collect_piece_paths(piece_paths is not None and layered) as paths:
+            moe.collect_piece_paths(piece_paths is not None and layered) as paths, \
+            moe.collect_launched(piece_paths is not None and layered) as launched:
         out = _forward_tokens(cfg, params, tokens, cache, pos, axis_name, ep_axis, n_real, paged)
     if per_layer:
         held_counts.append(sum(per_layer))
     if paths:
         every_row = sum(paths)
-        piece_paths.append(jnp.stack([every_row, len(paths) - every_row]))
+        piece_paths.append(jnp.stack([every_row, len(paths) - every_row] + ([sum(launched)] if launched else [])))
     return out
 
 
@@ -1128,8 +1130,9 @@ def forward_step_batched(
     paged=None,  # (pool, tables, matched) — zero-copy prefix aliasing
     held_counts: list | None = None,  # receives int32 [B], as in forward_tokens
     kv_reads: dict | None = None,  # receives {kind: int32 [B]}: cache positions read
-    every_row: list | None = None,  # receives an int32 scalar: the expert layers that ran
-    # every held expert over every row of the step (``models.moe``: no bucket, or one overflowed)
+    every_row: list | None = None,  # receives an int32 [2]: the expert layers that ran every
+    # held expert over every row of the step (``models.moe``: no bucket, or one overflowed), and
+    # the rows the layers' grouped launches multiplied
 ) -> tuple[jax.Array, jax.Array]:
     """One batched decode step: B tokens (one per sequence) at per-row
     positions through the whole model, reading each weight matrix ONCE.
@@ -1150,12 +1153,13 @@ def forward_step_batched(
 
     with moe.collect_held(held_counts is not None) as per_layer, \
             moe.collect_piece_paths(every_row is not None) as paths, \
+            moe.collect_launched(every_row is not None) as launched, \
             collect_kv_reads(kv_reads is not None) as reads:
         out = _forward_step_batched(cfg, params, tokens, cache, pos, active, axis_name, paged)
     if per_layer:
         held_counts.append(sum(per_layer))
     if paths:
-        every_row.append(sum(paths))
+        every_row.append(jnp.stack([sum(paths), sum(launched)]))
     for kind, positions in reads or ():
         # per row, over the step's layers of that kind
         kv_reads[kind] = kv_reads.get(kind, 0) + positions

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from distributed_llama_tpu.formats.model_file import ArchType
 from distributed_llama_tpu.models.config import LlamaConfig
+from distributed_llama_tpu.ops.q40 import grouped_live_tiles
 
 
 def router_probs(cfg: LlamaConfig, xn: jax.Array, router: jax.Array) -> jax.Array:
@@ -340,6 +341,22 @@ def _note_piece_path(every_row) -> None:
         _piece_paths.append(jnp.asarray(every_row, jnp.int32))
 
 
+# And for the rows a layer of HELD experts launched: while one is open
+# (:func:`collect_launched`), every :func:`_held_experts` appends an int32
+# scalar, the rows the arm it took multiplied (:func:`_launched_rows`).
+_launched: list | None = None
+
+
+@contextlib.contextmanager
+def collect_launched(enabled: bool = True):
+    global _launched
+    before, _launched = _launched, [] if enabled else None
+    try:
+        yield _launched
+    finally:
+        _launched = before
+
+
 # Trace-time too: the real rows of the padded piece whose expert share is
 # being traced (None: every row is real). :func:`_moe_share` is wrapped with
 # its three arguments by the benchmark's tests, so ``moe_ffn`` hands it the
@@ -388,12 +405,13 @@ def held_bucket_rows(cfg: LlamaConfig, rows: int) -> int:
     return rows if rows < 32 else next_pow2(math.ceil(2 * rows * share))
 
 
-def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) -> jax.Array:
-    """SwiGLU of every held expert ``on`` marks over its rows: ``x`` [T, D]
-    (every expert multiplies the same rows) or [E, C, D] -> [E, T or C, D].
-    Q40 banks go through ONE grouped launch for gate|up and one for down
-    (``ops.q40.q40_grouped_matmul``: an expert no row chose is neither read
-    nor computed), plain arrays through batched einsums. ``tokens``, the
+def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, counts: jax.Array, tokens: int) -> jax.Array:
+    """SwiGLU of every held expert over its rows, the first ``counts`` [E] of
+    them live: ``x`` [T, D] (every expert multiplies the same rows) or
+    [E, C, D] -> [E, T or C, D]. Q40 banks go through ONE grouped launch for
+    gate|up and one for down (``ops.q40.q40_grouped_matmul``: an expert with
+    no live row is neither read nor computed, nor is a row tile past an
+    expert's live rows), plain arrays through batched einsums. ``tokens``, the
     rows of the step that routed, goes into the launches' names: how many
     experts a launch reads follows from it, not from a bucket's rows."""
     from distributed_llama_tpu.models.llama import _activation
@@ -403,9 +421,9 @@ def _held_ffn(cfg: LlamaConfig, x: jax.Array, lp, on: jax.Array, tokens: int) ->
     width, dim = down.shape[-2], x.shape[-1]
     if isinstance(gate_up, QuantizedMatrix):
         role = f"held_experts_t{tokens}"
-        fused = q40_grouped_matmul(x, gate_up, on, role=role)
+        fused = q40_grouped_matmul(x, gate_up, counts, role=role)
         h = _activation(fused[..., :width], cfg.hidden_act) * fused[..., width : 2 * width]
-        return q40_grouped_matmul(h, down, on, role=role)[..., :dim]
+        return q40_grouped_matmul(h, down, counts, role=role)[..., :dim]
     hi = jax.lax.Precision.HIGHEST
     rows = "td" if x.ndim == 2 else "etd"
     fused = jnp.einsum(f"{rows},edf->etf", x.astype(gate_up.dtype), gate_up, precision=hi,
@@ -434,6 +452,15 @@ def _bucket_slots(local: jax.Array, weights: jax.Array, E: int, C: int):
     return jnp.sum(hot, axis=1), jnp.einsum("tk,tks->ts", weights, hot)
 
 
+def _launched_rows(counts: jax.Array, rows: int) -> jax.Array:
+    """Rows the held experts' grouped launch multiplies of the ``rows`` rows
+    each expert has, the first ``counts`` [E] of them live, int32: the whole
+    row tiles that hold a live row (``ops.q40.grouped_live_tiles``, the
+    launch's own rule)."""
+    tile, live = grouped_live_tiles(counts, rows, shared=False)
+    return tile * jnp.sum(live, dtype=jnp.int32)
+
+
 def _held_experts(
     cfg: LlamaConfig, xn: jax.Array, lp, top_vals: jax.Array, top_idx: jax.Array,
     n_real: jax.Array | None = None,
@@ -460,17 +487,15 @@ def _held_experts(
     local = jnp.where(is_held, local, E)  # E: the sink the scatter drops
     weights = jnp.where(is_held, top_vals, 0.0)
     counts = jnp.sum(jax.nn.one_hot(local, E + 1, dtype=jnp.int32), axis=(0, 1))[:E]
-    on = counts > 0
     C = held_bucket_rows(cfg, T)
 
+    # each arm: its result, and the rows its launches multiplied
     def every_row():
+        rows = jnp.where(counts > 0, T, 0)  # an expert some row chose multiplies them all
         held = jnp.einsum("tk,tke->te", weights, jax.nn.one_hot(local, E + 1)[..., :E])
-        return jnp.einsum("te,etd->td", held, _held_ffn(cfg, xn, lp, on, T),
-                          precision=jax.lax.Precision.HIGHEST)
-
-    if C >= T:
-        _note_piece_path(1)
-        return every_row()
+        out = jnp.einsum("te,etd->td", held, _held_ffn(cfg, xn, lp, rows, T),
+                         precision=jax.lax.Precision.HIGHEST)
+        return out, _launched_rows(rows, T)
 
     def bucketed(C):
         def run():
@@ -478,23 +503,31 @@ def _held_experts(
             hi = jax.lax.Precision.HIGHEST
             buckets = jnp.einsum("ts,td->sd", place.astype(xn.dtype), xn, precision=hi,
                                  preferred_element_type=xn.dtype).reshape(E, C, -1)
-            outs = _held_ffn(cfg, buckets, lp, on, T)
-            return jnp.einsum("ts,sd->td", mix, outs.reshape(E * C, -1), precision=hi)
+            outs = _held_ffn(cfg, buckets, lp, counts, T)
+            out = jnp.einsum("ts,sd->td", mix, outs.reshape(E * C, -1), precision=hi)
+            return out, _launched_rows(counts, C)
         return run
 
     most = jnp.max(counts)
-    if T <= 64 or 2 * C >= T:
+    if C >= T:
+        _note_piece_path(1)
+        out, launched = every_row()
+    elif T <= 64 or 2 * C >= T:
         # a decode step (the class of at most 64 rows), or no room for a
         # second bucket: the bucket, or every expert over every row
         over = most > C
         _note_piece_path(over)
-        return jax.lax.cond(over, every_row, bucketed(C))
-    # a prompt piece: the bucket; a bucket of twice its rows where some expert
-    # overflows the first; every expert over every row where one overflows
-    # that too: which of them, from the step's own counts
-    level = (most > C).astype(jnp.int32) + (most > 2 * C).astype(jnp.int32)
-    _note_piece_path(level == 2)
-    return jax.lax.switch(level, [bucketed(C), bucketed(2 * C), every_row])
+        out, launched = jax.lax.cond(over, every_row, bucketed(C))
+    else:
+        # a prompt piece: the bucket; a bucket of twice its rows where some expert
+        # overflows the first; every expert over every row where one overflows
+        # that too: which of them, from the step's own counts
+        level = (most > C).astype(jnp.int32) + (most > 2 * C).astype(jnp.int32)
+        _note_piece_path(level == 2)
+        out, launched = jax.lax.switch(level, [bucketed(C), bucketed(2 * C), every_row])
+    if _launched is not None:
+        _launched.append(launched)
+    return out
 
 
 def _moe_share(cfg: LlamaConfig, xn: jax.Array, lp) -> jax.Array:

@@ -497,9 +497,10 @@ def batched_decode_scan(
     requests can join/leave between chunks without a recompile. Returns
     (tokens [n_steps, B], cache, fingerprints uint32 [B], finite bool
     [B]) and, for an arch that holds a share of its experts, int32 [B] the
-    row's expert choices that fell on a held expert and int32 [B] (one number
-    in every column) the chunk's expert layer-steps that ran every held expert
-    over every row of the step, and for one with window
+    row's expert choices that fell on a held expert and two int32 [B] (one number
+    in every column): the chunk's expert layer-steps that ran every held expert
+    over every row of the step, and the rows the held experts' launches
+    multiplied, and for one with window
     or EVA layers two more, the cache positions the row's layers read by
     kind (``cfg.kv_read_kinds``) — NOTHING else needs to cross the host per
     chunk: the sampler is
@@ -568,12 +569,12 @@ def batched_decode_scan(
         step,
         (
             first_tokens.astype(jnp.int32), cache, pos.astype(jnp.int32),
-            h0, ok0, zeros, jnp.int32(0) if share else None, tuple(zeros for _ in kinds),
+            h0, ok0, zeros, jnp.zeros(2, jnp.int32) if share else None, tuple(zeros for _ in kinds),
         ),
         None,
         length=n_steps,
     )
-    shared = (held, jnp.broadcast_to(whole, held.shape)) if share else ()
+    shared = (held, *(jnp.broadcast_to(n, held.shape) for n in whole)) if share else ()
     return (tokens, cache, h, okf) + shared + kv
 
 

@@ -625,23 +625,60 @@ def _make_q40_grouped_kernel():
     return kernel
 
 
+# Rows of a row tile of a held expert's bucket (tools/q40_sweep.py's ``tiled``
+# cases, PERF.md §6, PR 54): a launch's time is linear in its rows from 16 up,
+# so a bucket of more rows than this is multiplied tile by tile and the tiles
+# past its expert's own rows are skipped
+GROUPED_ROW_TILE = 32
+
+
+def grouped_row_tile(rows: int) -> int:
+    """Rows of one row tile of a ``rows``-row bucket in the grouped launch: the
+    bucket itself up to ``GROUPED_ROW_TILE`` rows (one tile, every decode
+    step's), else ``GROUPED_ROW_TILE``."""
+    return GROUPED_ROW_TILE if rows > GROUPED_ROW_TILE and rows % GROUPED_ROW_TILE == 0 else rows
+
+
+def grouped_live_tiles(counts: jax.Array, rows: int, shared: bool) -> tuple[int, jax.Array]:
+    """The grouped launch's one rule of what it multiplies: the rows of a row
+    tile, and bool [E, R] which of each expert's ``R`` row tiles hold a live
+    row. ``counts`` [E]: the rows that chose each expert, its live rows the
+    first ``counts[e]`` of its ``rows``. Rows every expert ``shared`` are one
+    tile of all of them; an expert's own bucket is cut by
+    :func:`grouped_row_tile`."""
+    tm = rows if shared else grouped_row_tile(rows)
+    return tm, jnp.arange(rows // tm) * tm < counts.astype(jnp.int32)[:, None]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "role"))
 def q40_grouped_matmul(
     x: jax.Array,
     bank: QuantizedMatrix,
-    on: jax.Array,
+    counts: jax.Array,
     interpret: bool | None = None,
     role: str | None = None,
 ) -> jax.Array:
-    """``y[e] = x[e] @ dequant(bank[e])`` for the experts ``on`` [E] marks,
-    zeros for the others, f32 [E, T, d_padded]. ``x`` is [T, n] (every
-    expert multiplies the same rows) or [E, T, n]. Activations are Q80 as in
+    """``x @ expert`` for every expert of ``bank`` that ``counts`` [E] gives a
+    row, zeros for the others, f32 [E, T, d_padded]. ``x`` is [T, n] (every
+    expert multiplies the same rows) or [E, T, n], expert ``e``'s live rows
+    its first ``counts[e]`` and the rest zeros (a bucket filled from slot 0
+    up; a bool reads as one row). Activations are Q80 as in
     :func:`q40_matmul`'s int8 path. The launch is named
-    ``q40_int8_grouped_<role>``."""
+    ``q40_int8_grouped_<role>``.
+
+    The grid's first axis walks (expert, row tile) pairs, expert-major
+    (:func:`grouped_live_tiles`: one tile an expert but for a bucket of more
+    than ``GROUPED_ROW_TILE`` rows). A tile at or past its expert's count is
+    neither read nor multiplied and comes back as zeros. A live tile runs the
+    same body at the same input tile whatever the rows of a tile, so a live
+    row's result does not depend on how its bucket is cut."""
     E = bank.qs.shape[0]
     T = x.shape[-2]
     np_, dp = bank.n_padded, bank.d_padded
-    tiles = _int8_tiles(bank, T, BLOCK_N, BLOCK_D)
+    shared = x.ndim == 2
+    tm, live = grouped_live_tiles(counts, T, shared)
+    R = T // tm
+    tiles = _int8_tiles(bank, tm, BLOCK_N, BLOCK_D)
     if tiles is None:
         # packs too small or odd to tile (the tests' toy widths): every
         # expert through the XLA fallback, the unchosen ones zeroed
@@ -652,12 +689,11 @@ def q40_grouped_matmul(
                 xe, QuantizedMatrix(qs, sc, bank.n_logical, dp)
             )
         )(xs, bank.qs, bank.scales)
-        return jnp.where(on.astype(bool)[:, None, None], outs, 0.0)
+        return jnp.where(jnp.repeat(live, tm, axis=1)[..., None], outs, 0.0)
     block_n, block_d = tiles
     if interpret is None:
         interpret = _interpret_default()
     _note_path("q40_grouped_matmul", "mxu_int8")
-    shared = x.ndim == 2
     if x.shape[-1] != np_:
         x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, np_ - x.shape[-1]),))
     flat = x.reshape(-1, np_)
@@ -672,30 +708,48 @@ def q40_grouped_matmul(
         xqb, sxw, qsw = xqb[0], sxw[0], qsw[0]
     nj, ni = np_ // block_n, dp // block_d
     nbt = block_n // 2 // QK
-    on = on.astype(jnp.int32)
-    idx = jnp.arange(E, dtype=jnp.int32)
+    on = live.reshape(E * R).astype(jnp.int32)
+    idx = jnp.arange(E * R, dtype=jnp.int32)
     last_on = jax.lax.cummax(jnp.where(on != 0, idx, -1))
+    # the pair whose blocks a grid step holds: its own, or the last live one
+    # before it (the first live one, for those ahead of it): a block index
+    # that does not change starts no DMA
     sel = jnp.where(last_on >= 0, last_on, jnp.argmax(on).astype(jnp.int32))
 
+    def pair(p):
+        """(expert, row tile) of pair ``p``. One tile an expert pays for no
+        division in the launch's nine index maps: 0.6 us of a decode step's
+        launch of 104 (PERF.md section 6, PR 54)."""
+        if R == 1:
+            return p, 0
+        return jax.lax.div(p, jnp.int32(R)), jax.lax.rem(p, jnp.int32(R))
+
+    def held(p, j, sel_ref, on_ref):
+        """(expert, row tile, n-tile) whose blocks pair ``p`` holds at step ``j``."""
+        return *pair(sel_ref[p]), jnp.where(on_ref[p] != 0, j, nj - 1)
+
     def w_map(half):
-        def index(e, i, j, sel_ref, on_ref):
-            live = on_ref[e] != 0
-            jj = jnp.where(live, j, nj - 1)
-            return sel_ref[e], half * nj + jj if half is not None else jj, jnp.where(live, i, ni - 1)
+        def index(p, i, j, sel_ref, on_ref):
+            e, _, jj = held(p, j, sel_ref, on_ref)
+            return e, half * nj + jj if half is not None else jj, jnp.where(on_ref[p] != 0, i, ni - 1)
         return index
 
     def x_map(half):
         if shared:
-            return lambda e, i, j, sel_ref, on_ref: (half * nj + j, 0, 0)
-        return lambda e, i, j, sel_ref, on_ref: (e, half * nj + j, 0, 0)
+            return lambda p, i, j, sel_ref, on_ref: (half * nj + j, 0, 0)
 
-    x_block = (nbt, T, QK) if shared else (None, nbt, T, QK)
-    s_block = (None, T, nbt) if shared else (None, None, T, nbt)
+        def index(p, i, j, sel_ref, on_ref):
+            e, r, jj = held(p, j, sel_ref, on_ref)
+            return e, half * nj + jj, r, 0
+        return index
+
+    x_block = (nbt, tm, QK) if shared else (None, nbt, tm, QK)
+    s_block = (None, tm, nbt) if shared else (None, None, tm, nbt)
     out = pl.pallas_call(
         _make_q40_grouped_kernel(),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(E, ni, nj),
+            grid=(E * R, ni, nj),
             in_specs=[
                 pl.BlockSpec(x_block, x_map(0)),
                 pl.BlockSpec(x_block, x_map(1)),
@@ -707,8 +761,10 @@ def q40_grouped_matmul(
                 pl.BlockSpec((None, nbt, block_d), w_map(0)),
                 pl.BlockSpec((None, nbt, block_d), w_map(1)),
             ],
-            out_specs=pl.BlockSpec((None, T, block_d), lambda e, i, j, sel_ref, on_ref: (e, 0, i)),
-            scratch_shapes=[pltpu.VMEM((T, block_d), jnp.float32)],
+            out_specs=pl.BlockSpec(
+                (None, tm, block_d), lambda p, i, j, sel_ref, on_ref: (*pair(p), i)
+            ),
+            scratch_shapes=[pltpu.VMEM((tm, block_d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((E, T, dp), jnp.float32),
         interpret=interpret,

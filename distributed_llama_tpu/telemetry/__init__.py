@@ -362,7 +362,12 @@ class EngineInstruments:
             "counts: a layer on a bucketed arm that fits computes the rows "
             "that chose (1.0), a layer on the every-row arm every row of the "
             "program in every held expert (routed / k times the chosen when "
-            "routing is even). phase=piece the prompt pieces (their arm comes "
+            "routing is even). rows=launched: the rows the grouped launches "
+            "multiplied, a bucket's pad rows up to its expert's last live "
+            "row tile among them (whole tiles of 32 rows of a bucket of "
+            "more, the whole bucket of one of at most 32; every row of the "
+            "program in each expert some row chose on the every-row arm). "
+            "phase=piece the prompt pieces (their arm comes "
             "back with their results), phase=decode the decode chunks (the "
             "layer-steps that took the every-row arm, no bucket under the "
             "step's rows or one that overflowed, come back with their tokens)",
@@ -372,6 +377,8 @@ class EngineInstruments:
                                   for p in ("decode", "piece")}
         self.moe_rows_chosen = {p: moe_expert_rows.labels(rows="chosen", phase=p)
                                 for p in ("decode", "piece")}
+        self.moe_rows_launched = {p: moe_expert_rows.labels(rows="launched", phase=p)
+                                  for p in ("decode", "piece")}
         self.q40_padded_weight_bytes = gauge(
             "dllama_q40_padded_weight_bytes",
             "Bytes of the resident Q40 leaves of one role (the params leaf's "

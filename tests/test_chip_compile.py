@@ -159,8 +159,9 @@ def test_q40_grouped_kernel_compiles_over_a_bank_of_held_experts(one_chip, monke
 def test_q40_grouped_kernel_compiles_at_1536_columns_of_a_4096_deep_bank(one_chip, monkeypatch, rows):
     """Granite-4.0-H-Small's bank of 18 held experts' gate|up, 4096 -> 1536,
     whose pack keeps its 1536 columns (``q40._d_padded``; 2048 until PR 51):
-    the compiler accepts one tile of 1536 columns at 32 and 64 rows and two of
-    768 at a piece's buckets of 128 and 256 rows, inside the 32 MiB of scoped
+    the compiler accepts one tile of 1536 columns at the 32 rows of a decode
+    bucket and of a row tile of a larger one (64, 128, 256: the launch walks
+    their tiles of 32 since PR 54), inside the 32 MiB of scoped
     VMEM the launch states, and the launch's name and result are what the
     roofline's reader matches (``benchmark/layer_metrics/q40_held_experts_roofline.json``)."""
     monkeypatch.setattr(q40, "_interpret_default", lambda: False)
@@ -182,6 +183,42 @@ def test_q40_grouped_kernel_compiles_at_1536_columns_of_a_4096_deep_bank(one_chi
         pattern = json.load(f)["reader"]["ops"]
     assert launch.group(1) == f"f32[{E},{rows},1536]"
     assert re.match(pattern, f"q40_int8_grouped_{role} {launch.group(1)}")
+
+
+# a 256-row piece's buckets in row tiles (PR 54): Granite-4.0-H-Small's two banks (18 held, bucket 128; a
+# 65-128-row piece's 64), GLM-4.7-Flash's (64 held, buckets of 64 and of 128), and the bucket of 64 rows
+# behind a first one of 32 (one tile, the launch as it was): GLM-5's banks (16 held) and Solar-Open2's (20)
+@pytest.mark.parametrize("E,bucket,n,d", [
+    (18, 128, 4096, 1536), (18, 128, 768, 4096), (18, 64, 4096, 1536),
+    (64, 64, 2048, 3072), (64, 128, 2048, 3072), (64, 128, 1536, 2048),
+    (16, 64, 6144, 4096), (16, 64, 2048, 6144), (20, 64, 4096, 2560), (20, 64, 1280, 4096)])
+def test_q40_grouped_kernel_compiles_in_row_tiles_under_the_buckets_name_and_shape(one_chip, monkeypatch, E, bucket, n, d):
+    """The grouped launch of buckets of more than 32 rows walks (expert, row
+    tile) pairs, ``E * bucket / 32`` of them, each at the 32-row class's
+    output tile (one tile of up to 4096 columns), and the v5e compiler
+    accepts it inside the 32 MiB of scoped VMEM the launch states. Its
+    result keeps the bucket's shape ``[experts, bucket, columns]`` under the
+    launch's name, which is what the five ``q40_held_experts_roofline*``
+    readers match and take ``experts`` and ``rows`` from."""
+    monkeypatch.setattr(q40, "_interpret_default", lambda: False)
+    one = _qm_shape(n, d, one_chip)
+    block_n, block_d = q40._int8_tiles(one, q40.GROUPED_ROW_TILE, q40.BLOCK_N, q40.BLOCK_D)
+    assert q40.grouped_row_tile(bucket) == q40.GROUPED_ROW_TILE == 32
+    # a row tile's output tile is the 32-row class's: no narrower than the whole bucket's was (ROADMAP Speed 3(b2))
+    assert block_d == min(one.d_padded, 4096 if one.d_padded % 4096 == 0 else 3072) >= q40._int8_tiles(one, bucket, q40.BLOCK_N, q40.BLOCK_D)[1]
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bank = q40.QuantizedMatrix(s((E,) + one.qs.shape, jnp.uint8), s((E,) + one.scales.shape, jnp.float32), n, d)
+    args = (s((E, bucket, n), jnp.float32), bank, s((E,), jnp.int32))
+    role = "held_experts_t256"
+    grid = (E * bucket // 32, one.d_padded // block_d, one.n_padded // block_n)
+    assert f"grid={grid}" in str(jax.make_jaxpr(lambda *a: q40.q40_grouped_matmul(*a, role=role))(*args))
+    text = q40.q40_grouped_matmul.lower(*args, role=role).compile().as_text()
+    launch = re.search(rf"%q40_int8_grouped_{role}[.\d]* = (f32\[[\d,]+\])\S* custom-call\(", text)
+    assert launch, [line for line in text.splitlines() if "custom-call" in line][:4]
+    assert launch.group(1) == f"f32[{E},{bucket},{one.d_padded}]"
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+                           "q40_held_experts_roofline.json")) as f:  # the five entries' one reader
+        assert re.match(json.load(f)["reader"]["ops"], f"q40_int8_grouped_{role} {launch.group(1)}")
 
 
 @pytest.mark.parametrize("kernel,tokens", [("kda_step", 32), ("kda_chunk", 256), ("kda_chunk", 8)])
@@ -987,8 +1024,14 @@ def test_served_granite_experts_programs_form_nothing_of_a_leafs_size(one_chip, 
         assert not others, "state-sized buffers in a piece:\n" + "\n".join(others)
         writes, others = _slab_sized_results(text, slab[5].size // 2)
         assert not others, "slab-sized buffers in a piece:\n" + "\n".join(others)
+        # the bucket's launches, in row tiles since PR 54, are the ones the roofline's reader finds
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+                               "q40_held_experts_roofline.json")) as f:
+            pattern = json.load(f)["reader"]["ops"]
+        assert re.match(pattern, "q40_int8_grouped_held_experts_t256 f32[18,128,1536]")
+        # held choices, layers by arm, and the rows the launches multiplied
         moe_counts = compiled.out_info[2]
-        assert (moe_counts.shape, moe_counts.dtype) == ((3,), jnp.int32) and temp < 2.5e9
+        assert (moe_counts.shape, moe_counts.dtype) == ((4,), jnp.int32) and temp < 2.5e9
 
 
 def test_served_verify_chunk_forms_nothing_of_slab_size(one_chip, monkeypatch):

@@ -305,7 +305,7 @@ class TestColumnPaddingBitParity:
     @pytest.mark.parametrize("d", [1536, 1344])
     def test_the_grouped_launch_gives_the_bits_of_2048_columns(self, d, rows, T):
         packs, rng = self._packs(d, 3)
-        bank, on = stack_bank(packs), jnp.asarray([True, False, True])
+        bank, on = stack_bank(packs), jnp.asarray([T, 0, T])  # the rows that chose each expert
         x = jnp.asarray(rng.randn(*((T,) if rows == "shared" else (3, T)), self.N).astype(np.float32))
         got = q40_grouped_matmul(x, bank, on, interpret=True)
         before = q40_grouped_matmul(x, _repadded(bank, 2048), on, interpret=True)

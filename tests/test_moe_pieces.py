@@ -309,10 +309,14 @@ def test_the_decode_chunk_returns_the_layer_steps_that_took_every_row(engines, m
             cfg, params, jnp.arange(B, dtype=jnp.int32) + 5, cache, zeros, jnp.ones(B, bool),
             zeros.astype(jnp.uint32), steps, jnp.zeros(B), jnp.full(B, 0.9), zeros)
 
-    _, _, _, _, held, whole = jax.jit(chunk)(engine.params, llama.init_batch_cache(cfg, B, dtype=jnp.float32))
+    _, _, _, _, held, whole, launched = jax.jit(chunk)(
+        engine.params, llama.init_batch_cache(cfg, B, dtype=jnp.float32))
     layers = sum("router" in lp for lp in engine.params["layers"])
     # one number in every column of its row of the bundle
     assert np.asarray(whole).tolist() == [every_row * layers * steps] * B
+    # the rows the grouped launches multiplied: the 4 held experts' buckets of 32 rows (one row
+    # tile each, 8 rows chose it), or all 64 rows in the 2 experts every row chose
+    assert np.asarray(launched).tolist() == [layers * steps * (2 * 64 if every_row else 4 * 32)] * B
     # 4 of 16 experts held: a quarter of the even choices, both of a row's where all choose them
     assert int(np.asarray(held).sum()) == layers * steps * (2 * B if every_row else B // 2)
 
@@ -337,13 +341,19 @@ def test_the_decode_rows_counter_follows_the_chunks_own_arm(engines, enabled, mo
     stream = [sched.new_stream() for _ in range(64)][-1]
     decode(stream, stream.prefill([5, 6, 7]), 4)
     rows = telemetry.REGISTRY.get("dllama_moe_expert_rows_total")
-    computed, took = (rows.labels(rows=r, phase="decode").value for r in ("computed", "chosen"))
+    computed, took, launched = (rows.labels(rows=r, phase="decode").value for r in ("computed", "chosen", "launched"))
     layers = sum("router" in lp for lp in engine.params["layers"])
+    # the prompt's piece feeds the same three: what the launches multiplied lies between the rows
+    # that chose a held expert and every held expert over every row
+    piece = [rows.labels(rows=r, phase="piece").value for r in ("chosen", "launched", "computed")]
+    assert piece == sorted(piece) and (piece[1] > 0) == (chosen == "one_held_expert")
     if chosen == "no_held_expert":
-        assert (computed, took) == (0, 0)
+        assert (computed, took, launched) == (0, 0, 0)
         return
     # whole chunks of 2 steps, every layer-step on the every-row arm: 4 held experts x 64 rows
     chunks = computed / (layers * 2 * cfg.n_experts * 64)
     assert chunks >= 2 and chunks == int(chunks)
     # the one live row's choices, both on held experts, in every layer of every step
     assert took == chunks * 2 * layers * cfg.n_active_experts
+    # ... and the launches multiplied all 64 rows in the two experts the rows chose, not in all four
+    assert launched == chunks * 2 * layers * cfg.n_active_experts * 64

@@ -43,8 +43,9 @@ def cases():
             yield f"qkv_a_1344.{name}", T, 1344, fn(x, qkv_a), fn(x, _repadded(qkv_a, 2048))
         for name, shape in (("shared", (T, 4096)), ("per_expert", (18, T, 4096))):
             x = jnp.asarray(rng.randn(*shape).astype(np.float32))
-            yield (f"held_gate_up_1536.{name}", T, 1536, q40.q40_grouped_matmul(x, bank, on),
-                   q40.q40_grouped_matmul(x, _repadded(bank, 2048), on))
+            rows = jnp.where(on, T, 0)  # every row of a chosen expert's is live
+            yield (f"held_gate_up_1536.{name}", T, 1536, q40.q40_grouped_matmul(x, bank, rows),
+                   q40.q40_grouped_matmul(x, _repadded(bank, 2048), rows))
 
 
 if __name__ == "__main__":

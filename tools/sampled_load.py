@@ -109,6 +109,10 @@ def main() -> int:
                 path: prom.delta(before, after, "dllama_decode_chunk_sampler_total", {"path": path})
                 for path in ("greedy", "sampled")
             },
+            "expert_rows": {
+                f"{rows}.{phase}": prom.delta(before, after, "dllama_moe_expert_rows_total", {"rows": rows, "phase": phase})
+                for phase in ("piece", "decode") for rows in ("launched", "computed", "chosen")
+            },
             "programs_built_in_window": built,
             "loadgen": {k: report["aggregate"][k] for k in ("counts", "tokens_streamed", "tpot_ms")},
             "loadgen_wall_s": report["wall_s"],

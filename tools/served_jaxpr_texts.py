@@ -18,6 +18,12 @@ Widths are the toys': no line of the compared code reads one.
     PYTHONPATH=$PWD python3 tools/served_jaxpr_texts.py /tmp/served/change
     diff -r /tmp/served/parent /tmp/served/change && echo byte-equal
 
+Beside each text a ``<name>.launches.txt``: the program's kernel launches alone (every
+``pallas_call``'s parameters, the kernel's body among them, its blocks' index maps, which a
+``GridMapping``'s own text leaves out, and its operands' and results' shapes, in program order, no
+variable names), for a change that moves a program's text by something else (a counter's row, PR
+54) and has to say which of its launches are the parent's to the letter.
+
 With ``--published`` (PR 51: a change to the rule that pads a Q40 pack, which reads WIDTHS) the
 same two programs of every file of ``benchmark/configs/`` at its published widths, the params as
 shapes from the tree's own loader over a reader that holds no bytes (``tests/q40_leaf_shapes.py``,
@@ -34,6 +40,7 @@ sys.path[:0] = [TREE, os.path.join(TREE, "tests", "benchmark")]
 
 import jax
 import jax.numpy as jnp
+from jax._src import core as jax_core
 
 import exaone_tiny
 import glm5_tiny
@@ -62,11 +69,23 @@ CONFIGS = {
 }
 
 
+def launches(jaxpr):
+    """The texts of a jaxpr's ``pallas_call`` equations, those of its sub-jaxprs among them, in order."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            avals = [[str(v.aval) for v in vs] for vs in (eqn.invars, eqn.outvars)]
+            maps = [f"  index_map={m.index_map_jaxpr}" for m in eqn.params["grid_mapping"].block_mappings]
+            yield "\n".join([f"pallas_call {avals}"] + [f"  {k}={v}" for k, v in sorted(eqn.params.items())] + maps)
+        for sub in jax_core.jaxprs_in_params(eqn.params):
+            yield from launches(sub)
+
+
 def write(name, fn, static, *args):
-    text = str(jax.make_jaxpr(fn, static_argnums=static)(*args))
-    with open(os.path.join(out, name + ".txt"), "w") as f:
-        f.write(text)
-    print(name, len(text), hashlib.sha256(text.encode()).hexdigest()[:16])
+    jaxpr = jax.make_jaxpr(fn, static_argnums=static)(*args)
+    for suffix, text in ((".txt", str(jaxpr)), (".launches.txt", "\n".join(launches(jaxpr.jaxpr)))):
+        with open(os.path.join(out, name + suffix), "w") as f:
+            f.write(text)
+        print(name + suffix, len(text), hashlib.sha256(text.encode()).hexdigest()[:16])
 
 
 def shapes(tree):
